@@ -448,6 +448,9 @@ class DevicePlane:
         flat, treedef = jax.tree.flatten(tree)
         if not flat:
             raise DevicePlaneError("empty pytree")
+        # the transfer server arms jax Arrays only (jax 0.9 rejects a host
+        # ndarray in the list); a caller's numpy block goes onto the device
+        flat = [x if isinstance(x, jax.Array) else jax.numpy.asarray(x) for x in flat]
         specs = tuple(
             ArraySpec(tuple(x.shape), str(x.dtype), _describe_sharding(x), x.nbytes)
             for x in flat

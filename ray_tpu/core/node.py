@@ -13,6 +13,7 @@ from __future__ import annotations
 import atexit
 import copy
 import itertools
+import math
 import multiprocessing
 import multiprocessing.connection
 import os
@@ -77,6 +78,18 @@ def _system_memory_fraction() -> Optional[float]:
         return None
 
 
+def _see_out(process, grace_s: float = 10.0) -> None:
+    """Wait for a terminated tpu worker to be gone. A chip is free only once
+    its owner has exited — the TPU runtime's own SIGTERM handling can take
+    seconds — and whoever is given the chip next must find it free."""
+    if not hasattr(process, "join"):
+        return  # a remote worker: its node agent owns the process
+    process.join(timeout=grace_s)
+    if process.is_alive():
+        process.kill()
+        process.join(timeout=5.0)
+
+
 class WorkerHandle:
     def __init__(self, worker_id: WorkerID, process, conn, node: "NodeRuntime",
                  accel: str, pool_key: Optional[str] = None):
@@ -89,6 +102,9 @@ class WorkerHandle:
         # SPAWNED with task-specific env vars (reference: dedicated workers per
         # runtime env) — they may only be reused by tasks with the same env
         self.pool_key = pool_key or accel
+        # chips this process was told are its own at spawn; it keeps them,
+        # busy or idle, until it exits (a chip belongs to one process)
+        self.chip_ids: Tuple[int, ...] = ()
         self.state = "starting"  # starting | idle | busy | blocked | dead
         self.started_at = time.time()  # start-timeout watchdog reference point
         self.known_fns: set = set()
@@ -118,6 +134,11 @@ class NodeRuntime:
                             else _default_max_workers())
         self.idle: Dict[str, List[WorkerHandle]] = {}
         self.workers: Dict[WorkerID, WorkerHandle] = {}
+        self.num_chips = int(resources.get("TPU", 0))
+        self.free_chips: List[int] = list(range(self.num_chips))
+        # chips of dead workers whose process has not exited yet: on their way
+        # to free_chips (Cluster._free_chips_when_gone)
+        self.chips_leaving = 0
         self.alive = True
         # which host this node's workers (and their object storage) live on:
         # "local" = the head process's host; remote nodes use their agent's key
@@ -142,6 +163,29 @@ class NodeRuntime:
     def push_idle(self, w: WorkerHandle) -> None:
         w.state = "idle"
         self.idle.setdefault(w.pool_key, []).append(w)
+
+    def claim_chips(self, n: int) -> Optional[Tuple[int, ...]]:
+        """Take `n` chips no live worker owns, or None when fewer are free."""
+        if n > len(self.free_chips):
+            return None
+        taken, self.free_chips = self.free_chips[:n], self.free_chips[n:]
+        return tuple(taken)
+
+    def release_chips(self, chip_ids) -> None:
+        self.free_chips = sorted(self.free_chips + list(chip_ids))
+
+    def pop_idle_chip_holders(self, n: int) -> List[WorkerHandle]:
+        """Remove and return alive idle workers that own chips, as few as own
+        `n` between them (all of them when they own fewer)."""
+        out: List[WorkerHandle] = []
+        for pool in self.idle.values():
+            for w in [w for w in pool if w.chip_ids and w.alive()]:
+                if n <= 0:
+                    return out
+                pool.remove(w)
+                out.append(w)
+                n -= len(w.chip_ids)
+        return out
 
     def steal_idle_slot(self, exclude_key: str) -> Optional[WorkerHandle]:
         """Pop one alive idle worker from a DIFFERENT pool so its slot can be
@@ -475,6 +519,7 @@ class Cluster:
         self._conns: Dict[Any, WorkerHandle] = {}
         self._wakeup_r, self._wakeup_w = _mp.Pipe(duplex=False)
         self._shutdown = False
+        self._chip_reapers: List[threading.Thread] = []
         # multi-host plane (reference: GcsNodeManager + ObjectManager):
         self._agent_conns: Dict[Any, AgentHandle] = {}   # agent TCP conn -> handle
         self._agents_by_key: Dict[str, AgentHandle] = {}  # node_id hex -> handle
@@ -1773,7 +1818,13 @@ class Cluster:
             ledger.release(resources)
             self._dispatch_blocked_on_args = True
             return False  # transfer in flight; rescheduled when it lands
-        accel = "tpu" if resources.get("TPU", 0) > 0 else "cpu"
+        # whole chips: a chip cannot be split between processes, so a
+        # fractional request still makes its worker the owner of one
+        n_chips = math.ceil(resources.get("TPU", 0))
+        accel = "tpu" if n_chips > 0 else "cpu"
+        # a tpu worker sees only the chips it was spawned with, so it is
+        # reused only by tasks that hold as many
+        base_key = f"tpu:{n_chips}" if n_chips > 0 else "cpu"
         # Tasks with runtime_env env_vars get a DEDICATED worker pool keyed by
         # the env hash (reference: worker-per-runtime-env): process-level vars
         # (XLA_FLAGS, JAX_PLATFORMS, ...) only take effect at process spawn, so
@@ -1795,11 +1846,21 @@ class Cluster:
             ek = _hashlib.sha256(_json.dumps(
                 {"env": env_vars, "container": container}, sort_keys=True)
                 .encode()).hexdigest()[:10]
-            pool_key = f"{accel}|env:{ek}"
+            pool_key = f"{base_key}|env:{ek}"
         else:
-            pool_key = accel
+            pool_key = base_key
         worker = node.pop_idle(pool_key)
         if worker is None:
+            chip_ids: Tuple[int, ...] = ()
+            if n_chips > 0:
+                chip_ids = self._claim_chips(node, n_chips)
+                if chip_ids is None:
+                    ledger.release(resources)
+                    return False
+                from .accelerators import TPUAcceleratorManager
+
+                env_vars = {**TPUAcceleratorManager.visible_chips_env(
+                    chip_ids, node.num_chips), **(env_vars or {})}
             try:
                 worker = node.spawn_worker(accel, extra_env=env_vars or None,
                                            pool_key=pool_key,
@@ -1821,12 +1882,15 @@ class Cluster:
             except ContainerRuntimeError as e:
                 # env setup failure fails the TASK (reference: runtime-env
                 # agent setup errors), not the scheduler
+                node.release_chips(chip_ids)
                 ledger.release(resources)
                 self._fail_returns(spec, e)
                 return True
             if worker is None:
+                node.release_chips(chip_ids)
                 ledger.release(resources)
                 return False
+            worker.chip_ids = chip_ids
             # Worker is starting; it will announce "ready". Reserve it for this task by
             # dispatching immediately — the pipe buffers until the worker loop starts.
         worker.state = "busy"
@@ -2464,6 +2528,7 @@ class Cluster:
             if w.resources_held:
                 (w.bundle_ledger or w.node.ledger).release(w.resources_held)
                 w.resources_held = {}
+            self._free_chips_when_gone(w)
             self.metrics_by_worker.pop(w.worker_id, None)
         self._gc_arena_after_death(w)
         if err is None:
@@ -2629,6 +2694,46 @@ class Cluster:
         else:
             self._kill_worker(w, ActorDiedError("actor was killed via ray_tpu.kill()"))
 
+    def _claim_chips(self, node: NodeRuntime, n: int) -> Optional[Tuple[int, ...]]:
+        """Chips for a tpu worker about to be spawned, or None when they are
+        not free yet. The ledger has already admitted the task, so whatever
+        is missing is owned by IDLE workers of other pools (another chip count
+        or runtime env), or by dead ones still on their way out. Retire as
+        many idle owners as own the rest; their exit frees the chips and
+        schedules again (_free_chips_when_gone), the task waits until then."""
+        chips = node.claim_chips(n)
+        if chips is not None:
+            return chips
+        missing = n - len(node.free_chips) - node.chips_leaving
+        for victim in node.pop_idle_chip_holders(missing):
+            self._kill_worker(victim, WorkerCrashedError(
+                "idle tpu worker retired: its chips are needed by a worker "
+                "of another pool"))
+        return None
+
+    def _free_chips_when_gone(self, w: WorkerHandle) -> None:
+        """Give a dead worker's chips back once its process has exited, and
+        not before: only the exit gives a chip up, the TPU runtime's own
+        SIGTERM handling can take seconds, and whoever is handed the chip next
+        must find it free. The wait runs on a thread of its own — callers hold
+        the cluster lock. (caller holds the lock)"""
+        chip_ids, w.chip_ids = w.chip_ids, ()
+        if not chip_ids:
+            return
+        node = w.node
+        node.chips_leaving += len(chip_ids)
+
+        def reap():
+            _see_out(w.process)
+            with self._lock:
+                node.chips_leaving -= len(chip_ids)
+                node.release_chips(chip_ids)
+            self._schedule()
+
+        t = threading.Thread(target=reap, name="ray_tpu-chip-reaper", daemon=True)
+        self._chip_reapers = [r for r in self._chip_reapers if r.is_alive()] + [t]
+        t.start()
+
     def _kill_worker(self, w: WorkerHandle, err: Exception) -> None:
         try:
             w.process.terminate()
@@ -2719,6 +2824,12 @@ class Cluster:
             w.process.join(timeout=t)
             if w.process.is_alive():
                 w.process.terminate()
+        # the next cluster of this process must find every chip free
+        for w in workers:
+            if w.accel == "tpu":
+                _see_out(w.process)
+        for t in self._chip_reapers:
+            t.join()
         for a in agents:
             try:
                 a.conn.close()

@@ -88,8 +88,8 @@ class LLMConfig:
     prefill_chunk: Optional[int] = None
     # fused decode burst: run this many decode+sample iterations on-device per
     # host sync (lax.scan; vLLM multi-step scheduling). >1 amortizes the
-    # per-step host round trip — decisive over a network tunnel, a few percent
-    # on local chips — at the cost of K-token streaming granularity and up to
+    # per-step host round trip (about a millisecond on a local chip) at the
+    # cost of K-token streaming granularity and up to
     # K-1 wasted steps after a mid-burst EOS. None (the default) resolves
     # RAY_TPU_LLM_FUSED_STEPS, whose 0 default auto-tunes K from the measured
     # host round trip vs device step time — fused decode is the standard
@@ -121,7 +121,6 @@ class LLMConfig:
     pipeline_parallel_size: int = 1
     # serving
     tokenizer: str = "byte"  # "byte" | "hf:<name-or-path>"
-    accelerator_type: Optional[str] = None
     deployment_config: Dict[str, Any] = dataclasses.field(default_factory=dict)
     engine_kwargs: Dict[str, Any] = dataclasses.field(default_factory=dict)
 

@@ -309,6 +309,9 @@ class LLMServer:
     def metrics(self) -> Dict[str, Any]:
         return self.engine.metrics()
 
+    def device_report(self) -> Dict[str, Any]:
+        return self.engine.device_report()
+
     # scheduler-loop stall bound for check_health: generous enough for a cold
     # XLA compile of a big model's burst program, far below a wedged device
     ENGINE_STALL_S = 300.0
@@ -548,6 +551,23 @@ class PDRouter:
         return gen()
 
 
+def _replica_actor_options(cfg: LLMConfig) -> Optional[Dict[str, Any]]:
+    """ray_actor_options of an LLMServer replica. The caller's
+    deployment_config["ray_actor_options"] wins; otherwise, on a cluster that
+    advertises TPU chips, the replica asks for the pp×dp×ep×tp chips its engine
+    mesh spans — a replica that asks for none is a CPU worker and would serve
+    from the host. A cluster without chips (CPU test clusters) gets no request."""
+    opts = cfg.deployment_config.get("ray_actor_options")
+    if opts is not None:
+        return opts
+    import ray_tpu
+
+    if not ray_tpu.is_initialized() or ray_tpu.cluster_resources().get("TPU", 0) <= 0:
+        return None
+    return {"num_tpus": (cfg.pipeline_parallel_size * cfg.data_parallel_size
+                         * cfg.expert_parallel_size * cfg.tensor_parallel_size)}
+
+
 def build_pd_openai_app(llm_config: LLMConfig, *, num_prefill: int = 1,
                         num_decode: int = 1, name_prefix: str = "llm-pd",
                         max_prefill: Optional[int] = None,
@@ -586,10 +606,12 @@ def build_pd_openai_app(llm_config: LLMConfig, *, num_prefill: int = 1,
     prefill = serve.deployment(LLMServer).options(
         name=f"{name_prefix}:prefill", num_replicas=num_prefill,
         max_ongoing_requests=32,
+        ray_actor_options=_replica_actor_options(llm_config),
         autoscaling_config=prefill_autoscaling).bind(llm_config)
     decode = serve.deployment(LLMServer).options(
         name=f"{name_prefix}:decode", num_replicas=num_decode,
         max_ongoing_requests=64,
+        ray_actor_options=_replica_actor_options(llm_config),
         autoscaling_config=decode_autoscaling).bind(
             llm_config, prefill_handle=prefill)
     router = serve.deployment(PDRouter).options(name=f"{name_prefix}-router")
@@ -609,7 +631,7 @@ def build_openai_app(llm_configs: List[LLMConfig], name_prefix: str = "llm"):
             name=f"{name_prefix}:{cfg.model_id}",
             num_replicas=cfg.deployment_config.get("num_replicas", 1),
             max_ongoing_requests=cfg.deployment_config.get("max_ongoing_requests", 64),
-            ray_actor_options=cfg.deployment_config.get("ray_actor_options"),
+            ray_actor_options=_replica_actor_options(cfg),
         )
         servers[cfg.model_id] = d.bind(cfg)
     router = serve.deployment(OpenAIRouter).options(name=f"{name_prefix}-router")

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops import attention
 from ray_tpu.ops.quant import as_weight as _w
+from ray_tpu.parallel.sharding import auto_spec
 from ray_tpu.parallel.sharding import with_sharding_constraint as wsc
 
 from .config import ModelConfig
@@ -251,7 +252,8 @@ def _block(
                 q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl
             )
     else:
-        attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl)
+        attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
+                         shard_spec=auto_spec("batch", None, "act_heads", None))
     o = jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], dt))
     x = wsc(x + o, "batch", "seq", "act_embed")
 

@@ -8,6 +8,7 @@ Shapes (batch, seq, heads, head_dim) throughout — "BSHD".
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -40,15 +41,17 @@ def attention_reference(
     `q_offset`: kv index of query row 0 (decode-with-cache); default aligns the ends.
     `kv_valid_len`: kv slots >= this are masked out (padded cache tail).
     """
-    n_rep = q.shape[2] // k.shape[2]
-    k = _repeat_kv(k, n_rep)
-    v = _repeat_kv(v, n_rep)
-    d = q.shape[-1]
+    # GQA as a grouped contraction: q heads are viewed as [Hkv, n_rep] and K/V
+    # are never repeated. (Broadcasting K/V to H heads first is the same math,
+    # but at short sequences the v5e compiler overflows its stack costing the
+    # convolution it fuses that broadcast into — PR 22, on the chip.)
+    b, sq, h, d = q.shape
+    skv, hkv = k.shape[1], k.shape[2]
+    qg = q.reshape(b, sq, hkv, h // hkv, d)
     scale = scale if scale is not None else 1.0 / (d**0.5)
     # f32 logits regardless of input dtype: MXU accumulates in f32 on TPU anyway.
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
+    logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k, preferred_element_type=jnp.float32)
     logits = logits * scale
-    sq, skv = q.shape[1], k.shape[1]
     kj = jnp.arange(skv)[None, :]
     if causal:
         if q_offset is None:
@@ -60,11 +63,11 @@ def attention_reference(
     if segment_ids is not None:
         seg_q = segment_ids[:, -sq:]
         mask = seg_q[:, :, None] == segment_ids[:, None, :]
-        logits = jnp.where(mask[:, None, :, :], logits, -jnp.inf)
+        logits = jnp.where(mask[:, None, None, :, :], logits, -jnp.inf)
     # Rows with no valid kv (fully masked) softmax to NaN; zero them instead.
     probs = jnp.nan_to_num(jax.nn.softmax(logits, axis=-1))
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
-    return out.astype(q.dtype)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v.dtype), v)
+    return out.reshape(b, sq, h, d).astype(q.dtype)
 
 
 def attention_chunked(
@@ -169,23 +172,49 @@ def _chunked_min_logits() -> int:
 
     return CONFIG.chunked_attention_min_logits
 
-_logged_fallbacks: set = set()
+# TPU traces of a training shape that left the Pallas kernel for an XLA path
+# (chip_smoke.py asserts it stays 0)
+xla_fallback_count = 0
 
 
-def _log_fallback_once(q_shape, k_shape, impl: str) -> None:
-    """On-TPU shapes that miss the Pallas kernel get a one-time warning — the
-    perf cliff (Mosaic can't tile e.g. head_dim 64) should be visible, not silent."""
-    key = (tuple(q_shape), tuple(k_shape))
-    if key in _logged_fallbacks:
-        return
-    _logged_fallbacks.add(key)
+def _log_fallback(q_shape, k_shape, impl: str) -> None:
+    """A training shape on a TPU that misses the Pallas kernel is a perf cliff
+    (Mosaic can't tile e.g. head_dim 64): say so every time it is traced."""
     import logging
 
+    global xla_fallback_count
+    xla_fallback_count += 1
     logging.getLogger(__name__).warning(
         "attention: TPU shape q=%s kv=%s is not Mosaic-tileable "
         "(head_dim %% 128 or seq block alignment); using %s XLA path",
         tuple(q_shape), tuple(k_shape), impl,
     )
+
+
+def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec):
+    """The Pallas kernel under an ambient mesh. GSPMD cannot partition a Mosaic
+    kernel, and Mosaic refuses to lower while ANY mesh axis is still
+    automatic, so the kernel is called per shard with every such axis made
+    manual around it. `shard_spec` is the caller's layout of q/k/v over those
+    axes; axes an enclosing region already bound manually (a pipeline stage,
+    the bucketed grad sync) stay so. Without a spec, or with nothing left to
+    split, the kernel is called as it is."""
+    from jax.sharding import PartitionSpec as P
+
+    from .flash_attention import flash_attention
+
+    kernel = functools.partial(flash_attention, causal=causal, scale=scale)
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = set(mesh.axis_names) - set(mesh.manual_axes)
+    if shard_spec is None or not any(mesh.shape[a] > 1 for a in auto):
+        return kernel(q, k, v, segment_ids=segment_ids)
+    args, specs = (q, k, v), (shard_spec,) * 3
+    if segment_ids is not None:
+        args, specs = args + (segment_ids,), specs + (P(shard_spec[0], None),)
+    return jax.shard_map(
+        lambda q, k, v, seg=None: kernel(q, k, v, segment_ids=seg),
+        in_specs=specs, out_specs=shard_spec, axis_names=auto,
+        check_vma=False)(*args)
 
 
 def attention(
@@ -199,11 +228,17 @@ def attention(
     q_offset: Optional[jax.Array] = None,
     kv_valid_len: Optional[jax.Array] = None,
     impl: str = "auto",
+    shard_spec=None,
 ) -> jax.Array:
     """Dispatching attention. impl: auto|pallas|chunked|reference.
 
     The Pallas path currently covers the training shape (no cache offsets, optional
     segment ids); decode-with-cache shapes use the XLA path, which fuses well anyway.
+
+    shard_spec: how the caller lays q/k/v ([batch, seq, heads, head_dim]) out
+    over the ambient mesh, as a PartitionSpec whose seq entry is None (each
+    shard a whole attention problem). Only the Pallas path needs it, to run
+    the kernel per shard; the XLA paths are partitioned by GSPMD.
     """
     if impl == "auto":
         on_tpu = jax.default_backend() not in ("cpu", "gpu")
@@ -237,11 +272,10 @@ def attention(
         if (impl != "pallas" and on_tpu and not tileable
                 and q_offset is None and kv_valid_len is None
                 and (same_len or not causal)):
-            _log_fallback_once(q.shape, k.shape, impl)
+            _log_fallback(q.shape, k.shape, impl)
     if impl == "pallas":
-        from .flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal=causal, segment_ids=segment_ids, scale=scale)
+        return _flash_per_shard(q, k, v, causal=causal, segment_ids=segment_ids,
+                                scale=scale, shard_spec=shard_spec)
     if impl == "chunked":
         return attention_chunked(
             q,

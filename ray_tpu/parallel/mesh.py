@@ -87,12 +87,5 @@ def local_mesh(**axes: int) -> Mesh:
     return build_mesh(MeshSpec(**axes))
 
 
-def use_mesh(mesh: Mesh):
-    """Context manager installing `mesh` as the ambient mesh (jax version compat)."""
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    # jax<=0.4.x: Mesh is itself the context manager (thread-local physical
-    # mesh env; sharding.py's ambient-mesh probe reads it back)
-    return mesh
+# this package's name for installing `mesh` as the ambient mesh
+use_mesh = jax.sharding.set_mesh

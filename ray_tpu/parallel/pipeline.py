@@ -170,14 +170,12 @@ def pipeline(
             return out
 
     param_specs = jax.tree_util.tree_map(lambda _: P(axis_name), stacked_params)
-    from .sharding import compat_shard_map
-
-    mapped = compat_shard_map(
+    mapped = jax.shard_map(
         inner,
-        mesh,
-        (param_specs, mb_spec, side_specs),
-        (mb_spec, P()) if with_aux else mb_spec,
-        manual,
+        mesh=mesh,
+        in_specs=(param_specs, mb_spec, side_specs),
+        out_specs=(mb_spec, P()) if with_aux else mb_spec,
+        axis_names=set(manual),
     )
     if with_aux:
         y_mb, aux = mapped(stacked_params, x_mb, side_mb)

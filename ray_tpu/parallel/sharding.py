@@ -102,70 +102,64 @@ def shard_pytree(tree, axes_tree, mesh: Mesh, rules: AxisRules = TRAIN_RULES):
     """
 
     def _put(x, axes):
-        return jax.device_put(x, named_sharding(mesh, *axes, rules=rules))
+        return jax.device_put(x, _as_jit_returns(named_sharding(mesh, *axes, rules=rules)))
 
     return jax.tree.map(_put, tree, axes_tree, is_leaf=lambda x: x is None)
+
+
+def _as_jit_returns(sharding: NamedSharding) -> NamedSharding:
+    """The same sharding, spelled as jit spells the shardings of a program's
+    outputs: no mesh axis of size one, no trailing None. jit keys its compiled
+    programs by the spelling, so a state placed any other way compiles its
+    step twice: once for the state as placed, once for the state the first
+    step returned."""
+    sizes = sharding.mesh.shape
+
+    def keep(entry):
+        if entry is None or isinstance(entry, str):
+            return entry if entry is not None and sizes[entry] > 1 else None
+        kept = tuple(a for a in entry if sizes[a] > 1)
+        return kept or None
+
+    spec = [keep(e) for e in sharding.spec]
+    while spec and spec[-1] is None:
+        spec.pop()
+    return NamedSharding(sharding.mesh, P(*spec), memory_kind=sharding.memory_kind)
 
 
 _MANUAL_AXES: "contextvars.ContextVar[frozenset]" = None  # initialized below
 
 
 def ambient_mesh():
-    """The mesh in scope, across jax versions: the abstract mesh
-    (use_mesh/set_mesh on jax>=0.5) or the entered physical mesh
-    (`with mesh:` on jax<=0.4.x). None when no mesh is active."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    # graftlint: allow[swallowed-exception] degrades to the coded fallback (return None) by design
-    except Exception:
-        return None
-    try:
-        from jax._src.mesh import thread_resources
+    """The abstract mesh installed by `use_mesh`; None when no mesh is active."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
-        mesh = thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    # graftlint: allow[swallowed-exception] jax-version probe: missing thread_resources means no ambient mesh
-    except Exception:
-        pass
-    return None
+
+def auto_spec(*logical_axes: LogicalAxis, rules: AxisRules = TRAIN_RULES) -> Optional[P]:
+    """`logical_axes` as a PartitionSpec over the ambient mesh, keeping only
+    the mesh axes it has that are still automatic; None without a mesh.
+
+    Mesh axes currently bound manually (inside a shard_map region entered via
+    `manual_axes()`) are dropped — GSPMD may only constrain auto axes, and a
+    nested shard_map may only split over them."""
+    mesh = ambient_mesh()
+    if mesh is None:
+        return None
+    manual = active_manual_axes() | frozenset(mesh.manual_axes)
+
+    def _filt(entry):
+        names = entry if isinstance(entry, tuple) else (entry,) if entry else ()
+        kept = tuple(a for a in names if a in mesh.shape and a not in manual)
+        return None if not kept else kept if isinstance(entry, tuple) else kept[0]
+
+    return P(*(_filt(e) for e in rules.spec(logical_axes)))
 
 
 def with_sharding_constraint(x, *logical_axes: LogicalAxis, rules: AxisRules = TRAIN_RULES):
-    """In-jit sharding hint using logical names. No-op outside jit or without a mesh.
-
-    Mesh axes currently bound manually (inside a shard_map region entered via
-    `manual_axes()`) are dropped from the constraint — GSPMD may only constrain auto axes.
-    """
-    mesh = ambient_mesh()
-    if mesh is None:
-        return x
-    spec = rules.spec(logical_axes)
-    manual = active_manual_axes()
-    if manual:
-        if isinstance(mesh, Mesh):
-            # jax<=0.4.x: constraining auto axes from inside a partial-manual
-            # shard_map region trips the partitioner's IsManualSubgroup check —
-            # skip the hint entirely (it is an optimization, not semantics).
-            return x
-
-        def _filt(entry):
-            if entry is None:
-                return None
-            if isinstance(entry, tuple):
-                kept = tuple(a for a in entry if a not in manual)
-                return kept if kept else None
-            return None if entry in manual else entry
-
-        spec = P(*(_filt(e) for e in spec))
-    if isinstance(mesh, Mesh):
-        # concrete (physical) mesh: the constraint needs a full NamedSharding
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    return jax.lax.with_sharding_constraint(x, spec)
+    """In-jit sharding hint using logical names. No-op outside jit or without a mesh."""
+    spec = auto_spec(*logical_axes, rules=rules)
+    return x if spec is None else jax.lax.with_sharding_constraint(x, spec)
 
 
 # -- manual-axes context ---------------------------------------------------------------
@@ -196,51 +190,12 @@ def vary_like(z, ref=None, *, extra: Sequence[str] = ()):
 
     The shard_map vma type system requires loop carries/inits to match the body's
     varying-axes set; this is the one shared implementation of the
-    pcast/pvary-to-varying idiom (jax moved pvary -> pcast(..., to="varying")
-    across versions, hence the feature probe). ref=None means "just `extra`".
+    pcast-to-varying idiom. ref=None means "just `extra`".
     """
     want = set(extra)
     if ref is not None:
-        try:
-            want |= set(jax.typeof(ref).vma)
-        # graftlint: allow[swallowed-exception] jax-version probe: typeof/vma absent on older jax
-        except Exception:
-            pass
-    try:
-        have = set(jax.typeof(z).vma)
-    # graftlint: allow[swallowed-exception] degrades to the coded fallback (have = set()) by design
-    except Exception:
-        have = set()
-    need = tuple(want - have)
+        want |= set(jax.typeof(ref).vma)
+    need = tuple(want - set(jax.typeof(z).vma))
     if not need:
         return z
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(z, need, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(z, need)
-    return z  # pre-vma jax: shard_map has no varying-axes type system to satisfy
-
-
-def compat_shard_map(f, mesh: Mesh, in_specs, out_specs, manual: Sequence[str]):
-    """shard_map with the given axes manual and the rest in GSPMD auto mode,
-    across jax versions (jax.shard_map axis_names= vs experimental auto=).
-    One shared implementation for grad_sync's bucketed region, the in-program
-    pipeline combinator, and the MPMD stage runner's stage_dp sharding."""
-    manual = frozenset(manual)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual))
-    from jax.experimental.shard_map import shard_map as _sm
-
-    auto = frozenset(mesh.axis_names) - manual
-    bad = [a for a in sorted(auto) if mesh.shape[a] > 1]
-    if bad:
-        # jaxlib<=0.4.x partial-auto shard_map hard-crashes XLA
-        # (IsManualSubgroup check) when a non-trivial auto axis crosses the
-        # region — refuse with a python error instead.
-        raise NotImplementedError(
-            f"shard_map over manual axes {sorted(manual)} with non-trivial "
-            f"auto axes {bad} needs jax.shard_map (jax>=0.5); this jax only "
-            "supports fully-manual meshes here")
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               auto=auto, check_rep=False)
+    return jax.lax.pcast(z, need, to="varying")

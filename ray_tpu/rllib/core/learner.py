@@ -36,8 +36,8 @@ class Learner(CollectiveActorMixin):
 
         # params/opt_state stay DEVICE-RESIDENT between updates: fetching them
         # to host every update() (and re-uploading every minibatch) costs more
-        # than the update itself on real accelerators — brutal via a network
-        # tunnel. get_weights/get_state materialize numpy on demand.
+        # than the update itself on real accelerators.
+        # get_weights/get_state materialize numpy on demand.
         self.params = self.module.init_params(seed=self.config.seed or 0)
         clip = self.config.grad_clip
         tx = [optax.clip_by_global_norm(clip)] if clip else []

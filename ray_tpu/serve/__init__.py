@@ -7,6 +7,7 @@ machine + rolling updates (deployment_state.py), power-of-two-choices handle rou
 dynamic batching (batching.py), request-rate autoscaling (autoscaling_policy.py).
 """
 from .api import (  # noqa: F401
+    DeploymentStartError,
     delete,
     get_app_handle,
     get_deployment_handle,
@@ -36,6 +37,7 @@ __all__ = [
     "get_deployment_handle",
     "DeploymentHandle",
     "DeploymentResponse",
+    "DeploymentStartError",
     "AutoscalingConfig",
     "DeploymentConfig",
     "batch",

@@ -7,6 +7,7 @@ graphs, proxy bring-up, handle acquisition.
 from __future__ import annotations
 
 import dataclasses
+import logging
 from typing import Any, Dict, Optional
 
 import ray_tpu
@@ -14,6 +15,8 @@ import ray_tpu
 from .controller import CONTROLLER_NAME, ServeController
 from .deployment import Application, Deployment
 from .handle import DeploymentHandle
+
+logger = logging.getLogger(__name__)
 
 _PROXY_NAME = "SERVE_PROXY"
 
@@ -119,19 +122,47 @@ def run(
             "is_ingress": bound is target,
         })
     ray_tpu.get(controller.deploy_application.remote(name, route_prefix, payload))
-    handle = DeploymentHandle(name, target.deployment.name)
-    # wait until the ingress deployment has at least one running replica
+    _wait_until_running(controller, name)
+    return DeploymentHandle(name, target.deployment.name)
+
+
+class DeploymentStartError(RuntimeError):
+    """A deployment's replicas kept failing their start; `serve.run` gave up."""
+
+
+# failed replica starts in a row after which serve.run gives a deployment up
+_MAX_START_FAILURES = 3
+
+
+def _wait_until_running(controller, name: str) -> None:
+    """Block until every deployment of app `name` has a running replica, so
+    that a request sent the moment `run` returns finds one (reference:
+    serve.run waits for the application to be RUNNING). A replica that is
+    still starting is waited for as long as it takes, as the reference does —
+    an LLM replica makes or loads its weights for tens of seconds — and named
+    in the log meanwhile; a deployment whose replicas fail their start
+    `_MAX_START_FAILURES` times in a row raises."""
     import time
 
-    deadline = time.time() + 60
-    while time.time() < deadline:
-        info = ray_tpu.get(controller.get_deployment_info.remote(name, target.deployment.name))
-        if info and info["num_running"] >= 1:
-            break
+    started = said = time.monotonic()
+    while True:
+        deployments = ray_tpu.get(controller.status.remote())[name]["deployments"]
+        waiting = {d: info for d, info in deployments.items()
+                   if info["num_running"] < 1}
+        if not waiting:
+            return
+        for d, info in waiting.items():
+            if info["start_failures"] >= _MAX_START_FAILURES:
+                raise DeploymentStartError(
+                    f"app {name!r}: deployment {d!r} failed to start "
+                    f"{info['start_failures']} times in a row; last error: "
+                    f"{info['start_error']}")
+        now = time.monotonic()
+        if now - said >= 30.0:
+            said = now
+            logger.info("serve.run(%r): %.0f s, still starting: %s", name, now - started,
+                        {d: info["states"] for d, info in waiting.items()})
         time.sleep(0.1)
-    else:
-        raise TimeoutError(f"app {name!r} failed to reach RUNNING within 60s: {info}")
-    return handle
 
 
 def delete(name: str, _blocking: bool = True) -> None:

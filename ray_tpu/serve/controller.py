@@ -71,6 +71,9 @@ class _DeploymentState:
         self.autoscale_metric: float = 0.0
         self._last_scale_change = 0.0
         self.deleting = False  # drain-down in progress; reap when empty
+        # replica starts that failed in a row, and why (serve.run gives up on these)
+        self.start_failures = 0
+        self.start_error: Optional[str] = None
 
     def running(self) -> List[_ReplicaState]:
         return [r for r in self.replicas if r.state == RUNNING]
@@ -176,6 +179,9 @@ class ServeController:
                     # re-deploy racing a drain-down: resurrect as a rolling
                     # update (old draining replicas finish; fresh ones start)
                     existing.deleting = False
+                if existing is not None:
+                    # a new deploy is a new attempt
+                    existing.start_failures, existing.start_error = 0, None
                 if existing is not None and existing.info["config"].version != d["config"].version:
                     # version change -> rolling update: old replicas DRAIN
                     # (finish in-flight work) while replacements start
@@ -316,6 +322,8 @@ class ServeController:
                 "target_num_replicas": ds.target_num,
                 "num_running": len(ds.running()),
                 "states": [r.state for r in ds.replicas],
+                "start_failures": ds.start_failures,
+                "start_error": ds.start_error,
             }
 
     def get_deployment_limits(self, app_name: str,
@@ -621,6 +629,7 @@ class ServeController:
                                 r.state = RUNNING
                                 r.last_health_ok = now
                                 r.health_ref = None
+                                ds.start_failures, ds.start_error = 0, None
                             except Exception as e:
                                 if _is_head_unavailable(e):
                                     # control-plane outage, not replica death:
@@ -635,6 +644,8 @@ class ServeController:
                                         "check (%r); replacing it", ds.name, r.uid, e)
                                     r.state = STOPPING
                                     r.health_ref = None
+                                    ds.start_failures += 1
+                                    ds.start_error = repr(e)
                 # periodic health checks on RUNNING replicas
                 period = ds.info["config"].health_check_period_s
                 for r in ds.replicas:

@@ -1,7 +1,7 @@
 """host-sync-in-hot-path: device->host round trips inside registered hot paths.
 
-The single biggest perf bug in this repo's history — the ~110 ms host round
-trip that capped engine decode at 55.8 tok/s until PR 12 — was a host sync
+The single biggest perf bug in this repo's history — a host round trip per
+decode step that capped engine decode until PR 12 — was a host sync
 on the scheduler hot path that no review caught. Hot functions are now
 registered explicitly with `@hot_path` (ray_tpu/util/hot_path.py, a runtime
 no-op), and this check walks them PLUS their one-level same-file callees for

@@ -52,10 +52,6 @@ Semantics notes:
   "auto" mode inside the manual region, so it composes with the fsdp param
   sharding. It does not compose with model code that opens its own shard_map
   (pipeline_stages > 1, ring/ulysses attention) — `make_step` rejects those.
-- jax <= 0.4.x ships a partial-auto shard_map that miscompiles when a
-  NON-TRIVIAL auto axis (size > 1) crosses the manual region; `_shard_map`
-  raises a clear error there instead of letting XLA hard-crash. Pure-dp meshes
-  work on every supported jax; dp x fsdp needs the newer shard_map.
 """
 from __future__ import annotations
 
@@ -224,8 +220,7 @@ def sync_payload_bytes(tree: Any, sync: GradSyncConfig) -> Dict[str, int]:
 # ------------------------------------------------------------- mesh compat
 
 def _mesh_of(tree: Any) -> Optional[Mesh]:
-    """Concrete mesh from any NamedSharding-carrying leaf, else the ambient
-    (version-compat probe shared with parallel/sharding.py)."""
+    """Concrete mesh from any NamedSharding-carrying leaf, else the ambient."""
     for leaf in jax.tree_util.tree_leaves(tree):
         s = getattr(leaf, "sharding", None)
         if isinstance(s, NamedSharding):
@@ -235,26 +230,23 @@ def _mesh_of(tree: Any) -> Optional[Mesh]:
     return ambient_mesh()
 
 
-def _shard_map(f, mesh: Mesh, in_specs, out_specs, manual: Sequence[str]):
-    """shard_map with the given axes manual and the rest in GSPMD auto mode,
-    across jax versions — shared impl in parallel/sharding.compat_shard_map."""
-    from ray_tpu.parallel.sharding import compat_shard_map
-
-    return compat_shard_map(f, mesh, in_specs, out_specs, manual)
-
-
 # ----------------------------------------------------- in-jit sync kernels
 
 def _quantized_pmean(leaf: jax.Array, axis: str, sync: GradSyncConfig,
                      key: Optional[jax.Array]) -> jax.Array:
     """int8 block-quantized mean-reduce over `axis` (inside a manual region):
     quantize local contribution -> all-gather int8+scales -> dequant-sum."""
+    # the gathered blocks are the same on every member, and shard_map's
+    # replication check has to know it: the public all_gather types its
+    # result as varying over `axis`
+    from jax._src.lax.parallel import all_gather_invariant
+
     from ray_tpu.ops.quant import quantize_blockwise
 
     n = int(np.prod(leaf.shape or (1,)))
     q, scales = quantize_blockwise(leaf, sync.quant_block_elems, key=key)
-    qg = jax.lax.all_gather(q, axis)          # [W, nblocks, block] int8
-    sg = jax.lax.all_gather(scales, axis)     # [W, nblocks, 1] f32
+    qg = all_gather_invariant(q, axis)        # [W, nblocks, block] int8
+    sg = all_gather_invariant(scales, axis)   # [W, nblocks, 1] f32
     w = jax.lax.psum(1, axis)
     total = jnp.sum(qg.astype(jnp.float32) * sg, axis=0)
     return (total.reshape(-1)[:n] / w).reshape(leaf.shape).astype(leaf.dtype)
@@ -550,11 +542,11 @@ class GradSyncStep:
         bspec = jax.tree_util.tree_map(lambda _: P(sync.axis), batch)
         aux_spec = jax.tree_util.tree_map(lambda _: P(), aux_shape)
         gspec = jax.tree_util.tree_map(lambda _: P(), grads_shape)
-        return _shard_map(
-            body, mesh,
+        return jax.shard_map(
+            body, mesh=mesh,
             in_specs=(pspec, bspec, P()),
             out_specs=(P(), aux_spec, gspec),
-            manual=(sync.axis,))
+            axis_names={sync.axis})
 
     # -- public surface
     @hot_path

@@ -163,6 +163,13 @@ class JaxBackend(Backend):
                     "retrying once on fresh port %d", e, port)
                 _rendezvous(port)
 
+        # after the rendezvous (jax.distributed must come before the backend):
+        # a worker that was given chips and came up without them fails here,
+        # not by training on the host in silence
+        from ray_tpu.core.accelerators import check_worker_platform
+
+        worker_group.execute(check_worker_platform)
+
         if backend_config.collective_group:
             from ray_tpu.util import collective as col
             from ray_tpu.util import telemetry

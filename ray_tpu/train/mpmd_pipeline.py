@@ -578,9 +578,9 @@ class StageRunner:
         def scale(tree, s):
             return jax.tree_util.tree_map(lambda a: a * s, tree)
 
-        self._fwd = jax.jit(grad_sync._shard_map(
-            stage_fn, mesh, in_specs=(P(), P("dp")), out_specs=P("dp"),
-            manual=("dp",)))
+        self._fwd = jax.jit(jax.shard_map(
+            stage_fn, mesh=mesh, in_specs=(P(), P("dp")), out_specs=P("dp"),
+            axis_names={"dp"}))
         if self.is_last:
             def bwd_last(p, x, ct):
                 def head(p_, x_):
@@ -592,9 +592,9 @@ class StageRunner:
                 gp = scale(grad_sync._sync_bucketed(gp, "dp", sync, None), dp)
                 return jax.lax.pmean(loss, "dp"), gp, gx
 
-            self._bwd = jax.jit(grad_sync._shard_map(
-                bwd_last, mesh, in_specs=(P(), P("dp"), P()),
-                out_specs=(P(), P(), P("dp")), manual=("dp",)))
+            self._bwd = jax.jit(jax.shard_map(
+                bwd_last, mesh=mesh, in_specs=(P(), P("dp"), P()),
+                out_specs=(P(), P(), P("dp")), axis_names={"dp"}))
         else:
             def bwd(p, x, gy):
                 _, vjp = jax.vjp(stage_fn, p, x)
@@ -602,9 +602,9 @@ class StageRunner:
                 gp = scale(grad_sync._sync_bucketed(gp, "dp", sync, None), dp)
                 return gp, gx
 
-            self._bwd = jax.jit(grad_sync._shard_map(
-                bwd, mesh, in_specs=(P(), P("dp"), P("dp")),
-                out_specs=(P(), P("dp")), manual=("dp",)))
+            self._bwd = jax.jit(jax.shard_map(
+                bwd, mesh=mesh, in_specs=(P(), P("dp"), P("dp")),
+                out_specs=(P(), P("dp")), axis_names={"dp"}))
 
     # -- schedule execution ----------------------------------------------------------
     def _prefetch_ahead(self, step: int, idx: int) -> None:

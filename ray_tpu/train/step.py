@@ -82,14 +82,23 @@ def init_state(
             params = shard_pytree(params, llama.param_axes(cfg), mesh, rules)
     sync = sync or grad_sync.GradSyncConfig.from_env()
     if mesh is not None:
+        # Every optimizer leaf that mirrors a parameter takes that parameter's
+        # sharding, said out loud: the moments are zeros with no data
+        # dependence on the parameters, and left to itself XLA replicates them
+        # (f32 Adam state stacked whole on every device; PR 22, four chips).
+        opt_shardings = optax.tree_map_params(
+            tx, lambda _, p: p.sharding, jax.eval_shape(tx.init, params), params,
+            transform_non_params=lambda _: named_sharding(mesh))
         with use_mesh(mesh):
-            opt_state = jax.jit(tx.init)(params)
+            opt_state = jax.jit(tx.init, out_shardings=opt_shardings)(params)
             if sync.sharded_update:
                 opt_state = grad_sync.shard_opt_state(
                     tx, params, opt_state, sync, mesh)
+        step = jax.device_put(jnp.zeros((), jnp.int32), named_sharding(mesh))
     else:
         opt_state = tx.init(params)
-    return TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=opt_state)
+        step = jnp.zeros((), jnp.int32)
+    return TrainState(step=step, params=params, opt_state=opt_state)
 
 
 def make_train_step(

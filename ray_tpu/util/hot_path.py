@@ -8,7 +8,7 @@ enforces statically (`ray-tpu lint`):
   step, fused decode emit, grad-sync stage, ring-collective wait). The
   host-sync-in-hot-path check walks it plus its one-level same-file callees
   and flags device->host syncs (`.item()`, `np.asarray`, `float()` on
-  arrays, `block_until_ready`) — the defect class behind the 110 ms decode
+  arrays, `block_until_ready`) — the defect class behind the per-step decode
   round trip PR 12 had to dig out. A DESIGNED sync point (the one fetch per
   K-step burst) stays, with an inline
   ``# graftlint: allow[host-sync-in-hot-path] <why>``.

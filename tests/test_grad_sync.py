@@ -203,9 +203,12 @@ def test_bucketed_reductions_not_sunk_to_end(env):
                                sync=GradSyncConfig(mode="bucketed",
                                                    bucket_bytes=64 << 10))
     with use_mesh(env["mesh"]):
-        rep = grad_sync.overlap_report(
-            step.lower(env["state"], env["batch"]).compile())
-    assert rep["n_reductions"] >= len(step.buckets)
+        lowered = step.lower(env["state"], env["batch"])
+        rep = grad_sync.overlap_report(lowered.compile())
+    # the program asks for one reduction per bucket; XLA's combiner may merge
+    # neighbours afterwards, so count before it runs and check placement after
+    assert lowered.as_text().count("stablehlo.all_reduce") >= len(step.buckets)
+    assert rep["n_reductions"] >= 1
     assert not rep["all_sunk_to_end"]
     assert rep["n_compute_after_first_reduction"] > 0
 

@@ -3,6 +3,7 @@ the plain single-config forward bit-for-bit-ish (f32 tolerances)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ray_tpu.models import llama
 from ray_tpu.models.config import get_config
@@ -171,3 +172,25 @@ def test_pp_positions_honored():
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
     # the offset genuinely changes the result (otherwise this test proves nothing)
     assert np.abs(ref - base).max() > 1e-3
+
+
+@pytest.mark.parametrize("axes", [dict(dp=2, fsdp=2), dict(fsdp=2, tp=2), dict(dp=1)])
+def test_step_compiles_once_for_a_state_placed_on_a_mesh(axes):
+    """The state init_state places and the state a step returns are keyed
+    alike by jit: the second step must not compile again (on the chip that
+    was 16 s at step 2 of a dp=2 x fsdp=2 run)."""
+    from ray_tpu.parallel import MeshSpec, build_mesh
+    from ray_tpu.parallel.sharding import named_sharding
+
+    n = int(np.prod(list(axes.values())))
+    mesh = build_mesh(MeshSpec(**axes), jax.devices()[:n])
+    cfg = get_config("test-tiny", dtype="float32")
+    tx = make_optimizer(total_steps=10)
+    state = init_state(jax.random.PRNGKey(0), cfg, tx, mesh=mesh)
+    step = make_train_step(cfg, tx)
+    tokens = np.asarray(jax.random.randint(jax.random.PRNGKey(7), (4, 33), 0, 256))
+    with use_mesh(mesh):
+        batch = {"tokens": jax.device_put(tokens, named_sharding(mesh, "batch", None))}
+        for _ in range(3):
+            state, _ = step(state, batch)
+    assert step._cache_size() == 1

@@ -194,12 +194,12 @@ def test_cross_process_runner_bit_exact_vs_pipeline_spmd(rt):
     mesh = Mesh(np.array(jax.devices()[:pp]), ("pp",))
 
     def ref_loss(params, xx):
-        with use_mesh(mesh):
-            y = pipeline(_stage_fn, params, xx, num_microbatches=m, mesh=mesh)
+        y = pipeline(_stage_fn, params, xx, num_microbatches=m, mesh=mesh)
         y_mb = y.reshape(m, mb, d)
         return jnp.mean(jnp.stack([_mb_loss(y_mb[i]) for i in range(m)]))
 
-    _, g_ref = jax.jit(jax.value_and_grad(ref_loss))(stacked, x)
+    with use_mesh(mesh):  # the ambient mesh is set outside jit, not while tracing
+        _, g_ref = jax.jit(jax.value_and_grad(ref_loss))(stacked, x)
     # loss reference: pipeline_spmd's outputs reduced by the SAME standalone
     # per-microbatch program shape the runner compiles — fusing the reduction
     # into the big traced program instead lets XLA round the mean differently

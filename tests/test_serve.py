@@ -65,6 +65,50 @@ def test_composed_deployments(rt):
     assert handle.remote(4).result() == 70
 
 
+def test_run_waits_for_a_slow_child_deployment(rt, monkeypatch):
+    """serve.run returns when EVERY deployment has a running replica, not when
+    the ingress has: a model replica behind an ingress takes far longer to
+    start than a handle is willing to wait (an LLM replica at llama3-8b
+    widths: 45 s against serve_replica_wait_s)."""
+    from ray_tpu.config import CONFIG
+
+    @serve.deployment
+    class Slow:
+        def __init__(self):
+            time.sleep(3.0)
+
+        def __call__(self, x):
+            return x + 1
+
+    @serve.deployment
+    class Front:
+        def __init__(self, child):
+            self.child = child
+
+        def __call__(self, x):
+            return self.child.remote(x).result()
+
+    monkeypatch.setattr(CONFIG, "serve_replica_wait_s", 0.5)
+    handle = serve.run(Front.bind(Slow.bind()), name="slowchild")
+    assert serve.status()["slowchild"]["deployments"]["Slow"]["num_running"] == 1
+    assert serve.get_deployment_handle("Slow", "slowchild").remote(1).result() == 2
+    assert handle.remote(1).result() == 2
+
+
+def test_run_raises_when_a_deployment_cannot_start(rt):
+    @serve.deployment
+    class Broken:
+        def __init__(self):
+            raise ValueError("no weights here")
+
+        def __call__(self, x):
+            return x
+
+    with pytest.raises(serve.DeploymentStartError, match="no weights here"):
+        serve.run(Broken.bind(), name="broken")
+    serve.delete("broken")
+
+
 def test_method_call_and_user_config(rt):
     @serve.deployment(user_config={"threshold": 5})
     class Svc:

@@ -1,0 +1,217 @@
+"""The main path's kernels and serving programs compile for a TPU v5e.
+
+Nothing runs: the TPU compiler is installed here and compiles for a chip that
+is described, not attached. What it refuses here (a slice not aligned to the
+tiling, too much fast memory, a program that does not fit 16 GB) it would
+refuse on the chip, so these cases guard every later PR at no chip time.
+
+The topology is described inside the module-scoped `topo` fixture and nowhere
+else: only one process may load the TPU's library, and xdist workers all
+import this file. Keep every such test in THIS file, and compile in the test's
+own process (no children).
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """Steer the code's own backend probes to their TPU side: they ask the
+    attached backend, which here is the CPU."""
+    from ray_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shapes(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
+
+
+@pytest.mark.parametrize("b,s,h,kv,segments", [
+    (6, 2048, 32, 8, False),   # llama8b-geom2, the smoke's train batch
+    (8, 2048, 12, 6, False),   # llama-500m
+    (2, 4096, 32, 8, True),    # packed documents
+])
+def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    d = 128
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip) if segments else None
+
+    def loss(q, k, v, seg):
+        return jnp.sum(flash_attention(q, k, v, causal=True, segment_ids=seg)
+                       .astype(jnp.float32))
+
+    fwd = jax.jit(lambda q, k, v, seg: flash_attention(
+        q, k, v, causal=True, segment_ids=seg)).lower(q, k, k, seg).compile()
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, seg).compile()
+    assert "tpu_custom_call" in fwd.as_text()
+    assert bwd.as_text().count("tpu_custom_call") >= 2  # dQ and dK/dV kernels
+
+
+def test_train_step_compiles_for_four_chips(topo, on_tpu):
+    """make_train_step on llama8b-geom2 under dp=2 x fsdp=2, the four-chip
+    smoke's shape: the kernel is in the program (GSPMD cannot partition a
+    Mosaic kernel; ops/attention.py calls it per shard), and parameters and
+    optimizer state are split over fsdp, not stacked whole on every device."""
+    import optax
+
+    from ray_tpu.models import get_config, llama
+    from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
+    from ray_tpu.parallel.sharding import named_sharding
+    from ray_tpu.train import make_optimizer, make_train_step
+    from ray_tpu.train.step import TrainState
+
+    cfg = dataclasses.replace(get_config("llama8b-geom2"), remat_policy="dots")
+    mesh = build_mesh(MeshSpec(dp=2, fsdp=2), topo.devices)
+    params = jax.tree.map(
+        lambda x, axes: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=named_sharding(mesh, *axes)),
+        jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)),
+        llama.param_axes(cfg), is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+    tx = make_optimizer()
+    replicated = named_sharding(mesh)
+    opt_state = optax.tree_map_params(
+        tx, lambda x, p: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=p.sharding),
+        jax.eval_shape(tx.init, params), params,
+        transform_non_params=lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=replicated))
+    state = TrainState(step=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
+                       params=params, opt_state=opt_state)
+    batch = {"tokens": jax.ShapeDtypeStruct(
+        (4, 2049), jnp.int32, sharding=named_sharding(mesh, "batch", None))}
+    with use_mesh(mesh):
+        compiled = make_train_step(cfg, tx).lower(state, batch).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3  # forward, dQ, dK/dV
+    # 3 x f32 x 698M parameters (params, mu, nu) is 8.4 GB on one device
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.55 * 8.4e9
+
+
+@pytest.fixture(scope="module")
+def serving(one_chip):
+    """llama3-8b widths, full vocabulary, bf16 weights, at the depth and KV
+    geometry chip_smoke.py's serve phase runs (shapes only)."""
+    import chip_smoke
+    from ray_tpu.models import get_config, llama
+
+    slots, max_len = 8, 2048
+    depth = chip_smoke.serve_depth(slots, max_len, "llama3-8b")["depth"]
+    cfg = get_config("llama3-8b", n_layers=depth)
+    params = jax.eval_shape(lambda: jax.tree.map(
+        lambda x: x.astype(jnp.bfloat16), llama.init(jax.random.PRNGKey(0), cfg)))
+    return dict(cfg=cfg, slots=slots, max_len=max_len, block=16,
+                params=_shapes(params, one_chip))
+
+
+def _scalar(sharding, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct((), dtype, sharding=sharding)
+
+
+def _vec(n, sharding, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct((n,), dtype, sharding=sharding)
+
+
+def _lower_slot(sv, sh, program):
+    from ray_tpu.llm import model_runner
+
+    cfg, slots = sv["cfg"], sv["slots"]
+    kv = jax.ShapeDtypeStruct(
+        (cfg.n_layers, slots, sv["max_len"], cfg.n_kv_heads, cfg.head_dim),
+        jnp.bfloat16, sharding=sh)
+    state = model_runner.DecodeState(k=kv, v=kv, lengths=_vec(slots, sh))
+    if program == "prefill":
+        tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=sh)
+        return model_runner.prefill.lower(
+            sv["params"], state, tokens, _scalar(sh), _scalar(sh), cfg)
+    return model_runner.decode_step.lower(
+        sv["params"], state, _vec(slots, sh), _vec(slots, sh, jnp.bool_), cfg)
+
+
+def _lower_paged(sv, sh, program):
+    from ray_tpu.llm import model_runner, paged
+
+    cfg, slots, block = sv["cfg"], sv["slots"], sv["block"]
+    n_blocks = slots * sv["max_len"] // block
+    if program == "prefill":
+        tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=sh)
+        return model_runner.prefill_detached.lower(
+            sv["params"], tokens, _scalar(sh), cfg)
+    pool = jax.ShapeDtypeStruct(
+        (cfg.n_layers, n_blocks + 1, block, cfg.n_kv_heads, cfg.head_dim),
+        jnp.bfloat16, sharding=sh)
+    state = paged.PagedState(
+        k=pool, v=pool, lengths=_vec(slots, sh),
+        block_tables=jax.ShapeDtypeStruct(
+            (slots, sv["max_len"] // block), jnp.int32, sharding=sh))
+    if program == "install":
+        kv = jax.ShapeDtypeStruct((cfg.n_layers, 1, 64, cfg.n_kv_heads, cfg.head_dim),
+                                  jnp.bfloat16, sharding=sh)
+        return paged.install_prefill.lower(
+            state, kv, kv, _vec(64 // block, sh), _scalar(sh), _scalar(sh),
+            n_blocks=64 // block)
+    k_steps = 4  # one fused decode+sample burst
+    rngs = jax.ShapeDtypeStruct((k_steps, 2), jnp.uint32, sharding=sh)
+    return paged.decode_multi_paged.lower(
+        sv["params"], state, _vec(slots, sh), _vec(slots, sh, jnp.bool_), cfg, rngs,
+        _vec(slots, sh, jnp.float32), _vec(slots, sh, jnp.float32),
+        _vec(slots, sh), _vec(slots, sh))
+
+
+@pytest.mark.parametrize("layout,program", [
+    ("slot", "prefill"), ("slot", "decode"),
+    ("paged", "prefill"), ("paged", "install"), ("paged", "decode"),
+])
+def test_serving_program_compiles_and_fits(one_chip, on_tpu, serving, layout, program):
+    import chip_smoke
+
+    lower = _lower_slot if layout == "slot" else _lower_paged
+    compiled = lower(serving, one_chip, program).compile()
+    ma = compiled.memory_analysis()
+    # weights and the KV state are arguments (the state aliased to the output);
+    # with the temporaries they are what this program needs of the chip
+    need = ma.argument_size_in_bytes + ma.temp_size_in_bytes + (
+        ma.output_size_in_bytes - ma.alias_size_in_bytes)
+    assert need < chip_smoke.V5E_BYTES_LIMIT, (layout, program, need)
+
+
+def test_depth_cut_preset_keeps_its_own_init_cache_entry():
+    """Two depths of one preset share a name; the engine's seeded-init cache
+    must not hand one the other's weights, and serves in the dtype asked for."""
+    from ray_tpu.llm.engine import _INIT_CACHE, llama_init_cached
+    from ray_tpu.models import get_config
+
+    two = get_config("test-tiny")
+    one = dataclasses.replace(two, n_layers=1)
+    assert one.name == two.name
+    p2, p1 = llama_init_cached(two, "bfloat16"), llama_init_cached(one, "bfloat16")
+    assert p2["layers"]["wq"].shape[0] == 2 and p1["layers"]["wq"].shape[0] == 1
+    assert p1["layers"]["wq"].dtype == jnp.bfloat16
+    assert llama_init_cached(two, "float32")["embed"].dtype == jnp.float32
+    assert llama_init_cached(two, "bfloat16") is p2
+    assert (two, "bfloat16", None) in _INIT_CACHE and (one, "bfloat16", None) in _INIT_CACHE
