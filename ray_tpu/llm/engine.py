@@ -57,6 +57,74 @@ def _metrics_guard_warn(where: str, e: BaseException) -> None:
                        "30s): %r", where, e)
 
 
+# What one turn of the scheduler loop does between device calls, in order:
+# admission (with aborts and the 5 s gauge refresh), the burst plan with block
+# growth or preemption, building the arguments and enqueueing the decode
+# program, the designed host sync (the token fetch), detokenising and handing
+# tokens to the request threads, and the wait when no slot is active. The span
+# names (profiler annotations + ring) tell a device idle gap's cause, e.g.
+# `trace_reduce.reduce(planes, n, annotations=LOOP_SPANS)`; the same clock
+# reads feed the always-on `loop_*_ns_total` counters of metrics().
+LOOP_SPANS = ("llm.loop.admit", "llm.loop.grow_or_preempt", "llm.loop.dispatch",
+              "llm.loop.fetch", "llm.loop.emit", "llm.loop.idle")
+_ADMIT, _GROW, _DISPATCH, _FETCH, _EMIT, _IDLE = range(len(LOOP_SPANS))
+_LOOP_COUNTERS = ("loop_admit_ns_total", "loop_grow_ns_total",
+                  "loop_dispatch_ns_total", "loop_fetch_ns_total",
+                  "loop_emit_ns_total", "loop_idle_ns_total")
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# programs this PROCESS compiled (or read from the compile cache) and the
+# seconds that took: JAX reports them to process-wide listeners that cannot be
+# taken off again, so one listener and one count serve every engine here
+_COMPILES = {"compiles_total": 0, "compile_ns_total": 0}
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _count_compiles() -> None:
+    """Register the compile listener, once a process."""
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if _compile_listener_on:
+            return
+        _compile_listener_on = True
+
+    def on_duration(event: str, duration_secs: float, **_kw) -> None:
+        if event == COMPILE_EVENT:
+            with _compile_listener_lock:
+                _COMPILES["compiles_total"] += 1
+                _COMPILES["compile_ns_total"] += int(duration_secs * 1e9)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+
+
+class _LoopClock:
+    """Which phase of its turn the scheduler loop is in. enter(i) ends the
+    phase that was open and begins LOOP_SPANS[i] at the same instant, so every
+    nanosecond of the loop thread lies in exactly one phase (time the thread
+    waits to run, say for the interpreter lock after it woke the request
+    threads, goes to the phase it was in). Each phase is a telemetry span and,
+    tracing on or off, adds its duration to `counters[_LOOP_COUNTERS[i]]`.
+    The loop is one thread: no lock."""
+
+    __slots__ = ("_counters", "_key", "_span", "_t0")
+
+    def __init__(self, counters: Dict[str, int]):
+        self._counters, self._key, self._span, self._t0 = counters, None, None, 0
+
+    def enter(self, i: Optional[int]) -> None:
+        now = time.perf_counter_ns()
+        if self._key is not None:
+            self._counters[self._key] += now - self._t0
+            self._span.__exit__(None, None, None)
+        if i is None:  # the loop ends
+            self._key = self._span = None
+            return
+        self._key, self._t0 = _LOOP_COUNTERS[i], now
+        self._span = telemetry.span(LOOP_SPANS[i], "llm")
+        self._span.__enter__()
+
+
 @dataclasses.dataclass
 class RequestOutput:
     """One streamed chunk: the tokens emitted since the previous chunk."""
@@ -93,7 +161,7 @@ def _pow2_floor(n: int) -> int:
 
 class _Request:
     def __init__(self, req_id: str, prompt_ids: List[int], params: SamplingParams,
-                 prefill_kv=None):
+                 prefill_kv=None, arrival_wall_ns: Optional[int] = None):
         self.id = req_id
         self.prompt_ids = prompt_ids
         self.params = params
@@ -120,6 +188,9 @@ class _Request:
         # request-lifecycle telemetry (queue -> prefill -> decode spans, TTFT,
         # tokens/s) + the prefix-cache evidence the Serve decode work needs:
         # how many prompt tokens the cache served vs how many prefill computed
+        # time.time_ns() at which the HTTP proxy took the request in (None: it
+        # did not come through the proxy); created - arrival is the way in
+        self.arrival_wall_ns = arrival_wall_ns
         self.created_wall_ns = time.time_ns()
         self.created_perf_ns = time.perf_counter_ns()
         self.first_token_perf_ns = 0
@@ -182,10 +253,19 @@ class JaxLLMEngine(LLMEngine):
         self.num_active = 0
         self.total_generated = 0
         self.num_preemptions = 0
-        self.num_aborted = 0
         self.num_spec_drafted = 0
         self.num_spec_accepted = 0
         self.num_prefix_skipped = 0  # pay-or-skip gate declined the cache
+        # monotonic integer counters, always on, returned by metrics(): time
+        # and counts accumulated where the work happens, so that ratios over a
+        # window (after - before) need no tracing. _counters is the loop
+        # thread's alone; _ingress is written by request threads, under _lock.
+        self._counters: Dict[str, int] = dict.fromkeys(_LOOP_COUNTERS + (
+            "prefill_ns_total", "prefill_tokens_total", "prefill_calls_total",
+            "decode_steps_total", "decode_slot_steps_total",
+            "queue_wait_ns_total", "admitted_total"), 0)
+        self._ingress = {"ingress_ns_total": 0, "ingress_requests_total": 0}
+        self._clock = _LoopClock(self._counters)
         # P/D export bookkeeping (prefill side): (monotonic, key) per un-acked
         # KV export, LRU/TTL-pruned by _track_pd_export and the lazy prune
         # daemon; kept in sync with the device plane's own releases (consumer
@@ -205,6 +285,7 @@ class JaxLLMEngine(LLMEngine):
             from ray_tpu.usage import record_library_usage
 
             record_library_usage("llm")
+            _count_compiles()
             cfg = self.model_config
             c = self.config
             from ray_tpu.core.accelerators import check_worker_platform
@@ -494,15 +575,27 @@ class JaxLLMEngine(LLMEngine):
             self._loop_thread.join(timeout=5)
 
     # -- API ---------------------------------------------------------------------
-    def generate(self, prompt, params: SamplingParams, request_id: Optional[str] = None
-                 ) -> Iterator[RequestOutput]:
+    def generate(self, prompt, params: SamplingParams, request_id: Optional[str] = None,
+                 arrival_wall_ns: Optional[int] = None) -> Iterator[RequestOutput]:
+        """arrival_wall_ns: `time.time_ns()` at which the HTTP proxy took the
+        request in, carried with it through router and replica; a request
+        that did not come through the proxy has none and counts in neither
+        ingress counter. The ingress counters subtract the proxy's wall clock
+        from this process's, so they hold for a proxy and a replica on ONE
+        node; across nodes the clocks' skew goes into the sum, or is cut off
+        at zero."""
         self.start()
         self._ensure_decode_started()
         prompt_ids = self._encode_prompt(prompt, params)
-        req = _Request(request_id or uuid.uuid4().hex, prompt_ids, params)
+        req = _Request(request_id or uuid.uuid4().hex, prompt_ids, params,
+                       arrival_wall_ns=arrival_wall_ns)
         with self._lock:
             self.num_pending += 1
             self._requests[req.id] = req
+            if req.arrival_wall_ns is not None:
+                self._ingress["ingress_ns_total"] += max(
+                    0, req.created_wall_ns - int(req.arrival_wall_ns))
+                self._ingress["ingress_requests_total"] += 1
         self._waiting.put(req)
         self._wakeup.set()
 
@@ -536,7 +629,6 @@ class JaxLLMEngine(LLMEngine):
             request_id=req.id, token_ids=[], finished=True,
             finish_reason="abort", num_prompt_tokens=len(req.prompt_ids),
             num_generated_tokens=req.generated))
-        self.num_aborted += 1
         self._release(req)
         with self._lock:
             self._aborted.discard(req.id)
@@ -827,11 +919,12 @@ class JaxLLMEngine(LLMEngine):
                 self._aborted.discard(req.id)
         return finished
 
-    def generate_sync(self, prompt, params: SamplingParams) -> RequestOutput:
+    def generate_sync(self, prompt, params: SamplingParams,
+                      arrival_wall_ns: Optional[int] = None) -> RequestOutput:
         """Collect the full generation into one RequestOutput."""
         ids: List[int] = []
         last = None
-        for chunk in self.generate(prompt, params):
+        for chunk in self.generate(prompt, params, arrival_wall_ns=arrival_wall_ns):
             ids.extend(chunk.token_ids)
             last = chunk
         return RequestOutput(
@@ -847,13 +940,21 @@ class JaxLLMEngine(LLMEngine):
     def metrics(self) -> Dict[str, Any]:
         """Engine health + paged-KV performance counters (reference: vllm
         engine stats — pool occupancy, prefix-cache hits, preemptions — the
-        numbers that validate the paged design under load)."""
+        numbers that validate the paged design under load).
+
+        The `*_total` keys are monotonic integers counted where the work
+        happens (the scheduler loop's phases, prefill, decode steps and
+        slot-steps, queue wait, the way in, compiles of this process): read
+        them before and after a window and divide the differences.
+        `decode_device_step_ms`, `decode_host_rt_ms` and
+        `decode_host_sync_fraction` are ESTIMATES from the host clock (wall
+        time of dispatch-to-fetch minus a measured round trip, smoothed): the
+        burst planner steers by them; they are not device times."""
         out = {
             "num_pending": self.num_pending,
             "num_active": self.num_active,
             "total_generated": self.total_generated,
             "num_preemptions": self.num_preemptions,
-            "num_aborted": self.num_aborted,
             "num_spec_drafted": self.num_spec_drafted,
             "num_spec_accepted": self.num_spec_accepted,
             "num_prefix_skipped": self.num_prefix_skipped,
@@ -868,13 +969,15 @@ class JaxLLMEngine(LLMEngine):
             "decode_host_rt_ms": round(self._host_rt_s * 1e3, 4),
             "decode_device_step_ms": round(self._step_s * 1e3, 4),
         }
+        out.update(self._counters)
+        out.update(self._ingress)
+        out.update(_COMPILES)
         blocks = getattr(self, "_blocks", None)
         if blocks is not None:
             total = blocks.total_blocks
             free = blocks.num_free
             out.update({
                 "kv_blocks_total": total,
-                "kv_blocks_free": free,
                 "kv_pool_occupancy": (total - free) / total if total else 0.0,
                 "prefix_cache_hit_tokens": blocks.hit_tokens,
                 "prefix_cached_blocks": len(blocks.cached),
@@ -946,6 +1049,9 @@ class JaxLLMEngine(LLMEngine):
 
     def _record_prefill_inner(self, req: _Request, t_admit_perf: int) -> None:
         dur = time.perf_counter_ns() - t_admit_perf
+        self._counters["prefill_ns_total"] += dur
+        self._counters["prefill_tokens_total"] += self._prefill_tokens_of(req)
+        self._counters["prefill_calls_total"] += 1
         # per-token prefill cost EWMA (dispatch round trip subtracted): the
         # prefix-cache pay-or-skip gate's estimate of what a cached token
         # saves. A first-compile sample inflates it, which only biases the
@@ -955,9 +1061,6 @@ class JaxLLMEngine(LLMEngine):
         self._prefill_per_tok_s = per_tok if self._prefill_per_tok_s <= 0 else (
             0.3 * per_tok + 0.7 * self._prefill_per_tok_s)
         tags = self._model_tag()
-        telemetry.get_histogram(
-            "llm_prefill_seconds", "engine prefill latency per admission",
-            tag_keys=("model",)).observe(dur / 1e9, tags=tags)
         if req.prefix_hit_tokens >= 0:  # a paged prefill ran for this admission
             name = ("llm_prefix_cache_hits_total" if req.prefix_hit_tokens > 0
                     else "llm_prefix_cache_misses_total")
@@ -1084,7 +1187,6 @@ class JaxLLMEngine(LLMEngine):
                 was_aborted = req.id in self._aborted
                 self._aborted.discard(req.id)
             if was_aborted:
-                self.num_aborted += 1
                 if req.kv_fetch is not None:
                     req.kv_fetch.cancel()
                     req.kv_fetch = None
@@ -1119,6 +1221,9 @@ class JaxLLMEngine(LLMEngine):
                 # telemetry is off, so mid-flight enabling can't fabricate
                 # queue time that includes a previous admission's decode.
                 req.queue_recorded = True
+                self._counters["queue_wait_ns_total"] += (
+                    t_admit_perf - req.created_perf_ns)
+                self._counters["admitted_total"] += 1
                 if telemetry.enabled():
                     telemetry.complete(
                         "llm.queue", "llm", req.created_wall_ns,
@@ -1531,6 +1636,7 @@ class JaxLLMEngine(LLMEngine):
         active_mask = np.array([r is not None for r in self._active.values()], bool)
         if not active_mask.any():
             return
+        self._clock.enter(_DISPATCH)
         # history width bucketed to a power of two: bounds both the H2D upload
         # (not max_model_len when contexts are short) and the spec_multi trace
         # count (one program per width bucket)
@@ -1547,7 +1653,7 @@ class JaxLLMEngine(LLMEngine):
             hist[slot, :len(ctx)] = ctx
             hlen[slot] = len(ctx)
         rngs = jnp.stack([self._next_rng() for _ in range(m)])
-        t0_wall, t0_perf = time.time_ns(), time.perf_counter_ns()
+        t0_perf = time.perf_counter_ns()
         if c.kv_layout == "paged":
             self.state, toks_m, acc_m, drafted_m = self._pops.spec_multi(
                 self.params, self.state, jnp.asarray(hist), jnp.asarray(hlen),
@@ -1560,6 +1666,7 @@ class JaxLLMEngine(LLMEngine):
                 jnp.asarray(active_mask), cfg, rngs,
                 jnp.asarray(self._temp), jnp.asarray(self._top_p),
                 jnp.asarray(self._top_k), m, k, c.ngram_prompt_lookup_max)
+        self._clock.enter(_FETCH)
         # graftlint: allow[host-sync-in-hot-path] the ONE designed fetch per fused spec window (PR 12 contract)
         toks_m, acc_m, drafted_m = jax.device_get((toks_m, acc_m, drafted_m))
         dur_ns = time.perf_counter_ns() - t0_perf
@@ -1567,27 +1674,32 @@ class JaxLLMEngine(LLMEngine):
         # the unit decode_steps_target counts here): without this the EWMA
         # would freeze at whatever the single-window phase measured
         self._note_burst_device_wall(m, dur_ns / 1e9)
+        self._clock.enter(_EMIT)
         before = self.total_generated
         burst_reqs = {s: r for s, r in self._active.items() if r is not None}
+        advanced = 0
         for step in range(m):
             for slot, req in burst_reqs.items():
-                self._emit_spec_window(
+                advanced += self._emit_spec_window(
                     # graftlint: allow[host-sync-in-hot-path] acc_m/toks_m already fetched by this window's device_get
                     slot, req, toks_m[step, slot], int(acc_m[step, slot]),
                     # graftlint: allow[host-sync-in-hot-path] drafted_m already fetched by this window's device_get
                     int(drafted_m[step, slot]))
-        self._record_burst(m, self.total_generated - before,
-                           int(active_mask.sum()), t0_wall, dur_ns)
+        # a verify window is this path's device step
+        self._counters["decode_steps_total"] += m
+        self._counters["decode_slot_steps_total"] += advanced
+        self._record_burst(self.total_generated - before, dur_ns)
 
     def _emit_spec_window(self, slot: int, req: "_Request", toks_row,
-                          acc: int, drafted: int) -> None:
+                          acc: int, drafted: int) -> bool:
         """Emit one verify window's accepted prefix + bonus token for a slot
         (shared by the per-window and fused spec paths): counts acceptance,
-        discards tokens past a mid-burst finish, force-finishes at the KV cap."""
+        discards tokens past a mid-burst finish, force-finishes at the KV cap.
+        True when the slot advanced (it put out at least one token)."""
         if self._active.get(slot) is not req:
-            return  # finished (or aborted) earlier in this burst: discard tail
+            return False  # finished (or aborted) earlier in this burst: discard tail
         if self._aborted and self._finish_abort(req):
-            return  # cancelled mid-burst: tail discarded, blocks freed now
+            return False  # cancelled mid-burst: tail discarded, blocks freed now
         c = self.config
         self.num_spec_drafted += drafted
         self.num_spec_accepted += min(acc, drafted)
@@ -1608,6 +1720,7 @@ class JaxLLMEngine(LLMEngine):
                     num_generated_tokens=r2.generated,
                 ))
                 self._release(r2)
+        return True
 
     @hot_path
     def _step_decode_spec(self) -> None:
@@ -1616,6 +1729,7 @@ class JaxLLMEngine(LLMEngine):
         all emit this step (greedy slots only; others ride along with k=0)."""
         cfg = self.model_config
         c = self.config
+        self._clock.enter(_GROW)
         if self.decode_steps_target() > 1:
             # pp engines never reach here with >1 (start() downgrades the
             # target): pp keeps per-step scheduling (microbatch ticks)
@@ -1633,6 +1747,7 @@ class JaxLLMEngine(LLMEngine):
             # every window position must land in an owned block
             self._grow_or_preempt(headroom=wlen)
         n = c.max_num_seqs
+        self._clock.enter(_DISPATCH)
         window = np.zeros((n, wlen), np.int32)
         draft_len = np.zeros((n,), np.int32)
         active_mask = np.zeros((n,), bool)
@@ -1653,7 +1768,7 @@ class JaxLLMEngine(LLMEngine):
                 window[slot, 1:1 + len(drafts)] = drafts
         if not active_mask.any():
             return  # pool-exhaustion preemption may have drained every slot
-        t0_wall, t0_perf = time.time_ns(), time.perf_counter_ns()
+        t0_perf = time.perf_counter_ns()
         if c.kv_layout == "paged":
             self.state, out_toks, n_acc = self._pops.spec_verify(
                 self.params, self.state, jnp.asarray(window),
@@ -1672,6 +1787,7 @@ class JaxLLMEngine(LLMEngine):
                 jnp.asarray(draft_len), jnp.asarray(active_mask), cfg,
                 self._next_rng(), jnp.asarray(self._temp),
                 jnp.asarray(self._top_p), jnp.asarray(self._top_k))
+        self._clock.enter(_FETCH)
         # graftlint: allow[host-sync-in-hot-path] the ONE designed fetch per spec-decode step
         out_toks, n_acc = jax.device_get((out_toks, n_acc))
         dur_ns = time.perf_counter_ns() - t0_perf
@@ -1679,15 +1795,18 @@ class JaxLLMEngine(LLMEngine):
         # auto-K probe: once the EWMA settles, single-window spec engines in
         # auto mode graduate to fused multi-window bursts
         self._note_burst_device_wall(1, dur_ns / 1e9)
+        self._clock.enter(_EMIT)
         before = self.total_generated
         burst_reqs = {s: r for s, r in self._active.items() if r is not None}
+        advanced = 0
         for slot, req in burst_reqs.items():
-            self._emit_spec_window(slot, req, out_toks[slot],
-                                   # graftlint: allow[host-sync-in-hot-path] n_acc/draft_len already fetched by this step's device_get
-                                   int(n_acc[slot]), int(draft_len[slot]))
-        self._record_burst(1, self.total_generated - before,
-                           # graftlint: allow[host-sync-in-hot-path] active_mask is a host-side bool array
-                           int(np.asarray(active_mask).sum()), t0_wall, dur_ns)
+            advanced += self._emit_spec_window(
+                slot, req, out_toks[slot],
+                # graftlint: allow[host-sync-in-hot-path] n_acc/draft_len already fetched by this step's device_get
+                int(n_acc[slot]), int(draft_len[slot]))
+        self._counters["decode_steps_total"] += 1
+        self._counters["decode_slot_steps_total"] += advanced
+        self._record_burst(self.total_generated - before, dur_ns)
 
     @hot_path
     def _step_decode(self) -> None:
@@ -1695,6 +1814,7 @@ class JaxLLMEngine(LLMEngine):
         if self.config.num_speculative_tokens:
             self._step_decode_spec()
             return
+        self._clock.enter(_GROW)
         k_steps, steps = self._burst_plan()
         if self.config.kv_layout == "paged":
             self._grow_or_preempt(headroom=k_steps, steps=steps)
@@ -1702,7 +1822,8 @@ class JaxLLMEngine(LLMEngine):
         active_mask = np.array([r is not None for r in self._active.values()], bool)
         if not active_mask.any():
             return  # preemption may have drained every slot this cycle
-        t0_wall, t0_perf = time.time_ns(), time.perf_counter_ns()
+        t0_perf = time.perf_counter_ns()
+        self._clock.enter(_DISPATCH)
         if k_steps > 1:
             # fused burst: K decode+sample iterations, ONE host sync (vLLM
             # multi-step scheduling). The
@@ -1713,19 +1834,17 @@ class JaxLLMEngine(LLMEngine):
             rngs = jnp.stack([self._next_rng() for _ in range(k_steps)])
             steps_dev = jnp.asarray(steps)
             if self.config.kv_layout == "paged":
-                self.state, toks_k = self._pops.decode_multi(
+                self.state, toks_dev = self._pops.decode_multi(
                     self.params, self.state, jnp.asarray(self._last_tokens),
                     jnp.asarray(active_mask), rngs,
                     jnp.asarray(self._temp), jnp.asarray(self._top_p),
                     jnp.asarray(self._top_k), steps_dev)
             else:
-                self.state, toks_k = model_runner.decode_multi(
+                self.state, toks_dev = model_runner.decode_multi(
                     self.params, self.state, jnp.asarray(self._last_tokens),
                     jnp.asarray(active_mask), cfg, rngs,
                     jnp.asarray(self._temp), jnp.asarray(self._top_p),
                     jnp.asarray(self._top_k), steps_dev)
-            # graftlint: allow[host-sync-in-hot-path] the ONE designed host sync per K-step fused burst (PR 12)
-            toks_burst = np.asarray(toks_k)  # [K, slots] — the only fetch
         else:
             if self.config.kv_layout == "paged":
                 self.state, logits = self._pops.decode_step(
@@ -1742,12 +1861,17 @@ class JaxLLMEngine(LLMEngine):
                     self.params, self.state, jnp.asarray(self._last_tokens),
                     jnp.asarray(active_mask), cfg,
                 )
-            # graftlint: allow[host-sync-in-hot-path] the designed per-step token fetch on the K=1 path
-            toks_burst = np.asarray(model_runner.sample_tokens(
+            toks_dev = model_runner.sample_tokens(
                 self._next_rng(), logits, jnp.asarray(self._temp),
-                jnp.asarray(self._top_p), jnp.asarray(self._top_k)))[None, :]
+                jnp.asarray(self._top_p), jnp.asarray(self._top_k))
+        self._clock.enter(_FETCH)
+        # graftlint: allow[host-sync-in-hot-path] the ONE designed host sync per K-step fused burst (PR 12); at K=1 the per-step token fetch
+        toks_burst = np.asarray(toks_dev)  # [K, slots] — the only fetch
+        if k_steps == 1:
+            toks_burst = toks_burst[None, :]
         dur_ns = time.perf_counter_ns() - t0_perf
         self._note_burst_device_wall(k_steps, dur_ns / 1e9)
+        self._clock.enter(_EMIT)
         burst_reqs = {slot: req for slot, req in self._active.items() if req is not None}
         emitted = 0
         for t in range(toks_burst.shape[0]):
@@ -1776,16 +1900,18 @@ class JaxLLMEngine(LLMEngine):
                         num_generated_tokens=r2.generated,
                     ))
                     self._release(r2)
-        self._record_burst(k_steps, emitted, int(active_mask.sum()),
-                           t0_wall, dur_ns)
+        # device steps of the burst, and the slot-steps among them that
+        # put out a token: their ratio over max_num_seqs is the occupancy
+        self._counters["decode_steps_total"] += k_steps
+        self._counters["decode_slot_steps_total"] += emitted
+        self._record_burst(emitted, dur_ns)
 
-    def _record_burst(self, k: int, emitted: int, n_slots: int,
-                      t0_wall_ns: int, dur_ns: int) -> None:
-        """Per-burst decode telemetry: ONE span/observation per K-step burst
-        (tagged with K and tokens emitted) instead of per host step, so the
-        cross-worker timeline and the windowed quantiles stay truthful under
-        fused mode. Guarded like _export_metrics — metrics must never take
-        the engine down."""
+    def _record_burst(self, emitted: int, dur_ns: int) -> None:
+        """Per-burst decode telemetry: ONE observation per K-step burst
+        instead of per host step, so the windowed quantiles stay truthful
+        under fused mode (the burst's interval itself is the llm.loop.dispatch
+        and llm.loop.fetch spans). Guarded like _export_metrics — metrics
+        must never take the engine down."""
         try:
             tags = self._model_tag()
             if emitted:
@@ -1802,11 +1928,6 @@ class JaxLLMEngine(LLMEngine):
                         boundaries=[10, 50, 100, 250, 500, 1000, 2500, 5000,
                                     10000, 25000]).observe(
                         emitted / (dur_ns / 1e9), tags=tags)
-            if telemetry.enabled():
-                telemetry.complete(
-                    "llm.decode_burst", "llm", t0_wall_ns, dur_ns, k=k,
-                    tokens=emitted, slots=n_slots,
-                    model=str(self.config.model_id))
         except Exception as e:
             _metrics_guard_warn("_record_burst", e)
 
@@ -1820,6 +1941,7 @@ class JaxLLMEngine(LLMEngine):
                 # liveness heartbeat for LLMServer.check_health: a wedged
                 # device call shows up as a stale tick while requests wait
                 self._last_tick_monotonic = _time.monotonic()
+                self._clock.enter(_ADMIT)
                 self._admit()
                 self._process_aborts()
                 # periodic gauge refresh: /metrics must serve current llm_*
@@ -1833,6 +1955,7 @@ class JaxLLMEngine(LLMEngine):
                 else:
                     from ray_tpu.config import CONFIG as _CFG
 
+                    self._clock.enter(_IDLE)
                     self._wakeup.wait(timeout=_CFG.llm_engine_idle_wait_s)
                     self._wakeup.clear()
             except Exception:
@@ -1864,6 +1987,7 @@ class JaxLLMEngine(LLMEngine):
                         break
                     self._fail_request(req, len(req.prompt_ids), "error")
                 time.sleep(0.1)
+        self._clock.enter(None)
 
 
 _INIT_CACHE: Dict[Tuple[Any, str, Any], Any] = {}

@@ -96,30 +96,37 @@ class LLMServer:
         self.engine.start()
 
     # -- OpenAI endpoints --------------------------------------------------------
-    def chat(self, body: Dict[str, Any]):
+    # arrival_wall_ns: the HTTP proxy's time.time_ns() on taking the request
+    # in, handed on by OpenAIRouter; the engine counts the way in from it
+    # (metrics(): ingress_ns_total). A handle call has none.
+    def chat(self, body: Dict[str, Any], arrival_wall_ns: Optional[int] = None):
         prompt = render_chat_template(body.get("messages", []))
         if body.get("stream"):
-            return self._sse_stream(prompt, body, chat=True)
-        out = self.engine.generate_sync(prompt, _sampling_from_body(body))
+            return self._sse_stream(prompt, body, True, arrival_wall_ns)
+        out = self.engine.generate_sync(prompt, _sampling_from_body(body),
+                                        arrival_wall_ns=arrival_wall_ns)
         return _chat_envelope(
             body.get("model", self.llm_config.model_id), out.text, out.finish_reason,
             _usage(out.num_prompt_tokens, out.num_generated_tokens))
 
-    def completions(self, body: Dict[str, Any]):
+    def completions(self, body: Dict[str, Any], arrival_wall_ns: Optional[int] = None):
         if body.get("stream"):
-            return self._sse_stream(body.get("prompt", ""), body, chat=False)
-        out = self.engine.generate_sync(body.get("prompt", ""), _sampling_from_body(body))
+            return self._sse_stream(body.get("prompt", ""), body, False, arrival_wall_ns)
+        out = self.engine.generate_sync(body.get("prompt", ""), _sampling_from_body(body),
+                                        arrival_wall_ns=arrival_wall_ns)
         return _completion_envelope(
             body.get("model", self.llm_config.model_id), out.text, out.finish_reason,
             _usage(out.num_prompt_tokens, out.num_generated_tokens))
 
-    def _sse_stream(self, prompt: str, body: Dict[str, Any], chat: bool):
+    def _sse_stream(self, prompt: str, body: Dict[str, Any], chat: bool,
+                    arrival_wall_ns: Optional[int] = None):
         """OpenAI ``stream: true``: yield SSE frames ("data: {chunk}\\n\\n" ...
         "data: [DONE]\\n\\n") as the engine produces tokens. Runs as a streaming
         actor method through Serve (reference proxy.py:699 ASGI streaming)."""
         return self._sse_frames(
             lambda rid: self.engine.generate(
-                prompt, _sampling_from_body(body), request_id=rid),
+                prompt, _sampling_from_body(body), request_id=rid,
+                arrival_wall_ns=arrival_wall_ns),
             body, chat)
 
     def decode_stream(self, prefill_result, body: Dict[str, Any],
@@ -364,7 +371,10 @@ class OpenAIRouter:
             h = handle.options(method_name="completions", stream=stream)
         else:
             raise ValueError(f"unsupported path {path!r}")
-        resp = h.remote(body)
+        # the proxy's arrival stamp travels on with the request
+        arrival = request.get("arrival_wall_ns")
+        resp = h.remote(body) if arrival is None else h.remote(
+            body, arrival_wall_ns=arrival)
         # streaming: return the response generator itself — the router is called
         # with a streaming method too, so each SSE frame re-streams through it
         return resp if stream else resp.result()

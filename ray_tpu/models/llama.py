@@ -213,68 +213,73 @@ def _block(
 ):
     """One decoder block. Returns (x, updated (k,v) if caching, moe aux loss)."""
     dt = x.dtype
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
-    q = wsc(rope(q, positions, cfg.rope_theta), "batch", "seq", "act_heads", "head_dim")
-    k = rope(k, positions, cfg.rope_theta)
+    # named scopes: metadata only (free at run time); what a reader of the
+    # profile uses to tell one fusion from another
+    with jax.named_scope("attn"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
+        q = wsc(rope(q, positions, cfg.rope_theta), "batch", "seq", "act_heads", "head_dim")
+        k = rope(k, positions, cfg.rope_theta)
 
-    new_kv = None
-    if cache_kv is not None:
-        ck, cv = cache_kv
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_len, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_len, axis=1)
-        new_kv = (ck, cv)
-        attn = attention(
-            q, ck, cv, causal=True, q_offset=cache_len, kv_valid_len=cache_len + q.shape[1]
-        )
-    elif cfg.attention_impl in ("ring", "ulysses"):
-        # Sequence-parallel attention: activations stay seq-sharded over "sp"; KV chunks
-        # ride the ICI ring (ops/ring_attention.py). If "sp" is already bound manually
-        # (pipeline stage traced with extra_manual=("sp",)), call the collective form
-        # directly — nested shard_map is not composable.
-        from ray_tpu.ops import ring_attention as ra
-        from ray_tpu.parallel.sharding import active_manual_axes
-
-        if "sp" in active_manual_axes():
-            if cfg.attention_impl == "ring":
-                attn = ra.ring_attention(q, k, v, causal=True, segment_ids=segment_ids)
-            else:
-                if segment_ids is not None:
-                    # mirror ring_attention_sharded's refusal — dropping the
-                    # packing mask here would silently attend across documents
-                    raise NotImplementedError(
-                        "segment_ids only supported with impl='ring'")
-                attn = ra.ulysses_attention(q, k, v, causal=True)
-        else:
-            attn = ra.ring_attention_sharded(
-                q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl
+        new_kv = None
+        if cache_kv is not None:
+            ck, cv = cache_kv
+            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_len, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_len, axis=1)
+            new_kv = (ck, cv)
+            attn = attention(
+                q, ck, cv, causal=True, q_offset=cache_len, kv_valid_len=cache_len + q.shape[1]
             )
-    else:
-        attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
-                         shard_spec=auto_spec("batch", None, "act_heads", None))
-    o = jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], dt))
-    x = wsc(x + o, "batch", "seq", "act_embed")
+        elif cfg.attention_impl in ("ring", "ulysses"):
+            # Sequence-parallel attention: activations stay seq-sharded over "sp"; KV chunks
+            # ride the ICI ring (ops/ring_attention.py). If "sp" is already bound manually
+            # (pipeline stage traced with extra_manual=("sp",)), call the collective form
+            # directly — nested shard_map is not composable.
+            from ray_tpu.ops import ring_attention as ra
+            from ray_tpu.parallel.sharding import active_manual_axes
 
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        from . import moe as _moe
+            if "sp" in active_manual_axes():
+                if cfg.attention_impl == "ring":
+                    attn = ra.ring_attention(q, k, v, causal=True, segment_ids=segment_ids)
+                else:
+                    if segment_ids is not None:
+                        # mirror ring_attention_sharded's refusal — dropping the
+                        # packing mask here would silently attend across documents
+                        raise NotImplementedError(
+                            "segment_ids only supported with impl='ring'")
+                    attn = ra.ulysses_attention(q, k, v, causal=True)
+            else:
+                attn = ra.ring_attention_sharded(
+                    q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl
+                )
+        else:
+            attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
+                             shard_spec=auto_spec("batch", None, "act_heads", None))
+        o = jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], dt))
+        x = wsc(x + o, "batch", "seq", "act_embed")
 
-        b, s, d = h.shape
-        y2, aux = _moe.moe_mlp(
-            h.reshape(b * s, d), lp["router"], lp["w_gate"], lp["w_up"],
-            lp["w_down"], cfg,
-            mask=None if token_mask is None else token_mask.reshape(b * s),
-        )
-        down = y2.reshape(b, s, d)
-    else:
-        gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
-        up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
-        ff = wsc(jax.nn.silu(gate) * up, "batch", "seq", "act_mlp")
-        down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
-        aux = jnp.zeros((), jnp.float32)
-    return wsc(x + down, "batch", "seq", "act_embed"), new_kv, aux
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        if cfg.n_experts > 0:
+            from . import moe as _moe
+
+            b, s, d = h.shape
+            y2, aux = _moe.moe_mlp(
+                h.reshape(b * s, d), lp["router"], lp["w_gate"], lp["w_up"],
+                lp["w_down"], cfg,
+                mask=None if token_mask is None else token_mask.reshape(b * s),
+            )
+            down = y2.reshape(b, s, d)
+        else:
+            gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
+            up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
+            ff = wsc(jax.nn.silu(gate) * up, "batch", "seq", "act_mlp")
+            down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
+            aux = jnp.zeros((), jnp.float32)
+        x = wsc(x + down, "batch", "seq", "act_embed")
+    return x, new_kv, aux
 
 
 def _pipeline_layers(
@@ -378,8 +383,9 @@ def forward(
     if positions is None:
         start = cache.length if cache is not None else 0
         positions = jnp.broadcast_to(jnp.arange(s)[None, :] + start, (b, s))
-    x = _embed_lookup(params["embed"].astype(cfg.activation_dtype), tokens)
-    x = wsc(x, "batch", "seq", "act_embed")
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"].astype(cfg.activation_dtype), tokens)
+        x = wsc(x, "batch", "seq", "act_embed")
     aux_total = jnp.zeros((), jnp.float32)
 
     if cfg.pipeline_stages > 1 and cache is None:
@@ -427,10 +433,11 @@ def forward(
         if cache is not None:
             new_cache = KVCache(jnp.stack(ks), jnp.stack(vs), cache.length + s)
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, _w(head, cfg.activation_dtype))
-    logits = wsc(logits.astype(jnp.float32), "batch", "seq", "act_vocab")
+    with jax.named_scope("lm_head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, _w(head, cfg.activation_dtype))
+        logits = wsc(logits.astype(jnp.float32), "batch", "seq", "act_vocab")
     if return_aux:
         return logits, new_cache, aux_total
     return logits, new_cache
@@ -448,15 +455,16 @@ def loss_fn(
         params, tokens[:, :-1], cfg,
         segment_ids=None if seg is None else seg[:, :-1], return_aux=True,
     )
-    targets = tokens[:, 1:]
-    # target-logit minus logsumexp == log_softmax gathered at the target, without
-    # materializing a second [B,S,vocab] f32 tensor (1 GB/chip at 8B scale).
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    ll = tgt - lse
-    mask = batch.get("loss_mask")
-    mask = jnp.ones_like(ll) if mask is None else mask[:, 1:].astype(ll.dtype)
-    denom = jnp.maximum(mask.sum(), 1.0)
-    ce = -(ll * mask).sum() / denom
-    loss = ce + aux
+    with jax.named_scope("loss"):
+        targets = tokens[:, 1:]
+        # target-logit minus logsumexp == log_softmax gathered at the target, without
+        # materializing a second [B,S,vocab] f32 tensor (1 GB/chip at 8B scale).
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        ll = tgt - lse
+        mask = batch.get("loss_mask")
+        mask = jnp.ones_like(ll) if mask is None else mask[:, 1:].astype(ll.dtype)
+        denom = jnp.maximum(mask.sum(), 1.0)
+        ce = -(ll * mask).sum() / denom
+        loss = ce + aux
     return loss, {"loss": loss, "ce_loss": ce, "moe_aux_loss": aux, "tokens": denom}

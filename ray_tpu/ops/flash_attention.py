@@ -172,6 +172,7 @@ def _fwd(
 
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",  # the operation's name in the device trace
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -348,6 +349,7 @@ def _bwd(
 
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_bwd_dq",  # the operation's name in the device trace
         grid=(b, h, pl.cdiv(sq, bq_), pl.cdiv(skv, bkv_)),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, bq_, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
@@ -386,6 +388,7 @@ def _bwd(
 
     dk_per_h, dv_per_h = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_bwd_dkv",  # the operation's name in the device trace
         grid=(b, h, pl.cdiv(skv, bkv_), pl.cdiv(sq, bq_)),
         in_specs=in_specs2,
         out_specs=[

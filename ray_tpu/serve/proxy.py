@@ -194,6 +194,10 @@ class ProxyActor:
                 "query": dict(request.query),
                 "headers": dict(request.headers),
                 "body": payload,
+                # when this request came in, on the host's wall clock: an
+                # ingress deployment may hand it on (llm: OpenAIRouter ->
+                # LLMServer -> engine, which counts the way in from it)
+                "arrival_wall_ns": t0_wall,
             }
 
             # streaming (reference proxy.py:699 ASGI streaming): OpenAI-style

@@ -123,8 +123,9 @@ def make_train_step(
         (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, batch, cfg
         )
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):  # a name in the profile; no operation
+            updates, new_opt = tx.update(grads, state.opt_state, state.params)
+            new_params = optax.apply_updates(state.params, updates)
         metrics = dict(aux)
         metrics["grad_norm"] = optax.global_norm(grads)
         return TrainState(state.step + 1, new_params, new_opt), metrics

@@ -23,6 +23,17 @@ Enablement rides the tracing switch: RAY_TPU_TRACING=1 (or
 tracing.enable_tracing() / telemetry.enable()) turns both the span tracer and
 this recorder on. Ring capacity: RAY_TPU_TELEMETRY_RING_SIZE.
 
+The profiler's clock: while a `jax.profiler` session records in this process,
+span() ALSO enters a `jax.profiler.TraceAnnotation` of the same name, whether
+or not the ring is enabled, so the span lies on the host plane of the same
+`.xplane.pb` as the device's `XLA Ops`, on one clock by construction. JAX is
+never imported for this: a process that has not imported it has no profiler
+to write to. A profile's timeline starts at zero when the session starts;
+the first span after that also writes one `telemetry.clock_sync` annotation
+that carries `time.time_ns()` as `wall_ns`, so that whoever holds the profile
+can place the ring's events (complete(): spans whose two ends are on
+different threads) on it: profile_origin_ns().
+
 Transport: worker processes flush their ring to the head over the same
 control-pipe push the metrics registry uses (core/worker.py push_telemetry ->
 core/node.py "telemetry" message), tagged with a clock offset measured against
@@ -40,6 +51,7 @@ from __future__ import annotations
 
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -85,6 +97,57 @@ def reset_forced() -> None:
     """Back to env-driven enablement (RAY_TPU_TRACING)."""
     global _forced
     _forced = None
+
+
+# --------------------------------------------------------- the profiler's clock
+
+CLOCK_SYNC = "telemetry.clock_sync"  # marker annotation, `wall_ns` in its stats
+
+_annotation_cls = None  # jax.profiler.TraceAnnotation, once this process imported jax
+_synced = False  # this profile has its CLOCK_SYNC marker
+
+
+def _profiling():
+    """`jax.profiler.TraceAnnotation` while a profiler session records in this
+    process, else None (~0.1us). Never imports JAX: the driver and the head
+    must not, and a process without it has no session to write to. That a
+    profile ended is seen by the next span: a second profile that starts
+    before any span was made in between gets no CLOCK_SYNC marker."""
+    global _annotation_cls, _synced
+    cls = _annotation_cls
+    if cls is None:
+        cls = getattr(getattr(sys.modules.get("jax"), "profiler", None),
+                      "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _annotation_cls = cls
+    if not cls.is_enabled():
+        _synced = False
+        return None
+    if not _synced:
+        # first span of this profile: tie its timeline to the ring's clock
+        _synced = True
+        with cls(CLOCK_SYNC, wall_ns=time.time_ns()):
+            pass
+    return cls
+
+
+def profile_origin_ns(xplane_path: str) -> Optional[int]:
+    """The `time.time_ns()` of time zero of a profile written while spans were
+    recorded (`wall_ns - start` of its CLOCK_SYNC marker), so a ring event
+    stamped `ts_ns` lies at `ts_ns - origin` on the profile's timeline. None
+    when the profile holds no marker. Imports JAX (no backend): for whoever
+    opens a profile, not for the hot path."""
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_file(xplane_path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name == CLOCK_SYNC:
+                    wall_ns = dict(ev.stats).get("wall_ns")
+                    if wall_ns is not None:
+                        return int(wall_ns) - int(ev.start_ns)
+    return None
 
 
 def _resize_ring_locked() -> None:
@@ -143,28 +206,45 @@ def event(name: str, cat: str = "app", **args: Any) -> None:
 class _Span:
     """A lightweight timed region. Duration from perf_counter_ns (monotonic,
     ns resolution); the wall anchor from time_ns at entry places it on the
-    shared timeline. Extra attributes may be attached mid-span via set()."""
+    shared timeline. Extra attributes may be attached mid-span via set().
+    `ring`: record into the ring at exit; `note_cls`: the profiler's
+    annotation class while a profile records (entered right after the wall
+    anchor is read, so both starts are one instant on two timelines)."""
 
-    __slots__ = ("name", "cat", "args", "_t0_wall", "_t0_perf")
+    __slots__ = ("name", "cat", "args", "_t0_wall", "_t0_perf", "_ring",
+                 "_note_cls", "_note")
 
-    def __init__(self, name: str, cat: str, args: Dict[str, Any]):
+    def __init__(self, name: str, cat: str, args: Dict[str, Any],
+                 ring: bool = True, note_cls=None):
         self.name = name
         self.cat = cat
         self.args = args
+        self._ring = ring
+        self._note_cls = note_cls
+        self._note = None
 
     def set(self, **kw: Any) -> None:
         self.args.update(kw)
 
     def __enter__(self) -> "_Span":
-        # the trace tag is captured at ENTRY (the request thread); __exit__
-        # may run after the contextvar was reset
-        _tag_trace(self.args)
+        if self._ring:
+            # the trace tag is captured at ENTRY (the request thread);
+            # __exit__ may run after the contextvar was reset
+            _tag_trace(self.args)
         self._t0_wall = time.time_ns()
+        if self._note_cls is not None:
+            self._note = self._note_cls(self.name)
+            self._note.__enter__()
         self._t0_perf = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur = time.perf_counter_ns() - self._t0_perf
+        if self._note is not None:
+            self._note.__exit__(exc_type, exc, tb)
+            self._note = None
+        if not self._ring:
+            return
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
         _append({
@@ -193,10 +273,14 @@ _NOOP = _NoopSpan()
 
 
 def span(name: str, cat: str = "app", **args: Any):
-    """Context manager recording a complete ('X') event around the block."""
-    if not enabled():
+    """Context manager recording a complete ('X') event around the block:
+    into the ring when enabled(), and as a profiler annotation while a
+    `jax.profiler` session records in this process (enabled() or not).
+    Neither: the shared no-op."""
+    ring, note_cls = enabled(), _profiling()
+    if not ring and note_cls is None:
         return _NOOP
-    return _Span(name, cat, dict(args))
+    return _Span(name, cat, dict(args), ring, note_cls)
 
 
 def complete(name: str, cat: str, start_wall_ns: int, dur_ns: int,

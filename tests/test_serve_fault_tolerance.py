@@ -54,6 +54,8 @@ def test_retry_on_replica_failure_feeds_suspects(rt):
     h = serve.run(Echo.options(num_replicas=2).bind(), name="ft-rep")
     assert h.remote(0).result()[1] == 0
     chaos = ChaosController()
+    # serve.run returns once ONE replica runs: arm when both are there
+    wait_for_condition(lambda: len(chaos._replica_actors("ft-rep", "Echo")) == 2, timeout=30)
     # every replica fails exactly once: whichever gets the request bounces it,
     # the retry lands elsewhere (or re-picks after the budget of exclusions)
     assert chaos.arm_replica("ft-rep", "Echo", "serve.replica.request",

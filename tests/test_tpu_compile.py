@@ -11,6 +11,9 @@ import this file. Keep every such test in THIS file, and compile in the test's
 own process (no children).
 """
 import dataclasses
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +76,16 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
     bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, seg).compile()
     assert "tpu_custom_call" in fwd.as_text()
     assert bwd.as_text().count("tpu_custom_call") >= 2  # dQ and dK/dV kernels
+    # the kernels' names are their instructions' names, which the device trace
+    # shows (benchmarks/metrics/train_attn_{fwd,bwd}_kernel_pct.json select by them)
+    # (a transformation may wrap the name: %jvp_flash_attention_fwd_.1)
+    for path in ("train_attn_fwd_kernel_pct", "train_attn_bwd_kernel_pct"):
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics",
+                               f"{path}.json")) as f:
+            rx = re.compile(json.load(f)["args"]["pattern"])
+        kernels = [ln.strip() for ln in bwd.as_text().splitlines()
+                   if "tpu_custom_call" in ln and rx.search(ln.strip())]
+        assert len(kernels) == (1 if "fwd" in path else 2), (path, kernels)
 
 
 def test_train_step_compiles_for_four_chips(topo, on_tpu):
