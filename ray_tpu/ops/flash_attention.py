@@ -2,8 +2,7 @@
 
 Blockwise online-softmax attention (flash v2 style): the S×S score matrix never
 materializes in HBM; each (q-block, kv-block) tile is computed in VMEM and folded into
-running (max, sum, acc) statistics. Causal q/kv tiles that are fully masked are skipped
-entirely, so causal attention does half the FLOPs.
+running (max, sum, acc) statistics.
 
 Layout inside the kernel is [B, H, S, D] ("BHSD") so the S×D tiles are contiguous; the
 public wrapper takes BSHD like the rest of the framework. GQA is handled in the
@@ -11,8 +10,24 @@ BlockSpec index maps (kv head = q head // n_rep) — repeated KV heads are never
 materialized.
 
 Backward follows the standard two-kernel split: one pass computes dQ (grid over kv
-blocks inner), one computes dK/dV (grid over q blocks inner), both recomputing the
-block's probabilities from the saved logsumexp.
+blocks inner), one computes dK/dV (q blocks inner), both recomputing the block's
+probabilities from the saved logsumexp. The dK/dV pass works on the TRANSPOSED tile
+(kv rows, q columns): its four products are then plain or transposed-right-hand
+matmuls, the row statistics (logsumexp, delta) come in as lane vectors of `block_q`
+floats, and a kv head's group of query heads is the inner, accumulated grid
+dimension, so dK/dV are written once per kv head.
+
+Under `causal` a tile wholly above the diagonal runs nothing, and its index maps name
+the block of the nearest computed tile, so that the pipeline issues no copy for it.
+Every product feeds the MXU the inputs' own dtype (bf16 in training) and accumulates in
+f32; scores, exponentials, logsumexp, delta and all accumulators are f32. Per-row
+statistics are kept 128 equal lanes wide inside a kernel and travel between kernels as
+lane vectors (`_rows`): as [rows, 1] columns every use of them is a lane broadcast.
+
+Measured on a v5e at [6, 2048, 32/8, 128] bf16 causal, 512 x 512 tiles (PERF.md, PR 26):
+forward 2.6 ms, dQ 3.5 ms, dK/dV 3.4 ms a call, which is 50 / 57 / 76 % of the MXU's
+bf16 peak on the products the kernels execute (the masked halves of diagonal tiles
+included) and 40 / 45 / 60 % on the products causal attention needs.
 """
 from __future__ import annotations
 
@@ -26,6 +41,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 # tile sizes live in the flag registry: CONFIG.flash_block_q / flash_block_kv
 NEG_INF = -1e30
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract the minor dimension of both
+_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
 def _block_sizes(sq: int, skv: int, bq: int, bkv: int):
@@ -40,6 +57,78 @@ def _interpret() -> bool:
     return jax.default_backend() in ("cpu", "gpu")
 
 
+def _lanes_to(x, n: int):
+    """[rows, 128] with equal lanes -> [rows, n]."""
+    if n % 128 == 0:
+        return x if n == 128 else jnp.tile(x, (1, n // 128))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _lane_pad(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def _rows(x, block: int):
+    """[..., S] -> [..., S // block, 1, P]: a block of sequence positions as one lane
+    vector, P the block rounded up to whole vregs (a short single-tile sequence need
+    not be a multiple of 128; the kernels read `[:, :block]`)."""
+    x = x.reshape(*x.shape[:-1], x.shape[-1] // block, 1, block)
+    pad = _lane_pad(block) - block
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def _pallas_call(kernel, *, name: str, **kw):
+    """A four-dimensional grid whose last dimension accumulates. `name` is the
+    operation's name in the device trace (the benchmark's kernel metrics select by it)."""
+    return pl.pallas_call(
+        kernel, name=name, interpret=_interpret(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+        **kw)
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+# ------------------------------------------------------------- where a tile lies
+
+
+def _last_kv_block(qi, bq: int, bkv: int):
+    """The last kv block a causal q block attends to."""
+    return (qi * bq + (bq - 1)) // bkv
+
+
+def _first_q_block(kj, bq: int, bkv: int):
+    """The first q block that attends to a causal kv block."""
+    return (kj * bkv) // bq
+
+
+def _for_tile(causal: bool, qi, kj, bq: int, bkv: int, tile) -> None:
+    """Run `tile()` unless tile (qi, kj) lies wholly above the causal diagonal."""
+    if causal:
+        pl.when(kj <= _last_kv_block(qi, bq, bkv))(tile)
+    else:
+        tile()
+
+
+def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, seg_row):
+    """Which scores of a tile stay (None: all). `q_axis` is the axis query positions
+    run along; `seg_col` [rows, 128] and `seg_row` [1, >= cols] are the segment ids
+    of the tile's rows and columns. Every computed tile builds the causal mask, also
+    those the diagonal does not cross: a second, maskless tile body moved no kernel
+    by 0.3 % on the chip (PERF.md, PR 26)."""
+    keep = None
+    if causal:  # kv position <= q position
+        ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+                 - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+        keep = ahead >= kj * bkv - qi * bq
+    if seg_col is not None:
+        same = seg_col[:, :1] == seg_row[:][:, :shape[1]]
+        keep = same if keep is None else (keep & same)
+    return keep
+
+
 # ------------------------------------------------------------------- forward kernel
 
 
@@ -48,12 +137,13 @@ def _fwd_kernel(
     k_ref,  # [bkv, D]
     v_ref,  # [bkv, D]
     seg_q_ref,  # [bq, 128] or None
-    seg_kv_ref,  # [bkv, 128] or None
+    seg_kv_ref,  # [1, bkv] or None
     o_ref,  # [bq, D]
-    lse_ref,  # [bq, 128] (lanes replicated)
-    m_scr,  # VMEM [bq, 128] f32
-    l_scr,  # VMEM [bq, 128] f32
+    lse_ref,  # [1, P]
+    m_scr,  # VMEM [bq, 128] f32, lanes equal
+    l_scr,  # VMEM [bq, 128] f32, lanes equal
     acc_scr,  # VMEM [bq, D] f32
+    lse_scr,  # VMEM [P, 128] f32
     *,
     scale: float,
     causal: bool,
@@ -70,62 +160,72 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _compute():
-        q = q_ref[:]
-        k = k_ref[:]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [bq, bkv]
-        s = s * scale
-
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0) + qi * bq
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1) + kj * bkv
-        if causal:
-            s = jnp.where(cols <= rows, s, NEG_INF)
-        if seg_q_ref is not None:
-            seg_q = seg_q_ref[:, :1]  # [bq, 1]
-            seg_kv = seg_kv_ref[:, :1]  # [bkv, 1]
-            s = jnp.where(seg_q == seg_kv.T, s, NEG_INF)
-
-        m_prev = m_scr[:, :1]  # [bq, 1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+    def tile():
+        v = v_ref[:]
+        s = _dot(q_ref[:], k_ref[:], _NT) * scale  # [bq, bkv]
+        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref, seg_kv_ref)
+        if keep is not None:
+            s = jnp.where(keep, s, NEG_INF)
+        # The running statistics stay 128 equal lanes wide: as [bq, 1] columns every
+        # use of them is a lane broadcast, work done once a row a step, which bound
+        # this kernel whatever the tile's width (PERF.md, PR 26).
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)  # [bq, bkv]
-        l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        p = jnp.exp(s - _lanes_to(m_new, s.shape[1]))
+        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[:] = m_new
+        acc_scr[:] = acc_scr[:] * _lanes_to(alpha, v.shape[1]) + _dot(p.astype(v.dtype), v, _NN)
 
-        acc = acc_scr[:] * alpha
-        acc = acc + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[:], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[:] = acc
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-
-    if causal:
-        # Skip tiles strictly above the diagonal.
-        @pl.when(kj * bkv <= qi * bq + (bq - 1))
-        def _():
-            _compute()
-    else:
-        _compute()
+    _for_tile(causal, qi, kj, bq, bkv, tile)
 
     @pl.when(kj == nk - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[:] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
-        lse = m_scr[:, :1] + jnp.log(l_safe)
-        lse_ref[:] = jnp.broadcast_to(lse, lse_ref.shape).astype(lse_ref.dtype)
+        o_ref[:] = (acc_scr[:] / _lanes_to(l_safe, acc_scr.shape[1])).astype(o_ref.dtype)
+        lse_scr[:bq] = m_scr[:] + jnp.log(l_safe)
+        lse_ref[:] = lse_scr[:].T[:1]  # rows become lanes; those past bq are never read
+
+
+def _q_major_specs(d, n_rep, causal, bq, bkv, has_seg):
+    """BlockSpecs of the forward and dQ grids (b, h, q block, kv block): q-side,
+    kv-side, per-row statistics (`_rows`: a lane vector a q block), and the segment
+    ids of rows and columns."""
+
+    def kv_block(qi, kj):  # a tile above the diagonal names the last block used
+        return jnp.minimum(kj, _last_kv_block(qi, bq, bkv)) if causal else kj
+
+    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, bkv, d), lambda bi, hi, qi, kj: (bi, hi // n_rep, kv_block(qi, kj), 0))
+    stat_spec = pl.BlockSpec((1, 1, 1, 1, _lane_pad(bq)), lambda bi, hi, qi, kj: (bi, hi, qi, 0, 0))
+    seg_specs = []
+    if has_seg:
+        seg_specs = [
+            pl.BlockSpec((1, bq, 128), lambda bi, hi, qi, kj: (bi, qi, 0)),
+            pl.BlockSpec((1, 1, 1, _lane_pad(bkv)),
+                         lambda bi, hi, qi, kj: (bi, kv_block(qi, kj), 0, 0)),
+        ]
+    return q_spec, kv_spec, stat_spec, seg_specs
+
+
+def _unpack(refs, n_in: int, has_seg: bool):
+    """(inputs, segment-id pair, the remaining refs), the inputs' blocks indexed
+    down to their last two dimensions."""
+    def tile(r):
+        return r.at[(0,) * (len(r.shape) - 2)]
+
+    n_seg = 2 if has_seg else 0
+    segs = [tile(r) for r in refs[n_in:n_in + n_seg]] or [None, None]
+    return [tile(r) for r in refs[:n_in]], segs, refs[n_in + n_seg:]
 
 
 def _fwd(
     q: jax.Array,  # [B, H, Sq, D]
     k: jax.Array,  # [B, Hkv, Skv, D]
     v: jax.Array,
-    seg_q: Optional[jax.Array],  # [B, Sq, 128] int32
-    seg_kv: Optional[jax.Array],  # [B, Skv, 128]
+    seg: Optional[dict],  # _segment_lanes(), or None
     scale: float,
     causal: bool,
     bq: int,
@@ -133,76 +233,46 @@ def _fwd(
 ):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
-    n_rep = h // hkv
     bq, bkv = _block_sizes(sq, skv, bq, bkv)
-    grid = (b, h, pl.cdiv(sq, bq), pl.cdiv(skv, bkv))
-
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, bkv, d), lambda bi, hi, qi, kj: (bi, hi // n_rep, kj, 0))
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q, k, v]
-    if seg_q is not None:
-        in_specs.append(pl.BlockSpec((1, bq, 128), lambda bi, hi, qi, kj: (bi, qi, 0)))
-        in_specs.append(pl.BlockSpec((1, bkv, 128), lambda bi, hi, qi, kj: (bi, kj, 0)))
-        args += [seg_q, seg_kv]
+    has_seg = seg is not None
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, bq, bkv, has_seg)
+    args = [q, k, v] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def kernel(*refs):
-        if seg_q is not None:
-            q_ref, k_ref, v_ref, sq_ref, skv_ref, o_ref, lse_ref, m_s, l_s, a_s = refs
-            sq_r, skv_r = sq_ref.at[0], skv_ref.at[0]
-        else:
-            q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, a_s = refs
-            sq_r = skv_r = None
-        _fwd_kernel(
-            q_ref.at[0, 0],
-            k_ref.at[0, 0],
-            v_ref.at[0, 0],
-            sq_r,
-            skv_r,
-            o_ref.at[0, 0],
-            lse_ref.at[0, 0],
-            m_s,
-            l_s,
-            a_s,
-            scale=scale,
-            causal=causal,
-            bq=bq,
-            bkv=bkv,
-        )
+        ins, segs, (o_ref, lse_ref, *scratch) = _unpack(refs, 3, has_seg)
+        _fwd_kernel(*ins, *segs, o_ref.at[0, 0], lse_ref.at[0, 0, 0], *scratch,
+                    scale=scale, causal=causal, bq=bq, bkv=bkv)
 
-    out, lse = pl.pallas_call(
+    out, lse = _pallas_call(
         kernel,
-        name="flash_attention_fwd",  # the operation's name in the device trace
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
-            pl.BlockSpec((1, 1, bq, 128), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
-        ],
+        name="flash_attention_fwd",
+        grid=(b, h, sq // bq, skv // bkv),
+        in_specs=[q_spec, kv_spec, kv_spec] + seg_specs,
+        out_specs=[q_spec, stat_spec],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sq // bq, 1, _lane_pad(bq)), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((_lane_pad(bq), 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-        interpret=_interpret(),
     )(*args)
-    return out, lse[..., 0]  # lse: [B, H, Sq]
+    return out, lse  # lse: as `_rows` lays [B, H, Sq] out
 
 
 # ------------------------------------------------------------------ backward kernels
 
 
 def _bwd_dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_q_ref, seg_kv_ref, dq_ref, dq_scr,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_q_ref, seg_kv_ref, dq_ref,
+    dq_scr, lse_scr, delta_scr,
     *, scale, causal, bq, bkv,
 ):
+    """lse_ref and delta_ref are [1, P]; the tile [bq, bkv] wants them down its rows,
+    so the q block's first step turns them once into [bq, 128] with equal lanes."""
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -210,41 +280,21 @@ def _bwd_dq_kernel(
     @pl.when(kj == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
+        for row_ref, col_scr in ((lse_ref, lse_scr), (delta_ref, delta_scr)):
+            col_scr[:] = jnp.broadcast_to(row_ref[:], (128, row_ref.shape[1])).T[:bq]
 
-    def _compute():
-        q = q_ref[:]
+    def tile():
         k = k_ref[:]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0) + qi * bq
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1) + kj * bkv
-        mask = None
-        if causal:
-            mask = cols <= rows
-        if seg_q_ref is not None:
-            m2 = seg_q_ref[:, :1] == seg_kv_ref[:, :1].T
-            mask = m2 if mask is None else (mask & m2)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[:, :1])  # [bq, bkv]
-        do = do_ref[:].astype(jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_ref[:].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[:, :1]) * scale
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        s = _dot(q_ref[:], k, _NT) * scale  # [bq, bkv]
+        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref, seg_kv_ref)
+        if keep is not None:
+            s = jnp.where(keep, s, NEG_INF)
+        p = jnp.exp(s - _lanes_to(lse_scr[:], s.shape[1]))
+        dp = _dot(do_ref[:], v_ref[:], _NT)
+        ds = p * (dp - _lanes_to(delta_scr[:], s.shape[1])) * scale
+        dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    if causal:
-        @pl.when(kj * bkv <= qi * bq + (bq - 1))
-        def _():
-            _compute()
-    else:
-        _compute()
+    _for_tile(causal, qi, kj, bq, bkv, tile)
 
     @pl.when(kj == nk - 1)
     def _():
@@ -252,195 +302,159 @@ def _bwd_dq_kernel(
 
 
 def _bwd_dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_q_ref, seg_kv_ref,
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_kv_ref, seg_q_ref,
     dk_ref, dv_ref, dk_scr, dv_scr,
-    *, scale, causal, bq, bkv,
+    *, scale, causal, bq, bkv, nq,
 ):
+    """One (kv block, query head of its group, q block) step, on the transposed tile
+    [bkv, bq]: lse_ref and delta_ref are [1, P]."""
     kj = pl.program_id(2)
-    qi = pl.program_id(3)
-    nq = pl.num_programs(3)
+    t = pl.program_id(3)  # (query head within the group, q block), q block minor
+    nt = pl.num_programs(3)
+    qi = jax.lax.rem(t, nq)
 
-    @pl.when(qi == 0)
+    @pl.when(t == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _compute():
+    def tile():
         q = q_ref[:]
-        k = k_ref[:]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale  # [bq, bkv]
-        rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0) + qi * bq
-        cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1) + kj * bkv
-        mask = None
-        if causal:
-            mask = cols <= rows
-        if seg_q_ref is not None:
-            m2 = seg_q_ref[:, :1] == seg_kv_ref[:, :1].T
-            mask = m2 if mask is None else (mask & m2)
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[:, :1])  # [bq, bkv]
-        do = do_ref[:].astype(jnp.float32)
-        dv_scr[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        dp = jax.lax.dot_general(
-            do, v_ref[:].astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[:, :1]) * scale  # [bq, bkv]
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q.astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        do = do_ref[:]
+        st = _dot(k_ref[:], q, _NT) * scale  # [bkv, bq]
+        keep = _keep(st.shape, 1, qi, kj, bq, bkv, causal, seg_kv_ref, seg_q_ref)
+        if keep is not None:
+            st = jnp.where(keep, st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[:][:, :bq])
+        dv_scr[:] += _dot(pt.astype(do.dtype), do, _NN)
+        dpt = _dot(v_ref[:], do, _NT)
+        dst = pt * (dpt - delta_ref[:][:, :bq]) * scale
+        dk_scr[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-    if causal:
-        @pl.when(qi * bq + (bq - 1) >= kj * bkv)
-        def _():
-            _compute()
-    else:
-        _compute()
+    _for_tile(causal, qi, kj, bq, bkv, tile)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(t == nt - 1)
     def _():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(
-    q, k, v, seg_q, seg_kv, out, lse, dout, scale, causal, bq, bkv
-):
+def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     n_rep = h // hkv
-    bq_, bkv_ = _block_sizes(sq, skv, bq, bkv)
+    bq, bkv = _block_sizes(sq, skv, bq, bkv)
+    nq, nk = sq // bq, skv // bkv
+    has_seg = seg is not None
 
     # delta_i = sum_d(dO * O): rowwise, cheap in XLA.
-    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)  # [B,H,Sq]
-    lse_l = jnp.broadcast_to(lse[..., None], (*lse.shape, 128)).astype(jnp.float32)
-    delta_l = jnp.broadcast_to(delta[..., None], (*delta.shape, 128))
+    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    stats = [lse, _rows(delta, bq)]
 
-    # --- dQ pass: grid (b, h, nq, nk) ---
-    q_spec = pl.BlockSpec((1, 1, bq_, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0))
-    kv_spec = pl.BlockSpec((1, 1, bkv_, d), lambda bi, hi, qi, kj: (bi, hi // n_rep, kj, 0))
-    row_spec = pl.BlockSpec((1, 1, bq_, 128), lambda bi, hi, qi, kj: (bi, hi, qi, 0))
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec]
-    args = [q, k, v, dout, lse_l, delta_l]
-    has_seg = seg_q is not None
-    if has_seg:
-        in_specs.append(pl.BlockSpec((1, bq_, 128), lambda bi, hi, qi, kj: (bi, qi, 0)))
-        in_specs.append(pl.BlockSpec((1, bkv_, 128), lambda bi, hi, qi, kj: (bi, kj, 0)))
-        args += [seg_q, seg_kv]
+    # --- dQ pass: grid (b, h, nq, nk)
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, bq, bkv, has_seg)
+    args = [q, k, v, dout, *stats] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def dq_kernel(*refs):
-        if has_seg:
-            (qr, kr, vr, dor, lser, deltar, sqr, skvr, dqr, dqs) = refs
-            sq_r, skv_r = sqr.at[0], skvr.at[0]
-        else:
-            (qr, kr, vr, dor, lser, deltar, dqr, dqs) = refs
-            sq_r = skv_r = None
-        _bwd_dq_kernel(
-            qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], dor.at[0, 0], lser.at[0, 0],
-            deltar.at[0, 0], sq_r, skv_r, dqr.at[0, 0], dqs,
-            scale=scale, causal=causal, bq=bq_, bkv=bkv_,
-        )
+        ins, segs, (dq_ref, *scratch) = _unpack(refs, 6, has_seg)
+        _bwd_dq_kernel(*ins, *segs, dq_ref.at[0, 0], *scratch,
+                       scale=scale, causal=causal, bq=bq, bkv=bkv)
 
-    dq = pl.pallas_call(
+    dq = _pallas_call(
         dq_kernel,
-        name="flash_attention_bwd_dq",  # the operation's name in the device trace
-        grid=(b, h, pl.cdiv(sq, bq_), pl.cdiv(skv, bkv_)),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, bq_, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0)),
+        name="flash_attention_bwd_dq",
+        grid=(b, h, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec] + seg_specs,
+        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq_, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-        interpret=_interpret(),
+        scratch_shapes=[
+            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+            pltpu.VMEM((bq, 128), jnp.float32),
+        ],
     )(*args)
 
-    # --- dK/dV pass: grid (b, h, nk, nq); kv head accumulates over its rep group ---
-    # For GQA we accumulate per q-head then sum over the rep group in XLA.
-    q_spec2 = pl.BlockSpec((1, 1, bq_, d), lambda bi, hi, kj, qi: (bi, hi, qi, 0))
-    kv_spec2 = pl.BlockSpec((1, 1, bkv_, d), lambda bi, hi, kj, qi: (bi, hi // n_rep, kj, 0))
-    row_spec2 = pl.BlockSpec((1, 1, bq_, 128), lambda bi, hi, kj, qi: (bi, hi, qi, 0))
-    in_specs2 = [q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2]
-    args2 = [q, k, v, dout, lse_l, delta_l]
+    # --- dK/dV pass: grid (b, kv head, nk, group's query heads x nq), the last summed in
+    # the kernel. A q block no row of which sees the kv block names the first that does.
+    def q_side(t, kj):  # (query head within the group, q block)
+        qi = jax.lax.rem(t, nq)
+        return t // nq, (jnp.maximum(qi, _first_q_block(kj, bq, bkv)) if causal else qi)
+
+    def q_map(bi, hk, kj, t):
+        r, qi = q_side(t, kj)
+        return (bi, hk * n_rep + r, qi, 0)
+
+    def stat_map(bi, hk, kj, t):
+        r, qi = q_side(t, kj)
+        return (bi, hk * n_rep + r, qi, 0, 0)
+
+    q_spec2 = pl.BlockSpec((1, 1, bq, d), q_map)
+    kv_spec2 = pl.BlockSpec((1, 1, bkv, d), lambda bi, hk, kj, t: (bi, hk, kj, 0))
+    stat_spec2 = pl.BlockSpec((1, 1, 1, 1, _lane_pad(bq)), stat_map)
+    in_specs2 = [q_spec2, kv_spec2, kv_spec2, q_spec2, stat_spec2, stat_spec2]
+    args2 = [q, k, v, dout, *stats]
     if has_seg:
-        in_specs2.append(pl.BlockSpec((1, bq_, 128), lambda bi, hi, kj, qi: (bi, qi, 0)))
-        in_specs2.append(pl.BlockSpec((1, bkv_, 128), lambda bi, hi, kj, qi: (bi, kj, 0)))
-        args2 += [seg_q, seg_kv]
+        in_specs2 += [
+            pl.BlockSpec((1, bkv, 128), lambda bi, hk, kj, t: (bi, kj, 0)),
+            pl.BlockSpec((1, 1, 1, _lane_pad(bq)),
+                         lambda bi, hk, kj, t: (bi, q_side(t, kj)[1], 0, 0)),
+        ]
+        args2 += [seg["kv_col"], _rows(seg["q"], bq)]
 
     def dkv_kernel(*refs):
-        if has_seg:
-            (qr, kr, vr, dor, lser, deltar, sqr, skvr, dkr, dvr, dks, dvs) = refs
-            sq_r, skv_r = sqr.at[0], skvr.at[0]
-        else:
-            (qr, kr, vr, dor, lser, deltar, dkr, dvr, dks, dvs) = refs
-            sq_r = skv_r = None
-        _bwd_dkv_kernel(
-            qr.at[0, 0], kr.at[0, 0], vr.at[0, 0], dor.at[0, 0], lser.at[0, 0],
-            deltar.at[0, 0], sq_r, skv_r, dkr.at[0, 0], dvr.at[0, 0], dks, dvs,
-            scale=scale, causal=causal, bq=bq_, bkv=bkv_,
-        )
+        ins, segs, (dk_ref, dv_ref, dk_s, dv_s) = _unpack(refs, 6, has_seg)
+        _bwd_dkv_kernel(*ins, *segs, dk_ref.at[0, 0], dv_ref.at[0, 0], dk_s, dv_s,
+                        scale=scale, causal=causal, bq=bq, bkv=bkv, nq=nq)
 
-    dk_per_h, dv_per_h = pl.pallas_call(
+    dk, dv = _pallas_call(
         dkv_kernel,
-        name="flash_attention_bwd_dkv",  # the operation's name in the device trace
-        grid=(b, h, pl.cdiv(skv, bkv_), pl.cdiv(sq, bq_)),
+        name="flash_attention_bwd_dkv",
+        grid=(b, hkv, nk, n_rep * nq),
         in_specs=in_specs2,
-        out_specs=[
-            pl.BlockSpec((1, 1, bkv_, d), lambda bi, hi, kj, qi: (bi, hi, kj, 0)),
-            pl.BlockSpec((1, 1, bkv_, d), lambda bi, hi, kj, qi: (bi, hi, kj, 0)),
-        ],
+        out_specs=[kv_spec2, kv_spec2],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, skv, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, skv, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, skv, d), k.dtype),
+            jax.ShapeDtypeStruct((b, hkv, skv, d), v.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bkv_, d), jnp.float32),
-            pltpu.VMEM((bkv_, d), jnp.float32),
+            pltpu.VMEM((bkv, d), jnp.float32),
+            pltpu.VMEM((bkv, d), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-        interpret=_interpret(),
     )(*args2)
-
-    if n_rep > 1:
-        dk = dk_per_h.reshape(b, hkv, n_rep, skv, d).sum(axis=2)
-        dv = dv_per_h.reshape(b, hkv, n_rep, skv, d).sum(axis=2)
-    else:
-        dk, dv = dk_per_h, dv_per_h
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    return dq, dk, dv
 
 
 # ----------------------------------------------------------------------- public API
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_bhsd(q, k, v, seg_lanes, scale, causal, bq, bkv):
-    seg_q, seg_kv = (seg_lanes if seg_lanes is not None else (None, None))
-    out, _ = _fwd(q, k, v, seg_q, seg_kv, scale, causal, bq, bkv)
+def _flash_bhsd(q, k, v, seg, scale, causal, bq, bkv):
+    out, _ = _fwd(q, k, v, seg, scale, causal, bq, bkv)
     return out
 
 
-def _flash_fwd_rule(q, k, v, seg_lanes, scale, causal, bq, bkv):
-    seg_q, seg_kv = (seg_lanes if seg_lanes is not None else (None, None))
-    out, lse = _fwd(q, k, v, seg_q, seg_kv, scale, causal, bq, bkv)
-    return out, (q, k, v, seg_lanes, out, lse)
+def _flash_fwd_rule(q, k, v, seg, scale, causal, bq, bkv):
+    out, lse = _fwd(q, k, v, seg, scale, causal, bq, bkv)
+    return out, (q, k, v, seg, out, lse)
 
 
 def _flash_bwd_rule(scale, causal, bq, bkv, res, dout):
-    q, k, v, seg_lanes, out, lse = res
-    seg_q, seg_kv = (seg_lanes if seg_lanes is not None else (None, None))
-    dq, dk, dv = _bwd(q, k, v, seg_q, seg_kv, out, lse, dout, scale, causal, bq, bkv)
+    q, k, v, seg, out, lse = res
+    dq, dk, dv = _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv)
     return dq, dk, dv, None
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _segment_lanes(segment_ids: jax.Array, sq: int) -> dict:
+    """Segment ids of the q and the kv side, as they are ([B, S]; `_rows` lays them
+    along a tile's columns) and down a tile's rows (`*_col`, 128 equal lanes)."""
+    seg_kv = segment_ids.astype(jnp.int32)
+    out = {"q": seg_kv[:, -sq:], "kv": seg_kv}
+    for side in ("q", "kv"):
+        out[f"{side}_col"] = jnp.broadcast_to(out[side][:, :, None], (*out[side].shape, 128))
+    return out
 
 
 def flash_attention(
@@ -465,15 +479,6 @@ def flash_attention(
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    seg_lanes = None
-    if segment_ids is not None:
-        sq = q.shape[1]
-        seg_q = jnp.broadcast_to(
-            segment_ids[:, -sq:, None].astype(jnp.int32), (q.shape[0], sq, 128)
-        )
-        seg_kv = jnp.broadcast_to(
-            segment_ids[:, :, None].astype(jnp.int32), (*segment_ids.shape, 128)
-        )
-        seg_lanes = (seg_q, seg_kv)
-    out = _flash_bhsd(qt, kt, vt, seg_lanes, scale, causal, block_q, block_kv)
+    seg = None if segment_ids is None else _segment_lanes(segment_ids, q.shape[1])
+    out = _flash_bhsd(qt, kt, vt, seg, scale, causal, block_q, block_kv)
     return out.transpose(0, 2, 1, 3)

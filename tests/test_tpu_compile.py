@@ -55,9 +55,11 @@ def _shapes(tree, sharding):
 
 
 @pytest.mark.parametrize("b,s,h,kv,segments", [
-    (6, 2048, 32, 8, False),   # llama8b-geom2, the smoke's train batch
+    (6, 2048, 32, 8, False),   # llama8b-geom2, the smoke's train batch; mistral7b-train-1chip
+    (4, 2048, 32, 8, False),   # a chip's shard of mistral7b-train-fsdp4
     (8, 2048, 12, 6, False),   # llama-500m
     (2, 4096, 32, 8, True),    # packed documents
+    (2, 200, 32, 8, True),     # one tile, not a multiple of 128: the lane vectors are padded
 ])
 def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
     from ray_tpu.ops.flash_attention import flash_attention
