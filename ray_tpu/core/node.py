@@ -2735,12 +2735,17 @@ class Cluster:
         t.start()
 
     def _kill_worker(self, w: WorkerHandle, err: Exception) -> None:
+        # The death is settled before the process is signalled. The other way
+        # round, the router thread can meet the pipe's EOF first and declare
+        # the death itself ("died unexpectedly"), and this call then returns
+        # while that thread is still at it: after ray_tpu.kill() the actor's
+        # name was still taken and calls queued on the corpse.
+        self._on_worker_death(w, err)
         try:
             w.process.terminate()
         # graftlint: allow[swallowed-exception] best-effort cleanup of a target that may already be dead/gone
         except Exception:
             pass
-        self._on_worker_death(w, err)
 
     def get_named_actor_handle(self, name: str, namespace: str = ""):
         actor_id = self.gcs.get_named_actor(name, namespace)

@@ -88,6 +88,8 @@ class BackendExecutor:
     ) -> None:
         assert self.worker_group is not None, "call start() first"
         self.backend.on_training_start(self.worker_group, self.backend_config)
+        if self.checkpoint_manager is not None:
+            self.checkpoint_manager.resume_point = checkpoint
         node_ranks = self.worker_group.node_ranks()
         local_counts: Dict[int, int] = {}
         refs = []

@@ -34,6 +34,10 @@ class CheckpointManager:
         self.config = config or CheckpointConfig()
         self._tracked: List[_TrackedCheckpoint] = []
         self._next_index = 0
+        # What the running attempt's workers were started from. Reports are no
+        # barrier: a rank still downloading it must not lose it to the
+        # retention of the checkpoints a faster rank has reported since.
+        self.resume_point: Optional[Checkpoint] = None
         # Rerunning with the same RunConfig.name must continue the index sequence, not
         # collide with (and nest inside) existing checkpoint_NNNNNN directories.
         for entry in sorted(storage.listdir(self.storage_dir) if self._remote
@@ -91,6 +95,9 @@ class CheckpointManager:
         # Never delete the most recent checkpoint — it's the resume point.
         latest = max(self._tracked, key=lambda t: t.index)
         keep.add(id(latest))
+        if self.resume_point is not None:
+            keep.update(id(t) for t in self._tracked
+                        if t.checkpoint.path == self.resume_point.path)
         survivors = []
         for t in self._tracked:
             if id(t) in keep:

@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait as futures_wait
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -431,9 +431,12 @@ class StageComm:
             stale = [k for k in self.plane.store._bufs if k.startswith("mpmd:")]
         for k in stale:
             self.plane.retract(k)
-        for fut in self._futures.values():
-            fut.cancel()
+        # A prefetch already pulling cannot be cancelled: it meets the same
+        # verdict at its next probe, one abort-poll interval away (or its own
+        # deadline, after a timeout). Counted as in flight until then.
+        pulling = [f for f in self._futures.values() if not f.cancel()]
         self._futures.clear()
+        futures_wait(pulling, timeout=self._op_timeout())
         with self._lock:
             self._published.clear()
 

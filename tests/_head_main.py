@@ -5,10 +5,35 @@ Runs the cluster head with a node server (agents join), a client server
 incarnation is reachable at the same addresses.
 """
 import os
+import select
+import subprocess
 import sys
 import time
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+
+
+def spawn_head(env, node_port, client_port, timeout=60):
+    """Start this file as a head process and return it once it has said
+    HEAD_READY; a head that says nothing, or exits, fails the caller within
+    `timeout` with what it did print."""
+    proc = subprocess.Popen(
+        [sys.executable, os.path.abspath(__file__), str(node_port), str(client_port)],
+        env=env, stdout=subprocess.PIPE)
+    deadline = time.monotonic() + timeout
+    said = b""
+    while b"HEAD_READY" not in said:
+        ready, _, _ = select.select([proc.stdout], [], [],
+                                    max(0.0, deadline - time.monotonic()))
+        chunk = os.read(proc.stdout.fileno(), 4096) if ready else b""
+        if not chunk:
+            proc.kill()
+            raise AssertionError(
+                f"head on port {node_port} never said HEAD_READY within "
+                f"{timeout} s (exit code {proc.poll()}); it printed {said!r}")
+        said += chunk
+    return proc
+
 
 if __name__ == "__main__":
     import jax

@@ -130,6 +130,29 @@ def test_kill_actor(rt):
         rt.get(v.ping.remote(), timeout=10)
 
 
+def test_kill_returns_with_the_actor_dead_and_its_name_free(rt, monkeypatch):
+    """ray_tpu.kill() is synchronous: when it returns the name is free and a
+    call fails with the kill as its cause, however fast the router thread is to
+    meet the dead worker's pipe (here it is given half a second's start)."""
+    from ray_tpu.core import global_state
+
+    @rt.remote
+    class Named:
+        def ping(self):
+            return "alive"
+
+    a = Named.options(name="kill-me").remote()
+    assert rt.get(a.ping.remote()) == "alive"
+    process = global_state.try_cluster().actors[a._actor_id].worker.process
+    terminate = process.terminate
+    monkeypatch.setattr(process, "terminate", lambda: (terminate(), time.sleep(0.5)))
+    rt.kill(a)
+    with pytest.raises(ValueError):
+        rt.get_actor("kill-me")
+    with pytest.raises(rt.ActorDiedError, match="killed via ray_tpu.kill"):
+        rt.get(a.ping.remote(), timeout=10)
+
+
 def test_actor_restart(rt):
     @rt.remote(max_restarts=2)
     class Phoenix:

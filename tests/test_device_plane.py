@@ -335,7 +335,10 @@ def test_pd_disagg_unequal_pools_device_path(rt):
     common deployment: big prefill TP, small decode pool): prefill runs tp=2
     inside a 4-device actor, decode inside a 1-device actor. The KV handoff
     STILL rides the device plane — the decode side takes the reshard-fetch
-    path — and the output matches colocated greedy decoding exactly."""
+    path — and the output matches colocated greedy decoding exactly. This is
+    the monolithic export: the paged handoff, which is the default, moves
+    pages over the striped data plane and has no mesh to reshard
+    (tests/test_pd_paged.py), so both engines run with it off."""
     from ray_tpu.llm import JaxLLMEngine, LLMConfig, SamplingParams
 
     prompt = [1, 7, 42, 9]
@@ -352,7 +355,7 @@ def test_pd_disagg_unequal_pools_device_path(rt):
         ref_eng.shutdown()
 
     @rt.remote(runtime_env={"env_vars": {
-        "JAX_PLATFORMS": "cpu",
+        "JAX_PLATFORMS": "cpu", "RAY_TPU_PD_PAGED": "0",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}})
     class Prefill:
         def __init__(self):
@@ -372,7 +375,7 @@ def test_pd_disagg_unequal_pools_device_path(rt):
             return out
 
     @rt.remote(runtime_env={"env_vars": {
-        "JAX_PLATFORMS": "cpu",
+        "JAX_PLATFORMS": "cpu", "RAY_TPU_PD_PAGED": "0",
         "XLA_FLAGS": "--xla_force_host_platform_device_count=1"}})
     class Decode:
         def __init__(self):

@@ -23,6 +23,8 @@ import time
 
 import pytest
 
+from _head_main import spawn_head
+
 
 def _free_port():
     s = socket.socket()
@@ -30,19 +32,6 @@ def _free_port():
     p = s.getsockname()[1]
     s.close()
     return p
-
-
-def _spawn_head(env, node_port, client_port):
-    proc = subprocess.Popen(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "_head_main.py"),
-         str(node_port), str(client_port)],
-        env=env, stdout=subprocess.PIPE, text=True)
-    deadline = time.time() + 60
-    while True:
-        line = proc.stdout.readline()
-        if "HEAD_READY" in line:
-            return proc
-        assert proc.poll() is None and time.time() < deadline, "head never started"
 
 
 @pytest.fixture()
@@ -373,7 +362,7 @@ def test_client_entry_points_raise_typed_after_bounded_reconnect(
     monkeypatch.setenv("RAY_TPU_HEAD_RECONNECT_BACKOFF_S", "0.1")
     env, procs = outage_env
     node_port, client_port = _free_port(), _free_port()
-    head = _spawn_head(env, node_port, client_port)
+    head = spawn_head(env, node_port, client_port)
     procs.append(head)
 
     ray_tpu.init(address=f"ray-tpu://127.0.0.1:{client_port}")
@@ -419,7 +408,7 @@ def test_agent_failpoint_outage_reattaches_to_live_head(outage_env):
 
     env, procs = outage_env
     node_port, client_port = _free_port(), _free_port()
-    head = _spawn_head(env, node_port, client_port)
+    head = spawn_head(env, node_port, client_port)
     procs.append(head)
     agent_env = {**env,
                  "RAY_TPU_FAULT_INJECTION": "head.control.recv=error@n=2",

@@ -18,6 +18,8 @@ import time
 
 import pytest
 
+from _head_main import spawn_head
+
 
 def _free_port():
     s = socket.socket()
@@ -25,19 +27,6 @@ def _free_port():
     p = s.getsockname()[1]
     s.close()
     return p
-
-
-def _spawn_head(env, node_port, client_port):
-    proc = subprocess.Popen(
-        [sys.executable, os.path.join(os.path.dirname(__file__), "_head_main.py"),
-         str(node_port), str(client_port)],
-        env=env, stdout=subprocess.PIPE, text=True)
-    deadline = time.time() + 60
-    while True:
-        line = proc.stdout.readline()
-        if "HEAD_READY" in line:
-            return proc
-        assert proc.poll() is None and time.time() < deadline, "head never started"
 
 
 @pytest.fixture()
@@ -87,7 +76,7 @@ def test_head_restart_actor_and_object_survive(restart_env):
 
     env, procs = restart_env
     node_port, client_port = _free_port(), _free_port()
-    head = _spawn_head(env, node_port, client_port)
+    head = spawn_head(env, node_port, client_port)
     procs.append(head)
     agent = subprocess.Popen(
         [sys.executable, "-m", "ray_tpu.core.node_agent",
@@ -136,7 +125,7 @@ def test_head_restart_actor_and_object_survive(restart_env):
     os.kill(head.pid, signal.SIGKILL)
     head.wait(timeout=10)
     time.sleep(1.0)
-    head2 = _spawn_head(env, node_port, client_port)
+    head2 = spawn_head(env, node_port, client_port)
     procs.append(head2)
 
     # -- after: agent re-attached; actor state + object survived ----------------
@@ -178,7 +167,7 @@ def test_head_restart_new_address_external_journal(restart_env, tmp_path):
     try:
         port_a, client_a = _free_port(), _free_port()
         port_b, client_b = _free_port(), _free_port()
-        head = _spawn_head(env, port_a, client_a)
+        head = spawn_head(env, port_a, client_a)
         procs.append(head)
         agent = subprocess.Popen(
             [sys.executable, "-m", "ray_tpu.core.node_agent",
@@ -218,7 +207,7 @@ def test_head_restart_new_address_external_journal(restart_env, tmp_path):
         os.kill(head.pid, signal.SIGKILL)
         head.wait(timeout=10)
         time.sleep(1.0)
-        head2 = _spawn_head(env, port_b, client_b)
+        head2 = spawn_head(env, port_b, client_b)
         procs.append(head2)
 
         ray_tpu.init(address=f"ray-tpu://127.0.0.1:{client_b}")

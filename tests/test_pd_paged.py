@@ -11,6 +11,7 @@ import time
 import pytest
 
 from ray_tpu.llm import JaxLLMEngine, LLMConfig, SamplingParams
+from ray_tpu.test_utils import wait_for_condition
 
 PROMPT = [1, 7, 42, 99, 5]
 
@@ -57,6 +58,9 @@ def test_paged_handoff_matches_colocated(pd_engines):
     params = _params()
     want = colo.generate_sync(PROMPT, params).token_ids
 
+    # the plane is one a process: whatever an earlier file of this xdist
+    # worker left exported is not this test's to drain
+    others = plane().stats()["exports_live"]
     pre = prefill.prefill_only(PROMPT, params)
     assert isinstance(pre["kv_handle"], PagedKVHandle)
     assert pre["kv_handle"].n_pages >= 1
@@ -64,14 +68,11 @@ def test_paged_handoff_matches_colocated(pd_engines):
 
     # release-ack propagation is async (arm channel + listener); the TTL
     # backstop is minutes out, so draining within seconds proves the ack path
-    deadline = time.monotonic() + 10
-    while time.monotonic() < deadline:
-        if (prefill.metrics()["pd_exports_live"] == 0
-                and plane().stats()["exports_live"] == 0):
-            break
-        time.sleep(0.05)
-    assert prefill.metrics()["pd_exports_live"] == 0
-    assert plane().stats()["exports_live"] == 0
+    wait_for_condition(
+        lambda: (prefill.metrics()["pd_exports_live"] == 0
+                 and plane().stats()["exports_live"] == others),
+        timeout=10,
+        message="the decode side's release ack never drained this test's export")
 
 
 def test_first_token_streams_before_pages_land(pd_engines):

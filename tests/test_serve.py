@@ -261,9 +261,8 @@ def test_long_poll_listen_for_change(rt):
 
 
 def test_handle_sees_scale_up_via_push(rt):
-    import time
-
     from ray_tpu import serve
+    from ray_tpu.test_utils import wait_for_condition
 
     @serve.deployment(num_replicas=1)
     class E:
@@ -276,14 +275,13 @@ def test_handle_sees_scale_up_via_push(rt):
         from ray_tpu.serve.handle import _lp_registry
 
         serve.run(E.options(num_replicas=3).bind(), name="push-app")
-        deadline = time.time() + 15
-        while time.time() < deadline:
+
+        def pushed():
             entry = _lp_registry.get(("push-app", "E"))
-            if entry is not None and entry.replicas is not None and len(entry.replicas) == 3:
-                break
-            time.sleep(0.2)
-        else:
-            raise AssertionError("push update never arrived")
+            return entry is not None and entry.replicas is not None and len(entry.replicas) == 3
+
+        wait_for_condition(pushed, timeout=15, interval=0.2,
+                           message="the push of three replicas never reached the handle")
         assert h.remote(3).result() == 6
     finally:
         serve.delete("push-app")

@@ -90,6 +90,29 @@ def test_checkpoint_manager_remote_retention_and_resume_scan(mock_root, tmp_path
         assert json.load(open(os.path.join(d, "state.json")))["step"] == 2
 
 
+def test_retention_keeps_what_the_running_attempt_resumed_from(mock_root, tmp_path):
+    """Reports are no barrier: rank 0 can report (and the controller register)
+    num_to_keep checkpoints while another rank still downloads the one both
+    were started from. Retention must not take it from under that rank."""
+    uri = "mock://runs/exp2"
+    mgr = CheckpointManager(uri, CheckpointConfig(num_to_keep=2))
+    for step in range(2):
+        mgr.register(Checkpoint(_make_local_ckpt(tmp_path, step)), {"step": step})
+    rerun = CheckpointManager(uri, CheckpointConfig(num_to_keep=2))
+    rerun.resume_point = rerun.latest_checkpoint
+    for step in range(2, 4):
+        rerun.register(Checkpoint(_make_local_ckpt(tmp_path, step)), {"step": step})
+    assert sorted(n for n in storage.listdir(uri) if n.startswith("checkpoint_")) == [
+        "checkpoint_000001", "checkpoint_000002", "checkpoint_000003"]
+    with rerun.resume_point.as_directory() as d:
+        assert json.load(open(os.path.join(d, "state.json")))["step"] == 1
+    # the next attempt starts from the newest, and the old pin goes
+    rerun.resume_point = rerun.latest_checkpoint
+    rerun.register(Checkpoint(_make_local_ckpt(tmp_path, 4)), {"step": 4})
+    assert sorted(n for n in storage.listdir(uri) if n.startswith("checkpoint_")) == [
+        "checkpoint_000003", "checkpoint_000004"]
+
+
 def test_trainer_with_remote_storage_and_resume(rt, tmp_path):
     """End-to-end: workers UPLOAD checkpoints to mock:// storage on report;
     the result carries URIs; a rerun under the same name resumes from the URI

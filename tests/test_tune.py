@@ -112,14 +112,31 @@ def test_checkpoint_restore_on_failure(rt):
     assert r.metrics["training_iteration"] == 6
 
 
-def test_pbt_exploits(rt):
+def test_pbt_exploits(rt, tmp_path):
+    started = str(tmp_path)
+
     def objective(config):
+        import os
+        import time
+
+        from ray_tpu.test_utils import wait_for_condition
+
+        # PBT exploits within a population. On a loaded machine one trial's
+        # actor can come up seconds before the other's, and 20 unpaced
+        # iterations take milliseconds: the trials start their iterations
+        # together and take a second over them, or the weak one has finished
+        # before there is anyone to copy from.
+        open(os.path.join(started, f"lr{config['lr']}"), "w").close()
+        wait_for_condition(lambda: len(os.listdir(started)) >= 2, timeout=120,
+                           interval=0.005,
+                           message="the other trial of the population never started")
         score = 0.0
         ck = tune.get_checkpoint()
         if ck is not None:
             score = ck["score"]
         lr = config["lr"]
         for i in range(20):
+            time.sleep(0.05)
             score += lr  # higher lr -> faster score growth
             tune.report({"score": score}, checkpoint={"score": score})
 
