@@ -9,30 +9,43 @@ public wrapper takes BSHD like the rest of the framework. GQA is handled in the
 BlockSpec index maps (kv head = q head // n_rep) — repeated KV heads are never
 materialized.
 
-Backward follows the standard two-kernel split: one pass computes dQ (grid over kv
-blocks inner), one computes dK/dV (q blocks inner), both recomputing the block's
-probabilities from the saved logsumexp. The dK/dV pass works on the TRANSPOSED tile
-(kv rows, q columns): its four products are then plain or transposed-right-hand
-matmuls, the row statistics (logsumexp, delta) come in as lane vectors of `block_q`
-floats, and a kv head's group of query heads is the inner, accumulated grid
-dimension, so dK/dV are written once per kv head.
+Backward follows the standard two-kernel split: one pass computes dQ, one computes
+dK/dV, both recomputing a tile's probabilities from the saved logsumexp. The dK/dV
+pass works on the TRANSPOSED tile (kv rows, q columns): its four products are then
+plain or transposed-right-hand matmuls, the row statistics (logsumexp, delta) come in
+as lane vectors of `block_q` floats, and a kv head's group of query heads is summed in
+the kernel, so dK/dV are written once per kv head.
 
-Under `causal` a tile wholly above the diagonal runs nothing, and its index maps name
-the block of the nearest computed tile, so that the pipeline issues no copy for it.
+The block a grid step fetches is not the tile a product computes. A step of the
+forward and dQ kernels owns one q tile and a SPAN of K/V rows, a step of dK/dV one kv
+tile and a span of the q rows of its group's query heads; a `fori_loop` inside the
+kernel walks the span's compute tiles (`block_q` x `block_kv`, the knobs), in ascending
+order, with the running statistics and accumulators in VMEM scratch throughout. The
+span is derived (`_tiling`): all of the sequence where its blocks fit `SPAN_VMEM_BYTES`
+(at head_dim 128 in bf16: K/V up to 16,384 rows, the Q/dO of a group of four up to
+2,048), else the largest whole number of tiles that divides it, and then the last grid
+dimension runs over spans. A grid step costs 0.35-0.6 us on a v5e whatever it computes
+(PERF.md, PR 28): at s2048 a (batch, head) is 4 steps, where a tile a step made 16.
+
+Under `causal` the loop's bounds come from `program_id`: a tile wholly above the
+diagonal is not visited, and a span wholly above it names, in its index maps, the
+nearest span used, so that the pipeline issues no copy for it.
 Every product feeds the MXU the inputs' own dtype (bf16 in training) and accumulates in
 f32; scores, exponentials, logsumexp, delta and all accumulators are f32. Per-row
-statistics are kept 128 equal lanes wide inside a kernel and travel between kernels as
-lane vectors (`_rows`): as [rows, 1] columns every use of them is a lane broadcast.
+statistics and segment ids are kept 128 equal lanes wide inside a kernel and travel
+between kernels as lane vectors (`_rows`): as [rows, 1] columns every use of them is a
+lane broadcast.
 
-Measured on a v5e at [6, 2048, 32/8, 128] bf16 causal, 512 x 512 tiles (PERF.md, PR 26):
-forward 2.6 ms, dQ 3.5 ms, dK/dV 3.4 ms a call, which is 50 / 57 / 76 % of the MXU's
-bf16 peak on the products the kernels execute (the masked halves of diagonal tiles
-included) and 40 / 45 / 60 % on the products causal attention needs.
+Measured on a v5e at [6, 2048, 32/8, 128] bf16 causal, 512 x 512 tiles (PERF.md, PR 28):
+forward 2.05 ms, dQ 2.65 ms, dK/dV 3.00 ms a call, which is 64 / 74 / 87 % of the MXU's
+bf16 peak on the products the kernels execute (`tile_counts`: 10 tiles a (batch, head),
+the masked halves of the diagonal's included) and 51 / 59 / 70 % on the 8 that causal
+attention needs.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,12 +57,71 @@ NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract the minor dimension of both
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
+# VMEM the kernels may take (Mosaic's default scoped limit on a v5e is 16 MiB of the
+# core's 128), and the half of it that the span-long blocks of one kernel may take, both
+# pipeline buffers counted: K and V in the forward and dQ kernels; Q, dO, logsumexp and
+# delta of a kv head's group of query heads in dK/dV. The other half is for the compute
+# tile's blocks, accumulators and f32 intermediates (~6 MB at 512 x 512).
+VMEM_LIMIT_BYTES = 32 << 20
+SPAN_VMEM_BYTES = VMEM_LIMIT_BYTES // 2
+
 
 def _block_sizes(sq: int, skv: int, bq: int, bkv: int):
     bq, bkv = min(bq, sq), min(bkv, skv)
     if sq % bq or skv % bkv:
         raise ValueError(f"seq lengths ({sq},{skv}) must be multiples of blocks ({bq},{bkv})")
     return bq, bkv
+
+
+def _span(seq: int, tile: int, row_bytes: int, budget: Optional[int] = None) -> int:
+    """Rows of the other side's sequence that one grid step fetches: the largest
+    multiple of the compute tile that divides the sequence and whose blocks
+    (`row_bytes` a row, both pipeline buffers) fit the budget; at least one tile."""
+    budget = SPAN_VMEM_BYTES if budget is None else budget
+    n = seq // tile
+    return tile * max(m for m in range(1, n + 1) if n % m == 0 and (
+        m == 1 or m * tile * row_bytes <= budget))
+
+
+class Tiling(NamedTuple):
+    """How the (q, kv) plane of one (batch, head) is cut: compute tiles of bq x bkv; a
+    grid step of the forward and dQ kernels is one q tile against `kv_span` rows of K
+    and V, one of dK/dV a kv tile against `q_span` rows of its group's query heads."""
+    bq: int
+    bkv: int
+    kv_span: int
+    q_span: int
+
+
+def _tiling(sq, skv, bq, bkv, d, itemsize, n_rep=1) -> Tiling:
+    bq, bkv = _block_sizes(sq, skv, bq, bkv)
+    row = 2 * 2 * d * itemsize  # two arrays a side, two pipeline buffers each
+    # dK/dV: of every query head of the group, and the rows' logsumexp and delta, which
+    # a lane vector a tile holds in 8 sublanes
+    return Tiling(bq, bkv, _span(skv, bkv, row), _span(sq, bq, n_rep * (row + 2 * 2 * 4 * 8)))
+
+
+class TileCounts(NamedTuple):
+    grid_steps: int  # steps of the last two grid dimensions: those of one (batch, head)
+    tiles_computed: int  # compute tiles whose products run
+    tiles_needed: float  # the scores attention needs, in compute tiles
+
+
+def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim: int = 128,
+                itemsize: int = 2, n_rep: int = 1, kv_major: bool = False) -> TileCounts:
+    """What a (batch, query head) costs the forward and dQ kernels, or (`kv_major`) a
+    (batch, kv head with its `n_rep` query heads) the dK/dV kernel: from the same
+    `_tiling` the kernels' grids are built from. A causal tile is computed if any of
+    its scores is kept (kv position <= q position)."""
+    t = _tiling(sq, skv, bq, bkv, head_dim, itemsize, n_rep)
+    nq, nk = sq // t.bq, skv // t.bkv
+    steps, heads = (nk * (sq // t.q_span), n_rep) if kv_major else (nq * (skv // t.kv_span), 1)
+    if not causal:
+        return TileCounts(steps, heads * nq * nk, float(heads * nq * nk))
+    computed = sum(min(_last_kv_block(qi, t.bq, t.bkv) + 1, nk) for qi in range(nq))
+    m = min(sq, skv)
+    kept = m * (m + 1) // 2 + (sq - m) * skv  # row i keeps min(i + 1, skv) scores
+    return TileCounts(steps, heads * computed, heads * kept / (t.bq * t.bkv))
 
 
 def _interpret() -> bool:
@@ -83,7 +155,8 @@ def _pallas_call(kernel, *, name: str, **kw):
     return pl.pallas_call(
         kernel, name=name, interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         **kw)
 
 
@@ -104,12 +177,30 @@ def _first_q_block(kj, bq: int, bkv: int):
     return (kj * bkv) // bq
 
 
-def _for_tile(causal: bool, qi, kj, bq: int, bkv: int, tile) -> None:
-    """Run `tile()` unless tile (qi, kj) lies wholly above the causal diagonal."""
-    if causal:
-        pl.when(kj <= _last_kv_block(qi, bq, bkv))(tile)
+def _kv_tiles_end(causal: bool, qi, sj, n: int, bq: int, bkv: int):
+    """How many of span sj's `n` kv tiles q block qi walks: all, or up to the last
+    that the causal diagonal reaches."""
+    if not causal:
+        return n
+    return jnp.clip(_last_kv_block(qi, bq, bkv) + 1 - sj * n, 0, n)
+
+
+def _walk(lo, hi, n: int, tile) -> None:
+    """Run `tile(t)` for the compute tiles lo <= t < hi of a grid step's span of `n`.
+    Under `causal` the bounds come from `program_id`, so a tile wholly on the masked
+    side of the diagonal is not visited. A span of one tile is a step of the plain
+    tile-a-step grid: t is static and the blocks are read whole."""
+    if n > 1:
+        jax.lax.fori_loop(lo, hi, lambda t, _: tile(t), None)
+    elif isinstance(lo, int) and isinstance(hi, int):
+        tile(0)
     else:
-        tile()
+        pl.when((lo <= 0) & (hi > 0))(lambda: tile(0))
+
+
+def _at(t, block: int):
+    """Rows of compute tile `t` in a span-long block."""
+    return slice(None) if isinstance(t, int) else pl.ds(pl.multiple_of(t * block, block), block)
 
 
 def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, seg_row):
@@ -124,7 +215,7 @@ def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, 
                  - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
         keep = ahead >= kj * bkv - qi * bq
     if seg_col is not None:
-        same = seg_col[:, :1] == seg_row[:][:, :shape[1]]
+        same = _lanes_to(seg_col[:], shape[1]) == seg_row[:, :shape[1]]
         keep = same if keep is None else (keep & same)
     return keep
 
@@ -134,10 +225,10 @@ def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, 
 
 def _fwd_kernel(
     q_ref,  # [bq, D]
-    k_ref,  # [bkv, D]
-    v_ref,  # [bkv, D]
+    k_ref,  # [span, D]
+    v_ref,  # [span, D]
     seg_q_ref,  # [bq, 128] or None
-    seg_kv_ref,  # [1, bkv] or None
+    seg_kv_ref,  # [span // bkv, 1, P] or None
     o_ref,  # [bq, D]
     lse_ref,  # [1, P]
     m_scr,  # VMEM [bq, 128] f32, lanes equal
@@ -151,19 +242,21 @@ def _fwd_kernel(
     bkv: int,
 ):
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
+    sj = pl.program_id(3)  # which span of K/V
+    n = k_ref.shape[0] // bkv
 
-    @pl.when(kj == 0)
+    @pl.when(sj == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def tile():
-        v = v_ref[:]
-        s = _dot(q_ref[:], k_ref[:], _NT) * scale  # [bq, bkv]
-        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref, seg_kv_ref)
+    def tile(t):
+        kj = sj * n + t
+        v = v_ref[_at(t, bkv)]
+        s = _dot(q_ref[:], k_ref[_at(t, bkv)], _NT) * scale  # [bq, bkv]
+        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
+                     None if seg_kv_ref is None else seg_kv_ref[t])
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
         # The running statistics stay 128 equal lanes wide: as [bq, 1] columns every
@@ -177,9 +270,9 @@ def _fwd_kernel(
         m_scr[:] = m_new
         acc_scr[:] = acc_scr[:] * _lanes_to(alpha, v.shape[1]) + _dot(p.astype(v.dtype), v, _NN)
 
-    _for_tile(causal, qi, kj, bq, bkv, tile)
+    _walk(0, _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(sj == pl.num_programs(3) - 1)
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
@@ -188,37 +281,40 @@ def _fwd_kernel(
         lse_ref[:] = lse_scr[:].T[:1]  # rows become lanes; those past bq are never read
 
 
-def _q_major_specs(d, n_rep, causal, bq, bkv, has_seg):
-    """BlockSpecs of the forward and dQ grids (b, h, q block, kv block): q-side,
+def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg):
+    """BlockSpecs of the forward and dQ grids (b, h, q block, kv span): q-side,
     kv-side, per-row statistics (`_rows`: a lane vector a q block), and the segment
     ids of rows and columns."""
+    bq, bkv = t.bq, t.bkv
+    n = t.kv_span // bkv
 
-    def kv_block(qi, kj):  # a tile above the diagonal names the last block used
-        return jnp.minimum(kj, _last_kv_block(qi, bq, bkv)) if causal else kj
+    def kv_span(qi, sj):  # a span above the diagonal names the last span used
+        return jnp.minimum(sj, _last_kv_block(qi, bq, bkv) // n) if causal else sj
 
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, kj: (bi, hi, qi, 0))
+    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, sj: (bi, hi, qi, 0))
     kv_spec = pl.BlockSpec(
-        (1, 1, bkv, d), lambda bi, hi, qi, kj: (bi, hi // n_rep, kv_block(qi, kj), 0))
-    stat_spec = pl.BlockSpec((1, 1, 1, 1, _lane_pad(bq)), lambda bi, hi, qi, kj: (bi, hi, qi, 0, 0))
+        (1, 1, t.kv_span, d), lambda bi, hi, qi, sj: (bi, hi // n_rep, kv_span(qi, sj), 0))
+    stat_spec = pl.BlockSpec((1, 1, 1, 1, _lane_pad(bq)), lambda bi, hi, qi, sj: (bi, hi, qi, 0, 0))
     seg_specs = []
     if has_seg:
         seg_specs = [
-            pl.BlockSpec((1, bq, 128), lambda bi, hi, qi, kj: (bi, qi, 0)),
-            pl.BlockSpec((1, 1, 1, _lane_pad(bkv)),
-                         lambda bi, hi, qi, kj: (bi, kv_block(qi, kj), 0, 0)),
+            pl.BlockSpec((1, bq, 128), lambda bi, hi, qi, sj: (bi, qi, 0)),
+            pl.BlockSpec((1, n, 1, _lane_pad(bkv)),
+                         lambda bi, hi, qi, sj: (bi, kv_span(qi, sj), 0, 0)),
         ]
     return q_spec, kv_spec, stat_spec, seg_specs
 
 
-def _unpack(refs, n_in: int, has_seg: bool):
-    """(inputs, segment-id pair, the remaining refs), the inputs' blocks indexed
-    down to their last two dimensions."""
-    def tile(r):
-        return r.at[(0,) * (len(r.shape) - 2)]
+def _unpack(refs, keeps, has_seg: bool):
+    """(inputs, segment-id pair, the remaining refs): each input's block indexed down
+    to as many trailing dimensions as `keeps` says, the segment ids of the tile's rows
+    to two, and those of the span's columns, a lane vector a tile, to three."""
+    def last(r, keep):
+        return r.at[(0,) * (len(r.shape) - keep)]
 
-    n_seg = 2 if has_seg else 0
-    segs = [tile(r) for r in refs[n_in:n_in + n_seg]] or [None, None]
-    return [tile(r) for r in refs[:n_in]], segs, refs[n_in + n_seg:]
+    n_in = len(keeps)
+    segs = [last(refs[n_in], 2), last(refs[n_in + 1], 3)] if has_seg else [None, None]
+    return [last(r, keep) for r, keep in zip(refs, keeps)], segs, refs[n_in + 2 * has_seg:]
 
 
 def _fwd(
@@ -233,20 +329,21 @@ def _fwd(
 ):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
-    bq, bkv = _block_sizes(sq, skv, bq, bkv)
+    t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize)
+    bq, bkv = t.bq, t.bkv
     has_seg = seg is not None
-    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, bq, bkv, has_seg)
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, t, has_seg)
     args = [q, k, v] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def kernel(*refs):
-        ins, segs, (o_ref, lse_ref, *scratch) = _unpack(refs, 3, has_seg)
+        ins, segs, (o_ref, lse_ref, *scratch) = _unpack(refs, (2,) * 3, has_seg)
         _fwd_kernel(*ins, *segs, o_ref.at[0, 0], lse_ref.at[0, 0, 0], *scratch,
                     scale=scale, causal=causal, bq=bq, bkv=bkv)
 
     out, lse = _pallas_call(
         kernel,
         name="flash_attention_fwd",
-        grid=(b, h, sq // bq, skv // bkv),
+        grid=(b, h, sq // bq, skv // t.kv_span),
         in_specs=[q_spec, kv_spec, kv_spec] + seg_specs,
         out_specs=[q_spec, stat_spec],
         out_shape=[
@@ -271,32 +368,35 @@ def _bwd_dq_kernel(
     dq_scr, lse_scr, delta_scr,
     *, scale, causal, bq, bkv,
 ):
-    """lse_ref and delta_ref are [1, P]; the tile [bq, bkv] wants them down its rows,
-    so the q block's first step turns them once into [bq, 128] with equal lanes."""
+    """k_ref and v_ref are a span of K/V, [span, D]. lse_ref and delta_ref are [1, P];
+    the tile [bq, bkv] wants them down its rows, so the q block's first step turns
+    them once into [bq, 128] with equal lanes."""
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
-    nk = pl.num_programs(3)
+    sj = pl.program_id(3)
+    n = k_ref.shape[0] // bkv
 
-    @pl.when(kj == 0)
+    @pl.when(sj == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
         for row_ref, col_scr in ((lse_ref, lse_scr), (delta_ref, delta_scr)):
             col_scr[:] = jnp.broadcast_to(row_ref[:], (128, row_ref.shape[1])).T[:bq]
 
-    def tile():
-        k = k_ref[:]
+    def tile(t):
+        kj = sj * n + t
+        k = k_ref[_at(t, bkv)]
         s = _dot(q_ref[:], k, _NT) * scale  # [bq, bkv]
-        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref, seg_kv_ref)
+        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
+                     None if seg_kv_ref is None else seg_kv_ref[t])
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
         p = jnp.exp(s - _lanes_to(lse_scr[:], s.shape[1]))
-        dp = _dot(do_ref[:], v_ref[:], _NT)
+        dp = _dot(do_ref[:], v_ref[_at(t, bkv)], _NT)
         ds = p * (dp - _lanes_to(delta_scr[:], s.shape[1])) * scale
         dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _for_tile(causal, qi, kj, bq, bkv, tile)
+    _walk(0, _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
 
-    @pl.when(kj == nk - 1)
+    @pl.when(sj == pl.num_programs(3) - 1)
     def _():
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -304,36 +404,44 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_kv_ref, seg_q_ref,
     dk_ref, dv_ref, dk_scr, dv_scr,
-    *, scale, causal, bq, bkv, nq,
+    *, scale, causal, bq, bkv,
 ):
-    """One (kv block, query head of its group, q block) step, on the transposed tile
-    [bkv, bq]: lse_ref and delta_ref are [1, P]."""
+    """One kv block against a span of q rows of the query heads of its group, on the
+    transposed tile [bkv, bq]: q_ref and do_ref are [n_rep, span, D], lse_ref and
+    delta_ref [n_rep, span // bq, 1, P], seg_q_ref [span // bq, 1, P]."""
     kj = pl.program_id(2)
-    t = pl.program_id(3)  # (query head within the group, q block), q block minor
-    nt = pl.num_programs(3)
-    qi = jax.lax.rem(t, nq)
+    sp = pl.program_id(3)  # which span of q rows
+    n_rep, n = q_ref.shape[0], q_ref.shape[1] // bq
 
-    @pl.when(t == 0)
+    @pl.when(sp == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def tile():
-        q = q_ref[:]
-        do = do_ref[:]
-        st = _dot(k_ref[:], q, _NT) * scale  # [bkv, bq]
-        keep = _keep(st.shape, 1, qi, kj, bq, bkv, causal, seg_kv_ref, seg_q_ref)
-        if keep is not None:
-            st = jnp.where(keep, st, NEG_INF)
-        pt = jnp.exp(st - lse_ref[:][:, :bq])
-        dv_scr[:] += _dot(pt.astype(do.dtype), do, _NN)
-        dpt = _dot(v_ref[:], do, _NT)
-        dst = pt * (dpt - delta_ref[:][:, :bq]) * scale
-        dk_scr[:] += _dot(dst.astype(q.dtype), q, _NN)
+    # the first of the span's q tiles that attends to this kv block
+    lo = jnp.clip(_first_q_block(kj, bq, bkv) - sp * n, 0, n) if causal else 0
 
-    _for_tile(causal, qi, kj, bq, bkv, tile)
+    def head(r):
+        def tile(t):
+            qi = sp * n + t
+            q = q_ref[r, _at(t, bq)]
+            do = do_ref[r, _at(t, bq)]
+            st = _dot(k_ref[:], q, _NT) * scale  # [bkv, bq]
+            keep = _keep(st.shape, 1, qi, kj, bq, bkv, causal, seg_kv_ref,
+                         None if seg_q_ref is None else seg_q_ref[t])
+            if keep is not None:
+                st = jnp.where(keep, st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[r, t][:, :bq])
+            dv_scr[:] += _dot(pt.astype(do.dtype), do, _NN)
+            dpt = _dot(v_ref[:], do, _NT)
+            dst = pt * (dpt - delta_ref[r, t][:, :bq]) * scale
+            dk_scr[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-    @pl.when(t == nt - 1)
+        _walk(lo, n, n, tile)
+
+    _walk(0, n_rep, n_rep, head)
+
+    @pl.when(sp == pl.num_programs(3) - 1)
     def _():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
@@ -343,27 +451,27 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     n_rep = h // hkv
-    bq, bkv = _block_sizes(sq, skv, bq, bkv)
-    nq, nk = sq // bq, skv // bkv
+    t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize, n_rep)
+    bq, bkv = t.bq, t.bkv
     has_seg = seg is not None
 
     # delta_i = sum_d(dO * O): rowwise, cheap in XLA.
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     stats = [lse, _rows(delta, bq)]
 
-    # --- dQ pass: grid (b, h, nq, nk)
-    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, bq, bkv, has_seg)
+    # --- dQ pass: grid (b, h, q blocks, kv spans)
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, t, has_seg)
     args = [q, k, v, dout, *stats] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def dq_kernel(*refs):
-        ins, segs, (dq_ref, *scratch) = _unpack(refs, 6, has_seg)
+        ins, segs, (dq_ref, *scratch) = _unpack(refs, (2,) * 6, has_seg)
         _bwd_dq_kernel(*ins, *segs, dq_ref.at[0, 0], *scratch,
                        scale=scale, causal=causal, bq=bq, bkv=bkv)
 
     dq = _pallas_call(
         dq_kernel,
         name="flash_attention_bwd_dq",
-        grid=(b, h, nq, nk),
+        grid=(b, h, sq // bq, skv // t.kv_span),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec] + seg_specs,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
@@ -374,42 +482,39 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
         ],
     )(*args)
 
-    # --- dK/dV pass: grid (b, kv head, nk, group's query heads x nq), the last summed in
-    # the kernel. A q block no row of which sees the kv block names the first that does.
-    def q_side(t, kj):  # (query head within the group, q block)
-        qi = jax.lax.rem(t, nq)
-        return t // nq, (jnp.maximum(qi, _first_q_block(kj, bq, bkv)) if causal else qi)
+    # --- dK/dV pass: grid (b, kv head, kv blocks, q spans), the last and the group's query
+    # heads summed in the kernel. A q span no row of which sees the kv block names the
+    # first that does.
+    n = t.q_span // bq
 
-    def q_map(bi, hk, kj, t):
-        r, qi = q_side(t, kj)
-        return (bi, hk * n_rep + r, qi, 0)
+    def q_span(kj, sp):
+        return jnp.maximum(sp, _first_q_block(kj, bq, bkv) // n) if causal else sp
 
-    def stat_map(bi, hk, kj, t):
-        r, qi = q_side(t, kj)
-        return (bi, hk * n_rep + r, qi, 0, 0)
-
-    q_spec2 = pl.BlockSpec((1, 1, bq, d), q_map)
-    kv_spec2 = pl.BlockSpec((1, 1, bkv, d), lambda bi, hk, kj, t: (bi, hk, kj, 0))
-    stat_spec2 = pl.BlockSpec((1, 1, 1, 1, _lane_pad(bq)), stat_map)
+    q_spec2 = pl.BlockSpec((1, n_rep, t.q_span, d),
+                           lambda bi, hk, kj, sp: (bi, hk, q_span(kj, sp), 0))
+    kv_spec2 = pl.BlockSpec((1, 1, bkv, d), lambda bi, hk, kj, sp: (bi, hk, kj, 0))
+    stat_spec2 = pl.BlockSpec((1, n_rep, n, 1, _lane_pad(bq)),
+                              lambda bi, hk, kj, sp: (bi, hk, q_span(kj, sp), 0, 0))
     in_specs2 = [q_spec2, kv_spec2, kv_spec2, q_spec2, stat_spec2, stat_spec2]
     args2 = [q, k, v, dout, *stats]
     if has_seg:
         in_specs2 += [
-            pl.BlockSpec((1, bkv, 128), lambda bi, hk, kj, t: (bi, kj, 0)),
-            pl.BlockSpec((1, 1, 1, _lane_pad(bq)),
-                         lambda bi, hk, kj, t: (bi, q_side(t, kj)[1], 0, 0)),
+            pl.BlockSpec((1, bkv, 128), lambda bi, hk, kj, sp: (bi, kj, 0)),
+            pl.BlockSpec((1, n, 1, _lane_pad(bq)),
+                         lambda bi, hk, kj, sp: (bi, q_span(kj, sp), 0, 0)),
         ]
         args2 += [seg["kv_col"], _rows(seg["q"], bq)]
 
     def dkv_kernel(*refs):
-        ins, segs, (dk_ref, dv_ref, dk_s, dv_s) = _unpack(refs, 6, has_seg)
-        _bwd_dkv_kernel(*ins, *segs, dk_ref.at[0, 0], dv_ref.at[0, 0], dk_s, dv_s,
-                        scale=scale, causal=causal, bq=bq, bkv=bkv, nq=nq)
+        # q, dO and the statistics keep the group's query heads as their leading dimension
+        ins, segs, (dk_ref, dv_ref, *scratch) = _unpack(refs, (3, 2, 2, 3, 4, 4), has_seg)
+        _bwd_dkv_kernel(*ins, *segs, dk_ref.at[0, 0], dv_ref.at[0, 0], *scratch,
+                        scale=scale, causal=causal, bq=bq, bkv=bkv)
 
     dk, dv = _pallas_call(
         dkv_kernel,
         name="flash_attention_bwd_dkv",
-        grid=(b, hkv, nk, n_rep * nq),
+        grid=(b, hkv, skv // bkv, sq // t.q_span),
         in_specs=in_specs2,
         out_specs=[kv_spec2, kv_spec2],
         out_shape=[

@@ -5,7 +5,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.attention import attention_reference
-from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops import flash_attention as fa
+from ray_tpu.ops.flash_attention import flash_attention, tile_counts
 
 
 def _rand(shape, key, dtype=jnp.float32):
@@ -69,9 +70,12 @@ def _packed(b, s, cuts):
 # Tilings of the (q, kv) plane: every case runs the forward kernel and both backward
 # kernels against the f32 reference on f32 copies of the same inputs, with a random
 # cotangent. Under `causal` a tiling with more than one block a side has tiles below
-# the diagonal, on it, and above it (skipped, their index maps clamped).
+# the diagonal, on it, and above it (not visited). A grid step fetches a span of the
+# other side's sequence, all of it where it fits `SPAN_VMEM_BYTES`, and walks the
+# compute tiles inside the kernel; a case with an eleventh field runs under that
+# budget in bytes, so that a step's span is shorter than the sequence.
 TILINGS = {
-    # name: (b, s, h, hkv, d, block_q, block_kv, causal, segment cuts, dtype)
+    # name: (b, s, h, hkv, d, block_q, block_kv, causal, segment cuts, dtype[, span budget])
     "causal-4x4": (1, 256, 2, 2, 64, 64, 64, True, None, jnp.float32),
     "causal-wide-kv-2x4": (1, 256, 2, 2, 64, 128, 64, True, None, jnp.float32),
     "causal-wide-q-4x2": (1, 256, 2, 2, 64, 64, 128, True, None, jnp.float32),
@@ -88,12 +92,39 @@ TILINGS = {
     "bf16-gqa4-causal-2x2": (1, 256, 8, 2, 128, 128, 128, True, None, jnp.bfloat16),
     "bf16-segments-causal-4x2": (1, 256, 4, 2, 64, 64, 128, True, (90, 180), jnp.bfloat16),
     "bf16-noncausal-2x2": (1, 128, 4, 4, 64, 64, 64, False, None, jnp.bfloat16),
+    # the span is the sequence: one grid step a q block (a kv block), 2, 4 and 8 tiles walked
+    "span-2-tiles-causal": (1, 128, 2, 2, 64, 64, 64, True, None, jnp.float32),
+    "span-4-tiles-causal-gqa2": (1, 256, 4, 2, 64, 64, 64, True, None, jnp.float32),
+    "span-4-tiles-segments-gqa4": (2, 256, 4, 1, 64, 64, 64, True, (40, 150, 200), jnp.float32),
+    "span-8-tiles-causal-gqa2": (1, 512, 4, 2, 64, 64, 64, True, None, jnp.float32),
+    "span-8-tiles-segments-noncausal": (1, 512, 2, 2, 64, 64, 64, False, (100, 300), jnp.float32),
+    "span-4x2-unequal-tiles-gqa2": (1, 256, 4, 2, 64, 64, 128, True, None, jnp.float32),
+    "span-2x4-unequal-tiles-segments-gqa2": (1, 256, 4, 2, 64, 128, 64, True, (100, 130), jnp.float32),
+    "bf16-span-4-tiles-segments-gqa4": (1, 512, 8, 2, 128, 128, 128, True, (90, 300), jnp.bfloat16),
+    # the budget forced down: K/V take 1 KB a row here, a group's Q/dO n_rep x 1,152 B
+    "two-spans-of-2-causal-gqa2": (1, 256, 4, 2, 64, 64, 64, True, None, jnp.float32, 128 << 10),
+    "two-spans-of-2-segments-gqa2": (1, 256, 4, 2, 64, 64, 64, True, (40, 150, 200), jnp.float32,
+                                     128 << 10),
+    "four-spans-of-2-causal": (1, 512, 2, 2, 64, 64, 64, True, None, jnp.float32, 128 << 10),
+    "kv-spans-of-4-q-spans-of-1-gqa4": (1, 512, 4, 1, 64, 64, 64, True, None, jnp.float32, 256 << 10),
+    "two-spans-unequal-tiles-segments": (1, 512, 4, 2, 64, 64, 128, True, (70, 260, 400), jnp.float32,
+                                         256 << 10),
+    "two-spans-noncausal-gqa2": (1, 256, 4, 2, 64, 64, 64, False, None, jnp.float32, 128 << 10),
+    "bf16-two-spans-segments-gqa4": (1, 512, 8, 2, 128, 128, 128, True, (90, 300), jnp.bfloat16,
+                                     256 << 10),
 }
 
 
 @pytest.mark.parametrize("case", list(TILINGS))
-def test_fwd_and_grads_over_tilings(case):
-    b, s, h, hkv, d, bq, bkv, causal, cuts, dtype = TILINGS[case]
+def test_fwd_and_grads_over_tilings(case, monkeypatch):
+    b, s, h, hkv, d, bq, bkv, causal, cuts, dtype, *budget = TILINGS[case]
+    if budget:
+        monkeypatch.setattr(fa, "SPAN_VMEM_BYTES", budget[0])
+    t = fa._tiling(s, s, bq, bkv, d, jnp.dtype(dtype).itemsize, h // hkv)
+    if budget:  # both sides' spans are whole tiles, and shorter than the sequence
+        assert bkv <= t.kv_span < s and bq <= t.q_span < s, t
+    else:
+        assert (t.kv_span, t.q_span) == (s, s), t
     q = _rand((b, s, h, d), 0, dtype)
     k, v = _rand((b, s, hkv, d), 1, dtype), _rand((b, s, hkv, d), 2, dtype)
     g = _rand((b, s, h, d), 3, dtype)
@@ -116,6 +147,34 @@ def test_fwd_and_grads_over_tilings(case):
         scale = max(1.0, float(jnp.max(jnp.abs(ref))))
         np.testing.assert_allclose(np.asarray(a, np.float32) / scale, np.asarray(ref) / scale,
                                    rtol=0, atol=tol, err_msg=f"{case}: {name}")
+
+
+@pytest.mark.parametrize("s,causal,steps,computed,needed,dkv_steps", [
+    # 512 x 512 tiles, Mistral's heads: K/V and a group's Q/dO of 2,048 rows fit a span
+    (2048, True, 4, 10, 8.00390625, 4),
+    (2048, False, 4, 16, 16.0, 4),
+    # at 4,096 rows a group's Q/dO (4 heads x 1,152 B a row, two buffers) take two spans
+    (4096, True, 8, 36, 32.0078125, 16),
+    (4096, False, 8, 64, 64.0, 16),
+])
+def test_tile_counts(s, causal, steps, computed, needed, dkv_steps):
+    """What the kernels' grids are built from, at the cells' shape and twice it: a
+    (batch, head) of the forward and dQ kernels is one grid step a q tile where the
+    tile-a-step grid made (s / 512)^2, the diagonal's tiles and those below it are
+    computed, and dK/dV's figures are a kv head's with its four query heads."""
+    nq = s // 512
+    assert tile_counts(s, s, causal, 512, 512) == (steps, computed, needed)
+    assert tile_counts(s, s, causal, 512, 512, n_rep=4, kv_major=True) == (
+        dkv_steps, 4 * computed, 4 * needed)
+    assert steps == nq < nq * nq
+
+
+def test_span_is_derived():
+    """Whole compute tiles, a divisor of the sequence, inside the budget."""
+    assert fa._span(4096, 512, 1024) == 4096
+    assert fa._span(4096, 512, 1024, budget=3 << 20) == 2048  # 3,072 rows fit; 6 tiles do not divide 8
+    assert fa._span(4096, 512, 1024, budget=1) == 512  # never less than the compute tile
+    assert fa._span(200, 200, 1 << 30) == 200
 
 
 def test_products_take_the_inputs_dtype():

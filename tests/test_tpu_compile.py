@@ -54,15 +54,27 @@ def _shapes(tree, sharding):
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
 
 
+def _pallas_grids(jaxpr):
+    """The grid of every Pallas kernel in a program, nested calls included."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(eqn.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
 @pytest.mark.parametrize("b,s,h,kv,segments", [
     (6, 2048, 32, 8, False),   # llama8b-geom2, the smoke's train batch; mistral7b-train-1chip
     (4, 2048, 32, 8, False),   # a chip's shard of mistral7b-train-fsdp4
     (8, 2048, 12, 6, False),   # llama-500m
     (2, 4096, 32, 8, True),    # packed documents
     (2, 200, 32, 8, True),     # one tile, not a multiple of 128: the lane vectors are padded
+    (1, 32768, 32, 8, False),  # K and V of a kv head do not fit a grid step: two spans of 16,384
 ])
 def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
-    from ray_tpu.ops.flash_attention import flash_attention
+    from ray_tpu.ops.flash_attention import flash_attention, tile_counts
 
     d = 128
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
@@ -78,6 +90,14 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
     bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, seg).compile()
     assert "tpu_custom_call" in fwd.as_text()
     assert bwd.as_text().count("tpu_custom_call") >= 2  # dQ and dK/dV kernels
+    # a grid step owns a span of K/V and walks its 512-wide tiles in the kernel: the
+    # forward program's grid is the short one, not a step a (q tile, kv tile)
+    grid, = _pallas_grids(jax.make_jaxpr(
+        lambda q, k, v: flash_attention(q, k, v, causal=True))(q, k, k).jaxpr)
+    tiles = -(-s // 512)
+    steps = tile_counts(s, s, True, 512, 512).grid_steps
+    assert grid == (b, h, tiles, steps // tiles) and grid[2] * grid[3] == steps
+    assert steps == {200: 1, 2048: 4, 4096: 8, 32768: 128}[s] and steps <= tiles * tiles
     # the kernels' names are their instructions' names, which the device trace
     # shows (benchmarks/metrics/train_attn_{fwd,bwd}_kernel_pct.json select by them)
     # (a transformation may wrap the name: %jvp_flash_attention_fwd_.1)
