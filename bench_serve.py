@@ -404,7 +404,7 @@ def bench_spec_trained(steps=None, gen_tokens=96, k=4):
     cfg = ModelConfig(name="spec-train-byte", vocab_size=512,
                       d_model=128 if TINY else 256, n_layers=2 if TINY else 4,
                       n_heads=8, n_kv_heads=4, d_ff=512 if TINY else 1024,
-                      max_seq_len=512, dtype="float32", scan_layers=True)
+                      max_seq_len=512, dtype="float32")
     tok = get_tokenizer("byte")
     sentences = [
         "the quick brown fox jumps over the lazy dog. ",

@@ -595,10 +595,10 @@ def resolve(loc: Location, oid: Optional[ObjectID] = None) -> Any:
     return value
 
 
-def spill_location(loc: Location, spill_dir: str) -> Optional[Location]:
-    """Move a sealed arena/shm object's bytes to a disk file, freeing the memory
-    (reference LocalObjectManager::SpillObjects). Returns the new location, or
-    None if the object cannot be spilled (inline/already-disk/lost)."""
+def _copy_to_disk(loc: Location, spill_dir: str) -> Optional[Location]:
+    """Write a sealed arena/shm object's bytes to a disk file and return the
+    file's location; the memory stays. None if the object cannot be spilled
+    (inline/already-disk/lost)."""
     kind = loc[0]
     os.makedirs(spill_dir, exist_ok=True)
     if kind == "arena":
@@ -614,7 +614,6 @@ def spill_location(loc: Location, spill_dir: str) -> Optional[Location]:
         finally:
             view.release()
             arena.unpin(oid_bytes)
-        arena.delete(oid_bytes)
         return ("disk", path, size, is_error)
     if kind == "shm":
         _, name, size, is_error = loc
@@ -626,7 +625,6 @@ def spill_location(loc: Location, spill_dir: str) -> Optional[Location]:
         try:
             with open(path, "wb") as f:
                 f.write(bytes(seg.buf[:size]))
-            seg.unlink()  # removes the name; live mappings elsewhere stay valid
         finally:
             try:
                 seg.close()
@@ -634,9 +632,18 @@ def spill_location(loc: Location, spill_dir: str) -> Optional[Location]:
                 # zero-copy views in this process keep the mapping alive; park the
                 # handle so its __del__ doesn't warn at gc time
                 _unclosable_segments.append(seg)
-        _segment_cache.drop(name)
         return ("disk", path, size, is_error)
     return None
+
+
+def spill_location(loc: Location, spill_dir: str) -> Optional[Location]:
+    """Move a sealed arena/shm object's bytes to a disk file, freeing the memory
+    (reference LocalObjectManager::SpillObjects). Returns the new location, or
+    None if the object cannot be spilled (inline/already-disk/lost)."""
+    new_loc = _copy_to_disk(loc, spill_dir)
+    if new_loc is not None:
+        free_local(loc)  # shm: removes the name; live mappings elsewhere stay valid
+    return new_loc
 
 
 class ObjectStore:
@@ -792,7 +799,7 @@ class ObjectStore:
             if spilled >= bytes_to_free:
                 break
             try:
-                new_loc = spill_location(loc, spill_dir)
+                new_loc = _copy_to_disk(loc, spill_dir)
             # graftlint: allow[swallowed-exception] callback isolation: a throwing subscriber must not break the caller
             except Exception:
                 continue  # skip unspillable objects, keep relieving pressure
@@ -805,11 +812,11 @@ class ObjectStore:
                 swapped = self._locations.get(oid) == loc
                 if swapped:
                     self._locations[oid] = new_loc
+            # the memory goes only once the directory names the file: a reader
+            # that resolved the old location and lost it asks again
+            # (_recover_object) and must find the disk copy, not a dead entry
+            free_local(loc if swapped else new_loc)
             if not swapped:
-                try:
-                    os.remove(new_loc[1])
-                except OSError:
-                    pass
                 continue
             if self.on_spill is not None:
                 try:

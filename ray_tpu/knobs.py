@@ -482,20 +482,6 @@ KNOBS: List[Knob] = [
          "Tokens per MoE dispatch group: dispatch/combine tensors are "
          "[group, experts, capacity], so memory is O(tokens x group).",
          "ops", attr="moe_group_size"),
-    Knob("RAY_TPU_FLASH_BLOCK_Q", "int", 512,
-         "Rows of the flash-attention kernels' COMPUTE tile on the query side (a "
-         "multiple of 128; a shorter sequence is one tile): the size of the "
-         "products, not of what a grid step fetches. A step owns a span of the "
-         "other side's sequence, derived from the shape (all of it where it fits "
-         "VMEM), and walks the tiles inside the kernel. Measured on a v5e at [6, "
-         "2048, 32/8, 128] bf16 causal (PERF.md, PR 28): 512 x 512 tiles run the "
-         "forward, dQ and dK/dV kernels in 2.05 / 2.65 / 3.00 ms, 64 / 74 / 87 % "
-         "of the MXU's bf16 peak on the products they execute (2.72 / 3.47 / "
-         "3.46 ms with a tile a grid step).",
-         "ops", attr="flash_block_q"),
-    Knob("RAY_TPU_FLASH_BLOCK_KV", "int", 512,
-         "Rows of the flash-attention kernels' compute tile on the key/value side.",
-         "ops", attr="flash_block_kv"),
     Knob("RAY_TPU_CHUNKED_ATTENTION_MIN_LOGITS", "int", 1 << 20,
          "Sq*Skv above which non-pallas attention switches to the chunked "
          "online-softmax path (bounds the logits buffer on long context).",

@@ -341,8 +341,6 @@ class JaxLLMEngine(LLMEngine):
                         "dp replicas, then microbatch over pp stages)")
                 if cfg.n_layers % c.pipeline_parallel_size:
                     raise ValueError("n_layers must divide by pipeline_parallel_size")
-                if not cfg.scan_layers:
-                    raise ValueError("pipeline_parallel_size > 1 requires scan_layers")
                 if self._fused_auto or self._fused_fixed > 1:
                     # pp decode keeps per-step scheduling (microbatch ticks):
                     # downgrade cleanly instead of warning about a user knob

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import llama
-from ray_tpu.ops.quant import as_weight as _qw
 from ray_tpu.models.config import ModelConfig
 from ray_tpu.parallel.sharding import INFER_RULES, named_sharding, shard_pytree
 
@@ -141,63 +140,73 @@ def install_kv(
 
 # -------------------------------------------------------------------------- decode
 
-def _decode_core(x, lp, cfg: ModelConfig, lengths, active, cache_rw):
-    """One layer's single-token decode math, shared by every cache layout.
+def _window_core(x, lp, cfg: ModelConfig, lengths, active, cache_rw):
+    """One layer over a W-token window for every slot, shared by every cache
+    layout: the block's parts (models/llama.py) around the serving attention.
+    Decode is a window of one; speculative verify is [last token, drafts].
 
-    cache_rw(k_new [S,KV,HD], v_new) -> (ck_view [S,max_len,KV,HD], cv_view,
-    storage) — the adapter writes this step's K/V into its layout and returns
-    per-slot full-history views for attention plus the updated storage, which
-    is threaded back to the caller untouched."""
+    x [S,W,D]; K/V written at positions lengths[s]+0..W-1 through the layout
+    adapter; each query w attends to cache positions <= lengths[s]+w (causal
+    within the window, full history before it) — per-slot lengths, which
+    ops.attention's scalar q_offset / kv_valid_len cannot say.
+
+    cache_rw(k_new [S,W,KV,HD], v_new, pos [S,W]) -> (ck [S,max_len,KV,HD],
+    cv, storage): the adapter writes the window's K/V into its layout at the
+    absolute positions `pos` and returns per-slot full-history views for
+    attention plus the updated storage, which is threaded back to the caller
+    untouched. active [S] bool: inactive slots compute but must not claim MoE
+    expert capacity.
+    """
     dt = x.dtype
-    s = x.shape[0]
+    s, wlen, _ = x.shape
     kvh, hd = cfg.n_kv_heads, cfg.head_dim
     g = cfg.n_heads // kvh
-    pos = lengths[:, None]  # [S,1] — the new token's position
+    pos = lengths[:, None] + jnp.arange(wlen)[None, :]  # [S,W]
 
-    h = llama.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("sld,dhk->slhk", h, _qw(lp["wq"], dt))
-    k = jnp.einsum("sld,dhk->slhk", h, _qw(lp["wk"], dt))
-    vv = jnp.einsum("sld,dhk->slhk", h, _qw(lp["wv"], dt))
-    q = llama.rope(q, pos, cfg.rope_theta)
-    k = llama.rope(k, pos, cfg.rope_theta)
-
-    ck, cv, storage = cache_rw(k[:, 0], vv[:, 0])
+    q, k, v = llama.qkv_proj(x, lp, cfg, pos)
+    ck, cv, storage = cache_rw(k, v, pos)
     max_len = ck.shape[1]
 
-    qg = q[:, 0].reshape(s, kvh, g, hd) * (hd**-0.5)
-    scores = jnp.einsum("skgd,stkd->skgt", qg.astype(jnp.float32), ck.astype(jnp.float32))
-    valid = (jnp.arange(max_len)[None, :] <= lengths[:, None])[:, None, None, :]
-    scores = jnp.where(valid, scores, sampling.NEG_INF)
+    qg = q.reshape(s, wlen, kvh, g, hd) * (hd**-0.5)
+    scores = jnp.einsum("swkgd,stkd->swkgt", qg.astype(jnp.float32),
+                        ck.astype(jnp.float32))
+    valid = (jnp.arange(max_len)[None, None, :] <= pos[:, :, None])  # [S,W,T]
+    scores = jnp.where(valid[:, :, None, None, :], scores, sampling.NEG_INF)
     w = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("skgt,stkd->skgd", w, cv.astype(jnp.float32)).astype(dt)
-    o = o.reshape(s, 1, cfg.n_heads, hd)
-    x = x + jnp.einsum("slhk,hkd->sld", o, _qw(lp["wo"], dt))
+    o = jnp.einsum("swkgt,stkd->swkgd", w, cv.astype(jnp.float32)).astype(dt)
+    x = llama.attn_out(x, o.reshape(s, wlen, cfg.n_heads, hd), lp)
 
-    h = llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        from ray_tpu.models import moe as _moe
-
-        y2, _ = _moe.moe_mlp(h[:, 0], lp["router"], lp["w_gate"], lp["w_up"],
-                             lp["w_down"], cfg, mask=active.astype(jnp.float32))
-        down = y2[:, None, :]
-    else:
-        gate = jnp.einsum("sld,df->slf", h, _qw(lp["w_gate"], dt))
-        up = jnp.einsum("sld,df->slf", h, _qw(lp["w_up"], dt))
-        down = jnp.einsum("slf,fd->sld", jax.nn.silu(gate) * up, _qw(lp["w_down"], dt))
-    return x + down, storage
+    token_mask = jnp.broadcast_to(active[:, None], (s, wlen)).astype(jnp.float32)
+    x, _ = llama.feed_forward(x, lp, cfg, token_mask)
+    return x, storage
 
 
-def _decode_block(x, lp, cfg: ModelConfig, ck, cv, lengths, active):
-    """One layer's decode for all slots against the slot cache. x [S,1,D];
-    ck/cv [S,max_len,KV,HD]; K/V scattered in at position lengths[s]."""
+def _slot_block(x, lp, cfg: ModelConfig, ck, cv, lengths, active):
+    """One layer's window against the slot cache. x [S,W,D]; ck/cv
+    [S,max_len,KV,HD]; K/V scattered in at absolute positions lengths[s]+w
+    (writes past max_len dropped)."""
+    rows = jnp.arange(x.shape[0])[:, None]
 
-    def cache_rw(k_new, v_new):
-        rows = jnp.arange(ck.shape[0])
-        nk = ck.at[rows, lengths].set(k_new.astype(ck.dtype))
-        nv = cv.at[rows, lengths].set(v_new.astype(cv.dtype))
+    def cache_rw(k_new, v_new, pos):
+        nk = ck.at[rows, pos].set(k_new.astype(ck.dtype), mode="drop")
+        nv = cv.at[rows, pos].set(v_new.astype(cv.dtype), mode="drop")
         return nk, nv, (nk, nv)
 
-    x, (nk, nv) = _decode_core(x, lp, cfg, lengths, active, cache_rw)
+    x, (nk, nv) = _window_core(x, lp, cfg, lengths, active, cache_rw)
+    return x, nk, nv
+
+
+def _layer_loop(layer_fn, x, layers, k, v):
+    """The serving programs' layer loop: layer_fn(h, lp, k_l, v_l) -> (h, k_l,
+    v_l) over the stacked layers and their K/V storage (slot cache or block
+    pool). Returns (x, new k, new v)."""
+
+    def body(h, xs):
+        lp, k_l, v_l = xs
+        h, k_l, v_l = layer_fn(h, lp, k_l, v_l)
+        return h, (k_l, v_l)
+
+    x, (nk, nv) = jax.lax.scan(body, x, (layers, k, v))
     return x, nk, nv
 
 
@@ -215,99 +224,13 @@ def decode_step(
     write lands at position lengths[s] of a slot whose contents the next prefill
     overwrites, and their length does not advance.
     """
-    x = params["embed"].astype(cfg.activation_dtype)[tokens[:, None]]  # [S,1,D]
-
-    if cfg.scan_layers:
-        def body(carry, xs):
-            h = carry
-            lp, ck, cv = xs
-            h, ck, cv = _decode_block(h, lp, cfg, ck, cv, state.lengths, active)
-            return h, (ck, cv)
-
-        x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], state.k, state.v))
-    else:
-        nk, nv = [], []
-        for i, lp in enumerate(params["layers"]):
-            x, ck, cv = _decode_block(x, lp, cfg, state.k[i], state.v[i],
-                                      state.lengths, active)
-            nk.append(ck)
-            nv.append(cv)
-        nk, nv = jnp.stack(nk), jnp.stack(nv)
-
-    x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("sld,dv->slv", x, _qw(head, cfg.activation_dtype))[:, 0]
+    x = llama.embed_tokens(params, tokens[:, None], cfg)  # [S,1,D]
+    x, nk, nv = _layer_loop(
+        lambda h, lp, ck, cv: _slot_block(h, lp, cfg, ck, cv, state.lengths, active),
+        x, params["layers"], state.k, state.v)
+    logits = llama.output_head(params, x, cfg)[:, 0]
     lengths = jnp.where(active, state.lengths + 1, state.lengths)
-    return DecodeState(k=nk, v=nv, lengths=lengths), logits.astype(jnp.float32)
-
-
-def _verify_core(x, lp, cfg: ModelConfig, lengths, cache_rw, active=None):
-    """One layer over a W-token verify window for every slot (speculative
-    decoding), shared by every cache layout: x [S,W,D], K/V written at
-    positions lengths[s]+0..W-1 through the layout adapter, each query w
-    attends to cache positions <= lengths[s]+w (causal within the window,
-    full history before it).
-
-    cache_rw(k_new [S,W,KV,HD], v_new) -> (ck [S,max_len,KV,HD], cv, storage).
-    active [S] bool (MoE only): inactive slots' window tokens must not claim
-    expert capacity.
-    """
-    dt = x.dtype
-    s, wlen, _ = x.shape
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-    g = cfg.n_heads // kvh
-    pos = lengths[:, None] + jnp.arange(wlen)[None, :]  # [S,W]
-
-    h = llama.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("sld,dhk->slhk", h, _qw(lp["wq"], dt))
-    k = jnp.einsum("sld,dhk->slhk", h, _qw(lp["wk"], dt))
-    vv = jnp.einsum("sld,dhk->slhk", h, _qw(lp["wv"], dt))
-    q = llama.rope(q, pos, cfg.rope_theta)
-    k = llama.rope(k, pos, cfg.rope_theta)
-
-    ck, cv, storage = cache_rw(k, vv)
-    max_len = ck.shape[1]
-
-    qg = q.reshape(s, wlen, kvh, g, hd) * (hd**-0.5)
-    scores = jnp.einsum("swkgd,stkd->swkgt", qg.astype(jnp.float32),
-                        ck.astype(jnp.float32))
-    valid = (jnp.arange(max_len)[None, None, :] <= pos[:, :, None])  # [S,W,T]
-    scores = jnp.where(valid[:, :, None, None, :], scores, sampling.NEG_INF)
-    w = jax.nn.softmax(scores, axis=-1)
-    o = jnp.einsum("swkgt,stkd->swkgd", w, cv.astype(jnp.float32)).astype(dt)
-    o = o.reshape(s, wlen, cfg.n_heads, hd)
-    x = x + jnp.einsum("slhk,hkd->sld", o, _qw(lp["wo"], dt))
-
-    h = llama.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        from ray_tpu.models import moe as _moe
-
-        tok_mask = None
-        if active is not None:
-            tok_mask = jnp.repeat(active.astype(jnp.float32), wlen)
-        y2, _ = _moe.moe_mlp(h.reshape(s * wlen, -1), lp["router"], lp["w_gate"],
-                             lp["w_up"], lp["w_down"], cfg, mask=tok_mask)
-        down = y2.reshape(s, wlen, -1)
-    else:
-        gate = jnp.einsum("sld,df->slf", h, _qw(lp["w_gate"], dt))
-        up = jnp.einsum("sld,df->slf", h, _qw(lp["w_up"], dt))
-        down = jnp.einsum("slf,fd->sld", jax.nn.silu(gate) * up, _qw(lp["w_down"], dt))
-    return x + down, storage
-
-
-def _verify_block(x, lp, cfg: ModelConfig, ck, cv, lengths, active=None):
-    """Slot-layout verify: K/V scattered at absolute positions (writes past
-    max_len dropped)."""
-    pos = lengths[:, None] + jnp.arange(x.shape[1])[None, :]
-    rows = jnp.arange(x.shape[0])[:, None]
-
-    def cache_rw(k_new, v_new):
-        nk = ck.at[rows, pos].set(k_new.astype(ck.dtype), mode="drop")
-        nv = cv.at[rows, pos].set(v_new.astype(cv.dtype), mode="drop")
-        return nk, nv, (nk, nv)
-
-    x, (nk, nv) = _verify_core(x, lp, cfg, lengths, cache_rw, active=active)
-    return x, nk, nv
+    return DecodeState(k=nk, v=nv, lengths=lengths), logits
 
 
 def spec_accept(window, greedy, draft_len, active, lengths, rng, temperature,
@@ -330,36 +253,19 @@ def spec_driver(params, k0, v0, lengths, window, draft_len, active, cfg,
                 layers_pass=None):
     """Shared speculative-verify pipeline (embed -> layers -> norm -> head ->
     accept); the cache layout differs only in layer_fn(h, lp, k, v). MoE models
-    verify too: _verify_core routes the whole window through moe_mlp with
+    verify too: _window_core routes the whole window through moe_mlp with
     inactive slots masked out of expert capacity. `layers_pass(x) -> (x, nk,
     nv)` replaces the whole layer loop (the pp schedule owns its own loop)."""
-    x = params["embed"].astype(cfg.activation_dtype)[window]
-
+    x = llama.embed_tokens(params, window, cfg)
     if layers_pass is not None:
         x, nk, nv = layers_pass(x)
-    elif cfg.scan_layers:
-        def body(carry, xs):
-            h = carry
-            lp, a, b = xs
-            h, a, b = layer_fn(h, lp, a, b)
-            return h, (a, b)
-
-        x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], k0, v0))
     else:
-        nk, nv = [], []
-        for i, lp in enumerate(params["layers"]):
-            x, a, b = layer_fn(x, lp, k0[i], v0[i])
-            nk.append(a)
-            nv.append(b)
-        nk, nv = jnp.stack(nk), jnp.stack(nv)
-
-    x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("sld,dv->slv", x, _qw(head, cfg.activation_dtype))
-    greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+        x, nk, nv = _layer_loop(layer_fn, x, params["layers"], k0, v0)
+    logits = llama.output_head(params, x, cfg)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     greedy, n_acc, new_lengths = spec_accept(
         window, greedy, draft_len, active, lengths, rng, temperature,
-        top_p, top_k, logits[:, 0].astype(jnp.float32))
+        top_p, top_k, logits[:, 0])
     return nk, nv, new_lengths, greedy, n_acc
 
 
@@ -386,8 +292,8 @@ def spec_verify_step(
     nk, nv, lengths, greedy, n_acc = spec_driver(
         params, state.k, state.v, state.lengths, window, draft_len, active,
         cfg, rng, temperature, top_p, top_k,
-        lambda h, lp, ck, cv: _verify_block(h, lp, cfg, ck, cv, state.lengths,
-                                            active=active))
+        lambda h, lp, ck, cv: _slot_block(h, lp, cfg, ck, cv, state.lengths,
+                                          active))
     return DecodeState(k=nk, v=nv, lengths=lengths), greedy, n_acc
 
 
@@ -410,7 +316,7 @@ def spec_verify_step_pp(params, state: DecodeState, window, draft_len, active,
         return _pp_slot_layers(
             params, state.k, state.v, x, state.lengths, active, mesh, width=w,
             block_fn=lambda c, lp, ck, cv, ln, ac:
-                _verify_block(c, lp, cfg, ck, cv, ln, active=ac))
+                _slot_block(c, lp, cfg, ck, cv, ln, ac))
 
     nk, nv, lengths, greedy, n_acc = spec_driver(
         params, state.k, state.v, state.lengths, window, draft_len, active,
@@ -524,8 +430,8 @@ def spec_multi(
     return spec_multi_impl(
         params, state, hist, hlen, active, cfg, rngs, temperature, top_p,
         top_k, m, k, nmax, propose_fn or propose_ngram_device,
-        lambda st: lambda x, lp, ck, cv: _verify_block(
-            x, lp, cfg, ck, cv, st.lengths, active=active),
+        lambda st: lambda x, lp, ck, cv: _slot_block(
+            x, lp, cfg, ck, cv, st.lengths, active),
         lambda st, nk, nv, lengths: DecodeState(k=nk, v=nv, lengths=lengths))
 
 
@@ -657,13 +563,9 @@ def _pp_slot_layers(params, k0, v0, x, lengths, active, mesh: Mesh, *,
             k_mb = jax.lax.dynamic_slice_in_dim(k, jc * smb, smb, axis=1)
             v_mb = jax.lax.dynamic_slice_in_dim(v, jc * smb, smb, axis=1)
 
-            def lbody(c, xs):
-                lp, ck, cv = xs
-                h, ck, cv = block_fn(c, lp, ck, cv, mb_lengths, mb_active)
-                return h, (ck, cv)
-
-            h, (nk_mb, nv_mb) = jax.lax.scan(lbody, x_in,
-                                             (layers_local, k_mb, v_mb))
+            h, nk_mb, nv_mb = _layer_loop(
+                lambda c, lp, ck, cv: block_fn(c, lp, ck, cv, mb_lengths, mb_active),
+                x_in, layers_local, k_mb, v_mb)
             k_new = jax.lax.dynamic_update_slice_in_dim(k, nk_mb, jc * smb,
                                                         axis=1)
             v_new = jax.lax.dynamic_update_slice_in_dim(v, nv_mb, jc * smb,
@@ -698,17 +600,14 @@ def decode_step_pp(params, state: DecodeState, tokens: jax.Array, active: jax.Ar
     if s % (pp * dp):
         raise ValueError(f"max_num_seqs {s} must be divisible by pp*dp {pp * dp}")
 
-    x = params["embed"].astype(cfg.activation_dtype)[tokens[:, None]]  # [S,1,D]
+    x = llama.embed_tokens(params, tokens[:, None], cfg)  # [S,1,D]
     h, nk, nv = _pp_slot_layers(
         params, state.k, state.v, x, state.lengths, active, mesh, width=1,
         block_fn=lambda c, lp, ck, cv, ln, ac:
-            _decode_block(c, lp, cfg, ck, cv, ln, ac))
-
-    h = llama.rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("sld,dv->slv", h, _qw(head, cfg.activation_dtype))[:, 0]
+            _slot_block(c, lp, cfg, ck, cv, ln, ac))
+    logits = llama.output_head(params, h, cfg)[:, 0]
     lengths = jnp.where(active, state.lengths + 1, state.lengths)
-    return DecodeState(k=nk, v=nv, lengths=lengths), logits.astype(jnp.float32)
+    return DecodeState(k=nk, v=nv, lengths=lengths), logits
 
 
 # ------------------------------------------------------------------------- sampler

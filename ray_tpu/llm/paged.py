@@ -26,7 +26,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import llama
-from ray_tpu.ops.quant import as_weight as _qw
 from ray_tpu.models.config import ModelConfig
 
 from . import sampling
@@ -509,16 +508,18 @@ def install_with_prefix(
 
 # ------------------------------------------------------------------------- decode
 
-def _decode_block_paged(x, lp, cfg: ModelConfig, pk, pv, block_tables, lengths,
-                        active):
-    """One layer's paged decode for all slots: the shared layer math
-    (model_runner._decode_core) with a block-table cache adapter.
+def _paged_block(x, lp, cfg: ModelConfig, pk, pv, block_tables, lengths, active):
+    """One layer's window against the paged pool for all slots: the shared
+    layer math (model_runner._window_core) with a block-table cache adapter.
+    Decode is a window of one.
 
-    x [S,1,D]; pk/pv [NB, bs, KV, HD] (this layer's pool); reads gather each
+    x [S,W,D]; pk/pv [NB, bs, KV, HD] (this layer's pool); reads gather each
     slot's blocks into [S, max_len, KV, HD] (activation-only — the POOL is what
-    lives in HBM persistently), writes scatter the new token through the table.
+    lives in HBM persistently), writes scatter the window through the table.
+    The engine pre-grows every active slot's table by the window width, so all
+    window positions map to owned blocks.
     """
-    from .model_runner import _decode_core
+    from .model_runner import _window_core
 
     s = x.shape[0]
     nb_slot = block_tables.shape[1]
@@ -526,22 +527,27 @@ def _decode_block_paged(x, lp, cfg: ModelConfig, pk, pv, block_tables, lengths,
     max_len = nb_slot * bs
     kvh, hd = cfg.n_kv_heads, cfg.head_dim
 
-    def cache_rw(k_new, v_new):
+    def cache_rw(k_new, v_new, pos):  # pos [S,W]: the window's absolute positions
         # scatter through the block table (distinct active slots own distinct
         # blocks, so writes never collide); INACTIVE slots' tables may point at
-        # freed/re-owned blocks, so their writes land in the scratch block (the
-        # pool's last physical block, never allocated)
+        # freed/re-owned blocks, so their writes (and any position past the
+        # table) land in the scratch block (the pool's last physical block,
+        # never allocated)
         scratch = pk.shape[0] - 1
-        safe_idx = jnp.minimum(lengths // bs, nb_slot - 1)
-        write_block = jnp.where(active, block_tables[jnp.arange(s), safe_idx], scratch)
-        write_off = lengths % bs
+        blk_idx = pos // bs  # [S,W]
+        in_table = blk_idx < nb_slot
+        safe_idx = jnp.minimum(blk_idx, nb_slot - 1)
+        rows = jnp.arange(s)[:, None]
+        write_block = jnp.where(active[:, None] & in_table,
+                                block_tables[rows, safe_idx], scratch)
+        write_off = pos % bs
         nk = pk.at[write_block, write_off].set(k_new.astype(pk.dtype))
         nv = pv.at[write_block, write_off].set(v_new.astype(pv.dtype))
         ck = nk[block_tables].reshape(s, max_len, kvh, hd)
         cv = nv[block_tables].reshape(s, max_len, kvh, hd)
         return ck, cv, (nk, nv)
 
-    x, (nk, nv) = _decode_core(x, lp, cfg, lengths, active, cache_rw)
+    x, (nk, nv) = _window_core(x, lp, cfg, lengths, active, cache_rw)
     return x, nk, nv
 
 
@@ -550,31 +556,16 @@ def _decode_step_impl(params, k, v, block_tables, lengths, tokens, active,
     """One decode step against ONE pool (the whole pool, or — inside the dp
     shard_map — one replica's local shard). Raw arrays in/out so the same math
     serves the single-pool jit and the per-replica body."""
-    x = params["embed"].astype(cfg.activation_dtype)[tokens[:, None]]
+    from .model_runner import _layer_loop
 
-    if cfg.scan_layers:
-        def body(carry, xs):
-            h = carry
-            lp, pk, pv = xs
-            h, pk, pv = _decode_block_paged(h, lp, cfg, pk, pv,
-                                            block_tables, lengths, active)
-            return h, (pk, pv)
-
-        x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], k, v))
-    else:
-        nk, nv = [], []
-        for i, lp in enumerate(params["layers"]):
-            x, pk, pv = _decode_block_paged(x, lp, cfg, k[i], v[i],
-                                            block_tables, lengths, active)
-            nk.append(pk)
-            nv.append(pv)
-        nk, nv = jnp.stack(nk), jnp.stack(nv)
-
-    x = llama.rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("sld,dv->slv", x, _qw(head, cfg.activation_dtype))[:, 0]
+    x = llama.embed_tokens(params, tokens[:, None], cfg)  # [S,1,D]
+    x, nk, nv = _layer_loop(
+        lambda h, lp, pk, pv: _paged_block(h, lp, cfg, pk, pv, block_tables,
+                                           lengths, active),
+        x, params["layers"], k, v)
+    logits = llama.output_head(params, x, cfg)[:, 0]
     new_lengths = jnp.where(active, lengths + 1, lengths)
-    return nk, nv, new_lengths, logits.astype(jnp.float32)
+    return nk, nv, new_lengths, logits
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",), donate_argnames=("state",))
@@ -600,7 +591,7 @@ def _pp_paged_layers(params, state: PagedState, x, active, mesh: Mesh, *,
     pool rides the scan carry; block_fn(h, lp, pk, pv, bt_mb, ln_mb, act_eff)
     -> (h, pk, pv), where act_eff is False on bubble ticks so those writes
     land in the scratch block."""
-    from ray_tpu.llm.model_runner import _pp_schedule, _pp_shard_map
+    from ray_tpu.llm.model_runner import _layer_loop, _pp_schedule, _pp_shard_map
 
     m = mesh.shape["pp"]
     nb_slot = state.block_tables.shape[1]
@@ -617,12 +608,9 @@ def _pp_paged_layers(params, state: PagedState, x, active, mesh: Mesh, *,
             act_mb = (jax.lax.dynamic_slice(active_i, (jc * smb,), (smb,)) > 0)
             act_eff = act_mb & valid  # bubble ticks write only the scratch block
 
-            def lbody(c, xs):
-                lp, pk, pv = xs
-                h, pk, pv = block_fn(c, lp, pk, pv, bt_mb, ln_mb, act_eff)
-                return h, (pk, pv)
-
-            h, (nk, nv) = jax.lax.scan(lbody, x_in, (layers_local, k, v))
+            h, nk, nv = _layer_loop(
+                lambda c, lp, pk, pv: block_fn(c, lp, pk, pv, bt_mb, ln_mb, act_eff),
+                x_in, layers_local, k, v)
             return h, (nk, nv)
 
         outs, (k, v) = _pp_schedule(x_mb, (k_local, v_local), step_mb)
@@ -655,52 +643,15 @@ def decode_step_paged_pp(params, state: PagedState, tokens, active,
     if s % (pp * dp):
         raise ValueError(f"max_num_seqs {s} must be divisible by pp*dp {pp * dp}")
 
-    x = params["embed"].astype(cfg.activation_dtype)[tokens[:, None]]  # [S,1,D]
+    x = llama.embed_tokens(params, tokens[:, None], cfg)  # [S,1,D]
     h, nk, nv = _pp_paged_layers(
         params, state, x, active, mesh, width=1,
         block_fn=lambda c, lp, pk, pv, bt, ln, ac:
-            _decode_block_paged(c, lp, cfg, pk, pv, bt, ln, ac))
-
-    h = llama.rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("sld,dv->slv", h, _qw(head, cfg.activation_dtype))[:, 0]
+            _paged_block(c, lp, cfg, pk, pv, bt, ln, ac))
+    logits = llama.output_head(params, h, cfg)[:, 0]
     lengths = jnp.where(active, state.lengths + 1, state.lengths)
     return PagedState(k=nk, v=nv, block_tables=state.block_tables,
-                      lengths=lengths), logits.astype(jnp.float32)
-
-
-def _verify_block_paged(x, lp, cfg: ModelConfig, pk, pv, block_tables, lengths,
-                        active):
-    """Paged verify: the shared W-token window math with block-table writes.
-    The engine pre-grows every active slot's table by the window width, so all
-    window positions map to owned blocks; inactive slots (and any position
-    past the table) write to the scratch block."""
-    from .model_runner import _verify_core
-
-    s, wlen, _ = x.shape
-    nb_slot = block_tables.shape[1]
-    bs = pk.shape[1]
-    max_len = nb_slot * bs
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
-    pos = lengths[:, None] + jnp.arange(wlen)[None, :]  # [S,W]
-
-    def cache_rw(k_new, v_new):
-        scratch = pk.shape[0] - 1
-        blk_idx = pos // bs  # [S,W]
-        in_table = blk_idx < nb_slot
-        safe_idx = jnp.minimum(blk_idx, nb_slot - 1)
-        rows = jnp.arange(s)[:, None]
-        write_block = jnp.where(active[:, None] & in_table,
-                                block_tables[rows, safe_idx], scratch)
-        write_off = pos % bs
-        nk = pk.at[write_block, write_off].set(k_new.astype(pk.dtype))
-        nv = pv.at[write_block, write_off].set(v_new.astype(pv.dtype))
-        ck = nk[block_tables].reshape(s, max_len, kvh, hd)
-        cv = nv[block_tables].reshape(s, max_len, kvh, hd)
-        return ck, cv, (nk, nv)
-
-    x, (nk, nv) = _verify_core(x, lp, cfg, lengths, cache_rw, active=active)
-    return x, nk, nv
+                      lengths=lengths), logits
 
 
 def spec_verify_step_paged_pp(params, state: PagedState, window, draft_len,
@@ -709,7 +660,7 @@ def spec_verify_step_paged_pp(params, state: PagedState, window, draft_len,
     """Paged speculative verify through the pipeline schedule: the verify
     window is the microbatch payload, each stage holds its layers' pool slice,
     and bubble-tick writes redirect to the scratch block via the same
-    active-mask plumbing _verify_block_paged already has. Composes with dp
+    active-mask plumbing _paged_block already has. Composes with dp
     (replica pool partitions) exactly like decode_step_paged_pp."""
     from .model_runner import spec_driver
 
@@ -723,7 +674,7 @@ def spec_verify_step_paged_pp(params, state: PagedState, window, draft_len,
         return _pp_paged_layers(
             params, state, x, active, mesh, width=w,
             block_fn=lambda c, lp, pk, pv, bt, ln, ac:
-                _verify_block_paged(c, lp, cfg, pk, pv, bt, ln, ac))
+                _paged_block(c, lp, cfg, pk, pv, bt, ln, ac))
 
     nk, nv, lengths, greedy, n_acc = spec_driver(
         params, state.k, state.v, state.lengths, window, draft_len, active,
@@ -752,7 +703,7 @@ def spec_verify_step_paged(
     nk, nv, lengths, greedy, n_acc = spec_driver(
         params, state.k, state.v, state.lengths, window, draft_len, active,
         cfg, rng, temperature, top_p, top_k,
-        lambda h, lp, pk, pv: _verify_block_paged(
+        lambda h, lp, pk, pv: _paged_block(
             h, lp, cfg, pk, pv, state.block_tables, state.lengths, active))
     return PagedState(k=nk, v=nv, block_tables=state.block_tables,
                       lengths=lengths), greedy, n_acc
@@ -788,7 +739,7 @@ def spec_multi_paged(
     return spec_multi_impl(
         params, state, hist, hlen, active, cfg, rngs, temperature, top_p,
         top_k, m, k, nmax, propose_fn or propose_ngram_device,
-        lambda st: lambda x, lp, pk, pv: _verify_block_paged(
+        lambda st: lambda x, lp, pk, pv: _paged_block(
             x, lp, cfg, pk, pv, st.block_tables, st.lengths, active),
         lambda st, nk, nv, lengths: PagedState(
             k=nk, v=nv, block_tables=st.block_tables, lengths=lengths))
@@ -1035,7 +986,7 @@ def spec_verify_step_paged_dp(params, state: PagedState, window, draft_len,
         rr = jax.random.fold_in(rr, jax.lax.axis_index("dp"))
         nk, nv, lengths, greedy, n_acc = spec_driver(
             p, k, v, ln, win, dl, act, cfg, rr, tt, tp_, tk,
-            lambda h, lp, pk, pv: _verify_block_paged(h, lp, cfg, pk, pv,
+            lambda h, lp, pk, pv: _paged_block(h, lp, cfg, pk, pv,
                                                       bt, ln, act))
         return nk, nv, lengths, greedy, n_acc
 
@@ -1068,7 +1019,7 @@ def spec_multi_paged_dp(params, state: PagedState, hist, hlen, active,
         st, toks_m, acc_m, drafted_m = spec_multi_impl(
             p, st, hh, hl, act, cfg, rr, tt, tp_, tk, m, k, nmax,
             propose_ngram_device,
-            lambda s: lambda x, lp, kk, vv: _verify_block_paged(
+            lambda s: lambda x, lp, kk, vv: _paged_block(
                 x, lp, cfg, kk, vv, s.block_tables, s.lengths, act),
             lambda s, nk, nv, lengths: PagedState(
                 k=nk, v=nv, block_tables=s.block_tables, lengths=lengths))

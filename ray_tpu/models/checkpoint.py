@@ -255,21 +255,13 @@ def load_llama_params(
         "final_norm": put(readers["final_norm"](), axes["final_norm"]),
     }
     fields = _layer_fields(cfg)
-    if cfg.scan_layers:
-        layers = {}
-        for field in fields:
-            read = readers["layer"](field)
-            stacked = np.stack([np.asarray(read(i)) for i in range(cfg.n_layers)])
-            layers[field] = put(stacked, axes["layers"][field])
-            del stacked  # one leaf resident at a time
-        params["layers"] = layers
-    else:
-        params["layers"] = [
-            {field: put(np.asarray(readers["layer"](field)(i)),
-                        axes["layers"][i][field])
-             for field in fields}
-            for i in range(cfg.n_layers)
-        ]
+    layers = {}
+    for field in fields:
+        read = readers["layer"](field)
+        stacked = np.stack([np.asarray(read(i)) for i in range(cfg.n_layers)])
+        layers[field] = put(stacked, axes["layers"][field])
+        del stacked  # one leaf resident at a time
+    params["layers"] = layers
     if not cfg.tie_embeddings:
         params["lm_head"] = put(readers["lm_head"](), axes["lm_head"])
     return params
@@ -301,10 +293,8 @@ def save_llama_params(params: Params, cfg: ModelConfig, out_dir: str) -> str:
         return arr.astype(np.float32) if arr.dtype not in (np.float32, np.float16) else arr
 
     def layer(i):
-        if cfg.scan_layers:
-            return {f: jax.tree.map(lambda x: x[i], params["layers"][f])
-                    for f in _layer_fields(cfg)}
-        return params["layers"][i]
+        return {f: jax.tree.map(lambda x: x[i], params["layers"][f])
+                for f in _layer_fields(cfg)}
 
     tensors: Dict[str, np.ndarray] = {
         "model.embed_tokens.weight": host(params["embed"]),

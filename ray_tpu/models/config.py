@@ -30,7 +30,6 @@ class ModelConfig:
     # and recompute only cheap elementwise ops (more HBM, fewer recomputed FLOPs —
     # higher MFU when activations fit). none == remat=False.
     remat_policy: str = "full"  # full | dots | dots_no_batch | none
-    scan_layers: bool = True  # stack layer params + lax.scan (fast compile)
     # Attention backend: auto|pallas|reference|ring|ulysses. ring/ulysses are the
     # sequence-parallel collectives (ops/ring_attention.py) — use with an sp>1 mesh.
     attention_impl: str = "auto"
@@ -93,7 +92,6 @@ register_config(
         d_ff=128,
         max_seq_len=128,
         dtype="float32",
-        scan_layers=True,
     )
 )
 register_config(
@@ -108,7 +106,6 @@ register_config(
         d_ff=128,
         max_seq_len=256,
         dtype="float32",
-        scan_layers=True,
     )
 )
 register_config(
@@ -192,7 +189,6 @@ register_config(
         d_ff=96,
         max_seq_len=128,
         dtype="float32",
-        scan_layers=True,
         n_experts=4,
         moe_top_k=2,
     )

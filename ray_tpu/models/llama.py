@@ -29,11 +29,10 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------- init
 
 def param_axes(cfg: ModelConfig) -> Params:
-    """Logical-axis tree mirroring init() output (leading 'layer' axis when scanned)."""
-    lyr = ("layer",) if cfg.scan_layers else ()
+    """Logical-axis tree mirroring init() output (layers stacked on a leading 'layer' axis)."""
 
     def L(*axes):
-        return lyr + axes
+        return ("layer",) + axes
 
     layers = {
         "attn_norm": L("embed"),
@@ -55,7 +54,7 @@ def param_axes(cfg: ModelConfig) -> Params:
         })
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": layers if cfg.scan_layers else [dict(layers) for _ in range(cfg.n_layers)],
+        "layers": layers,
         "final_norm": ("embed",),
     }
     if not cfg.tie_embeddings:
@@ -95,15 +94,9 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             })
         return out
 
-    layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    if cfg.scan_layers:
-        layers = jax.vmap(layer_init)(layer_keys)
-    else:
-        layers = [layer_init(k) for k in layer_keys]
-
     params: Params = {
         "embed": norm(k_emb, (cfg.vocab_size, d), 1.0),
-        "layers": layers,
+        "layers": jax.vmap(layer_init)(jax.random.split(k_layers, cfg.n_layers)),
         "final_norm": jnp.ones((d,), jnp.float32),
     }
     if not cfg.tie_embeddings:
@@ -117,7 +110,7 @@ def _maybe_remat(body, cfg: ModelConfig):
     """Per-layer rematerialization with a selectable policy (cfg.remat_policy):
     'full' recomputes everything; 'dots' saves matmul outputs so only cheap
     elementwise ops replay in the backward pass (XLA's usual MFU sweet spot)."""
-    policy = getattr(cfg, "remat_policy", "full")
+    policy = cfg.remat_policy
     if not cfg.remat or policy == "none":
         return body
     if policy == "dots":
@@ -131,8 +124,15 @@ def _maybe_remat(body, cfg: ModelConfig):
     return jax.checkpoint(body)
 
 
-def _embed_lookup(table: jax.Array, tokens: jax.Array) -> jax.Array:
-    """Token embedding lookup, sharding-aware.
+# A row shorter than this is looked up with a gather whatever the mesh: the
+# smallest prefill bucket (llm/config.py:buckets) is 16, so only a decode token
+# and a speculative window (k drafts + 1) fall below it.
+_ONE_HOT_MIN_ROW = 16
+
+
+def embed_tokens(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Token embedding lookup, sharding-aware: tokens [B, S] -> [B, S, D] in the
+    activation dtype.
 
     When the vocab dim is sharded (tp>1) a plain gather carries a transposed-
     device-order output sharding that GSPMD can only reconcile with the batch-sharded
@@ -141,20 +141,23 @@ def _embed_lookup(table: jax.Array, tokens: jax.Array) -> jax.Array:
     the vocab shard — GSPMD turns that into a local dot + psum over tp, the
     embed/fsdp dim flows through, and the op lands on the MXU. With vocab unsharded
     (tp=1, incl. single device) the cheaper gather is kept: embed-dim (fsdp) sharding
-    flows through a gather cleanly. Single-token decode (S==1) also keeps the gather
-    — one row per sequence is too small for the resharding cost to matter and the
-    matmul would add vocab*d FLOPs per token. (Sharding-in-types can't see Auto-axis
+    flows through a gather cleanly. What decides between them is the row's length
+    S: a decode token (S == 1) or a verify window (S = drafts + 1 < 16) keeps the
+    gather — a few rows per sequence are too small for the resharding cost to
+    matter, no constraint follows them in the serving programs, and the matmul
+    would add vocab*d FLOPs per token. (Sharding-in-types can't see Auto-axis
     specs, so the gate is the mesh's tp extent, not the table's actual spec.)
     Semantics note: out-of-range token ids clamp under gather but embed to zeros
     under the one-hot path; valid inputs (< vocab_size) are identical.
     """
+    table = params["embed"].astype(cfg.activation_dtype)
     try:
         mesh = jax.sharding.get_abstract_mesh()
         sharded = mesh is not None and not mesh.empty and mesh.shape.get("tp", 1) > 1
     # graftlint: allow[swallowed-exception] degrades to the coded fallback (sharded = False) by design
     except Exception:
         sharded = False
-    if not sharded or tokens.shape[-1] == 1:
+    if not sharded or tokens.shape[-1] < _ONE_HOT_MIN_ROW:
         return table[tokens]
     onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=table.dtype)
     return jnp.einsum("bsv,vd->bsd", onehot, table)
@@ -177,6 +180,68 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+# --------------------------------------------------------------------------- block
+# The decoder block's arithmetic, written once. A program is these parts around an
+# attention over its own cache: _block (train step, prefill: ops.attention or ring
+# attention) and llm/model_runner.py:_window_core (decode and the verify window:
+# per-slot lengths over a layout adapter's cache view). Math only — a sharding
+# constraint comes from the caller, so the serving programs carry none.
+
+def _unconstrained(x: jax.Array, *logical_axes) -> jax.Array:
+    return x
+
+
+def qkv_proj(x: jax.Array, lp: Params, cfg: ModelConfig, positions: jax.Array):
+    """Attention's inputs for one layer: norm, the three projections, RoPE.
+    x [B, S, D], positions [B, S] -> q [B, S, H, hd], k and v [B, S, KV, hd]."""
+    dt = x.dtype
+    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
+    k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
+    v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
+    return rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta), v
+
+
+def attn_out(x: jax.Array, attn: jax.Array, lp: Params) -> jax.Array:
+    """Output projection of attn [B, S, H, hd] and the residual."""
+    return x + jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], x.dtype))
+
+
+def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
+                 token_mask: Optional[jax.Array] = None, constrain=_unconstrained):
+    """Norm, the dense or MoE feed-forward, residual. Returns (x, moe aux loss).
+    token_mask [B, S] (1 = real) keeps pad tokens and inactive slots out of the
+    experts' capacity; `constrain(array, *logical_axes)` is the caller's sharding
+    constraint on the dense product."""
+    dt = x.dtype
+    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    if cfg.n_experts > 0:
+        from . import moe as _moe
+
+        b, s, d = h.shape
+        y2, aux = _moe.moe_mlp(
+            h.reshape(b * s, d), lp["router"], lp["w_gate"], lp["w_up"],
+            lp["w_down"], cfg,
+            mask=None if token_mask is None else token_mask.reshape(b * s),
+        )
+        down = y2.reshape(b, s, d)
+    else:
+        gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
+        up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
+        ff = constrain(jax.nn.silu(gate) * up, "batch", "seq", "act_mlp")
+        down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
+        aux = jnp.zeros((), jnp.float32)
+    return x + down, aux
+
+
+def output_head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """Final norm and the (tied or untied) head: x [B, S, D] -> f32 logits [B, S, vocab]."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("bsd,dv->bsv", x, _w(head, cfg.activation_dtype))
+    return logits.astype(jnp.float32)
 
 
 # ------------------------------------------------------------------------- forward
@@ -212,16 +277,11 @@ def _block(
     token_mask: Optional[jax.Array] = None,
 ):
     """One decoder block. Returns (x, updated (k,v) if caching, moe aux loss)."""
-    dt = x.dtype
     # named scopes: metadata only (free at run time); what a reader of the
     # profile uses to tell one fusion from another
     with jax.named_scope("attn"):
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
-        k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
-        v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
-        q = wsc(rope(q, positions, cfg.rope_theta), "batch", "seq", "act_heads", "head_dim")
-        k = rope(k, positions, cfg.rope_theta)
+        q, k, v = qkv_proj(x, lp, cfg, positions)
+        q = wsc(q, "batch", "seq", "act_heads", "head_dim")
 
         new_kv = None
         if cache_kv is not None:
@@ -257,28 +317,11 @@ def _block(
         else:
             attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
                              shard_spec=auto_spec("batch", None, "act_heads", None))
-        o = jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], dt))
-        x = wsc(x + o, "batch", "seq", "act_embed")
+        x = wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed")
 
     with jax.named_scope("mlp"):
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.n_experts > 0:
-            from . import moe as _moe
-
-            b, s, d = h.shape
-            y2, aux = _moe.moe_mlp(
-                h.reshape(b * s, d), lp["router"], lp["w_gate"], lp["w_up"],
-                lp["w_down"], cfg,
-                mask=None if token_mask is None else token_mask.reshape(b * s),
-            )
-            down = y2.reshape(b, s, d)
-        else:
-            gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
-            up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
-            ff = wsc(jax.nn.silu(gate) * up, "batch", "seq", "act_mlp")
-            down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
-            aux = jnp.zeros((), jnp.float32)
-        x = wsc(x + down, "batch", "seq", "act_embed")
+        x, aux = feed_forward(x, lp, cfg, token_mask, constrain=wsc)
+        x = wsc(x, "batch", "seq", "act_embed")
     return x, new_kv, aux
 
 
@@ -304,8 +347,6 @@ def _pipeline_layers(
     pp = cfg.pipeline_stages
     if cfg.n_layers % pp:
         raise ValueError(f"n_layers {cfg.n_layers} not divisible by pipeline_stages {pp}")
-    if not cfg.scan_layers:
-        raise ValueError("pipeline_stages > 1 requires scan_layers=True (stacked params)")
     layers = params["layers"]
     stacked = jax.tree_util.tree_map(
         lambda p: p.reshape(pp, cfg.n_layers // pp, *p.shape[1:]), layers
@@ -384,60 +425,32 @@ def forward(
         start = cache.length if cache is not None else 0
         positions = jnp.broadcast_to(jnp.arange(s)[None, :] + start, (b, s))
     with jax.named_scope("embed"):
-        x = _embed_lookup(params["embed"].astype(cfg.activation_dtype), tokens)
-        x = wsc(x, "batch", "seq", "act_embed")
-    aux_total = jnp.zeros((), jnp.float32)
+        x = wsc(embed_tokens(params, tokens, cfg), "batch", "seq", "act_embed")
 
+    new_cache = None
     if cfg.pipeline_stages > 1 and cache is None:
         x, aux_total = _pipeline_layers(x, params, cfg, positions, segment_ids,
                                         token_mask)
-        new_cache = None
-    elif cfg.scan_layers:
-        if cache is not None:
-
-            def body(carry, xs):
-                h = carry
-                lp, ck, cv = xs
-                h, new_kv, aux = _block(h, lp, cfg, positions, segment_ids, (ck, cv),
-                                        cache.length, token_mask)
-                return h, (new_kv, aux)
-
-            fn = _maybe_remat(body, cfg)
-            x, ((nk, nv), auxs) = jax.lax.scan(fn, x, (params["layers"], cache.k, cache.v))
-            new_cache = KVCache(k=nk, v=nv, length=cache.length + s)
-            aux_total = auxs.sum()
-        else:
-
-            def body(carry, lp):
-                h, _, aux = _block(carry, lp, cfg, positions, segment_ids,
-                                   token_mask=token_mask)
-                return h, aux
-
-            fn = _maybe_remat(body, cfg)
-            x, auxs = jax.lax.scan(fn, x, params["layers"])
-            new_cache = None
-            aux_total = auxs.sum()
     else:
-        new_cache = None
-        ks, vs = [], []
-        for i, lp in enumerate(params["layers"]):
-            if cache is not None:
-                x, kv, aux = _block(x, lp, cfg, positions, segment_ids,
-                                    (cache.k[i], cache.v[i]), cache.length, token_mask)
-                ks.append(kv[0])
-                vs.append(kv[1])
-            else:
-                x, _, aux = _block(x, lp, cfg, positions, segment_ids,
-                                   token_mask=token_mask)
-            aux_total = aux_total + aux
+        # one loop: a layer's parameters and, when there is a cache, its K/V
+        # (None is an empty pytree: the scan then carries no K/V in or out)
+        cache_len = None if cache is None else cache.length
+
+        def body(h, xs):
+            lp, cache_kv = xs
+            h, new_kv, aux = _block(h, lp, cfg, positions, segment_ids, cache_kv,
+                                    cache_len, token_mask)
+            return h, (new_kv, aux)
+
+        x, (new_kv, auxs) = jax.lax.scan(
+            _maybe_remat(body, cfg), x,
+            (params["layers"], None if cache is None else (cache.k, cache.v)))
+        aux_total = auxs.sum()
         if cache is not None:
-            new_cache = KVCache(jnp.stack(ks), jnp.stack(vs), cache.length + s)
+            new_cache = KVCache(k=new_kv[0], v=new_kv[1], length=cache.length + s)
 
     with jax.named_scope("lm_head"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,dv->bsv", x, _w(head, cfg.activation_dtype))
-        logits = wsc(logits.astype(jnp.float32), "batch", "seq", "act_vocab")
+        logits = wsc(output_head(params, x, cfg), "batch", "seq", "act_vocab")
     if return_aux:
         return logits, new_cache, aux_total
     return logits, new_cache

@@ -245,19 +245,10 @@ def attention(
         # The pallas kernel's causal mask assumes query row i is absolute position i,
         # i.e. Sq == Skv; any offset/partial-window shape takes the XLA path.
         same_len = q.shape[1] == k.shape[1]
-        # Mosaic tiles the lane (last) dim at 128 and sublanes at 8, and the
-        # kernel requires seqs to be block-multiples once they exceed one
-        # block: geometries the kernel can't tile (head_dim 16, seq 16, kv 20,
-        # seq 520...) must fall back to XLA or TPU compile fails
-        # ("slice shape must be aligned to tiling")
-        def seq_ok(n: int, block: int) -> bool:
-            return n % 8 == 0 and (n <= block or n % block == 0)
+        # geometries the kernel can't tile must fall back to XLA or TPU compile fails
+        from . import flash_attention as _fa
 
-        from ray_tpu.config import CONFIG
-
-        tileable = (q.shape[-1] % 128 == 0
-                    and seq_ok(q.shape[1], CONFIG.flash_block_q)
-                    and seq_ok(k.shape[1], CONFIG.flash_block_kv))
+        tileable = _fa.supports(q.shape[1], k.shape[1], q.shape[-1])
         if (on_tpu and tileable and q_offset is None and kv_valid_len is None
                 and (same_len or not causal)):
             impl = "pallas"

@@ -19,7 +19,7 @@ the kernel, so dK/dV are written once per kv head.
 The block a grid step fetches is not the tile a product computes. A step of the
 forward and dQ kernels owns one q tile and a SPAN of K/V rows, a step of dK/dV one kv
 tile and a span of the q rows of its group's query heads; a `fori_loop` inside the
-kernel walks the span's compute tiles (`block_q` x `block_kv`, the knobs), in ascending
+kernel walks the span's compute tiles (`block_q` x `block_kv`), in ascending
 order, with the running statistics and accumulators in VMEM scratch throughout. The
 span is derived (`_tiling`): all of the sequence where its blocks fit `SPAN_VMEM_BYTES`
 (at head_dim 128 in bf16: K/V up to 16,384 rows, the Q/dO of a group of four up to
@@ -52,7 +52,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# tile sizes live in the flag registry: CONFIG.flash_block_q / flash_block_kv
+# Rows of the kernels' COMPUTE tile on the query and on the key/value side (a multiple
+# of 128; a shorter sequence is one tile): the size of the products, not of what a grid
+# step fetches (the span, `_tiling`). Measured on a v5e at [6, 2048, 32/8, 128] bf16
+# causal (PERF.md, PR 28): 512 x 512 tiles with a span a step run the forward, dQ and
+# dK/dV kernels in 2.05 / 2.65 / 3.00 ms, where 1,024 x 1,024 with a tile a step took
+# 2.48 / 3.00 / 3.43 and 512 x 512 with a tile a step 2.72 / 3.47 / 3.46.
+BLOCK_Q = 512
+BLOCK_KV = 512
 NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract the minor dimension of both
 _NN = (((1,), (0,)), ((), ()))  # a @ b
@@ -64,6 +71,18 @@ _NN = (((1,), (0,)), ((), ()))  # a @ b
 # tile's blocks, accumulators and f32 intermediates (~6 MB at 512 x 512).
 VMEM_LIMIT_BYTES = 32 << 20
 SPAN_VMEM_BYTES = VMEM_LIMIT_BYTES // 2
+
+
+def supports(sq: int, skv: int, head_dim: int, block_q: int = BLOCK_Q,
+             block_kv: int = BLOCK_KV) -> bool:
+    """Whether the kernels can tile this geometry. Mosaic tiles the lane (last) dim at
+    128 and sublanes at 8, and a sequence longer than one compute tile must be a whole
+    number of them (`_block_sizes`): head_dim 16, seq 20 or seq 520 would fail the TPU
+    compile ("slice shape must be aligned to tiling")."""
+    def seq_ok(n: int, block: int) -> bool:
+        return n % 8 == 0 and (n <= block or n % block == 0)
+
+    return head_dim % 128 == 0 and seq_ok(sq, block_q) and seq_ok(skv, block_kv)
 
 
 def _block_sizes(sq: int, skv: int, bq: int, bkv: int):
@@ -570,15 +589,10 @@ def flash_attention(
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,  # [B, Skv]
     scale: Optional[float] = None,
-    block_q: Optional[int] = None,
-    block_kv: Optional[int] = None,
+    block_q: int = BLOCK_Q,
+    block_kv: int = BLOCK_KV,
 ) -> jax.Array:
     """BSHD flash attention. Sq must equal Skv when segment_ids are used."""
-    if block_q is None or block_kv is None:
-        from ray_tpu.config import CONFIG
-
-        block_q = block_q if block_q is not None else CONFIG.flash_block_q
-        block_kv = block_kv if block_kv is not None else CONFIG.flash_block_kv
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d**0.5)
     qt = q.transpose(0, 2, 1, 3)

@@ -1,8 +1,9 @@
 """Canonical jitted train step: loss -> grads -> optax update, GSPMD-sharded.
 
-This is the compute core `JaxTrainer` drives; it is also what `__graft_entry__` and
-`bench.py` exercise. One function builds the whole step so XLA fuses grad + update and
-the optimizer state inherits the parameter shardings (ZeRO-for-free under fsdp).
+This is the compute core `JaxTrainer` drives, and what the benchmark's two train cells
+measure (`benchmarks/drivers/train.py`). One function builds the whole step so XLA fuses
+grad + update and the optimizer state inherits the parameter shardings (ZeRO-for-free
+under fsdp).
 """
 from __future__ import annotations
 

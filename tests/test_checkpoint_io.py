@@ -36,10 +36,37 @@ def test_roundtrip_exact(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_roundtrip_unscanned_layers(tmp_path):
-    cfg = _cfg(scan_layers=False)
+def _saved_tensors(ckpt_dir):
+    from safetensors.numpy import load_file
+
+    out = {}
+    for name in os.listdir(ckpt_dir):
+        if name.endswith(".safetensors"):
+            out.update(load_file(os.path.join(ckpt_dir, name)))
+    return out
+
+
+def test_roundtrip_layers_by_hf_name(tmp_path):
+    """Row i of every stacked leaf is saved under HF's `model.layers.{i}.` names
+    (and read back from them): a passed cfg, distinct values per layer."""
+    cfg = _cfg()
     params = llama.init(jax.random.PRNGKey(1), cfg)
     ckpt_io.save_llama_params(params, cfg, str(tmp_path / "ckpt"))
+    saved = _saved_tensors(str(tmp_path / "ckpt"))
+    d, ly = cfg.d_model, params["layers"]
+    for i in range(cfg.n_layers):
+        pre = f"model.layers.{i}."
+        for hf, want in (
+            ("input_layernorm.weight", ly["attn_norm"][i]),
+            ("self_attn.q_proj.weight", ly["wq"][i].reshape(d, -1).T),
+            ("self_attn.k_proj.weight", ly["wk"][i].reshape(d, -1).T),
+            ("self_attn.v_proj.weight", ly["wv"][i].reshape(d, -1).T),
+            ("self_attn.o_proj.weight", ly["wo"][i].reshape(-1, d).T),
+            ("mlp.gate_proj.weight", ly["w_gate"][i].T),
+            ("mlp.up_proj.weight", ly["w_up"][i].T),
+            ("mlp.down_proj.weight", ly["w_down"][i].T),
+        ):
+            np.testing.assert_array_equal(saved[pre + hf], np.asarray(want))
     loaded = ckpt_io.load_llama_params(
         str(tmp_path / "ckpt"), cfg=cfg, param_dtype=jnp.float32)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(loaded)):
@@ -198,11 +225,22 @@ def test_moe_roundtrip_exact(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_moe_roundtrip_unscanned(tmp_path):
-    cfg = _cfg(**MOE_TINY, scan_layers=False)
+def test_moe_roundtrip_layers_by_hf_name(tmp_path):
+    cfg = _cfg(**MOE_TINY)
     params = llama.init(jax.random.PRNGKey(7), cfg)
     src = str(tmp_path / "ckpt")
     ckpt_io.save_llama_params(params, cfg, src)
+    saved = _saved_tensors(src)
+    ly = params["layers"]
+    for i in range(cfg.n_layers):
+        moe_pre = f"model.layers.{i}.block_sparse_moe."
+        np.testing.assert_array_equal(saved[moe_pre + "gate.weight"],
+                                      np.asarray(ly["router"][i].T))
+        for j in range(cfg.n_experts):
+            for hf, field in (("w1", "w_gate"), ("w3", "w_up"), ("w2", "w_down")):
+                np.testing.assert_array_equal(
+                    saved[moe_pre + f"experts.{j}.{hf}.weight"],
+                    np.asarray(ly[field][i, j].T))
     loaded = ckpt_io.load_llama_params(src, cfg=cfg, param_dtype=jnp.float32)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(loaded)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from ray_tpu.models import get_config, llama
 from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
@@ -113,3 +114,151 @@ def test_remat_policies_agree():
         loss, _ = ll.loss_fn(params, {"tokens": tokens}, cfg)
         losses[pol] = float(loss)
     assert losses["full"] == losses["dots"] == losses["dots_no_batch"], losses
+
+
+# ------------------------------------------------- one block, every program (PR 29)
+
+def _serving_inputs(cfg, layout, slots=4, max_len=32, block_size=8, seed=3):
+    """A decode state of `layout` with distinct per-slot histories (prefilled
+    through the model, so the cache holds real K/V), the last token of every
+    slot, and one inactive slot."""
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm import paged
+
+    params = llama.init(jax.random.PRNGKey(seed), cfg)
+    rng = np.random.default_rng(seed)
+    lens = [5, 9, 3, 7][:slots]
+    if layout == "slot":
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+        state = mr.init_state(cfg, slots, max_len, mesh)
+    else:
+        n_per_slot = max_len // block_size
+        state = paged.init_paged_state(cfg, slots, max_len, slots * n_per_slot, block_size)
+    for s, n in enumerate(lens):
+        toks = np.zeros((1, 16), np.int32)
+        toks[0, :n] = rng.integers(1, cfg.vocab_size, n)
+        k, v, _ = mr.prefill_detached(params, jnp.asarray(toks), jnp.int32(n), cfg)
+        if layout == "slot":
+            state = mr.install_kv(state, k, v, jnp.int32(n), jnp.int32(s))
+        else:
+            # the slot's whole table, so a window past the prompt has blocks to land in
+            row = jnp.arange(s * n_per_slot, (s + 1) * n_per_slot, dtype=jnp.int32)
+            state = paged.install_with_prefix(
+                state, k, v, row[:16 // block_size], row, jnp.int32(n), jnp.int32(s),
+                n_new=16 // block_size)
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, slots), jnp.int32)
+    active = jnp.asarray([True, True, False, True][:slots])
+    return params, state, tokens, active
+
+
+def _copy(state):
+    return jax.tree.map(jnp.copy, state)  # the programs donate their state
+
+
+def _slot_view(state, s, n):
+    """Slot s's first n cached positions as [L, n, KV, HD], either layout."""
+    if hasattr(state, "block_tables"):
+        bs = state.k.shape[2]
+        blocks = state.block_tables[s]
+        k = state.k[:, blocks].reshape(state.k.shape[0], -1, *state.k.shape[3:])
+        v = state.v[:, blocks].reshape(state.v.shape[0], -1, *state.v.shape[3:])
+        assert k.shape[1] == blocks.shape[0] * bs
+        return k[:, :n], v[:, :n]
+    return state.k[:, s, :n], state.v[:, s, :n]
+
+
+SEAM_PROGRAMS = ["forward", "prefill_detached", "decode_step", "decode_step_paged",
+                 "spec_verify_step", "spec_verify_step_paged"]
+
+
+@pytest.mark.parametrize("program", SEAM_PROGRAMS)
+def test_every_program_goes_through_the_one_feed_forward(program, monkeypatch):
+    """The block is written once: tracing any program that runs the model calls
+    llama.feed_forward (and, with it, the other parts beside it). A fourth copy
+    of the block in a serving program would pass every parity test and fail this."""
+    import dataclasses
+
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm import paged
+
+    calls = []
+    real = llama.feed_forward
+
+    def marked(x, lp, cfg, *a, **kw):
+        calls.append(x.shape)
+        return real(x, lp, cfg, *a, **kw)
+
+    monkeypatch.setattr(llama, "feed_forward", marked)
+    # a cfg of its own: the jitted programs key their traces on it
+    cfg = dataclasses.replace(CFG, name=f"seam-{program}")
+    layout = "paged" if program.endswith("paged") else "slot"
+    params, state, tokens, active = _serving_inputs(cfg, layout)
+    calls.clear()  # _serving_inputs prefilled through the model
+    s = tokens.shape[0]
+    sample = (jax.random.PRNGKey(0), jnp.zeros((s,)), jnp.ones((s,)),
+              jnp.zeros((s,), jnp.int32))
+    window = jnp.stack([tokens, tokens, tokens], axis=1)
+    if program == "forward":
+        llama.forward(params, window, cfg)
+    elif program == "prefill_detached":
+        mr.prefill_detached(params, jnp.zeros((1, 32), jnp.int32), jnp.int32(4), cfg)
+    elif program == "decode_step":
+        mr.decode_step(params, state, tokens, active, cfg)
+    elif program == "decode_step_paged":
+        paged.decode_step_paged(params, state, tokens, active, cfg)
+    elif program == "spec_verify_step":
+        mr.spec_verify_step(params, state, window, jnp.full((s,), 2, jnp.int32),
+                            active, cfg, *sample)
+    else:
+        paged.spec_verify_step_paged(params, state, window, jnp.full((s,), 2, jnp.int32),
+                                     active, cfg, *sample)
+    assert calls, f"{program} traced no call of llama.feed_forward"
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("model", ["test-tiny", "moe-tiny"])
+def test_decode_is_a_window_of_one(model, layout):
+    """A decode step is the verify window at W=1, and both are the block that
+    prefill runs: for every slot, decode_step's logits and the K/V it wrote equal
+    (a) a one-token verify step's token, cache and lengths, and (b) llama.forward
+    of that token against the slot's own cache (the train/prefill body, scalar
+    offsets, ops.attention), which holds the window core to _block."""
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm import paged
+
+    cfg = get_config(model)
+    params, state, tokens, active = _serving_inputs(cfg, layout)
+    s = tokens.shape[0]
+    lens = np.asarray(state.lengths)
+    step, verify = ((mr.decode_step, mr.spec_verify_step) if layout == "slot"
+                    else (paged.decode_step_paged, paged.spec_verify_step_paged))
+    before = _copy(state)
+    dec, logits = step(params, _copy(state), tokens, active, cfg)
+    ver, greedy, n_acc = verify(
+        params, _copy(state), tokens[:, None], jnp.zeros((s,), jnp.int32), active, cfg,
+        jax.random.PRNGKey(0), jnp.zeros((s,)), jnp.ones((s,)), jnp.zeros((s,), jnp.int32))
+
+    # (a) the one-token window: same token, same cache, same lengths
+    np.testing.assert_array_equal(np.asarray(greedy[:, 0]), np.asarray(jnp.argmax(logits, -1)))
+    np.testing.assert_array_equal(np.asarray(n_acc), 0)
+    np.testing.assert_array_equal(np.asarray(ver.lengths), np.asarray(dec.lengths))
+    np.testing.assert_array_equal(np.asarray(dec.lengths), lens + np.asarray(active))
+    for i in np.flatnonzero(np.asarray(active)):
+        for a, b in zip(_slot_view(ver, i, lens[i] + 1), _slot_view(dec, i, lens[i] + 1)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # (b) the prefill body on each slot alone
+    for i in np.flatnonzero(np.asarray(active)):
+        n = int(lens[i])
+        k, v = _slot_view(before, i, n)
+        pad = ((0, 0), (0, 0), (0, 1), (0, 0), (0, 0))
+        cache = llama.KVCache(k=jnp.pad(k[:, None], pad), v=jnp.pad(v[:, None], pad),
+                              length=jnp.int32(n))
+        want, cache = llama.forward(params, tokens[i][None, None], cfg, cache=cache)
+        np.testing.assert_allclose(np.asarray(logits[i]), np.asarray(want[0, 0]),
+                                   rtol=2e-5, atol=2e-5)
+        got_k, got_v = _slot_view(dec, i, n + 1)
+        np.testing.assert_allclose(np.asarray(got_k), np.asarray(cache.k[:, 0]),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(got_v), np.asarray(cache.v[:, 0]),
+                                   rtol=1e-6, atol=1e-6)

@@ -64,6 +64,29 @@ def test_pressure_spills_lru_and_gets_still_work(small_store_cluster):
         assert v[0] == i and len(v) == 128 * 1024
 
 
+def test_spill_frees_memory_after_the_directory_names_the_file(small_store_cluster,
+                                                              monkeypatch):
+    """A get that resolved an object's arena location just before the spill took
+    the memory asks the directory again: by then it must name the disk copy (a
+    put object has no lineage to fall back on). So spill_lru frees an object's
+    memory only after it swapped the entry."""
+    store = small_store_cluster.store
+    stale = []
+    real = object_store.free_local
+
+    def checking(loc):
+        with store._lock:
+            stale.append(loc in store._locations.values())
+        real(loc)
+
+    monkeypatch.setattr(object_store, "free_local", checking)
+    refs = [ray_tpu.put(np.full(128 * 1024, i, np.float64)) for i in range(4)]
+    assert store.spill_lru(2 << 20, small_store_cluster.spill_dir) >= 2 << 20
+    assert stale and not any(stale)
+    for i, r in enumerate(refs):
+        assert ray_tpu.get(r)[0] == i
+
+
 def test_lineage_reconstruction_after_loss(small_store_cluster):
     cluster = small_store_cluster
 
