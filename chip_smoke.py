@@ -90,11 +90,13 @@ def _device_report():
 
 def _kernel_phase(shape: dict, seed: int) -> dict:
     """Compiled (not interpreted) flash attention, forward and backward,
-    against attention_reference, with and without segment ids."""
+    against attention_reference, with and without segment ids; and the rotate
+    kernel in front of it against models/llama.py:rope."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.core.accelerators import check_worker_platform
+    from ray_tpu.models.llama import rope
     from ray_tpu.ops import flash_attention as fa
     from ray_tpu.ops.attention import attention_reference
 
@@ -108,6 +110,34 @@ def _kernel_phase(shape: dict, seed: int) -> dict:
     # three packed documents per row
     seg = (jnp.arange(s)[None, :] // (-(-s // 3))).astype(jnp.int32).repeat(b, 0)
     out = dict(_device_report(), interpreted=bool(fa._interpret()), cases={})
+
+    def err(a, ref):
+        a, ref = a.astype(jnp.float32), ref.astype(jnp.float32)
+        return float(jnp.max(jnp.abs(a - ref))), float(jnp.max(jnp.abs(ref)))
+
+    def case(hlo, errs):
+        return {
+            "kernel_in_program": "tpu_custom_call" in hlo,
+            "max_abs_err": {n: e for n, (e, _) in errs.items()},
+            "ref_max_abs": {n: m for n, (_, m) in errs.items()},
+            "worst_relative": max(e / m for e, m in errs.values()),
+        }
+
+    # the rotate kernel: every row of positions starts somewhere else
+    pos = jnp.arange(s, dtype=jnp.int32)[None, :] + 1000 * jnp.arange(b, dtype=jnp.int32)[:, None]
+    cts = (g.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3))
+
+    def rotated(fn):
+        def both(q, k):
+            rot, vjp = jax.vjp(fn, q, k)
+            return (*rot, *vjp(cts))
+        return jax.jit(both)
+
+    kernel = rotated(lambda q, k: fa.rope_to_heads(q, k, pos, 1e6))
+    plain = rotated(lambda q, k: tuple(rope(x, pos, 1e6).transpose(0, 2, 1, 3) for x in (q, k)))
+    out["cases"]["rope"] = case(
+        kernel.lower(q, k).compile().as_text(),
+        {n: err(a, ref) for n, a, ref in zip(("q", "k", "dq", "dk"), kernel(q, k), plain(q, k))})
     for name, segment_ids in (("causal", None), ("segment_ids", seg)):
         def run(fn):
             def loss(q, k, v):
@@ -119,17 +149,8 @@ def _kernel_phase(shape: dict, seed: int) -> dict:
         hlo = flash.lower(q, k, v).compile().as_text()
         (_, o1), g1 = flash(q, k, v)
         (_, o2), g2 = run(attention_reference)(q, k, v)
-        def err(a, ref):
-            a, ref = a.astype(jnp.float32), ref.astype(jnp.float32)
-            return float(jnp.max(jnp.abs(a - ref))), float(jnp.max(jnp.abs(ref)))
-
-        errs = {"fwd": err(o1, o2), **{f"d{n}": err(a, ref) for n, a, ref in zip("qkv", g1, g2)}}
-        out["cases"][name] = {
-            "kernel_in_program": "tpu_custom_call" in hlo,
-            "max_abs_err": {n: e for n, (e, _) in errs.items()},
-            "ref_max_abs": {n: m for n, (_, m) in errs.items()},
-            "worst_relative": max(e / m for e, m in errs.values()),
-        }
+        out["cases"][name] = case(hlo, {
+            "fwd": err(o1, o2), **{f"d{n}": err(a, ref) for n, a, ref in zip("qkv", g1, g2)}})
     return out
 
 

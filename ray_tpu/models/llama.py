@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import attention
+from ray_tpu.ops.attention import ROTATED_NAMES, Rotation
 from ray_tpu.ops.quant import as_weight as _w
 from ray_tpu.parallel.sharding import auto_spec
 from ray_tpu.parallel.sharding import with_sharding_constraint as wsc
@@ -113,11 +114,17 @@ def _maybe_remat(body, cfg: ModelConfig):
     policy = cfg.remat_policy
     if not cfg.remat or policy == "none":
         return body
+    # the rotated q and k (ops/flash_attention.py names them) are kept beside the matmul
+    # outputs: the backward of the projections needs dQ and dK, never their own output,
+    # so the un-rotated pair is dropped for them and the rotation is not run again
+    policies = jax.checkpoint_policies
+    rotated = policies.save_only_these_names(*ROTATED_NAMES)
     if policy == "dots":
-        return jax.checkpoint(body, policy=jax.checkpoint_policies.checkpoint_dots)
-    if policy == "dots_no_batch":
         return jax.checkpoint(
-            body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+            body, policy=policies.save_from_both_policies(policies.checkpoint_dots, rotated))
+    if policy == "dots_no_batch":
+        return jax.checkpoint(body, policy=policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, rotated))
     if policy != "full":
         raise ValueError(
             f"unknown remat_policy {policy!r} (expected full | dots | dots_no_batch | none)")
@@ -193,14 +200,17 @@ def _unconstrained(x: jax.Array, *logical_axes) -> jax.Array:
     return x
 
 
-def qkv_proj(x: jax.Array, lp: Params, cfg: ModelConfig, positions: jax.Array):
+def qkv_proj(x: jax.Array, lp: Params, cfg: ModelConfig, positions: Optional[jax.Array]):
     """Attention's inputs for one layer: norm, the three projections, RoPE.
-    x [B, S, D], positions [B, S] -> q [B, S, H, hd], k and v [B, S, KV, hd]."""
+    x [B, S, D], positions [B, S] -> q [B, S, H, hd], k and v [B, S, KV, hd].
+    Without positions q and k come back un-rotated: the caller hands the rotation on."""
     dt = x.dtype
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
     k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
     v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
+    if positions is None:
+        return q, k, v
     return rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta), v
 
 
@@ -280,7 +290,10 @@ def _block(
     # named scopes: metadata only (free at run time); what a reader of the
     # profile uses to tell one fusion from another
     with jax.named_scope("attn"):
-        q, k, v = qkv_proj(x, lp, cfg, positions)
+        # ops.attention rotates q and k itself (in its kernel's own pass over them, where
+        # the Pallas path runs); a cache or the ring takes them rotated
+        deferred = cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
+        q, k, v = qkv_proj(x, lp, cfg, None if deferred else positions)
         q = wsc(q, "batch", "seq", "act_heads", "head_dim")
 
         new_kv = None
@@ -316,7 +329,8 @@ def _block(
                 )
         else:
             attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
-                             shard_spec=auto_spec("batch", None, "act_heads", None))
+                             shard_spec=auto_spec("batch", None, "act_heads", None),
+                             rotation=Rotation(positions, cfg.rope_theta, rope))
         x = wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed")
 
     with jax.named_scope("mlp"):
@@ -421,9 +435,9 @@ def forward(
     With return_aux=True also returns the summed MoE load-balancing loss (zero for
     dense configs) as a third element."""
     b, s = tokens.shape
-    if positions is None:
+    if positions is None:  # one row, which every row of the batch shares
         start = cache.length if cache is not None else 0
-        positions = jnp.broadcast_to(jnp.arange(s)[None, :] + start, (b, s))
+        positions = jnp.arange(s)[None, :] + start
     with jax.named_scope("embed"):
         x = wsc(embed_tokens(params, tokens, cfg), "batch", "seq", "act_embed")
 

@@ -8,11 +8,26 @@ Shapes (batch, seq, heads, head_dim) throughout — "BSHD".
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+
+
+# checkpoint names of the rotated q and k where the Pallas path's rotate kernel made
+# them (ops/flash_attention.py), for a remat policy that keeps them
+ROTATED_NAMES = ("rope_q", "rope_k")
+
+
+class Rotation(NamedTuple):
+    """A rotary embedding that q and k still lack when they reach `attention`, which
+    then applies it: in the Pallas path's own rotate kernel (rotate-half,
+    ops/flash_attention.py:rope_to_heads), elsewhere as `apply(x, positions, theta)`,
+    the caller's jax.numpy statement of the rotation."""
+
+    positions: jax.Array  # [B, S], or [1, S] where every row of the batch shares them
+    theta: float
+    apply: Callable[[jax.Array, jax.Array, float], jax.Array]
 
 
 def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
@@ -191,30 +206,36 @@ def _log_fallback(q_shape, k_shape, impl: str) -> None:
     )
 
 
-def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec):
+def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotation=None):
     """The Pallas kernel under an ambient mesh. GSPMD cannot partition a Mosaic
     kernel, and Mosaic refuses to lower while ANY mesh axis is still
     automatic, so the kernel is called per shard with every such axis made
     manual around it. `shard_spec` is the caller's layout of q/k/v over those
     axes; axes an enclosing region already bound manually (a pipeline stage,
     the bucketed grad sync) stay so. Without a spec, or with nothing left to
-    split, the kernel is called as it is."""
+    split, the kernel is called as it is. With a `rotation` q and k come un-rotated, and
+    the rotate kernel runs in the same region, on each shard's own positions."""
     from jax.sharding import PartitionSpec as P
 
     from .flash_attention import flash_attention
 
-    kernel = functools.partial(flash_attention, causal=causal, scale=scale)
+    def kernel(q, k, v, rows):
+        return flash_attention(q, k, v, causal=causal, scale=scale, segment_ids=rows.get("seg"),
+                               rope=None if rotation is None else (rows["pos"], rotation.theta))
+
+    # what comes a row of the batch (or one row for all of it): segment ids, positions
+    rows = {} if segment_ids is None else {"seg": segment_ids}
+    if rotation is not None:
+        rows["pos"] = rotation.positions
     mesh = jax.sharding.get_abstract_mesh()
     auto = set(mesh.axis_names) - set(mesh.manual_axes)
     if shard_spec is None or not any(mesh.shape[a] > 1 for a in auto):
-        return kernel(q, k, v, segment_ids=segment_ids)
-    args, specs = (q, k, v), (shard_spec,) * 3
-    if segment_ids is not None:
-        args, specs = args + (segment_ids,), specs + (P(shard_spec[0], None),)
+        return kernel(q, k, v, rows)
+    row_specs = {name: P(shard_spec[0] if x.shape[0] > 1 else None, None)
+                 for name, x in rows.items()}
     return jax.shard_map(
-        lambda q, k, v, seg=None: kernel(q, k, v, segment_ids=seg),
-        in_specs=specs, out_specs=shard_spec, axis_names=auto,
-        check_vma=False)(*args)
+        kernel, in_specs=(shard_spec,) * 3 + (row_specs,),
+        out_specs=shard_spec, axis_names=auto, check_vma=False)(q, k, v, rows)
 
 
 def attention(
@@ -229,6 +250,7 @@ def attention(
     kv_valid_len: Optional[jax.Array] = None,
     impl: str = "auto",
     shard_spec=None,
+    rotation: Optional[Rotation] = None,
 ) -> jax.Array:
     """Dispatching attention. impl: auto|pallas|chunked|reference.
 
@@ -239,6 +261,10 @@ def attention(
     over the ambient mesh, as a PartitionSpec whose seq entry is None (each
     shard a whole attention problem). Only the Pallas path needs it, to run
     the kernel per shard; the XLA paths are partitioned by GSPMD.
+
+    rotation: q and k come without their rotary embedding. Where the Pallas path runs
+    (and q and k are one sequence's), its rotate kernel applies it in front of the
+    flash kernels; every other path applies `rotation.apply` first.
     """
     if impl == "auto":
         on_tpu = jax.default_backend() not in ("cpu", "gpu")
@@ -264,9 +290,12 @@ def attention(
                 and q_offset is None and kv_valid_len is None
                 and (same_len or not causal)):
             _log_fallback(q.shape, k.shape, impl)
+    if rotation is not None and not (impl == "pallas" and q.shape[1] == k.shape[1]):
+        q, k = (rotation.apply(x, rotation.positions, rotation.theta) for x in (q, k))
+        rotation = None
     if impl == "pallas":
-        return _flash_per_shard(q, k, v, causal=causal, segment_ids=segment_ids,
-                                scale=scale, shard_spec=shard_spec)
+        return _flash_per_shard(q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
+                                shard_spec=shard_spec, rotation=rotation)
     if impl == "chunked":
         return attention_chunked(
             q,

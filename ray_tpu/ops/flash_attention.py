@@ -49,8 +49,11 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .attention import ROTATED_NAMES
 
 # Rows of the kernels' COMPUTE tile on the query and on the key/value side (a multiple
 # of 128; a shorter sequence is one tile): the size of the products, not of what a grid
@@ -168,14 +171,15 @@ def _rows(x, block: int):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
-def _pallas_call(kernel, *, name: str, **kw):
-    """A four-dimensional grid whose last dimension accumulates. `name` is the
-    operation's name in the device trace (the benchmark's kernel metrics select by it)."""
+def _pallas_call(kernel, *, name: str,
+                 semantics=("parallel", "parallel", "parallel", "arbitrary"), **kw):
+    """The flash kernels' grid has four dimensions, the last of which accumulates.
+    `name` is the operation's name in the device trace (the benchmark's kernel
+    metrics select by it)."""
     return pl.pallas_call(
         kernel, name=name, interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            dimension_semantics=semantics, vmem_limit_bytes=VMEM_LIMIT_BYTES),
         **kw)
 
 
@@ -548,6 +552,115 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
     return dq, dk, dv
 
 
+# --------------------------------------------------------- RoPE, in front of the kernels
+# q and k reach the flash kernels rotated and head-major, [B, H, S, D]. The layout costs
+# nothing: XLA lays a projection's output out for its consumer, and with head_dim the
+# lane width a matmul writes [B, H, S, D] as cheaply as [B, S, H, D] (compiled for a
+# v5e, both train cells: the projection fusions feed the kernel below, no copy between).
+# The rotation is then one pass over HBM in that layout: a grid step reads `rows`
+# positions of every head of q and k, rotates each lane-dense [rows, D] tile in f32
+# registers and writes it back in the inputs' dtype. Rotate-half, `x * cos + roll(x, D/2)
+# * sin_signed`, both halves' angles side by side and the sign folded into the sine: the
+# arithmetic of models/llama.py:rope, f32 throughout (on the chip: bit-equal to it). Its
+# transpose is the same kernel with the conjugate angle, so the backward keeps nothing
+# but the positions. Measured on a v5e at [6, 2048, 32/8, 128] bf16 (PERF.md, PR 30):
+# 0.37 ms a run forward or backward, 0.25 GB read and written = 83 % of the HBM roofline,
+# where the jax.numpy rope and the transpose took 3.6 ms for the two.
+
+
+def _rope_tables(positions: jax.Array, d: int, theta: float):
+    """cos and signed sine of every position's angles: positions [R, S] -> [R, S, D] f32."""
+    freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    return jnp.concatenate([cos, cos], axis=-1), jnp.concatenate([-sin, sin], axis=-1)
+
+
+def _rope_rows(s: int, row_bytes: int) -> int:
+    """Positions a grid step of the rotate kernel moves: the most (a multiple of 16
+    that divides the sequence, at most 512) whose blocks, both pipeline buffers, fit
+    `SPAN_VMEM_BYTES`; a short or odd sequence is one block."""
+    fits = [r for r in range(16, min(s, 512) + 1, 16)
+            if s % r == 0 and 2 * r * row_bytes <= SPAN_VMEM_BYTES]
+    return max(fits) if fits else s
+
+
+def _rope_kernel(*refs, n: int, conjugate: bool):
+    """refs: n inputs [H, rows, D], cos and signed sine [rows, D], n outputs. The heads
+    are walked by a loop, not unrolled: 40 copies of the body were two seconds of
+    tracing at every process start, compile cache or not (a cached first step 5.14 s
+    against 3.20), for 1 % of the kernel's time (PERF.md, PR 30)."""
+    cos_ref, sin_ref = refs[n].at[0], refs[n + 1].at[0]
+    for x_ref, o_ref in zip(refs[:n], refs[n + 2:]):
+        def head(h, x_ref=x_ref, o_ref=o_ref):
+            x = x_ref[0, h].astype(jnp.float32)
+            turned = pltpu.roll(x, x.shape[1] // 2, 1) * sin_ref[:]
+            y = x * cos_ref[:] - turned if conjugate else x * cos_ref[:] + turned
+            o_ref[0, h] = y.astype(o_ref.dtype)
+
+        _walk(0, x_ref.shape[1], x_ref.shape[1], head)
+
+
+def _rope_call(xs, positions, theta: float, conjugate: bool):
+    """Rotate every array of `xs` ([B, H, S, D], any head counts) in one kernel call, by
+    the positions' angles or (`conjugate`) back. positions [B, S], or [1, S] where every
+    row of the batch shares them: then one row of angles is made and every batch row's
+    grid steps fetch it."""
+    b, _, s, d = xs[0].shape
+    per_row = positions.shape[0] > 1
+    rows = _rope_rows(s, 2 * sum(x.shape[1] * d * x.dtype.itemsize for x in xs) + 2 * d * 4)
+    blocks = [pl.BlockSpec((1, x.shape[1], rows, d), lambda bi, si: (bi, 0, si, 0)) for x in xs]
+    angles = pl.BlockSpec((1, rows, d), lambda bi, si: (bi if per_row else 0, si, 0))
+    return _pallas_call(
+        functools.partial(_rope_kernel, n=len(xs), conjugate=conjugate),
+        name="rope_bwd" if conjugate else "rope_fwd",
+        semantics=("parallel", "parallel"),
+        grid=(b, s // rows),
+        in_specs=blocks + [angles] * 2,
+        out_specs=blocks,
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in xs],
+    )(*xs, *_rope_tables(positions, d, theta))
+
+
+def _heads_major(x):
+    """[B, S, H, D] <-> [B, H, S, D]: a view, which XLA gives its producer's layout."""
+    return x.transpose(0, 2, 1, 3)
+
+
+def _rotated(q, k, positions, theta):
+    return tuple(_rope_call((_heads_major(q), _heads_major(k)), positions, theta, False))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def rope_to_heads(q, k, positions, theta):
+    """q [B, S, H, D], k [B, S, Hkv, D] un-rotated, positions [B or 1, S] -> the rotated q
+    [B, H, S, D] and k [B, Hkv, S, D], as the flash kernels read them. Differentiated,
+    the two carry `ROTATED_NAMES` for a remat policy to keep."""
+    return _rotated(q, k, positions, theta)
+
+
+def _named_bits(x, name: str):
+    """x under `name` for a remat policy to keep, as its bits. jax.checkpoint rounds a
+    float residual once more where it is made (`reduce_precision`, against XLA's excess
+    precision); after a kernel, which wrote the dtype itself, that rounds nothing and
+    is a pass over HBM of its own (a stand-alone `reduce-precision` of each array a
+    layer, compiled for a v5e). The policy saves integers as they are."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
+    return jax.lax.bitcast_convert_type(checkpoint_name(bits, name), x.dtype)
+
+
+def _rope_fwd_rule(q, k, positions, theta):
+    out = _rotated(q, k, positions, theta)
+    return tuple(_named_bits(x, name) for x, name in zip(out, ROTATED_NAMES)), positions
+
+
+def _rope_bwd_rule(theta, positions, cts):
+    return (*(_heads_major(g) for g in _rope_call(cts, positions, theta, True)), None)
+
+
+rope_to_heads.defvjp(_rope_fwd_rule, _rope_bwd_rule)
+
+
 # ----------------------------------------------------------------------- public API
 
 
@@ -591,13 +704,14 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = BLOCK_Q,
     block_kv: int = BLOCK_KV,
+    rope: Optional[tuple] = None,  # (positions [B or 1, S], theta): q and k come un-rotated
 ) -> jax.Array:
-    """BSHD flash attention. Sq must equal Skv when segment_ids are used."""
+    """BSHD flash attention. Sq must equal Skv when segment_ids are used, and with
+    `rope`: then the rotate kernel runs in front of the flash kernels (`rope_to_heads`)."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d**0.5)
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    qt, kt = (_heads_major(q), _heads_major(k)) if rope is None else rope_to_heads(q, k, *rope)
+    vt = _heads_major(v)
     seg = None if segment_ids is None else _segment_lanes(segment_ids, q.shape[1])
     out = _flash_bhsd(qt, kt, vt, seg, scale, causal, block_q, block_kv)
-    return out.transpose(0, 2, 1, 3)
+    return _heads_major(out)

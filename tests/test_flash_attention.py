@@ -199,3 +199,86 @@ def test_products_take_the_inputs_dtype():
     assert len(found) == 2 + 3 + 4  # forward, dQ, dK/dV
     for ins, out in found:
         assert ins == (jnp.bfloat16, jnp.bfloat16) and out == jnp.float32, (ins, out)
+
+
+# ------------------------------------------------ the rotate-and-lay-out kernel (PR 30)
+
+def _positions(kind, b, s):
+    """offset: every row starts at its own offset and strides by two (RoPE is shift-
+    invariant under attention, not here: the kernel's output is the rotated q itself);
+    restart: documents packed into a row, each counting from zero, cut differently a row;
+    shared: one row of positions for the whole batch."""
+    if kind == "shared":
+        return (jnp.arange(s, dtype=jnp.int32) * 3 + 7)[None, :]
+    if kind == "offset":
+        return (jnp.arange(s, dtype=jnp.int32)[None, :] * 2
+                + 1000 * (1 + jnp.arange(b, dtype=jnp.int32))[:, None])
+    cuts = [max(1, s // 3) + r for r in range(b)]
+    return jnp.stack([jnp.where(jnp.arange(s) < c, jnp.arange(s), jnp.arange(s) - c)
+                      for c in cuts]).astype(jnp.int32)
+
+
+@pytest.mark.parametrize("positions", ["offset", "restart", "shared"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("h,hkv", [(2, 2), (4, 1)], ids=["mha", "gqa4"])
+@pytest.mark.parametrize("s", [8, 64, 512, 1536])
+def test_rope_kernel_is_rope_then_transpose(s, h, hkv, dtype, positions):
+    """`rope_to_heads` against models/llama.py:rope followed by the transpose the flash
+    kernels' layout wants: values, and both gradients under a random cotangent. Both
+    compute in f32 and round once, so they differ by a rounding of the last place at
+    most (a fused multiply-add on one side)."""
+    from ray_tpu.models.llama import rope
+
+    b, d, theta = 2, 128, 1e4
+    q, k = _rand((b, s, h, d), 0, dtype), _rand((b, s, hkv, d), 1, dtype)
+    cts = _rand((b, h, s, d), 2, dtype), _rand((b, hkv, s, d), 3, dtype)
+    pos = _positions(positions, b, s)
+
+    def want(q, k):
+        return tuple(rope(x, pos, theta).transpose(0, 2, 1, 3) for x in (q, k))
+
+    got, got_vjp = jax.vjp(lambda q, k: fa.rope_to_heads(q, k, pos, theta), q, k)
+    ref, ref_vjp = jax.vjp(want, q, k)
+    ulp = 2.0 ** (-7 if dtype == jnp.bfloat16 else -22)
+    for name, a, r in zip(("q", "k", "dq", "dk"), got + got_vjp(cts), ref + ref_vjp(cts)):
+        assert a.dtype == dtype and a.shape == r.shape, name
+        np.testing.assert_allclose(np.asarray(a, np.float32), np.asarray(r, np.float32),
+                                   rtol=ulp, atol=ulp, err_msg=name)
+
+
+def test_rope_rows_are_derived():
+    """A grid step moves as many positions as fit the budget in whole blocks: 256 of
+    Mistral's 40 heads in bf16 (10.5 MB with both buffers), the whole of a short or odd
+    sequence, fewer of f32."""
+    row = 2 * 40 * 128 * 2 + 2 * 128 * 4  # q and k in and out, the two angle tiles
+    assert fa._rope_rows(2048, row) == 256 and fa._rope_rows(1536, row) == 384
+    assert fa._rope_rows(200, row) == 200 and fa._rope_rows(8, row) == 8
+    assert fa._rope_rows(2048, 2 * row) == 128
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["plain", "packed"])
+def test_flash_attention_rotates_in_front(segments):
+    """flash_attention(rope=...) on un-rotated q and k is flash_attention on rotated
+    ones: output and the three gradients."""
+    from ray_tpu.models.llama import rope
+
+    b, s, h, hkv, d, theta = 2, 128, 4, 2, 128, 1e4
+    q, k, v = _rand((b, s, h, d), 0), _rand((b, s, hkv, d), 1), _rand((b, s, hkv, d), 2)
+    g = _rand((b, s, h, d), 3)
+    seg = _packed(b, s, (50,)) if segments else None
+    pos = _positions("restart" if segments else "offset", b, s)
+
+    def run(rotate_in_kernel):
+        def loss(q, k, v):
+            if rotate_in_kernel:
+                o = flash_attention(q, k, v, causal=True, segment_ids=seg, rope=(pos, theta),
+                                    block_q=64, block_kv=64)
+            else:
+                o = flash_attention(rope(q, pos, theta), rope(k, pos, theta), v, causal=True,
+                                    segment_ids=seg, block_q=64, block_kv=64)
+            return jnp.sum(o * g), o
+        (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (o, *grads)
+
+    for name, a, r in zip(("out", "dq", "dk", "dv"), run(True), run(False)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5, atol=1e-5, err_msg=name)

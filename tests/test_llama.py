@@ -262,3 +262,137 @@ def test_decode_is_a_window_of_one(model, layout):
                                    rtol=1e-6, atol=1e-6)
         np.testing.assert_allclose(np.asarray(got_v), np.asarray(cache.v[:, 0]),
                                    rtol=1e-6, atol=1e-6)
+
+
+# ------------------------------------- RoPE in front of the flash kernels (PR 30)
+
+def _eqns(jaxpr, out=None):
+    """Every equation of a program, nested calls included."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _eqns(sub, out)
+    return out
+
+
+def _kernel_names(jaxpr):
+    return [e.params["name"] for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+def _pallas_cfg(**kw):
+    """test-tiny at the lane width the kernels tile (head_dim 128), forced onto the
+    Pallas path (interpreted on the CPU)."""
+    import dataclasses
+
+    return dataclasses.replace(CFG, **{"name": "rope-in-front", "d_model": 256, "n_heads": 2,
+                                       "n_kv_heads": 1, "attention_impl": "pallas", **kw})
+
+
+def test_train_step_rotates_in_the_kernel():
+    """On the Pallas path the train step's loss holds the rotate kernel once a phase
+    (the rotated q and k are kept for the backward under remat `dots`) and no
+    concatenate of q's or k's halves: `rope`'s split-and-join is not in the program."""
+    cfg = _pallas_cfg(remat_policy="dots")
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.zeros((2, 33), jnp.int32)
+    grad = jax.make_jaxpr(jax.grad(lambda p: llama.loss_fn(p, {"tokens": tokens}, cfg)[0]))(params)
+    names = _kernel_names(grad.jaxpr)
+    assert names.count("rope_fwd") == 1 and names.count("rope_bwd") == 1, names
+    assert names.count("flash_attention_fwd") == 2, names  # once more in the backward
+    joins = [e.outvars[0].aval.shape for e in _eqns(grad.jaxpr) if e.primitive.name == "concatenate"]
+    assert joins and all(len(shape) == 3 for shape in joins), joins  # the angles' tables only
+    # `full` keeps nothing: the rotation runs again in the backward
+    full = _pallas_cfg(remat_policy="full")
+    names = _kernel_names(jax.make_jaxpr(jax.grad(
+        lambda p: llama.loss_fn(p, {"tokens": tokens}, full)[0]))(params).jaxpr)
+    assert names.count("rope_fwd") == 2 and names.count("rope_bwd") == 1, names
+
+
+@pytest.mark.parametrize("program", ["prefill_detached", "decode_step", "decode_step_paged",
+                                     "spec_verify_step"])
+def test_serving_programs_hold_no_kernel(program):
+    """Cached prefill and the decode window take the XLA attention path and the
+    jax.numpy `rope` (PERF.md, PR 28 (a)): no Pallas kernel in any of them, also with
+    the model forced onto the Pallas path where it can take it."""
+    from ray_tpu.llm import model_runner as mr
+    from ray_tpu.llm import paged
+
+    cfg = _pallas_cfg(attention_impl="auto", name=f"no-kernel-{program}")
+    layout = "paged" if program.endswith("paged") else "slot"
+    params, state, tokens, active = _serving_inputs(cfg, layout)
+    s = tokens.shape[0]
+    if program == "prefill_detached":
+        jaxpr = jax.make_jaxpr(lambda p, t: mr.prefill_detached(p, t, jnp.int32(20), cfg))(
+            params, jnp.zeros((1, 512), jnp.int32))
+    elif program == "decode_step":
+        jaxpr = jax.make_jaxpr(lambda p, st: mr.decode_step(p, st, tokens, active, cfg))(params, state)
+    elif program == "decode_step_paged":
+        jaxpr = jax.make_jaxpr(lambda p, st: paged.decode_step_paged(p, st, tokens, active, cfg))(
+            params, state)
+    else:
+        sample = (jax.random.PRNGKey(0), jnp.zeros((s,)), jnp.ones((s,)), jnp.zeros((s,), jnp.int32))
+        window = jnp.stack([tokens, tokens, tokens], axis=1)
+        jaxpr = jax.make_jaxpr(lambda p, st: mr.spec_verify_step(
+            p, st, window, jnp.full((s,), 2, jnp.int32), active, cfg, *sample))(params, state)
+    assert _kernel_names(jaxpr.jaxpr) == []
+    assert any(e.primitive.name == "concatenate" and len(e.outvars[0].aval.shape) == 4
+               for e in _eqns(jaxpr.jaxpr)), "rope's join of the halves is in the program"
+
+
+@pytest.mark.parametrize("policy", ["dots", "dots_no_batch"])
+def test_remat_keeps_the_rotated_pair(policy):
+    """Under the `dots` policies a layer's residuals hold the rotated q and k (as the
+    kernel wrote them, by name) and v, and not the q and k projections' own outputs,
+    which nothing in the backward reads."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    cfg = _pallas_cfg(remat_policy=policy)
+    params = llama.init(jax.random.PRNGKey(0), cfg)
+    lp = jax.tree.map(lambda x: x[0], params["layers"])
+    b, s = 2, 32
+    x = jnp.zeros((b, s, cfg.d_model), jnp.float32)
+    pos = jnp.arange(s)[None, :]
+    body = llama._maybe_remat(lambda x, lp: llama._block(x, lp, cfg, pos, None)[0], cfg)
+    kept = [(aval.shape, why) for aval, why in saved_residuals(body, x, lp)]
+    named = {why.split("'")[1]: shape for shape, why in kept if why.startswith("named")}
+    assert named == {"rope_q": (b, cfg.n_heads, s, cfg.head_dim),
+                     "rope_k": (b, cfg.n_kv_heads, s, cfg.head_dim)}, kept
+    from_proj = [shape for shape, why in kept if "(qkv_proj)" in why]
+    assert from_proj == [(b, s, cfg.n_kv_heads, cfg.head_dim)], kept  # v alone
+
+
+@pytest.mark.parametrize("case", ["plain", "packed", "mesh", "packed-mesh"])
+def test_rotating_in_the_kernel_is_the_model(case):
+    """The forced-Pallas forward (rotation in the kernel, interpreted) against the same
+    model on the XLA path (`rope`, then attention_reference): logits and the gradients
+    of every parameter; packed rows restart their positions; under a dp x fsdp mesh the
+    kernels run per shard with their shard's positions."""
+    import contextlib
+
+    pallas, xla = _pallas_cfg(), _pallas_cfg(attention_impl="reference")
+    params = llama.init(jax.random.PRNGKey(0), pallas)
+    b, s = 4, 64
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (b, s), 0, pallas.vocab_size)
+    g = jax.random.normal(jax.random.PRNGKey(2), (b, s, pallas.vocab_size), jnp.float32)
+    kw = {}
+    if case.startswith("packed"):
+        cuts = np.asarray([20, 31, 40, 7])[:, None]
+        at = np.arange(s)[None, :]
+        kw = {"segment_ids": jnp.asarray((at >= cuts).astype(np.int32)),
+              "positions": jnp.asarray(np.where(at < cuts, at, at - cuts).astype(np.int32))}
+
+    def run(cfg):
+        def loss(p):
+            logits, _ = llama.forward(p, tokens, cfg, **kw)
+            return jnp.sum(logits * g), logits
+        (_, logits), grads = jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+        return logits, grads
+
+    mesh = build_mesh(MeshSpec(dp=2, fsdp=2), jax.devices()[:4]) if case.endswith("mesh") else None
+    with use_mesh(mesh) if mesh is not None else contextlib.nullcontext():
+        got, want = run(pallas), run(xla)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]), rtol=2e-4, atol=2e-4)
+    for a, r in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        scale = max(1.0, float(jnp.max(jnp.abs(r))))
+        np.testing.assert_allclose(np.asarray(a) / scale, np.asarray(r) / scale, rtol=0, atol=5e-4)

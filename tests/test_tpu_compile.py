@@ -110,6 +110,37 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
         assert len(kernels) == (1 if "fwd" in path else 2), (path, kernels)
 
 
+@pytest.mark.parametrize("b,s,h,kv,per_row", [
+    (6, 2048, 32, 8, False),   # mistral7b-train-1chip: one row of positions for the batch
+    (4, 2048, 32, 8, False),   # a chip's shard of mistral7b-train-fsdp4
+    (2, 4096, 32, 8, True),    # packed documents: positions a row
+    (2, 200, 32, 8, True),     # one block, not a multiple of 16
+    (8, 2048, 12, 6, False),   # llama-500m
+])
+def test_rope_kernel_compiles(one_chip, on_tpu, b, s, h, kv, per_row):
+    """The rotate kernel in front of the flash kernels, forward and backward, at the
+    cells' widths: two custom calls by their trace names, 256 positions of all 40 heads
+    a grid step at Mistral's widths."""
+    from ray_tpu.ops import flash_attention as fa
+
+    d = 128
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((b if per_row else 1, s), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, pos):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, rope=(pos, 1e6)).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, pos).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for name in ("rope_fwd", "rope_bwd"):
+        assert sum(name in ln.split(" = ")[0] for ln in calls) == 1, (name, len(calls))
+    grids = _pallas_grids(jax.make_jaxpr(
+        lambda q, k, pos: fa.rope_to_heads(q, k, pos, 1e6))(q, k, pos).jaxpr)
+    rows = {2048: 256 if h == 32 else 512, 4096: 256, 200: 200}[s]
+    assert grids == [(b, s // rows)], grids
+
+
 def test_train_step_compiles_for_four_chips(topo, on_tpu):
     """make_train_step on llama8b-geom2 under dp=2 x fsdp=2, the four-chip
     smoke's shape: the kernel is in the program (GSPMD cannot partition a
@@ -143,7 +174,16 @@ def test_train_step_compiles_for_four_chips(topo, on_tpu):
         (4, 2049), jnp.int32, sharding=named_sharding(mesh, "batch", None))}
     with use_mesh(mesh):
         compiled = make_train_step(cfg, tx).lower(state, batch).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3  # forward, dQ, dK/dV
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # forward, dQ, dK/dV
+    # the rotation runs in its kernel, once a phase (the rotated pair is kept under
+    # `dots`), on q and k as the projections' epilogues wrote them: no layout copy of
+    # either in front of it (PERF.md, PR 30: a [B, S, H*D] view cost one on four chips)
+    calls = {ln.split(" = ")[0].strip(): ln for ln in text.splitlines() if "tpu_custom_call" in ln}
+    (fwd,) = [ln for name, ln in calls.items() if "rope_fwd" in name]
+    assert sum("rope_bwd" in name for name in calls) == 1, list(calls)
+    q, k = re.search(r"custom-call\((%[\w.\-]+), (%[\w.\-]+),", fwd).groups()
+    assert not any(x.startswith(("%copy", "%transpose")) for x in (q, k)), (q, k)
     # 3 x f32 x 698M parameters (params, mu, nu) is 8.4 GB on one device
     assert compiled.memory_analysis().argument_size_in_bytes < 0.55 * 8.4e9
 
