@@ -138,19 +138,31 @@ def _kernel_phase(shape: dict, seed: int) -> dict:
     out["cases"]["rope"] = case(
         kernel.lower(q, k).compile().as_text(),
         {n: err(a, ref) for n, a, ref in zip(("q", "k", "dq", "dk"), kernel(q, k), plain(q, k))})
+    # the reference a few kv heads at a time (heads are independent), so that its
+    # [heads, S, S] float32 scores fit at any sequence length: 2 GB of them a call
+    group = max(1, min(kv, (1 << 29) // (s * s * (h // kv))))
     for name, segment_ids in (("causal", None), ("segment_ids", seg)):
-        def run(fn):
+        def run(fn, g):
             def loss(q, k, v):
                 o = fn(q, k, v, causal=True, segment_ids=segment_ids)
                 return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32)), o
             return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))
 
-        flash = run(fa.flash_attention)
+        flash = run(fa.flash_attention, g)
         hlo = flash.lower(q, k, v).compile().as_text()
         (_, o1), g1 = flash(q, k, v)
-        (_, o2), g2 = run(attention_reference)(q, k, v)
-        out["cases"][name] = case(hlo, {
-            "fwd": err(o1, o2), **{f"d{n}": err(a, ref) for n, a, ref in zip("qkv", g1, g2)}})
+        errs = {}
+        for j in range(0, kv, group):
+            heads, kvs = slice(j * (h // kv), (j + group) * (h // kv)), slice(j, j + group)
+            sl = lambda x, hs: x[:, :, hs].astype(jnp.float32)  # noqa: E731
+            with jax.default_matmul_precision("highest"):  # float32 products as such
+                (_, o2), g2 = run(attention_reference, g[:, :, heads])(
+                    sl(q, heads), sl(k, kvs), sl(v, kvs))
+            for n, a, ref, hs in zip(("fwd", "dq", "dk", "dv"), (o1, *g1), (o2, *g2),
+                                     (heads, heads, kvs, kvs)):
+                e, m = err(a[:, :, hs], ref)
+                errs[n] = (max(e, errs.get(n, (0, 0))[0]), max(m, errs.get(n, (0, 0))[1]))
+        out["cases"][name] = case(hlo, errs)
     return out
 
 
@@ -326,16 +338,20 @@ def _init_cluster(ray_tpu):
     return res
 
 
+def _kernel_shape(args) -> dict:
+    return {**KERNEL_SHAPE, "seq": args.kernel_seq, **json.loads(args.kernel_shape)}
+
+
 def phase_kernel(args) -> dict:
     import ray_tpu
 
     res = _init_cluster(ray_tpu)
     try:
         out = ray_tpu.get(ray_tpu.remote(num_tpus=1)(_kernel_phase).remote(
-            dict(KERNEL_SHAPE, seq=args.kernel_seq), args.seed))
+            _kernel_shape(args), args.seed))
     finally:
         ray_tpu.shutdown()
-    out = dict(phase="kernel", shape=dict(KERNEL_SHAPE, seq=args.kernel_seq),
+    out = dict(phase="kernel", shape=_kernel_shape(args),
                dtype="bfloat16", tolerance=KERNEL_TOL, cluster_tpus=res.get("TPU", 0), **out)
     emit(out)
     for name, c in out["cases"].items():
@@ -593,6 +609,11 @@ def main() -> int:
     p.add_argument("--chips", type=int, choices=(1, 4), default=1)
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--kernel-seq", type=int, default=KERNEL_SHAPE["seq"])
+    p.add_argument("--kernel-shape", default="{}",
+                   help="JSON over KERNEL_SHAPE's keys, e.g. the latent-attention cell's "
+                        '{"batch": 1, "seq": 8192, "heads": 20, "kv_heads": 20, "head_dim": 256}')
+    p.add_argument("--phases", default="all", choices=("all", "kernel"),
+                   help="kernel: the kernel phase alone (one chip)")
     p.add_argument("--train-model", default="llama8b-geom2")
     p.add_argument("--train-batch", type=int, default=None)
     p.add_argument("--train-seq", type=int, default=2048)
@@ -626,7 +647,9 @@ def main() -> int:
           "serve_depth": depth, "at_defaults": at_defaults,
           "parent_backend_untouched": parent_backend_untouched()})
 
-    if args.chips == 1:
+    if args.phases == "kernel":
+        phases = [phase_kernel(args)]
+    elif args.chips == 1:
         phases = [phase_kernel(args),
                   phase_train(args, args.train_steps, "train"),
                   phase_train(args, 3, "train_again")]

@@ -145,14 +145,14 @@ class LLMConfig:
         from ray_tpu.models.config import ModelConfig, get_config
 
         if isinstance(self.model_source, ModelConfig):
-            return self.model_source
+            return _served(self.model_source)
         from ray_tpu.models import checkpoint as ckpt_io
 
         if ckpt_io.looks_like_checkpoint_dir(self.model_source):
             # a local HF-layout checkpoint dir: architecture from its config.json,
             # weights loaded by the engine at start() (vllm_engine.py:180 contract)
-            return ckpt_io.config_from_hf(self.model_source, **self.engine_kwargs)
-        return get_config(self.model_source, **self.engine_kwargs)
+            return _served(ckpt_io.config_from_hf(self.model_source, **self.engine_kwargs))
+        return _served(get_config(self.model_source, **self.engine_kwargs))
 
     def resolve_tokenizer_name(self) -> str:
         """Default the tokenizer to the checkpoint's own HF tokenizer when the
@@ -179,3 +179,18 @@ class LLMConfig:
             b *= 2
         out.append(self.max_model_len)
         return out
+
+
+def _served(cfg):
+    """The model configuration, if the engine can serve it; else what it lacks, by name
+    (models/llama.py trains these; ROADMAP.md B4-B6 has the serving side)."""
+    missing = [what for has, what in (
+        (cfg.latent_attention, "a paged cache of latents (llm/paged.py holds K and V a head)"),
+        (cfg.n_dense_layers, "a layer loop over stacks of more than one kind (llm/model_runner.py)"),
+        (cfg.moe_dropless, "the dropless expert layer under decode (inactive slots are not masked)"),
+        (cfg.mtp_depth, "multi-token-prediction modules as drafts of the verify window"),
+    ) if has]
+    if missing:
+        raise NotImplementedError(
+            f"llm cannot serve {cfg.name!r} yet; it lacks: " + "; ".join(missing))
+    return cfg

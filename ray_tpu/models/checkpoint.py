@@ -80,8 +80,37 @@ def config_from_hf(source_dir: str, **overrides) -> ModelConfig:
         fields["moe_top1_renorm"] = bool(hf.get("moe_top1_renorm", True))
         fields["moe_capacity_factor"] = float(
             hf.get("moe_capacity_factor", e / k))
+    if hf.get("model_type") == "glm4_moe_lite":
+        fields.update(_glm4_moe_lite_fields(hf))
     fields.update(overrides)
     return ModelConfig(**fields)
+
+
+def _glm4_moe_lite_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `glm4_moe_lite` keys (GLM-4.7-Flash) as ModelConfig fields: latent attention,
+    leading dense layers, sigmoid-routed experts chosen by score + bias (`noaux_tc`)
+    beside shared ones, served without drops, and the MTP modules. Every expert is held;
+    a share is an override (`experts_held=(index, of)`). Weights' names are not mapped:
+    load_llama_params does not read this family's tensors yet."""
+    if hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1:
+        raise ValueError("group-limited routing (n_group / topk_group > 1) is not supported")
+    if hf.get("rope_scaling") is not None:
+        raise ValueError(f"rope_scaling {hf['rope_scaling']!r} is not supported")
+    if not hf.get("norm_topk_prob", True):
+        raise ValueError("gates that are not normalised over the chosen experts "
+                         "(norm_topk_prob false) are not supported")
+    return dict(
+        q_lora_rank=hf["q_lora_rank"], kv_lora_rank=hf["kv_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"], qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        n_experts=hf["n_routed_experts"], moe_top_k=hf["num_experts_per_tok"],
+        d_ff_expert=hf["moe_intermediate_size"], n_shared_experts=hf.get("n_shared_experts", 0),
+        n_dense_layers=hf.get("first_k_dense_replace", 0),
+        moe_capacity_factor=0.0, moe_aux_loss_coef=0.0,
+        moe_scoring="sigmoid", moe_select_bias=hf.get("topk_method") == "noaux_tc",
+        moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)),
+        mtp_depth=hf.get("num_nextn_predict_layers", 0),
+    )
 
 
 def config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:

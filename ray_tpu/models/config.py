@@ -6,7 +6,7 @@ SwiGLU, untied or tied embeddings).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict
+from typing import Dict, Tuple
 
 import jax.numpy as jnp
 
@@ -49,10 +49,58 @@ class ModelConfig:
     # num_experts_per_tok=1). checkpoint.config_from_hf sets True for
     # model_type=mixtral; irrelevant when moe_top_k > 1 (both renormalize).
     moe_top1_renorm: bool = False
+    # --- what a published config.json of another family states (0 / default = absent) ---
+    # Latent attention (MLA): q through a rank-`q_lora_rank` latent, k and v through one
+    # of rank `kv_lora_rank` beside a rotated key of `qk_rope_head_dim` shared by all
+    # heads; a head's q and k are `qk_nope_head_dim` un-rotated + `qk_rope_head_dim`
+    # rotated wide, its v `v_head_dim`.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Expert layers: `n_dense_layers` leading layers keep the dense MLP (width d_ff), the
+    # rest route over `n_experts` experts of width `d_ff_expert` (0 = d_ff) beside
+    # `n_shared_experts` that every token meets. moe_capacity_factor <= 0 is no capacity:
+    # the dropless layer (moe.py:expert_layer), which is told which experts it holds
+    # (`experts_held` = (index, of): the index-th of `of` equal contiguous shares).
+    d_ff_expert: int = 0
+    n_shared_experts: int = 0
+    n_dense_layers: int = 0
+    moe_scoring: str = "softmax"  # softmax | sigmoid
+    moe_route_scale: float = 1.0  # the gates' factor (routed_scaling_factor)
+    # selection by score + a bias that no gradient reaches; it moves by the balance rule
+    # after a step (train/step.py) at this rate
+    moe_select_bias: bool = False
+    moe_bias_update_rate: float = 0.001
+    experts_held: Tuple[int, int] = (0, 1)
+    # Multi-token prediction: modules after the last block, each one more expert layer
+    # that predicts one token further; their loss is added with this weight.
+    mtp_depth: int = 0
+    mtp_loss_weight: float = 0.3
+
+    def __post_init__(self):
+        # JSON hands a list; the dataclass is a static (hashed) argument of jitted programs
+        object.__setattr__(self, "experts_held", tuple(self.experts_held))
+
+    @property
+    def latent_attention(self) -> bool:
+        return self.kv_lora_rank > 0
 
     @property
     def head_dim(self) -> int:
+        """Width of a head's q and k (and v, which every family here has as wide)."""
+        if self.latent_attention:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.d_model // self.n_heads
+
+    @property
+    def moe_dropless(self) -> bool:
+        return self.n_experts > 0 and self.moe_capacity_factor <= 0
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.n_experts // self.experts_held[1]
 
     @property
     def activation_dtype(self):
@@ -60,12 +108,26 @@ class ModelConfig:
 
     @property
     def n_params(self) -> int:
-        """Approximate parameter count (embeddings + blocks + norms)."""
-        emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
-        attn = self.d_model * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
-        mlp = 3 * self.d_model * self.d_ff
-        norms = 2 * self.d_model
-        return emb + self.n_layers * (attn + mlp + norms) + self.d_model
+        """Approximate parameter count (embeddings + blocks + norms), of what is held."""
+        d = self.d_model
+        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        if self.latent_attention:
+            h, qk = self.n_heads, self.head_dim
+            attn = (d * self.q_lora_rank + self.q_lora_rank * h * qk
+                    + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                    + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d + self.q_lora_rank + self.kv_lora_rank)
+        else:
+            attn = d * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
+        mlp = 3 * d * self.d_ff
+        norms = 2 * d
+        if not self.moe_dropless:
+            return emb + self.n_layers * (attn + mlp + norms) + d
+        expert = 3 * d * (self.d_ff_expert or self.d_ff)
+        moe = (self.n_experts_held + self.n_shared_experts) * expert + d * self.n_experts
+        return (emb + d + self.n_dense_layers * (attn + mlp + norms)
+                + (self.n_layers - self.n_dense_layers + self.mtp_depth) * (attn + moe + norms)
+                + self.mtp_depth * (2 * d * d + 3 * d))
 
 
 _REGISTRY: Dict[str, ModelConfig] = {}
@@ -191,6 +253,39 @@ register_config(
         dtype="float32",
         n_experts=4,
         moe_top_k=2,
+    )
+)
+register_config(
+    # Toy of the GLM-4.7-Flash family (glm4_moe_lite) for the CPU tests: latent attention,
+    # a leading dense layer, sigmoid-routed experts beside a shared one, one MTP module.
+    # All 8 experts held; tests cut shares with experts_held=(i, n).
+    ModelConfig(
+        name="glm-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=160,
+        max_seq_len=128,
+        rope_theta=1e6,
+        dtype="float32",
+        q_lora_rank=48,
+        kv_lora_rank=32,
+        qk_nope_head_dim=24,
+        qk_rope_head_dim=8,
+        v_head_dim=32,
+        n_experts=8,
+        moe_top_k=2,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=48,
+        n_shared_experts=1,
+        n_dense_layers=1,
+        moe_scoring="sigmoid",
+        moe_route_scale=1.8,
+        moe_select_bias=True,
+        mtp_depth=1,
     )
 )
 register_config(

@@ -11,6 +11,7 @@ this is the flagship model its Train/Serve equivalents here exercise.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
@@ -29,37 +30,68 @@ Params = Dict[str, Any]
 
 # ---------------------------------------------------------------------------- init
 
+def _layer_kinds(cfg: ModelConfig) -> Dict[str, Tuple[int, bool]]:
+    """The stacks of params, in the order forward walks them: name -> (layers, whether
+    they are expert layers). `layers` is every layer of a one-kind model; cfg.n_dense_layers
+    leading layers with the dense MLP lie in a stack of their own in front of it."""
+    kinds = {}
+    if cfg.n_dense_layers:
+        kinds["dense_layers"] = (cfg.n_dense_layers, False)
+    kinds["layers"] = (cfg.n_layers - cfg.n_dense_layers, cfg.n_experts > 0)
+    return kinds
+
+
+def _attn_axes(cfg: ModelConfig) -> Params:
+    if cfg.latent_attention:
+        return {
+            "wq_a": ("embed", "latent"), "q_norm": ("latent",),
+            "wq_b": ("latent", "heads", "head_dim"),
+            "wkv_a": ("embed", "latent"), "kv_norm": ("latent",),
+            "wkv_b": ("latent", "heads", "head_dim"),
+            "wo": ("heads", "head_dim", "embed"),
+        }
+    return {
+        "wq": ("embed", "heads", "head_dim"),
+        "wk": ("embed", "kv_heads", "head_dim"),
+        "wv": ("embed", "kv_heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+
+
+def _layer_axes(cfg: ModelConfig, experts: bool) -> Params:
+    """One layer's logical axes (no leading 'layer' axis)."""
+    axes = {"attn_norm": ("embed",), **_attn_axes(cfg), "mlp_norm": ("embed",)}
+    if experts:
+        from . import moe as _moe
+
+        axes.update(_moe.expert_axes(cfg))
+    else:
+        axes.update({
+            "w_gate": ("embed", "mlp"),
+            "w_up": ("embed", "mlp"),
+            "w_down": ("mlp", "embed"),
+        })
+    return axes
+
+
 def param_axes(cfg: ModelConfig) -> Params:
     """Logical-axis tree mirroring init() output (layers stacked on a leading 'layer' axis)."""
 
-    def L(*axes):
-        return ("layer",) + axes
+    def stacked(tree):
+        return {k: ("layer",) + v for k, v in tree.items()}
 
-    layers = {
-        "attn_norm": L("embed"),
-        "wq": L("embed", "heads", "head_dim"),
-        "wk": L("embed", "kv_heads", "head_dim"),
-        "wv": L("embed", "kv_heads", "head_dim"),
-        "wo": L("heads", "head_dim", "embed"),
-        "mlp_norm": L("embed"),
-    }
-    if cfg.n_experts > 0:
-        from . import moe as _moe
-
-        layers.update({k: L(*axes) for k, axes in _moe.EXPERT_AXES.items()})
-    else:
-        layers.update({
-            "w_gate": L("embed", "mlp"),
-            "w_up": L("embed", "mlp"),
-            "w_down": L("mlp", "embed"),
-        })
     axes = {
         "embed": ("vocab", "embed"),
-        "layers": layers,
+        **{name: stacked(_layer_axes(cfg, experts))
+           for name, (_, experts) in _layer_kinds(cfg).items()},
         "final_norm": ("embed",),
     }
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.mtp_depth:
+        axes["mtp"] = stacked({
+            "embed_norm": ("embed",), "hidden_norm": ("embed",), "eh_proj": ("mlp", "embed"),
+            "final_norm": ("embed",), **_layer_axes(cfg, True)})
     return axes
 
 
@@ -71,19 +103,36 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
     def norm(key, shape, scale):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(jnp.float32)
 
-    def layer_init(key):
+    s_in = d**-0.5
+    s_out = (2 * cfg.n_layers * d) ** -0.5
+
+    def attn_init(ks):
+        if not cfg.latent_attention:
+            return {
+                "wq": norm(ks[0], (d, nh, hd), s_in),
+                "wk": norm(ks[1], (d, nkv, hd), s_in),
+                "wv": norm(ks[2], (d, nkv, hd), s_in),
+                "wo": norm(ks[3], (nh, hd, d), s_out),
+            }
+        qr, kvr, rd = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        ka, kb = jax.random.split(ks[1])
+        return {
+            "wq_a": norm(ks[0], (d, qr), s_in), "q_norm": jnp.ones((qr,), jnp.float32),
+            "wq_b": norm(ka, (qr, nh, hd), qr**-0.5),
+            # the latent and, behind it, the rotated key every head shares
+            "wkv_a": norm(kb, (d, kvr + rd), s_in), "kv_norm": jnp.ones((kvr,), jnp.float32),
+            "wkv_b": norm(ks[2], (kvr, nh, cfg.qk_nope_head_dim + cfg.v_head_dim), kvr**-0.5),
+            "wo": norm(ks[3], (nh, cfg.v_head_dim, d), s_out),
+        }
+
+    def layer_init(key, experts: bool):
         ks = jax.random.split(key, 7)
-        s_in = d**-0.5
-        s_out = (2 * cfg.n_layers * d) ** -0.5
         out = {
             "attn_norm": jnp.ones((d,), jnp.float32),
-            "wq": norm(ks[0], (d, nh, hd), s_in),
-            "wk": norm(ks[1], (d, nkv, hd), s_in),
-            "wv": norm(ks[2], (d, nkv, hd), s_in),
-            "wo": norm(ks[3], (nh, hd, d), s_out),
+            **attn_init(ks),
             "mlp_norm": jnp.ones((d,), jnp.float32),
         }
-        if cfg.n_experts > 0:
+        if experts:
             from . import moe as _moe
 
             out.update(_moe.init_expert_weights(ks[4], cfg))
@@ -95,13 +144,31 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             })
         return out
 
-    params: Params = {
-        "embed": norm(k_emb, (cfg.vocab_size, d), 1.0),
-        "layers": jax.vmap(layer_init)(jax.random.split(k_layers, cfg.n_layers)),
-        "final_norm": jnp.ones((d,), jnp.float32),
-    }
+    params: Params = {"embed": norm(k_emb, (cfg.vocab_size, d), 1.0)}
+    kinds = _layer_kinds(cfg)
+    if len(kinds) == 1:  # the one-kind model draws its layers' keys as it always did
+        kind_keys = {"layers": k_layers}
+    else:
+        kind_keys = dict(zip(kinds, jax.random.split(k_layers, len(kinds))))
+    for name, (n, experts) in kinds.items():
+        params[name] = jax.vmap(functools.partial(layer_init, experts=experts))(
+            jax.random.split(kind_keys[name], n))
+    params["final_norm"] = jnp.ones((d,), jnp.float32)
     if not cfg.tie_embeddings:
         params["lm_head"] = norm(k_head, (d, cfg.vocab_size), d**-0.5)
+    if cfg.mtp_depth:
+        def mtp_init(key):
+            k_proj, k_block = jax.random.split(key)
+            return {
+                "embed_norm": jnp.ones((d,), jnp.float32),
+                "hidden_norm": jnp.ones((d,), jnp.float32),
+                "eh_proj": norm(k_proj, (2 * d, d), (2 * d) ** -0.5),
+                "final_norm": jnp.ones((d,), jnp.float32),
+                **layer_init(k_block, True),
+            }
+
+        params["mtp"] = jax.vmap(mtp_init)(
+            jax.random.split(jax.random.fold_in(k_layers, 1), cfg.mtp_depth))
     return params
 
 
@@ -116,19 +183,24 @@ def _maybe_remat(body, cfg: ModelConfig):
         return body
     # the rotated q and k (ops/flash_attention.py names them) are kept beside the matmul
     # outputs: the backward of the projections needs dQ and dK, never their own output,
-    # so the un-rotated pair is dropped for them and the rotation is not run again
+    # so the un-rotated pair is dropped for them and the rotation is not run again.
+    # What an expert layer chose (moe.route names it) is kept under every policy: a
+    # recomputed forward pass must not choose again.
+    from . import moe as _moe
+
     policies = jax.checkpoint_policies
-    rotated = policies.save_only_these_names(*ROTATED_NAMES)
+    chosen = policies.save_only_these_names(_moe.CHOSEN_NAME)
+    kept = policies.save_only_these_names(*ROTATED_NAMES, _moe.CHOSEN_NAME)
     if policy == "dots":
         return jax.checkpoint(
-            body, policy=policies.save_from_both_policies(policies.checkpoint_dots, rotated))
+            body, policy=policies.save_from_both_policies(policies.checkpoint_dots, kept))
     if policy == "dots_no_batch":
         return jax.checkpoint(body, policy=policies.save_from_both_policies(
-            policies.dots_with_no_batch_dims_saveable, rotated))
+            policies.dots_with_no_batch_dims_saveable, kept))
     if policy != "full":
         raise ValueError(
             f"unknown remat_policy {policy!r} (expected full | dots | dots_no_batch | none)")
-    return jax.checkpoint(body)
+    return jax.checkpoint(body, policy=chosen)
 
 
 # A row shorter than this is looked up with a gather whatever the mesh: the
@@ -201,17 +273,53 @@ def _unconstrained(x: jax.Array, *logical_axes) -> jax.Array:
 
 
 def qkv_proj(x: jax.Array, lp: Params, cfg: ModelConfig, positions: Optional[jax.Array]):
-    """Attention's inputs for one layer: norm, the three projections, RoPE.
+    """Attention's inputs for one layer: norm, the projections, RoPE.
     x [B, S, D], positions [B, S] -> q [B, S, H, hd], k and v [B, S, KV, hd].
-    Without positions q and k come back un-rotated: the caller hands the rotation on."""
+    Without positions q and k come back un-rotated: the caller hands the rotation on
+    (latent attention rotates a slice of its heads and always needs them)."""
     dt = x.dtype
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    if cfg.latent_attention:
+        return _latent_qkv(h, lp, cfg, positions)
     q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
     k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
     v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
     if positions is None:
         return q, k, v
     return rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta), v
+
+
+def rope_pairs_to_halves(d: int):
+    """Where each column of a rotated slice lies in a published checkpoint: the
+    DeepSeek-V3 lineage rotates the pairs (2i, 2i + 1), `rope` the pairs (i, i + d/2).
+    Column j here is the checkpoint's column perm[j]; scores do not see the order, as q
+    and k share it. (models/reference/ rotates pairs on the columns put back.)"""
+    return [2 * i for i in range(d // 2)] + [2 * i + 1 for i in range(d // 2)]
+
+
+def _latent_qkv(h: jax.Array, lp: Params, cfg: ModelConfig, positions: jax.Array):
+    """Latent attention's q, k and v from the normed input h [B, S, D]: q through its
+    low-rank latent; k's un-rotated part and v from the shared latent, k's rotated part
+    one key for all heads. Nothing is absorbed: what comes out is plain multi-head
+    attention's input, [B, S, H, nope + rope] twice and [B, S, H, v]."""
+    dt = h.dtype
+    nope, kvr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    if cfg.v_head_dim != cfg.head_dim:
+        raise NotImplementedError(
+            f"latent attention with v heads {cfg.v_head_dim} wide beside q/k heads "
+            f"{cfg.head_dim} wide: ops.attention takes one width")
+    with jax.named_scope("mla_q"):
+        cq = rms_norm(jnp.einsum("bsd,dr->bsr", h, _w(lp["wq_a"], dt)), lp["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsr,rhk->bshk", cq, _w(lp["wq_b"], dt))
+        q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+    with jax.named_scope("mla_kv"):
+        ckv = jnp.einsum("bsd,dr->bsr", h, _w(lp["wkv_a"], dt))
+        k_rot = rope(ckv[:, :, None, kvr:], positions, cfg.rope_theta)  # [B, S, 1, rope]
+        kv = jnp.einsum("bsr,rhk->bshk", rms_norm(ckv[..., :kvr], lp["kv_norm"], cfg.norm_eps),
+                        _w(lp["wkv_b"], dt))
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(k_rot, (*kv.shape[:3], k_rot.shape[-1]))], -1)
+    return q, k, kv[..., nope:]
 
 
 def attn_out(x: jax.Array, attn: jax.Array, lp: Params) -> jax.Array:
@@ -221,29 +329,33 @@ def attn_out(x: jax.Array, attn: jax.Array, lp: Params) -> jax.Array:
 
 def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
                  token_mask: Optional[jax.Array] = None, constrain=_unconstrained):
-    """Norm, the dense or MoE feed-forward, residual. Returns (x, moe aux loss).
+    """Norm, the dense or MoE feed-forward (whichever the layer's parameters are),
+    residual. Returns (x, aux): the capacity-based experts' load-balancing loss (a
+    scalar, zero for a dense layer), or what the dropless layer counted and chose
+    (moe.expert_layer: {"load": [E], "chosen": [B * S, k]}).
     token_mask [B, S] (1 = real) keeps pad tokens and inactive slots out of the
     experts' capacity; `constrain(array, *logical_axes)` is the caller's sharding
     constraint on the dense product."""
     dt = x.dtype
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.n_experts > 0:
-        from . import moe as _moe
+    b, s, d = h.shape
+    if "router" not in lp:
+        gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
+        up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
+        ff = constrain(jax.nn.silu(gate) * up, "batch", "seq", "act_mlp")
+        down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
+        return x + down, jnp.zeros((), jnp.float32)
+    from . import moe as _moe
 
-        b, s, d = h.shape
+    if cfg.moe_dropless:
+        y2, aux = _moe.expert_layer(h.reshape(b * s, d), lp, cfg)
+    else:
         y2, aux = _moe.moe_mlp(
             h.reshape(b * s, d), lp["router"], lp["w_gate"], lp["w_up"],
             lp["w_down"], cfg,
             mask=None if token_mask is None else token_mask.reshape(b * s),
         )
-        down = y2.reshape(b, s, d)
-    else:
-        gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
-        up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
-        ff = constrain(jax.nn.silu(gate) * up, "batch", "seq", "act_mlp")
-        down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
-        aux = jnp.zeros((), jnp.float32)
-    return x + down, aux
+    return x + y2.reshape(b, s, d), aux
 
 
 def output_head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -292,7 +404,8 @@ def _block(
     with jax.named_scope("attn"):
         # ops.attention rotates q and k itself (in its kernel's own pass over them, where
         # the Pallas path runs); a cache or the ring takes them rotated
-        deferred = cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
+        deferred = (cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
+                    and not cfg.latent_attention)
         q, k, v = qkv_proj(x, lp, cfg, None if deferred else positions)
         q = wsc(q, "batch", "seq", "act_heads", "head_dim")
 
@@ -330,7 +443,7 @@ def _block(
         else:
             attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
                              shard_spec=auto_spec("batch", None, "act_heads", None),
-                             rotation=Rotation(positions, cfg.rope_theta, rope))
+                             rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
         x = wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed")
 
     with jax.named_scope("mlp"):
@@ -433,7 +546,10 @@ def forward(
     """tokens [B, S] -> (logits [B, S, vocab] f32, updated cache or None).
 
     With return_aux=True also returns the summed MoE load-balancing loss (zero for
-    dense configs) as a third element."""
+    dense configs) as a third element; for the dropless expert layer, which has no such
+    loss, what each expert layer counted and chose and what the MTP modules go on from
+    ({"load": [layers, E], "chosen": [layers, B * S, k], "hidden": the last block's
+    output before the final norm [B, S, D]})."""
     b, s = tokens.shape
     if positions is None:  # one row, which every row of the batch shares
         start = cache.length if cache is not None else 0
@@ -442,24 +558,30 @@ def forward(
         x = wsc(embed_tokens(params, tokens, cfg), "batch", "seq", "act_embed")
 
     new_cache = None
+    if len(_layer_kinds(cfg)) > 1 and (cfg.pipeline_stages > 1 or cache is not None):
+        raise NotImplementedError(
+            "pipeline stages or a KV cache over layers of more than one kind")
     if cfg.pipeline_stages > 1 and cache is None:
         x, aux_total = _pipeline_layers(x, params, cfg, positions, segment_ids,
                                         token_mask)
     else:
-        # one loop: a layer's parameters and, when there is a cache, its K/V
-        # (None is an empty pytree: the scan then carries no K/V in or out)
+        # one loop a stack of layers (a leading dense stack, then `layers`): a layer's
+        # parameters and, when there is a cache (one stack only), its K/V (None is an
+        # empty pytree: the scan then carries no K/V in or out)
         cache_len = None if cache is None else cache.length
 
         def body(h, xs):
-            lp, cache_kv = xs
-            h, new_kv, aux = _block(h, lp, cfg, positions, segment_ids, cache_kv,
+            lp, kv = xs
+            h, new_kv, aux = _block(h, lp, cfg, positions, segment_ids, kv,
                                     cache_len, token_mask)
             return h, (new_kv, aux)
 
-        x, (new_kv, auxs) = jax.lax.scan(
-            _maybe_remat(body, cfg), x,
-            (params["layers"], None if cache is None else (cache.k, cache.v)))
-        aux_total = auxs.sum()
+        for name in _layer_kinds(cfg):
+            x, (new_kv, auxs) = jax.lax.scan(
+                _maybe_remat(body, cfg), x,
+                (params[name], None if cache is None else (cache.k, cache.v)))
+        # the last stack's: the expert layers' where there are any
+        aux_total = dict(auxs, hidden=x) if cfg.moe_dropless else auxs.sum()
         if cache is not None:
             new_cache = KVCache(k=new_kv[0], v=new_kv[1], length=cache.length + s)
 
@@ -470,28 +592,100 @@ def forward(
     return logits, new_cache
 
 
+def mtp_logits(params: Params, hidden: jax.Array, tokens: jax.Array, cfg: ModelConfig):
+    """The multi-token-prediction modules (DeepSeek-V3, arXiv:2412.19437 section 2.2),
+    one after the other. hidden [B, S, D]: the last block's output before the final
+    norm, at the positions of tokens[:, :S]; tokens [B, >= S] (what lies past S is looked
+    ahead at). Module m (from 1) joins position i's state with the embedding of token
+    i + m through `eh_proj`, runs one more block, and its logits at i predict token
+    i + m + 1. Embedding and head are the model's. A module runs all S positions, so
+    that attention keeps the sequence's length and its kernel (S - m is no multiple of
+    a tile); the last m, which look past the tokens, are cut before the head: returns
+    [(logits [B, S - m, vocab], the block's aux)] a module."""
+    s = hidden.shape[1]
+    tokens = jnp.pad(tokens, ((0, 0), (0, max(0, s + cfg.mtp_depth - tokens.shape[1]))))
+    positions = jnp.arange(s)[None, :]
+    out = []
+    for m in range(1, cfg.mtp_depth + 1):
+        mp = jax.tree.map(lambda a: a[m - 1], params["mtp"])
+        with jax.named_scope("mtp"):
+            ahead = embed_tokens(params, tokens[:, m:m + s], cfg)
+            joined = jnp.concatenate([rms_norm(ahead, mp["embed_norm"], cfg.norm_eps),
+                                      rms_norm(hidden, mp["hidden_norm"], cfg.norm_eps)], -1)
+            x = jnp.einsum("bse,ed->bsd", joined, _w(mp["eh_proj"], joined.dtype))
+            hidden, _, aux = _maybe_remat(
+                lambda h, lp: _block(h, lp, cfg, positions, None), cfg)(x, mp)
+            logits = output_head({**params, "final_norm": mp["final_norm"]},
+                                 hidden[:, :s - m], cfg)
+        out.append((logits, aux))
+    return out
+
+
+def balance_router_bias(old: Params, new: Params, load: jax.Array, cfg: ModelConfig) -> Params:
+    """`new` with every expert layer's selection bias set to `old`'s moved by the balance
+    rule (moe.balance_bias). load [expert layers + MTP modules, E], as loss_fn's
+    `expert_load` orders them."""
+    from . import moe as _moe
+
+    n = load.shape[0] - cfg.mtp_depth
+    out = dict(new)
+    for name, rows in (("layers", load[:n]), ("mtp", load[n:])):
+        if name in new:
+            out[name] = dict(new[name], router_bias=_moe.balance_bias(
+                old[name]["router_bias"], rows, cfg.moe_bias_update_rate))
+    return out
+
+
+def _cross_entropy(logits: jax.Array, targets: jax.Array, mask: jax.Array):
+    # target-logit minus logsumexp == log_softmax gathered at the target, without
+    # materializing a second [B,S,vocab] f32 tensor (1 GB/chip at 8B scale).
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return -((tgt - lse) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+
 def loss_fn(
     params: Params,
     batch: Dict[str, jax.Array],
     cfg: ModelConfig,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """Next-token cross entropy. batch: tokens [B,S]; optional loss_mask/segment_ids."""
+    """Next-token cross entropy. batch: tokens [B,S]; optional loss_mask/segment_ids.
+    With MTP modules (cfg.mtp_depth) the mean of their losses is added at
+    cfg.mtp_loss_weight; module m predicts the token m + 1 ahead."""
     tokens = batch["tokens"]
     seg = batch.get("segment_ids")
     logits, _, aux = forward(
         params, tokens[:, :-1], cfg,
         segment_ids=None if seg is None else seg[:, :-1], return_aux=True,
     )
+    mask = batch.get("loss_mask")
+    mask = jnp.ones(tokens.shape, jnp.float32) if mask is None else mask.astype(jnp.float32)
     with jax.named_scope("loss"):
-        targets = tokens[:, 1:]
-        # target-logit minus logsumexp == log_softmax gathered at the target, without
-        # materializing a second [B,S,vocab] f32 tensor (1 GB/chip at 8B scale).
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        ll = tgt - lse
-        mask = batch.get("loss_mask")
-        mask = jnp.ones_like(ll) if mask is None else mask[:, 1:].astype(ll.dtype)
-        denom = jnp.maximum(mask.sum(), 1.0)
-        ce = -(ll * mask).sum() / denom
-        loss = ce + aux
-    return loss, {"loss": loss, "ce_loss": ce, "moe_aux_loss": aux, "tokens": denom}
+        ce = _cross_entropy(logits, tokens[:, 1:], mask[:, 1:])
+    metrics = {"tokens": jnp.maximum(mask[:, 1:].sum(), 1.0)}
+    if cfg.moe_dropless:  # no auxiliary loss: the selection bias balances (train/step.py)
+        loss, load, chosen = ce, aux["load"], aux["chosen"]
+    else:
+        loss, load = ce + aux, None
+        metrics["moe_aux_loss"] = aux
+    if cfg.mtp_depth:
+        if seg is not None or load is None:
+            raise NotImplementedError(
+                "MTP modules over packed documents (segment_ids) or the capacity-based experts")
+        heads = mtp_logits(params, aux["hidden"], tokens, cfg)
+        with jax.named_scope("loss"):
+            mtp = sum(_cross_entropy(lg, tokens[:, m + 1:], mask[:, m + 1:])
+                      for m, (lg, _) in enumerate(heads, 1)) / cfg.mtp_depth
+        loss = loss + cfg.mtp_loss_weight * mtp
+        metrics["mtp_loss"] = mtp
+        load = jnp.concatenate([load] + [a["load"][None] for _, a in heads])
+        chosen = jnp.concatenate([chosen] + [a["chosen"][None] for _, a in heads])
+    if load is not None:
+        from . import moe as _moe
+
+        lo, hi = _moe.held_range(cfg)
+        # a row an expert layer, the MTP modules' last: what the balance rule reads, what
+        # fell on the experts held here, and the experts each token chose [.., B * S, k]
+        metrics.update(expert_load=load, held_assignments=load[:, lo:hi].sum(-1),
+                       fullest_held_expert_rows=load[:, lo:hi].max(-1), experts_chosen=chosen)
+    return loss, {"loss": loss, "ce_loss": ce, **metrics}

@@ -18,10 +18,12 @@ renormalize to sum to 1 (Mixtral convention).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.quant import as_weight as _qw
 from ray_tpu.parallel.sharding import with_sharding_constraint as wsc
@@ -127,18 +129,173 @@ def moe_mlp(
     return yg.reshape(t, d), auxg.mean()
 
 
+# ------------------------------------------------------------- the dropless layer
+# cfg.moe_dropless (no capacity factor): every assignment is served. The layer is told
+# which experts it holds (cfg.experts_held = (index, of)): it routes over all
+# cfg.n_experts, sorts the step's tokens x k assignments by expert, keeps those that fall
+# on held experts and computes their part of the result with grouped products over the
+# ragged groups (jax.lax.ragged_dot: the TPU compiler has a kernel of its own for it,
+# forward and both transposes, whose work follows the rows the groups hold). What the
+# experts held elsewhere would add is left out: on one chip the layer runs without its
+# exchange, and nothing here stands in for it.
+#
+# Shapes are static, so the sorted buffer has a row for every assignment (tokens x k:
+# all of them can fall on held experts); the held ones lie first, grouped, and the rows
+# behind them are zeros that no group covers.
+
+
+CHOSEN_NAME = "experts_chosen"  # what `route` chose, by the name remat policies keep it under
+
+
+def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: ModelConfig):
+    """x [T, D] -> (experts chosen [T, k] int32, their gates [T, k] f32), as glm4_moe_lite
+    states it. Sigmoid scores in float32, products at the highest precision (2 % of a
+    layer's operations); the k largest of score + bias are chosen; the gates are the
+    chosen SCORES (the bias selects and never weights), normalised over the k, times
+    moe_route_scale. (Against a float32 reference 2 % of tokens choose another expert at
+    8,192 positions in bfloat16, all by the activations' rounding: a bfloat16 router
+    chose the same experts to the token on the chip, PERF.md section 6, PR 31.)"""
+    if cfg.moe_scoring != "sigmoid":
+        raise NotImplementedError(
+            f"the dropless layer scores by sigmoid; {cfg.moe_scoring!r} is the capacity path's (moe_mlp)")
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choose = scores if bias is None else scores + jax.lax.stop_gradient(bias)[None, :]
+    _, idx = jax.lax.top_k(choose, cfg.moe_top_k)
+    # A decision, not arithmetic: rematerialisation keeps it (llama._maybe_remat). A
+    # forward pass recomputed in the backward pass rounds its bfloat16 activations
+    # otherwise where XLA fuses it otherwise, and 0.5 % of the MTP block's assignments,
+    # two scores within a rounding, then went to other experts in the gradient than in
+    # the loss: its experts' gradients were 6-8 % off on the chip (PERF.md section 6, PR 31).
+    idx = checkpoint_name(idx.astype(jnp.int32), CHOSEN_NAME)
+    # the chosen scores, picked by a mask: take_along_axis would transpose to a scatter
+    gates = jnp.sum(scores[:, None, :] * (idx[..., None] == jnp.arange(scores.shape[-1])), -1)
+    gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    return idx, gates * cfg.moe_route_scale
+
+
+def held_range(cfg: ModelConfig) -> Tuple[int, int]:
+    """[lo, hi) of the experts this layer holds."""
+    index, of = cfg.experts_held
+    n = cfg.n_experts // of
+    return index * n, (index + 1) * n
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch(x, order, inverse, k: int):
+    """x [T, D] -> [T * k, D]: row j is the token of assignment order[j] (assignment a
+    belongs to token a // k). Its transpose, `_combine`, sums each token's k rows; both
+    directions are gathers (a scatter-add serialises on the TPU)."""
+    return x[order // k]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _combine(rows, order, inverse, k: int):
+    """rows [T * k, D] in sorted order -> [T, D]: `inverse` puts them back in assignment
+    order, where a token's k rows are neighbours."""
+    return rows[inverse].reshape(-1, k, rows.shape[-1]).sum(axis=1)
+
+
+@jax.custom_vjp
+def _permute(x, order, inverse):
+    """x[order], whose transpose is a gather too: g[inverse]."""
+    return x[order]
+
+
+_permute.defvjp(lambda x, order, inverse: (x[order], (order, inverse)),
+                lambda res, g: (_permute(g, res[1], res[0]), None, None))
+_dispatch.defvjp(lambda x, order, inverse, k: (_dispatch(x, order, inverse, k), (order, inverse)),
+                 lambda k, res, g: (_combine(g, *res, k), None, None))
+_combine.defvjp(lambda rows, order, inverse, k: (_combine(rows, order, inverse, k), (order, inverse)),
+                lambda k, res, g: (_dispatch(g, *res, k), None, None))
+
+
+def _gated_mlp(x, w_gate, w_up, w_down, product=jnp.matmul, clean=lambda a: a):
+    """SiLU-gated MLP over `product`; `clean` goes around each product's input and output."""
+    act = clean(jax.nn.silu(clean(product(x, w_gate))) * clean(product(x, w_up)))
+    return clean(product(act, w_down))
+
+
+def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
+    """x [T, D] -> (shared experts' + held routed experts' part of the layer [T, D],
+    {"load": assignments an expert [n_experts] f32, over ALL experts: the step's counter;
+    "chosen": the experts each token chose [T, k]}).
+    lp: router [D, E], router_bias [E] (where cfg.moe_select_bias), w_gate / w_up
+    [held, D, F], w_down [held, F, D], shared_gate / shared_up [D, S * F], shared_down
+    [S * F, D] (where cfg.n_shared_experts)."""
+    dt = x.dtype
+    k, (lo, hi) = cfg.moe_top_k, held_range(cfg)
+    with jax.named_scope("moe_router"):
+        idx, gates = route(x, lp["router"], lp.get("router_bias"), cfg)
+        load = (idx[..., None] == jnp.arange(cfg.n_experts)).sum((0, 1), dtype=jnp.float32)
+    with jax.named_scope("moe_dispatch"):
+        # held assignments first, by expert; every other after them, under one key
+        key = jnp.where((idx >= lo) & (idx < hi), idx - lo, hi - lo).reshape(-1)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        group_sizes = load[lo:hi].astype(jnp.int32)
+        served = (jnp.arange(order.shape[0]) < group_sizes.sum())[:, None]
+
+        def clean(a):
+            # The rows behind the groups are zeros going in, and whatever the grouped
+            # product left there coming out, forward (its output) and backward (its
+            # cotangents): on the chip that is stale memory, NaN included, and 0 x NaN
+            # reaches the gates' gradient. A select, both ways, around every product.
+            return jnp.where(served, a, 0)
+
+        rows = clean(_dispatch(x, order, inverse, k))
+    with jax.named_scope("moe_experts"):
+        out = _gated_mlp(
+            rows, *(_qw(lp[n], dt) for n in ("w_gate", "w_up", "w_down")),
+            product=lambda a, w: jax.lax.ragged_dot(a, w, group_sizes), clean=clean)
+    with jax.named_scope("moe_combine"):
+        by_row = _permute(gates.reshape(-1), order, inverse)
+        # weighted in float32 and rounded once: the gates' gradient is then a float32 sum
+        # over the row (a sum of 2,048 bfloat16 products kept in bfloat16 was 12 % off in
+        # the router's gradient on the chip, ten times the plain bfloat16 reference's)
+        y = _combine((out.astype(jnp.float32) * by_row[:, None]).astype(dt), order, inverse, k)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            y = y + _gated_mlp(x, *(_qw(lp[n], dt) for n in
+                                    ("shared_gate", "shared_up", "shared_down")))
+    return y, {"load": load, "chosen": idx}
+
+
 def init_expert_weights(key: jax.Array, cfg: ModelConfig):
-    """Per-layer MoE parameter block (replaces the dense w_gate/w_up/w_down)."""
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
-    ks = jax.random.split(key, 4)
+    """Per-layer MoE parameter block (replaces the dense w_gate/w_up/w_down). The
+    dropless layer has the experts it holds, of width d_ff_expert, and beside them the
+    selection bias and the shared experts (as one MLP of n_shared_experts times the
+    width) where the configuration has them."""
+    d, e = cfg.d_model, cfg.n_experts
+    f = (cfg.d_ff_expert or cfg.d_ff) if cfg.moe_dropless else cfg.d_ff
+    held = cfg.n_experts_held if cfg.moe_dropless else e
+    ks = jax.random.split(key, 7)
     s_in = d**-0.5
     s_out = (2 * cfg.n_layers * f) ** -0.5
-    return {
+    out = {
         "router": jax.random.normal(ks[0], (d, e), jnp.float32) * s_in,
-        "w_gate": jax.random.normal(ks[1], (e, d, f), jnp.float32) * s_in,
-        "w_up": jax.random.normal(ks[2], (e, d, f), jnp.float32) * s_in,
-        "w_down": jax.random.normal(ks[3], (e, f, d), jnp.float32) * s_out,
+        "w_gate": jax.random.normal(ks[1], (held, d, f), jnp.float32) * s_in,
+        "w_up": jax.random.normal(ks[2], (held, d, f), jnp.float32) * s_in,
+        "w_down": jax.random.normal(ks[3], (held, f, d), jnp.float32) * s_out,
     }
+    if cfg.moe_dropless and cfg.moe_select_bias:
+        out["router_bias"] = jnp.zeros((e,), jnp.float32)
+    if cfg.moe_dropless and cfg.n_shared_experts:
+        fs = cfg.n_shared_experts * f
+        out.update(shared_gate=jax.random.normal(ks[4], (d, fs), jnp.float32) * s_in,
+                   shared_up=jax.random.normal(ks[5], (d, fs), jnp.float32) * s_in,
+                   shared_down=jax.random.normal(ks[6], (fs, d), jnp.float32) * s_out)
+    return out
+
+
+def expert_axes(cfg: ModelConfig):
+    """Logical axes of the leaves init_expert_weights gives this configuration."""
+    names = ["router", "w_gate", "w_up", "w_down"]
+    if cfg.moe_dropless:
+        names += ["router_bias"] * cfg.moe_select_bias
+        names += ["shared_gate", "shared_up", "shared_down"] * bool(cfg.n_shared_experts)
+    return {n: EXPERT_AXES[n] for n in names}
 
 
 EXPERT_AXES = {
@@ -146,4 +303,15 @@ EXPERT_AXES = {
     "w_gate": ("expert", "embed", "mlp"),
     "w_up": ("expert", "embed", "mlp"),
     "w_down": ("expert", "mlp", "embed"),
+    "router_bias": ("expert",),
+    "shared_gate": ("embed", "mlp"),
+    "shared_up": ("embed", "mlp"),
+    "shared_down": ("mlp", "embed"),
 }
+
+
+def balance_bias(bias: jax.Array, load: jax.Array, rate: float) -> jax.Array:
+    """The selection bias after a step (DeepSeek-V3, arXiv:2412.19437 section 2.1.2): an
+    expert that got less than the mean load rises by `rate`, one that got more falls.
+    bias and load [..., E]."""
+    return bias + rate * jnp.sign(load.mean(-1, keepdims=True) - load)

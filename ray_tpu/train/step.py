@@ -118,6 +118,10 @@ def make_train_step(
     loss_fn = loss_fn or llama.loss_fn
     sync = sync or grad_sync.GradSyncConfig.from_env()
     if not sync.is_default:
+        if cfg.moe_dropless and cfg.moe_select_bias:
+            raise NotImplementedError(
+                "the selection bias's balance rule runs in the stock step only; "
+                "train/grad_sync.py's steps would leave it to the optimizer")
         return grad_sync.make_step(cfg, tx, loss_fn, sync, donate)
 
     def step(state: TrainState, batch: Dict[str, jax.Array]):
@@ -127,6 +131,10 @@ def make_train_step(
         with jax.named_scope("optimizer"):  # a name in the profile; no operation
             updates, new_opt = tx.update(grads, state.opt_state, state.params)
             new_params = optax.apply_updates(state.params, updates)
+        if cfg.moe_dropless and cfg.moe_select_bias:
+            # outside the gradient and the optimizer (whose weight decay would pull it to
+            # zero): the selection bias moves by the balance rule, on the step's own counts
+            new_params = llama.balance_router_bias(state.params, new_params, aux["expert_load"], cfg)
         metrics = dict(aux)
         metrics["grad_norm"] = optax.global_norm(grads)
         return TrainState(state.step + 1, new_params, new_opt), metrics

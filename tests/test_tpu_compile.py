@@ -110,6 +110,54 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
         assert len(kernels) == (1 if "fwd" in path else 2), (path, kernels)
 
 
+def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
+    """[1, 8192, 20/20, 256], the latent-attention cell's shape (glm47flash-train-
+    ep8share-s8192): heads twice the lane width, no grouping, K and V of a head exactly
+    the span budget; forward, dQ and dK/dV under the names the trace metrics select by."""
+    from ray_tpu.ops.flash_attention import flash_attention, tile_counts
+
+    b, s, h, d = 1, 8192, 20, 256
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile().as_text()
+    calls = [ln.strip().split(" = ")[0] for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert sum(name in c for c in calls) == 1, (name, calls)
+    grids = _pallas_grids(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr)
+    steps = tile_counts(s, s, True, 512, 512, head_dim=d).grid_steps
+    assert (b, h, 16, steps // 16) in grids and (b, h, 16, 2) in grids, grids  # one kv span; two q spans
+
+
+def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
+    """The dropless expert layer at the cell's shape (8,192 tokens x 4 assignments, 8
+    held experts of 2048 x 1536): its grouped products, forward and both transposes,
+    are the TPU compiler's own ragged-dot kernels (9 = 3 products x 3), not a dense
+    product an expert over the whole buffer; no scatter in either direction."""
+    from ray_tpu.models import moe
+    from ray_tpu.models.config import ModelConfig
+
+    cfg = ModelConfig(
+        name="glm-shape", vocab_size=19360, d_model=2048, n_layers=5, n_heads=20, n_kv_heads=20,
+        d_ff=10240, n_experts=64, moe_top_k=4, moe_capacity_factor=0.0, d_ff_expert=1536,
+        n_shared_experts=1, moe_scoring="sigmoid", moe_route_scale=1.8, moe_select_bias=True,
+        experts_held=(0, 8))
+    lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
+                 one_chip)
+    x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(moe.expert_layer(x, lp, cfg)[0].astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if re.match(r"\s*%ragged-dot-none[\w.]* = ", ln)]
+    assert len(kernels) == 9, len(kernels)
+    assert sum("bf16[32768," in ln.split(" custom-call(")[0] for ln in kernels) == 6
+    assert not re.search(r" scatter\(", text)
+
+
 @pytest.mark.parametrize("b,s,h,kv,per_row", [
     (6, 2048, 32, 8, False),   # mistral7b-train-1chip: one row of positions for the batch
     (4, 2048, 32, 8, False),   # a chip's shard of mistral7b-train-fsdp4
