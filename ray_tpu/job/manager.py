@@ -109,14 +109,14 @@ class JobManager:
 
         def supervise():
             rc = proc.wait()
-            cur = self._load(job_id)
-            if cur is None or cur.status == JobStatus.STOPPED:
-                return
-            cur.status = JobStatus.SUCCEEDED if rc == 0 else JobStatus.FAILED
-            cur.return_code = rc
-            cur.end_time = time.time()
-            self._save(cur)
-            with self._lock:
+            with self._lock:  # against stop_job, which marks the job STOPPED before it kills
+                cur = self._load(job_id)
+                if cur is None or cur.status == JobStatus.STOPPED:
+                    return
+                cur.status = JobStatus.SUCCEEDED if rc == 0 else JobStatus.FAILED
+                cur.return_code = rc
+                cur.end_time = time.time()
+                self._save(cur)
                 self._procs.pop(job_id, None)
 
         threading.Thread(target=supervise, daemon=True,
@@ -156,8 +156,12 @@ class JobManager:
             raise KeyError(f"unknown job {job_id}")
         with self._lock:
             proc = self._procs.get(job_id)
-        if proc is None or proc.poll() is not None:
-            return False
+            if proc is None or proc.poll() is not None:
+                return False
+            # marked before the kill: the supervisor wakes with the process's death, and
+            # would else write FAILED beside this STOPPED (two writers of one info.json.tmp)
+            info.status = JobStatus.STOPPED
+            self._save(info)
         # SIGTERM the whole process group (shell + script)
         try:
             os.killpg(os.getpgid(proc.pid), signal.SIGTERM)
@@ -171,10 +175,10 @@ class JobManager:
             with __import__("contextlib").suppress(ProcessLookupError):
                 os.killpg(os.getpgid(proc.pid), signal.SIGKILL)
             proc.wait()
-        info.status = JobStatus.STOPPED
         info.end_time = time.time()
         info.return_code = proc.returncode
-        self._save(info)
+        with self._lock:
+            self._save(info)
         return True
 
     def wait_job(self, job_id: str, timeout: Optional[float] = None) -> str:

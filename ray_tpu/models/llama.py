@@ -684,8 +684,14 @@ def loss_fn(
         from . import moe as _moe
 
         lo, hi = _moe.held_range(cfg)
+        held = load[:, lo:hi].sum(-1)
         # a row an expert layer, the MTP modules' last: what the balance rule reads, what
-        # fell on the experts held here, and the experts each token chose [.., B * S, k]
-        metrics.update(expert_load=load, held_assignments=load[:, lo:hi].sum(-1),
-                       fullest_held_expert_rows=load[:, lo:hi].max(-1), experts_chosen=chosen)
+        # fell on the experts held here, the windows of the layer's buffer that load took
+        # (1: the step fitted the rows the held experts can expect; more: it overflowed
+        # them and was served all the same), and the experts each token chose [.., B * S, k]
+        metrics.update(
+            expert_load=load, held_assignments=held,
+            fullest_held_expert_rows=load[:, lo:hi].max(-1), experts_chosen=chosen,
+            expert_windows=_moe.windows_walked(
+                held.astype(jnp.int32), _moe.window_rows(cfg, chosen.shape[-2])))
     return loss, {"loss": loss, "ce_loss": ce, **metrics}

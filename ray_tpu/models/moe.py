@@ -139,12 +139,20 @@ def moe_mlp(
 # experts held elsewhere would add is left out: on one chip the layer runs without its
 # exchange, and nothing here stands in for it.
 #
-# Shapes are static, so the sorted buffer has a row for every assignment (tokens x k:
-# all of them can fall on held experts); the held ones lie first, grouped, and the rows
-# behind them are zeros that no group covers.
+# Shapes are static and every assignment CAN fall on held experts, but a layer that holds
+# a share of them expects that share of the rows. So the routed path works on a buffer of
+# `window_rows` rows, twice what the held experts can expect, and stays dropless at any
+# load by walking the sorted held assignments in windows of that many rows, as many as
+# the step's load needs: one at any load a balanced router produces, tokens x k over the
+# window's rows at most. Inside a window the held rows lie first, grouped, and the rows
+# behind them belong to no group.
 
 
 CHOSEN_NAME = "experts_chosen"  # what `route` chose, by the name remat policies keep it under
+
+# Rows of a tile of the TPU compiler's grouped kernels (`ragged-dot-none`: its metadata
+# has a tile for every 512 rows of the buffer and one more a group); a window is whole tiles.
+_ROW_TILE = 512
 
 
 def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: ModelConfig):
@@ -182,39 +190,129 @@ def held_range(cfg: ModelConfig) -> Tuple[int, int]:
     return index * n, (index + 1) * n
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _dispatch(x, order, inverse, k: int):
-    """x [T, D] -> [T * k, D]: row j is the token of assignment order[j] (assignment a
-    belongs to token a // k). Its transpose, `_combine`, sums each token's k rows; both
-    directions are gathers (a scatter-add serialises on the TPU)."""
-    return x[order // k]
+def window_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Rows of the routed path's buffer for `tokens` tokens: twice what the held experts
+    can expect of the tokens x k assignments (at uniform routing the held rows of the
+    benchmark's cell are 4,096 +- 60 of 32,768, so only a router far out of balance walks a
+    second window), in whole tiles of the grouped product, tokens x k at most. A layer
+    that holds every expert, or half of them, gets tokens x k: one window, statically."""
+    n = tokens * cfg.moe_top_k
+    return min(n, -(-2 * n // (cfg.experts_held[1] * _ROW_TILE)) * _ROW_TILE)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _combine(rows, order, inverse, k: int):
-    """rows [T * k, D] in sorted order -> [T, D]: `inverse` puts them back in assignment
-    order, where a token's k rows are neighbours."""
-    return rows[inverse].reshape(-1, k, rows.shape[-1]).sum(axis=1)
+def windows_walked(held_rows: jax.Array, rows: int) -> jax.Array:
+    """Windows of `rows` rows the layer walks for a load of `held_rows` (int32): the
+    first always, one more for every `rows` rows begun beyond it."""
+    return jnp.maximum(-(-held_rows // rows), 1)
 
 
-@jax.custom_vjp
-def _permute(x, order, inverse):
-    """x[order], whose transpose is a gather too: g[inverse]."""
-    return x[order]
+# A window is rows [start, start + rows) of the sorted order. `_take` brings a token's (or
+# an assignment's, k = 1) values to the window's rows, `_put` sums a window's rows back
+# onto their tokens; each is the other's transpose, and both directions are gathers (a
+# scatter-add serialises on the TPU): assignment a belongs to token a // k, lies at
+# inverse[a] in the sorted order, and a token's k assignments are neighbours. Each names
+# its own scope, as the kind of pass it is: JAX drops the caller's `named_scope`s from
+# what a `custom_vjp` inside a `custom_vjp` (`_walk`) traces, and the trace's share of
+# the expert layer is read by these names.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _take(a, order, inverse, start, rows: int, k: int):
+    """a [T, ...] (k = 1: [T * k]) -> [rows, ...]: row j is a's entry for the token
+    (the assignment) of order[start + j]."""
+    with jax.named_scope("moe_dispatch" if k > 1 else "moe_combine"):  # tokens, or gates
+        return a[jax.lax.dynamic_slice(order, (start,), (rows,)) // k]
 
 
-_permute.defvjp(lambda x, order, inverse: (x[order], (order, inverse)),
-                lambda res, g: (_permute(g, res[1], res[0]), None, None))
-_dispatch.defvjp(lambda x, order, inverse, k: (_dispatch(x, order, inverse, k), (order, inverse)),
-                 lambda k, res, g: (_combine(g, *res, k), None, None))
-_combine.defvjp(lambda rows, order, inverse, k: (_combine(rows, order, inverse, k), (order, inverse)),
-                lambda k, res, g: (_dispatch(g, *res, k), None, None))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _put(b, order, inverse, start, rows: int, k: int):
+    """b [rows, ...] -> [T, ...] (k = 1: [T * k]): every assignment's row of the window
+    (zeros for the assignments that lie outside it), summed over a token's k in float32
+    and rounded once. A gather a slot, tokens rows each: no buffer has tokens x k rows."""
+    with jax.named_scope("moe_combine"):
+        at = (inverse - start).reshape(-1, k)
+        out = 0
+        for j in range(k):
+            row = b[jnp.clip(at[:, j], 0, rows - 1)]
+            if rows != inverse.shape[0]:
+                inside = (at[:, j] >= 0) & (at[:, j] < rows)
+                row = jnp.where(inside.reshape(-1, *[1] * (b.ndim - 1)), row, 0)
+            out = row if k == 1 else out + row.astype(jnp.float32)
+        return out.astype(b.dtype)
+
+
+_take.defvjp(lambda a, *w: (_take(a, *w), w[:3]),
+             lambda rows, k, w, g: (_put(g, *w, rows, k), None, None, None))
+_put.defvjp(lambda b, *w: (_put(b, *w), w[:3]),
+            lambda rows, k, w, g: (_take(g, *w, rows, k), None, None, None))
 
 
 def _gated_mlp(x, w_gate, w_up, w_down, product=jnp.matmul, clean=lambda a: a):
     """SiLU-gated MLP over `product`; `clean` goes around each product's input and output."""
     act = clean(jax.nn.silu(clean(product(x, w_gate))) * clean(product(x, w_up)))
     return clean(product(act, w_down))
+
+
+def _window(x, w_gate, w_up, w_down, gates, order, inverse, ends, start, rows: int, k: int):
+    """What the held experts add to y [T, D] for the assignments at [start, start + rows)
+    of the sorted order. gates [T * k] f32; ends [held] int32: where each held expert's
+    rows end in that order."""
+    upto = jnp.clip(ends - start, 0, rows)  # ... and in this window
+    group_sizes = jnp.diff(upto, prepend=0)
+    served = (jnp.arange(rows) < upto[-1])[:, None]
+    where = (order, inverse, start, rows)
+
+    def clean(a):
+        # The rows behind the groups come back from the grouped product as whatever it
+        # left there, forward (its output) and backward (its cotangents): on the chip that
+        # is stale memory, NaN included, and 0 x NaN reaches the gates' gradient. A
+        # select, both ways, around every product.
+        return jnp.where(served, a, 0)
+
+    with jax.named_scope("moe_dispatch"):
+        xin = clean(_take(x, *where, k))
+    with jax.named_scope("moe_experts"):
+        out = _gated_mlp(xin, w_gate, w_up, w_down, clean=clean,
+                         product=lambda a, w: jax.lax.ragged_dot(a, w, group_sizes))
+    with jax.named_scope("moe_combine"):
+        by_row = _take(gates, *where, 1)
+        # weighted in float32 and rounded once: the gates' gradient is then a float32 sum
+        # over the row (a sum of 2,048 bfloat16 products kept in bfloat16 was 12 % off in
+        # the router's gradient on the chip, ten times the plain bfloat16 reference's)
+        return _put((out.astype(jnp.float32) * by_row[:, None]).astype(x.dtype), *where, k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _walk(x, w_gate, w_up, w_down, gates, order, inverse, ends, rows: int, k: int):
+    """The sum of `_window` over the windows that hold served rows. The first is always
+    computed; the others, which only a load beyond `rows` has, in a loop of as many turns
+    as that load needs. Differentiated by hand: JAX cannot transpose a loop of dynamic
+    length, and through a `cond` it would keep both branches' residuals at full size.
+    Only the inputs are kept; the backward pass walks the same windows and takes each
+    one's `jax.vjp` (under rematerialisation the layer is recomputed anyway)."""
+    def window(start):
+        return _window(x, w_gate, w_up, w_down, gates, order, inverse, ends, start, rows, k)
+
+    with jax.named_scope("moe_walk"):  # the loop's own copies and sums: the layer's too
+        return jax.lax.fori_loop(1, windows_walked(ends[-1], rows),
+                                 lambda w, y: y + window(w * rows), window(0))
+
+
+def _walk_bwd(rows, k, args, dy):
+    inputs, (order, inverse, ends) = args[:5], args[5:]
+
+    def pull(start):
+        return jax.vjp(lambda *a: _window(*a, order, inverse, ends, start, rows, k), *inputs)[1](dy)
+
+    def more(w, acc):  # an overflowing step's sums, in float32
+        return tuple((s.astype(jnp.float32) + g.astype(jnp.float32)).astype(s.dtype)
+                     for s, g in zip(acc, pull(w * rows)))
+
+    with jax.named_scope("moe_walk"):
+        grads = jax.lax.fori_loop(1, windows_walked(ends[-1], rows), more, pull(0))
+    return (*grads, None, None, None)
+
+
+_walk.defvjp(lambda *a: (_walk(*a), a[:8]), _walk_bwd)
 
 
 def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
@@ -226,6 +324,7 @@ def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
     [S * F, D] (where cfg.n_shared_experts)."""
     dt = x.dtype
     k, (lo, hi) = cfg.moe_top_k, held_range(cfg)
+    rows = window_rows(cfg, x.shape[0])
     with jax.named_scope("moe_router"):
         idx, gates = route(x, lp["router"], lp.get("router_bias"), cfg)
         load = (idx[..., None] == jnp.arange(cfg.n_experts)).sum((0, 1), dtype=jnp.float32)
@@ -234,27 +333,14 @@ def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
         key = jnp.where((idx >= lo) & (idx < hi), idx - lo, hi - lo).reshape(-1)
         order = jnp.argsort(key, stable=True).astype(jnp.int32)
         inverse = jnp.argsort(order).astype(jnp.int32)
-        group_sizes = load[lo:hi].astype(jnp.int32)
-        served = (jnp.arange(order.shape[0]) < group_sizes.sum())[:, None]
-
-        def clean(a):
-            # The rows behind the groups are zeros going in, and whatever the grouped
-            # product left there coming out, forward (its output) and backward (its
-            # cotangents): on the chip that is stale memory, NaN included, and 0 x NaN
-            # reaches the gates' gradient. A select, both ways, around every product.
-            return jnp.where(served, a, 0)
-
-        rows = clean(_dispatch(x, order, inverse, k))
+        order = jnp.pad(order, (0, -order.shape[0] % rows))  # the last window, whole
+        ends = jnp.cumsum(load[lo:hi].astype(jnp.int32))
+    # one window, statically (every expert held): plain differentiation of it is the
+    # program this layer always was, with no loop and nothing recomputed
     with jax.named_scope("moe_experts"):
-        out = _gated_mlp(
-            rows, *(_qw(lp[n], dt) for n in ("w_gate", "w_up", "w_down")),
-            product=lambda a, w: jax.lax.ragged_dot(a, w, group_sizes), clean=clean)
-    with jax.named_scope("moe_combine"):
-        by_row = _permute(gates.reshape(-1), order, inverse)
-        # weighted in float32 and rounded once: the gates' gradient is then a float32 sum
-        # over the row (a sum of 2,048 bfloat16 products kept in bfloat16 was 12 % off in
-        # the router's gradient on the chip, ten times the plain bfloat16 reference's)
-        y = _combine((out.astype(jnp.float32) * by_row[:, None]).astype(dt), order, inverse, k)
+        weights = [_qw(lp[n], dt) for n in ("w_gate", "w_up", "w_down")]
+    args = (x, *weights, gates.reshape(-1), order, inverse, ends)
+    y = _window(*args, 0, rows, k) if rows == inverse.shape[0] else _walk(*args, rows, k)
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
             y = y + _gated_mlp(x, *(_qw(lp[n], dt) for n in

@@ -96,10 +96,15 @@ def init_state(
                 opt_state = grad_sync.shard_opt_state(
                     tx, params, opt_state, sync, mesh)
         step = jax.device_put(jnp.zeros((), jnp.int32), named_sharding(mesh))
-    else:
-        opt_state = tx.init(params)
-        step = jnp.zeros((), jnp.int32)
-    return TrainState(step=step, params=params, opt_state=opt_state)
+        return TrainState(step=step, params=params, opt_state=opt_state)
+    # Committed to the device it is on, as a state that comes back from a checkpoint or
+    # from the host is: `jit` keys its programs on that, and a step first called on an
+    # uncommitted state was compiled a second time at its first call after a restore
+    # (the same program: 3 s from a warm compile cache, 31-39 s from a cold one in the
+    # GLM cell, PERF.md section 7).
+    state = TrainState(step=jnp.zeros((), jnp.int32), params=params, opt_state=tx.init(params))
+    return jax.tree.map(  # (a caller that only asks for shapes traces this: nothing to commit)
+        lambda a: a if isinstance(a, jax.core.Tracer) else jax.device_put(a, a.sharding), state)
 
 
 def make_train_step(

@@ -174,6 +174,145 @@ def test_dropless_under_a_forced_skew():
     assert (np.asarray(m["expert_load"]).sum(-1) == tokens * cfg.moe_top_k).all()
 
 
+# The window walk: a layer that holds a share of the experts works on a buffer of
+# moe.window_rows rows and walks the sorted held assignments in as many windows of it as
+# the load needs. Sixteen experts, so that a share of an eighth holds two and every one
+# of a token's two assignments can be held.
+WALK = dataclasses.replace(CFG, n_experts=16)
+
+
+def _steered(cfg, tokens, held_rows, seed=7):
+    """A layer and tokens whose load on the held experts is `held_rows` exactly: each
+    held expert's router column reads one feature of x alone, +1 for the tokens steered
+    to it and -1 for the others (a score of 0.9997 or 0.0003 beside the other experts'
+    0.1-0.9), so a token chooses the held experts it is steered to and no other."""
+    lp, x = _layer(cfg, seed=seed, tokens=tokens)
+    lo, hi = moe.held_range(cfg)
+    j = np.arange(held_rows)  # round by round over the tokens, a round an assignment
+    assert held_rows <= tokens * cfg.moe_top_k and hi - lo >= cfg.moe_top_k
+    steer = np.zeros((tokens, hi - lo), bool)
+    steer[j % tokens, (j % tokens + j // tokens) % (hi - lo)] = True
+    lp["router"] = lp["router"].at[:, lo:hi].set(8.0 * jnp.eye(cfg.d_model, hi - lo))
+    x = x.at[:, :hi - lo].set(jnp.where(steer, 1.0, -1.0))
+    return lp, x
+
+
+@pytest.mark.parametrize("held,tokens,load", [
+    (held, tokens, load)
+    for held, tokens in (((1, 4), 1024), ((3, 8), 1024), ((3, 8), 1000))  # 1,000: a last window not whole
+    for load in ("under", "exactly", "one_over", "every")])
+def test_the_window_walk_matches_the_reference(held, tokens, load):
+    """Output and every gradient (x, the three weights, the router through the gates)
+    at loads under the window's rows, at them, one over (a second window of one row) and
+    with every assignment held (the most windows); the counter says how many were walked."""
+    cfg = dataclasses.replace(WALK, experts_held=held)
+    n, rows = tokens * cfg.moe_top_k, moe.window_rows(cfg, tokens)
+    assert rows < n
+    held_rows = {"under": rows // 2 + 3, "exactly": rows, "one_over": rows + 1, "every": n}[load]
+    lp, x = _steered(cfg, tokens, held_rows)
+    lo, hi = moe.held_range(cfg)
+    lp.pop("router_bias")
+    cot = jax.random.normal(jax.random.PRNGKey(11), x.shape)
+    leaves = ("router", "w_gate", "w_up", "w_down")
+
+    def mine(x, w):
+        y, counted = moe.expert_layer(x, {**lp, **w}, cfg)
+        return jnp.sum(y * cot), (y, counted)
+
+    def theirs(x, w):
+        y, _ = ref.expert_layer(x[None], {**lp, **w}, _model(cfg))
+        return jnp.sum(y[0] * cot), y[0]
+
+    w = {name: lp[name] for name in leaves}
+    (_, (y, counted)), grads = jax.jit(jax.value_and_grad(mine, argnums=(0, 1), has_aux=True))(x, w)
+    (_, want), r_grads = jax.jit(jax.value_and_grad(theirs, argnums=(0, 1), has_aux=True))(x, w)
+    assert float(counted["load"][lo:hi].sum()) == held_rows
+    walked = moe.windows_walked(counted["load"][lo:hi].sum().astype(jnp.int32), rows)
+    assert int(walked) == -(-held_rows // rows) == {
+        "under": 1, "exactly": 1, "one_over": 2, "every": -(-n // rows)}[load]
+    np.testing.assert_allclose(y, want, atol=2e-5 * float(jnp.abs(want).max()))
+    for (path, g), r in zip(jax.tree_util.tree_flatten_with_path(grads)[0], jax.tree.leaves(r_grads)):
+        scale = float(jnp.abs(r).max())
+        assert scale > 0, path
+        np.testing.assert_allclose(g, r, atol=2e-5 * scale, err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("tokens,k,n_experts,held,rows", [
+    (8192, 4, 64, (0, 8), 8192),    # glm47flash-train-ep8share-s8192: a quarter of 32,768
+    (96, 2, 8, (0, 1), 192),        # tier-1: every expert held, tokens x k
+    (96, 2, 8, (1, 2), 192),        # half of them: twice the expected rows is all of them
+    (96, 2, 8, (3, 8), 192),        # a tile is more than tokens x k
+    (128, 2, 8, (1, 2), 256),       # the benchmark's rehearsal
+    (1024, 2, 16, (1, 4), 1024),
+    (1024, 2, 16, (3, 8), 512),
+    (1000, 2, 16, (3, 8), 512),     # 500 rows expected twice, in whole tiles
+    (8192, 4, 64, (5, 64), 1024),   # one expert of 64 held
+])
+def test_window_rows_follow_the_share_held(tokens, k, n_experts, held, rows):
+    cfg = dataclasses.replace(CFG, n_experts=n_experts, moe_top_k=k, experts_held=held)
+    assert moe.window_rows(cfg, tokens) == rows
+    assert rows % 512 == 0 or rows == tokens * k
+
+
+def _primitives(jaxpr):
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names |= _primitives(sub)
+    return names
+
+
+@pytest.mark.parametrize("held,tokens,loops", [((0, 1), 1024, False), ((1, 2), 1024, False),
+                                               ((1, 4), 96, False), ((1, 4), 1024, True)])
+def test_only_a_share_that_can_overflow_its_window_has_a_loop(held, tokens, loops):
+    """One window, statically (every expert held, or a buffer no smaller than tokens x
+    k): the program this layer always was, with no loop and no branch. A smaller window:
+    a loop, and still no scatter in either direction."""
+    cfg = dataclasses.replace(WALK, experts_held=held)
+    lp, x = _layer(cfg, tokens=tokens)
+
+    def loss(x, lp):
+        return jnp.sum(moe.expert_layer(x, lp, cfg)[0])
+
+    names = _primitives(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, lp).jaxpr)
+    assert ("while" in names) == loops and "cond" not in names, sorted(names)
+    assert not any("scatter" in name for name in names), sorted(names)
+    assert "ragged_dot" in names or "ragged_dot_general" in names, sorted(names)
+
+
+def test_the_step_counts_the_windows_it_walked():
+    """`expert_windows`, a row an expert layer and the MTP module's last: 1 at the
+    benchmark's rehearsal size; with the stack's router biased onto the held experts,
+    tokens x k over the window's rows there and still 1 in the MTP module."""
+    sys.path.insert(0, ROOT)
+    from benchmarks.lib import modelcfg
+
+    with open(os.path.join(ROOT, "benchmarks", "rehearsal", "glm-4.7-flash-train-ep8.json")) as f:
+        rehearsal = json.load(f)
+    cfg = modelcfg.model_config(modelcfg.model_keys(rehearsal))
+    tr = rehearsal["trainer"]
+    t = _tokens(cfg, (tr["batch"], tr["seq"] + 1))
+    _, m = llama.loss_fn(_params(cfg), {"tokens": t}, cfg)
+    np.testing.assert_array_equal(m["expert_windows"], [1, 1, 1])
+    cfg = dataclasses.replace(WALK, experts_held=(1, 4), max_seq_len=512)  # holds 4..7
+    p, t = _params(cfg, biased=False), _tokens(cfg, (2, 513))
+    p["layers"]["router_bias"] = p["layers"]["router_bias"].at[:, 4:6].set(10.0)
+    (loss, m), grads = jax.jit(jax.value_and_grad(
+        lambda p: llama.loss_fn(p, {"tokens": t}, cfg), has_aux=True))(p)
+    rows = moe.window_rows(cfg, 1024)
+    assert rows == 1024
+    np.testing.assert_array_equal(m["held_assignments"][:2], [2048, 2048])
+    np.testing.assert_array_equal(m["expert_windows"][:2], [2, 2])
+    assert m["expert_windows"][2] == 1 and m["held_assignments"][2] <= rows
+    r_loss, r_grads = jax.jit(jax.value_and_grad(lambda p: ref.loss(p, t, _model(cfg))))(p)
+    np.testing.assert_allclose(loss, r_loss, rtol=1e-6)
+    for name in ("w_gate", "w_up", "w_down", "router"):
+        scale = float(jnp.abs(r_grads["layers"][name]).max())
+        np.testing.assert_allclose(grads["layers"][name], r_grads["layers"][name],
+                                   atol=2e-5 * scale, err_msg=name)
+
+
 def test_selection_is_by_score_plus_bias_and_gates_are_from_the_scores():
     cfg = dataclasses.replace(CFG, moe_top_k=2)
     w = jnp.eye(8)[:4]  # d_model 4: logits are x's own entries

@@ -92,6 +92,23 @@ def test_sharded_train_step():
     assert w.sharding.spec == params["layers"]["w_gate"].sharding.spec
 
 
+def test_a_restored_state_runs_the_step_it_compiled():
+    """A seeded state is committed to its device as a state that comes back from the
+    host (a checkpoint restored, the benchmark's comparison with its reference) is:
+    the step compiled for the first serves the second. It was compiled twice."""
+    from ray_tpu.train import init_state, make_optimizer, make_train_step
+
+    tx = make_optimizer()
+    state = init_state(jax.random.PRNGKey(0), CFG, tx)
+    shardings = jax.tree.map(lambda a: a.sharding, state)
+    step = make_train_step(CFG, tx, donate=False)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(5), (2, 17), 0, CFG.vocab_size)}
+    state, _ = step(state, batch)
+    restored = jax.device_put(jax.device_get(state), shardings)
+    step(restored, batch)
+    assert step._cache_size() == 1
+
+
 def test_n_params_reasonable():
     cfg8b = get_config("llama3-8b")
     assert 7.5e9 < cfg8b.n_params < 8.6e9

@@ -133,9 +133,14 @@ def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
 
 def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
     """The dropless expert layer at the cell's shape (8,192 tokens x 4 assignments, 8
-    held experts of 2048 x 1536): its grouped products, forward and both transposes,
-    are the TPU compiler's own ragged-dot kernels (9 = 3 products x 3), not a dense
-    product an expert over the whole buffer; no scatter in either direction."""
+    held experts of 2048 x 1536, a window of 8,192 rows): its grouped products are the
+    TPU compiler's own ragged-dot kernels, not a dense product an expert over the whole
+    buffer. 21 of them: a window is 3 products forward and 9 in its backward pass (the
+    3 again, since only the walk's inputs are kept, and 2 transposes each), and the
+    window's body is in the program twice, for the first window (9: XLA shares its
+    forward products with the backward's, no rematerialisation standing between them
+    here) and in the loops that only an overflowing step enters (3 + 9). No scatter in
+    either direction, and nothing of tokens x k rows by either width is left."""
     from ray_tpu.models import moe
     from ray_tpu.models.config import ModelConfig
 
@@ -144,6 +149,7 @@ def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
         d_ff=10240, n_experts=64, moe_top_k=4, moe_capacity_factor=0.0, d_ff_expert=1536,
         n_shared_experts=1, moe_scoring="sigmoid", moe_route_scale=1.8, moe_select_bias=True,
         experts_held=(0, 8))
+    assert moe.window_rows(cfg, 8192) == 8192
     lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
                  one_chip)
     x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=one_chip)
@@ -151,11 +157,15 @@ def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
     def loss(x, lp):
         return jnp.sum(moe.expert_layer(x, lp, cfg)[0].astype(jnp.float32))
 
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
     kernels = [ln for ln in text.splitlines() if re.match(r"\s*%ragged-dot-none[\w.]* = ", ln)]
-    assert len(kernels) == 9, len(kernels)
-    assert sum("bf16[32768," in ln.split(" custom-call(")[0] for ln in kernels) == 6
+    assert len(kernels) == 21, len(kernels)
+    assert sum("bf16[8192," in ln.split(" custom-call(")[0] for ln in kernels) == 15
     assert not re.search(r" scatter\(", text)
+    full = [ln.strip()[:160] for ln in text.splitlines()
+            if re.search(r"\[32768,(1536|2048)\]", ln) and re.search(r'op_name="[^"]*moe_', ln)]
+    assert not full, full[:4]
+    assert not re.search(r"\[32768,(1536|2048)\]", text)  # nor anywhere else in the layer
 
 
 @pytest.mark.parametrize("b,s,h,kv,per_row", [
