@@ -189,6 +189,8 @@ def _served(cfg):
         (cfg.n_dense_layers, "a layer loop over stacks of more than one kind (llm/model_runner.py)"),
         (cfg.moe_dropless, "the dropless expert layer under decode (inactive slots are not masked)"),
         (cfg.mtp_depth, "multi-token-prediction modules as drafts of the verify window"),
+        (cfg.layer_pattern, "a pattern of single-part layers under decode: recurrent state and "
+                            "convolution tails a slot beside the KV cache (cache manager, snapshots, prefix cache)"),
     ) if has]
     if missing:
         raise NotImplementedError(

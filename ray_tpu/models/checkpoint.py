@@ -82,8 +82,67 @@ def config_from_hf(source_dir: str, **overrides) -> ModelConfig:
             hf.get("moe_capacity_factor", e / k))
     if hf.get("model_type") == "glm4_moe_lite":
         fields.update(_glm4_moe_lite_fields(hf))
+    if hf.get("model_type") == "nemotron_h":
+        fields.update(_nemotron_h_fields(hf))
     fields.update(overrides)
     return ModelConfig(**fields)
+
+
+def _nemotron_h_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `nemotron_h` keys (Nemotron-3-Super) as ModelConfig fields: a pattern of
+    single-part layers (Mamba-2 mixers, attention, latent relu2 experts beside a shared
+    one, the dense relu2 MLP) and the MTP modules. Everything is held; a share is an
+    override (fewer ssm heads and groups, `attn_heads_held`, `experts_held`). What the
+    program does not run is refused by name. The attention layers apply NO rotation (the
+    published implementation's attention is Jamba's, which has none; rope_theta and
+    partial_rotary_factor stand in the config unread): one field, attention_rotation, for
+    a reader who finds otherwise. Weights' names are not mapped."""
+    pattern = hf["hybrid_override_pattern"]
+    refused = [what for has, what in (
+        (hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1,
+         "group-limited routing (n_group / topk_group > 1)"),
+        (not hf.get("norm_topk_prob", True), "gates that are not normalised (norm_topk_prob false)"),
+        (hf.get("mlp_hidden_act", "relu2") != "relu2", f"mlp_hidden_act {hf.get('mlp_hidden_act')!r}"),
+        (hf.get("mamba_hidden_act", "silu") != "silu", f"mamba_hidden_act {hf.get('mamba_hidden_act')!r}"),
+        (any(hf.get(k) for k in ("attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias")),
+         "biases on the projections"),
+        (not hf.get("use_conv_bias", True), "a convolution without its bias"),
+        (hf.get("time_step_limit") is not None, "a clamp on dt (time_step_limit)"),
+        (hf.get("sliding_window") is not None, "window attention (sliding_window)"),
+        (hf.get("moe_shared_expert_overlap", False), "moe_shared_expert_overlap"),
+        (hf.get("num_nextn_predict_layers", 0) and hf.get("mtp_hybrid_override_pattern", "*E") != "*E",
+         f"an MTP module of layers {hf.get('mtp_hybrid_override_pattern')!r} (only '*E')"),
+        (hf.get("n_routed_experts", 0) and not hf.get("moe_latent_size"),
+         "experts at the full width (no moe_latent_size)"),
+    ) if has]
+    if refused:
+        raise ValueError("nemotron_h as this config.json states it is not supported: " + "; ".join(refused))
+    d_inner = hf["mamba_num_heads"] * hf["mamba_head_dim"]
+    if d_inner != hf.get("expand", 2) * hf["hidden_size"]:
+        raise ValueError(f"mamba_num_heads x mamba_head_dim ({d_inner}) is not expand x hidden_size")
+    experts = hf.get("n_routed_experts", 0)
+    fields = dict(
+        n_layers=len(pattern), layer_pattern=pattern,
+        norm_eps=float(hf.get("norm_eps", hf.get("layer_norm_epsilon", 1e-5))),
+        attn_head_dim=hf.get("head_dim", 0), attention_rotation=False, mlp_activation="relu2",
+        ssm_n_heads=hf["mamba_num_heads"], ssm_head_dim=hf["mamba_head_dim"],
+        ssm_n_groups=hf["n_groups"], ssm_state=hf["ssm_state_size"],
+        ssm_conv_taps=hf["conv_kernel"], ssm_chunk=hf["chunk_size"],
+        ssm_dt_min=hf.get("time_step_min", 0.001), ssm_dt_max=hf.get("time_step_max", 0.1),
+        ssm_dt_floor=hf.get("time_step_floor", 1e-4),
+        mtp_depth=hf.get("num_nextn_predict_layers", 0),
+    )
+    if fields["mtp_depth"]:
+        fields["mtp_layer_pattern"] = hf.get("mtp_hybrid_override_pattern", "*E")
+    if experts:
+        fields.update(
+            n_experts=experts, moe_top_k=hf["num_experts_per_tok"],
+            d_ff_expert=hf["moe_intermediate_size"], n_shared_experts=hf.get("n_shared_experts", 0),
+            d_ff_shared=hf.get("moe_shared_expert_intermediate_size", 0),
+            moe_latent_dim=hf["moe_latent_size"], moe_capacity_factor=0.0, moe_aux_loss_coef=0.0,
+            moe_scoring="sigmoid", moe_select_bias=True,
+            moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)))
+    return fields
 
 
 def _glm4_moe_lite_fields(hf: Dict[str, Any]) -> Dict[str, Any]:

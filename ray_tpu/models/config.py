@@ -78,10 +78,65 @@ class ModelConfig:
     # that predicts one token further; their loss is added with this weight.
     mtp_depth: int = 0
     mtp_loss_weight: float = 0.3
+    # --- a stack of single-part layers (nemotron_h's hybrid_override_pattern) ---
+    # One character a layer, each layer a mixer OR a feed-forward part alone behind its
+    # own norm and residual: M a Mamba-2 mixer (models/ssm.py), * attention, E an expert
+    # layer, - the dense MLP. "" is the block every other family has: attention followed
+    # by a feed-forward part, n_layers times (a prefix of n_dense_layers dense).
+    # mtp_layer_pattern is an MTP module's own layers (only "*E": the block, in two).
+    layer_pattern: str = ""
+    mtp_layer_pattern: str = ""
+    # Mamba-2: ssm_n_heads heads ssm_head_dim wide (d_inner their product), B and C a
+    # group of ssm_n_groups, ssm_state wide; a causal depthwise convolution of
+    # ssm_conv_taps taps; the scan runs in chunks of ssm_chunk positions. The counts are
+    # what is HELD: a tensor-parallel share of a layer is fewer heads and groups.
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_n_groups: int = 1
+    ssm_state: int = 128
+    ssm_conv_taps: int = 4
+    ssm_chunk: int = 128
+    # the range the seeded dt_bias is drawn from (time_step_min / _max / _floor)
+    ssm_dt_min: float = 0.001
+    ssm_dt_max: float = 0.1
+    ssm_dt_floor: float = 1e-4
+    # A head's width where it is not d_model / n_heads (0 = derived): a published
+    # `head_dim`, which a share of the heads needs too (4 heads of 128 beside d_model 4096)
+    attn_head_dim: int = 0
+    # (query heads, key/value heads) an attention layer HOLDS of n_heads / n_kv_heads,
+    # 0 = all: a tensor-parallel share of its heads, as experts_held is of the experts
+    attn_heads_held: Tuple[int, int] = (0, 0)
+    attention_rotation: bool = True  # False: q and k are not rotated (position comes from elsewhere)
+    # Experts in a latent: the routed experts work at this width, between a projection
+    # down before the dispatch and one up after the combine (0 = at d_model); router and
+    # shared expert see d_model. d_ff_shared: the shared expert's width (0 =
+    # n_shared_experts x d_ff_expert).
+    moe_latent_dim: int = 0
+    d_ff_shared: int = 0
+    mlp_activation: str = "silu_gated"  # silu_gated | relu2 (non-gated: relu(x W_up)^2 W_down)
+    # what moe.route cannot do and refuses by name: a group limit on the choice
+    # (n_group > 1) and gates that are not normalised over the chosen (norm_topk_prob false)
+    moe_n_group: int = 1
+    moe_norm_topk: bool = True
 
     def __post_init__(self):
         # JSON hands a list; the dataclass is a static (hashed) argument of jitted programs
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        object.__setattr__(self, "attn_heads_held", tuple(self.attn_heads_held))
+        if self.layer_pattern:
+            unknown = set(self.layer_pattern) - set("ME*-")
+            if unknown or len(self.layer_pattern) != self.n_layers:
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern!r}: one of M E * - a layer, "
+                    f"n_layers ({self.n_layers}) of them")
+            if self.n_dense_layers:
+                raise ValueError("layer_pattern says which layers are dense ('-'); n_dense_layers is the block's")
+        if self.mtp_layer_pattern not in ("", "*E"):
+            raise NotImplementedError(
+                f"mtp_layer_pattern {self.mtp_layer_pattern!r}: an MTP module is attention then "
+                "an expert layer ('*E')")
+        if self.mlp_activation not in ("silu_gated", "relu2"):
+            raise ValueError(f"unknown mlp_activation {self.mlp_activation!r} (silu_gated | relu2)")
 
     @property
     def latent_attention(self) -> bool:
@@ -92,7 +147,24 @@ class ModelConfig:
         """Width of a head's q and k (and v, which every family here has as wide)."""
         if self.latent_attention:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.d_model // self.n_heads
+        return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def heads_held(self) -> int:
+        return self.attn_heads_held[0] or self.n_heads
+
+    @property
+    def kv_heads_held(self) -> int:
+        return self.attn_heads_held[1] or self.n_kv_heads
+
+    @property
+    def ssm_d_inner(self) -> int:
+        return self.ssm_n_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the convolution runs over: x beside every group's B and C."""
+        return self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_state
 
     @property
     def moe_dropless(self) -> bool:
@@ -101,6 +173,11 @@ class ModelConfig:
     @property
     def n_experts_held(self) -> int:
         return self.n_experts // self.experts_held[1]
+
+    @property
+    def shared_width(self) -> int:
+        """Width of the shared experts, as the one MLP they are run as."""
+        return self.d_ff_shared or self.n_shared_experts * (self.d_ff_expert or self.d_ff)
 
     @property
     def activation_dtype(self):
@@ -112,15 +189,26 @@ class ModelConfig:
         d = self.d_model
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         if self.latent_attention:
-            h, qk = self.n_heads, self.head_dim
+            h, qk = self.heads_held, self.head_dim
             attn = (d * self.q_lora_rank + self.q_lora_rank * h * qk
                     + d * (self.kv_lora_rank + self.qk_rope_head_dim)
                     + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
                     + h * self.v_head_dim * d + self.q_lora_rank + self.kv_lora_rank)
         else:
-            attn = d * self.head_dim * (2 * self.n_heads + 2 * self.n_kv_heads)
-        mlp = 3 * d * self.d_ff
+            attn = d * self.head_dim * (2 * self.heads_held + 2 * self.kv_heads_held)
+        mats = 3 if self.mlp_activation == "silu_gated" else 2  # a gated MLP has one more
+        mlp = mats * d * self.d_ff
         norms = 2 * d
+        if self.layer_pattern:
+            latent = self.moe_latent_dim or d
+            ssm = (d * (self.ssm_d_inner + self.ssm_conv_dim + self.ssm_n_heads)  # z | xBC | dt
+                   + (self.ssm_conv_taps + 1) * self.ssm_conv_dim + 3 * self.ssm_n_heads
+                   + self.ssm_d_inner + self.ssm_d_inner * d)
+            experts = (d * self.n_experts + self.n_experts_held * mats * latent * (self.d_ff_expert or self.d_ff)
+                       + mats * d * self.shared_width + (2 * d * latent if self.moe_latent_dim else 0))
+            kind = {"M": ssm + d, "*": attn + d, "E": experts + d, "-": mlp + d}
+            return (emb + d + sum(kind[c] for c in self.layer_pattern)
+                    + self.mtp_depth * (attn + experts + norms + 2 * d * d + 3 * d))
         if not self.moe_dropless:
             return emb + self.n_layers * (attn + mlp + norms) + d
         expert = 3 * d * (self.d_ff_expert or self.d_ff)
@@ -284,6 +372,45 @@ register_config(
         n_dense_layers=1,
         moe_scoring="sigmoid",
         moe_route_scale=1.8,
+        moe_select_bias=True,
+        mtp_depth=1,
+    )
+)
+register_config(
+    # Toy of the nemotron_h family (Nemotron-3-Super) for the CPU tests: a pattern of
+    # single-part layers (Mamba-2, latent relu2 experts beside a shared one, attention
+    # without rotation at a head width of its own), one MTP module. Everything held;
+    # tests cut shares of heads, groups and experts.
+    ModelConfig(
+        name="nemotron-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=6,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=96,
+        max_seq_len=128,
+        dtype="float32",
+        layer_pattern="MEM*E-",
+        mtp_layer_pattern="*E",
+        ssm_n_heads=8,
+        ssm_head_dim=8,
+        ssm_n_groups=2,
+        ssm_state=16,
+        ssm_chunk=8,
+        attn_head_dim=24,
+        attention_rotation=False,
+        n_experts=16,
+        moe_top_k=3,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=40,
+        n_shared_experts=1,
+        d_ff_shared=80,
+        moe_latent_dim=32,
+        mlp_activation="relu2",
+        moe_scoring="sigmoid",
+        moe_route_scale=5.0,
         moe_select_bias=True,
         mtp_depth=1,
     )

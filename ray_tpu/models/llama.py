@@ -30,15 +30,44 @@ Params = Dict[str, Any]
 
 # ---------------------------------------------------------------------------- init
 
-def _layer_kinds(cfg: ModelConfig) -> Dict[str, Tuple[int, bool]]:
-    """The stacks of params, in the order forward walks them: name -> (layers, whether
-    they are expert layers). `layers` is every layer of a one-kind model; cfg.n_dense_layers
-    leading layers with the dense MLP lie in a stack of their own in front of it."""
+# A pattern's characters (config.layer_pattern): the stack its layers lie in, their
+# mixer and their feed-forward part. A layer without a pattern has both parts.
+_PATTERN = {"M": ("ssm_layers", "ssm", None), "*": ("attn_layers", "attn", None),
+            "E": ("layers", None, "experts"), "-": ("mlp_layers", None, "dense")}
+
+
+def _layer_kinds(cfg: ModelConfig) -> Dict[str, Tuple[int, Optional[str], Optional[str]]]:
+    """The stacks of params: name -> (layers, mixer: attn | ssm | None, feed-forward
+    part: dense | experts | None). Without a pattern every layer is attention followed by
+    a feed-forward part and forward walks the stacks in this order: `layers` is every
+    layer of a one-kind model; cfg.n_dense_layers leading layers with the dense MLP lie
+    in a stack of their own in front of it. With cfg.layer_pattern a stack holds the
+    layers of one character, in the pattern's order (`layers` the expert layers: the
+    step's counters and the balance rule take a row of them each), and `_pattern_layers`
+    walks the pattern."""
+    if cfg.layer_pattern:
+        kinds = {}
+        for c in cfg.layer_pattern:
+            name, mixer, ff = _PATTERN[c]
+            kinds[name] = (kinds.get(name, (0,))[0] + 1, mixer, ff)
+        return kinds
     kinds = {}
     if cfg.n_dense_layers:
-        kinds["dense_layers"] = (cfg.n_dense_layers, False)
-    kinds["layers"] = (cfg.n_layers - cfg.n_dense_layers, cfg.n_experts > 0)
+        kinds["dense_layers"] = (cfg.n_dense_layers, "attn", "dense")
+    kinds["layers"] = (cfg.n_layers - cfg.n_dense_layers, "attn",
+                       "experts" if cfg.n_experts > 0 else "dense")
     return kinds
+
+
+def pattern_period(pattern: str) -> Tuple[str, int]:
+    """(unit, n): the shortest unit whose n repetitions are the pattern. What repeats is
+    scanned: "MEME*EMEME*E" is 2 periods of "MEME*E". Nemotron-3-Super's published 88
+    layers are periods of 9 and of 11 layers (MEMEMEM*E x 3, MEMEMEMEM*E x 4, MEMEMEM*E,
+    MEMEMEME), so no shorter unit tiles them and they are one period of 88; the
+    benchmark's cut is the first 11, one period as well."""
+    size = next(size for size in range(1, len(pattern) + 1)
+                if len(pattern) % size == 0 and pattern[:size] * (len(pattern) // size) == pattern)
+    return pattern[:size], len(pattern) // size
 
 
 def _attn_axes(cfg: ModelConfig) -> Params:
@@ -58,19 +87,21 @@ def _attn_axes(cfg: ModelConfig) -> Params:
     }
 
 
-def _layer_axes(cfg: ModelConfig, experts: bool) -> Params:
+def _layer_axes(cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Params:
     """One layer's logical axes (no leading 'layer' axis)."""
-    axes = {"attn_norm": ("embed",), **_attn_axes(cfg), "mlp_norm": ("embed",)}
-    if experts:
-        from . import moe as _moe
+    from . import moe as _moe
+    from . import ssm as _ssm
 
-        axes.update(_moe.expert_axes(cfg))
-    else:
-        axes.update({
-            "w_gate": ("embed", "mlp"),
-            "w_up": ("embed", "mlp"),
-            "w_down": ("mlp", "embed"),
-        })
+    axes = {}
+    if mixer == "attn":
+        axes.update({"attn_norm": ("embed",), **_attn_axes(cfg)})
+    elif mixer == "ssm":
+        axes.update(_ssm.AXES)
+    if ff == "experts":
+        axes.update({"mlp_norm": ("embed",), **_moe.expert_axes(cfg)})
+    elif ff == "dense":
+        dense = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
+        axes.update({"mlp_norm": ("embed",), **{n: dense[n] for n in _moe.mlp_leaves(cfg)}})
     return axes
 
 
@@ -82,8 +113,8 @@ def param_axes(cfg: ModelConfig) -> Params:
 
     axes = {
         "embed": ("vocab", "embed"),
-        **{name: stacked(_layer_axes(cfg, experts))
-           for name, (_, experts) in _layer_kinds(cfg).items()},
+        **{name: stacked(_layer_axes(cfg, mixer, ff))
+           for name, (_, mixer, ff) in _layer_kinds(cfg).items()},
         "final_norm": ("embed",),
     }
     if not cfg.tie_embeddings:
@@ -91,14 +122,14 @@ def param_axes(cfg: ModelConfig) -> Params:
     if cfg.mtp_depth:
         axes["mtp"] = stacked({
             "embed_norm": ("embed",), "hidden_norm": ("embed",), "eh_proj": ("mlp", "embed"),
-            "final_norm": ("embed",), **_layer_axes(cfg, True)})
+            "final_norm": ("embed",), **_layer_axes(cfg, "attn", "experts")})
     return axes
 
 
 def init(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Initialize parameters (f32). Scaled-normal init, wo/w_down scaled by depth."""
     k_emb, k_head, k_layers = jax.random.split(rng, 3)
-    d, hd, nh, nkv, ff = cfg.d_model, cfg.head_dim, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff
+    d, hd, nh, nkv, ff_width = cfg.d_model, cfg.head_dim, cfg.heads_held, cfg.kv_heads_held, cfg.d_ff
 
     def norm(key, shape, scale):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(jnp.float32)
@@ -125,33 +156,37 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             "wo": norm(ks[3], (nh, cfg.v_head_dim, d), s_out),
         }
 
-    def layer_init(key, experts: bool):
-        ks = jax.random.split(key, 7)
-        out = {
-            "attn_norm": jnp.ones((d,), jnp.float32),
-            **attn_init(ks),
-            "mlp_norm": jnp.ones((d,), jnp.float32),
-        }
-        if experts:
-            from . import moe as _moe
+    def layer_init(key, mixer: Optional[str], ff: Optional[str]):
+        from . import moe as _moe
+        from . import ssm as _ssm
 
+        ks = jax.random.split(key, 7)
+        out = {}
+        if mixer == "attn":
+            out.update({"attn_norm": jnp.ones((d,), jnp.float32), **attn_init(ks)})
+        elif mixer == "ssm":
+            out.update(_ssm.init(ks[0], cfg))
+        if ff is not None:
+            out["mlp_norm"] = jnp.ones((d,), jnp.float32)
+        if ff == "experts":
             out.update(_moe.init_expert_weights(ks[4], cfg))
-        else:
-            out.update({
-                "w_gate": norm(ks[4], (d, ff), s_in),
-                "w_up": norm(ks[5], (d, ff), s_in),
-                "w_down": norm(ks[6], (ff, d), (2 * cfg.n_layers * ff) ** -0.5),
-            })
+        elif ff == "dense":
+            dense = {
+                "w_gate": norm(ks[4], (d, ff_width), s_in),
+                "w_up": norm(ks[5], (d, ff_width), s_in),
+                "w_down": norm(ks[6], (ff_width, d), (2 * cfg.n_layers * ff_width) ** -0.5),
+            }
+            out.update({n: dense[n] for n in _moe.mlp_leaves(cfg)})
         return out
 
     params: Params = {"embed": norm(k_emb, (cfg.vocab_size, d), 1.0)}
     kinds = _layer_kinds(cfg)
     if len(kinds) == 1:  # the one-kind model draws its layers' keys as it always did
-        kind_keys = {"layers": k_layers}
+        kind_keys = {next(iter(kinds)): k_layers}
     else:
         kind_keys = dict(zip(kinds, jax.random.split(k_layers, len(kinds))))
-    for name, (n, experts) in kinds.items():
-        params[name] = jax.vmap(functools.partial(layer_init, experts=experts))(
+    for name, (n, mixer, ff) in kinds.items():
+        params[name] = jax.vmap(functools.partial(layer_init, mixer=mixer, ff=ff))(
             jax.random.split(kind_keys[name], n))
     params["final_norm"] = jnp.ones((d,), jnp.float32)
     if not cfg.tie_embeddings:
@@ -164,7 +199,7 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
                 "hidden_norm": jnp.ones((d,), jnp.float32),
                 "eh_proj": norm(k_proj, (2 * d, d), (2 * d) ** -0.5),
                 "final_norm": jnp.ones((d,), jnp.float32),
-                **layer_init(k_block, True),
+                **layer_init(k_block, "attn", "experts"),
             }
 
         params["mtp"] = jax.vmap(mtp_init)(
@@ -340,9 +375,13 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     b, s, d = h.shape
     if "router" not in lp:
-        gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
-        up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
-        ff = constrain(jax.nn.silu(gate) * up, "batch", "seq", "act_mlp")
+        if "w_gate" in lp:
+            gate = jnp.einsum("bsd,df->bsf", h, _w(lp["w_gate"], dt))
+            up = jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))
+            act = jax.nn.silu(gate) * up
+        else:  # cfg.mlp_activation relu2: no gate
+            act = jnp.square(jax.nn.relu(jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))))
+        ff = constrain(act, "batch", "seq", "act_mlp")
         down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
         return x + down, jnp.zeros((), jnp.float32)
     from . import moe as _moe
@@ -398,15 +437,39 @@ def _block(
     cache_len: Optional[jax.Array] = None,
     token_mask: Optional[jax.Array] = None,
 ):
-    """One decoder block. Returns (x, updated (k,v) if caching, moe aux loss)."""
+    """One layer: the mixer its parameters hold (attention, a Mamba-2 mixer, or none) and
+    then the feed-forward part they hold (or none), each behind its own norm and
+    residual. Every family but nemotron_h holds attention and a feed-forward part in each
+    layer: the decoder block. Returns (x, updated (k,v) if caching, moe aux loss)."""
+    new_kv, aux = None, jnp.zeros((), jnp.float32)
+    if "in_proj" in lp:
+        if segment_ids is not None or cache_kv is not None:
+            raise NotImplementedError(
+                "a Mamba-2 layer over packed documents (segment_ids: state and convolution do "
+                "not start again at a boundary yet) or under a KV cache (no recurrent state is kept)")
+        from . import ssm as _ssm
+
+        x = wsc(_ssm.mixer(x, lp, cfg), "batch", "seq", "act_embed")
+    elif "attn_norm" in lp:
+        x, new_kv = _attention_part(x, lp, cfg, positions, segment_ids, cache_kv, cache_len)
+    if "mlp_norm" in lp:
+        with jax.named_scope("mlp"):
+            x, aux = feed_forward(x, lp, cfg, token_mask, constrain=wsc)
+            x = wsc(x, "batch", "seq", "act_embed")
+    return x, new_kv, aux
+
+
+def _attention_part(x, lp, cfg, positions, segment_ids, cache_kv, cache_len):
+    """The block's attention: (x + attention's output, updated (k, v) if caching)."""
     # named scopes: metadata only (free at run time); what a reader of the
     # profile uses to tell one fusion from another
     with jax.named_scope("attn"):
         # ops.attention rotates q and k itself (in its kernel's own pass over them, where
         # the Pallas path runs); a cache or the ring takes them rotated
-        deferred = (cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
+        rotate = cfg.attention_rotation
+        deferred = (rotate and cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
                     and not cfg.latent_attention)
-        q, k, v = qkv_proj(x, lp, cfg, None if deferred else positions)
+        q, k, v = qkv_proj(x, lp, cfg, positions if rotate and not deferred else None)
         q = wsc(q, "batch", "seq", "act_heads", "head_dim")
 
         new_kv = None
@@ -444,12 +507,7 @@ def _block(
             attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
                              shard_spec=auto_spec("batch", None, "act_heads", None),
                              rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
-        x = wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed")
-
-    with jax.named_scope("mlp"):
-        x, aux = feed_forward(x, lp, cfg, token_mask, constrain=wsc)
-        x = wsc(x, "batch", "seq", "act_embed")
-    return x, new_kv, aux
+        return wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed"), new_kv
 
 
 def _pipeline_layers(
@@ -532,6 +590,42 @@ def _pipeline_layers(
     return out if moe else (out, jnp.zeros((), jnp.float32))
 
 
+def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids, token_mask):
+    """x through cfg.layer_pattern. Returns (x, aux as forward's return_aux has it).
+
+    The pattern's period (`pattern_period`) is traced once, each of its layers under its
+    own rematerialisation, and scanned over the periods: period r's j-th layer of a kind
+    that has c layers a period is row r * c + j of that kind's stack, so a stack
+    [n * c, ...] is scanned as [n, c, ...]. One period is run as it stands, with no loop
+    around it: each layer's gradient is then written once, into its row."""
+    unit, n = pattern_period(cfg.layer_pattern)
+    per_unit = {name: count // n for name, (count, _, _) in _layer_kinds(cfg).items()}
+    layer = _maybe_remat(
+        lambda h, lp: _block(h, lp, cfg, positions, segment_ids, token_mask=token_mask)[::2], cfg)
+
+    def period(h, stacks):
+        at, auxs = dict.fromkeys(stacks, 0), []
+        for c in unit:
+            name = _PATTERN[c][0]
+            h, aux = layer(h, jax.tree.map(lambda a: a[at[name]], stacks[name]))  # noqa: B023
+            at[name] += 1
+            if c == "E":
+                auxs.append(aux)
+        return h, jax.tree.map(lambda *a: jnp.stack(a), *auxs) if auxs else None
+
+    stacks = {name: params[name] for name in per_unit}
+    if n == 1:
+        x, auxs = period(x, stacks)
+    else:
+        x, auxs = jax.lax.scan(period, x, {
+            name: jax.tree.map(lambda a: a.reshape(n, per_unit[name], *a.shape[1:]), stack)  # noqa: B023
+            for name, stack in stacks.items()})
+        auxs = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), auxs)
+    if cfg.moe_dropless and auxs is not None:
+        return x, dict(auxs, hidden=x)
+    return x, jnp.zeros((), jnp.float32) if auxs is None else auxs.sum()
+
+
 def forward(
     params: Params,
     tokens: jax.Array,
@@ -561,7 +655,11 @@ def forward(
     if len(_layer_kinds(cfg)) > 1 and (cfg.pipeline_stages > 1 or cache is not None):
         raise NotImplementedError(
             "pipeline stages or a KV cache over layers of more than one kind")
-    if cfg.pipeline_stages > 1 and cache is None:
+    if cfg.layer_pattern:
+        if cfg.pipeline_stages > 1 or cache is not None:
+            raise NotImplementedError("pipeline stages or a KV cache over a pattern of layers")
+        x, aux_total = _pattern_layers(x, params, cfg, positions, segment_ids, token_mask)
+    elif cfg.pipeline_stages > 1 and cache is None:
         x, aux_total = _pipeline_layers(x, params, cfg, positions, segment_ids,
                                         token_mask)
     else:
@@ -663,7 +761,7 @@ def loss_fn(
     with jax.named_scope("loss"):
         ce = _cross_entropy(logits, tokens[:, 1:], mask[:, 1:])
     metrics = {"tokens": jnp.maximum(mask[:, 1:].sum(), 1.0)}
-    if cfg.moe_dropless:  # no auxiliary loss: the selection bias balances (train/step.py)
+    if isinstance(aux, dict):  # the dropless layers' counters; no auxiliary loss: the selection bias balances (train/step.py)
         loss, load, chosen = ce, aux["load"], aux["chosen"]
     else:
         loss, load = ce + aux, None

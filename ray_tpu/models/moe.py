@@ -166,6 +166,12 @@ def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: Mod
     if cfg.moe_scoring != "sigmoid":
         raise NotImplementedError(
             f"the dropless layer scores by sigmoid; {cfg.moe_scoring!r} is the capacity path's (moe_mlp)")
+    if cfg.moe_n_group > 1:
+        raise NotImplementedError(
+            f"group-limited routing (n_group {cfg.moe_n_group}): the choice is over all experts at once")
+    if not cfg.moe_norm_topk:
+        raise NotImplementedError(
+            "gates that are not normalised over the chosen experts (norm_topk_prob false)")
     logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
@@ -177,10 +183,39 @@ def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: Mod
     # two scores within a rounding, then went to other experts in the gradient than in
     # the loss: its experts' gradients were 6-8 % off on the chip (PERF.md section 6, PR 31).
     idx = checkpoint_name(idx.astype(jnp.int32), CHOSEN_NAME)
-    # the chosen scores, picked by a mask: take_along_axis would transpose to a scatter
-    gates = jnp.sum(scores[:, None, :] * (idx[..., None] == jnp.arange(scores.shape[-1])), -1)
+    gates = _chosen_scores(scores, idx)
     gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
     return idx, gates * cfg.moe_route_scale
+
+
+# A mask of [T, k, E] elements is one fused pass where it is small (4 of 64 at 8,192
+# tokens: 2 M) and not where 22 are chosen of 512 (92 M, 369 MB in float32, a layer, and
+# again in the backward pass): beyond this many elements the k slots are taken one at a
+# time, [T, E] each, and no operand of the program has all three extents.
+_MASK_ELEMENTS = 1 << 22
+
+
+def _chosen_scores(scores: jax.Array, idx: jax.Array) -> jax.Array:
+    """scores [T, E], idx [T, k] -> the chosen scores [T, k], picked by a mask:
+    take_along_axis would transpose to a scatter."""
+    lanes = jnp.arange(scores.shape[-1])
+    if idx.size * scores.shape[-1] <= _MASK_ELEMENTS:
+        return jnp.sum(scores[:, None, :] * (idx[..., None] == lanes), -1)
+    # (rematerialised: the backward pass makes a slot's mask again and keeps none)
+    _, picked = jax.lax.scan(jax.checkpoint(
+        lambda _, slot: (None, jnp.sum(jnp.where(slot[:, None] == lanes, scores, 0), -1))), None, idx.T)
+    return picked.T
+
+
+def expert_load(idx: jax.Array, n_experts: int) -> jax.Array:
+    """idx [T, k] -> assignments an expert [n_experts] f32 (the step's counter)."""
+    lanes = jnp.arange(n_experts)
+    if idx.size * n_experts <= _MASK_ELEMENTS:
+        return (idx[..., None] == lanes).sum((0, 1), dtype=jnp.float32)
+    load, _ = jax.lax.scan(
+        lambda acc, slot: (acc + (slot[:, None] == lanes).sum(0, dtype=jnp.float32), None),
+        jnp.zeros((n_experts,), jnp.float32), idx.T)
+    return load
 
 
 def held_range(cfg: ModelConfig) -> Tuple[int, int]:
@@ -246,13 +281,26 @@ _put.defvjp(lambda b, *w: (_put(b, *w), w[:3]),
             lambda rows, k, w, g: (_take(g, *w, rows, k), None, None, None))
 
 
-def _gated_mlp(x, w_gate, w_up, w_down, product=jnp.matmul, clean=lambda a: a):
-    """SiLU-gated MLP over `product`; `clean` goes around each product's input and output."""
-    act = clean(jax.nn.silu(clean(product(x, w_gate))) * clean(product(x, w_up)))
+def _mlp(x, weights, product=jnp.matmul, clean=lambda a: a):
+    """An MLP over `product`; `clean` goes around each product's input and output.
+    weights (w_gate, w_up, w_down): SiLU-gated; (w_up, w_down): relu(x W_up)^2 W_down,
+    two products where the gated one runs three."""
+    if len(weights) == 3:
+        w_gate, w_up, w_down = weights
+        act = clean(jax.nn.silu(clean(product(x, w_gate))) * clean(product(x, w_up)))
+    else:
+        w_up, w_down = weights
+        act = clean(jnp.square(jax.nn.relu(clean(product(x, w_up)))))
     return clean(product(act, w_down))
 
 
-def _window(x, w_gate, w_up, w_down, gates, order, inverse, ends, start, rows: int, k: int):
+def mlp_leaves(cfg: ModelConfig, prefix: str = "w_"):
+    """Names of an MLP's weights in this configuration, in `_mlp`'s order."""
+    parts = ("gate", "up", "down") if cfg.mlp_activation == "silu_gated" else ("up", "down")
+    return tuple(prefix + part for part in parts)
+
+
+def _window(x, weights, gates, order, inverse, ends, start, rows: int, k: int):
     """What the held experts add to y [T, D] for the assignments at [start, start + rows)
     of the sorted order. gates [T * k] f32; ends [held] int32: where each held expert's
     rows end in that order."""
@@ -271,8 +319,8 @@ def _window(x, w_gate, w_up, w_down, gates, order, inverse, ends, start, rows: i
     with jax.named_scope("moe_dispatch"):
         xin = clean(_take(x, *where, k))
     with jax.named_scope("moe_experts"):
-        out = _gated_mlp(xin, w_gate, w_up, w_down, clean=clean,
-                         product=lambda a, w: jax.lax.ragged_dot(a, w, group_sizes))
+        out = _mlp(xin, weights, clean=clean,
+                   product=lambda a, w: jax.lax.ragged_dot(a, w, group_sizes))
     with jax.named_scope("moe_combine"):
         by_row = _take(gates, *where, 1)
         # weighted in float32 and rounded once: the gates' gradient is then a float32 sum
@@ -281,8 +329,8 @@ def _window(x, w_gate, w_up, w_down, gates, order, inverse, ends, start, rows: i
         return _put((out.astype(jnp.float32) * by_row[:, None]).astype(x.dtype), *where, k)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
-def _walk(x, w_gate, w_up, w_down, gates, order, inverse, ends, rows: int, k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _walk(x, weights, gates, order, inverse, ends, rows: int, k: int):
     """The sum of `_window` over the windows that hold served rows. The first is always
     computed; the others, which only a load beyond `rows` has, in a loop of as many turns
     as that load needs. Differentiated by hand: JAX cannot transpose a loop of dynamic
@@ -290,7 +338,7 @@ def _walk(x, w_gate, w_up, w_down, gates, order, inverse, ends, rows: int, k: in
     Only the inputs are kept; the backward pass walks the same windows and takes each
     one's `jax.vjp` (under rematerialisation the layer is recomputed anyway)."""
     def window(start):
-        return _window(x, w_gate, w_up, w_down, gates, order, inverse, ends, start, rows, k)
+        return _window(x, weights, gates, order, inverse, ends, start, rows, k)
 
     with jax.named_scope("moe_walk"):  # the loop's own copies and sums: the layer's too
         return jax.lax.fori_loop(1, windows_walked(ends[-1], rows),
@@ -298,21 +346,21 @@ def _walk(x, w_gate, w_up, w_down, gates, order, inverse, ends, rows: int, k: in
 
 
 def _walk_bwd(rows, k, args, dy):
-    inputs, (order, inverse, ends) = args[:5], args[5:]
+    inputs, (order, inverse, ends) = args[:3], args[3:]
 
     def pull(start):
         return jax.vjp(lambda *a: _window(*a, order, inverse, ends, start, rows, k), *inputs)[1](dy)
 
     def more(w, acc):  # an overflowing step's sums, in float32
-        return tuple((s.astype(jnp.float32) + g.astype(jnp.float32)).astype(s.dtype)
-                     for s, g in zip(acc, pull(w * rows)))
+        return jax.tree.map(lambda s, g: (s.astype(jnp.float32) + g.astype(jnp.float32)).astype(s.dtype),
+                            acc, pull(w * rows))
 
     with jax.named_scope("moe_walk"):
         grads = jax.lax.fori_loop(1, windows_walked(ends[-1], rows), more, pull(0))
     return (*grads, None, None, None)
 
 
-_walk.defvjp(lambda *a: (_walk(*a), a[:8]), _walk_bwd)
+_walk.defvjp(lambda *a: (_walk(*a), a[:6]), _walk_bwd)
 
 
 def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
@@ -321,13 +369,20 @@ def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
     "chosen": the experts each token chose [T, k]}).
     lp: router [D, E], router_bias [E] (where cfg.moe_select_bias), w_gate / w_up
     [held, D, F], w_down [held, F, D], shared_gate / shared_up [D, S * F], shared_down
-    [S * F, D] (where cfg.n_shared_experts)."""
+    [S * F, D] (where cfg.n_shared_experts); no w_gate / shared_gate where the MLPs are
+    relu2 (cfg.mlp_activation). With cfg.moe_latent_dim the routed experts live in a
+    latent L wide (their D above is L): latent_down [D, L] before the dispatch,
+    latent_up [L, D] after the combine; router and shared experts see x itself."""
     dt = x.dtype
     k, (lo, hi) = cfg.moe_top_k, held_range(cfg)
     rows = window_rows(cfg, x.shape[0])
     with jax.named_scope("moe_router"):
         idx, gates = route(x, lp["router"], lp.get("router_bias"), cfg)
-        load = (idx[..., None] == jnp.arange(cfg.n_experts)).sum((0, 1), dtype=jnp.float32)
+        load = expert_load(idx, cfg.n_experts)
+    full = x
+    if cfg.moe_latent_dim:
+        with jax.named_scope("moe_latent"):
+            x = jnp.matmul(x, _qw(lp["latent_down"], dt))
     with jax.named_scope("moe_dispatch"):
         # held assignments first, by expert; every other after them, under one key
         key = jnp.where((idx >= lo) & (idx < hi), idx - lo, hi - lo).reshape(-1)
@@ -338,13 +393,15 @@ def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
     # one window, statically (every expert held): plain differentiation of it is the
     # program this layer always was, with no loop and nothing recomputed
     with jax.named_scope("moe_experts"):
-        weights = [_qw(lp[n], dt) for n in ("w_gate", "w_up", "w_down")]
-    args = (x, *weights, gates.reshape(-1), order, inverse, ends)
+        weights = tuple(_qw(lp[n], dt) for n in mlp_leaves(cfg))
+    args = (x, weights, gates.reshape(-1), order, inverse, ends)
     y = _window(*args, 0, rows, k) if rows == inverse.shape[0] else _walk(*args, rows, k)
+    if cfg.moe_latent_dim:
+        with jax.named_scope("moe_latent"):
+            y = jnp.matmul(y, _qw(lp["latent_up"], dt))
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            y = y + _gated_mlp(x, *(_qw(lp[n], dt) for n in
-                                    ("shared_gate", "shared_up", "shared_down")))
+            y = y + _mlp(full, tuple(_qw(lp[n], dt) for n in mlp_leaves(cfg, "shared_")))
     return y, {"load": load, "chosen": idx}
 
 
@@ -356,31 +413,40 @@ def init_expert_weights(key: jax.Array, cfg: ModelConfig):
     d, e = cfg.d_model, cfg.n_experts
     f = (cfg.d_ff_expert or cfg.d_ff) if cfg.moe_dropless else cfg.d_ff
     held = cfg.n_experts_held if cfg.moe_dropless else e
+    lat = cfg.moe_latent_dim or d  # the width the routed experts work at
     ks = jax.random.split(key, 7)
     s_in = d**-0.5
     s_out = (2 * cfg.n_layers * f) ** -0.5
     out = {
         "router": jax.random.normal(ks[0], (d, e), jnp.float32) * s_in,
-        "w_gate": jax.random.normal(ks[1], (held, d, f), jnp.float32) * s_in,
-        "w_up": jax.random.normal(ks[2], (held, d, f), jnp.float32) * s_in,
-        "w_down": jax.random.normal(ks[3], (held, f, d), jnp.float32) * s_out,
+        "w_gate": jax.random.normal(ks[1], (held, lat, f), jnp.float32) * lat**-0.5,
+        "w_up": jax.random.normal(ks[2], (held, lat, f), jnp.float32) * lat**-0.5,
+        "w_down": jax.random.normal(ks[3], (held, f, lat), jnp.float32) * s_out,
     }
     if cfg.moe_dropless and cfg.moe_select_bias:
         out["router_bias"] = jnp.zeros((e,), jnp.float32)
     if cfg.moe_dropless and cfg.n_shared_experts:
-        fs = cfg.n_shared_experts * f
+        fs = cfg.shared_width
         out.update(shared_gate=jax.random.normal(ks[4], (d, fs), jnp.float32) * s_in,
                    shared_up=jax.random.normal(ks[5], (d, fs), jnp.float32) * s_in,
                    shared_down=jax.random.normal(ks[6], (fs, d), jnp.float32) * s_out)
+    if cfg.moe_latent_dim:
+        k_down, k_up = jax.random.split(jax.random.fold_in(key, 7))
+        out.update(latent_down=jax.random.normal(k_down, (d, lat), jnp.float32) * s_in,
+                   latent_up=jax.random.normal(k_up, (lat, d), jnp.float32) * lat**-0.5)
+    if cfg.mlp_activation != "silu_gated":  # a non-gated MLP has no gate
+        out.pop("w_gate")
+        out.pop("shared_gate", None)
     return out
 
 
 def expert_axes(cfg: ModelConfig):
     """Logical axes of the leaves init_expert_weights gives this configuration."""
-    names = ["router", "w_gate", "w_up", "w_down"]
+    names = ["router", *mlp_leaves(cfg)]
     if cfg.moe_dropless:
         names += ["router_bias"] * cfg.moe_select_bias
-        names += ["shared_gate", "shared_up", "shared_down"] * bool(cfg.n_shared_experts)
+        names += mlp_leaves(cfg, "shared_") * bool(cfg.n_shared_experts)
+        names += ["latent_down", "latent_up"] * bool(cfg.moe_latent_dim)
     return {n: EXPERT_AXES[n] for n in names}
 
 
@@ -393,6 +459,8 @@ EXPERT_AXES = {
     "shared_gate": ("embed", "mlp"),
     "shared_up": ("embed", "mlp"),
     "shared_down": ("mlp", "embed"),
+    "latent_down": ("embed", None),
+    "latent_up": (None, "embed"),
 }
 
 

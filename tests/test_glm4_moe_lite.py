@@ -132,7 +132,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     reference gives for the whole layer."""
     whole = dataclasses.replace(CFG, experts_held=(0, 1))
     lp, x = _layer(whole)
-    shared = moe._gated_mlp(x, lp["shared_gate"], lp["shared_up"], lp["shared_down"])
+    shared = moe._mlp(x, (lp["shared_gate"], lp["shared_up"], lp["shared_down"]))
     total, loads = shared, []
     for i in range(8):
         share = dataclasses.replace(whole, experts_held=(i, 8))

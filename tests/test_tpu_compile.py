@@ -168,6 +168,65 @@ def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
     assert not re.search(r"\[32768,(1536|2048)\]", text)  # nor anywhere else in the layer
 
 
+def _nemotron_share():
+    from ray_tpu.models.config import ModelConfig
+
+    return ModelConfig(
+        name="nemotron-shape", vocab_size=16384, d_model=4096, n_layers=11, n_heads=32, n_kv_heads=2,
+        d_ff=2688, layer_pattern="MEMEMEM*EME", ssm_n_heads=16, ssm_head_dim=64, ssm_n_groups=1,
+        ssm_state=128, ssm_chunk=128, attn_heads_held=(4, 1), attention_rotation=False, n_experts=512,
+        moe_top_k=22, moe_capacity_factor=0.0, d_ff_expert=2688, n_shared_experts=1, d_ff_shared=5376,
+        moe_latent_dim=1024, mlp_activation="relu2", moe_scoring="sigmoid", moe_route_scale=5.0,
+        moe_select_bias=True, experts_held=(0, 64))
+
+
+def test_latent_expert_layer_at_22_of_512_compiles_without_a_tokens_by_k_by_experts_operand(one_chip, on_tpu):
+    """The expert layer of the Nemotron-3-Super cell (8,192 tokens x 22 assignments over a
+    router of 512, 8 experts of 1024 x 2688 held in a latent, a window of 5,632 rows): two
+    grouped products an expert MLP (`ragged-dot-none`: 2 forward and 6 in the backward of a
+    window, the window's body in the program twice), no scatter, and no operand with the
+    extents of tokens, k and experts together: a mask `[8192, 22, 512]` is 92 M elements a
+    layer, forward and again in the backward pass."""
+    from ray_tpu.models import moe
+
+    cfg = _nemotron_share()
+    assert moe.window_rows(cfg, 8192) == 5632
+    lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
+                 one_chip)
+    assert set(lp) == {"router", "router_bias", "w_up", "w_down", "shared_up", "shared_down",
+                       "latent_down", "latent_up"}
+    x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(moe.expert_layer(x, lp, cfg)[0].astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
+    kernels = [ln for ln in text.splitlines() if re.match(r"\s*%ragged-dot-none[\w.]* = ", ln)]
+    assert len(kernels) == 14, len(kernels)
+    assert not re.search(r" scatter\(", text)
+    assert not re.search(r"\[(8192,22,512|22,8192,512|8192,512,22|180224,512)\]", text)
+    assert not re.search(r"\[180224,(1024|2688|4096)\]", text)  # nor tokens x k rows of any width
+
+
+def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
+    """A Mamba-2 layer's share of the Nemotron-3-Super cell (16 heads of 64, 1 group, state
+    128, 8,192 positions in 64 chunks of 128), value and every gradient: plain XLA, no
+    kernel, the chunked scan's float32 intermediates beside the projections' under 2 GB."""
+    from ray_tpu.models import ssm
+
+    cfg = _nemotron_share()
+    lp = _shapes(jax.eval_shape(lambda: ssm.init(jax.random.PRNGKey(0), cfg)), one_chip)
+    assert lp["in_proj"].shape == (4096, 2 * 1024 + 2 * 128 + 16) and lp["out_proj"].shape == (1024, 4096)
+    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(ssm.mixer(x, lp, cfg).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
 @pytest.mark.parametrize("b,s,h,kv,per_row", [
     (6, 2048, 32, 8, False),   # mistral7b-train-1chip: one row of positions for the batch
     (4, 2048, 32, 8, False),   # a chip's shard of mistral7b-train-fsdp4
