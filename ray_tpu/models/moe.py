@@ -243,12 +243,73 @@ def windows_walked(held_rows: jax.Array, rows: int) -> jax.Array:
 
 # A window is rows [start, start + rows) of the sorted order. `_take` brings a token's (or
 # an assignment's, k = 1) values to the window's rows, `_put` sums a window's rows back
-# onto their tokens; each is the other's transpose, and both directions are gathers (a
-# scatter-add serialises on the TPU): assignment a belongs to token a // k, lies at
-# inverse[a] in the sorted order, and a token's k assignments are neighbours. Each names
-# its own scope, as the kind of pass it is: JAX drops the caller's `named_scope`s from
-# what a `custom_vjp` inside a `custom_vjp` (`_walk`) traces, and the trace's share of
-# the expert layer is read by these names.
+# onto their tokens; each is the other's transpose, and neither direction scatters (a
+# scatter-add serialises on the TPU): assignment a belongs to token a // k and lies at
+# inverse[a] in the sorted order. `_take` is a gather of the window's rows. `_put` has
+# two forms, and `combine_from_rows` says from the static shapes which one a layer runs:
+# from the tokens' side a gather a slot, k gathers of `tokens` rows each, all but
+# rows / (tokens x k) of them clipped and zeroed; from the window's side one grouped
+# product over the window's rows, whose work follows the rows and not tokens x k. Each
+# names its own scope, as the kind of pass it is: JAX drops the caller's `named_scope`s
+# from what a `custom_vjp` inside a `custom_vjp` (`_walk`) traces, and the trace's share
+# of the expert layer is read by these names (the grouped product is a kernel of the TPU
+# compiler's, `ragged-dot-none`, and a kernel carries no scope at all).
+
+
+def combine_from_rows(tokens: int, k: int, rows: int) -> bool:
+    """Whether `_put` sums a window of `rows` rows onto `tokens` tokens from the window's
+    side (`_sum_from_rows`) or from the tokens' (a gather a slot): from the window's side
+    where the window holds a quarter of the tokens x k assignments or less, which is every
+    layer that holds an eighth of its experts or less (`window_rows`). The gathers cost
+    by the assignment, the grouped product by the window's row. Measured alone on a v5e
+    (PERF.md section 6, PR 34), ms a call, slots -> rows (the gates' scalars, k = 1):
+    22 x 8,192 assignments 1,024 wide over a window of a 32nd of them 1.393 -> 0.165
+    (1.288 -> 0.088), a 16th 0.228 (0.166), an 8th 0.416 (0.324), a quarter 0.799
+    (0.651); 4 x 8,192 assignments 2,048 wide over a quarter 0.381 -> 0.348
+    (0.235 -> 0.119), an 8th 0.329 -> 0.292, a 16th 0.328 -> 0.244. Over half of them
+    the two cross: 4.836 -> 2.830 (1.288 -> 1.520) at 22 a token, 0.432 -> 0.643
+    (0.235 -> 0.229) at 4. A layer that holds all, half or a quarter of its experts keeps
+    the gathers."""
+    return tokens * k >= 4 * rows
+
+
+_LANES = 128
+_NO_ASSIGNMENT = jnp.iinfo(jnp.int32).max  # beyond every tile of every result
+# lhs [rows, tile] and rhs [rows, width] contracted over the rows, which the groups
+# split: [groups, tile, width], what a grouped product's weight gradient is too
+_ROWS_CONTRACTED = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())), lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _sum_from_rows(b, at, n: int, k: int):
+    """b [rows, width] -> [n // k, width]: the rows of each token summed, row j being one
+    of assignment at[j] (at [rows] int32, each assignment once; `_NO_ASSIGNMENT`: of none).
+    b [rows] -> [n]: every assignment's value, 0 for those that have no row (k = 1).
+
+    The rows are brought into the assignments' order (one sort of `rows` keys, one gather
+    of `rows` rows), which is the tokens' order too, and every tile of 512 tokens is the
+    product of a 0/1 matrix [512, the tile's rows] with those rows: one grouped product
+    whose ragged extent is the contraction. 0, 1 and a bfloat16 are exact on the MXU and
+    the sum is float32, rounded once; float32 values go at the highest precision and
+    keep every bit (a token's sum then differs from a sum in slot order by the order of
+    its additions alone). A scalar takes one of 128 lanes, a "token" of 128 assignments."""
+    rows, scalars = b.shape[0], b.ndim == 1
+    w = _LANES if scalars else k
+    span = _ROW_TILE * w  # assignments under a tile of the result
+    tiles = -(-n // span)
+    # rows of no assignment sort behind every tile's and belong to no group
+    at, perm = jax.lax.sort((at, jnp.arange(rows, dtype=jnp.int32)), num_keys=1)
+    b = b[perm]
+    if scalars:
+        b = jnp.where((at % w)[:, None] == jnp.arange(w), b[:, None], 0)
+    sizes = (at[:, None] // span == jnp.arange(tiles)).sum(0, dtype=jnp.int32)
+    onehot = ((at // w % _ROW_TILE)[:, None] == jnp.arange(_ROW_TILE)).astype(b.dtype)
+    out = jax.lax.ragged_dot_general(
+        onehot, b, sizes, _ROWS_CONTRACTED, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST if b.dtype == jnp.float32 else None)
+    out = out.astype(b.dtype)
+    return out.reshape(-1)[:n] if scalars else out.reshape(tiles * _ROW_TILE, -1)[:n // k]
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _take(a, order, inverse, start, rows: int, k: int):
@@ -262,8 +323,15 @@ def _take(a, order, inverse, start, rows: int, k: int):
 def _put(b, order, inverse, start, rows: int, k: int):
     """b [rows, ...] -> [T, ...] (k = 1: [T * k]): every assignment's row of the window
     (zeros for the assignments that lie outside it), summed over a token's k in float32
-    and rounded once. A gather a slot, tokens rows each: no buffer has tokens x k rows."""
+    and rounded once. No buffer has tokens x k rows: from the window's side
+    (`combine_from_rows`) one grouped product over the window's rows, else a gather a
+    slot, tokens rows each."""
     with jax.named_scope("moe_combine"):
+        n = inverse.shape[0]
+        if combine_from_rows(n // k, k, rows):
+            inside = start + jnp.arange(rows, dtype=jnp.int32) < n  # the last window is padded
+            at = jnp.where(inside, jax.lax.dynamic_slice(order, (start,), (rows,)), _NO_ASSIGNMENT)
+            return _sum_from_rows(b, at, n, k)
         at = (inverse - start).reshape(-1, k)
         out = 0
         for j in range(k):

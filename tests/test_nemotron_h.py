@@ -333,6 +333,103 @@ def test_the_window_walk_serves_latent_relu2_experts_at_any_load(load):
     _leaves_match(grads, r_grads, least=6)
 
 
+# The combine (`moe._put`) against a plain sum by token written here: float32 sums of a
+# token's rows in slot order, rounded once. Routing is made by hand (who chose whom), then
+# sorted as `expert_layer` sorts it. (tokens, k, experts, held, skew, from the window's
+# side): token 0 chooses held experts with as many of its slots as there are held experts,
+# token 1 with none; "skew" sends every token's first slots to the held experts, so the
+# load overflows the window and the windows at start > 0 hold rows too.
+COMBINE = {
+    "22_of_512_a_64th_held": (1024, 22, 512, (0, 64), False, True),
+    "22_of_512_a_64th_held_skewed": (1024, 22, 512, (0, 64), True, True),
+    "22_of_512_a_64th_held_last_window_padded": (1000, 22, 512, (0, 64), True, True),
+    "22_of_512_a_16th_held_every_slot_of_a_token": (2048, 22, 512, (0, 16), False, True),
+    "22_of_512_an_8th_held": (1024, 22, 512, (0, 8), False, True),
+    "4_of_64_an_8th_held": (1024, 4, 64, (0, 8), False, True),
+    "4_of_64_an_8th_held_skewed": (1024, 4, 64, (0, 8), True, True),
+    "22_of_512_a_quarter_held_every_slot_of_a_token": (1024, 22, 512, (0, 4), False, False),
+    "4_of_64_a_quarter_held_skewed": (1024, 4, 64, (1, 4), True, False),
+    "4_of_64_a_64th_held": (1024, 4, 64, (5, 64), False, True),
+    "2_of_128_a_16th_held_skewed": (2048, 2, 128, (1, 16), True, True),
+    "2_of_8_every_expert_held": (96, 2, 8, (0, 1), False, False),
+}
+
+
+def _sorted_assignments(tokens, k, n_experts, held, skew, seed=0):
+    """(order padded to whole windows, inverse, rows, held rows) for a routing made by hand."""
+    rng = np.random.default_rng(seed)
+    cfg = dataclasses.replace(ROUTED, n_experts=n_experts, moe_top_k=k, experts_held=held)
+    lo, hi = moe.held_range(cfg)
+    inside, outside = np.arange(lo, hi), np.setdiff1d(np.arange(n_experts), np.arange(lo, hi))
+    idx = np.argsort(rng.random((tokens, n_experts)), -1)[:, :k]
+    most = min(k, hi - lo)
+    for t in range(tokens) if skew else (0,):  # as many slots as can be, on held experts
+        idx[t] = np.concatenate([rng.permutation(inside)[:most], rng.permutation(outside)[:k - most]])
+    if len(outside) >= k:
+        idx[1] = rng.permutation(outside)[:k]
+    key = np.where((idx >= lo) & (idx < hi), idx - lo, hi - lo).reshape(-1)
+    order = np.argsort(key, kind="stable").astype(np.int32)
+    rows = moe.window_rows(cfg, tokens)
+    return (np.pad(order, (0, -order.size % rows)), np.argsort(order).astype(np.int32), rows,
+            int((key < hi - lo).sum()))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("case", list(COMBINE))
+def test_the_combine_is_a_float32_sum_by_token_rounded_once(case, dtype):
+    """Rows and scalars (the gates' case, k = 1), every window the load needs, both
+    directions: `_put` is the plain sum by token, its transpose the plain gather, whichever
+    side `combine_from_rows` says the layer sums from. Rows of 1 + 2^-5 sum exactly in
+    float32 in any order: a token with 22 of them reads 22.75 in bfloat16, and 22.25 where
+    the running sum was kept in bfloat16."""
+    tokens, k, n_experts, held, skew, from_rows = COMBINE[case]
+    order, inverse, rows, held_rows = _sorted_assignments(tokens, k, n_experts, held, skew)
+    n, width = tokens * k, 16
+    assert moe.combine_from_rows(tokens, k, rows) == from_rows
+    windows = -(-held_rows // rows)
+    assert (windows > 1) == (skew and rows < n)
+    rng = np.random.default_rng(1)
+    fullest = 0
+    for start in range(0, windows * rows, rows):
+        at = order[start:start + rows]
+        valid = start + np.arange(rows) < n
+        token = np.where(valid, at // k, tokens)
+        fullest = max(fullest, int(np.bincount(token[valid]).max()))
+        for rows_of in ("normal", "one_and_a_32nd"):
+            b = rng.standard_normal((rows, width)).astype(np.float32) if rows_of == "normal" \
+                else np.full((rows, width), 1.03125, np.float32)
+            b = jnp.asarray(b, dtype)
+            want = np.zeros((tokens + 1, width), np.float32)
+            slots = np.argsort(np.where(valid, at, n), kind="stable")  # a token's slots in order
+            np.add.at(want, token[slots], np.asarray(b, np.float32)[slots])  # one by one, in float32
+            want = np.asarray(jnp.asarray(want[:tokens]).astype(dtype), np.float32)
+            got, pull = jax.vjp(lambda b: moe._put(b, order, inverse, np.int32(start), rows, k), b)
+            got = np.asarray(got, np.float32)
+            if rows_of == "normal" and dtype == jnp.float32:  # the additions' order may differ
+                np.testing.assert_allclose(got, want, rtol=1e-6, atol=2e-6)
+            elif rows_of == "normal":  # ... and may round a sum at a tie's edge the other way
+                assert (got != want).mean() < 2e-3
+                np.testing.assert_allclose(got, want, rtol=2 ** -7)
+            else:
+                np.testing.assert_array_equal(got, want)
+                if "every_slot" in case and dtype == jnp.bfloat16:
+                    assert got[0, 0] == 22.75  # a bfloat16 running sum reads 22.25
+            g = jnp.asarray(rng.standard_normal((tokens, width)), dtype)
+            back = np.asarray(pull(g)[0], np.float32)  # a row's cotangent is its token's
+            np.testing.assert_array_equal(back[valid], np.asarray(g, np.float32)[token[valid]])
+        # the scalars: every assignment's value, in float32 to the bit
+        v = jnp.asarray(rng.standard_normal(rows), jnp.float32) * (1 + 2.0 ** -20)
+        want = np.zeros(n + 1, np.float32)
+        want[np.where(valid, at, n)] = np.where(valid, np.asarray(v), 0)
+        got, pull = jax.vjp(lambda v: moe._put(v, order, inverse, np.int32(start), rows, 1), v)
+        np.testing.assert_array_equal(got, want[:n])
+        g = jnp.asarray(rng.standard_normal(n), jnp.float32)
+        np.testing.assert_array_equal(np.asarray(pull(g)[0])[valid], np.asarray(g)[at[valid]])
+    if "every_slot" in case:
+        assert fullest == k == 22  # the case a bfloat16 running sum fails
+    assert fullest >= min(k, n_experts // held[1]) or skew
+
+
 def test_the_cells_window_is_twice_what_its_experts_can_expect():
     cfg = _cell_config()[2]
     assert moe.window_rows(cfg, 8192) == 5632 == 11 * 512

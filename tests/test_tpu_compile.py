@@ -131,6 +131,41 @@ def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
     assert (b, h, 16, steps // 16) in grids and (b, h, 16, 2) in grids, grids  # one kv span; two q spans
 
 
+def _glm_share():
+    from ray_tpu.models.config import ModelConfig
+
+    return ModelConfig(
+        name="glm-shape", vocab_size=19360, d_model=2048, n_layers=5, n_heads=20, n_kv_heads=20,
+        d_ff=10240, n_experts=64, moe_top_k=4, moe_capacity_factor=0.0, d_ff_expert=1536,
+        n_shared_experts=1, moe_scoring="sigmoid", moe_route_scale=1.8, moe_select_bias=True,
+        experts_held=(0, 8))
+
+
+_LAYER_TEXTS = {}
+
+
+def _expert_layer_text(cfg, one_chip):
+    """The compiled text of an expert layer's value and every gradient at 8,192 tokens,
+    made once a configuration (under `on_tpu`, which every caller has)."""
+    from ray_tpu.models import moe
+
+    if cfg.name not in _LAYER_TEXTS:
+        lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
+                     one_chip)
+        x = jax.ShapeDtypeStruct((8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+
+        def loss(x, lp):
+            return jnp.sum(moe.expert_layer(x, lp, cfg)[0].astype(jnp.float32))
+
+        _LAYER_TEXTS[cfg.name] = (
+            set(lp), jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text())
+    return _LAYER_TEXTS[cfg.name]
+
+
+def _grouped_kernels(text):
+    return [ln for ln in text.splitlines() if re.match(r"\s*%ragged-dot-none[\w.]* = ", ln)]
+
+
 def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
     """The dropless expert layer at the cell's shape (8,192 tokens x 4 assignments, 8
     held experts of 2048 x 1536, a window of 8,192 rows): its grouped products are the
@@ -139,27 +174,16 @@ def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
     3 again, since only the walk's inputs are kept, and 2 transposes each), and the
     window's body is in the program twice, for the first window (9: XLA shares its
     forward products with the backward's, no rematerialisation standing between them
-    here) and in the loops that only an overflowing step enters (3 + 9). No scatter in
-    either direction, and nothing of tokens x k rows by either width is left."""
+    here) and in the loops that only an overflowing step enters (3 + 9); and since PR 34
+    the combine's own, 3 a body. No scatter in either direction, and nothing of tokens x
+    k rows by either width is left."""
     from ray_tpu.models import moe
-    from ray_tpu.models.config import ModelConfig
 
-    cfg = ModelConfig(
-        name="glm-shape", vocab_size=19360, d_model=2048, n_layers=5, n_heads=20, n_kv_heads=20,
-        d_ff=10240, n_experts=64, moe_top_k=4, moe_capacity_factor=0.0, d_ff_expert=1536,
-        n_shared_experts=1, moe_scoring="sigmoid", moe_route_scale=1.8, moe_select_bias=True,
-        experts_held=(0, 8))
+    cfg = _glm_share()
     assert moe.window_rows(cfg, 8192) == 8192
-    lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
-                 one_chip)
-    x = jax.ShapeDtypeStruct((8192, 2048), jnp.bfloat16, sharding=one_chip)
-
-    def loss(x, lp):
-        return jnp.sum(moe.expert_layer(x, lp, cfg)[0].astype(jnp.float32))
-
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
-    kernels = [ln for ln in text.splitlines() if re.match(r"\s*%ragged-dot-none[\w.]* = ", ln)]
-    assert len(kernels) == 21, len(kernels)
+    _, text = _expert_layer_text(cfg, one_chip)
+    kernels = _grouped_kernels(text)
+    assert len(kernels) == 21 + 6, len(kernels)
     assert sum("bf16[8192," in ln.split(" custom-call(")[0] for ln in kernels) == 15
     assert not re.search(r" scatter\(", text)
     full = [ln.strip()[:160] for ln in text.splitlines()
@@ -184,28 +208,64 @@ def test_latent_expert_layer_at_22_of_512_compiles_without_a_tokens_by_k_by_expe
     """The expert layer of the Nemotron-3-Super cell (8,192 tokens x 22 assignments over a
     router of 512, 8 experts of 1024 x 2688 held in a latent, a window of 5,632 rows): two
     grouped products an expert MLP (`ragged-dot-none`: 2 forward and 6 in the backward of a
-    window, the window's body in the program twice), no scatter, and no operand with the
-    extents of tokens, k and experts together: a mask `[8192, 22, 512]` is 92 M elements a
-    layer, forward and again in the backward pass."""
+    window, the window's body in the program twice: 14; and since PR 34 the combine's own,
+    3 a body: the window's rows summed onto their tokens forward, for dx, and the gates'
+    gradient), no scatter, and no operand with the extents of tokens, k and experts
+    together: a mask `[8192, 22, 512]` is 92 M elements a layer, forward and again in the
+    backward pass."""
     from ray_tpu.models import moe
 
     cfg = _nemotron_share()
     assert moe.window_rows(cfg, 8192) == 5632
-    lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
-                 one_chip)
-    assert set(lp) == {"router", "router_bias", "w_up", "w_down", "shared_up", "shared_down",
-                       "latent_down", "latent_up"}
-    x = jax.ShapeDtypeStruct((8192, 4096), jnp.bfloat16, sharding=one_chip)
-
-    def loss(x, lp):
-        return jnp.sum(moe.expert_layer(x, lp, cfg)[0].astype(jnp.float32))
-
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
-    kernels = [ln for ln in text.splitlines() if re.match(r"\s*%ragged-dot-none[\w.]* = ", ln)]
-    assert len(kernels) == 14, len(kernels)
+    names, text = _expert_layer_text(cfg, one_chip)
+    assert names == {"router", "router_bias", "w_up", "w_down", "shared_up", "shared_down",
+                     "latent_down", "latent_up"}
+    kernels = _grouped_kernels(text)
+    assert len(kernels) == 14 + 6, len(kernels)
     assert not re.search(r" scatter\(", text)
     assert not re.search(r"\[(8192,22,512|22,8192,512|8192,512,22|180224,512)\]", text)
     assert not re.search(r"\[180224,(1024|2688|4096)\]", text)  # nor tokens x k rows of any width
+
+
+def _gathers_under(text, scope):
+    """Result shapes of the gather instructions (fused or not) traced under `scope`."""
+    return [m.group(1) for ln in text.splitlines()
+            if (m := re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) gather\(", ln))
+            and re.search(rf'op_name="[^"]*/{scope}/', ln)]
+
+
+@pytest.mark.parametrize("cell,ratio,sums", [
+    ("nemotron", 32, ["f32[16,512,1024]"] * 4 + ["f32[3,512,128]"] * 2),
+    ("glm", 4, ["f32[1,512,128]"] * 2 + ["f32[16,512,2048]"] * 4)])
+def test_the_combine_follows_the_windows_rows_in_both_cells(one_chip, on_tpu, cell, ratio, sums):
+    """`moe.combine_from_rows` at the two cells' shapes, and the program it makes (PERF.md
+    sections 3 and 6, PR 34): Nemotron-3-Super sums 22 x 8,192 assignments over a window
+    of 5,632 rows (32 to 1), GLM-4.7-Flash 4 x 8,192 over 8,192 (4 to 1), both from the
+    window's side: no gather under `moe_combine` has a row a token or an assignment (the
+    sorted rows and the gates are gathered, a window's rows each), the sums are grouped
+    products by tile of 512 tokens (`[16, 512, width]`, forward and for dx in each body;
+    the gates' scalars 128 to a row), no scatter, no operand of tokens x k rows. A layer
+    that holds a quarter of its experts or more keeps a gather a slot."""
+    from ray_tpu.models import moe
+
+    cfg = {"nemotron": _nemotron_share, "glm": _glm_share}[cell]()
+    k, rows = cfg.moe_top_k, moe.window_rows(cfg, 8192)
+    assert 8192 * k == ratio * rows
+    assert moe.combine_from_rows(8192, k, rows) and moe.combine_from_rows(8192 * k, 1, rows)
+    for held in ((0, 4), (0, 2), (0, 1)):  # the same layer with a quarter, half or all of its experts
+        assert not moe.combine_from_rows(
+            8192, k, moe.window_rows(dataclasses.replace(cfg, experts_held=held), 8192))
+    _, text = _expert_layer_text(cfg, one_chip)
+    combined = [ln.split(" custom-call(")[0].split(" = ")[1].split("{")[0] for ln in _grouped_kernels(text)
+                if re.search(r"= f32\[\d+,512,\d+\]", ln)]
+    assert sorted(combined) == sums
+    # one gather a sum, the window's rows into the tokens' order (a gather a slot: k a sum)
+    gathered = [s.split("{")[0] for s in _gathers_under(text, "moe_combine")]
+    assert gathered.count(f"bf16[{rows},{cfg.moe_latent_dim or cfg.d_model}]") == 4, gathered
+    if rows != 8192:  # nor has any a row a token or an assignment
+        assert not [s for s in gathered if re.match(rf"\w+\[({8192 * k}|8192)[,\]]", s)], gathered
+    assert not re.search(r" scatter\(", text)
+    assert not re.search(rf"\[{8192 * k},\d+\]", text)
 
 
 def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
