@@ -13,6 +13,7 @@ core/accelerators.py) — a chip belongs to one process at a time.
 """
 from __future__ import annotations
 
+import gc
 import os
 import sys
 import threading
@@ -29,11 +30,84 @@ from .ids import ActorID, ObjectID, TaskID, WorkerID
 from .object_ref import ObjectRef
 from .task_spec import TaskSpec, _RefMarker
 
+from ray_tpu.util import telemetry
 
 import contextvars
 
 _ASYNC_TASK_ID: "contextvars.ContextVar[Optional[TaskID]]" = contextvars.ContextVar(
     "rt_async_task_id", default=None)
+
+
+# What this PROCESS did beside whatever loop runs in it, always on: the tasks and actor
+# methods it executed (`worker.task` spans: the executor's `poll_session` at 20 Hz shows
+# here) and the garbage collector's pauses. Monotonic integers, read through
+# `process_counters()`; `ray_tpu.train.metrics()` hands them on, and a process that runs a
+# train loop carries them to the head with its laps (train/session.py).
+_COUNTERS = {"worker_tasks_total": 0, "worker_task_ns_total": 0,
+             "gc_pause_ns_total": 0, "gc_collections_total": 0}
+_counters_lock = threading.Lock()  # tasks end on several threads; collections do not overlap
+_GC_EVENT_NS = 1_000_000  # a pause this long is written to the ring as `worker.gc`
+_gc = {"t0": 0, "note": None}
+# phase -> (start, wall clock ns; duration ns): process creation -> `worker_main` entered
+# -> `ready` sent -> the first task received. A train session publishes them
+# (train/session.py: `train_setup_seconds{phase}`, the ring).
+_BOOT: Dict[str, Tuple[int, int]] = {}
+_entered_wall_ns = 0  # when `worker_main` was entered
+
+
+def _count_task(dur_ns: int) -> None:
+    with _counters_lock:
+        _COUNTERS["worker_tasks_total"] += 1
+        _COUNTERS["worker_task_ns_total"] += dur_ns
+
+
+def process_counters() -> Dict[str, int]:
+    return dict(_COUNTERS)
+
+
+def boot_stamps() -> Dict[str, Tuple[int, int]]:
+    return dict(_BOOT)
+
+
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """`gc.callbacks`: add the pause to the counters (two clock reads a collection). A
+    pause of a millisecond or more is also a `worker.gc` event of the ring, and while a
+    profile records every collection is an annotation on the thread it stopped. It runs
+    wherever the interpreter stops, also in a thread that holds the ring's lock, so it
+    takes no lock: the event goes through `telemetry.complete_deferred`."""
+    if phase == "start":
+        _gc["note"] = telemetry.annotate("worker.gc", generation=info["generation"])
+        _gc["t0"] = time.perf_counter_ns()
+        return
+    dur = time.perf_counter_ns() - _gc["t0"]
+    note, _gc["note"] = _gc["note"], None
+    if note is not None:
+        note.__exit__(None, None, None)
+    _COUNTERS["gc_pause_ns_total"] += dur
+    _COUNTERS["gc_collections_total"] += 1
+    if dur >= _GC_EVENT_NS:
+        telemetry.complete_deferred("worker.gc", "worker", time.time_ns() - dur, dur,
+                                    generation=info["generation"], collected=info["collected"])
+
+
+def count_collections() -> None:
+    """Register the collector's callback, once a process."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def _process_created_wall_ns() -> Optional[int]:
+    """When the kernel created this process, on the wall clock: /proc/self/stat's start
+    time (ticks since boot) against /proc/uptime, to a hundredth of a second. None where
+    there is no /proc."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])  # field 22, `starttime`
+        with open("/proc/uptime") as f:
+            age_s = float(f.read().split()[0]) - ticks / os.sysconf("SC_CLK_TCK")
+        return time.time_ns() - int(age_s * 1e9)
+    except (OSError, ValueError, IndexError):
+        return None
 
 
 class _ThreadPerCallExecutor:
@@ -414,6 +488,17 @@ class WorkerContext:
         self._execute_inner(spec, resolved_locs)
 
     def _execute_inner(self, spec: TaskSpec, resolved_locs: List) -> None:
+        """One task or actor method on the calling thread, as a `worker.task` span named
+        after it (ring, and the profile's host plane) and in the always-on counters."""
+        t0 = time.perf_counter_ns()
+        try:
+            with telemetry.span("worker.task", "worker",
+                                task=spec.method_name or spec.name, kind=spec.kind):
+                self._execute_traced(spec, resolved_locs)
+        finally:
+            _count_task(time.perf_counter_ns() - t0)
+
+    def _execute_traced(self, spec: TaskSpec, resolved_locs: List) -> None:
         self.current_task_id = spec.task_id
         ctx_token = None
         try:
@@ -553,6 +638,7 @@ class WorkerContext:
         import contextlib
 
         _ASYNC_TASK_ID.set(spec.task_id)  # task-scoped (per-asyncio.Task context)
+        t0_wall, t0 = time.time_ns(), time.perf_counter_ns()
         try:
             if spec.trace_ctx is not None:
                 from ray_tpu.util import tracing
@@ -573,6 +659,13 @@ class WorkerContext:
                 self._send(("result", spec.task_id, payload, None))
         except BaseException as e:  # noqa: BLE001
             self._send_error(spec, e)
+        finally:
+            # calls interleave at their awaits on the loop's one thread: no annotation
+            # could hold one, so the ring alone gets it, entry to result
+            dur = time.perf_counter_ns() - t0
+            _count_task(dur)
+            telemetry.complete("worker.task", "worker", t0_wall, dur,
+                               task=spec.method_name or spec.name, kind=spec.kind)
 
     def _execute_streaming(self, spec: TaskSpec, args, kwargs) -> None:
         from .object_ref import stream_item_id
@@ -665,8 +758,13 @@ class WorkerContext:
     def main_loop(self) -> None:
         self._ensure_recv_thread()
         self._send(("ready", self.worker_id_hex))
+        ready = time.time_ns()
+        if _entered_wall_ns:
+            _BOOT["worker.boot.ready"] = (_entered_wall_ns, ready - _entered_wall_ns)
         while not self._exit:
             msg = self._task_queue.get()
+            if "worker.boot.first_task" not in _BOOT:
+                _BOOT["worker.boot.first_task"] = (ready, time.time_ns() - ready)
             kind = msg[0]
             if kind == "task":
                 _, spec, resolved_locs = msg
@@ -677,6 +775,11 @@ class WorkerContext:
 
 def worker_main(conn, node_id_hex: str, worker_id_hex: str, accel: str, env: Dict[str, str]):
     """Entry point of a spawned worker process."""
+    global _entered_wall_ns
+    _entered_wall_ns = time.time_ns()
+    created = _process_created_wall_ns()
+    if created is not None:  # interpreter start and `spawn`'s re-import of the driver's __main__
+        _BOOT["worker.boot.spawn"] = (created, _entered_wall_ns - created)
     for k, v in env.items():
         os.environ[k] = v
     log_dir = os.environ.get("RAY_TPU_WORKER_LOG_DIR")
@@ -710,6 +813,7 @@ def worker_main(conn, node_id_hex: str, worker_id_hex: str, accel: str, env: Dic
             ensure_compile_cache_dir()
     ctx = WorkerContext(conn, node_id_hex, worker_id_hex, accel)
     global_state.set_worker(ctx)
+    count_collections()
     try:
         ctx.main_loop()
     except KeyboardInterrupt:

@@ -6,6 +6,7 @@ one block ahead of consumption — the pattern that keeps the TPU fed during tra
 """
 from __future__ import annotations
 
+import functools
 import threading
 import queue as _queue
 from typing import Any, Dict, Iterator, List, Optional
@@ -13,8 +14,27 @@ from typing import Any, Dict, Iterator, List, Optional
 import numpy as np
 
 import ray_tpu
+from ray_tpu.util import telemetry
 
 from .block import BlockAccessor
+
+
+def _in_data_lap(iterate):
+    """A generator method whose every item is asked for inside the `train.loop.data` lap
+    of the calling thread's train loop (train/session.py), where that thread runs one:
+    from the moment the loop asks for a batch until it has it."""
+    @functools.wraps(iterate)
+    def lapped(*args, **kwargs):
+        items = iterate(*args, **kwargs)
+        while True:
+            with telemetry.lap(telemetry.DATA_LAP):
+                try:
+                    item = next(items)
+                except StopIteration:
+                    return
+            yield item
+
+    return lapped
 
 
 class DataIterator:
@@ -87,6 +107,7 @@ class DataIterator:
             except _queue.Empty:
                 pass
 
+    @_in_data_lap
     def iter_batches(
         self,
         *,
@@ -121,16 +142,19 @@ class DataIterator:
         if carry is not None and carry.num_rows and not drop_last and batch_size is not None:
             yield BlockAccessor.for_block(carry).to_batch_format(batch_format)
 
+    @_in_data_lap
     def iter_rows(self) -> Iterator[Dict[str, Any]]:
         for block in self._iter_blocks():
             yield from BlockAccessor.for_block(block).iter_rows()
 
+    @_in_data_lap
     def iter_torch_batches(self, *, batch_size: Optional[int] = 256, **kw) -> Iterator[Dict[str, Any]]:
         import torch
 
         for batch in self.iter_batches(batch_size=batch_size, batch_format="numpy", **kw):
             yield {k: torch.as_tensor(v) for k, v in batch.items() if v.dtype != object}
 
+    @_in_data_lap
     def iter_jax_batches(
         self, *, batch_size: Optional[int] = 256, sharding=None, **kw
     ) -> Iterator[Dict[str, Any]]:

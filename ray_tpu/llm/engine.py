@@ -72,58 +72,6 @@ _LOOP_COUNTERS = ("loop_admit_ns_total", "loop_grow_ns_total",
                   "loop_dispatch_ns_total", "loop_fetch_ns_total",
                   "loop_emit_ns_total", "loop_idle_ns_total")
 
-COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-# programs this PROCESS compiled (or read from the compile cache) and the
-# seconds that took: JAX reports them to process-wide listeners that cannot be
-# taken off again, so one listener and one count serve every engine here
-_COMPILES = {"compiles_total": 0, "compile_ns_total": 0}
-_compile_listener_lock = threading.Lock()
-_compile_listener_on = False
-
-
-def _count_compiles() -> None:
-    """Register the compile listener, once a process."""
-    global _compile_listener_on
-    with _compile_listener_lock:
-        if _compile_listener_on:
-            return
-        _compile_listener_on = True
-
-    def on_duration(event: str, duration_secs: float, **_kw) -> None:
-        if event == COMPILE_EVENT:
-            with _compile_listener_lock:
-                _COMPILES["compiles_total"] += 1
-                _COMPILES["compile_ns_total"] += int(duration_secs * 1e9)
-
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
-
-
-class _LoopClock:
-    """Which phase of its turn the scheduler loop is in. enter(i) ends the
-    phase that was open and begins LOOP_SPANS[i] at the same instant, so every
-    nanosecond of the loop thread lies in exactly one phase (time the thread
-    waits to run, say for the interpreter lock after it woke the request
-    threads, goes to the phase it was in). Each phase is a telemetry span and,
-    tracing on or off, adds its duration to `counters[_LOOP_COUNTERS[i]]`.
-    The loop is one thread: no lock."""
-
-    __slots__ = ("_counters", "_key", "_span", "_t0")
-
-    def __init__(self, counters: Dict[str, int]):
-        self._counters, self._key, self._span, self._t0 = counters, None, None, 0
-
-    def enter(self, i: Optional[int]) -> None:
-        now = time.perf_counter_ns()
-        if self._key is not None:
-            self._counters[self._key] += now - self._t0
-            self._span.__exit__(None, None, None)
-        if i is None:  # the loop ends
-            self._key = self._span = None
-            return
-        self._key, self._t0 = _LOOP_COUNTERS[i], now
-        self._span = telemetry.span(LOOP_SPANS[i], "llm")
-        self._span.__enter__()
-
 
 @dataclasses.dataclass
 class RequestOutput:
@@ -265,7 +213,10 @@ class JaxLLMEngine(LLMEngine):
             "decode_steps_total", "decode_slot_steps_total",
             "queue_wait_ns_total", "admitted_total"), 0)
         self._ingress = {"ingress_ns_total": 0, "ingress_requests_total": 0}
-        self._clock = _LoopClock(self._counters)
+        # a lap ends where the next begins (util/telemetry.LapClock); the laps'
+        # nanoseconds add into _counters
+        self._clock = telemetry.LapClock(LOOP_SPANS, _LOOP_COUNTERS, "llm",
+                                         self._counters)
         # P/D export bookkeeping (prefill side): (monotonic, key) per un-acked
         # KV export, LRU/TTL-pruned by _track_pd_export and the lazy prune
         # daemon; kept in sync with the device plane's own releases (consumer
@@ -285,7 +236,7 @@ class JaxLLMEngine(LLMEngine):
             from ray_tpu.usage import record_library_usage
 
             record_library_usage("llm")
-            _count_compiles()
+            telemetry.compile_counters()  # the listener, once a process
             cfg = self.model_config
             c = self.config
             from ray_tpu.core.accelerators import check_worker_platform
@@ -969,7 +920,7 @@ class JaxLLMEngine(LLMEngine):
         }
         out.update(self._counters)
         out.update(self._ingress)
-        out.update(_COMPILES)
+        out.update(telemetry.compile_counters())
         blocks = getattr(self, "_blocks", None)
         if blocks is not None:
             total = blocks.total_blocks

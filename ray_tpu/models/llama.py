@@ -607,7 +607,9 @@ def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
         at, auxs = dict.fromkeys(stacks, 0), []
         for c in unit:
             name = _PATTERN[c][0]
-            h, aux = layer(h, jax.tree.map(lambda a: a[at[name]], stacks[name]))  # noqa: B023
+            with jax.named_scope("layer_params"):  # the layer's rows of its stack: copies a step pays for
+                lp = jax.tree.map(lambda a: a[at[name]], stacks[name])  # noqa: B023
+            h, aux = layer(h, lp)
             at[name] += 1
             if c == "E":
                 auxs.append(aux)
@@ -713,8 +715,9 @@ def mtp_logits(params: Params, hidden: jax.Array, tokens: jax.Array, cfg: ModelC
             x = jnp.einsum("bse,ed->bsd", joined, _w(mp["eh_proj"], joined.dtype))
             hidden, _, aux = _maybe_remat(
                 lambda h, lp: _block(h, lp, cfg, positions, None), cfg)(x, mp)
-            logits = output_head({**params, "final_norm": mp["final_norm"]},
-                                 hidden[:, :s - m], cfg)
+            with jax.named_scope("lm_head"):  # the model's head again, under `mtp`
+                logits = output_head({**params, "final_norm": mp["final_norm"]},
+                                     hidden[:, :s - m], cfg)
         out.append((logits, aux))
     return out
 

@@ -195,6 +195,15 @@ def _render_status(s: dict) -> str:
         phases = " ".join(f"{k}:{v * 1e3:.1f}ms"
                           for k, v in sorted(tn.get("step_phases_s", {}).items()))
         lines.append(f"train      mfu[{mfu or '-'}] step_phases[{phases or '-'}]")
+    if tn.get("steps") or tn.get("setup_seconds") or tn.get("group_failures"):
+        laps = " ".join(f"{k}:{v:.2f}ms" for k, v in (tn.get("loop_ms_per_step") or {}).items())
+        setup = " ".join(f"{k.rpartition('.')[2]}:{v:.1f}s"
+                         for k, v in sorted((tn.get("setup_seconds") or {}).items()))
+        lines.append(
+            f"train      steps={tn.get('steps', 0)} loop/step[{laps or '-'}] "
+            f"compiles={tn.get('compiles', 0)} gc={tn.get('gc_pause_ms', 0):.1f}ms/"
+            f"{tn.get('gc_collections', 0)} group_failures={tn.get('group_failures', 0)} "
+            f"setup[{setup or '-'}]")
     bubbles = tn.get("pipeline_bubble_fraction") or {}
     if bubbles:
         frac = " ".join(f"{k}:{v:.2f}" for k, v in sorted(bubbles.items()))

@@ -49,6 +49,7 @@ from .session import (  # noqa: F401
     get_checkpoint,
     get_context,
     get_dataset_shard,
+    metrics,
     report,
     step_phase,
 )

@@ -7,6 +7,7 @@ torn down and restarted from the latest checkpoint, up to FailureConfig.max_fail
 """
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -19,6 +20,7 @@ from .backend import BackendConfig
 from .checkpoint import Checkpoint
 from .checkpoint_manager import CheckpointManager
 from .result import Result
+from . import session
 from .session import TrainContext
 from .worker_group import WorkerGroup
 
@@ -48,6 +50,15 @@ def restart_backoff_s(failure_count: int) -> float:
                base * (2 ** max(0, failure_count - 1)))
 
 
+@contextlib.contextmanager
+def _setup_phase(phase: str):
+    start_wall, start = time.time_ns(), time.perf_counter_ns()
+    try:
+        yield
+    finally:
+        session.record_setup(phase, start_wall, time.perf_counter_ns() - start)
+
+
 class BackendExecutor:
     def __init__(
         self,
@@ -72,12 +83,15 @@ class BackendExecutor:
 
     # -- lifecycle ---------------------------------------------------------------------
     def start(self) -> None:
-        self.worker_group = WorkerGroup(
-            num_workers=self.scaling_config.num_workers,
-            resources_per_worker=self.scaling_config.worker_resources(),
-            placement_strategy=self.scaling_config.placement_strategy,
-        )
-        self.backend.on_start(self.worker_group, self.backend_config)
+        # the way from fit() to the user's loop, stamped where it happens (session.record_setup)
+        with _setup_phase("train.setup.worker_group"):  # actors asked for -> every get_metadata back
+            self.worker_group = WorkerGroup(
+                num_workers=self.scaling_config.num_workers,
+                resources_per_worker=self.scaling_config.worker_resources(),
+                placement_strategy=self.scaling_config.placement_strategy,
+            )
+        with _setup_phase("train.setup.backend"):
+            self.backend.on_start(self.worker_group, self.backend_config)
 
     def start_training(
         self,
@@ -87,6 +101,10 @@ class BackendExecutor:
         checkpoint: Optional[Checkpoint] = None,
     ) -> None:
         assert self.worker_group is not None, "call start() first"
+        with _setup_phase("train.setup.session"):  # start_session sent -> returned
+            self._start_sessions(train_fn, train_loop_config, datasets, checkpoint)
+
+    def _start_sessions(self, train_fn, train_loop_config, datasets, checkpoint) -> None:
         self.backend.on_training_start(self.worker_group, self.backend_config)
         if self.checkpoint_manager is not None:
             self.checkpoint_manager.resume_point = checkpoint

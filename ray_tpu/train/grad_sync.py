@@ -68,6 +68,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.util.hot_path import hot_path
 
+from . import session
+
 DEFAULT_BUCKET_BYTES = 4 << 20  # 4 MiB: ~8 buckets on a 500M-param f32 tree
 
 _TRUE = ("1", "true", "yes", "on")
@@ -580,7 +582,6 @@ class InstrumentedGradSyncStep(GradSyncStep):
         self._fn = self._run
 
     def _phase(self, name: str):
-        from . import session
         from ray_tpu.util import telemetry
 
         class _Ctx:
@@ -627,9 +628,10 @@ class InstrumentedGradSyncStep(GradSyncStep):
 
 def make_step(cfg, tx, loss_fn, sync: GradSyncConfig, donate: bool = True):
     """Factory `train.step.make_train_step` delegates to for non-default
-    sync configs."""
+    sync configs. The step is counted as the stock one is (the loop's `dispatch` lap
+    around its call, train/session.py); `.lower` and its other attributes come through."""
     cls = InstrumentedGradSyncStep if sync.telemetry else GradSyncStep
-    return cls(cfg, tx, loss_fn, sync, donate)
+    return session.CountedStep(cls(cfg, tx, loss_fn, sync, donate))
 
 
 # -------------------------------------------------------- HLO inspection

@@ -11,6 +11,7 @@ directory immediately after reporting.
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import queue
 import shutil
@@ -20,12 +21,170 @@ import uuid
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional
 
+from ray_tpu.core import worker as core_worker
 from ray_tpu.util import telemetry
 
 from .checkpoint import Checkpoint
 
 _session_lock = threading.Lock()
 _session: Optional["_TrainSession"] = None
+
+# The train loop's clock. What the PROGRAM can see of a loop it does not own: the step
+# callable is entered and returns (train/step.py), `report()` is entered and returns, a
+# batch is asked of a dataset's iterator and comes back (data/iterator.py); whatever lies
+# between is the user's own code, which in a loop that syncs on its loss is the wait for
+# the device. A lap ends where the next begins, so the four sum to the thread's wall
+# time. Each is a span (a profiler annotation on the loop's thread whenever a profile
+# records, an entry of the ring under RAY_TPU_TRACING) and, always, a monotonic integer
+# of `metrics()`.
+LOOP_SPANS = ("train.loop.dispatch", "train.loop.report", telemetry.DATA_LAP,
+              "train.loop.user")
+DISPATCH, REPORT, DATA, USER = range(len(LOOP_SPANS))
+_LOOP_COUNTERS = ("train_loop_dispatch_ns_total", "train_loop_report_ns_total",
+                  "train_loop_data_ns_total", "train_loop_user_ns_total")
+_STEPS = "train_steps_total"
+_STEP_INTERVAL_BOUNDARIES = [0.001 * 2 ** (i / 2) for i in range(34)]  # 1 ms .. 92 s
+
+
+class _Loop:
+    """One thread's laps, its count of steps and when it last entered a step. A
+    session's `train_loop` thread has one; outside a session (a bare process, the
+    tests) a thread gets one at its first step."""
+
+    __slots__ = ("clock", "last_step_ns", "thread")
+
+    def __init__(self):
+        self.clock = telemetry.LapClock(LOOP_SPANS, _LOOP_COUNTERS + (_STEPS,), "train")
+        self.last_step_ns = 0
+        self.thread = threading.current_thread()
+
+
+_loops_lock = threading.Lock()
+_loops: list = []  # the live threads'
+_retired: Dict[str, int] = dict.fromkeys(_LOOP_COUNTERS + (_STEPS,), 0)  # the ended threads', summed
+_local = threading.local()
+_setup_seconds: Dict[str, float] = {}  # phase -> seconds, this process's (record_setup)
+
+
+def _loop() -> _Loop:
+    """The calling thread's laps, begun in `user` at the first call."""
+    loop = getattr(_local, "loop", None)
+    if loop is None:
+        loop = _local.loop = _Loop()
+        with _loops_lock:
+            _loops.append(loop)
+        telemetry.set_thread_clock(loop.clock)
+        loop.clock.enter(USER)
+        # carried to the head from the processes that run steps only, so that the
+        # `train` row of cluster_status() sums the train workers' own
+        telemetry.export_counters(
+            metrics, _LOOP_COUNTERS + (_STEPS, "compiles_total", "compile_ns_total")
+            + tuple(core_worker.process_counters()),
+            "the train loop's laps, steps and compiles, its process's tasks and "
+            "collector pauses (ray_tpu.train.metrics)")
+    return loop
+
+
+def _end_loop() -> None:
+    """The calling thread's loop ends: its open lap closes, its integers stay in the sums."""
+    loop = getattr(_local, "loop", None)
+    if loop is None:
+        return
+    loop.clock.enter(None)
+    telemetry.set_thread_clock(None)
+    _local.loop = None
+    with _loops_lock:
+        _retire_locked(loop)
+
+
+def _retire_locked(loop: _Loop) -> None:
+    _loops.remove(loop)
+    for key, n in loop.clock.totals.items():
+        _retired[key] += n
+
+
+@functools.lru_cache(maxsize=None)
+def _step_interval():
+    return telemetry.get_histogram(
+        "train_step_interval_seconds", "entry to entry of the train step callable",
+        boundaries=_STEP_INTERVAL_BOUNDARIES)
+
+
+def enter_step() -> Optional[int]:
+    """The step callable is entered (train/step.py): the `dispatch` lap begins, the step
+    is counted, the time since the last entry is observed. -> what `leave_step` takes."""
+    loop = _loop()
+    back = loop.clock.lap
+    now = loop.clock.enter(DISPATCH)
+    if loop.last_step_ns:
+        _step_interval().observe((now - loop.last_step_ns) * 1e-9)
+    loop.last_step_ns = now
+    loop.clock.totals[_STEPS] += 1
+    return back
+
+
+def leave_step(back: Optional[int]) -> None:
+    _local.loop.clock.enter(back)
+
+
+class CountedStep:
+    """A step callable (train/step.py's jitted step, train/grad_sync.py's) with the train
+    loop's clock around its call: the `dispatch` lap from entry to return (pytree
+    flattening, donation, the enqueue; a compile if there is one), `train_steps_total`,
+    the interval since the last entry. Tracing on or off that is two clock reads and
+    integer additions a call. Everything else is the step's own: `.lower(...)`,
+    `._cache_size()`, its name (the profile's module stays `jit_step`, the compile
+    cache's key knows nothing of this wrapper)."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+
+    def __call__(self, *args, **kwargs):
+        back = enter_step()
+        try:
+            return self._jitted(*args, **kwargs)
+        finally:
+            leave_step(back)
+
+    def __getattr__(self, name):  # only what this class does not define
+        return getattr(self._jitted, name)
+
+
+def record_setup(phase: str, start_wall_ns: int, dur_ns: int, **args: Any) -> None:
+    """One part of the way from `fit()` to the first line of the user's loop, measured
+    where it happens: a ring event under the phase's name on the wall clock (the ring's
+    head offset places it beside other processes'), `train_setup_seconds{phase}`, and
+    `metrics()["setup_seconds"]` of this process."""
+    _setup_seconds[phase] = dur_ns * 1e-9
+    telemetry.get_gauge(
+        "train_setup_seconds", "seconds of each part of the way from fit() to the "
+        "user's loop", tag_keys=("phase",)).set(dur_ns * 1e-9, tags={"phase": phase})
+    telemetry.complete(phase, "train", start_wall_ns, dur_ns, **args)
+
+
+def metrics() -> Dict[str, Any]:
+    """This process's always-on integers of the training path, as `JaxLLMEngine.metrics()`
+    gives the engine's: `train_loop_{dispatch,report,data,user}_ns_total` (the laps of
+    every thread that ran steps), `train_steps_total`, `compiles_total` /
+    `compile_ns_total` (the process's: a step that compiled again shows here), the
+    worker's `worker_tasks_total` / `worker_task_ns_total` and the collector's
+    `gc_pause_ns_total` / `gc_collections_total` (core/worker.py), and `setup_seconds`
+    by phase. Monotonic: read them before and after a window and divide the differences.
+    Tracing on or off."""
+    with _loops_lock:
+        for loop in [l for l in _loops if not l.thread.is_alive()]:
+            # a thread that ran steps outside a session and ended without `_end_loop`:
+            # its closed laps stay in the sums, its open one (`user`, of a length nobody
+            # measured) stops growing
+            _retire_locked(loop)
+        out: Dict[str, Any] = dict(_retired)
+        for loop in _loops:
+            for key, n in loop.clock.read().items():
+                out[key] += n
+    out.update(telemetry.compile_counters())
+    out.update(core_worker.process_counters())
+    out["setup_seconds"] = dict(_setup_seconds)
+    return out
 
 
 @dataclass
@@ -81,19 +240,30 @@ class _TrainSession:
         self._thread: Optional[threading.Thread] = None
 
     def start(self) -> None:
+        started_wall, started = time.time_ns(), time.perf_counter_ns()
+        for phase, (wall_ns, dur_ns) in core_worker.boot_stamps().items():
+            record_setup(phase, wall_ns, dur_ns, rank=self.context.world_rank)
+
         def run():
-            global _session
             try:
+                _loop()  # the thread's laps begin, in `user`
+                record_setup("train.setup.loop_entered", started_wall,
+                             time.perf_counter_ns() - started, rank=self.context.world_rank)
                 self.train_fn(self.config)
             except BaseException as e:  # noqa: BLE001 — report worker crash faithfully
                 self.error = e
             finally:
+                _end_loop()
                 self.finished.set()
 
         self._thread = threading.Thread(target=run, daemon=True, name="train_loop")
         self._thread.start()
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None) -> None:
+        with telemetry.lap(LOOP_SPANS[REPORT]):  # checkpoint staging lies here
+            self._report(metrics, checkpoint)
+
+    def _report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint]) -> None:
         if checkpoint is not None and self.staging_dir is not None:
             # Stage into run storage now: the caller may delete its snapshot dir the
             # moment report() returns, long before the driver polls.
@@ -183,13 +353,19 @@ def get_checkpoint() -> Optional[Checkpoint]:
 
 @contextlib.contextmanager
 def step_phase(name: str):
-    """Time one phase of a training step — the step-composition breakdown
-    (`data` / `forward_backward` / `allreduce` / `optimizer`) behind the
-    train row of `ray-tpu status` and the chrome-trace timeline.
+    """Time a phase of a training step that the loop runs as a block of its own: a span
+    `train.phase.<name>` and `train_step_phase_seconds{phase}`, behind `step_phases` in
+    the train row of `ray-tpu status`. The stock step (`make_train_step`) is ONE jitted
+    program, so it has no `forward_backward` / `optimizer` to time from the host: the
+    loop's own laps (`train.loop.*`, `ray_tpu.train.metrics()`) say where its thread's
+    time goes, the device's profile where the program's does. What still enters this:
+    `grad_sync`'s telemetry mode (`forward_backward` / `bucket_wait` / `optimizer`, three
+    programs with a sync after each) and a user's loop around blocks of its own (`data`,
+    an evaluation pass).
 
     Usage inside a train loop:
-        with train.step_phase("forward_backward"):
-            loss, grads = value_and_grad(...)
+        with train.step_phase("eval"):
+            ...
 
     Works outside a session too (bench scripts): rank then reports as -1."""
     s = _get_session()

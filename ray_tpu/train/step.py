@@ -18,7 +18,7 @@ from ray_tpu.models import ModelConfig, llama
 from ray_tpu.parallel import build_mesh, MeshSpec, use_mesh
 from ray_tpu.parallel.sharding import AxisRules, TRAIN_RULES, named_sharding, shard_pytree
 
-from . import grad_sync
+from . import grad_sync, session
 
 
 class TrainState(NamedTuple):
@@ -129,8 +129,16 @@ def make_train_step(
                 "train/grad_sync.py's steps would leave it to the optimizer")
         return grad_sync.make_step(cfg, tx, loss_fn, sync, donate)
 
+    def model(params, batch, cfg):
+        # JAX wraps the FIRST name inside a transformation in the transformation's own
+        # (`jvp(embed)`, `transpose(jvp(lm_head))`), which no reader of plain names finds;
+        # this scope takes that wrapper, so the model's scopes below it (`embed`,
+        # `lm_head`, `loss`, a layer's parts) stand plain in every instruction's `op_name`
+        with jax.named_scope("model"):
+            return loss_fn(params, batch, cfg)
+
     def step(state: TrainState, batch: Dict[str, jax.Array]):
-        (loss, aux), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+        (loss, aux), grads = jax.value_and_grad(model, has_aux=True)(
             state.params, batch, cfg
         )
         with jax.named_scope("optimizer"):  # a name in the profile; no operation
@@ -144,4 +152,4 @@ def make_train_step(
         metrics["grad_norm"] = optax.global_norm(grads)
         return TrainState(state.step + 1, new_params, new_opt), metrics
 
-    return jax.jit(step, donate_argnums=(0,) if donate else ())
+    return session.CountedStep(jax.jit(step, donate_argnums=(0,) if donate else ()))

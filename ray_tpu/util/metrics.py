@@ -111,12 +111,15 @@ class _Registry:
             return
 
         def loop():
+            from ray_tpu.util import telemetry
+
             while True:
                 time.sleep(_report_interval())
                 try:
-                    snap = self.snapshot()
-                    if snap:
-                        w.push_metrics(snap)
+                    with telemetry.span("worker.push_metrics", "worker"):
+                        snap = self.snapshot()
+                        if snap:
+                            w.push_metrics(snap)
                 # graftlint: allow[swallowed-exception] degrades to the coded fallback (return) by design
                 except Exception:
                     return  # pipe closed: worker exiting
@@ -193,6 +196,24 @@ class Counter(Metric):
         with self._lock:
             return {"name": self.name, "type": self.TYPE, "description": self.description,
                     "values": {k: v for k, v in self._values.items()}}
+
+
+class CounterView(Counter):
+    """A counter whose value lives outside the registry: hot paths add to plain
+    integers (a loop's laps, a step's dispatch) and `read()` is asked for the
+    total only when the registry exports, so that carrying a count to the head
+    costs the hot path nothing."""
+
+    def __init__(self, name, read, description=""):
+        self._read = read
+        super().__init__(name, description)
+
+    def inc(self, value: float = 1.0, tags=None):
+        raise TypeError(f"{self.name} is a view: its owner counts")
+
+    def _export(self) -> dict:
+        return {"name": self.name, "type": self.TYPE, "description": self.description,
+                "values": {(): float(self._read())}}
 
 
 class Gauge(Metric):

@@ -492,9 +492,29 @@ def cluster_status() -> Dict[str, Any]:
     status["control_plane"] = cp
 
     # -- train
+    steps = int(counter_total("train_steps_total"))
+    interval = merged.get("train_step_interval_seconds")
     status["train"] = {
         "mfu": gauges("train_mfu"),
         "tokens_per_s": gauges("train_tokens_per_s"),
+        # the train loop's own clock (train/session.py), over the processes that run
+        # steps (they alone export these, their tasks and collector pauses too): steps, where the loop's thread spent a step (a synced loop waits for its
+        # loss in `user`), the tail of entry-to-entry step intervals, programs
+        # compiled, the collector's pauses, the way from fit() to the loop by phase
+        "steps": steps,
+        "loop_ms_per_step": {
+            lap: round(counter_total(f"train_loop_{lap}_ns_total") / steps / 1e6, 3)
+            for lap in ("dispatch", "report", "data", "user")} if steps else {},
+        "step_interval_p50_s": m.histogram_quantile(interval, 0.5) if interval else None,
+        "step_interval_p99_s": m.histogram_quantile(interval, 0.99) if interval else None,
+        "compiles": int(counter_total("compiles_total")),
+        "compile_s": round(counter_total("compile_ns_total") / 1e9, 3),
+        "gc_pause_ms": round(counter_total("gc_pause_ns_total") / 1e6, 3),
+        "gc_collections": int(counter_total("gc_collections_total")),
+        "worker_tasks": int(counter_total("worker_tasks_total")),
+        "setup_seconds": {dict(key).get("phase", "?"): round(v, 3) for key, v in
+                          merged.get("train_setup_seconds", {}).get("values", {}).items()},
+        "group_failures": int(counter_total("train_group_failures_total")),
         "step_phases_s": {
             dict(key).get("phase", "?"): round(v["sum"] / v["count"], 6)
             for key, v in merged.get("train_step_phase_seconds",

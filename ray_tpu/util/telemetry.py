@@ -71,6 +71,10 @@ _forced: Optional[bool] = None
 _lock = threading.Lock()
 _ring: deque = deque(maxlen=8192)
 _dropped = 0  # events lost to ring overflow since the last flush/drain
+# records made where `_lock` may already be held by the same thread (a `gc.callbacks`
+# hook runs wherever the interpreter stops, also inside `_append` and `drain`): a plain
+# list, whose append is atomic, spliced into the ring by the next `_append` or `drain`
+_deferred: List[dict] = []
 _flush_thread: Optional[threading.Thread] = None
 _clock_offset_ns: Optional[int] = None  # head_clock - local_clock (workers)
 
@@ -183,13 +187,23 @@ def _tag_trace(args: Dict[str, Any]) -> Dict[str, Any]:
     return args
 
 
-def _append(rec: dict) -> None:
+def _push_locked(rec: dict) -> None:
     global _dropped
+    if len(_ring) == _ring.maxlen:
+        _dropped += 1
+    _ring.append(rec)
+
+
+def _splice_deferred_locked() -> None:
+    while _deferred:  # a collection may add one while this runs: pop, never swap
+        _push_locked(_deferred.pop(0))
+
+
+def _append(rec: dict) -> None:
     with _lock:
         _resize_ring_locked()
-        if len(_ring) == _ring.maxlen:
-            _dropped += 1
-        _ring.append(rec)
+        _splice_deferred_locked()
+        _push_locked(rec)
     _ensure_flush_thread()
 
 
@@ -233,7 +247,7 @@ class _Span:
             _tag_trace(self.args)
         self._t0_wall = time.time_ns()
         if self._note_cls is not None:
-            self._note = self._note_cls(self.name)
+            self._note = self._note_cls(self.name, **_scalars(self.args))
             self._note.__enter__()
         self._t0_perf = time.perf_counter_ns()
         return self
@@ -252,6 +266,23 @@ class _Span:
             "dur_ns": dur, "tid": threading.current_thread().name,
             "args": self.args,
         })
+
+
+def _scalars(args: Dict[str, Any]) -> Dict[str, Any]:
+    """What of a span's attributes an annotation can carry as its stats."""
+    return {k: v for k, v in args.items() if isinstance(v, (str, int, float, bool))}
+
+
+def annotate(name: str, **args: Any):
+    """An ENTERED profiler annotation while a profile records in this process (its
+    holder calls `__exit__(None, None, None)`), else None: for a region whose two ends
+    are two calls, as a `gc.callbacks` function sees a collection. No ring entry."""
+    note_cls = _profiling()
+    if note_cls is None:
+        return None
+    note = note_cls(name, **_scalars(args))
+    note.__enter__()
+    return note
 
 
 class _NoopSpan:
@@ -296,6 +327,139 @@ def complete(name: str, cat: str, start_wall_ns: int, dur_ns: int,
     })
 
 
+def complete_deferred(name: str, cat: str, start_wall_ns: int, dur_ns: int,
+                      **args: Any) -> None:
+    """`complete` for a caller that may run while its own thread holds `_lock`
+    (the garbage collector's callback): takes no lock, starts no thread and
+    reads no contextvar. The record waits in `_deferred` for the next
+    `_append` or `drain`."""
+    if not enabled():
+        return
+    _deferred.append({
+        "name": name, "cat": cat, "ts_ns": int(start_wall_ns),
+        "dur_ns": int(dur_ns), "tid": threading.current_thread().name,
+        "args": args,
+    })
+
+
+# ------------------------------------------------------------- laps of a loop
+
+class LapClock:
+    """Which phase of its turn ONE thread's loop is in. enter(i) ends the lap
+    that was open and begins `names[i]` at the same instant, so every
+    nanosecond of the thread lies in exactly one lap (time the thread waits to
+    run, say for the interpreter lock after it woke other threads, goes to
+    the lap it was in). Each lap is a span (ring + profiler annotation) and,
+    tracing on or off, adds its duration to `totals[counters[i]]`: monotonic
+    integers that a `metrics()` hands out, to be read before and after a
+    window. `totals` may be the owner's own dict of counters (the keys must be
+    there). The loop is one thread: no lock."""
+
+    __slots__ = ("names", "counters", "cat", "totals", "lap", "_span", "_t0")
+
+    def __init__(self, names, counters, cat: str,
+                 totals: Optional[Dict[str, int]] = None):
+        self.names, self.counters, self.cat = tuple(names), tuple(counters), cat
+        self.totals = dict.fromkeys(self.counters, 0) if totals is None else totals
+        self.lap: Optional[int] = None  # the open lap's index
+        self._span, self._t0 = None, 0
+
+    def read(self) -> Dict[str, int]:
+        """The totals with the open lap counted up to now. Exact from the loop's own
+        thread; another thread's read may be off by the lap that changes under it."""
+        out, i, t0 = dict(self.totals), self.lap, self._t0
+        if i is not None:
+            out[self.counters[i]] += time.perf_counter_ns() - t0
+        return out
+
+    def enter(self, i: Optional[int]) -> int:
+        """-> the instant of the change, on `perf_counter_ns`' clock."""
+        now = time.perf_counter_ns()
+        if self.lap is not None:
+            self.totals[self.counters[self.lap]] += now - self._t0
+            self._span.__exit__(None, None, None)
+        self.lap = i
+        if i is None:  # the loop ends
+            self._span = None
+            return now
+        self._t0 = now
+        self._span = span(self.names[i], self.cat)
+        self._span.__enter__()
+        return now
+
+
+# The lap a dataset's iterator (data/iterator.py) opens in the loop of the thread that
+# asks it for a batch; the train loop's clock (train/session.py) has a lap of this name.
+DATA_LAP = "train.loop.data"
+_thread = threading.local()  # .clock: the LapClock of the loop this thread runs
+
+
+def set_thread_clock(clock: Optional[LapClock]) -> None:
+    """Say that the calling thread runs the loop `clock` times, so that code
+    the loop calls into (a dataset's iterator) can open a lap of it by name."""
+    _thread.clock = clock
+
+
+class _Lap:
+    __slots__ = ("_clock", "_i", "_back")
+
+    def __init__(self, clock: LapClock, i: int):
+        self._clock, self._i = clock, i
+
+    def __enter__(self) -> "_Lap":
+        self._back = self._clock.lap
+        if self._back != self._i:  # the lap inside itself (iter_jax_batches around
+            self._clock.enter(self._i)  # iter_batches) is the lap that is open
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._back != self._i:
+            self._clock.enter(self._back)
+
+
+def lap(name: str):
+    """Context manager: the lap `name` of the calling thread's loop around the
+    block, after which the lap that was open goes on. The shared no-op where
+    the thread runs no loop that knows the name."""
+    clock = getattr(_thread, "clock", None)
+    if clock is None or name not in clock.names:
+        return _NOOP
+    return _Lap(clock, clock.names.index(name))
+
+
+# ------------------------------------------------------ compiles of a process
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# programs this PROCESS compiled (or read from the compile cache) and the
+# seconds that took: JAX reports them to process-wide listeners that cannot be
+# taken off again, so one listener and one count serve every engine and every
+# train step here
+_COMPILES = {"compiles_total": 0, "compile_ns_total": 0}
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def compile_counters() -> Dict[str, int]:
+    """The process's `compiles_total` / `compile_ns_total`, live (read, do not
+    write). Registers the `jax.monitoring` listener at the first call, once a
+    process; imports JAX, so call it from code that runs programs."""
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if _compile_listener_on:
+            return _COMPILES
+        _compile_listener_on = True
+    import jax
+
+    def on_duration(event: str, duration_secs: float, **_kw) -> None:
+        if event == COMPILE_EVENT:
+            with _compile_listener_lock:
+                _COMPILES["compiles_total"] += 1
+                _COMPILES["compile_ns_total"] += int(duration_secs * 1e9)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return _COMPILES
+
+
 # ------------------------------------------------------------------- draining
 
 def drain() -> List[dict]:
@@ -303,6 +467,7 @@ def drain() -> List[dict]:
     by util/state for the in-process driver's ring."""
     global _dropped
     with _lock:
+        _splice_deferred_locked()
         out = list(_ring)
         _ring.clear()
         n_dropped, _dropped = _dropped, 0
@@ -326,7 +491,7 @@ def drain() -> List[dict]:
 
 def pending() -> int:
     with _lock:
-        return len(_ring)
+        return len(_ring) + len(_deferred)
 
 
 # -------------------------------------------------------------------- flushing
@@ -416,7 +581,8 @@ def _ensure_flush_thread() -> None:
         while True:
             time.sleep(_flush_interval())
             try:
-                flush()
+                with span("worker.flush_telemetry", "worker"):
+                    flush()
             # graftlint: allow[swallowed-exception] degrades to the coded fallback (return) by design
             except Exception:
                 return
@@ -462,6 +628,20 @@ def get_gauge(name: str, description: str = "", tag_keys=None):
 def get_histogram(name: str, description: str = "", tag_keys=None,
                   boundaries=None):
     return _get_metric("histogram", name, description, tag_keys, boundaries)
+
+
+def export_counters(read, names, description: str = "") -> None:
+    """Carry monotonic integers that live outside the registry into it: one
+    counter a name, read as `read()[name]` whenever the registry exports (a
+    worker's push, the driver's state API). Once a name: a second call with a
+    name that is already there leaves it."""
+    from ray_tpu.util import metrics as rm
+
+    with _metric_cache_lock:
+        for name in names:
+            if name not in _metric_cache:
+                _metric_cache[name] = rm.CounterView(
+                    name, lambda name=name: read()[name], description)
 
 
 def _get_metric(kind: str, name: str, description: str, tag_keys,

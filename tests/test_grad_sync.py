@@ -92,6 +92,20 @@ def test_bucketed_matches_monolithic_bit_exact(env):
     env["bucketed_step"] = step  # reused by the HLO overlap test (one compile)
 
 
+def test_a_sync_step_is_counted_like_the_stock_one(env):
+    # the loop's `dispatch` lap and `train_steps_total` (train/session.py) around its call
+    from ray_tpu.train import metrics as train_metrics
+
+    step = env.get("bucketed_step") or make_train_step(
+        env["cfg"], env["tx"], donate=False, sync=GradSyncConfig(mode="bucketed"))
+    before = train_metrics()
+    with use_mesh(env["mesh"]):
+        step(env["state"], env["batch"])
+    after = train_metrics()
+    assert after["train_steps_total"] == before["train_steps_total"] + 1
+    assert after["train_loop_dispatch_ns_total"] > before["train_loop_dispatch_ns_total"]
+
+
 def test_bucket_boundaries_do_not_change_result(env):
     # tiny buckets: every leaf its own collective, boundaries cross odd
     # shapes and scalar-adjacent leaves; reference = the monolithic step
