@@ -219,13 +219,14 @@ def _maybe_remat(body, cfg: ModelConfig):
     # the rotated q and k (ops/flash_attention.py names them) are kept beside the matmul
     # outputs: the backward of the projections needs dQ and dK, never their own output,
     # so the un-rotated pair is dropped for them and the rotation is not run again.
-    # What an expert layer chose (moe.route names it) is kept under every policy: a
-    # recomputed forward pass must not choose again.
+    # What an expert layer's router made (moe.route names it: the choice, the scores, the
+    # chosen scores) is kept under every policy: a recomputed forward pass must neither
+    # choose nor score again.
     from . import moe as _moe
 
     policies = jax.checkpoint_policies
-    chosen = policies.save_only_these_names(_moe.CHOSEN_NAME)
-    kept = policies.save_only_these_names(*ROTATED_NAMES, _moe.CHOSEN_NAME)
+    routed = policies.save_only_these_names(*_moe.ROUTER_NAMES)
+    kept = policies.save_only_these_names(*ROTATED_NAMES, *_moe.ROUTER_NAMES)
     if policy == "dots":
         return jax.checkpoint(
             body, policy=policies.save_from_both_policies(policies.checkpoint_dots, kept))
@@ -235,7 +236,7 @@ def _maybe_remat(body, cfg: ModelConfig):
     if policy != "full":
         raise ValueError(
             f"unknown remat_policy {policy!r} (expected full | dots | dots_no_batch | none)")
-    return jax.checkpoint(body, policy=chosen)
+    return jax.checkpoint(body, policy=routed)
 
 
 # A row shorter than this is looked up with a gather whatever the mesh: the

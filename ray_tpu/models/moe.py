@@ -148,7 +148,14 @@ def moe_mlp(
 # behind them belong to no group.
 
 
-CHOSEN_NAME = "experts_chosen"  # what `route` chose, by the name remat policies keep it under
+# What the router made in the forward pass, by the names every remat policy keeps it
+# under (`llama._maybe_remat`): the experts chosen, every score, the chosen scores
+# (`route`), and the count of the choice an expert (`expert_layer`).
+CHOSEN_NAME = "experts_chosen"
+SCORES_NAME = "router_scores"
+PICKED_NAME = "router_picked"
+LOAD_NAME = "router_load"
+ROUTER_NAMES = (CHOSEN_NAME, SCORES_NAME, PICKED_NAME, LOAD_NAME)
 
 # Rows of a tile of the TPU compiler's grouped kernels (`ragged-dot-none`: its metadata
 # has a tile for every 512 rows of the buffer and one more a group); a window is whole tiles.
@@ -162,7 +169,22 @@ def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: Mod
     chosen SCORES (the bias selects and never weights), normalised over the k, times
     moe_route_scale. (Against a float32 reference 2 % of tokens choose another expert at
     8,192 positions in bfloat16, all by the activations' rounding: a bfloat16 router
-    chose the same experts to the token on the chip, PERF.md section 6, PR 31.)"""
+    chose the same experts to the token on the chip, PERF.md section 6, PR 31.)
+
+    Scoring and choosing have a backward rule of their own (`_score_and_pick`), which
+    keeps what the forward pass made, under `ROUTER_NAMES`: the choice, the scores
+    [T, E] and the chosen scores [T, k]. The choice is a decision, not arithmetic: a
+    forward pass recomputed in the backward pass rounds its bfloat16 activations
+    otherwise where XLA fuses it otherwise, and 0.5 % of the MTP block's assignments,
+    two scores within a rounding, then went to other experts in the gradient than in
+    the loss (its experts' gradients were 6-8 % off on the chip, PERF.md section 6,
+    PR 31). The scores are kept so that the gradient weighs what the loss weighed and
+    the backward pass of a rematerialised layer runs no score product, sigmoid, `top_k`
+    or pick again (with the pick's gradient in one pass, 8 of the router's 24.5 ms a step
+    at 22 of 512: PERF.md section 6, PR 36).
+    A name on the scores alone does not do that under plain differentiation:
+    `jax.nn.sigmoid`'s own derivative rule keeps ITS output, the value before the name,
+    which no policy can save, and the product is made again for it."""
     if cfg.moe_scoring != "sigmoid":
         raise NotImplementedError(
             f"the dropless layer scores by sigmoid; {cfg.moe_scoring!r} is the capacity path's (moe_mlp)")
@@ -172,39 +194,72 @@ def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: Mod
     if not cfg.moe_norm_topk:
         raise NotImplementedError(
             "gates that are not normalised over the chosen experts (norm_topk_prob false)")
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w.astype(jnp.float32),
-                        precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    choose = scores if bias is None else scores + jax.lax.stop_gradient(bias)[None, :]
-    _, idx = jax.lax.top_k(choose, cfg.moe_top_k)
-    # A decision, not arithmetic: rematerialisation keeps it (llama._maybe_remat). A
-    # forward pass recomputed in the backward pass rounds its bfloat16 activations
-    # otherwise where XLA fuses it otherwise, and 0.5 % of the MTP block's assignments,
-    # two scores within a rounding, then went to other experts in the gradient than in
-    # the loss: its experts' gradients were 6-8 % off on the chip (PERF.md section 6, PR 31).
-    idx = checkpoint_name(idx.astype(jnp.int32), CHOSEN_NAME)
-    gates = _chosen_scores(scores, idx)
+    idx, gates = _score_and_pick(x, router_w, bias, cfg.moe_top_k)
     gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
     return idx, gates * cfg.moe_route_scale
 
 
 # A mask of [T, k, E] elements is one fused pass where it is small (4 of 64 at 8,192
-# tokens: 2 M) and not where 22 are chosen of 512 (92 M, 369 MB in float32, a layer, and
-# again in the backward pass): beyond this many elements the k slots are taken one at a
-# time, [T, E] each, and no operand of the program has all three extents.
+# tokens: 2 M) and not where 22 are chosen of 512 (92 M, 369 MB in float32, a layer):
+# beyond this many elements the k slots are taken one at a time, [T, E] each, and no
+# operand of the program has all three extents.
 _MASK_ELEMENTS = 1 << 22
 
 
 def _chosen_scores(scores: jax.Array, idx: jax.Array) -> jax.Array:
-    """scores [T, E], idx [T, k] -> the chosen scores [T, k], picked by a mask:
-    take_along_axis would transpose to a scatter."""
+    """scores [T, E], idx [T, k] -> the chosen scores [T, k], picked by a mask (the
+    forward pass only: `_score_and_pick`'s backward rule writes the transpose itself)."""
     lanes = jnp.arange(scores.shape[-1])
     if idx.size * scores.shape[-1] <= _MASK_ELEMENTS:
         return jnp.sum(scores[:, None, :] * (idx[..., None] == lanes), -1)
-    # (rematerialised: the backward pass makes a slot's mask again and keeps none)
-    _, picked = jax.lax.scan(jax.checkpoint(
-        lambda _, slot: (None, jnp.sum(jnp.where(slot[:, None] == lanes, scores, 0), -1))), None, idx.T)
+    _, picked = jax.lax.scan(
+        lambda _, slot: (None, jnp.sum(jnp.where(slot[:, None] == lanes, scores, 0), -1)), None, idx.T)
     return picked.T
+
+
+def _router_product(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
+    return jnp.einsum(eq, a.astype(jnp.float32), b.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _score_and_pick_fwd(x, router_w, bias, k: int):
+    scores = jax.nn.sigmoid(_router_product("td,de->te", x, router_w))
+    _, idx = jax.lax.top_k(scores if bias is None else scores + bias[None, :], k)
+    idx = checkpoint_name(idx.astype(jnp.int32), CHOSEN_NAME)
+    scores = checkpoint_name(scores, SCORES_NAME)
+    picked = checkpoint_name(_chosen_scores(scores, idx), PICKED_NAME)
+    return (idx, picked), (x, router_w, scores, idx)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _score_and_pick(x, router_w, bias, k: int):
+    """x [T, D], router_w [D, E], bias [E] or None -> (the k experts of the largest
+    score + bias [T, k] int32, their scores [T, k] f32)."""
+    return _score_and_pick_fwd(x, router_w, bias, k)[0]
+
+
+def _score_and_pick_bwd(k, kept, cotangents):
+    """The pick's transpose in one pass: a token's k experts are distinct, so a lane of
+    its row of d_scores takes its value from at most one slot (a select a slot over
+    [T, E], fused; no loop, no accumulator, no scatter); then the sigmoid's derivative
+    from the kept scores and the two products. The bias selects: its gradient is zero."""
+    x, router_w, scores, idx = kept
+    d_picked = cotangents[1]
+    lanes = jnp.arange(scores.shape[-1])
+    d_scores = jnp.zeros_like(scores)
+    for slot in range(k):
+        d_scores = jnp.where(idx[:, slot, None] == lanes, d_picked[:, slot, None], d_scores)
+    # written once ([T, E] f32, 0.11 ms at 22 of 512): left to itself XLA fuses the k
+    # selects into BOTH products as their producer and makes them again for every tile
+    # of each product's other extent (+0.39 and +0.20 ms a layer on the chip, PERF.md
+    # section 6, PR 36)
+    d_logits = jax.lax.optimization_barrier(d_scores * scores * (1 - scores))
+    dx = _router_product("te,de->td", d_logits, router_w).astype(x.dtype)
+    dw = _router_product("td,te->de", x, d_logits).astype(router_w.dtype)
+    return dx, dw, None
+
+
+_score_and_pick.defvjp(_score_and_pick_fwd, _score_and_pick_bwd)
 
 
 def expert_load(idx: jax.Array, n_experts: int) -> jax.Array:
@@ -446,7 +501,9 @@ def expert_layer(x: jax.Array, lp, cfg: ModelConfig):
     rows = window_rows(cfg, x.shape[0])
     with jax.named_scope("moe_router"):
         idx, gates = route(x, lp["router"], lp.get("router_bias"), cfg)
-        load = expert_load(idx, cfg.n_experts)
+        # kept like the choice it counts: the windows' ends in the backward pass are read
+        # from it, and a rematerialised layer would count the k slots again for them
+        load = checkpoint_name(expert_load(idx, cfg.n_experts), LOAD_NAME)
     full = x
     if cfg.moe_latent_dim:
         with jax.named_scope("moe_latent"):

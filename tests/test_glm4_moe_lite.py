@@ -370,6 +370,75 @@ def test_remat_keeps_what_the_experts_chose(capsys):
         assert f"i32[2,32,{cfg.moe_top_k}] output of scan" in saved, policy  # the stack's, a row a layer
 
 
+def _highest_products(jaxpr):
+    """(operand shapes, result shape) of every product a program asks for at the highest
+    precision by name, nested programs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and eqn.params.get("precision") == (jax.lax.Precision.HIGHEST,) * 2:
+            found.append(([v.aval.shape for v in eqn.invars], eqn.outvars[0].aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _highest_products(sub)
+    return found
+
+
+@pytest.mark.parametrize("policy", ["full", "dots", "dots_no_batch"])
+def test_a_rematerialised_expert_layer_scores_once_and_keeps_what_the_router_made(policy, capsys):
+    """The gradient of one expert layer under `_maybe_remat`: the router's three products
+    at the highest precision (scores, dx, the weight's gradient) and no fourth, the
+    scores made again; what the policy saves of the layer is what the router made, under
+    its four names (`moe.ROUTER_NAMES`), and under `full` nothing else but arguments."""
+    cfg = dataclasses.replace(CFG, remat=True, remat_policy=policy)
+    lp = moe.init_expert_weights(jax.random.PRNGKey(0), cfg)
+    lp["router_bias"] = 0.05 * jax.random.normal(jax.random.PRNGKey(5), lp["router_bias"].shape)
+    t, d, e, k = 32, cfg.d_model, cfg.n_experts, cfg.moe_top_k
+    x = jax.random.normal(jax.random.PRNGKey(1), (t, d))
+    cot = jax.random.normal(jax.random.PRNGKey(2), (t, d))
+
+    def layer(x, lp):
+        return jnp.sum(moe.expert_layer(x, lp, cfg)[0] * cot)
+
+    body = llama._maybe_remat(layer, cfg)
+    with jax.default_matmul_precision("default"):  # (this file's tests run at the highest: not here)
+        products = _highest_products(jax.make_jaxpr(jax.grad(body, argnums=(0, 1)))(x, lp).jaxpr)
+    assert sorted(result for _, result in products) == sorted([(t, e), (t, d), (d, e)]), products
+    jax.ad_checkpoint.print_saved_residuals(body, x, lp)
+    saved = [ln for ln in capsys.readouterr().out.splitlines()
+             if "from the argument" not in ln and "from a constant" not in ln]  # (a constant: `cot`)
+    of_the_router = [f"i32[{t},{k}] named 'experts_chosen'", f"f32[{t},{e}] ", f"f32[{t},{k}] ", f"f32[{e}] "]
+    for shape in of_the_router:
+        assert sum(ln.startswith(shape) for ln in saved) == 1, (shape, saved)
+    if policy == "full":
+        assert len(saved) == len(of_the_router), saved
+    # and the gradient is the plain layer's
+    value, grads = jax.value_and_grad(body, argnums=(0, 1))(x, lp)
+    want, plain = jax.value_and_grad(layer, argnums=(0, 1))(x, lp)
+    np.testing.assert_allclose(value, want, rtol=1e-6)
+    for got, ref_ in zip(jax.tree.leaves(grads), jax.tree.leaves(plain)):
+        np.testing.assert_allclose(got, ref_, atol=1e-6 * max(float(jnp.abs(ref_).max()), 1e-30))
+
+
+@pytest.mark.parametrize("family", ["glm-tiny", "nemotron-tiny"])
+def test_every_remat_policy_weighs_what_the_forward_pass_weighed(family):
+    """Loss and every leaf's gradient of a tiny model of each family under remat `full`,
+    `dots` and `none` agree to rounding: the backward pass weighs the experts with the
+    scores the forward pass made (kept by name), whatever is made again around them."""
+    base = get_config(family)
+    p, t = llama.init(jax.random.PRNGKey(0), base), _tokens(base)
+    results = {}
+    for policy in ("none", "full", "dots"):
+        cfg = dataclasses.replace(base, remat=policy != "none", remat_policy=policy)
+        results[policy] = jax.jit(jax.value_and_grad(lambda p: llama.loss_fn(p, {"tokens": t}, cfg)[0]))(p)
+    loss, grads = results["none"]
+    assert len(jax.tree.leaves(grads)) >= 20
+    for policy in ("full", "dots"):
+        np.testing.assert_allclose(results[policy][0], loss, rtol=1e-6, err_msg=policy)
+        for (path, got), want in zip(jax.tree_util.tree_flatten_with_path(results[policy][1])[0],
+                                     jax.tree.leaves(grads)):
+            np.testing.assert_allclose(got, want, atol=2e-6 * float(jnp.abs(want).max()) + 1e-12,
+                                       err_msg=f"{policy} {jax.tree_util.keystr(path)}")
+
+
 # ------------------------------------------------------------------ stacks and slices
 
 def test_leading_and_following_stacks():

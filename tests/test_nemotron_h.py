@@ -263,6 +263,68 @@ def test_route_at_22_of_512_is_the_references_choice_and_gates():
     np.testing.assert_array_equal(moe.expert_load(small, 512), np.bincount(np.asarray(small).ravel(), minlength=512))
 
 
+def _plain_gates(x, w, bias, idx, scale):
+    """`route`'s formula written plainly on a given choice, in whatever type it is handed."""
+    scores = jax.nn.sigmoid(jnp.matmul(x, w, precision=jax.lax.Precision.HIGHEST))
+    picked = jnp.take_along_axis(scores, idx, -1)
+    return scale * picked / (picked.sum(-1, keepdims=True) + 1e-20) + 0 * bias.sum()
+
+
+@pytest.mark.parametrize("scores", ["random", "ties"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("form", ["slots_22_of_512", "mask_4_of_64"])
+def test_routes_backward_rule_is_the_gradient_of_the_plain_formula(form, dtype, scores):
+    """`moe.route` differentiates by a rule of its own (it keeps the scores it made and
+    writes the pick's gradient in one pass): against plain differentiation of the same
+    formula (`take_along_axis`) on the same choice, for both forms of the forward pick,
+    with float32 and bfloat16 activations, float64 as the yardstick. `ties`: triples
+    of experts with equal weights (equal scores in every token, and k is no multiple of
+    3, so every token's k-th and (k+1)-th scores are equal and the choice cuts a tie),
+    and tokens repeated."""
+    n_experts, k, tokens = (512, 22, 384) if form.startswith("slots") else (64, 4, 96)
+    cfg = dataclasses.replace(ROUTED, n_experts=n_experts, moe_top_k=k)
+    assert (tokens * k * n_experts > moe._MASK_ELEMENTS) == form.startswith("slots")
+    x = jax.random.normal(jax.random.PRNGKey(0), (tokens, 64))
+    w = jax.random.normal(jax.random.PRNGKey(1), (64, n_experts)) * 0.125
+    bias = 0.02 * jax.random.normal(jax.random.PRNGKey(2), (n_experts,))
+    if scores == "ties":
+        first = jnp.arange(n_experts) // 3 * 3  # experts 3j, 3j + 1, 3j + 2 score alike everywhere
+        w, bias = w[:, first], bias[first]
+        x = x.at[tokens // 2:].set(x[:tokens // 2])
+    x = x.astype(dtype)
+    cot = jax.random.normal(jax.random.PRNGKey(3), (tokens, k))
+
+    def mine(x, w, bias):
+        idx, gates = moe.route(x, w, bias, cfg)
+        return jnp.sum(gates * cot), idx
+
+    (_, idx), (dx, dw, db) = jax.jit(jax.value_and_grad(mine, argnums=(0, 1, 2), has_aux=True))(x, w, bias)
+    assert dx.dtype == x.dtype and dw.dtype == w.dtype and not np.asarray(db).any()
+    assert all(len(set(row)) == k for row in np.asarray(idx).tolist())
+    if scores == "ties":  # k is no multiple of 3: every token's choice cuts a triple of equal scores
+        assert all(np.bincount(np.asarray(row) // 3).max() == 3 and set(np.bincount(np.asarray(row) // 3)) > {0, 3}
+                   for row in np.asarray(idx))
+
+    def plain(x, w, bias):
+        return jnp.sum(_plain_gates(x.astype(w.dtype), w, bias, idx, cfg.moe_route_scale) * cot.astype(w.dtype))
+
+    p_dx, p_dw, p_db = jax.jit(jax.grad(plain, argnums=(0, 1, 2)))(x, w, bias)
+    assert not np.asarray(p_db).any()
+    with jax.enable_x64(True):
+        exact = jax.grad(plain, argnums=(0, 1))(
+            np.asarray(x.astype(jnp.float32), np.float64), np.asarray(w, np.float64), np.asarray(bias, np.float64))
+    for name, got, same_type, want in (("dx", dx, p_dx, exact[0]), ("dw", dw, p_dw, exact[1])):
+        want = np.asarray(want)
+        scale = np.abs(want).max()
+        assert scale > 0, name
+        err = np.abs(np.asarray(got.astype(jnp.float32), np.float64) - want).max() / scale
+        yardstick = np.abs(np.asarray(same_type.astype(jnp.float32), np.float64) - want).max() / scale
+        # float32 roundings (dx in bfloat16: one rounding of the result), and no further
+        # from float64 than plain differentiation in the same types is
+        assert err <= (2 ** -8 if got.dtype == jnp.bfloat16 else 2e-6), (name, err)
+        assert err <= 1.5 * yardstick + 1e-7, (name, err, yardstick)
+
+
 def test_the_compiled_layer_has_no_operand_of_tokens_by_k_by_experts_and_no_scatter():
     """22 of 512 at 384 tokens: value and every gradient of the layer. No shape in the
     program has the extents of tokens, k and experts together (as a mask [T, k, E] has,

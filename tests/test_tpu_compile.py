@@ -144,22 +144,28 @@ def _glm_share():
 _LAYER_TEXTS = {}
 
 
-def _expert_layer_text(cfg, one_chip):
-    """The compiled text of an expert layer's value and every gradient at 8,192 tokens,
+def _expert_layer_text(cfg, one_chip, remat=False):
+    """The compiled text of an expert layer's value and every gradient at 8,192 tokens
+    (`remat`: rematerialised under the configuration's policy, as a model's layer is),
     made once a configuration (under `on_tpu`, which every caller has)."""
-    from ray_tpu.models import moe
+    from ray_tpu.models import llama, moe
 
-    if cfg.name not in _LAYER_TEXTS:
+    if (cfg.name, remat) not in _LAYER_TEXTS:
         lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
                      one_chip)
         x = jax.ShapeDtypeStruct((8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
 
-        def loss(x, lp):
-            return jnp.sum(moe.expert_layer(x, lp, cfg)[0].astype(jnp.float32))
+        def layer(x, lp):
+            return moe.expert_layer(x, lp, cfg)[0]
 
-        _LAYER_TEXTS[cfg.name] = (
-            set(lp), jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text())
-    return _LAYER_TEXTS[cfg.name]
+        def loss(x, lp, cot):
+            with jax.named_scope("model"):  # as train/step.py: the first name inside `grad` is written jvp(..)
+                y = (llama._maybe_remat(layer, cfg) if remat else layer)(x, lp)
+                return jnp.sum((y * cot).astype(jnp.float32))
+
+        _LAYER_TEXTS[cfg.name, remat] = (
+            set(lp), jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp, x).compile().as_text())
+    return _LAYER_TEXTS[cfg.name, remat]
 
 
 def _grouped_kernels(text):
@@ -266,6 +272,73 @@ def test_the_combine_follows_the_windows_rows_in_both_cells(one_chip, on_tpu, ce
         assert not [s for s in gathered if re.match(rf"\w+\[({8192 * k}|8192)[,\]]", s)], gathered
     assert not re.search(r" scatter\(", text)
     assert not re.search(rf"\[{8192 * k},\d+\]", text)
+
+
+def _instructions(text, op, scope=None):
+    """The program's instructions of kind `op`, fused or not (under `scope`, by `op_name`)."""
+    return [ln for ln in text.splitlines() if re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = .*?[\])}}] {op}\(", ln)
+            and (scope is None or re.search(rf'op_name="[^"]*/{scope}/', ln))]
+
+
+@pytest.mark.parametrize("cell,loops", [("nemotron", 2), ("glm", 0)])
+def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tpu, cell, loops):
+    """An expert layer of each family cell under remat `full` (as the cells run it), value
+    and every gradient at 8,192 tokens, compiled for the described chip (PERF.md section
+    6, PR 36): the router's products are three (the scores once, `[T, E]`; dx; the
+    weight's gradient), where a backward pass that scores again has four; at 22 of 512
+    the only loops under `moe_router` are the forward pick's and the count's (the pick
+    made again, its backward slot by slot into an accumulator and the count made again
+    were three more) and no operand has the extents of tokens, k and experts together
+    (4 of 64 picks by one fused mask of 2 M elements in the forward pass, as it did)."""
+    from ray_tpu.models import moe
+
+    cfg = {"nemotron": _nemotron_share, "glm": _glm_share}[cell]()
+    assert cfg.remat and cfg.remat_policy == "full"
+    _, text = _expert_layer_text(cfg, one_chip, remat=True)
+    t, k, e = 8192, cfg.moe_top_k, cfg.n_experts
+    products = _instructions(text, "convolution", "moe_router")
+    assert len(products) == 3, products
+    assert sum(f" = f32[{t},{e}]" in ln for ln in products) == 1, products
+    assert len(_instructions(text, "while", "moe_router")) == loops
+    if t * k * e > moe._MASK_ELEMENTS:
+        assert not re.search(rf"\[({t},{k},{e}|{k},{t},{e}|{t},{e},{k}|{t * k},{e})\]", text)
+    assert not re.search(r" scatter\(", text)
+
+
+@pytest.mark.parametrize("config,bodies,loops,temp_gb", [
+    ("nemotron-3-super-train-tp8-ep64", 5, 2, 3.83),  # (a period of layers, unrolled: a body each)
+    ("glm-4.7-flash-train-ep8", 2, 0, 6.01)])  # (the scan over four layers has one body; the MTP module)
+def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on_tpu, config, bodies,
+                                                                    loops, temp_gb):
+    """The whole step of each family cell as its configuration file states it, compiled for
+    the described chip: three router products an expert layer and the forward pass's loops
+    only (the test above, in the step); what the router keeps from forward to backward
+    (88 MB in the Nemotron cell, 10.5 MB in GLM's) leaves the temporaries within 0.15 GB of
+    what they were before it did (PR 35's programs: 3.83 and 6.01 GB), and XLA
+    rematerialises nothing of its own to fit (PERF.md section 7, after PR 26 (2))."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks.lib import modelcfg
+    from ray_tpu.models import llama
+    from ray_tpu.train import make_optimizer, make_train_step
+    from ray_tpu.train.step import TrainState
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", f"{config}.json")) as f:
+        file = json.load(f)
+    cfg, trainer = modelcfg.model_config(modelcfg.model_keys(file)), file["trainer"]
+    assert cfg.remat and cfg.remat_policy == "full" and trainer["mesh"] is None
+    tx = make_optimizer(**trainer["optimizer"])
+    params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
+    opt_state = _shapes(jax.eval_shape(tx.init, params), one_chip)
+    state = TrainState(step=_scalar(one_chip), params=params, opt_state=opt_state)
+    batch = {"tokens": jax.ShapeDtypeStruct((trainer["batch"], trainer["seq"] + 1), jnp.int32, sharding=one_chip)}
+    compiled = make_train_step(cfg, tx).lower(state, batch).compile()
+    text = compiled.as_text()
+    assert len(_instructions(text, "convolution", "moe_router")) == 3 * bodies
+    assert len(_instructions(text, "while", "moe_router")) == loops * bodies
+    assert not re.search(r"^\s*(?:ROOT )?%[\w.\-]*\.remat", text, re.M)
+    assert compiled.memory_analysis().temp_size_in_bytes < (temp_gb + 0.15) * 1e9
 
 
 def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
