@@ -191,6 +191,8 @@ def _served(cfg):
         (cfg.mtp_depth, "multi-token-prediction modules as drafts of the verify window"),
         (cfg.layer_pattern, "a pattern of single-part layers under decode: recurrent state and "
                             "convolution tails a slot beside the KV cache (cache manager, snapshots, prefix cache)"),
+        (cfg.kda_n_heads, "a delta-rule state [heads, 128, 128] a slot and layer (models/kda.py keeps none)"),
+        (cfg.attn_output_gate, "the attention output gate in the decode window (llm/model_runner.py)"),
     ) if has]
     if missing:
         raise NotImplementedError(

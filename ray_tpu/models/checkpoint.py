@@ -84,6 +84,8 @@ def config_from_hf(source_dir: str, **overrides) -> ModelConfig:
         fields.update(_glm4_moe_lite_fields(hf))
     if hf.get("model_type") == "nemotron_h":
         fields.update(_nemotron_h_fields(hf))
+    if hf.get("model_type") == "solar_open2":
+        fields.update(_solar_open2_fields(hf))
     fields.update(overrides)
     return ModelConfig(**fields)
 
@@ -143,6 +145,50 @@ def _nemotron_h_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
             moe_scoring="sigmoid", moe_select_bias=True,
             moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)))
     return fields
+
+
+def _solar_open2_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `solar_open2` keys (Solar-Open2) as ModelConfig fields. A published layer is a
+    token mixer then an expert part, each behind its own norm and residual: two characters
+    of the layer pattern, `*E` in `gqa_layers` (softmax attention without positions, with
+    an output gate) and `KE` elsewhere (Kimi Delta Attention), so n_layers counts parts.
+    Everything is held; a share is an override (`kda_n_heads`, `attn_heads_held`,
+    `experts_held`). What the program does not run is refused by name. What config.json
+    does not state (the low-rank projections' rank, the gate's form, the router's family)
+    is benchmarks/configs/solar-open2-train-tp8-ep40.json's `assumed`, one field each.
+    Weights' names are not mapped."""
+    linear = hf.get("linear_attn_config") or {}
+    refused = [what for has, what in (
+        (hf.get("use_rope", False), "rotated attention layers (use_rope true)"),
+        (hf.get("kda_use_full_proj", False), "full-rank decay and gate projections (kda_use_full_proj true)"),
+        (hf.get("first_k_dense_replace", 0), "leading dense layers (first_k_dense_replace > 0)"),
+        (not hf.get("norm_topk_prob", True), "gates that are not normalised (norm_topk_prob false)"),
+        (hf.get("n_group", 1) != 1 or hf.get("topk_group", 1) != 1,
+         "group-limited routing (n_group / topk_group > 1)"),
+        (linear.get("num_kv_heads") not in (None, linear.get("num_heads")),
+         "Kimi-Delta-Attention layers whose keys have fewer heads than their values (num_kv_heads)"),
+        (linear.get("head_dim") is None or linear.get("num_heads") is None,
+         "no linear_attn_config (head_dim, num_heads)"),
+        (hf.get("sliding_window") is not None, "window attention (sliding_window)"),
+        (hf.get("num_nextn_predict_layers", 0), "MTP modules (num_nextn_predict_layers)"),
+        (not hf.get("n_routed_experts", 0), "layers without routed experts"),
+    ) if has]
+    if refused:
+        raise ValueError("solar_open2 as this config.json states it is not supported: " + "; ".join(refused))
+    gqa = set(hf.get("gqa_layers", ()))
+    pattern = "".join("*E" if i in gqa else "KE" for i in range(hf["num_hidden_layers"]))
+    return dict(
+        n_layers=len(pattern), layer_pattern=pattern,
+        attn_head_dim=hf.get("head_dim", 0), attention_rotation=False,
+        attn_output_gate=bool(hf.get("use_gqa_gate", False)),
+        kda_n_heads=linear["num_heads"], kda_head_dim=linear["head_dim"],
+        kda_conv_taps=linear.get("short_conv_kernel_size", 4),
+        kda_neg_eigval=bool(hf.get("kda_allow_neg_eigval", False)),
+        n_experts=hf["n_routed_experts"], moe_top_k=hf["num_experts_per_tok"],
+        d_ff_expert=hf["moe_intermediate_size"], n_shared_experts=hf.get("n_shared_experts", 0),
+        moe_capacity_factor=0.0, moe_aux_loss_coef=0.0, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)),
+    )
 
 
 def _glm4_moe_lite_fields(hf: Dict[str, Any]) -> Dict[str, Any]:

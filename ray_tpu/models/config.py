@@ -80,8 +80,10 @@ class ModelConfig:
     mtp_loss_weight: float = 0.3
     # --- a stack of single-part layers (nemotron_h's hybrid_override_pattern) ---
     # One character a layer, each layer a mixer OR a feed-forward part alone behind its
-    # own norm and residual: M a Mamba-2 mixer (models/ssm.py), * attention, E an expert
-    # layer, - the dense MLP. "" is the block every other family has: attention followed
+    # own norm and residual: M a Mamba-2 mixer (models/ssm.py), K a Kimi-Delta-Attention
+    # mixer (models/kda.py), * attention, E an expert layer, - the dense MLP. A published
+    # layer of two parts (solar_open2: a mixer, then experts) is two characters. "" is
+    # the block every other family has: attention followed
     # by a feed-forward part, n_layers times (a prefix of n_dense_layers dense).
     # mtp_layer_pattern is an MTP module's own layers (only "*E": the block, in two).
     layer_pattern: str = ""
@@ -107,6 +109,22 @@ class ModelConfig:
     # 0 = all: a tensor-parallel share of its heads, as experts_held is of the experts
     attn_heads_held: Tuple[int, int] = (0, 0)
     attention_rotation: bool = True  # False: q and k are not rotated (position comes from elsewhere)
+    # attention's output times sigmoid(u W_gate) before W_o, a channel ([d_model -> heads x
+    # head_dim], from the layer's normed input: solar_open2's use_gqa_gate)
+    attn_output_gate: bool = False
+    # Kimi Delta Attention (arXiv:2510.26692; models/kda.py, ops/kda.py): kda_n_heads heads
+    # HELD (a tensor-parallel share is fewer heads), keys and values kda_head_dim wide, a
+    # causal depthwise convolution of kda_conv_taps taps over q, k and v, the decay and
+    # the output gate through low-rank projections of kda_proj_rank (0 = kda_head_dim;
+    # kda_use_full_proj false), beta in (0, 2) where kda_neg_eigval (kda_allow_neg_eigval)
+    # and (0, 1) else; the scan runs in chunks of kda_chunk positions. The seeded dt_bias
+    # is drawn from ssm_dt_min / _max / _floor, as a Mamba-2 layer's.
+    kda_n_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv_taps: int = 4
+    kda_proj_rank: int = 0
+    kda_neg_eigval: bool = True
+    kda_chunk: int = 64
     # Experts in a latent: the routed experts work at this width, between a projection
     # down before the dispatch and one up after the combine (0 = at d_model); router and
     # shared expert see d_model. d_ff_shared: the shared expert's width (0 =
@@ -124,11 +142,14 @@ class ModelConfig:
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
         object.__setattr__(self, "attn_heads_held", tuple(self.attn_heads_held))
         if self.layer_pattern:
-            unknown = set(self.layer_pattern) - set("ME*-")
+            unknown = set(self.layer_pattern) - set("MKE*-")
             if unknown or len(self.layer_pattern) != self.n_layers:
                 raise ValueError(
-                    f"layer_pattern {self.layer_pattern!r}: one of M E * - a layer, "
-                    f"n_layers ({self.n_layers}) of them")
+                    f"layer_pattern {self.layer_pattern!r}: one of M (Mamba-2) K (Kimi Delta "
+                    f"Attention) E (experts) * (attention) - (MLP) a layer, n_layers "
+                    f"({self.n_layers}) of them")
+            if "K" in self.layer_pattern and not self.kda_n_heads:
+                raise ValueError("layer_pattern has K layers: kda_n_heads says how many heads one holds")
             if self.n_dense_layers:
                 raise ValueError("layer_pattern says which layers are dense ('-'); n_dense_layers is the block's")
         if self.mtp_layer_pattern not in ("", "*E"):
@@ -167,6 +188,14 @@ class ModelConfig:
         return self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_state
 
     @property
+    def kda_d_inner(self) -> int:
+        return self.kda_n_heads * self.kda_head_dim
+
+    @property
+    def kda_rank(self) -> int:
+        return self.kda_proj_rank or self.kda_head_dim
+
+    @property
     def moe_dropless(self) -> bool:
         return self.n_experts > 0 and self.moe_capacity_factor <= 0
 
@@ -195,7 +224,7 @@ class ModelConfig:
                     + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
                     + h * self.v_head_dim * d + self.q_lora_rank + self.kv_lora_rank)
         else:
-            attn = d * self.head_dim * (2 * self.heads_held + 2 * self.kv_heads_held)
+            attn = d * self.head_dim * ((2 + self.attn_output_gate) * self.heads_held + 2 * self.kv_heads_held)
         mats = 3 if self.mlp_activation == "silu_gated" else 2  # a gated MLP has one more
         mlp = mats * d * self.d_ff
         norms = 2 * d
@@ -204,9 +233,13 @@ class ModelConfig:
             ssm = (d * (self.ssm_d_inner + self.ssm_conv_dim + self.ssm_n_heads)  # z | xBC | dt
                    + (self.ssm_conv_taps + 1) * self.ssm_conv_dim + 3 * self.ssm_n_heads
                    + self.ssm_d_inner + self.ssm_d_inner * d)
+            inner = self.kda_d_inner
+            kda = (d * 3 * inner + self.kda_conv_taps * 3 * inner + d * self.kda_n_heads  # q k v, beta
+                   + 2 * (d + inner) * self.kda_rank  # the decay's and the gate's low-rank pairs
+                   + self.kda_n_heads + inner + self.kda_head_dim + inner * d)  # A_log, dt_bias, norm, W_o
             experts = (d * self.n_experts + self.n_experts_held * mats * latent * (self.d_ff_expert or self.d_ff)
                        + mats * d * self.shared_width + (2 * d * latent if self.moe_latent_dim else 0))
-            kind = {"M": ssm + d, "*": attn + d, "E": experts + d, "-": mlp + d}
+            kind = {"M": ssm + d, "K": kda + d, "*": attn + d, "E": experts + d, "-": mlp + d}
             return (emb + d + sum(kind[c] for c in self.layer_pattern)
                     + self.mtp_depth * (attn + experts + norms + 2 * d * d + 3 * d))
         if not self.moe_dropless:
@@ -413,6 +446,39 @@ register_config(
         moe_route_scale=5.0,
         moe_select_bias=True,
         mtp_depth=1,
+    )
+)
+register_config(
+    # Toy of the solar_open2 family (Solar-Open2) for the CPU tests: every published layer
+    # two parts of the pattern (a mixer, then experts); Kimi-Delta-Attention mixers three
+    # to one with softmax attention that has an output gate and no rotation, at a head
+    # width of its own; sigmoid-routed SwiGLU experts beside a shared one. Everything
+    # held; tests cut shares of heads and experts.
+    ModelConfig(
+        name="solar-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=96,
+        max_seq_len=128,
+        dtype="float32",
+        layer_pattern="*EKEKEKE",
+        kda_n_heads=4,
+        kda_head_dim=16,
+        kda_chunk=8,
+        attn_head_dim=24,
+        attention_rotation=False,
+        attn_output_gate=True,
+        n_experts=20,
+        moe_top_k=3,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=40,
+        n_shared_experts=1,
+        moe_scoring="sigmoid",
+        moe_select_bias=True,
     )
 )
 register_config(

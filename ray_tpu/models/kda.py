@@ -1,0 +1,105 @@
+"""The Kimi-Delta-Attention mixer (Kimi Linear, arXiv:2510.26692) as solar_open2 uses it:
+one layer of a pattern of single-part layers (config.layer_pattern, `K`), behind its own
+norm and residual. Per head, keys and values 128 wide:
+
+    [q~ | k~ | v~] = conv(RMSNorm(x) W_qkv)    causal, depthwise, `kda_conv_taps` taps, no bias
+    q = l2norm(silu(q~)) * 128^-1/2,  k = l2norm(silu(k~)),  v = silu(v~)
+    g = -exp(A_log) * softplus(W_f_up (W_f_down u) + dt_bias)    a CHANNEL [H, 128], <= 0; float32
+    beta = 2 * sigmoid(u W_beta)               a head (the 2: kda_neg_eigval); float32
+    o = scan(q, k, v, g, beta)                 ops/kda.py: the gated delta rule, in chunks
+    y = (RMSNorm_head(o) * sigmoid(W_g_up (W_g_down u))) W_o     one [128] norm weight for all heads
+
+The count is what the layer HOLDS. A tensor-parallel share of a published layer is fewer
+heads of the same width: q, k, v, the convolution, beta, the two up-projections, dt_bias
+and A_log divide by heads, W_o by its rows; the two low-rank down-projections (rank 128,
+0.5 M each) and the norm weight are whole in every share. 8 shares of 8 heads add up to
+the layer of 64 through W_o (tests/test_solar_open2.py).
+
+Leaves: kda_norm [D], kda_qkv [D, 3, H, K], kda_conv [taps, 3, H, K] (the last tap is the
+current position's), kda_f_down [D, r], kda_f_up [r, H, K], kda_dt_bias [H, K], kda_A_log
+[H], kda_beta [D, H], kda_g_down [D, r], kda_g_up [r, H, K], kda_o_norm [K], kda_out
+[H, K, D]. Packed documents and a KV cache are refused (llama._block): state and
+convolution would have to start again at a boundary, and no recurrent state is kept.
+"""
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops import kda
+from ray_tpu.ops.quant import as_weight as _w
+
+from .config import ModelConfig
+from .ssm import _causal_conv
+
+AXES = {
+    "kda_norm": ("embed",), "kda_qkv": ("embed", None, "heads", "head_dim"),
+    "kda_conv": (None, None, "heads", "head_dim"),
+    "kda_f_down": ("embed", None), "kda_f_up": (None, "heads", "head_dim"),
+    "kda_dt_bias": ("heads", "head_dim"), "kda_A_log": ("heads",), "kda_beta": ("embed", "heads"),
+    "kda_g_down": ("embed", None), "kda_g_up": (None, "heads", "head_dim"),
+    "kda_o_norm": ("head_dim",), "kda_out": ("heads", "head_dim", "embed"),
+}
+
+
+def init(key: jax.Array, cfg: ModelConfig):
+    """Seeded weights whose decays lie in a trained layer's range and not all at 0 or 1, as
+    ssm.init's: A_log the log of uniform [1, 16] a head, dt_bias the inverse softplus of a
+    log-uniform draw in [ssm_dt_min, ssm_dt_max] floored at ssm_dt_floor a channel."""
+    d, h, width, rank, taps = cfg.d_model, cfg.kda_n_heads, cfg.kda_head_dim, cfg.kda_rank, cfg.kda_conv_taps
+    ks = jax.random.split(key, 10)
+
+    def normal(k, shape, scale):
+        return jax.random.normal(k, shape, jnp.float32) * scale
+
+    dt = jnp.exp(jax.random.uniform(ks[0], (h, width), jnp.float32, jnp.log(cfg.ssm_dt_min),
+                                    jnp.log(cfg.ssm_dt_max)))
+    dt = jnp.maximum(dt, cfg.ssm_dt_floor)
+    return {
+        "kda_norm": jnp.ones((d,), jnp.float32),
+        "kda_qkv": normal(ks[1], (d, 3, h, width), d**-0.5),
+        "kda_conv": normal(ks[2], (taps, 3, h, width), taps**-0.5),
+        "kda_f_down": normal(ks[3], (d, rank), d**-0.5),
+        "kda_f_up": normal(ks[4], (rank, h, width), rank**-0.5),
+        "kda_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus(dt_bias) == dt
+        "kda_A_log": jnp.log(jax.random.uniform(ks[5], (h,), jnp.float32, 1.0, 16.0)),
+        "kda_beta": normal(ks[6], (d, h), d**-0.5),
+        "kda_g_down": normal(ks[7], (d, rank), d**-0.5),
+        "kda_g_up": normal(ks[8], (rank, h, width), rank**-0.5),
+        "kda_o_norm": jnp.ones((width,), jnp.float32),
+        "kda_out": normal(ks[9], (h, width, d), (2 * cfg.n_layers * h * width) ** -0.5),
+    }
+
+
+def _l2norm(x: jax.Array) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+
+def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
+    """x [B, T, D] -> x + the layer's output."""
+    from .llama import rms_norm
+
+    dt_, f32 = x.dtype, jnp.float32
+    bsz, t, _ = x.shape
+    h, width = cfg.kda_n_heads, cfg.kda_head_dim
+    with jax.named_scope("kda_in_proj"):
+        u = rms_norm(x, lp["kda_norm"], cfg.norm_eps)
+        qkv = jnp.einsum("btd,dphk->btphk", u, _w(lp["kda_qkv"], dt_))
+        decay = jnp.einsum("btr,rhk->bthk", jnp.einsum("btd,dr->btr", u, _w(lp["kda_f_down"], dt_)),
+                           _w(lp["kda_f_up"], dt_))
+        gate = jnp.einsum("btr,rhk->bthk", jnp.einsum("btd,dr->btr", u, _w(lp["kda_g_down"], dt_)),
+                          _w(lp["kda_g_up"], dt_))
+        beta = jnp.einsum("btd,dh->bth", u, _w(lp["kda_beta"], dt_))
+    with jax.named_scope("kda_conv"):
+        channels = 3 * h * width
+        qkv = jax.nn.silu(_causal_conv(qkv.reshape(bsz, t, channels), lp["kda_conv"].reshape(-1, channels), 0.0))
+        q, k, v = jnp.moveaxis(qkv.reshape(bsz, t, 3, h, width), 2, 0)
+        q, k = _l2norm(q) * width**-0.5, _l2norm(k)
+        q, k, v = q.astype(dt_), k.astype(dt_), v.astype(dt_)
+    with jax.named_scope("kda_scan"):
+        g = -jnp.exp(lp["kda_A_log"])[:, None] * jax.nn.softplus(decay.astype(f32) + lp["kda_dt_bias"])
+        beta = jax.nn.sigmoid(beta.astype(f32)) * (2.0 if cfg.kda_neg_eigval else 1.0)
+        o = kda.kda_scan(q, k, v, g, beta, cfg.kda_chunk)
+    with jax.named_scope("kda_norm_gate"):
+        o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
+        o = (o * lp["kda_o_norm"] * jax.nn.sigmoid(gate.astype(f32))).astype(dt_)
+    with jax.named_scope("kda_out_proj"):
+        return x + jnp.einsum("bthk,hkd->btd", o, _w(lp["kda_out"], dt_))

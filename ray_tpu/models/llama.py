@@ -32,12 +32,13 @@ Params = Dict[str, Any]
 
 # A pattern's characters (config.layer_pattern): the stack its layers lie in, their
 # mixer and their feed-forward part. A layer without a pattern has both parts.
-_PATTERN = {"M": ("ssm_layers", "ssm", None), "*": ("attn_layers", "attn", None),
+_PATTERN = {"M": ("ssm_layers", "ssm", None), "K": ("kda_layers", "kda", None),
+            "*": ("attn_layers", "attn", None),
             "E": ("layers", None, "experts"), "-": ("mlp_layers", None, "dense")}
 
 
 def _layer_kinds(cfg: ModelConfig) -> Dict[str, Tuple[int, Optional[str], Optional[str]]]:
-    """The stacks of params: name -> (layers, mixer: attn | ssm | None, feed-forward
+    """The stacks of params: name -> (layers, mixer: attn | ssm | kda | None, feed-forward
     part: dense | experts | None). Without a pattern every layer is attention followed by
     a feed-forward part and forward walks the stacks in this order: `layers` is every
     layer of a one-kind model; cfg.n_dense_layers leading layers with the dense MLP lie
@@ -72,6 +73,8 @@ def pattern_period(pattern: str) -> Tuple[str, int]:
 
 def _attn_axes(cfg: ModelConfig) -> Params:
     if cfg.latent_attention:
+        if cfg.attn_output_gate:
+            raise NotImplementedError("an output gate on latent attention")
         return {
             "wq_a": ("embed", "latent"), "q_norm": ("latent",),
             "wq_b": ("latent", "heads", "head_dim"),
@@ -84,11 +87,13 @@ def _attn_axes(cfg: ModelConfig) -> Params:
         "wk": ("embed", "kv_heads", "head_dim"),
         "wv": ("embed", "kv_heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
+        **({"wo_gate": ("embed", "heads", "head_dim")} if cfg.attn_output_gate else {}),
     }
 
 
 def _layer_axes(cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Params:
     """One layer's logical axes (no leading 'layer' axis)."""
+    from . import kda as _kda
     from . import moe as _moe
     from . import ssm as _ssm
 
@@ -97,6 +102,8 @@ def _layer_axes(cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Pa
         axes.update({"attn_norm": ("embed",), **_attn_axes(cfg)})
     elif mixer == "ssm":
         axes.update(_ssm.AXES)
+    elif mixer == "kda":
+        axes.update(_kda.AXES)
     if ff == "experts":
         axes.update({"mlp_norm": ("embed",), **_moe.expert_axes(cfg)})
     elif ff == "dense":
@@ -139,12 +146,15 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
 
     def attn_init(ks):
         if not cfg.latent_attention:
-            return {
+            out = {
                 "wq": norm(ks[0], (d, nh, hd), s_in),
                 "wk": norm(ks[1], (d, nkv, hd), s_in),
                 "wv": norm(ks[2], (d, nkv, hd), s_in),
                 "wo": norm(ks[3], (nh, hd, d), s_out),
             }
+            if cfg.attn_output_gate:
+                out["wo_gate"] = norm(jax.random.fold_in(ks[0], 1), (d, nh, hd), s_in)
+            return out
         qr, kvr, rd = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
         ka, kb = jax.random.split(ks[1])
         return {
@@ -157,6 +167,7 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
         }
 
     def layer_init(key, mixer: Optional[str], ff: Optional[str]):
+        from . import kda as _kda
         from . import moe as _moe
         from . import ssm as _ssm
 
@@ -166,6 +177,8 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             out.update({"attn_norm": jnp.ones((d,), jnp.float32), **attn_init(ks)})
         elif mixer == "ssm":
             out.update(_ssm.init(ks[0], cfg))
+        elif mixer == "kda":
+            out.update(_kda.init(ks[0], cfg))
         if ff is not None:
             out["mlp_norm"] = jnp.ones((d,), jnp.float32)
         if ff == "experts":
@@ -222,11 +235,16 @@ def _maybe_remat(body, cfg: ModelConfig):
     # What an expert layer's router made (moe.route names it: the choice, the scores, the
     # chosen scores) is kept under every policy: a recomputed forward pass must neither
     # choose nor score again.
+    # The inverses of a delta-rule mixer's triangular systems (ops/kda.py names them:
+    # [Q, Q] a chunk and head, 32 MB a layer at 8 heads and 8,192 positions) are kept too:
+    # the substitution is the scan's slowest kernel and would run again.
+    from ray_tpu.ops.kda import INVERSE_NAME
+
     from . import moe as _moe
 
     policies = jax.checkpoint_policies
-    routed = policies.save_only_these_names(*_moe.ROUTER_NAMES)
-    kept = policies.save_only_these_names(*ROTATED_NAMES, *_moe.ROUTER_NAMES)
+    routed = policies.save_only_these_names(*_moe.ROUTER_NAMES, INVERSE_NAME)
+    kept = policies.save_only_these_names(*ROTATED_NAMES, *_moe.ROUTER_NAMES, INVERSE_NAME)
     if policy == "dots":
         return jax.checkpoint(
             body, policy=policies.save_from_both_policies(policies.checkpoint_dots, kept))
@@ -438,19 +456,28 @@ def _block(
     cache_len: Optional[jax.Array] = None,
     token_mask: Optional[jax.Array] = None,
 ):
-    """One layer: the mixer its parameters hold (attention, a Mamba-2 mixer, or none) and
-    then the feed-forward part they hold (or none), each behind its own norm and
-    residual. Every family but nemotron_h holds attention and a feed-forward part in each
-    layer: the decoder block. Returns (x, updated (k,v) if caching, moe aux loss)."""
+    """One layer: the mixer its parameters hold (attention, a Mamba-2 or a Kimi-Delta-
+    Attention mixer, or none) and then the feed-forward part they hold (or none), each
+    behind its own norm and residual. Every family with a layer pattern holds one part a
+    layer; the others attention and a feed-forward part in each: the decoder block.
+    Returns (x, updated (k,v) if caching, moe aux loss)."""
     new_kv, aux = None, jnp.zeros((), jnp.float32)
-    if "in_proj" in lp:
+    recurrent = "Mamba-2" if "in_proj" in lp else "Kimi-Delta-Attention" if "kda_qkv" in lp else None
+    if recurrent:
         if segment_ids is not None or cache_kv is not None:
             raise NotImplementedError(
-                "a Mamba-2 layer over packed documents (segment_ids: state and convolution do "
+                f"a {recurrent} layer over packed documents (segment_ids: state and convolution do "
                 "not start again at a boundary yet) or under a KV cache (no recurrent state is kept)")
+        from . import kda as _kda
         from . import ssm as _ssm
 
-        x = wsc(_ssm.mixer(x, lp, cfg), "batch", "seq", "act_embed")
+        if recurrent == "Mamba-2":
+            x = wsc(_ssm.mixer(x, lp, cfg), "batch", "seq", "act_embed")
+        else:
+            # under `attn`, where the readers of the trace look for a layer's mixer
+            # (benchmarks/metrics/train_scoped_pct.json, train_head_loss_pct.json)
+            with jax.named_scope("attn"):
+                x = wsc(_kda.mixer(x, lp, cfg), "batch", "seq", "act_embed")
     elif "attn_norm" in lp:
         x, new_kv = _attention_part(x, lp, cfg, positions, segment_ids, cache_kv, cache_len)
     if "mlp_norm" in lp:
@@ -508,6 +535,10 @@ def _attention_part(x, lp, cfg, positions, segment_ids, cache_kv, cache_len):
             attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
                              shard_spec=auto_spec("batch", None, "act_heads", None),
                              rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
+        if "wo_gate" in lp:  # a channel of the output, from the layer's normed input
+            gate = jnp.einsum("bsd,dhk->bshk", rms_norm(x, lp["attn_norm"], cfg.norm_eps),
+                              _w(lp["wo_gate"], x.dtype))
+            attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
         return wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed"), new_kv
 
 

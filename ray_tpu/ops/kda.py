@@ -1,0 +1,193 @@
+"""The Kimi-Delta-Attention recurrence over a sequence, in chunks (Kimi Linear,
+arXiv:2510.26692: a gated delta rule whose decay is a CHANNEL's).
+
+A head with keys and values K and V wide, state S [K, V], from a zero state:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T       o_t = S_t^T q_t
+
+with alpha_t = exp(g_t), g_t [K] <= 0. Run as written it is T dependent steps. The
+transition is not diagonal, so unlike ops/ssd.py's a chunk is not four products: with
+u_t = beta_t (v_t - (Diag(alpha_t) S_{t-1})^T k_t) the step is S_t = Diag(alpha_t) S_{t-1}
++ k_t u_t^T, and inside a chunk of Q positions that starts from S_0, with G_t the running
+sum of g inside the chunk (<= 0, falling),
+
+    S_t = Diag(exp G_t) S_0 + sum_{s<=t} Diag(exp(G_t - G_s)) k_s u_s^T
+    A_ts = beta_t sum_c k_tc k_sc exp(G_tc - G_sc)   (s < t)      the keys' decayed overlaps
+    B_ts =        sum_c q_tc k_sc exp(G_tc - G_sc)   (s <= t)     the queries' with the keys
+    (I + A) U = beta (V - (K exp G) S_0)      the WY form's triangular system: U = U0 - W S_0,
+                                              [W | U0] = (I + A)^-1 [beta K exp G | beta V]
+    O     = (Q exp G) S_0 + B U      = P S_0 + O0,   P = Q exp G - B W,       O0 = B U0
+    S_end = Diag(exp G_Q) S_0 + Kend^T U = M S_0 + N,  M = Diag(exp G_Q) - Kend^T W,  N = Kend^T U0
+                                              Kend_s = k_s exp(G_Q - G_s)
+
+P, O0, M, N are a chunk's own (nothing carried): every chunk's at once, in batched
+products; the chunks are then joined by S <- M S + N, one [K, K] x [K, V] product a head and
+chunk, the only dependent steps (T / Q of them); the outputs P S_0 + O0 are one batched
+product more.
+
+Every decay is the exponential of a non-positive number, whatever g holds: exp G_t,
+exp(G_Q - G_s), and the pairs' exp(G_tc - G_sc). That one is NOT split into
+exp(G_t) exp(-G_s): a channel's sum over 64 positions reaches -100 at the seeded decays
+and exp(100) is no float32. A chunk is cut into sub-chunks of `_SUB` positions:
+  pairs inside one  from the differences themselves, [_SUB, _SUB, K] a sub-chunk, under the
+                    mask s <= t, which the exponential never sees outside of, reduced over
+                    the channels where they are made (0.5 MB a sub-chunk and head at 32; a
+                    whole chunk's [Q, Q, K] would be 8 MB at 128);
+  pairs of two      through the running sum at the later sub-chunk's start, G_b:
+                    exp(G_t - G_s) = exp(G_t - G_b) exp(G_b - G_s), s < b <= t, both factors
+                    <= 1 because G falls: a product [_SUB, K] x [K, Q] a sub-chunk. Where
+                    G_b - G_s < -87 the second factor underflows to 0 and the pair with it,
+                    whose true weight is below exp(-87) = 1.6e-38: that is the bound.
+`_GROUP` chunks' differences are alive at a time (a `lax.map` whose body is
+rematerialised: the backward pass keeps a group's q, k, g, beta), never all chunks'.
+(What a difference of float32 sums costs: a decay's relative error is the sums' rounding,
+|G| x 6e-8: 6e-6 at -100, where a chunk-long decay itself is 4e-44; so a chunk's summed
+|g| belongs in the hundreds, as ops/ssd.py's.)
+The triangular system is solved by substitution, not by the series I - A + A^2 - ...: at
+beta near 2 and keys that repeat, the powers of A grow to 2^i C(Q, i) before they cancel.
+Blocks of `_SOLVE` rows go to `solve_triangular` (the TPU compiler's own kernel) and are
+joined in halves by products; (I + A)^-1 is made explicitly, once: its backward rule is
+two products (plain differentiation solves two more systems), and it carries a name
+(`INVERSE_NAME`) under which models/llama.py's remat policies keep it, [Q, Q] a chunk and
+head, so that a rematerialised layer does not substitute again (on a v5e 5.2 ms a layer
+and pass at blocks of 128, 1.0 at 32: PERF.md section 6, PR 37).
+
+Plain `jax.numpy`, float32 operands at the highest matrix precision, differentiated by
+JAX. The products are small (PERF.md section 5 has the trace); the differences' pass is
+the vector unit's.
+"""
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+_HI = jax.lax.Precision.HIGHEST
+_GROUP = 4  # chunks whose differences are alive together
+_SUB = 32  # positions of a sub-chunk: the differences are [_SUB, _SUB, K] a sub-chunk
+_SOLVE = 32  # rows of a triangular system solved by substitution; larger ones in halves
+INVERSE_NAME = "kda_inverse"  # (I + A)^-1 of every chunk: kept under every remat policy (llama._maybe_remat)
+
+
+def _decayed_overlaps(q, k, run):
+    """(sum_c k_tc k_sc exp(G_tc - G_sc), sum_c q_tc k_sc exp(G_tc - G_sc)) [..., Q, Q] for
+    s <= t, 0 elsewhere; q, k, run (G) [..., Q, K]. In sub-chunks of `_SUB` positions (the
+    module's docstring): the pairs inside one from their own differences, the pairs of two
+    through the sums at the later one's start."""
+    *lead, size, width = k.shape
+    sub = _SUB if size % _SUB == 0 else size
+    nb = size // sub
+    blocks = lambda x: x.reshape(*lead, nb, sub, width)  # noqa: E731
+    q_b, k_b, run_b = blocks(q), blocks(k), blocks(run)
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    # [..., i, t, s, c]: exp(G_tc - G_sc) where s <= t in sub-chunk i, 0 elsewhere
+    decay = jnp.exp(jnp.where(lower[:, :, None], run_b[..., :, None, :] - run_b[..., None, :, :], -jnp.inf))
+    k_decayed = k_b[..., None, :, :] * decay
+    same = jnp.eye(nb, dtype=k.dtype)[:, None, :, None]  # a sub-chunk's pairs on the diagonal of [i, t, j, s]
+    inside = [(jnp.sum(x[..., :, None, :] * k_decayed, -1)[..., None, :] * same).reshape(*lead, size, size)
+              for x in (k_b, q_b)]
+    if nb == 1:
+        return inside
+    # the sums before each sub-chunk's first position: G at the end of the one before
+    start = jnp.concatenate([jnp.zeros_like(run_b[..., :1, -1, :]), run_b[..., :-1, -1, :]], -2)  # [..., i, K]
+    since = jnp.exp(run_b - start[..., None, :])  # exp(G_t - G_start(i)), t in i: <= 1
+    earlier = (jnp.arange(size) // sub)[None, :] < jnp.arange(nb)[:, None]  # [i, s]: s before sub-chunk i
+    upto = jnp.exp(jnp.where(earlier[:, :, None], start[..., :, None, :] - run[..., None, :, :], -jnp.inf))
+    k_upto = k[..., None, :, :] * upto  # [..., i, s, c]: k_sc exp(G_start(i) - G_sc), <= |k_sc|
+    across = [jnp.einsum("...itc,...isc->...its", x * since, k_upto, precision=_HI).reshape(*lead, size, size)
+              for x in (k_b, q_b)]
+    return [a + b for a, b in zip(inside, across)]
+
+
+def _substituted(a):
+    """(I + a)^-1, a strictly lower triangular [..., n, n]: blocks of `_SOLVE` by the
+    compiler's substitution kernel, joined in halves by products,
+    [[T11, 0], [-T22 a21 T11, T22]]."""
+    n = a.shape[-1]
+    if n > _SOLVE and n % 2 == 0:
+        half = n // 2
+        t11, t22 = _substituted(a[..., :half, :half]), _substituted(a[..., half:, half:])
+        t21 = -jnp.einsum("...ij,...jk,...kl->...il", t22, a[..., half:, :half], t11, precision=_HI)
+        return jnp.concatenate([jnp.concatenate([t11, jnp.zeros_like(t21.mT)], -1),
+                                jnp.concatenate([t21, t22], -1)], -2)
+    eye = jnp.eye(n, dtype=a.dtype)
+    return jax.scipy.linalg.solve_triangular(a + eye, jnp.broadcast_to(eye, a.shape), lower=True,
+                                             unit_diagonal=True)
+
+
+def _unit_lower_inverse_fwd(a):
+    # named here, inside the rule: what the backward pass keeps is this value, and a name
+    # put on the function's result outside would be on a copy no policy can reach
+    t = checkpoint_name(_substituted(a), INVERSE_NAME)
+    return t, t
+
+
+@jax.custom_vjp
+def _unit_lower_inverse(a):
+    """(I + a)^-1 for a strictly lower triangular [..., Q, Q], by substitution. Its backward
+    rule is two products, d a = -T^T (d T) T^T, where plain differentiation solves two more
+    systems."""
+    return _unit_lower_inverse_fwd(a)[0]
+
+
+def _unit_lower_inverse_bwd(t, dt):
+    da = -jnp.einsum("...ji,...jk,...lk->...il", t, dt, t, precision=_HI)
+    return (jnp.where(jnp.tril(jnp.ones(t.shape[-2:], bool), -1), da, 0.0),)
+
+
+_unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _overlaps(q, k, g, beta):
+    """One group of chunks, every leading axis a batch -> (A, B) [..., Q, Q] of the module's
+    docstring."""
+    size = q.shape[-2]
+    kk, b = _decayed_overlaps(q, k, jnp.cumsum(g, axis=-2))
+    return jnp.where(jnp.tril(jnp.ones((size, size), bool), -1), kk * beta[..., :, None], 0.0), b
+
+
+def _chunk_parts(q, k, v, g, beta, a, b):
+    """Every chunk at once, every leading axis a batch: q, k, v, g [..., Q, K], beta [..., Q],
+    a and b [..., Q, Q] -> P [..., Q, K], O0 [..., Q, V], M [..., K, K], N [..., K, V] of
+    the module's docstring."""
+    run = jnp.cumsum(g, axis=-2)  # G, [..., Q, K]
+    from_start = jnp.exp(run)
+    rhs = jnp.concatenate([k * from_start, v], -1) * beta[..., None]
+    inverse = _unit_lower_inverse(a)
+    solved = jnp.einsum("...ts,...sc->...tc", inverse, rhs, precision=_HI)
+    w, u0 = solved[..., :k.shape[-1]], solved[..., k.shape[-1]:]
+    k_end = k * jnp.exp(run[..., -1:, :] - run)
+    p = q * from_start - jnp.einsum("...ts,...sc->...tc", b, w, precision=_HI)
+    o0 = jnp.einsum("...ts,...sv->...tv", b, u0, precision=_HI)
+    m = -jnp.einsum("...sc,...sd->...cd", k_end, w, precision=_HI)
+    m = m + from_start[..., -1, :, None] * jnp.eye(k.shape[-1], dtype=m.dtype)
+    n = jnp.einsum("...sc,...sv->...cv", k_end, u0, precision=_HI)
+    return p, o0, m, n
+
+
+def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
+             chunk: int) -> jax.Array:
+    """q, k, v [B, T, H, K] (q scaled, k of unit length: the caller's), g [B, T, H, K]
+    (<= 0: the log of a channel's decay), beta [B, T, H] -> o [B, T, H, K] in float32: the
+    recurrence's output from a zero state."""
+    bsz, t, h, width = q.shape
+    if t % chunk:
+        raise ValueError(f"sequence length {t} is not a multiple of the scan's chunk {chunk}")
+    nc = t // chunk
+    group = next(n for n in range(min(_GROUP, nc), 0, -1) if nc % n == 0)
+    f32 = jnp.float32
+
+    def chunks(x):  # [B, T, H, ...] -> [chunks, B, H, Q, ...]
+        x = x.astype(f32).reshape(bsz, nc, chunk, h, *x.shape[3:])
+        return x.transpose(1, 0, 3, 2, *range(4, x.ndim))
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    grouped = lambda x: x.reshape(nc // group, group, *x.shape[1:])  # noqa: E731
+    a, b = jax.lax.map(jax.checkpoint(lambda xs: _overlaps(*xs)), tuple(grouped(x) for x in (q, k, g, beta)))
+    p, o0, m, n = _chunk_parts(q, k, v, g, beta, a.reshape(nc, *a.shape[2:]), b.reshape(nc, *b.shape[2:]))
+
+    def join(state, mn):  # the state each chunk starts from
+        m_c, n_c = mn
+        return jnp.einsum("bhcd,bhdv->bhcv", m_c, state, precision=_HI) + n_c, state
+
+    _, starts = jax.lax.scan(join, jnp.zeros((bsz, h, width, width), f32), (m, n))
+    o = jnp.einsum("kbhtc,kbhcv->kbhtv", p, starts, precision=_HI) + o0
+    return o.transpose(1, 0, 3, 2, 4).reshape(bsz, t, h, width)

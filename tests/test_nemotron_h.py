@@ -742,10 +742,10 @@ def test_the_compiled_step_names_the_mixers_scopes():
 def test_the_manifest_lists_the_cell_and_its_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    assert [w["name"] for w in manifest["workloads"]][-1] == CELL and len(manifest["workloads"]) == 4
-    assert manifest["workloads"][-1]["chips"] == 1 and manifest["configs"][-1]["name"] == CONFIG
+    assert [w["name"] for w in manifest["workloads"]][3] == CELL and len(manifest["workloads"]) >= 4
+    assert manifest["workloads"][3]["chips"] == 1 and manifest["configs"][3]["name"] == CONFIG
     config = _cell_config()[0]
-    assert manifest["configs"][-1]["reduced"] == config["reduced"]
+    assert manifest["configs"][3]["reduced"] == config["reduced"]
     reported = {m["name"] for m in manifest["per_layer"] + manifest["end_to_end"]
                 if CELL in m.get("workloads", [CELL])}
     assert reported == {

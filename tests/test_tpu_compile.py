@@ -210,6 +210,18 @@ def _nemotron_share():
         moe_select_bias=True, experts_held=(0, 64))
 
 
+def _cell_file(config):
+    """(ModelConfig, file) of a family cell's configuration as its file states it."""
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks.lib import modelcfg
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", f"{config}.json")) as f:
+        file = json.load(f)
+    return modelcfg.model_config(modelcfg.model_keys(file)), file
+
+
 def test_latent_expert_layer_at_22_of_512_compiles_without_a_tokens_by_k_by_experts_operand(one_chip, on_tpu):
     """The expert layer of the Nemotron-3-Super cell (8,192 tokens x 22 assignments over a
     router of 512, 8 experts of 1024 x 2688 held in a latent, a window of 5,632 rows): two
@@ -307,7 +319,10 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
 
 @pytest.mark.parametrize("config,bodies,loops,temp_gb", [
     ("nemotron-3-super-train-tp8-ep64", 5, 2, 3.83),  # (a period of layers, unrolled: a body each)
-    ("glm-4.7-flash-train-ep8", 2, 0, 6.01)])  # (the scan over four layers has one body; the MTP module)
+    ("glm-4.7-flash-train-ep8", 2, 0, 6.01),  # (the scan over four layers has one body; the MTP module)
+    # PR 37: four expert parts at 8 of 320 (the pick a slot at a time, as at 22 of 512), three
+    # delta-rule scans whose triangular systems are inverted once each and kept
+    ("solar-open2-train-tp8-ep40", 4, 2, 4.67)])
 def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on_tpu, config, bodies,
                                                                     loops, temp_gb):
     """The whole step of each family cell as its configuration file states it, compiled for
@@ -316,17 +331,12 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     (88 MB in the Nemotron cell, 10.5 MB in GLM's) leaves the temporaries within 0.15 GB of
     what they were before it did (PR 35's programs: 3.83 and 6.01 GB), and XLA
     rematerialises nothing of its own to fit (PERF.md section 7, after PR 26 (2))."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from benchmarks.lib import modelcfg
     from ray_tpu.models import llama
     from ray_tpu.train import make_optimizer, make_train_step
     from ray_tpu.train.step import TrainState
 
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", f"{config}.json")) as f:
-        file = json.load(f)
-    cfg, trainer = modelcfg.model_config(modelcfg.model_keys(file)), file["trainer"]
+    cfg, file = _cell_file(config)
+    trainer = file["trainer"]
     assert cfg.remat and cfg.remat_policy == "full" and trainer["mesh"] is None
     tx = make_optimizer(**trainer["optimizer"])
     params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
@@ -338,7 +348,15 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     assert len(_instructions(text, "convolution", "moe_router")) == 3 * bodies
     assert len(_instructions(text, "while", "moe_router")) == loops * bodies
     assert not re.search(r"^\s*(?:ROOT )?%[\w.\-]*\.remat", text, re.M)
-    assert compiled.memory_analysis().temp_size_in_bytes < (temp_gb + 0.15) * 1e9
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < (temp_gb + 0.15) * 1e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
+    if cfg.kda_n_heads:  # a delta-rule part substitutes once, a call a block: the backward pass keeps the inverse
+        from ray_tpu.ops.kda import _SOLVE
+
+        assert (text.count('custom_call_target="InvertDiagBlocksLowerTriangular"')
+                == cfg.layer_pattern.count("K") * (cfg.kda_chunk // _SOLVE))
+        assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
 
 
 def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
@@ -357,6 +375,30 @@ def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
+    """A Kimi-Delta-Attention part's share of the Solar-Open2 cell (8 heads of 128, 8,192
+    positions in 64 chunks of 128), value and every gradient: plain XLA and the compiler's
+    own triangular kernel (no Pallas call), no operand with the extents of all chunks and
+    a chunk's [Q, Q, K] differences, the scan's float32 intermediates beside the
+    projections' under 2 GB."""
+    from ray_tpu.models import kda
+
+    cfg, _ = _cell_file("solar-open2-train-tp8-ep40")
+    lp = _shapes(jax.eval_shape(lambda: kda.init(jax.random.PRNGKey(0), cfg)), one_chip)
+    assert lp["kda_qkv"].shape == (4096, 3, 8, 128) and lp["kda_out"].shape == (8, 128, 4096)
+    assert lp["kda_f_down"].shape == lp["kda_g_down"].shape == (4096, 128)
+    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one_chip)
+
+    def loss(x, lp):
+        return jnp.sum(kda.mixer(x, lp, cfg).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "InvertDiagBlocksLowerTriangular" in text
+    assert cfg.kda_chunk == 128 and not re.search(r"\[64,1,8,(128|32),(128|32),128\]", text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
