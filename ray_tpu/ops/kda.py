@@ -38,8 +38,22 @@ and exp(100) is no float32. A chunk is cut into sub-chunks of `_SUB` positions:
                     <= 1 because G falls: a product [_SUB, K] x [K, Q] a sub-chunk. Where
                     G_b - G_s < -87 the second factor underflows to 0 and the pair with it,
                     whose true weight is below exp(-87) = 1.6e-38: that is the bound.
-`_GROUP` chunks' differences are alive at a time (a `lax.map` whose body is
-rematerialised: the backward pass keeps a group's q, k, g, beta), never all chunks'.
+Where they are made decides what is alive: `overlaps` hands a chunk to the two Pallas kernels
+of ops/kda_overlaps.py wherever they tile it (`kda_overlaps.supports`: channels in whole
+128-lane registers, sub-chunks in whole registers of 8 rows; the Solar-Open2 cell's 128 /
+32 / 128), one grid step a chunk and head, and then a chunk's differences, decayed keys and
+factors live and die in fast memory in both passes and the backward pass keeps q, k and G
+alone (kk and b carry no name for the remat policies: kept they would spare a rematerialised
+layer the forward kernel's second run, 1.1 ms, for 201 MB a step, which takes the
+Solar-Open2 step's temporaries from 4.63 to 4.83 GB, over what its compile test allows:
+PERF.md section 6, PR 38). Any other shape (a width of 16, a chunk of 16: tier-1's) runs
+`_decayed_overlaps`, the same sums in `jax.numpy` differentiated by JAX, which is also what
+the kernels are tested against; there every chunk's differences are alive at once, which
+only a small shape affords. The shape alone chooses: no flag, and nothing to set. Under an ambient mesh
+with an axis still automatic (heads or batch sharded by GSPMD) the `jax.numpy` path runs
+whatever the shape, because GSPMD cannot partition a Mosaic call and the compiler does
+partition plain operations; no listed cell trains this family under a mesh (a wrap as
+ops/attention.py's `_flash_per_shard` is the step to take when one does).
 (What a difference of float32 sums costs: a decay's relative error is the sums' rounding,
 |G| x 6e-8: 6e-6 at -100, where a chunk-long decay itself is 4e-44; so a chunk's summed
 |g| belongs in the hundreds, as ops/ssd.py's.)
@@ -52,19 +66,44 @@ two products (plain differentiation solves two more systems), and it carries a n
 head, so that a rematerialised layer does not substitute again (on a v5e 5.2 ms a layer
 and pass at blocks of 128, 1.0 at 32: PERF.md section 6, PR 37).
 
-Plain `jax.numpy`, float32 operands at the highest matrix precision, differentiated by
-JAX. The products are small (PERF.md section 5 has the trace); the differences' pass is
-the vector unit's.
+Everything outside the overlaps (the inverse, the chunks' four matrices, the join) is plain
+`jax.numpy`, float32 operands at the highest matrix precision, differentiated by JAX; the
+kernels' products run at the same precision and no operand anywhere is narrower than
+float32. G is summed once a scan and handed to both halves. PERF.md section 5 has the trace.
 """
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from . import kda_overlaps
+
 _HI = jax.lax.Precision.HIGHEST
-_GROUP = 4  # chunks whose differences are alive together
 _SUB = 32  # positions of a sub-chunk: the differences are [_SUB, _SUB, K] a sub-chunk
 _SOLVE = 32  # rows of a triangular system solved by substitution; larger ones in halves
 INVERSE_NAME = "kda_inverse"  # (I + A)^-1 of every chunk: kept under every remat policy (llama._maybe_remat)
+
+
+def _sub(size: int) -> int:
+    return _SUB if size % _SUB == 0 else size
+
+
+def _partitioned() -> bool:
+    """Whether an ambient mesh leaves an axis of more than one device to GSPMD."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return any(mesh.shape[a] > 1 for a in set(mesh.axis_names) - set(mesh.manual_axes))
+
+
+def takes_kernels(size: int, width: int) -> bool:
+    """Whether a chunk of `size` positions at `width` channels goes to the Pallas kernels."""
+    return kda_overlaps.supports(size, _sub(size), width) and not _partitioned()
+
+
+def overlaps(q, k, run):
+    """`_decayed_overlaps`' two sums, by the Pallas kernels where they tile the shape, else by it."""
+    size, width = k.shape[-2:]
+    if takes_kernels(size, width):
+        return kda_overlaps.overlaps(q, k, run, _sub(size))
+    return _decayed_overlaps(q, k, run)
 
 
 def _decayed_overlaps(q, k, run):
@@ -73,7 +112,7 @@ def _decayed_overlaps(q, k, run):
     module's docstring): the pairs inside one from their own differences, the pairs of two
     through the sums at the later one's start."""
     *lead, size, width = k.shape
-    sub = _SUB if size % _SUB == 0 else size
+    sub = _sub(size)
     nb = size // sub
     blocks = lambda x: x.reshape(*lead, nb, sub, width)  # noqa: E731
     q_b, k_b, run_b = blocks(q), blocks(k), blocks(run)
@@ -136,19 +175,18 @@ def _unit_lower_inverse_bwd(t, dt):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _overlaps(q, k, g, beta):
-    """One group of chunks, every leading axis a batch -> (A, B) [..., Q, Q] of the module's
+def _overlaps(q, k, run, beta):
+    """Every chunk at once, every leading axis a batch -> (A, B) [..., Q, Q] of the module's
     docstring."""
     size = q.shape[-2]
-    kk, b = _decayed_overlaps(q, k, jnp.cumsum(g, axis=-2))
+    kk, b = overlaps(q, k, run)
     return jnp.where(jnp.tril(jnp.ones((size, size), bool), -1), kk * beta[..., :, None], 0.0), b
 
 
-def _chunk_parts(q, k, v, g, beta, a, b):
-    """Every chunk at once, every leading axis a batch: q, k, v, g [..., Q, K], beta [..., Q],
-    a and b [..., Q, Q] -> P [..., Q, K], O0 [..., Q, V], M [..., K, K], N [..., K, V] of
-    the module's docstring."""
-    run = jnp.cumsum(g, axis=-2)  # G, [..., Q, K]
+def _chunk_parts(q, k, v, run, beta, a, b):
+    """Every chunk at once, every leading axis a batch: q, k, v, run (G) [..., Q, K], beta
+    [..., Q], a and b [..., Q, Q] -> P [..., Q, K], O0 [..., Q, V], M [..., K, K], N
+    [..., K, V] of the module's docstring."""
     from_start = jnp.exp(run)
     rhs = jnp.concatenate([k * from_start, v], -1) * beta[..., None]
     inverse = _unit_lower_inverse(a)
@@ -172,7 +210,6 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     if t % chunk:
         raise ValueError(f"sequence length {t} is not a multiple of the scan's chunk {chunk}")
     nc = t // chunk
-    group = next(n for n in range(min(_GROUP, nc), 0, -1) if nc % n == 0)
     f32 = jnp.float32
 
     def chunks(x):  # [B, T, H, ...] -> [chunks, B, H, Q, ...]
@@ -180,9 +217,8 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
         return x.transpose(1, 0, 3, 2, *range(4, x.ndim))
 
     q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
-    grouped = lambda x: x.reshape(nc // group, group, *x.shape[1:])  # noqa: E731
-    a, b = jax.lax.map(jax.checkpoint(lambda xs: _overlaps(*xs)), tuple(grouped(x) for x in (q, k, g, beta)))
-    p, o0, m, n = _chunk_parts(q, k, v, g, beta, a.reshape(nc, *a.shape[2:]), b.reshape(nc, *b.shape[2:]))
+    run = jnp.cumsum(g, axis=-2)  # G, [chunks, B, H, Q, K]: both halves read it
+    p, o0, m, n = _chunk_parts(q, k, v, run, beta, *_overlaps(q, k, run, beta))
 
     def join(state, mn):  # the state each chunk starts from
         m_c, n_c = mn

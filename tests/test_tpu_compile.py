@@ -354,8 +354,12 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     if cfg.kda_n_heads:  # a delta-rule part substitutes once, a call a block: the backward pass keeps the inverse
         from ray_tpu.ops.kda import _SOLVE
 
-        assert (text.count('custom_call_target="InvertDiagBlocksLowerTriangular"')
-                == cfg.layer_pattern.count("K") * (cfg.kda_chunk // _SOLVE))
+        parts = cfg.layer_pattern.count("K")
+        assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == parts * (cfg.kda_chunk // _SOLVE)
+        # the overlaps' kernels a part: forward, again in the rematerialised layer, backward (PR 38)
+        assert _kernel_calls(text, "kda_overlaps_fwd") == (parts, parts)
+        assert _kernel_calls(text, "kda_overlaps_bwd") == (parts, 0)
+        assert not re.search(_OVERLAPS_INTERMEDIATES, text)
         assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
 
 
@@ -378,27 +382,47 @@ def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
+def _kernel_calls(text, name):
+    """The compiled program's calls of the Pallas kernel `name`, by what ran them: forward,
+    the forward made again by a rematerialised layer, backward."""
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and f"/{name}/" in ln]
+    again = sum("rematted_computation" in ln for ln in calls)
+    return len(calls) - again, again
+
+
+# float32 arrays of every chunk with the extents of a sub-chunk's differences [.., 32, 32, 128]
+# or of the sub-chunks' factors [.., 4, 128, 128]: what `_decayed_overlaps` wrote to HBM
+_OVERLAPS_INTERMEDIATES = r"f32\[(\d+,)+(32,32,128|4,128,128)\]"
+
+
 def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     """A Kimi-Delta-Attention part's share of the Solar-Open2 cell (8 heads of 128, 8,192
-    positions in 64 chunks of 128), value and every gradient: plain XLA and the compiler's
-    own triangular kernel (no Pallas call), no operand with the extents of all chunks and
-    a chunk's [Q, Q, K] differences, the scan's float32 intermediates beside the
-    projections' under 2 GB."""
-    from ray_tpu.models import kda
+    positions in 64 chunks of 128), value and every gradient under the cell's remat: the
+    overlaps are the two Pallas kernels by name (forward, forward again in the
+    rematerialised layer, backward: ISSUE 38's item 5 was not taken, PERF.md section 6), the
+    inverse the compiler's own triangular kernel once a block, no float32 array with the
+    extents of the differences or the sub-chunks' factors of all chunks, the scan's float32
+    intermediates beside the projections' under 2 GB."""
+    from ray_tpu.models import kda, llama
+    from ray_tpu.ops.kda import _SOLVE, takes_kernels
 
     cfg, _ = _cell_file("solar-open2-train-tp8-ep40")
     lp = _shapes(jax.eval_shape(lambda: kda.init(jax.random.PRNGKey(0), cfg)), one_chip)
     assert lp["kda_qkv"].shape == (4096, 3, 8, 128) and lp["kda_out"].shape == (8, 128, 4096)
     assert lp["kda_f_down"].shape == lp["kda_g_down"].shape == (4096, 128)
+    assert cfg.kda_chunk == 128 and takes_kernels(cfg.kda_chunk, cfg.kda_head_dim)
     x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one_chip)
+    part = llama._maybe_remat(lambda x, lp: kda.mixer(x, lp, cfg), cfg)
 
     def loss(x, lp):
-        return jnp.sum(kda.mixer(x, lp, cfg).astype(jnp.float32))
+        return jnp.sum(part(x, lp).astype(jnp.float32))
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text and "InvertDiagBlocksLowerTriangular" in text
-    assert cfg.kda_chunk == 128 and not re.search(r"\[64,1,8,(128|32),(128|32),128\]", text)
+    assert _kernel_calls(text, "kda_overlaps_fwd") == (1, 1) and _kernel_calls(text, "kda_overlaps_bwd") == (1, 0)
+    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 3
+    assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == cfg.kda_chunk // _SOLVE
+    assert not re.search(_OVERLAPS_INTERMEDIATES, text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
