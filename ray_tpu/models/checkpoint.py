@@ -86,8 +86,54 @@ def config_from_hf(source_dir: str, **overrides) -> ModelConfig:
         fields.update(_nemotron_h_fields(hf))
     if hf.get("model_type") == "solar_open2":
         fields.update(_solar_open2_fields(hf))
+    if hf.get("model_type") == "lfm2_moe":
+        fields.update(_lfm2_moe_fields(hf))
     fields.update(overrides)
     return ModelConfig(**fields)
+
+
+def _lfm2_moe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `lfm2_moe` keys (LFM2-24B-A2B) as ModelConfig fields. A published layer is a
+    token mixer then a feed-forward part, each behind its own norm and residual: two
+    characters of the layer pattern, the mixer `*` where `layer_types` says
+    `full_attention` (GQA whose q and k are normed a head before the rotation) and `C`
+    where it says `conv` (the gated short convolution), the feed-forward part `-` in the
+    `num_dense_layers` leading layers and `E` after them (sigmoid-routed SwiGLU experts,
+    no shared one), so n_layers counts parts. Every expert is held; a share is an override
+    (`experts_held`). The family writes `norm_eps`, `num_experts` and
+    `rope_parameters.rope_theta` where Llama's writes `rms_norm_eps`, `num_local_experts`
+    and `rope_theta`, and ties its head unless told otherwise. What the program does not
+    run is refused by name; what config.json does not state is
+    benchmarks/configs/lfm2-24b-a2b-train-ep8.json's `assumed`. Weights' names are not
+    mapped."""
+    kinds = hf.get("layer_types") or []
+    rope = hf.get("rope_parameters") or {}
+    refused = [what for has, what in (
+        (len(kinds) != hf["num_hidden_layers"] or set(kinds) - {"full_attention", "conv"},
+         f"layer_types that are not num_hidden_layers of full_attention | conv ({sorted(set(kinds))})"),
+        (hf.get("conv_bias", False), "a bias on the convolution's projections (conv_bias true)"),
+        (not hf.get("norm_topk_prob", True), "gates that are not normalised (norm_topk_prob false)"),
+        (not hf.get("use_expert_bias", True), "a router without its selection bias (use_expert_bias false)"),
+        (rope.get("rope_type", "default") != "default", f"rope_type {rope.get('rope_type')!r}"),
+        (hf.get("sliding_window") is not None, "window attention (sliding_window)"),
+        (not hf.get("num_experts", 0), "layers without routed experts (the dense family is lfm2)"),
+    ) if has]
+    if refused:
+        raise ValueError("lfm2_moe as this config.json states it is not supported: " + "; ".join(refused))
+    dense = hf.get("num_dense_layers", 0)
+    pattern = "".join(("*" if kind == "full_attention" else "C") + ("-" if i < dense else "E")
+                      for i, kind in enumerate(kinds))
+    return dict(
+        n_layers=len(pattern), layer_pattern=pattern,
+        norm_eps=float(hf.get("norm_eps", 1e-5)),
+        rope_theta=float(rope.get("rope_theta", hf.get("rope_theta", 1e6))),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", hf.get("tie_embedding", True))),
+        attn_qk_norm=True, conv_taps=hf.get("conv_L_cache", 3),
+        n_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+        d_ff_expert=hf["moe_intermediate_size"], n_shared_experts=0,
+        moe_capacity_factor=0.0, moe_aux_loss_coef=0.0, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)), moe_gate_eps=1e-6,
+    )
 
 
 def _nemotron_h_fields(hf: Dict[str, Any]) -> Dict[str, Any]:

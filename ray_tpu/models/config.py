@@ -81,8 +81,9 @@ class ModelConfig:
     # --- a stack of single-part layers (nemotron_h's hybrid_override_pattern) ---
     # One character a layer, each layer a mixer OR a feed-forward part alone behind its
     # own norm and residual: M a Mamba-2 mixer (models/ssm.py), K a Kimi-Delta-Attention
-    # mixer (models/kda.py), * attention, E an expert layer, - the dense MLP. A published
-    # layer of two parts (solar_open2: a mixer, then experts) is two characters. "" is
+    # mixer (models/kda.py), C a gated short convolution (models/sconv.py), * attention, E an
+    # expert layer, - the dense MLP. A published layer of two parts (solar_open2, lfm2_moe:
+    # a mixer, then a feed-forward part) is two characters. "" is
     # the block every other family has: attention followed
     # by a feed-forward part, n_layers times (a prefix of n_dense_layers dense).
     # mtp_layer_pattern is an MTP module's own layers (only "*E": the block, in two).
@@ -136,18 +137,28 @@ class ModelConfig:
     # (n_group > 1) and gates that are not normalised over the chosen (norm_topk_prob false)
     moe_n_group: int = 1
     moe_norm_topk: bool = True
+    # what is added to the chosen scores' sum before the gates are divided by it (1e-20 is
+    # every family's here but lfm2_moe's, which states 1e-6)
+    moe_gate_eps: float = 1e-20
+    # The gated short convolution (lfm2's Lfm2ShortConv; models/sconv.py): [B | C | x] by one
+    # projection, a causal depthwise convolution of conv_taps taps (conv_L_cache) over B * x,
+    # times C, an output projection; all d_model wide, no bias
+    conv_taps: int = 3
+    # RMSNorm over a head's width of q and of k, one [head_dim] weight each, before the
+    # rotation (lfm2's q_layernorm / k_layernorm; eps is norm_eps)
+    attn_qk_norm: bool = False
 
     def __post_init__(self):
         # JSON hands a list; the dataclass is a static (hashed) argument of jitted programs
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
         object.__setattr__(self, "attn_heads_held", tuple(self.attn_heads_held))
         if self.layer_pattern:
-            unknown = set(self.layer_pattern) - set("MKE*-")
+            unknown = set(self.layer_pattern) - set("MKCE*-")
             if unknown or len(self.layer_pattern) != self.n_layers:
                 raise ValueError(
                     f"layer_pattern {self.layer_pattern!r}: one of M (Mamba-2) K (Kimi Delta "
-                    f"Attention) E (experts) * (attention) - (MLP) a layer, n_layers "
-                    f"({self.n_layers}) of them")
+                    f"Attention) C (gated short convolution) E (experts) * (attention) - (MLP) "
+                    f"a layer, n_layers ({self.n_layers}) of them")
             if "K" in self.layer_pattern and not self.kda_n_heads:
                 raise ValueError("layer_pattern has K layers: kda_n_heads says how many heads one holds")
             if self.n_dense_layers:
@@ -224,7 +235,8 @@ class ModelConfig:
                     + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
                     + h * self.v_head_dim * d + self.q_lora_rank + self.kv_lora_rank)
         else:
-            attn = d * self.head_dim * ((2 + self.attn_output_gate) * self.heads_held + 2 * self.kv_heads_held)
+            attn = (d * self.head_dim * ((2 + self.attn_output_gate) * self.heads_held + 2 * self.kv_heads_held)
+                    + 2 * self.head_dim * self.attn_qk_norm)
         mats = 3 if self.mlp_activation == "silu_gated" else 2  # a gated MLP has one more
         mlp = mats * d * self.d_ff
         norms = 2 * d
@@ -237,9 +249,11 @@ class ModelConfig:
             kda = (d * 3 * inner + self.kda_conv_taps * 3 * inner + d * self.kda_n_heads  # q k v, beta
                    + 2 * (d + inner) * self.kda_rank  # the decay's and the gate's low-rank pairs
                    + self.kda_n_heads + inner + self.kda_head_dim + inner * d)  # A_log, dt_bias, norm, W_o
+            sconv = d * 3 * d + self.conv_taps * d + d * d  # [B | C | x], the taps, W_out
             experts = (d * self.n_experts + self.n_experts_held * mats * latent * (self.d_ff_expert or self.d_ff)
                        + mats * d * self.shared_width + (2 * d * latent if self.moe_latent_dim else 0))
-            kind = {"M": ssm + d, "K": kda + d, "*": attn + d, "E": experts + d, "-": mlp + d}
+            kind = {"M": ssm + d, "K": kda + d, "C": sconv + d, "*": attn + d, "E": experts + d,
+                    "-": mlp + d}
             return (emb + d + sum(kind[c] for c in self.layer_pattern)
                     + self.mtp_depth * (attn + experts + norms + 2 * d * d + 3 * d))
         if not self.moe_dropless:
@@ -479,6 +493,36 @@ register_config(
         n_shared_experts=1,
         moe_scoring="sigmoid",
         moe_select_bias=True,
+    )
+)
+register_config(
+    # Toy of the lfm2_moe family (LFM2-24B-A2B) for the CPU tests: every published layer two
+    # parts of the pattern (a mixer, then a feed-forward part); gated short convolutions
+    # three to one with rotated GQA whose q and k are normed a head; a leading dense layer,
+    # then sigmoid-routed SwiGLU experts with NO shared expert; a tied head. Everything
+    # held; tests cut shares of the experts.
+    ModelConfig(
+        name="lfm2-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=10,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=96,
+        max_seq_len=128,
+        rope_theta=1e6,
+        tie_embeddings=True,
+        dtype="float32",
+        layer_pattern="C-*ECECECE",
+        attn_qk_norm=True,
+        n_experts=16,
+        moe_top_k=4,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=40,
+        moe_scoring="sigmoid",
+        moe_select_bias=True,
+        moe_gate_eps=1e-6,
     )
 )
 register_config(

@@ -33,12 +33,12 @@ Params = Dict[str, Any]
 # A pattern's characters (config.layer_pattern): the stack its layers lie in, their
 # mixer and their feed-forward part. A layer without a pattern has both parts.
 _PATTERN = {"M": ("ssm_layers", "ssm", None), "K": ("kda_layers", "kda", None),
-            "*": ("attn_layers", "attn", None),
+            "C": ("sconv_layers", "sconv", None), "*": ("attn_layers", "attn", None),
             "E": ("layers", None, "experts"), "-": ("mlp_layers", None, "dense")}
 
 
 def _layer_kinds(cfg: ModelConfig) -> Dict[str, Tuple[int, Optional[str], Optional[str]]]:
-    """The stacks of params: name -> (layers, mixer: attn | ssm | kda | None, feed-forward
+    """The stacks of params: name -> (layers, mixer: attn | ssm | kda | sconv | None, feed-forward
     part: dense | experts | None). Without a pattern every layer is attention followed by
     a feed-forward part and forward walks the stacks in this order: `layers` is every
     layer of a one-kind model; cfg.n_dense_layers leading layers with the dense MLP lie
@@ -73,8 +73,8 @@ def pattern_period(pattern: str) -> Tuple[str, int]:
 
 def _attn_axes(cfg: ModelConfig) -> Params:
     if cfg.latent_attention:
-        if cfg.attn_output_gate:
-            raise NotImplementedError("an output gate on latent attention")
+        if cfg.attn_output_gate or cfg.attn_qk_norm:
+            raise NotImplementedError("an output gate or a norm a head on latent attention")
         return {
             "wq_a": ("embed", "latent"), "q_norm": ("latent",),
             "wq_b": ("latent", "heads", "head_dim"),
@@ -88,6 +88,7 @@ def _attn_axes(cfg: ModelConfig) -> Params:
         "wv": ("embed", "kv_heads", "head_dim"),
         "wo": ("heads", "head_dim", "embed"),
         **({"wo_gate": ("embed", "heads", "head_dim")} if cfg.attn_output_gate else {}),
+        **({"q_head_norm": ("head_dim",), "k_head_norm": ("head_dim",)} if cfg.attn_qk_norm else {}),
     }
 
 
@@ -104,6 +105,10 @@ def _layer_axes(cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Pa
         axes.update(_ssm.AXES)
     elif mixer == "kda":
         axes.update(_kda.AXES)
+    elif mixer == "sconv":
+        from . import sconv as _sconv
+
+        axes.update(_sconv.AXES)
     if ff == "experts":
         axes.update({"mlp_norm": ("embed",), **_moe.expert_axes(cfg)})
     elif ff == "dense":
@@ -154,6 +159,8 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             }
             if cfg.attn_output_gate:
                 out["wo_gate"] = norm(jax.random.fold_in(ks[0], 1), (d, nh, hd), s_in)
+            if cfg.attn_qk_norm:
+                out.update(q_head_norm=jnp.ones((hd,), jnp.float32), k_head_norm=jnp.ones((hd,), jnp.float32))
             return out
         qr, kvr, rd = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
         ka, kb = jax.random.split(ks[1])
@@ -179,6 +186,10 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             out.update(_ssm.init(ks[0], cfg))
         elif mixer == "kda":
             out.update(_kda.init(ks[0], cfg))
+        elif mixer == "sconv":
+            from . import sconv as _sconv
+
+            out.update(_sconv.init(ks[0], cfg))
         if ff is not None:
             out["mlp_norm"] = jnp.ones((d,), jnp.float32)
         if ff == "experts":
@@ -192,7 +203,10 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
             out.update({n: dense[n] for n in _moe.mlp_leaves(cfg)})
         return out
 
-    params: Params = {"embed": norm(k_emb, (cfg.vocab_size, d), 1.0)}
+    # a tied table is drawn at the head's scale: as the head it makes logits of order 1,
+    # where a table of unit entries made them of order sqrt(d) (every part norms its input,
+    # so the blocks see the same either way)
+    params: Params = {"embed": norm(k_emb, (cfg.vocab_size, d), d**-0.5 if cfg.tie_embeddings else 1.0)}
     kinds = _layer_kinds(cfg)
     if len(kinds) == 1:  # the one-kind model draws its layers' keys as it always did
         kind_keys = {next(iter(kinds)): k_layers}
@@ -327,7 +341,8 @@ def _unconstrained(x: jax.Array, *logical_axes) -> jax.Array:
 
 
 def qkv_proj(x: jax.Array, lp: Params, cfg: ModelConfig, positions: Optional[jax.Array]):
-    """Attention's inputs for one layer: norm, the projections, RoPE.
+    """Attention's inputs for one layer: norm, the projections, a norm a head of q and k
+    where the layer has one (cfg.attn_qk_norm: before the rotation), RoPE.
     x [B, S, D], positions [B, S] -> q [B, S, H, hd], k and v [B, S, KV, hd].
     Without positions q and k come back un-rotated: the caller hands the rotation on
     (latent attention rotates a slice of its heads and always needs them)."""
@@ -338,6 +353,8 @@ def qkv_proj(x: jax.Array, lp: Params, cfg: ModelConfig, positions: Optional[jax
     q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
     k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
     v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
+    if "q_head_norm" in lp:
+        q, k = rms_norm(q, lp["q_head_norm"], cfg.norm_eps), rms_norm(k, lp["k_head_norm"], cfg.norm_eps)
     if positions is None:
         return q, k, v
     return rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta), v
@@ -457,27 +474,35 @@ def _block(
     token_mask: Optional[jax.Array] = None,
 ):
     """One layer: the mixer its parameters hold (attention, a Mamba-2 or a Kimi-Delta-
-    Attention mixer, or none) and then the feed-forward part they hold (or none), each
+    Attention mixer, a gated short convolution, or none) and then the feed-forward part
+    they hold (or none), each
     behind its own norm and residual. Every family with a layer pattern holds one part a
     layer; the others attention and a feed-forward part in each: the decoder block.
     Returns (x, updated (k,v) if caching, moe aux loss)."""
     new_kv, aux = None, jnp.zeros((), jnp.float32)
-    recurrent = "Mamba-2" if "in_proj" in lp else "Kimi-Delta-Attention" if "kda_qkv" in lp else None
+    recurrent = ("Mamba-2" if "in_proj" in lp else "Kimi-Delta-Attention" if "kda_qkv" in lp
+                 else "gated short-convolution" if "sconv_in" in lp else None)
     if recurrent:
         if segment_ids is not None or cache_kv is not None:
             raise NotImplementedError(
                 f"a {recurrent} layer over packed documents (segment_ids: state and convolution do "
-                "not start again at a boundary yet) or under a KV cache (no recurrent state is kept)")
+                "not start again at a boundary yet) or under a KV cache (no recurrent state or "
+                "convolution tail is kept)")
         from . import kda as _kda
         from . import ssm as _ssm
 
         if recurrent == "Mamba-2":
             x = wsc(_ssm.mixer(x, lp, cfg), "batch", "seq", "act_embed")
-        else:
+        elif recurrent == "Kimi-Delta-Attention":
             # under `attn`, where the readers of the trace look for a layer's mixer
             # (benchmarks/metrics/train_scoped_pct.json, train_head_loss_pct.json)
             with jax.named_scope("attn"):
                 x = wsc(_kda.mixer(x, lp, cfg), "batch", "seq", "act_embed")
+        else:
+            from . import sconv as _sconv
+
+            with jax.named_scope("attn"):  # as the Kimi-Delta-Attention mixer's
+                x = wsc(_sconv.mixer(x, lp, cfg), "batch", "seq", "act_embed")
     elif "attn_norm" in lp:
         x, new_kv = _attention_part(x, lp, cfg, positions, segment_ids, cache_kv, cache_len)
     if "mlp_norm" in lp:

@@ -195,7 +195,7 @@ def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: Mod
         raise NotImplementedError(
             "gates that are not normalised over the chosen experts (norm_topk_prob false)")
     idx, gates = _score_and_pick(x, router_w, bias, cfg.moe_top_k)
-    gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+    gates = gates / (gates.sum(-1, keepdims=True) + cfg.moe_gate_eps)
     return idx, gates * cfg.moe_route_scale
 
 

@@ -194,14 +194,14 @@ xla_fallback_count = 0
 
 def _log_fallback(q_shape, k_shape, impl: str) -> None:
     """A training shape on a TPU that misses the Pallas kernel is a perf cliff
-    (Mosaic can't tile e.g. head_dim 64): say so every time it is traced."""
+    (Mosaic can't tile e.g. head_dim 96): say so every time it is traced."""
     import logging
 
     global xla_fallback_count
     xla_fallback_count += 1
     logging.getLogger(__name__).warning(
         "attention: TPU shape q=%s kv=%s is not Mosaic-tileable "
-        "(head_dim %% 128 or seq block alignment); using %s XLA path",
+        "(head_dim not 64 or a multiple of 128, or seq block alignment); using %s XLA path",
         tuple(q_shape), tuple(k_shape), impl,
     )
 
@@ -263,8 +263,11 @@ def attention(
     the kernel per shard; the XLA paths are partitioned by GSPMD.
 
     rotation: q and k come without their rotary embedding. Where the Pallas path runs
-    (and q and k are one sequence's), its rotate kernel applies it in front of the
-    flash kernels; every other path applies `rotation.apply` first.
+    (and q and k are one sequence's, their heads whole vregs wide), its rotate kernel
+    applies it in front of the flash kernels; every other path applies `rotation.apply`
+    first, and so does the Pallas path at head width 64 (the rotate kernel's tiles are
+    lane-dense [rows, D]; in jax.numpy the rotation fuses with the projections' epilogue
+    and the pad to 128 lanes, as latent attention's does with its concatenation).
     """
     if impl == "auto":
         on_tpu = jax.default_backend() not in ("cpu", "gpu")
@@ -290,7 +293,8 @@ def attention(
                 and q_offset is None and kv_valid_len is None
                 and (same_len or not causal)):
             _log_fallback(q.shape, k.shape, impl)
-    if rotation is not None and not (impl == "pallas" and q.shape[1] == k.shape[1]):
+    if rotation is not None and not (impl == "pallas" and q.shape[1] == k.shape[1]
+                                     and q.shape[-1] % 128 == 0):
         q, k = (rotation.apply(x, rotation.positions, rotation.theta) for x in (q, k))
         rotation = None
     if impl == "pallas":

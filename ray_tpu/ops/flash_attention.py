@@ -78,14 +78,14 @@ SPAN_VMEM_BYTES = VMEM_LIMIT_BYTES // 2
 
 def supports(sq: int, skv: int, head_dim: int, block_q: int = BLOCK_Q,
              block_kv: int = BLOCK_KV) -> bool:
-    """Whether the kernels can tile this geometry. Mosaic tiles the lane (last) dim at
-    128 and sublanes at 8, and a sequence longer than one compute tile must be a whole
-    number of them (`_block_sizes`): head_dim 16, seq 20 or seq 520 would fail the TPU
-    compile ("slice shape must be aligned to tiling")."""
+    """Whether the kernels can tile this geometry. Mosaic tiles the lane (last) dim at 128
+    and sublanes at 8, and a sequence longer than one compute tile must be a whole number of
+    them (`_block_sizes`): head_dim 16, seq 20 or seq 520 would fail the TPU compile ("slice
+    shape must be aligned to tiling"). Heads 64 wide run on padded lanes (`NARROW_HEAD`)."""
     def seq_ok(n: int, block: int) -> bool:
         return n % 8 == 0 and (n <= block or n % block == 0)
 
-    return head_dim % 128 == 0 and seq_ok(sq, block_q) and seq_ok(skv, block_kv)
+    return (head_dim == NARROW_HEAD or head_dim % 128 == 0) and seq_ok(sq, block_q) and seq_ok(skv, block_kv)
 
 
 def _block_sizes(sq: int, skv: int, bq: int, bkv: int):
@@ -663,6 +663,11 @@ rope_to_heads.defvjp(_rope_fwd_rule, _rope_bwd_rule)
 
 # ----------------------------------------------------------------------- public API
 
+# The one head width below the lane width that runs the kernels (`flash_attention`): half
+# a vreg, so the padded products cost the MXU what the narrow ones would (a 128 x 128
+# array contracts 64 in the passes of 128, and writes 64 columns in the passes of 128).
+NARROW_HEAD = 64
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_bhsd(q, k, v, seg, scale, causal, bq, bkv):
@@ -707,11 +712,26 @@ def flash_attention(
     rope: Optional[tuple] = None,  # (positions [B or 1, S], theta): q and k come un-rotated
 ) -> jax.Array:
     """BSHD flash attention. Sq must equal Skv when segment_ids are used, and with
-    `rope`: then the rotate kernel runs in front of the flash kernels (`rope_to_heads`)."""
+    `rope`: then the rotate kernel runs in front of the flash kernels (`rope_to_heads`).
+
+    Heads 64 wide (`NARROW_HEAD`) run the same three kernels, under the same names, on
+    q, k and v padded with zero lanes to 128: the scores do not see zeros in q and k, the
+    output's padded lanes are zero and are cut, and the cut's transpose pads dO, so dq, dk
+    and dv come out of the pad's own transpose. Mosaic lays a [.., S, 64] array out in HBM
+    in (8, 128) tiles as it is (compiled for a v5e: `memref<..x8192x128xbf16>` behind a
+    block of 64), so a kernel of its own at 64 would move the same bytes; what the padding
+    adds is the pad and the cut themselves, which XLA fuses into the producer of q, k, v
+    and the consumer of the output. They come rotated (`rope` is refused: the rotate
+    kernel's tiles are whole vregs)."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d**0.5)
+    if d == NARROW_HEAD:
+        if rope is not None:
+            raise NotImplementedError(f"the rotate kernel at head width {d}: hand q and k over rotated")
+        lanes = ((0, 0),) * 3 + ((0, _lane_pad(d) - d),)
+        q, k, v = (jnp.pad(x, lanes) for x in (q, k, v))
     qt, kt = (_heads_major(q), _heads_major(k)) if rope is None else rope_to_heads(q, k, *rope)
     vt = _heads_major(v)
     seg = None if segment_ids is None else _segment_lanes(segment_ids, q.shape[1])
     out = _flash_bhsd(qt, kt, vt, seg, scale, causal, block_q, block_kv)
-    return _heads_major(out)
+    return _heads_major(out)[..., :d]

@@ -322,7 +322,11 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
     ("glm-4.7-flash-train-ep8", 2, 0, 6.01),  # (the scan over four layers has one body; the MTP module)
     # PR 37: four expert parts at 8 of 320 (the pick a slot at a time, as at 22 of 512), three
     # delta-rule scans whose triangular systems are inverted once each and kept
-    ("solar-open2-train-tp8-ep40", 4, 2, 4.67)])
+    ("solar-open2-train-tp8-ep40", 4, 2, 4.67),
+    # PR 42: four expert parts at 4 of 64 over 32,768 tokens (the pick a slot at a time: 8.4 M mask
+    # elements), no shared expert, beside four gated short convolutions, a dense part and attention
+    # at head width 64 on padded lanes; arguments 5.63 GB (16 B a parameter less the gradient)
+    ("lfm2-24b-a2b-train-ep8", 4, 2, 5.20)])
 def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on_tpu, config, bodies,
                                                                     loops, temp_gb):
     """The whole step of each family cell as its configuration file states it, compiled for
@@ -380,6 +384,59 @@ def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+def test_flash_attention_compiles_at_head_width_64(one_chip, on_tpu):
+    """[4, 8192, 32 / 8, 64], the LFM2 cell's shape (lfm2moe-train-ep8share-b4-s8192): heads
+    half the lane width run the same three kernels on zero-padded lanes (Mosaic refuses a
+    block 64 lanes wide: "must be aligned to tiling (128)"), under the names the trace
+    metrics select by; the rotation in front of them is jax.numpy's (no rotate kernel)."""
+    from ray_tpu.models.llama import rope
+    from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.ops.attention import Rotation, attention
+
+    b, s, h, kv, d = 4, 8192, 32, 8, 64
+    assert fa.supports(s, s, d)
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, pos):
+        return jnp.sum(attention(q, k, v, causal=True, rotation=Rotation(pos, 1e6, rope)).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, pos).compile().as_text()
+    calls = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3 and not any("rope_" in ln.split(" = ")[0] for ln in calls)
+    for path, n in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 2),
+                    ("train_attn_w64_roofline_pct", 3)):
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
+            rx = re.compile(json.load(f)["args"]["pattern"])
+        assert sum(bool(rx.search(ln)) for ln in calls) == n, (path, calls)
+    # the kernels see whole vregs: [4, 32, 8192, 128] and [4, 8, 8192, 128]
+    assert all("bf16[4,32,8192,128]" in ln and "bf16[4,8,8192,128]" in ln for ln in calls)
+    grid, = _pallas_grids(jax.make_jaxpr(lambda q, k, v: fa.flash_attention(q, k, v, causal=True))(q, k, k).jaxpr)
+    assert grid == (b, h, 16, 1)  # K and V of a kv head, 8,192 rows of 128 lanes, are one span
+
+
+def test_gated_short_convolution_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
+    """A gated short-convolution part of the LFM2 cell ([4, 8192] tokens, 2048 wide, 3 taps),
+    value and every gradient under the cell's remat: plain XLA, no kernel, the float32
+    convolution beside the projections' outputs under 2.5 GB."""
+    from ray_tpu.models import llama, sconv
+
+    cfg, _ = _cell_file("lfm2-24b-a2b-train-ep8")
+    lp = _shapes(jax.eval_shape(lambda: sconv.init(jax.random.PRNGKey(0), cfg)), one_chip)
+    assert lp["sconv_in"].shape == (2048, 3, 2048) and lp["sconv_w"].shape == (3, 2048)
+    assert lp["sconv_out"].shape == (2048, 2048)
+    x = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16, sharding=one_chip)
+    part = llama._maybe_remat(lambda x, lp: sconv.mixer(x, lp, cfg), cfg)
+
+    def loss(x, lp):
+        return jnp.sum(part(x, lp).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
 def _kernel_calls(text, name):
