@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops import attention
-from ray_tpu.ops.attention import ROTATED_NAMES, Rotation
+from ray_tpu.ops.attention import FLASH_NAMES, ROTATED_NAMES, Rotation
 from ray_tpu.ops.quant import as_weight as _w
 from ray_tpu.parallel.sharding import auto_spec
 from ray_tpu.parallel.sharding import with_sharding_constraint as wsc
@@ -237,28 +237,41 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
 # ------------------------------------------------------------------------- kernels
 
 def _maybe_remat(body, cfg: ModelConfig):
-    """Per-layer rematerialization with a selectable policy (cfg.remat_policy):
-    'full' recomputes everything; 'dots' saves matmul outputs so only cheap
-    elementwise ops replay in the backward pass (XLA's usual MFU sweet spot)."""
+    """Per-layer rematerialization with a selectable policy (cfg.remat_policy).
+
+    'dots' and 'dots_no_batch' save matmul outputs, so only cheap elementwise ops replay
+    in the backward pass (XLA's usual MFU sweet spot), and beside them the rotated q and k
+    (ops/flash_attention.py names them: the backward of the projections needs dQ and dK,
+    never their own output, so the un-rotated pair is dropped for them and the rotation is
+    not run again).
+
+    'full' keeps a layer's input and the short list of what is too dear to make again:
+    - the attention core's two results, `out` and its logsumexp (ops/flash_attention.py
+      names them where the forward kernel made them): the kernel is quadratic in the
+      sequence and its output linear, 84 MB for 4.5 ms a block at [1, 8192, 20, 256], so
+      the backward kernels read what the forward pass wrote and the rematerialised layer
+      runs no forward kernel; q, k and v are made again from the layer's input by the
+      projections it runs anyway. Not under the 'dots' policies: both Mistral depths are
+      the greatest that fit, and half a gigabyte more there turns into XLA's own `.remat`
+      of MLP products (PERF.md section 7, after PR 26 (1)).
+
+    Under EVERY policy:
+    - what an expert layer's router made (moe.route names it: the choice, the scores, the
+      chosen scores, the count): a recomputed forward pass must neither choose nor score
+      again;
+    - the inverses of a delta-rule mixer's triangular systems (ops/kda.py names them:
+      [Q, Q] a chunk and head, 32 MB a layer at 8 heads and 8,192 positions): the
+      substitution is the scan's slowest kernel."""
     policy = cfg.remat_policy
     if not cfg.remat or policy == "none":
         return body
-    # the rotated q and k (ops/flash_attention.py names them) are kept beside the matmul
-    # outputs: the backward of the projections needs dQ and dK, never their own output,
-    # so the un-rotated pair is dropped for them and the rotation is not run again.
-    # What an expert layer's router made (moe.route names it: the choice, the scores, the
-    # chosen scores) is kept under every policy: a recomputed forward pass must neither
-    # choose nor score again.
-    # The inverses of a delta-rule mixer's triangular systems (ops/kda.py names them:
-    # [Q, Q] a chunk and head, 32 MB a layer at 8 heads and 8,192 positions) are kept too:
-    # the substitution is the scan's slowest kernel and would run again.
     from ray_tpu.ops.kda import INVERSE_NAME
 
     from . import moe as _moe
 
     policies = jax.checkpoint_policies
-    routed = policies.save_only_these_names(*_moe.ROUTER_NAMES, INVERSE_NAME)
-    kept = policies.save_only_these_names(*ROTATED_NAMES, *_moe.ROUTER_NAMES, INVERSE_NAME)
+    routed = (*_moe.ROUTER_NAMES, INVERSE_NAME)
+    kept = policies.save_only_these_names(*ROTATED_NAMES, *routed)
     if policy == "dots":
         return jax.checkpoint(
             body, policy=policies.save_from_both_policies(policies.checkpoint_dots, kept))
@@ -268,7 +281,7 @@ def _maybe_remat(body, cfg: ModelConfig):
     if policy != "full":
         raise ValueError(
             f"unknown remat_policy {policy!r} (expected full | dots | dots_no_batch | none)")
-    return jax.checkpoint(body, policy=routed)
+    return jax.checkpoint(body, policy=policies.save_only_these_names(*FLASH_NAMES, *routed))
 
 
 # A row shorter than this is looked up with a gather whatever the mesh: the

@@ -17,6 +17,10 @@ import jax.numpy as jnp
 # checkpoint names of the rotated q and k where the Pallas path's rotate kernel made
 # them (ops/flash_attention.py), for a remat policy that keeps them
 ROTATED_NAMES = ("rope_q", "rope_k")
+# checkpoint names of the forward flash kernel's output and logsumexp where its forward rule
+# made them (ops/flash_attention.py), for a remat policy that keeps them: with both kept the
+# backward kernels read them and a rematerialised layer does not run the forward kernel again
+FLASH_NAMES = ("flash_out", "flash_lse")
 
 
 class Rotation(NamedTuple):

@@ -53,7 +53,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .attention import ROTATED_NAMES
+from .attention import FLASH_NAMES, ROTATED_NAMES
 
 # Rows of the kernels' COMPUTE tile on the query and on the key/value side (a multiple
 # of 128; a shorter sequence is one tile): the size of the products, not of what a grid
@@ -676,7 +676,10 @@ def _flash_bhsd(q, k, v, seg, scale, causal, bq, bkv):
 
 
 def _flash_fwd_rule(q, k, v, seg, scale, causal, bq, bkv):
-    out, lse = _fwd(q, k, v, seg, scale, causal, bq, bkv)
+    # the kernel's two results carry `FLASH_NAMES`: a policy that keeps both leaves nothing
+    # in a rematerialised layer that reads the kernel, and JAX drops its second run there
+    out, lse = (_named_bits(x, name)
+                for x, name in zip(_fwd(q, k, v, seg, scale, causal, bq, bkv), FLASH_NAMES))
     return out, (q, k, v, seg, out, lse)
 
 

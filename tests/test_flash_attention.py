@@ -282,3 +282,94 @@ def test_flash_attention_rotates_in_front(segments):
 
     for name, a, r in zip(("out", "dq", "dk", "dv"), run(True), run(False)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(r), rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+# ------------------------------- the forward kernel's results kept under remat `full` (PR 43)
+
+def _attention_block(seg):
+    """A layer's attention part in small: three projections, the flash kernels, the output
+    projection. x [B, S, M], w a dict of the four weights."""
+    def block(x, w):
+        q, k, v = (jnp.einsum("bsm,mhd->bshd", x, w[n]) for n in ("q", "k", "v"))
+        o = flash_attention(q, k, v, causal=True, segment_ids=seg, block_q=64, block_kv=64)
+        return jnp.einsum("bshd,hdm->bsm", o, w["o"])
+    return block
+
+
+def _kernel_names(jaxpr):
+    """The names of a program's Pallas kernels, nested calls included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernel_names(sub)
+    return names
+
+
+def _remat_cfg(policy):
+    import dataclasses
+
+    from ray_tpu.models import get_config
+
+    return dataclasses.replace(get_config("test-tiny"), remat=True, remat_policy=policy)
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["plain", "packed"])
+@pytest.mark.parametrize("d", [128, 256, 64], ids=["w128", "w256", "w64-padded"])
+def test_a_block_under_remat_full_is_the_block_bit_for_bit(d, segments):
+    """Value and every gradient of an attention block rematerialised under `full` (which
+    keeps the forward kernel's `out` and logsumexp by name, so the backward kernels read
+    what the forward pass wrote) are bit-equal to the same block without remat, which
+    hands the backward rule those arrays as plain residuals; the rematerialised program
+    holds the forward kernel once, where under `dots` (which keeps neither) it holds it
+    twice."""
+    from ray_tpu.models import llama
+
+    b, s, m, h, hkv = 2, 128, 64, 4, 2
+    x = _rand((b, s, m), 0, jnp.bfloat16)
+    w = {n: _rand((m, heads, d), i + 1, jnp.bfloat16) * m ** -0.5
+         for i, (n, heads) in enumerate((("q", h), ("k", hkv), ("v", hkv)))}
+    w["o"] = _rand((h, d, m), 4, jnp.bfloat16) * (h * d) ** -0.5
+    g = _rand((b, s, m), 5, jnp.bfloat16)
+    block = _attention_block(_packed(b, s, (50,)) if segments else None)
+
+    def run(body):
+        def loss(x, w):
+            y = body(x, w)
+            return jnp.sum((y * g).astype(jnp.float32)), y
+        fn = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+        return jax.jit(fn)(x, w), _kernel_names(jax.make_jaxpr(fn)(x, w).jaxpr)
+
+    plain, names = run(block)
+    assert sorted(names) == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"]
+    full, names_full = run(llama._maybe_remat(block, _remat_cfg("full")))
+    assert sorted(names_full) == sorted(names), names_full  # the forward kernel once
+    for a, r in zip(jax.tree.leaves(full), jax.tree.leaves(plain)):
+        assert a.dtype == r.dtype and np.array_equal(np.asarray(a, np.float32), np.asarray(r, np.float32))
+    _, names_dots = run(llama._maybe_remat(block, _remat_cfg("dots")))
+    assert names_dots.count("flash_attention_fwd") == 2, names_dots
+
+
+@pytest.mark.parametrize("policy,named", [("full", True), ("dots", False), ("dots_no_batch", False)])
+def test_remat_full_alone_keeps_the_kernels_results(policy, named):
+    """What a rematerialised attention block keeps by `FLASH_NAMES`: under `full` the
+    forward kernel's output [B, H, S, D] (the padded 128 lanes at width 64: the pad and
+    the cut lie outside the rule) and its logsumexp; under the `dots` policies neither."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops.attention import FLASH_NAMES
+
+    b, s, m, h, hkv, d = 2, 128, 64, 4, 2, 64
+    x = jnp.zeros((b, s, m), jnp.bfloat16)
+    w = {n: jnp.zeros((m, heads, d), jnp.bfloat16) for n, heads in (("q", h), ("k", hkv), ("v", hkv))}
+    w["o"] = jnp.zeros((h, d, m), jnp.bfloat16)
+    body = llama._maybe_remat(_attention_block(None), _remat_cfg(policy))
+    kept = {why.split("'")[1]: aval for aval, why in saved_residuals(body, x, w) if why.startswith("named")}
+    assert set(kept) == (set(FLASH_NAMES) if named else set()), kept
+    if named:
+        out, lse = (kept[name] for name in FLASH_NAMES)
+        assert out.shape == (b, h, s, 128) and lse.shape[:2] == (b, h) and lse.size >= b * h * s, kept
+        assert (out.dtype, lse.dtype) == (jnp.uint16, jnp.uint32)  # their bits: the policy rounds nothing
+

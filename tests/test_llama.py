@@ -319,11 +319,13 @@ def test_train_step_rotates_in_the_kernel():
     assert names.count("flash_attention_fwd") == 2, names  # once more in the backward
     joins = [e.outvars[0].aval.shape for e in _eqns(grad.jaxpr) if e.primitive.name == "concatenate"]
     assert joins and all(len(shape) == 3 for shape in joins), joins  # the angles' tables only
-    # `full` keeps nothing: the rotation runs again in the backward
+    # `full` keeps the flash kernel's output and logsumexp and no q or k: the rotation
+    # runs again in the backward, the forward flash kernel does not (PR 43)
     full = _pallas_cfg(remat_policy="full")
     names = _kernel_names(jax.make_jaxpr(jax.grad(
         lambda p: llama.loss_fn(p, {"tokens": tokens}, full)[0]))(params).jaxpr)
     assert names.count("rope_fwd") == 2 and names.count("rope_bwd") == 1, names
+    assert names.count("flash_attention_fwd") == 1, names
 
 
 @pytest.mark.parametrize("program", ["prefill_detached", "decode_step", "decode_step_paged",

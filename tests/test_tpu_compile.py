@@ -319,14 +319,19 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
 
 @pytest.mark.parametrize("config,bodies,loops,temp_gb", [
     ("nemotron-3-super-train-tp8-ep64", 5, 2, 3.83),  # (a period of layers, unrolled: a body each)
-    ("glm-4.7-flash-train-ep8", 2, 0, 6.01),  # (the scan over four layers has one body; the MTP module)
+    # (the scan over four layers has one body; the MTP module). PR 43: 6.045 -> 6.539 GB, remat `full`
+    # keeps the forward flash kernel's `out` [1, 20, 8192, 256] bfloat16 (84 MB) and logsumexp (0.66 MB)
+    # of six blocks, 0.51 GB, and runs the kernel 3 times a step where it ran 6
+    ("glm-4.7-flash-train-ep8", 2, 0, 6.54),
     # PR 37: four expert parts at 8 of 320 (the pick a slot at a time, as at 22 of 512), three
     # delta-rule scans whose triangular systems are inverted once each and kept
     ("solar-open2-train-tp8-ep40", 4, 2, 4.67),
     # PR 42: four expert parts at 4 of 64 over 32,768 tokens (the pick a slot at a time: 8.4 M mask
     # elements), no shared expert, beside four gated short convolutions, a dense part and attention
-    # at head width 64 on padded lanes; arguments 5.63 GB (16 B a parameter less the gradient)
-    ("lfm2-24b-a2b-train-ep8", 4, 2, 5.20)])
+    # at head width 64 on padded lanes; arguments 5.63 GB (16 B a parameter less the gradient).
+    # PR 43: 5.193 -> 5.568 GB, the one attention part's `out` on its padded lanes [4, 32, 8192, 128]
+    # bfloat16 (268 MB) and logsumexp (4 MB) kept, 0.27 GB, and 0.10 GB of the compiler's placing
+    ("lfm2-24b-a2b-train-ep8", 4, 2, 5.57)])
 def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on_tpu, config, bodies,
                                                                     loops, temp_gb):
     """The whole step of each family cell as its configuration file states it, compiled for
@@ -352,6 +357,11 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     assert len(_instructions(text, "convolution", "moe_router")) == 3 * bodies
     assert len(_instructions(text, "while", "moe_router")) == loops * bodies
     assert not re.search(r"^\s*(?:ROOT )?%[\w.\-]*\.remat", text, re.M)
+    # under `full` a rematerialised layer keeps the forward flash kernel's results: a call an
+    # attention block forward, none made again, one of each backward kernel (PR 43)
+    blocks = _kernel_calls(text, "flash_attention_bwd_dq")[0]
+    assert blocks >= 1 and _kernel_calls(text, "flash_attention_bwd_dkv") == (blocks, 0)
+    assert _kernel_calls(text, "flash_attention_fwd") == (blocks, 0)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < (temp_gb + 0.15) * 1e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
@@ -365,6 +375,43 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
         assert _kernel_calls(text, "kda_overlaps_bwd") == (parts, 0)
         assert not re.search(_OVERLAPS_INTERMEDIATES, text)
         assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
+
+
+@pytest.mark.parametrize("config,forward", [
+    ("glm-4.7-flash-train-ep8", (1, 0)),  # `full`, [1, 8192, 20 / 20, 256] behind the latent projections
+    ("lfm2-24b-a2b-train-ep8", (1, 0)),   # `full`, [4, 8192, 32 / 8, 64] on padded lanes
+    ("mistral-7b-train", (1, 1))])        # `dots`, the control: the forward kernel again in the backward pass
+def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full(one_chip, on_tpu, config, forward):
+    """A cell's attention part (norm, projections, rotation, the flash kernels, the output
+    projection, the residual) under the cell's remat, value and every gradient at the cell's
+    shape, compiled for the described chip: under `full` the forward kernel's `out` and
+    logsumexp are kept by name (`ops.attention.FLASH_NAMES`) and the program holds ONE
+    `flash_attention_fwd`, where it held a second in the rematerialised layer (PR 43); under
+    `dots`, which keeps neither (PERF.md section 7, after PR 26 (1)), it holds two as before.
+    One `_bwd_dq` and one `_bwd_dkv` either way."""
+    from ray_tpu.models import llama
+
+    cfg, file = _cell_file(config)
+    trainer = file["trainer"]
+    assert cfg.remat and cfg.remat_policy == ("full" if forward == (1, 0) else "dots")
+    stacks = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+    stack = next(st for st in stacks.values() if isinstance(st, dict) and "attn_norm" in st)
+    lp = {n: jax.ShapeDtypeStruct(stack[n].shape[1:], stack[n].dtype, sharding=one_chip)
+          for n in ("attn_norm", *llama._attn_axes(cfg))}
+    b, s = trainer["batch"], trainer["seq"]
+    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+
+    def loss(x, lp, pos):
+        with jax.named_scope("model"):  # as train/step.py
+            part = llama._maybe_remat(
+                lambda x, lp: llama._attention_part(x, lp, cfg, pos, None, None, None)[0], cfg)
+            return jnp.sum(part(x, lp).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp, pos).compile().as_text()
+    assert _kernel_calls(text, "flash_attention_fwd") == forward
+    assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (1, 0)
+    assert not re.search(r"^\s*(?:ROOT )?%[\w.\-]*\.remat", text, re.M)
 
 
 def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
