@@ -15,6 +15,17 @@ and A_log divide by heads, W_o by its rows; the two low-rank down-projections (r
 0.5 M each) and the norm weight are whole in every share. 8 shares of 8 heads add up to
 the layer of 64 through W_o (tests/test_solar_open2.py).
 
+The second and third lines (convolution, silu, the two L2 norms, q's scale, the one rounding
+to the activation's type) are ONE pass over HBM each way where ops/short_conv.py's two
+Pallas kernels tile the shape (a head width of whole 128-lane registers, at most 9 taps, no
+mesh axis left to GSPMD: the Solar-Open2 cell's 8 heads of 128, 4 taps): q, k and v in one
+call a pass, the results float32 arrays of values rounded to the activation's type, so that
+the scan's float32 cotangents come back unrounded (ops/short_conv.py's docstring has what
+that is worth, and why the cell's gradient comparison reads 0.876 where the plain form's
+program read 0.848). Anywhere else `_conv_silu_norm` runs, the plain form: a float32
+copy of q|k|v padded by the taps, the taps' shifted products, silu, the norms and the casts,
+a pass of XLA's each (tier-1's width of 16; what the kernels are tested against).
+
 Leaves: kda_norm [D], kda_qkv [D, 3, H, K], kda_conv [taps, 3, H, K] (the last tap is the
 current position's), kda_f_down [D, r], kda_f_up [r, H, K], kda_dt_bias [H, K], kda_A_log
 [H], kda_beta [D, H], kda_g_down [D, r], kda_g_up [r, H, K], kda_o_norm [K], kda_out
@@ -24,7 +35,7 @@ convolution would have to start again at a boundary, and no recurrent state is k
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import kda
+from ray_tpu.ops import kda, short_conv
 from ray_tpu.ops.quant import as_weight as _w
 
 from .config import ModelConfig
@@ -73,6 +84,18 @@ def _l2norm(x: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
 
+def _conv_silu_norm(x: jax.Array, w: jax.Array, width: int):
+    """x [B, T, 3 C] (q | k | v before the convolution), w [taps, 3 C] -> q, k, v [B, T, C] in
+    x's type, q and k of unit length over every `width` channels (a head) and q scaled: the
+    plain form, a pass of XLA's over HBM an operation. What ops/short_conv.py's kernels
+    compute in one, and what tier-1 holds them to."""
+    bsz, t, channels = x.shape
+    a = jax.nn.silu(_causal_conv(x, w, 0.0)).reshape(bsz, t, 3, channels // (3 * width), width)
+    q, k, v = jnp.moveaxis(a, 2, 0)
+    q, k = _l2norm(q) * width**-0.5, _l2norm(k)
+    return tuple(a.astype(x.dtype).reshape(bsz, t, channels // 3) for a in (q, k, v))
+
+
 def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     """x [B, T, D] -> x + the layer's output."""
     from .llama import rms_norm
@@ -90,11 +113,13 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
         beta = jnp.einsum("btd,dh->bth", u, _w(lp["kda_beta"], dt_))
     with jax.named_scope("kda_conv"):
         channels = 3 * h * width
-        qkv = jax.nn.silu(_causal_conv(qkv.reshape(bsz, t, channels), lp["kda_conv"].reshape(-1, channels), 0.0))
-        q, k, v = jnp.moveaxis(qkv.reshape(bsz, t, 3, h, width), 2, 0)
-        q, k = _l2norm(q) * width**-0.5, _l2norm(k)
-        q, k, v = q.astype(dt_), k.astype(dt_), v.astype(dt_)
+        qkv, conv_w = qkv.reshape(bsz, t, channels), lp["kda_conv"].reshape(-1, channels)
+        if short_conv.takes_kernels(channels, 3, width, conv_w.shape[0]):
+            q, k, v = short_conv.short_conv(qkv, conv_w, None, (width**-0.5, 1.0, None), width)
+        else:
+            q, k, v = _conv_silu_norm(qkv, conv_w, width)
     with jax.named_scope("kda_scan"):
+        q, k, v = (a.reshape(bsz, t, h, width) for a in (q, k, v))
         g = -jnp.exp(lp["kda_A_log"])[:, None] * jax.nn.softplus(decay.astype(f32) + lp["kda_dt_bias"])
         beta = jax.nn.sigmoid(beta.astype(f32)) * (2.0 if cfg.kda_neg_eigval else 1.0)
         o = kda.kda_scan(q, k, v, g, beta, cfg.kda_chunk)

@@ -635,8 +635,9 @@ def test_the_manifest_lists_the_cell_and_its_metrics():
         "setup_s", "train_tokens_per_s", "train_step_ms", "train_device_idle_pct", "train_device_step_ms",
         "train_attn_fwd_kernel_pct", "train_attn_bwd_kernel_pct", "train_moe_pct", "train_moe_gmm_mxu_pct",
         "train_moe_imbalance", "train_moe_router_pct", "train_optimizer_pct", "train_head_loss_pct",
-        "train_scoped_pct", "train_kda_pct", "train_kda_scan_roofline_pct", "train_mfu_kda_moe_pct"}
-    for name in ("train_kda_pct", "train_kda_scan_roofline_pct", "train_mfu_kda_moe_pct"):
+        "train_scoped_pct", "train_kda_pct", "train_kda_scan_roofline_pct", "train_mfu_kda_moe_pct",
+        "train_kda_conv_pct"}
+    for name in ("train_kda_pct", "train_kda_scan_roofline_pct", "train_mfu_kda_moe_pct", "train_kda_conv_pct"):
         assert os.path.exists(os.path.join(ROOT, "benchmarks", "metrics", f"{name}.json"))
 
 

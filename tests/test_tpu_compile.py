@@ -324,7 +324,8 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
     # of six blocks, 0.51 GB, and runs the kernel 3 times a step where it ran 6
     ("glm-4.7-flash-train-ep8", 2, 0, 6.54),
     # PR 37: four expert parts at 8 of 320 (the pick a slot at a time, as at 22 of 512), three
-    # delta-rule scans whose triangular systems are inverted once each and kept
+    # delta-rule scans whose triangular systems are inverted once each and kept. PR 44: 4.650 ->
+    # 4.644 GB, the float32 `[1, 8195, 3072]` padded copies and the taps' products gone (the figure stays)
     ("solar-open2-train-tp8-ep40", 4, 2, 4.67),
     # PR 42: four expert parts at 4 of 64 over 32,768 tokens (the pick a slot at a time: 8.4 M mask
     # elements), no shared expert, beside four gated short convolutions, a dense part and attention
@@ -374,6 +375,12 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
         assert _kernel_calls(text, "kda_overlaps_fwd") == (parts, parts)
         assert _kernel_calls(text, "kda_overlaps_bwd") == (parts, 0)
         assert not re.search(_OVERLAPS_INTERMEDIATES, text)
+        # the convolution, silu and norms of q, k and v: ONE call a part and pass whatever the three
+        # (9 a step; a call for each of q, k, v was 27, and 3 s of every first step: PR 44), and the
+        # plain form's float32 copy of q|k|v padded by the taps is gone with its shifted products
+        assert _kernel_calls(text, "short_conv_fwd") == (parts, parts)
+        assert _kernel_calls(text, "short_conv_bwd") == (parts, 0)
+        assert not re.search(_CONV_PADDED_COPY, text)
         assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
 
 
@@ -494,6 +501,9 @@ def _kernel_calls(text, name):
     return len(calls) - again, again
 
 
+# q|k|v `[1, 8192, 3072]` in float32 with the taps' 3 rows of zeros in front: `ssm._causal_conv`'s copy
+_CONV_PADDED_COPY = r"f32\[1,8195,3072\]"
+
 # float32 arrays of every chunk with the extents of a sub-chunk's differences [.., 32, 32, 128]
 # or of the sub-chunks' factors [.., 4, 128, 128]: what `_decayed_overlaps` wrote to HBM
 _OVERLAPS_INTERMEDIATES = r"f32\[(\d+,)+(32,32,128|4,128,128)\]"
@@ -504,6 +514,7 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     positions in 64 chunks of 128), value and every gradient under the cell's remat: the
     overlaps are the two Pallas kernels by name (forward, forward again in the
     rematerialised layer, backward: ISSUE 38's item 5 was not taken, PERF.md section 6), the
+    convolution, silu and norms of q, k and v likewise two kernels and three calls (PR 44), the
     inverse the compiler's own triangular kernel once a block, no float32 array with the
     extents of the differences or the sub-chunks' factors of all chunks, the scan's float32
     intermediates beside the projections' under 2 GB."""
@@ -524,7 +535,9 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
     text = compiled.as_text()
     assert _kernel_calls(text, "kda_overlaps_fwd") == (1, 1) and _kernel_calls(text, "kda_overlaps_bwd") == (1, 0)
-    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 3
+    assert _kernel_calls(text, "short_conv_fwd") == (1, 1) and _kernel_calls(text, "short_conv_bwd") == (1, 0)
+    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 6
+    assert not re.search(_CONV_PADDED_COPY, text)
     assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == cfg.kda_chunk // _SOLVE
     assert not re.search(_OVERLAPS_INTERMEDIATES, text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
