@@ -80,14 +80,8 @@ def config_from_hf(source_dir: str, **overrides) -> ModelConfig:
         fields["moe_top1_renorm"] = bool(hf.get("moe_top1_renorm", True))
         fields["moe_capacity_factor"] = float(
             hf.get("moe_capacity_factor", e / k))
-    if hf.get("model_type") == "glm4_moe_lite":
-        fields.update(_glm4_moe_lite_fields(hf))
-    if hf.get("model_type") == "nemotron_h":
-        fields.update(_nemotron_h_fields(hf))
-    if hf.get("model_type") == "solar_open2":
-        fields.update(_solar_open2_fields(hf))
-    if hf.get("model_type") == "lfm2_moe":
-        fields.update(_lfm2_moe_fields(hf))
+    if hf.get("model_type") in _FAMILY_FIELDS:
+        fields.update(_FAMILY_FIELDS[hf["model_type"]](hf))
     fields.update(overrides)
     return ModelConfig(**fields)
 
@@ -262,6 +256,11 @@ def _glm4_moe_lite_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
         moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)),
         mtp_depth=hf.get("num_nextn_predict_layers", 0),
     )
+
+
+# a family's keys as ModelConfig fields, by its `model_type` (config_from_hf)
+_FAMILY_FIELDS = {"glm4_moe_lite": _glm4_moe_lite_fields, "nemotron_h": _nemotron_h_fields,
+                  "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields}
 
 
 def config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:

@@ -5,10 +5,24 @@ SwiGLU, untied or tied embeddings).
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 from typing import Dict, Tuple
 
 import jax.numpy as jnp
+
+# One character of a layer pattern: the stack of params its layers lie in, its mixer and its
+# feed-forward part (rows of models/llama.py's MIXERS and FEED_FORWARD; either or none), and
+# what a message calls it. Pure data: this module imports no model code.
+LayerKind = collections.namedtuple("LayerKind", "stack mixer ff says")
+LAYER_KINDS = {
+    "M": LayerKind("ssm_layers", "ssm", None, "Mamba-2"),
+    "K": LayerKind("kda_layers", "kda", None, "Kimi Delta Attention"),
+    "C": LayerKind("sconv_layers", "sconv", None, "gated short convolution"),
+    "E": LayerKind("layers", None, "experts", "experts"),
+    "*": LayerKind("attn_layers", "attn", None, "attention"),
+    "-": LayerKind("mlp_layers", None, "dense", "MLP"),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,12 +167,12 @@ class ModelConfig:
         object.__setattr__(self, "experts_held", tuple(self.experts_held))
         object.__setattr__(self, "attn_heads_held", tuple(self.attn_heads_held))
         if self.layer_pattern:
-            unknown = set(self.layer_pattern) - set("MKCE*-")
+            unknown = set(self.layer_pattern) - set(LAYER_KINDS)
             if unknown or len(self.layer_pattern) != self.n_layers:
                 raise ValueError(
-                    f"layer_pattern {self.layer_pattern!r}: one of M (Mamba-2) K (Kimi Delta "
-                    f"Attention) C (gated short convolution) E (experts) * (attention) - (MLP) "
-                    f"a layer, n_layers ({self.n_layers}) of them")
+                    f"layer_pattern {self.layer_pattern!r}: one of "
+                    + " ".join(f"{c} ({kind.says})" for c, kind in LAYER_KINDS.items())
+                    + f" a layer, n_layers ({self.n_layers}) of them")
             if "K" in self.layer_pattern and not self.kda_n_heads:
                 raise ValueError("layer_pattern has K layers: kda_n_heads says how many heads one holds")
             if self.n_dense_layers:
@@ -225,44 +239,11 @@ class ModelConfig:
 
     @property
     def n_params(self) -> int:
-        """Approximate parameter count (embeddings + blocks + norms), of what is held."""
-        d = self.d_model
-        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        if self.latent_attention:
-            h, qk = self.heads_held, self.head_dim
-            attn = (d * self.q_lora_rank + self.q_lora_rank * h * qk
-                    + d * (self.kv_lora_rank + self.qk_rope_head_dim)
-                    + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
-                    + h * self.v_head_dim * d + self.q_lora_rank + self.kv_lora_rank)
-        else:
-            attn = (d * self.head_dim * ((2 + self.attn_output_gate) * self.heads_held + 2 * self.kv_heads_held)
-                    + 2 * self.head_dim * self.attn_qk_norm)
-        mats = 3 if self.mlp_activation == "silu_gated" else 2  # a gated MLP has one more
-        mlp = mats * d * self.d_ff
-        norms = 2 * d
-        if self.layer_pattern:
-            latent = self.moe_latent_dim or d
-            ssm = (d * (self.ssm_d_inner + self.ssm_conv_dim + self.ssm_n_heads)  # z | xBC | dt
-                   + (self.ssm_conv_taps + 1) * self.ssm_conv_dim + 3 * self.ssm_n_heads
-                   + self.ssm_d_inner + self.ssm_d_inner * d)
-            inner = self.kda_d_inner
-            kda = (d * 3 * inner + self.kda_conv_taps * 3 * inner + d * self.kda_n_heads  # q k v, beta
-                   + 2 * (d + inner) * self.kda_rank  # the decay's and the gate's low-rank pairs
-                   + self.kda_n_heads + inner + self.kda_head_dim + inner * d)  # A_log, dt_bias, norm, W_o
-            sconv = d * 3 * d + self.conv_taps * d + d * d  # [B | C | x], the taps, W_out
-            experts = (d * self.n_experts + self.n_experts_held * mats * latent * (self.d_ff_expert or self.d_ff)
-                       + mats * d * self.shared_width + (2 * d * latent if self.moe_latent_dim else 0))
-            kind = {"M": ssm + d, "K": kda + d, "C": sconv + d, "*": attn + d, "E": experts + d,
-                    "-": mlp + d}
-            return (emb + d + sum(kind[c] for c in self.layer_pattern)
-                    + self.mtp_depth * (attn + experts + norms + 2 * d * d + 3 * d))
-        if not self.moe_dropless:
-            return emb + self.n_layers * (attn + mlp + norms) + d
-        expert = 3 * d * (self.d_ff_expert or self.d_ff)
-        moe = (self.n_experts_held + self.n_shared_experts) * expert + d * self.n_experts
-        return (emb + d + self.n_dense_layers * (attn + mlp + norms)
-                + (self.n_layers - self.n_dense_layers + self.mtp_depth) * (attn + moe + norms)
-                + self.mtp_depth * (2 * d * d + 3 * d))
+        """Approximate parameter count (embeddings + blocks + norms), of what is held: the sum
+        of what each layer kind's parts count beside their `init` (llama.n_params)."""
+        from . import llama  # at the call: this module imports no model code
+
+        return llama.n_params(self)
 
 
 _REGISTRY: Dict[str, ModelConfig] = {}

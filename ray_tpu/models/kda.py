@@ -13,7 +13,7 @@ The count is what the layer HOLDS. A tensor-parallel share of a published layer 
 heads of the same width: q, k, v, the convolution, beta, the two up-projections, dt_bias
 and A_log divide by heads, W_o by its rows; the two low-rank down-projections (rank 128,
 0.5 M each) and the norm weight are whole in every share. 8 shares of 8 heads add up to
-the layer of 64 through W_o (tests/test_solar_open2.py).
+the layer of 64 through W_o (tests/test_family_solar_open2.py).
 
 The second and third lines (convolution, silu, the two L2 norms, q's scale, the one rounding
 to the activation's type) are ONE pass over HBM each way where ops/short_conv.py's two
@@ -38,9 +38,13 @@ import jax.numpy as jnp
 from ray_tpu.ops import kda, short_conv
 from ray_tpu.ops.quant import as_weight as _w
 
+from .attn import rms_norm
 from .config import ModelConfig
 from .ssm import _causal_conv
 
+# what llama.py's table of layer kinds reads of a mixer (its comment says what each is);
+# kept under every remat policy: the inverses of the scan's triangular systems
+LEAF, RECURRENT, SCOPE, KEPT = "kda_qkv", "Kimi-Delta-Attention", "attn", {"every": (kda.INVERSE_NAME,)}
 AXES = {
     "kda_norm": ("embed",), "kda_qkv": ("embed", None, "heads", "head_dim"),
     "kda_conv": (None, None, "heads", "head_dim"),
@@ -80,6 +84,13 @@ def init(key: jax.Array, cfg: ModelConfig):
     }
 
 
+def n_params(cfg: ModelConfig) -> int:
+    d, inner = cfg.d_model, cfg.kda_d_inner
+    return (d + d * 3 * inner + cfg.kda_conv_taps * 3 * inner + d * cfg.kda_n_heads  # the norm; q k v, beta
+            + 2 * (d + inner) * cfg.kda_rank  # the decay's and the gate's low-rank pairs
+            + cfg.kda_n_heads + inner + cfg.kda_head_dim + inner * d)  # A_log, dt_bias, norm, W_o
+
+
 def _l2norm(x: jax.Array) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
 
@@ -98,8 +109,6 @@ def _conv_silu_norm(x: jax.Array, w: jax.Array, width: int):
 
 def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     """x [B, T, D] -> x + the layer's output."""
-    from .llama import rms_norm
-
     dt_, f32 = x.dtype, jnp.float32
     bsz, t, _ = x.shape
     h, width = cfg.kda_n_heads, cfg.kda_head_dim

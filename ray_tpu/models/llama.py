@@ -11,31 +11,53 @@ this is the flagship model its Train/Serve equivalents here exercise.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
+import types
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import attention
-from ray_tpu.ops.attention import FLASH_NAMES, ROTATED_NAMES, Rotation
 from ray_tpu.ops.quant import as_weight as _w
-from ray_tpu.parallel.sharding import auto_spec
 from ray_tpu.parallel.sharding import with_sharding_constraint as wsc
 
-from .config import ModelConfig
+from . import attn, kda, moe, sconv, ssm
+from .attn import attn_out, qkv_proj, rms_norm, rope, rope_pairs_to_halves  # noqa: F401  (llm/ calls them here)
+from .config import LAYER_KINDS, ModelConfig
 
 Params = Dict[str, Any]
 
 
+# ------------------------------------------------------------------ a layer's parts
+# A layer kind (config.LAYER_KINDS: its character in a pattern, its stack) names a mixer
+# and a feed-forward part, either or none. Each part is a module of one shape, read here
+# and nowhere by name: `AXES` (every leaf it can have: a layer's are those its `init`
+# makes), `init(keys, cfg)`, `n_params(cfg)` (beside the `init` whose leaves it counts) and
+# `KEPT`, the residuals of its kernels that a remat policy keeps by name ({policy or
+# "every": names}); a mixer also `mixer(x, lp, cfg)`, `LEAF` (the
+# leaf that tells `_block` a layer's parameters hold it), `RECURRENT` (None, or the name
+# under which packed documents and a KV cache are refused) and `SCOPE` (the scope `_block`
+# runs it under, or None). The second of a row is which of the SEVEN keys a layer splits
+# its own into the part's `init` draws from: a seed gives the weights it always gave.
+
+
+def _dense_init(ks: jax.Array, cfg: ModelConfig) -> Params:
+    d, width = cfg.d_model, cfg.d_ff
+    shapes = {"w_gate": (ks[0], (d, width), d**-0.5), "w_up": (ks[1], (d, width), d**-0.5),
+              "w_down": (ks[2], (width, d), (2 * cfg.n_layers * width) ** -0.5)}
+    return {n: jax.random.normal(shapes[n][0], shapes[n][1], jnp.float32) * shapes[n][2]
+            for n in moe.mlp_leaves(cfg)}
+
+
+_dense = types.SimpleNamespace(
+    AXES={"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}, init=_dense_init,
+    n_params=lambda cfg: len(moe.mlp_leaves(cfg)) * cfg.d_model * cfg.d_ff, KEPT={})
+MIXERS = {"attn": (attn, slice(0, 4)), "ssm": (ssm, 0), "kda": (kda, 0), "sconv": (sconv, 0)}
+FEED_FORWARD = {"experts": (moe, 4), "dense": (_dense, slice(4, 7))}
+
+
 # ---------------------------------------------------------------------------- init
-
-# A pattern's characters (config.layer_pattern): the stack its layers lie in, their
-# mixer and their feed-forward part. A layer without a pattern has both parts.
-_PATTERN = {"M": ("ssm_layers", "ssm", None), "K": ("kda_layers", "kda", None),
-            "C": ("sconv_layers", "sconv", None), "*": ("attn_layers", "attn", None),
-            "E": ("layers", None, "experts"), "-": ("mlp_layers", None, "dense")}
-
 
 def _layer_kinds(cfg: ModelConfig) -> Dict[str, Tuple[int, Optional[str], Optional[str]]]:
     """The stacks of params: name -> (layers, mixer: attn | ssm | kda | sconv | None, feed-forward
@@ -49,8 +71,8 @@ def _layer_kinds(cfg: ModelConfig) -> Dict[str, Tuple[int, Optional[str], Option
     if cfg.layer_pattern:
         kinds = {}
         for c in cfg.layer_pattern:
-            name, mixer, ff = _PATTERN[c]
-            kinds[name] = (kinds.get(name, (0,))[0] + 1, mixer, ff)
+            kind = LAYER_KINDS[c]
+            kinds[kind.stack] = (kinds.get(kind.stack, (0,))[0] + 1, kind.mixer, kind.ff)
         return kinds
     kinds = {}
     if cfg.n_dense_layers:
@@ -71,50 +93,24 @@ def pattern_period(pattern: str) -> Tuple[str, int]:
     return pattern[:size], len(pattern) // size
 
 
-def _attn_axes(cfg: ModelConfig) -> Params:
-    if cfg.latent_attention:
-        if cfg.attn_output_gate or cfg.attn_qk_norm:
-            raise NotImplementedError("an output gate or a norm a head on latent attention")
-        return {
-            "wq_a": ("embed", "latent"), "q_norm": ("latent",),
-            "wq_b": ("latent", "heads", "head_dim"),
-            "wkv_a": ("embed", "latent"), "kv_norm": ("latent",),
-            "wkv_b": ("latent", "heads", "head_dim"),
-            "wo": ("heads", "head_dim", "embed"),
-        }
-    return {
-        "wq": ("embed", "heads", "head_dim"),
-        "wk": ("embed", "kv_heads", "head_dim"),
-        "wv": ("embed", "kv_heads", "head_dim"),
-        "wo": ("heads", "head_dim", "embed"),
-        **({"wo_gate": ("embed", "heads", "head_dim")} if cfg.attn_output_gate else {}),
-        **({"q_head_norm": ("head_dim",), "k_head_norm": ("head_dim",)} if cfg.attn_qk_norm else {}),
-    }
+def _layer_init(key: jax.Array, cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Params:
+    ks = jax.random.split(key, 7)
+    out = {}
+    if mixer:
+        part, keys = MIXERS[mixer]
+        out.update(part.init(ks[keys], cfg))
+    if ff:
+        part, keys = FEED_FORWARD[ff]
+        out.update(mlp_norm=jnp.ones((cfg.d_model,), jnp.float32), **part.init(ks[keys], cfg))
+    return out
 
 
+@functools.lru_cache(maxsize=None)  # (an abstract `init` a call otherwise; callers copy, never write)
 def _layer_axes(cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Params:
-    """One layer's logical axes (no leading 'layer' axis)."""
-    from . import kda as _kda
-    from . import moe as _moe
-    from . import ssm as _ssm
-
-    axes = {}
-    if mixer == "attn":
-        axes.update({"attn_norm": ("embed",), **_attn_axes(cfg)})
-    elif mixer == "ssm":
-        axes.update(_ssm.AXES)
-    elif mixer == "kda":
-        axes.update(_kda.AXES)
-    elif mixer == "sconv":
-        from . import sconv as _sconv
-
-        axes.update(_sconv.AXES)
-    if ff == "experts":
-        axes.update({"mlp_norm": ("embed",), **_moe.expert_axes(cfg)})
-    elif ff == "dense":
-        dense = {"w_gate": ("embed", "mlp"), "w_up": ("embed", "mlp"), "w_down": ("mlp", "embed")}
-        axes.update({"mlp_norm": ("embed",), **{n: dense[n] for n in _moe.mlp_leaves(cfg)}})
-    return axes
+    """One layer's logical axes (no leading 'layer' axis): its parts', of the leaves their `init` makes."""
+    axes = {"mlp_norm": ("embed",), **(MIXERS[mixer][0].AXES if mixer else {}), **(FEED_FORWARD[ff][0].AXES if ff else {})}
+    made = jax.eval_shape(functools.partial(_layer_init, cfg=cfg, mixer=mixer, ff=ff), jax.random.PRNGKey(0))
+    return {leaf: axes[leaf] for leaf in made}
 
 
 def param_axes(cfg: ModelConfig) -> Params:
@@ -138,70 +134,29 @@ def param_axes(cfg: ModelConfig) -> Params:
     return axes
 
 
+def n_params(cfg: ModelConfig) -> int:
+    """Approximate parameter count (embeddings + blocks + norms), of what is held: each
+    part's own count (beside its `init`), a norm a feed-forward part. Capacity-based
+    experts count as the dense MLP they stand in for, as they always have."""
+    d = cfg.d_model
+
+    def layer(mixer, ff):
+        ff = "dense" if ff and not cfg.moe_dropless else ff
+        return ((MIXERS[mixer][0].n_params(cfg) if mixer else 0)
+                + (FEED_FORWARD[ff][0].n_params(cfg) + d if ff else 0))
+
+    return (cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + d
+            + sum(n * layer(mixer, ff) for n, mixer, ff in _layer_kinds(cfg).values())
+            + cfg.mtp_depth * (layer("attn", "experts") + 2 * d * d + 3 * d))
+
+
 def init(rng: jax.Array, cfg: ModelConfig) -> Params:
     """Initialize parameters (f32). Scaled-normal init, wo/w_down scaled by depth."""
     k_emb, k_head, k_layers = jax.random.split(rng, 3)
-    d, hd, nh, nkv, ff_width = cfg.d_model, cfg.head_dim, cfg.heads_held, cfg.kv_heads_held, cfg.d_ff
+    d = cfg.d_model
 
     def norm(key, shape, scale):
         return (jax.random.normal(key, shape, jnp.float32) * scale).astype(jnp.float32)
-
-    s_in = d**-0.5
-    s_out = (2 * cfg.n_layers * d) ** -0.5
-
-    def attn_init(ks):
-        if not cfg.latent_attention:
-            out = {
-                "wq": norm(ks[0], (d, nh, hd), s_in),
-                "wk": norm(ks[1], (d, nkv, hd), s_in),
-                "wv": norm(ks[2], (d, nkv, hd), s_in),
-                "wo": norm(ks[3], (nh, hd, d), s_out),
-            }
-            if cfg.attn_output_gate:
-                out["wo_gate"] = norm(jax.random.fold_in(ks[0], 1), (d, nh, hd), s_in)
-            if cfg.attn_qk_norm:
-                out.update(q_head_norm=jnp.ones((hd,), jnp.float32), k_head_norm=jnp.ones((hd,), jnp.float32))
-            return out
-        qr, kvr, rd = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
-        ka, kb = jax.random.split(ks[1])
-        return {
-            "wq_a": norm(ks[0], (d, qr), s_in), "q_norm": jnp.ones((qr,), jnp.float32),
-            "wq_b": norm(ka, (qr, nh, hd), qr**-0.5),
-            # the latent and, behind it, the rotated key every head shares
-            "wkv_a": norm(kb, (d, kvr + rd), s_in), "kv_norm": jnp.ones((kvr,), jnp.float32),
-            "wkv_b": norm(ks[2], (kvr, nh, cfg.qk_nope_head_dim + cfg.v_head_dim), kvr**-0.5),
-            "wo": norm(ks[3], (nh, cfg.v_head_dim, d), s_out),
-        }
-
-    def layer_init(key, mixer: Optional[str], ff: Optional[str]):
-        from . import kda as _kda
-        from . import moe as _moe
-        from . import ssm as _ssm
-
-        ks = jax.random.split(key, 7)
-        out = {}
-        if mixer == "attn":
-            out.update({"attn_norm": jnp.ones((d,), jnp.float32), **attn_init(ks)})
-        elif mixer == "ssm":
-            out.update(_ssm.init(ks[0], cfg))
-        elif mixer == "kda":
-            out.update(_kda.init(ks[0], cfg))
-        elif mixer == "sconv":
-            from . import sconv as _sconv
-
-            out.update(_sconv.init(ks[0], cfg))
-        if ff is not None:
-            out["mlp_norm"] = jnp.ones((d,), jnp.float32)
-        if ff == "experts":
-            out.update(_moe.init_expert_weights(ks[4], cfg))
-        elif ff == "dense":
-            dense = {
-                "w_gate": norm(ks[4], (d, ff_width), s_in),
-                "w_up": norm(ks[5], (d, ff_width), s_in),
-                "w_down": norm(ks[6], (ff_width, d), (2 * cfg.n_layers * ff_width) ** -0.5),
-            }
-            out.update({n: dense[n] for n in _moe.mlp_leaves(cfg)})
-        return out
 
     # a tied table is drawn at the head's scale: as the head it makes logits of order 1,
     # where a table of unit entries made them of order sqrt(d) (every part norms its input,
@@ -213,7 +168,7 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
     else:
         kind_keys = dict(zip(kinds, jax.random.split(k_layers, len(kinds))))
     for name, (n, mixer, ff) in kinds.items():
-        params[name] = jax.vmap(functools.partial(layer_init, mixer=mixer, ff=ff))(
+        params[name] = jax.vmap(functools.partial(_layer_init, cfg=cfg, mixer=mixer, ff=ff))(
             jax.random.split(kind_keys[name], n))
     params["final_norm"] = jnp.ones((d,), jnp.float32)
     if not cfg.tie_embeddings:
@@ -226,7 +181,7 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
                 "hidden_norm": jnp.ones((d,), jnp.float32),
                 "eh_proj": norm(k_proj, (2 * d, d), (2 * d) ** -0.5),
                 "final_norm": jnp.ones((d,), jnp.float32),
-                **layer_init(k_block, "attn", "experts"),
+                **_layer_init(k_block, cfg, "attn", "experts"),
             }
 
         params["mtp"] = jax.vmap(mtp_init)(
@@ -261,27 +216,23 @@ def _maybe_remat(body, cfg: ModelConfig):
       again;
     - the inverses of a delta-rule mixer's triangular systems (ops/kda.py names them:
       [Q, Q] a chunk and head, 32 MB a layer at 8 heads and 8,192 positions): the
-      substitution is the scan's slowest kernel."""
+      substitution is the scan's slowest kernel.
+
+    Which names those are, each part says itself (`KEPT`, by policy or under "every")."""
     policy = cfg.remat_policy
     if not cfg.remat or policy == "none":
         return body
-    from ray_tpu.ops.kda import INVERSE_NAME
-
-    from . import moe as _moe
-
     policies = jax.checkpoint_policies
-    routed = (*_moe.ROUTER_NAMES, INVERSE_NAME)
-    kept = policies.save_only_these_names(*ROTATED_NAMES, *routed)
-    if policy == "dots":
-        return jax.checkpoint(
-            body, policy=policies.save_from_both_policies(policies.checkpoint_dots, kept))
-    if policy == "dots_no_batch":
-        return jax.checkpoint(body, policy=policies.save_from_both_policies(
-            policies.dots_with_no_batch_dims_saveable, kept))
-    if policy != "full":
+    if policy not in ("full", "dots", "dots_no_batch"):
         raise ValueError(
             f"unknown remat_policy {policy!r} (expected full | dots | dots_no_batch | none)")
-    return jax.checkpoint(body, policy=policies.save_only_these_names(*FLASH_NAMES, *routed))
+    kept = policies.save_only_these_names(*(
+        name for part, _ in (*MIXERS.values(), *FEED_FORWARD.values())
+        for name in (*part.KEPT.get(policy, ()), *part.KEPT.get("every", ()))))
+    if policy == "full":
+        return jax.checkpoint(body, policy=kept)
+    dots = policies.checkpoint_dots if policy == "dots" else policies.dots_with_no_batch_dims_saveable
+    return jax.checkpoint(body, policy=policies.save_from_both_policies(dots, kept))
 
 
 # A row shorter than this is looked up with a gather whatever the mesh: the
@@ -323,25 +274,6 @@ def embed_tokens(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Arr
     return jnp.einsum("bsv,vd->bsd", onehot, table)
 
 
-def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps) * scale).astype(dtype)
-
-
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half RoPE (HF Llama convention). x: [B, S, H, D], positions: [B, S]."""
-    d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
-    angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,S,D/2]
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
-
-
 # --------------------------------------------------------------------------- block
 # The decoder block's arithmetic, written once. A program is these parts around an
 # attention over its own cache: _block (train step, prefill: ops.attention or ring
@@ -351,64 +283,6 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 def _unconstrained(x: jax.Array, *logical_axes) -> jax.Array:
     return x
-
-
-def qkv_proj(x: jax.Array, lp: Params, cfg: ModelConfig, positions: Optional[jax.Array]):
-    """Attention's inputs for one layer: norm, the projections, a norm a head of q and k
-    where the layer has one (cfg.attn_qk_norm: before the rotation), RoPE.
-    x [B, S, D], positions [B, S] -> q [B, S, H, hd], k and v [B, S, KV, hd].
-    Without positions q and k come back un-rotated: the caller hands the rotation on
-    (latent attention rotates a slice of its heads and always needs them)."""
-    dt = x.dtype
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    if cfg.latent_attention:
-        return _latent_qkv(h, lp, cfg, positions)
-    q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
-    if "q_head_norm" in lp:
-        q, k = rms_norm(q, lp["q_head_norm"], cfg.norm_eps), rms_norm(k, lp["k_head_norm"], cfg.norm_eps)
-    if positions is None:
-        return q, k, v
-    return rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta), v
-
-
-def rope_pairs_to_halves(d: int):
-    """Where each column of a rotated slice lies in a published checkpoint: the
-    DeepSeek-V3 lineage rotates the pairs (2i, 2i + 1), `rope` the pairs (i, i + d/2).
-    Column j here is the checkpoint's column perm[j]; scores do not see the order, as q
-    and k share it. (models/reference/ rotates pairs on the columns put back.)"""
-    return [2 * i for i in range(d // 2)] + [2 * i + 1 for i in range(d // 2)]
-
-
-def _latent_qkv(h: jax.Array, lp: Params, cfg: ModelConfig, positions: jax.Array):
-    """Latent attention's q, k and v from the normed input h [B, S, D]: q through its
-    low-rank latent; k's un-rotated part and v from the shared latent, k's rotated part
-    one key for all heads. Nothing is absorbed: what comes out is plain multi-head
-    attention's input, [B, S, H, nope + rope] twice and [B, S, H, v]."""
-    dt = h.dtype
-    nope, kvr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    if cfg.v_head_dim != cfg.head_dim:
-        raise NotImplementedError(
-            f"latent attention with v heads {cfg.v_head_dim} wide beside q/k heads "
-            f"{cfg.head_dim} wide: ops.attention takes one width")
-    with jax.named_scope("mla_q"):
-        cq = rms_norm(jnp.einsum("bsd,dr->bsr", h, _w(lp["wq_a"], dt)), lp["q_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsr,rhk->bshk", cq, _w(lp["wq_b"], dt))
-        q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
-    with jax.named_scope("mla_kv"):
-        ckv = jnp.einsum("bsd,dr->bsr", h, _w(lp["wkv_a"], dt))
-        k_rot = rope(ckv[:, :, None, kvr:], positions, cfg.rope_theta)  # [B, S, 1, rope]
-        kv = jnp.einsum("bsr,rhk->bshk", rms_norm(ckv[..., :kvr], lp["kv_norm"], cfg.norm_eps),
-                        _w(lp["wkv_b"], dt))
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_rot, (*kv.shape[:3], k_rot.shape[-1]))], -1)
-    return q, k, kv[..., nope:]
-
-
-def attn_out(x: jax.Array, attn: jax.Array, lp: Params) -> jax.Array:
-    """Output projection of attn [B, S, H, hd] and the residual."""
-    return x + jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], x.dtype))
 
 
 def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
@@ -433,12 +307,10 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
         ff = constrain(act, "batch", "seq", "act_mlp")
         down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
         return x + down, jnp.zeros((), jnp.float32)
-    from . import moe as _moe
-
     if cfg.moe_dropless:
-        y2, aux = _moe.expert_layer(h.reshape(b * s, d), lp, cfg)
+        y2, aux = moe.expert_layer(h.reshape(b * s, d), lp, cfg)
     else:
-        y2, aux = _moe.moe_mlp(
+        y2, aux = moe.moe_mlp(
             h.reshape(b * s, d), lp["router"], lp["w_gate"], lp["w_up"],
             lp["w_down"], cfg,
             mask=None if token_mask is None else token_mask.reshape(b * s),
@@ -493,91 +365,24 @@ def _block(
     layer; the others attention and a feed-forward part in each: the decoder block.
     Returns (x, updated (k,v) if caching, moe aux loss)."""
     new_kv, aux = None, jnp.zeros((), jnp.float32)
-    recurrent = ("Mamba-2" if "in_proj" in lp else "Kimi-Delta-Attention" if "kda_qkv" in lp
-                 else "gated short-convolution" if "sconv_in" in lp else None)
-    if recurrent:
+    part = next((part for part, _ in MIXERS.values() if part.LEAF in lp), None)
+    if part is not None and part.RECURRENT:
         if segment_ids is not None or cache_kv is not None:
             raise NotImplementedError(
-                f"a {recurrent} layer over packed documents (segment_ids: state and convolution do "
+                f"a {part.RECURRENT} layer over packed documents (segment_ids: state and convolution do "
                 "not start again at a boundary yet) or under a KV cache (no recurrent state or "
                 "convolution tail is kept)")
-        from . import kda as _kda
-        from . import ssm as _ssm
-
-        if recurrent == "Mamba-2":
-            x = wsc(_ssm.mixer(x, lp, cfg), "batch", "seq", "act_embed")
-        elif recurrent == "Kimi-Delta-Attention":
-            # under `attn`, where the readers of the trace look for a layer's mixer
-            # (benchmarks/metrics/train_scoped_pct.json, train_head_loss_pct.json)
-            with jax.named_scope("attn"):
-                x = wsc(_kda.mixer(x, lp, cfg), "batch", "seq", "act_embed")
-        else:
-            from . import sconv as _sconv
-
-            with jax.named_scope("attn"):  # as the Kimi-Delta-Attention mixer's
-                x = wsc(_sconv.mixer(x, lp, cfg), "batch", "seq", "act_embed")
-    elif "attn_norm" in lp:
-        x, new_kv = _attention_part(x, lp, cfg, positions, segment_ids, cache_kv, cache_len)
+        # `attn` is where the readers of the trace look for a layer's mixer
+        # (benchmarks/metrics/train_scoped_pct.json, train_head_loss_pct.json)
+        with jax.named_scope(part.SCOPE) if part.SCOPE else contextlib.nullcontext():
+            x = wsc(part.mixer(x, lp, cfg), "batch", "seq", "act_embed")
+    elif part is not None:
+        x, new_kv = part.mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len)
     if "mlp_norm" in lp:
         with jax.named_scope("mlp"):
             x, aux = feed_forward(x, lp, cfg, token_mask, constrain=wsc)
             x = wsc(x, "batch", "seq", "act_embed")
     return x, new_kv, aux
-
-
-def _attention_part(x, lp, cfg, positions, segment_ids, cache_kv, cache_len):
-    """The block's attention: (x + attention's output, updated (k, v) if caching)."""
-    # named scopes: metadata only (free at run time); what a reader of the
-    # profile uses to tell one fusion from another
-    with jax.named_scope("attn"):
-        # ops.attention rotates q and k itself (in its kernel's own pass over them, where
-        # the Pallas path runs); a cache or the ring takes them rotated
-        rotate = cfg.attention_rotation
-        deferred = (rotate and cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
-                    and not cfg.latent_attention)
-        q, k, v = qkv_proj(x, lp, cfg, positions if rotate and not deferred else None)
-        q = wsc(q, "batch", "seq", "act_heads", "head_dim")
-
-        new_kv = None
-        if cache_kv is not None:
-            ck, cv = cache_kv
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_len, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_len, axis=1)
-            new_kv = (ck, cv)
-            attn = attention(
-                q, ck, cv, causal=True, q_offset=cache_len, kv_valid_len=cache_len + q.shape[1]
-            )
-        elif cfg.attention_impl in ("ring", "ulysses"):
-            # Sequence-parallel attention: activations stay seq-sharded over "sp"; KV chunks
-            # ride the ICI ring (ops/ring_attention.py). If "sp" is already bound manually
-            # (pipeline stage traced with extra_manual=("sp",)), call the collective form
-            # directly — nested shard_map is not composable.
-            from ray_tpu.ops import ring_attention as ra
-            from ray_tpu.parallel.sharding import active_manual_axes
-
-            if "sp" in active_manual_axes():
-                if cfg.attention_impl == "ring":
-                    attn = ra.ring_attention(q, k, v, causal=True, segment_ids=segment_ids)
-                else:
-                    if segment_ids is not None:
-                        # mirror ring_attention_sharded's refusal — dropping the
-                        # packing mask here would silently attend across documents
-                        raise NotImplementedError(
-                            "segment_ids only supported with impl='ring'")
-                    attn = ra.ulysses_attention(q, k, v, causal=True)
-            else:
-                attn = ra.ring_attention_sharded(
-                    q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl
-                )
-        else:
-            attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
-                             shard_spec=auto_spec("batch", None, "act_heads", None),
-                             rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
-        if "wo_gate" in lp:  # a channel of the output, from the layer's normed input
-            gate = jnp.einsum("bsd,dhk->bshk", rms_norm(x, lp["attn_norm"], cfg.norm_eps),
-                              _w(lp["wo_gate"], x.dtype))
-            attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
-        return wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed"), new_kv
 
 
 def _pipeline_layers(
@@ -608,7 +413,7 @@ def _pipeline_layers(
     )
     seq_manual = cfg.attention_impl in ("ring", "ulysses")
 
-    moe = cfg.n_experts > 0
+    has_experts = cfg.n_experts > 0
     from jax.sharding import PartitionSpec as P
 
     side = {}
@@ -642,7 +447,7 @@ def _pipeline_layers(
         aux0 = vary_like(jnp.zeros((), jnp.float32), xm)
         fn = _maybe_remat(body, cfg)
         (out, aux), _ = jax.lax.scan(fn, (xm, aux0), stage_params)
-        return (out, aux) if moe else out
+        return (out, aux) if has_experts else out
 
     m = cfg.pipeline_microbatches or pp
 
@@ -653,11 +458,11 @@ def _pipeline_layers(
         num_microbatches=m,
         x_spec=P(None, "sp", None) if seq_manual else None,
         extra_manual=("sp",) if seq_manual else (),
-        with_aux=moe,
+        with_aux=has_experts,
         side=side,
         side_spec=side_spec,
     )
-    return out if moe else (out, jnp.zeros((), jnp.float32))
+    return out if has_experts else (out, jnp.zeros((), jnp.float32))
 
 
 def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids, token_mask):
@@ -676,12 +481,12 @@ def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
     def period(h, stacks):
         at, auxs = dict.fromkeys(stacks, 0), []
         for c in unit:
-            name = _PATTERN[c][0]
+            name = LAYER_KINDS[c].stack
             with jax.named_scope("layer_params"):  # the layer's rows of its stack: copies a step pays for
                 lp = jax.tree.map(lambda a: a[at[name]], stacks[name])  # noqa: B023
             h, aux = layer(h, lp)
             at[name] += 1
-            if c == "E":
+            if LAYER_KINDS[c].ff == "experts":
                 auxs.append(aux)
         return h, jax.tree.map(lambda *a: jnp.stack(a), *auxs) if auxs else None
 
@@ -796,13 +601,11 @@ def balance_router_bias(old: Params, new: Params, load: jax.Array, cfg: ModelCon
     """`new` with every expert layer's selection bias set to `old`'s moved by the balance
     rule (moe.balance_bias). load [expert layers + MTP modules, E], as loss_fn's
     `expert_load` orders them."""
-    from . import moe as _moe
-
     n = load.shape[0] - cfg.mtp_depth
     out = dict(new)
     for name, rows in (("layers", load[:n]), ("mtp", load[n:])):
         if name in new:
-            out[name] = dict(new[name], router_bias=_moe.balance_bias(
+            out[name] = dict(new[name], router_bias=moe.balance_bias(
                 old[name]["router_bias"], rows, cfg.moe_bias_update_rate))
     return out
 
@@ -852,9 +655,7 @@ def loss_fn(
         load = jnp.concatenate([load] + [a["load"][None] for _, a in heads])
         chosen = jnp.concatenate([chosen] + [a["chosen"][None] for _, a in heads])
     if load is not None:
-        from . import moe as _moe
-
-        lo, hi = _moe.held_range(cfg)
+        lo, hi = moe.held_range(cfg)
         held = load[:, lo:hi].sum(-1)
         # a row an expert layer, the MTP modules' last: what the balance rule reads, what
         # fell on the experts held here, the windows of the layer's buffer that load took
@@ -863,6 +664,6 @@ def loss_fn(
         metrics.update(
             expert_load=load, held_assignments=held,
             fullest_held_expert_rows=load[:, lo:hi].max(-1), experts_chosen=chosen,
-            expert_windows=_moe.windows_walked(
-                held.astype(jnp.int32), _moe.window_rows(cfg, chosen.shape[-2])))
+            expert_windows=moe.windows_walked(
+                held.astype(jnp.int32), moe.window_rows(cfg, chosen.shape[-2])))
     return loss, {"loss": loss, "ce_loss": ce, **metrics}

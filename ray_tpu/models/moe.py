@@ -156,6 +156,7 @@ SCORES_NAME = "router_scores"
 PICKED_NAME = "router_picked"
 LOAD_NAME = "router_load"
 ROUTER_NAMES = (CHOSEN_NAME, SCORES_NAME, PICKED_NAME, LOAD_NAME)
+KEPT = {"every": ROUTER_NAMES}  # what llama.py's table of layer kinds reads of a part (its docstring)
 
 # Rows of a tile of the TPU compiler's grouped kernels (`ragged-dot-none`: its metadata
 # has a tile for every 512 rows of the buffer and one more a group); a window is whole tiles.
@@ -565,17 +566,15 @@ def init_expert_weights(key: jax.Array, cfg: ModelConfig):
     return out
 
 
-def expert_axes(cfg: ModelConfig):
-    """Logical axes of the leaves init_expert_weights gives this configuration."""
-    names = ["router", *mlp_leaves(cfg)]
-    if cfg.moe_dropless:
-        names += ["router_bias"] * cfg.moe_select_bias
-        names += mlp_leaves(cfg, "shared_") * bool(cfg.n_shared_experts)
-        names += ["latent_down", "latent_up"] * bool(cfg.moe_latent_dim)
-    return {n: EXPERT_AXES[n] for n in names}
+def n_params(cfg: ModelConfig) -> int:
+    """What the dropless layer's `init_expert_weights` makes, counted (less the selection bias)."""
+    d, mats, latent = cfg.d_model, len(mlp_leaves(cfg)), cfg.moe_latent_dim or cfg.d_model
+    return (d * cfg.n_experts + cfg.n_experts_held * mats * latent * (cfg.d_ff_expert or cfg.d_ff)
+            + mats * d * cfg.shared_width + (2 * d * latent if cfg.moe_latent_dim else 0))
 
 
-EXPERT_AXES = {
+init = init_expert_weights  # the part's shape, as llama.py's table reads it
+AXES = {  # of every leaf `init` can make
     "router": ("embed", "expert"),
     "w_gate": ("expert", "embed", "mlp"),
     "w_up": ("expert", "embed", "mlp"),

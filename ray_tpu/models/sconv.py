@@ -20,9 +20,13 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.quant import as_weight as _w
 
+from .attn import rms_norm
 from .config import ModelConfig
 from .ssm import _causal_conv
 
+# what llama.py's table of layer kinds reads of a mixer (its comment says what each is);
+# under `attn`, as the Kimi-Delta-Attention mixer
+LEAF, RECURRENT, SCOPE, KEPT = "sconv_in", "gated short-convolution", "attn", {}
 AXES = {"sconv_norm": ("embed",), "sconv_in": ("embed", None, None), "sconv_w": (None, None),
         "sconv_out": (None, "embed")}
 
@@ -38,10 +42,13 @@ def init(key: jax.Array, cfg: ModelConfig):
     }
 
 
+def n_params(cfg: ModelConfig) -> int:
+    d = cfg.d_model
+    return d + d * 3 * d + cfg.conv_taps * d + d * d  # the norm, [B | C | x], the taps, W_out
+
+
 def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     """x [B, T, D] -> x + the layer's output."""
-    from .llama import rms_norm
-
     dt = x.dtype
     with jax.named_scope("sconv"):
         with jax.named_scope("sconv_in_proj"):

@@ -13,7 +13,7 @@ The counts are what the layer HOLDS. A tensor-parallel share of a published laye
 fewer heads and groups of the same widths (H / G heads read one group, so heads and
 groups divide together, and the grouped norm is a share's own): 8 shares of 16 heads
 and 1 group add up to the layer of 128 heads and 8 groups through W_out
-(tests/test_nemotron_h.py).
+(tests/test_family_nemotron_h.py).
 
 Leaves, as the published checkpoint lays them out: in_proj [D, 2 d_inner + 2 G N + H],
 conv_w [taps, d_inner + 2 G N] (the last tap is the current position's), conv_b,
@@ -27,8 +27,11 @@ import jax.numpy as jnp
 from ray_tpu.ops import ssd
 from ray_tpu.ops.quant import as_weight as _w
 
+from .attn import rms_norm
 from .config import ModelConfig
 
+# what llama.py's table of layer kinds reads of a mixer (its comment says what each is)
+LEAF, RECURRENT, SCOPE, KEPT = "in_proj", "Mamba-2", None, {}
 AXES = {
     "ssm_norm": ("embed",), "in_proj": ("embed", None), "conv_w": (None, None), "conv_b": (None,),
     "dt_bias": (None,), "A_log": (None,), "D": (None,), "gate_norm": (None,),
@@ -60,6 +63,13 @@ def init(key: jax.Array, cfg: ModelConfig):
     }
 
 
+def n_params(cfg: ModelConfig) -> int:
+    d = cfg.d_model
+    return (d + d * (cfg.ssm_d_inner + cfg.ssm_conv_dim + cfg.ssm_n_heads)  # the norm; z | xBC | dt
+            + (cfg.ssm_conv_taps + 1) * cfg.ssm_conv_dim + 3 * cfg.ssm_n_heads
+            + cfg.ssm_d_inner + cfg.ssm_d_inner * d)
+
+
 def _causal_conv(x: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
     """x [B, T, C], w [taps, C]: channel by channel, y_t = sum_k w_k x_{t - (taps-1) + k} + b,
     zeros before the sequence. float32."""
@@ -70,8 +80,6 @@ def _causal_conv(x: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
 
 def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     """x [B, T, D] -> x + the layer's output."""
-    from .llama import rms_norm
-
     dt_ = x.dtype
     bsz, t, _ = x.shape
     h, p, g, n = cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_n_groups, cfg.ssm_state

@@ -13,6 +13,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.parallel.sharding import partitioned_by_gspmd
+
 
 # checkpoint names of the rotated q and k where the Pallas path's rotate kernel made
 # them (ops/flash_attention.py), for a remat policy that keeps them
@@ -231,10 +233,10 @@ def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotatio
     rows = {} if segment_ids is None else {"seg": segment_ids}
     if rotation is not None:
         rows["pos"] = rotation.positions
+    if shard_spec is None or not partitioned_by_gspmd():
+        return kernel(q, k, v, rows)
     mesh = jax.sharding.get_abstract_mesh()
     auto = set(mesh.axis_names) - set(mesh.manual_axes)
-    if shard_spec is None or not any(mesh.shape[a] > 1 for a in auto):
-        return kernel(q, k, v, rows)
     row_specs = {name: P(shard_spec[0] if x.shape[0] > 1 else None, None)
                  for name, x in rows.items()}
     return jax.shard_map(

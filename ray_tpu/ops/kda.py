@@ -75,6 +75,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.parallel.sharding import partitioned_by_gspmd
+
 from . import kda_overlaps
 
 _HI = jax.lax.Precision.HIGHEST
@@ -87,15 +89,9 @@ def _sub(size: int) -> int:
     return _SUB if size % _SUB == 0 else size
 
 
-def _partitioned() -> bool:
-    """Whether an ambient mesh leaves an axis of more than one device to GSPMD."""
-    mesh = jax.sharding.get_abstract_mesh()
-    return any(mesh.shape[a] > 1 for a in set(mesh.axis_names) - set(mesh.manual_axes))
-
-
 def takes_kernels(size: int, width: int) -> bool:
     """Whether a chunk of `size` positions at `width` channels goes to the Pallas kernels."""
-    return kda_overlaps.supports(size, _sub(size), width) and not _partitioned()
+    return kda_overlaps.supports(size, _sub(size), width) and not partitioned_by_gspmd()
 
 
 def overlaps(q, k, run):

@@ -68,7 +68,7 @@ it): zeros after the sequence change nothing before it, and zero cotangents give
 `supports` says what the kernels tile: parts of whole 128-lane registers, a head `width` of
 whole registers that divides a part, at most 9 taps (the 8 rows the scratch keeps). Anything
 else, and any call under a mesh that leaves an axis to GSPMD (which cannot partition a
-Mosaic call: `ops/kda.py:_partitioned`), is the caller's plain form. Off a TPU the kernels
+Mosaic call: `parallel/sharding.py:partitioned_by_gspmd`), is the caller's plain form. Off a TPU the kernels
 run in Pallas' interpreter (`flash_attention._interpret`'s rule).
 """
 import functools
@@ -78,8 +78,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.parallel.sharding import partitioned_by_gspmd
+
 from . import flash_attention as _fa
-from .kda import _partitioned
 
 _TILE = 1024  # positions of a grid step
 _ROWS = (256, 128, 64, 32, 16)  # positions of a chunk of the loop inside one: the most that divide the tile
@@ -99,7 +100,7 @@ def supports(channels: int, parts: int, width, taps: int) -> bool:
 
 def takes_kernels(channels: int, parts: int, width, taps: int) -> bool:
     """`supports`, and no ambient mesh leaves an axis to GSPMD."""
-    return supports(channels, parts, width, taps) and not _partitioned()
+    return supports(channels, parts, width, taps) and not partitioned_by_gspmd()
 
 
 def _of_part(part, values):

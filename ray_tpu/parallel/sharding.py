@@ -176,6 +176,14 @@ def active_manual_axes() -> frozenset:
     return _MANUAL_AXES.get()
 
 
+def partitioned_by_gspmd() -> bool:
+    """Whether an ambient mesh leaves an axis of more than one device to GSPMD: a Pallas
+    call there needs a `shard_map` around it (ops/attention.py) or gives way to the plain
+    form (ops/kda.py, ops/short_conv.py)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return any(mesh.shape[a] > 1 for a in set(mesh.axis_names) - set(mesh.manual_axes))
+
+
 @_contextlib.contextmanager
 def manual_axes(*names: str):
     token = _MANUAL_AXES.set(_MANUAL_AXES.get() | frozenset(names))

@@ -27,6 +27,10 @@ jax.config.update("jax_platforms", "cpu")
 
 import pytest  # noqa: E402
 
+# the family contract's tests live in a module pytest does not collect by itself: a
+# family's file imports them (tests/family_contract.py)
+pytest.register_assert_rewrite("family_contract")
+
 # One test's set-up, call and tear-down together. Sized from the slowest test
 # of whole runs in the driver's form (six xdist workers, 8 cores, PR 27):
 # test_sac.py::test_sac_learns_pendulum, 60.0 and 63.7 s on an idle machine,

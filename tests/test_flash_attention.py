@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from family_contract import highest  # noqa: F401  (a fixture)
 from ray_tpu.ops.attention import attention_reference
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops.flash_attention import flash_attention, tile_counts
@@ -134,7 +135,7 @@ def test_fwd_and_grads_over_tilings(case, monkeypatch):
         def loss(q, k, v):
             o = fn(q, k, v, causal=causal, segment_ids=seg, **kw)
             return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32)), o
-        (_, o), grads = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(*xs)
+        (_, o), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(*xs)  # one program, as its users run it
         return (o, *grads)
 
     got = run(flash_attention, q, k, v, block_q=bq, block_kv=bkv)
@@ -373,3 +374,72 @@ def test_remat_full_alone_keeps_the_kernels_results(policy, named):
         assert out.shape == (b, h, s, 128) and lse.shape[:2] == (b, h) and lse.size >= b * h * s, kept
         assert (out.dtype, lse.dtype) == (jnp.uint16, jnp.uint32)  # their bits: the policy rounds nothing
 
+
+# ------------------------------------------------- the kernels at the families' head widths: 64 on padded lanes, 256
+
+@pytest.mark.usefixtures("highest")
+def test_the_flash_kernels_run_at_head_width_64_on_padded_lanes():
+    """[1, 256, 4 / 1, 64] through Pallas' interpreter in tiles of 128: forward, dQ and
+    dK-dV of the SAME three kernels (by name), causal, a group of 4, against the plain
+    softmax; `supports` says 64 and the multiples of 128 and nothing between."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops.attention import Rotation, attention
+
+    assert fa.supports(8192, 8192, 64) and fa.supports(8192, 8192, 128) and fa.supports(8192, 8192, 256)
+    assert not fa.supports(8192, 8192, 192) and not fa.supports(8192, 8192, 32) and not fa.supports(8191, 8191, 64)
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (1, 256, 4, 64), jnp.float32)
+    k, v = (jax.random.normal(key, (1, 256, 1, 64), jnp.float32) for key in ks[1:3])
+    w = jax.random.normal(ks[3], (1, 256, 4, 64), jnp.float32)
+
+    def flash(q, k, v):
+        return jnp.sum(w * fa.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128))
+
+    def plain(q, k, v):
+        return jnp.sum(w * attention_reference(q, k, v, causal=True))
+
+    jaxpr = str(jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(q, k, v))
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert f"name={name}" in jaxpr, name
+    out = fa.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128)
+    assert out.shape == q.shape
+    np.testing.assert_allclose(out, attention_reference(q, k, v, causal=True), atol=2e-5)
+    for mine, want in zip(jax.grad(flash, argnums=(0, 1, 2))(q, k, v), jax.grad(plain, argnums=(0, 1, 2))(q, k, v)):
+        assert mine.shape == want.shape
+        np.testing.assert_allclose(mine, want, atol=3e-5 * float(jnp.abs(want).max()))
+    # the rotation at 64 is the caller's jax.numpy statement, in front of the kernels
+    with pytest.raises(NotImplementedError, match="head width 64"):
+        fa.flash_attention(q, k, v, rope=(jnp.arange(256)[None], 1e6))
+    rotated = attention(q, k, v, impl="pallas", rotation=Rotation(jnp.arange(256)[None], 1e6, llama.rope))
+    want = attention_reference(*(llama.rope(a, jnp.arange(256)[None], 1e6) for a in (q, k)), v, causal=True)
+    np.testing.assert_allclose(rotated, want, atol=2e-5)
+
+
+def test_flash_kernels_tile_width_256():
+    assert fa.supports(8192, 8192, 256) and not fa.supports(8191, 8191, 256)
+    assert not fa.supports(8192, 8192, 192)
+    # K and V of one head at 8,192 x 256 bf16 are the span budget, exactly: one span
+    t = fa._tiling(8192, 8192, 512, 512, 256, 2)
+    assert (t.kv_span, t.q_span) == (8192, 4096)
+    fwd = fa.tile_counts(8192, 8192, True, 512, 512, head_dim=256)
+    assert (fwd.grid_steps, fwd.tiles_computed) == (16, 136) and fwd.tiles_needed == 128.015625
+    dkv = fa.tile_counts(8192, 8192, True, 512, 512, head_dim=256, kv_major=True)
+    assert (dkv.grid_steps, dkv.tiles_computed) == (32, 136)
+
+
+@pytest.mark.usefixtures("highest")
+@pytest.mark.parametrize("s", [64, 256])
+def test_flash_attention_at_unequal_head_parts(s):
+    """The kernels (interpreted here) at a head twice the lane width, fed as the latent
+    projections feed them: against the reference attention, values and gradients."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, g = (jax.random.normal(x, (1, s, 2, 256), jnp.float32) for x in ks)
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v, causal=True) * g), argnums=(0, 1, 2))(q, k, v)
+
+    (l1, g1), (l2, g2) = run(flash_attention), run(attention_reference)
+    np.testing.assert_allclose(l1, l2, rtol=1e-5)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(a, b, atol=2e-4)

@@ -79,8 +79,11 @@ def test_impala_improves_cartpole(rt):
     )
     algo = config.build_algo()
     try:
+        # an asynchronous learner beside five other test workers learns at the pace the machine
+        # leaves it (16.7 -> 28.9 in 25 iterations in the driver's run of PR 44, where alone it
+        # is there in a dozen): train until the improvement is seen, 75 iterations at most
         returns = []
-        for _ in range(25):
+        while len(returns) < 75 and not (len(returns) > 3 and max(returns[3:]) > returns[0] + 15):
             result = algo.train()
             returns.append(result.get("episode_return_mean") or 0.0)
         assert max(returns[3:]) > returns[0] + 15, returns

@@ -1,0 +1,188 @@
+"""The gated delta rule in chunks (ops/kda.py) and its two Pallas kernels (ops/kda_overlaps.py,
+in the interpreter here) against the recurrence a position at a time (the solar_open2
+reference's), at a small size on the CPU; and that the shape alone says which path runs."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from family_contract import _highest, highest  # noqa: F401  (autouse: every product at the highest precision)
+from ray_tpu.models.reference import solar_open2 as ref
+from ray_tpu.ops import kda as kda_op
+
+
+def _value_and_pull(fn, args, cot):
+    """fn(*args) and the pull-back of `cot` to every argument, as one program."""
+    def run(args, cot):
+        value, pull = jax.vjp(fn, *args)
+        return value, pull(cot)
+    return jax.jit(run)(args, cot)
+
+
+def _scan_inputs(t, regime, seed=0, b=2, h=3, width=16):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (b, t, h, width)) * width**-0.5
+    k = jax.random.normal(ks[1], (b, t, h, width))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, t, h, width))
+    # exp(g): near 1 (a long memory), a chunk's sum below -100 (none: 16 positions of -7 to
+    # -30 a channel), and both in one layer; beta over (0, 2) or within 0.1 of 2, where
+    # I - beta k k^T is all but a reflection
+    lo, hi = {"near_one": (1e-4, 1e-2), "below_minus_100_a_chunk": (7.0, 30.0), "mixed": (1e-3, 30.0),
+              "beta_near_2": (1e-3, 1.0)}[regime]
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, t, h, width), minval=jnp.log(lo), maxval=jnp.log(hi)))
+    beta = jax.random.uniform(ks[4], (b, t, h), minval=1.9 if regime == "beta_near_2" else 0.0, maxval=2.0)
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("chunk,sub", [(16, 4), (16, 16), (64, 16)])
+@pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2"])
+def test_the_chunked_scan_is_the_recurrence(regime, chunk, sub, monkeypatch):
+    """ops/kda.py against the recurrence a position at a time (the reference's), output
+    and the gradient of every input, over several chunks and sub-chunks."""
+    monkeypatch.setattr(kda_op, "_SUB", sub)
+    args = _scan_inputs(64, regime)
+    if regime == "below_minus_100_a_chunk":
+        assert float(args[3].reshape(2, 64 // chunk, chunk, 3, 16).sum(2).max()) < -100
+    cot = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    want, theirs = _value_and_pull(ref.recurrence, args, cot)
+    got, mine = _value_and_pull(lambda *a: kda_op.kda_scan(*a, chunk), args, cot)
+    assert np.isfinite(np.asarray(got)).all()
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(got, want, atol=1e-5 * scale)
+    for name, mine, theirs in zip("q k v g beta".split(), mine, theirs):
+        assert np.isfinite(np.asarray(mine)).all(), name
+        # (a float32 rounding of values and cotangents of order 1, where the gradient is all but zero)
+        np.testing.assert_allclose(mine, theirs, atol=3e-5 * float(jnp.abs(theirs).max()) + 1e-6, err_msg=name)
+
+
+def test_no_decay_is_the_exponential_of_a_positive_number():
+    """Whatever A_log and dt_bias hold: decays of exp(-3000) a position neither overflow
+    nor poison the gradient (0 x inf), and the scan of chunks whose sums fall to -4e4 is the
+    recurrence's to the bound ops/kda.py states (a decay's relative error is the running
+    sums' rounding, |G| x 6e-8: the one position in five that forgets nothing, g = -0.001,
+    is a difference of sums near -4e4, which float32 keeps to 0.004)."""
+    q, k, v, _, beta = _scan_inputs(32, "mixed")
+    g = jnp.full(q.shape, -3000.0).at[:, ::5].set(-1e-3)
+    want = ref.recurrence(q, k, v, g, beta)
+    got, grads = jax.value_and_grad(lambda *a: jnp.sum(kda_op.kda_scan(*a, 16) * want), argnums=(0, 1, 2, 3, 4))(
+        q, k, v, g, beta)
+    bound = 2 * 6e-8 * 13 * 3000 * float(jnp.abs(want).max())
+    np.testing.assert_allclose(kda_op.kda_scan(q, k, v, g, beta, 16), want, atol=bound)
+    assert np.isfinite(float(got)) and all(np.isfinite(np.asarray(x)).all() for x in grads)
+    # the same layer at decays a trained layer has is the recurrence's to a float32 rounding
+    g = g / 3000
+    np.testing.assert_allclose(kda_op.kda_scan(q, k, v, g, beta, 16), ref.recurrence(q, k, v, g, beta), atol=2e-6)
+
+
+def _pallas_calls(fn, *args):
+    """The names of the Pallas kernels a function's jaxpr holds, nested calls included."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+@pytest.mark.parametrize("chunk,sub,t", [(32, 8, 128), (32, 32, 64), (128, 32, 256)])
+@pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2"])
+def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, t, monkeypatch):
+    """At a width of 128 the overlaps go to the two Pallas kernels (ops/kda_overlaps.py, in
+    the interpreter here): the scan's output and the gradient of q, k, v, g and beta against
+    the same scan with `_decayed_overlaps` in their place and against the recurrence a
+    position at a time, 2 heads, 2 to 4 chunks, sub-chunks of 8 and 32."""
+    monkeypatch.setattr(kda_op, "_SUB", sub)
+    args = _scan_inputs(t, regime, b=1, h=2, width=128)
+    if regime == "below_minus_100_a_chunk":
+        assert float(args[3].reshape(1, t // chunk, chunk, 2, 128).sum(2).max()) < -100
+    scan = lambda *a: kda_op.kda_scan(*a, chunk)  # noqa: E731
+    assert sorted(set(_pallas_calls(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=(0, 1, 3)), *args))) == [
+        "kda_overlaps_bwd", "kda_overlaps_fwd"]
+    cot = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+    got, mine = _value_and_pull(scan, args, cot)
+    want, theirs = _value_and_pull(ref.recurrence, args, cot)
+    with monkeypatch.context() as m:
+        m.setattr(kda_op.kda_overlaps, "supports", lambda *shape: False)
+        assert not _pallas_calls(scan, *args)
+        plain, plains = _value_and_pull(scan, args, cot)
+    assert np.isfinite(np.asarray(got)).all()
+    scale = float(jnp.abs(want).max())
+    np.testing.assert_allclose(got, plain, atol=2e-6 * scale)  # the same sums: a rounding apart
+    np.testing.assert_allclose(got, want, atol=1e-5 * scale)
+    for name, x, same, theirs in zip("q k v g beta".split(), mine, plains, theirs):
+        assert np.isfinite(np.asarray(x)).all(), name
+        top = float(jnp.abs(theirs).max())
+        np.testing.assert_allclose(x, same, atol=1e-5 * top + 1e-6, err_msg=name)
+        np.testing.assert_allclose(x, theirs, atol=3e-5 * top + 1e-6, err_msg=name)
+
+
+def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
+    """The case above on the kernel path (width 128, chunks of 32): decays of exp(-3000) a
+    position, chunks whose sums fall to -8e4; a value and gradients that are finite say that
+    no exponential saw a positive number (exp(3000) is inf, and 0 x inf poisons a sum), the
+    masks cut before it in both kernels, and the factors across sub-chunks are <= 1."""
+    q, k, v, _, beta = _scan_inputs(64, "mixed", b=1, h=2, width=128)
+    g = jnp.full(q.shape, -3000.0).at[:, ::5].set(-1e-3)
+    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, 32), q, k, v, g, beta) == ["kda_overlaps_fwd"]
+    want = ref.recurrence(q, k, v, g, beta)
+    got, grads = jax.value_and_grad(lambda *a: jnp.sum(kda_op.kda_scan(*a, 32) * want), argnums=(0, 1, 2, 3, 4))(
+        q, k, v, g, beta)
+    bound = 2 * 6e-8 * 26 * 3000 * float(jnp.abs(want).max())
+    np.testing.assert_allclose(kda_op.kda_scan(q, k, v, g, beta, 32), want, atol=bound)
+    assert np.isfinite(float(got)) and all(np.isfinite(np.asarray(x)).all() for x in grads)
+    g = g / 3000
+    np.testing.assert_allclose(kda_op.kda_scan(q, k, v, g, beta, 32), ref.recurrence(q, k, v, g, beta), atol=2e-6)
+
+
+@pytest.mark.parametrize("chunk,width,sub,kernels", [
+    (128, 128, 32, True),  # the Solar-Open2 cell's
+    (32, 128, 8, True), (32, 128, 32, True), (256, 256, 32, True),
+    (48, 128, 32, True),  # no whole sub-chunks of 32: one sub-chunk of 48, six registers of 8 rows
+    (64, 16, 16, False), (16, 16, 4, False),  # a width of 16: the cases above this section, tier-1's own
+    (128, 64, 32, False),  # half a register of lanes
+    (20, 128, 32, False),  # one sub-chunk of 20 rows: no whole registers
+    (32, 128, 4, False),  # sub-chunks of half a register
+])
+def test_the_shape_alone_says_which_path_runs(chunk, width, sub, kernels, monkeypatch):
+    """`kda.takes_kernels` reads the chunk and the width (and the sub-chunk they imply); the
+    scan's jaxpr holds the forward kernel exactly where it says so. Nobody sets it."""
+    monkeypatch.setattr(kda_op, "_SUB", sub)
+    assert kda_op.takes_kernels(chunk, width) == kernels
+    assert kda_op.kda_overlaps.supports(chunk, kda_op._sub(chunk), width) == kernels
+    args = _scan_inputs(2 * chunk, "mixed", b=1, h=2, width=width)
+    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, chunk), *args) == (["kda_overlaps_fwd"] if kernels else [])
+
+
+def test_under_a_mesh_that_shards_the_heads_the_jnp_path_runs():
+    """GSPMD cannot partition a Mosaic call: with an axis of the ambient mesh still automatic
+    the scan runs `_decayed_overlaps` at the kernels' own shape, partitioned by the compiler,
+    and is the single-device scan's value; with every axis of size one the kernels run."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
+
+    args = _scan_inputs(64, "mixed", b=2, h=2, width=128)
+    scan = lambda *a: kda_op.kda_scan(*a, 32)  # noqa: E731
+    want = scan(*args)
+    mesh = build_mesh(MeshSpec(dp=2, tp=2), jax.devices()[:4])
+    with use_mesh(mesh):
+        assert not kda_op.takes_kernels(32, 128) and not _pallas_calls(scan, *args)
+        heads = NamedSharding(mesh, P("dp", None, "tp", None))
+        sharded = [jax.device_put(x, heads if x.ndim == 4 else NamedSharding(mesh, P("dp", None, "tp"))) for x in args]
+        got = jax.jit(scan)(*sharded)
+        assert got.sharding.spec[2] == "tp"
+    np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()))
+    with use_mesh(build_mesh(MeshSpec(dp=1), jax.devices()[:1])):
+        assert kda_op.takes_kernels(32, 128) and _pallas_calls(scan, *args) == ["kda_overlaps_fwd"]
+
+
+def test_the_scan_asserts_whole_chunks():
+    q, k, v, g, beta = _scan_inputs(24, "mixed")
+    with pytest.raises(ValueError, match="multiple of the scan's chunk"):
+        kda_op.kda_scan(q, k, v, g, beta, 16)

@@ -1,10 +1,16 @@
 """Model correctness tests on CPU (8 virtual devices)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from family_contract import highest, model_of, params, tokens  # noqa: F401  (highest: a fixture)
 from ray_tpu.models import get_config, llama
+from ray_tpu.models.config import ModelConfig
+from ray_tpu.models.reference import glm4_moe_lite as glm_ref
+from ray_tpu.models.reference import lfm2_moe as lfm2_ref
 from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
 from ray_tpu.parallel.sharding import TRAIN_RULES, shard_pytree
 
@@ -415,3 +421,132 @@ def test_rotating_in_the_kernel_is_the_model(case):
     for a, r in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         scale = max(1.0, float(jnp.max(jnp.abs(r))))
         np.testing.assert_allclose(np.asarray(a) / scale, np.asarray(r) / scale, rtol=0, atol=5e-4)
+
+
+# ------------------------------------------------- the families' stacks, patterns and attention layers
+# (at a small size against the plain references, ray_tpu/models/reference/; the families' own
+# contract is tests/family_contract.py)
+
+GLM, NEMOTRON = get_config("glm-tiny"), get_config("nemotron-tiny")
+
+
+@pytest.mark.usefixtures("highest")
+def test_llama_and_glm_keep_their_stacks_and_blocks():
+    """Every other family is the pattern 'attention + feed-forward' of period 1: the same
+    stacks under the same names, and a block with both parts."""
+    assert llama._layer_kinds(get_config("test-tiny")) == {"layers": (2, "attn", "dense")}
+    assert llama._layer_kinds(get_config("glm-tiny")) == {
+        "dense_layers": (1, "attn", "dense"), "layers": (2, "attn", "experts")}
+    assert llama._layer_kinds(NEMOTRON) == {"ssm_layers": (2, "ssm", None), "layers": (2, None, "experts"),
+                                       "attn_layers": (1, "attn", None), "mlp_layers": (1, None, "dense")}
+    p = params(NEMOTRON)
+    assert set(llama.param_axes(NEMOTRON)) == set(p)
+    for name, stack in llama.param_axes(NEMOTRON).items():
+        if isinstance(stack, dict):
+            assert set(stack) == set(p[name]), name
+
+
+@pytest.mark.parametrize("pattern,unit,n", [
+    ("ME", "ME", 1), ("MEME*EMEME*E", "MEME*E", 2), ("MMMM", "M", 4), ("MEMEMEM*EME", "MEMEMEM*EME", 1)])
+def test_a_patterns_period(pattern, unit, n):
+    assert llama.pattern_period(pattern) == (unit, n)
+
+
+@pytest.mark.usefixtures("highest")
+def test_a_pattern_is_checked_against_the_depth_and_the_kinds_it_names():
+    with pytest.raises(ValueError, match="layer_pattern"):
+        dataclasses.replace(NEMOTRON, layer_pattern="MEM")
+    with pytest.raises(ValueError, match="layer_pattern"):
+        dataclasses.replace(NEMOTRON, layer_pattern="MEMXE-")
+    with pytest.raises(NotImplementedError, match="mtp_layer_pattern"):
+        dataclasses.replace(NEMOTRON, mtp_layer_pattern="ME")
+    with pytest.raises(NotImplementedError, match="KV cache"):
+        llama.forward(params(NEMOTRON), tokens(NEMOTRON), NEMOTRON, cache=llama.init_kv_cache(NEMOTRON, 2, 64))
+
+
+@pytest.mark.usefixtures("highest")
+def test_leading_and_following_stacks():
+    cfg = dataclasses.replace(GLM, n_layers=4, n_dense_layers=2)
+    p = jax.jit(llama.init, static_argnums=1)(jax.random.PRNGKey(0), cfg)
+    axes = llama.param_axes(cfg)
+    assert p["dense_layers"]["w_gate"].shape == (2, cfg.d_model, cfg.d_ff)
+    assert p["layers"]["w_gate"].shape == (2, cfg.n_experts, cfg.d_model, cfg.d_ff_expert)
+    assert "router" not in p["dense_layers"] and "router" in p["layers"]
+    assert p["mtp"]["eh_proj"].shape == (1, 2 * cfg.d_model, cfg.d_model)
+    same = jax.tree.map(lambda a, ax: a.ndim == len(ax), p, axes,
+                        is_leaf=lambda x: isinstance(x, tuple))
+    assert all(jax.tree.leaves(same))
+    t = tokens(cfg)
+    logits, _, aux = jax.jit(lambda p, t: llama.forward(p, t, cfg, return_aux=True))(p, t)
+    assert aux["load"].shape == (2, cfg.n_experts)
+    np.testing.assert_allclose(logits, jax.jit(lambda p, t: glm_ref.forward(p, t, model_of(cfg))[0])(p, t), atol=2e-5)
+    # a one-kind model keeps its one stack and the keys it always drew
+    dense = get_config("test-tiny")
+    assert set(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), dense))) == {
+        "embed", "layers", "final_norm", "lm_head"}
+    assert cfg.n_params == sum(a.size for a in jax.tree.leaves(p)) - sum(
+        p[n]["router_bias"].size for n in ("layers", "mtp"))
+
+
+@pytest.mark.usefixtures("highest")
+def test_a_cache_over_two_stacks_is_refused():
+    """llm/ refuses the family (llm/config.py:_served), so nothing walks a cache over a
+    leading and a following stack: forward says so instead of carrying the code."""
+    cfg = dataclasses.replace(GLM, mtp_depth=0)
+    cache = llama.init_kv_cache(cfg, 1, 16, dtype=jnp.float32)
+    with pytest.raises(NotImplementedError, match="more than one kind"):  # (while it is traced: nothing has to run)
+        jax.eval_shape(lambda: llama.forward(llama.init(jax.random.PRNGKey(0), cfg), tokens(cfg, (1, 8)), cfg, cache=cache))
+
+
+@pytest.mark.usefixtures("highest")
+def test_rotated_slice_and_its_shared_key_against_plain_rope():
+    cfg = GLM
+    p = jax.tree.map(lambda a: a[0], llama.init(jax.random.PRNGKey(0), cfg)["dense_layers"])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 10, cfg.d_model))
+    pos = jnp.arange(10)[None, :] * 2 + jnp.array([[0], [5]])
+    q, k, v = llama.qkv_proj(x, p, cfg, pos)
+    nope, rd, kvr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
+    assert q.shape == k.shape == (2, 10, cfg.n_heads, nope + rd) and v.shape[-1] == cfg.v_head_dim
+    h = llama.rms_norm(x, p["attn_norm"], cfg.norm_eps)
+    cq = llama.rms_norm(h @ p["wq_a"], p["q_norm"], cfg.norm_eps)
+    q_plain = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])
+    np.testing.assert_allclose(q[..., :nope], q_plain[..., :nope], atol=1e-6)
+    np.testing.assert_allclose(q[..., nope:], llama.rope(q_plain[..., nope:], pos, cfg.rope_theta),
+                               atol=1e-6)
+    ckv = h @ p["wkv_a"]
+    k_rot = llama.rope(ckv[:, :, None, kvr:], pos, cfg.rope_theta)
+    for head in range(cfg.n_heads):  # one rotated key, every head's
+        np.testing.assert_allclose(k[:, :, head, nope:], k_rot[:, :, 0], atol=1e-6)
+    # the published pairing (2i, 2i + 1) on the checkpoint's column order is this rotation
+    # on the program's: scores do not see the permutation
+    perm = jnp.array(llama.rope_pairs_to_halves(rd))
+    assert sorted(perm.tolist()) == list(range(rd)) and perm[:3].tolist() == [0, 2, 4]
+    slice_ = q_plain[:1, :, :, nope:]
+    published = jnp.zeros_like(slice_).at[..., perm].set(slice_)  # column j lies at perm[j]
+    np.testing.assert_allclose(glm_ref._published_order(slice_), published, atol=0)
+    turned = glm_ref._rope_pairs(published, cfg.rope_theta)  # positions 0..9
+    np.testing.assert_allclose(turned[..., perm], llama.rope(slice_, jnp.arange(10)[None], cfg.rope_theta),
+                               atol=1e-6)
+
+
+def _attention_cfg(qk_norm):
+    return ModelConfig(name="w64", vocab_size=64, d_model=512, n_layers=1, n_heads=8, n_kv_heads=2, d_ff=64,
+                       rope_theta=1e6, dtype="float32", layer_pattern="*", attn_qk_norm=qk_norm)
+
+
+@pytest.mark.usefixtures("highest")
+@pytest.mark.parametrize("qk_norm", [True, False])
+def test_attention_at_head_width_64_is_the_references(qk_norm):
+    """32 / 8 heads of 64 in small: 8 / 2 heads of 64 (groups of 4), q and k normed a head
+    BEFORE the rotation where the layer has the weights, rotated at theta 1e6."""
+    cfg = _attention_cfg(qk_norm)
+    assert cfg.head_dim == 64
+    lp = jax.tree.map(lambda a: a[0], params(cfg)["attn_layers"])
+    assert ("q_head_norm" in lp) == qk_norm and lp["wq"].shape == (512, 8, 64) and lp["wk"].shape == (512, 2, 64)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 48, 512))
+    got = llama._block(x, lp, cfg, jnp.arange(48)[None], None)[0]
+    want = lfm2_ref.attention_layer(x, lp, model_of(cfg))
+    np.testing.assert_allclose(got, want, atol=2e-5 * float(jnp.abs(want).max()))
+    if qk_norm:  # the norm is in front of the rotation: behind it, another number
+        plain = lfm2_ref.attention_layer(x, {n: a for n, a in lp.items() if "head_norm" not in n}, model_of(cfg))
+        assert float(jnp.abs(plain - want).max()) > 1e-2

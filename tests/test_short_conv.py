@@ -9,7 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import get_config, kda, llama
+from family_contract import model_of
+from ray_tpu.models import get_config, kda, llama, sconv
+from ray_tpu.models.reference import lfm2_moe as lfm2_ref
 from ray_tpu.models.ssm import _causal_conv
 from ray_tpu.ops import short_conv
 
@@ -201,3 +203,36 @@ def test_a_rematerialised_mixer_holds_one_kernel_a_pass_whatever_the_parts():
     for eqn in found:
         assert eqn.params["grid_mapping"].grid[0] == 3
         assert any(e.primitive.name in ("scan", "while") for e in eqn.params["jaxpr"].eqns)
+
+
+# ------------------------------------------------- the gated short convolution (models/sconv.py: lfm2's mixer)
+
+LFM2 = get_config("lfm2-tiny")
+
+
+@pytest.mark.parametrize("taps", [3, 4])
+def test_the_gated_short_convolution_is_the_position_at_a_time_loop(taps):
+    """c_t = sum_k w_k z_{t-(taps-1)+k} with z = B * x, zeros before the sequence (the first
+    taps - 1 positions see them), times C, through W_out: the mixer, the reference's layer and
+    a loop over positions that keeps the last taps - 1 values of z, as a decoder would."""
+    cfg = dataclasses.replace(LFM2, conv_taps=taps)
+    lp = sconv.init(jax.random.PRNGKey(3), cfg)
+    lp["sconv_norm"] = 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(4), lp["sconv_norm"].shape)
+    assert lp["sconv_in"].shape == (64, 3, 64) and lp["sconv_w"].shape == (taps, 64)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 9, 64))
+    u = llama.rms_norm(x, lp["sconv_norm"], cfg.norm_eps)
+    b, c, v = (u @ lp["sconv_in"][:, i] for i in range(3))  # the thirds in this order: B, C, x
+    tail, out = jnp.zeros((2, taps - 1, 64)), []
+    for t in range(x.shape[1]):
+        window = jnp.concatenate([tail, (b[:, t] * v[:, t])[:, None]], axis=1)  # oldest first
+        out.append((c[:, t] * jnp.einsum("bkd,kd->bd", window, lp["sconv_w"])) @ lp["sconv_out"])
+        tail = window[:, 1:]
+    want = x + jnp.stack(out, axis=1)
+    np.testing.assert_allclose(sconv.mixer(x, lp, cfg), want, atol=2e-5)
+    np.testing.assert_allclose(lfm2_ref.conv_layer(x, lp, model_of(cfg)), want, atol=2e-5)
+    # position 0 sees only its own z through the LAST tap
+    first = (c[:, 0] * (b[:, 0] * v[:, 0]) * lp["sconv_w"][-1]) @ lp["sconv_out"]
+    np.testing.assert_allclose(sconv.mixer(x, lp, cfg)[:, 0] - x[:, 0], first, atol=2e-5)
+    # causal: what comes later changes nothing earlier
+    later = x.at[:, 5:].add(1.0)
+    np.testing.assert_array_equal(sconv.mixer(later, lp, cfg)[:, :5], sconv.mixer(x, lp, cfg)[:, :5])

@@ -396,7 +396,7 @@ def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full
     `flash_attention_fwd`, where it held a second in the rematerialised layer (PR 43); under
     `dots`, which keeps neither (PERF.md section 7, after PR 26 (1)), it holds two as before.
     One `_bwd_dq` and one `_bwd_dkv` either way."""
-    from ray_tpu.models import llama
+    from ray_tpu.models import attn, llama
 
     cfg, file = _cell_file(config)
     trainer = file["trainer"]
@@ -404,7 +404,7 @@ def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full
     stacks = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
     stack = next(st for st in stacks.values() if isinstance(st, dict) and "attn_norm" in st)
     lp = {n: jax.ShapeDtypeStruct(stack[n].shape[1:], stack[n].dtype, sharding=one_chip)
-          for n in ("attn_norm", *llama._attn_axes(cfg))}
+          for n in llama._layer_axes(cfg, "attn", None)}
     b, s = trainer["batch"], trainer["seq"]
     x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
     pos = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
@@ -412,7 +412,7 @@ def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full
     def loss(x, lp, pos):
         with jax.named_scope("model"):  # as train/step.py
             part = llama._maybe_remat(
-                lambda x, lp: llama._attention_part(x, lp, cfg, pos, None, None, None)[0], cfg)
+                lambda x, lp: attn.mixer(x, lp, cfg, pos, None, None, None)[0], cfg)
             return jnp.sum(part(x, lp).astype(jnp.float32))
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp, pos).compile().as_text()
