@@ -193,6 +193,10 @@ def _served(cfg):
                             "convolution tails a slot beside the KV cache (cache manager, snapshots, prefix cache)"),
         (cfg.kda_n_heads, "a delta-rule state [heads, 128, 128] a slot and layer (models/kda.py keeps none)"),
         (cfg.attn_output_gate, "the attention output gate in the decode window (llm/model_runner.py)"),
+        (cfg.attn_window, "a window of the KV cache: the cache keeps every position and the decode "
+                          "window masks none (llm/model_runner.py, llm/paged.py)"),
+        (cfg.part_post_norm, "a norm behind each part in the decode window (llm/model_runner.py adds "
+                             "attention's output itself)"),
         ("C" in cfg.layer_pattern, "a gated short convolution's tail of conv_taps - 1 positions a slot "
                                    "and layer (models/sconv.py keeps none)"),
     ) if has]

@@ -6,6 +6,7 @@ reads of each. `rms_norm` and `rope` are here because attention is their first u
 serving programs (llm/model_runner.py) call the parts, `qkv_proj` and `attn_out`, around
 their own cache.
 """
+import contextlib
 from typing import Optional
 
 import jax
@@ -152,19 +153,32 @@ def _latent_qkv(h: jax.Array, lp: dict, cfg: ModelConfig, positions: jax.Array):
     return q, k, kv[..., nope:]
 
 
+def _out_proj(attn: jax.Array, lp: dict, dt) -> jax.Array:
+    return jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], dt))
+
+
 def attn_out(x: jax.Array, attn: jax.Array, lp: dict) -> jax.Array:
-    """Output projection of attn [B, S, H, hd] and the residual."""
-    return x + jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], x.dtype))
+    """Output projection of attn [B, S, H, hd] and the residual (the serving programs')."""
+    return x + _out_proj(attn, lp, x.dtype)
 
 
-def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len):
-    """The block's attention: (x + attention's output, updated (k, v) if caching)."""
+def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed=False):
+    """The block's attention: (attention's output, which llama._block adds to x; updated
+    (k, v) if caching). `windowed` (a `W` part of the pattern): inside cfg.attn_window and
+    rotated whatever cfg.attention_rotation says, which is the `*` parts'."""
+    window = cfg.attn_window if windowed else None
+    if window and (cache_kv is not None or cfg.attention_impl in ("ring", "ulysses")):
+        raise NotImplementedError(
+            "an attention window under a KV cache (no window of the cache is kept or masked) or on the "
+            "ring / Ulysses paths (ops/ring_attention.py takes none)")
     # named scopes: metadata only (free at run time); what a reader of the
-    # profile uses to tell one fusion from another
-    with jax.named_scope("attn"):
+    # profile uses to tell one fusion from another. A model of both kinds of attention part
+    # names which one this is, inside `attn`
+    kind = jax.named_scope("attn_window" if windowed else "attn_full") if cfg.attn_window else contextlib.nullcontext()
+    with jax.named_scope("attn"), kind:
         # ops.attention rotates q and k itself (in its kernel's own pass over them, where
         # the Pallas path runs); a cache or the ring takes them rotated
-        rotate = cfg.attention_rotation
+        rotate = cfg.attention_rotation or windowed
         deferred = (rotate and cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
                     and not cfg.latent_attention)
         q, k, v = qkv_proj(x, lp, cfg, positions if rotate and not deferred else None)
@@ -203,10 +217,10 @@ def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len):
                 )
         else:
             attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
-                             shard_spec=auto_spec("batch", None, "act_heads", None),
+                             shard_spec=auto_spec("batch", None, "act_heads", None), window=window,
                              rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
         if "wo_gate" in lp:  # a channel of the output, from the layer's normed input
             gate = jnp.einsum("bsd,dhk->bshk", rms_norm(x, lp["attn_norm"], cfg.norm_eps),
                               _w(lp["wo_gate"], x.dtype))
             attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
-        return wsc(attn_out(x, attn, lp), "batch", "seq", "act_embed"), new_kv
+        return _out_proj(attn, lp, x.dtype), new_kv

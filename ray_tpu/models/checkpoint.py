@@ -130,6 +130,50 @@ def _lfm2_moe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+def _afmoe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `afmoe` keys (Trinity-Mini) as ModelConfig fields. A published layer is attention
+    then a feed-forward part, each between a norm on its input and one on its output
+    (`part_post_norm`): two characters of the layer pattern, the mixer `W` where
+    `layer_types` says `sliding_attention` (inside `sliding_window`, rotated) and `*` where it
+    says `full_attention` (not rotated: `attention_rotation` false is the `*` parts'), both
+    gated a channel and normed a head; the feed-forward part `-` in the `num_dense_layers`
+    leading layers and `E` after them (sigmoid-routed SwiGLU experts beside shared ones), so
+    n_layers counts parts. `mup_enabled` is the embedding's output times sqrt(hidden_size);
+    `load_balance_coeff` the rate at which the selection bias moves. Every expert is held; a
+    share is an override (`experts_held`). What the program does not run is refused by name;
+    what config.json does not state is benchmarks/configs/trinity-mini-train-ep16.json's
+    `assumed`. Weights' names are not mapped."""
+    kinds = hf.get("layer_types") or []
+    refused = [what for has, what in (
+        (len(kinds) != hf["num_hidden_layers"] or set(kinds) - {"full_attention", "sliding_attention"},
+         f"layer_types that are not num_hidden_layers of full_attention | sliding_attention ({sorted(set(kinds))})"),
+        ("sliding_attention" in kinds and not hf.get("sliding_window"), "sliding_attention layers without a sliding_window"),
+        (hf.get("score_func", "sigmoid") != "sigmoid", f"score_func {hf.get('score_func')!r}"),
+        (not hf.get("route_norm", True), "gates that are not normalised (route_norm false)"),
+        (any(hf.get(key, 1) != 1 for key in ("n_group", "topk_group", "num_expert_groups", "num_limited_groups")),
+         "group-limited routing (n_group / topk_group / num_expert_groups / num_limited_groups > 1)"),
+        (hf.get("rope_scaling") is not None, f"rope_scaling {hf.get('rope_scaling')!r}"),
+        (hf.get("hidden_act", "silu") != "silu", f"hidden_act {hf.get('hidden_act')!r}"),
+        (not hf.get("num_experts", 0), "layers without routed experts"),
+    ) if has]
+    if refused:
+        raise ValueError("afmoe as this config.json states it is not supported: " + "; ".join(refused))
+    dense = hf.get("num_dense_layers", 0)
+    pattern = "".join(("W" if kind == "sliding_attention" else "*") + ("-" if i < dense else "E")
+                      for i, kind in enumerate(kinds))
+    return dict(
+        n_layers=len(pattern), layer_pattern=pattern,
+        attn_head_dim=hf.get("head_dim", 0), attn_window=hf.get("sliding_window") or 0,
+        attention_rotation=False, attn_output_gate=True, attn_qk_norm=True, part_post_norm=True,
+        embed_scale=float(hf["hidden_size"]) ** 0.5 if hf.get("mup_enabled", False) else 0.0,
+        n_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+        d_ff_expert=hf["moe_intermediate_size"], n_shared_experts=hf.get("num_shared_experts", 0),
+        moe_capacity_factor=0.0, moe_aux_loss_coef=0.0, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_route_scale=float(hf.get("route_scale", 1.0)),
+        moe_bias_update_rate=float(hf.get("load_balance_coeff", 0.001)),
+    )
+
+
 def _nemotron_h_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     """The `nemotron_h` keys (Nemotron-3-Super) as ModelConfig fields: a pattern of
     single-part layers (Mamba-2 mixers, attention, latent relu2 experts beside a shared
@@ -260,7 +304,8 @@ def _glm4_moe_lite_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
 
 # a family's keys as ModelConfig fields, by its `model_type` (config_from_hf)
 _FAMILY_FIELDS = {"glm4_moe_lite": _glm4_moe_lite_fields, "nemotron_h": _nemotron_h_fields,
-                  "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields}
+                  "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields,
+                  "afmoe": _afmoe_fields}
 
 
 def config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:

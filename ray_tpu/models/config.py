@@ -13,14 +13,16 @@ import jax.numpy as jnp
 
 # One character of a layer pattern: the stack of params its layers lie in, its mixer and its
 # feed-forward part (rows of models/llama.py's MIXERS and FEED_FORWARD; either or none), and
-# what a message calls it. Pure data: this module imports no model code.
-LayerKind = collections.namedtuple("LayerKind", "stack mixer ff says")
+# what a message calls it; `windowed`: attention inside cfg.attn_window, always rotated.
+# Pure data: this module imports no model code.
+LayerKind = collections.namedtuple("LayerKind", "stack mixer ff says windowed", defaults=(False,))
 LAYER_KINDS = {
     "M": LayerKind("ssm_layers", "ssm", None, "Mamba-2"),
     "K": LayerKind("kda_layers", "kda", None, "Kimi Delta Attention"),
     "C": LayerKind("sconv_layers", "sconv", None, "gated short convolution"),
     "E": LayerKind("layers", None, "experts", "experts"),
     "*": LayerKind("attn_layers", "attn", None, "attention"),
+    "W": LayerKind("window_layers", "attn", None, "attention inside attn_window, rotated", True),
     "-": LayerKind("mlp_layers", None, "dense", "MLP"),
 }
 
@@ -94,9 +96,10 @@ class ModelConfig:
     mtp_loss_weight: float = 0.3
     # --- a stack of single-part layers (nemotron_h's hybrid_override_pattern) ---
     # One character a layer, each layer a mixer OR a feed-forward part alone behind its
-    # own norm and residual: M a Mamba-2 mixer (models/ssm.py), K a Kimi-Delta-Attention
-    # mixer (models/kda.py), C a gated short convolution (models/sconv.py), * attention, E an
-    # expert layer, - the dense MLP. A published layer of two parts (solar_open2, lfm2_moe:
+    # own norm and residual: the characters are LAYER_KINDS' (M a Mamba-2 mixer, models/ssm.py;
+    # K a Kimi-Delta-Attention mixer, models/kda.py; C a gated short convolution,
+    # models/sconv.py; * attention; W attention inside attn_window; E an expert layer; - the
+    # dense MLP). A published layer of two parts (solar_open2, lfm2_moe, afmoe:
     # a mixer, then a feed-forward part) is two characters. "" is
     # the block every other family has: attention followed
     # by a feed-forward part, n_layers times (a prefix of n_dense_layers dense).
@@ -161,6 +164,14 @@ class ModelConfig:
     # RMSNorm over a head's width of q and of k, one [head_dim] weight each, before the
     # rotation (lfm2's q_layernorm / k_layernorm; eps is norm_eps)
     attn_qk_norm: bool = False
+    # A sliding window (afmoe's sliding_window; 0 = none): in a `W` part of the pattern key j
+    # is kept for query i where 0 <= i - j < attn_window, and q and k are rotated whatever
+    # attention_rotation says, which is the `*` parts' (afmoe's full layers are not rotated)
+    attn_window: int = 0
+    # every part's OUTPUT goes through an RMSNorm of its own before the residual, mixers and
+    # feed-forward parts alike (afmoe's sandwich norms): x + RMSNorm(part(RMSNorm(x)))
+    part_post_norm: bool = False
+    embed_scale: float = 0.0  # the embedding's output times this (0 = none; afmoe's mup_enabled: sqrt(d_model))
 
     def __post_init__(self):
         # JSON hands a list; the dataclass is a static (hashed) argument of jitted programs
@@ -175,6 +186,8 @@ class ModelConfig:
                     + f" a layer, n_layers ({self.n_layers}) of them")
             if "K" in self.layer_pattern and not self.kda_n_heads:
                 raise ValueError("layer_pattern has K layers: kda_n_heads says how many heads one holds")
+            if any(LAYER_KINDS[c].windowed for c in self.layer_pattern) and self.attn_window < 1:
+                raise ValueError("layer_pattern has W layers: attn_window says how many keys a query keeps")
             if self.n_dense_layers:
                 raise ValueError("layer_pattern says which layers are dense ('-'); n_dense_layers is the block's")
         if self.mtp_layer_pattern not in ("", "*E"):
@@ -504,6 +517,44 @@ register_config(
         moe_scoring="sigmoid",
         moe_select_bias=True,
         moe_gate_eps=1e-6,
+    )
+)
+register_config(
+    # Toy of the afmoe family (Trinity-Mini) for the CPU tests: every published layer two parts
+    # of the pattern (a mixer, then a feed-forward part), each between two norms; attention
+    # inside a window (11 keys: shorter than any test's sequence, a multiple of no tile) and
+    # rotated, three to one with full attention that is not; both gated and normed a head, at a
+    # head width of its own (4 x 24 is wider than d_model, as 32 x 128 is than 2048); a leading
+    # dense layer, then sigmoid-routed SwiGLU experts beside a shared one; the embedding
+    # scaled by sqrt(d_model). Everything held; tests cut shares of the experts.
+    ModelConfig(
+        name="trinity-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=10,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=96,
+        max_seq_len=128,
+        rope_theta=1e4,
+        dtype="float32",
+        layer_pattern="W-WEWEWE*E",
+        attn_head_dim=24,
+        attention_rotation=False,
+        attn_output_gate=True,
+        attn_qk_norm=True,
+        attn_window=11,
+        part_post_norm=True,
+        embed_scale=8.0,
+        n_experts=16,
+        moe_top_k=3,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=40,
+        n_shared_experts=1,
+        moe_scoring="sigmoid",
+        moe_route_scale=2.826,
+        moe_select_bias=True,
     )
 )
 register_config(

@@ -108,7 +108,7 @@ def _conv_silu_norm(x: jax.Array, w: jax.Array, width: int):
 
 
 def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
-    """x [B, T, D] -> x + the layer's output."""
+    """x [B, T, D] -> the layer's output (llama._block adds it to x)."""
     dt_, f32 = x.dtype, jnp.float32
     bsz, t, _ = x.shape
     h, width = cfg.kda_n_heads, cfg.kda_head_dim
@@ -136,4 +136,4 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
         o = o * jax.lax.rsqrt(jnp.mean(jnp.square(o), axis=-1, keepdims=True) + cfg.norm_eps)
         o = (o * lp["kda_o_norm"] * jax.nn.sigmoid(gate.astype(f32))).astype(dt_)
     with jax.named_scope("kda_out_proj"):
-        return x + jnp.einsum("bthk,hkd->btd", o, _w(lp["kda_out"], dt_))
+        return jnp.einsum("bthk,hkd->btd", o, _w(lp["kda_out"], dt_))

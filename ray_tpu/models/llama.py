@@ -102,13 +102,17 @@ def _layer_init(key: jax.Array, cfg: ModelConfig, mixer: Optional[str], ff: Opti
     if ff:
         part, keys = FEED_FORWARD[ff]
         out.update(mlp_norm=jnp.ones((cfg.d_model,), jnp.float32), **part.init(ks[keys], cfg))
+    if cfg.part_post_norm:  # a norm behind each part as well (`_onto`)
+        out.update({leaf: jnp.ones((cfg.d_model,), jnp.float32)
+                    for leaf, has in (("attn_post_norm", mixer), ("mlp_post_norm", ff)) if has})
     return out
 
 
 @functools.lru_cache(maxsize=None)  # (an abstract `init` a call otherwise; callers copy, never write)
 def _layer_axes(cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Params:
     """One layer's logical axes (no leading 'layer' axis): its parts', of the leaves their `init` makes."""
-    axes = {"mlp_norm": ("embed",), **(MIXERS[mixer][0].AXES if mixer else {}), **(FEED_FORWARD[ff][0].AXES if ff else {})}
+    axes = {**dict.fromkeys(("mlp_norm", "attn_post_norm", "mlp_post_norm"), ("embed",)),
+            **(MIXERS[mixer][0].AXES if mixer else {}), **(FEED_FORWARD[ff][0].AXES if ff else {})}
     made = jax.eval_shape(functools.partial(_layer_init, cfg=cfg, mixer=mixer, ff=ff), jax.random.PRNGKey(0))
     return {leaf: axes[leaf] for leaf in made}
 
@@ -136,14 +140,16 @@ def param_axes(cfg: ModelConfig) -> Params:
 
 def n_params(cfg: ModelConfig) -> int:
     """Approximate parameter count (embeddings + blocks + norms), of what is held: each
-    part's own count (beside its `init`), a norm a feed-forward part. Capacity-based
-    experts count as the dense MLP they stand in for, as they always have."""
+    part's own count (beside its `init`), a norm a feed-forward part, one more behind every
+    part under cfg.part_post_norm. Capacity-based experts count as the dense MLP they stand
+    in for, as they always have."""
     d = cfg.d_model
 
     def layer(mixer, ff):
         ff = "dense" if ff and not cfg.moe_dropless else ff
         return ((MIXERS[mixer][0].n_params(cfg) if mixer else 0)
-                + (FEED_FORWARD[ff][0].n_params(cfg) + d if ff else 0))
+                + (FEED_FORWARD[ff][0].n_params(cfg) + d if ff else 0)
+                + d * cfg.part_post_norm * (bool(mixer) + bool(ff)))
 
     return (cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + d
             + sum(n * layer(mixer, ff) for n, mixer, ff in _layer_kinds(cfg).values())
@@ -260,6 +266,7 @@ def embed_tokens(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Arr
     specs, so the gate is the mesh's tp extent, not the table's actual spec.)
     Semantics note: out-of-range token ids clamp under gather but embed to zeros
     under the one-hot path; valid inputs (< vocab_size) are identical.
+    cfg.embed_scale (0 = none) multiplies the result.
     """
     table = params["embed"].astype(cfg.activation_dtype)
     try:
@@ -269,9 +276,13 @@ def embed_tokens(params: Params, tokens: jax.Array, cfg: ModelConfig) -> jax.Arr
     except Exception:
         sharded = False
     if not sharded or tokens.shape[-1] < _ONE_HOT_MIN_ROW:
-        return table[tokens]
-    onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=table.dtype)
-    return jnp.einsum("bsv,vd->bsd", onehot, table)
+        x = table[tokens]
+    else:
+        onehot = jax.nn.one_hot(tokens, table.shape[0], dtype=table.dtype)
+        x = jnp.einsum("bsv,vd->bsd", onehot, table)
+    if cfg.embed_scale:  # in float32, rounded once
+        x = (x.astype(jnp.float32) * cfg.embed_scale).astype(x.dtype)
+    return x
 
 
 # --------------------------------------------------------------------------- block
@@ -285,6 +296,16 @@ def _unconstrained(x: jax.Array, *logical_axes) -> jax.Array:
     return x
 
 
+def _onto(x: jax.Array, out: jax.Array, lp: Params, leaf: str, cfg: ModelConfig,
+          constrain=_unconstrained) -> jax.Array:
+    """The residual, for mixers and feed-forward parts alike: x + a part's output, which
+    goes through the part's own norm first where the layer has one (cfg.part_post_norm:
+    `attn_post_norm` behind a mixer, `mlp_post_norm` behind a feed-forward part)."""
+    if leaf in lp:
+        out = rms_norm(out, lp[leaf], cfg.norm_eps)
+    return constrain(x + out, "batch", "seq", "act_embed")
+
+
 def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
                  token_mask: Optional[jax.Array] = None, constrain=_unconstrained):
     """Norm, the dense or MoE feed-forward (whichever the layer's parameters are),
@@ -293,7 +314,7 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
     (moe.expert_layer: {"load": [E], "chosen": [B * S, k]}).
     token_mask [B, S] (1 = real) keeps pad tokens and inactive slots out of the
     experts' capacity; `constrain(array, *logical_axes)` is the caller's sharding
-    constraint on the dense product."""
+    constraint on the dense product and on the result."""
     dt = x.dtype
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     b, s, d = h.shape
@@ -306,7 +327,7 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
             act = jnp.square(jax.nn.relu(jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))))
         ff = constrain(act, "batch", "seq", "act_mlp")
         down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
-        return x + down, jnp.zeros((), jnp.float32)
+        return _onto(x, down, lp, "mlp_post_norm", cfg, constrain), jnp.zeros((), jnp.float32)
     if cfg.moe_dropless:
         y2, aux = moe.expert_layer(h.reshape(b * s, d), lp, cfg)
     else:
@@ -315,7 +336,7 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
             lp["w_down"], cfg,
             mask=None if token_mask is None else token_mask.reshape(b * s),
         )
-    return x + y2.reshape(b, s, d), aux
+    return _onto(x, y2.reshape(b, s, d), lp, "mlp_post_norm", cfg, constrain), aux
 
 
 def output_head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -357,12 +378,15 @@ def _block(
     cache_kv: Optional[Tuple[jax.Array, jax.Array]] = None,
     cache_len: Optional[jax.Array] = None,
     token_mask: Optional[jax.Array] = None,
+    windowed: bool = False,
 ):
     """One layer: the mixer its parameters hold (attention, a Mamba-2 or a Kimi-Delta-
     Attention mixer, a gated short convolution, or none) and then the feed-forward part
     they hold (or none), each
-    behind its own norm and residual. Every family with a layer pattern holds one part a
+    behind its own norm and residual (`_onto`: the one place a part's output joins the
+    stream). Every family with a layer pattern holds one part a
     layer; the others attention and a feed-forward part in each: the decoder block.
+    `windowed`: the attention is a `W` part's (config.LAYER_KINDS).
     Returns (x, updated (k,v) if caching, moe aux loss)."""
     new_kv, aux = None, jnp.zeros((), jnp.float32)
     part = next((part for part, _ in MIXERS.values() if part.LEAF in lp), None)
@@ -375,13 +399,15 @@ def _block(
         # `attn` is where the readers of the trace look for a layer's mixer
         # (benchmarks/metrics/train_scoped_pct.json, train_head_loss_pct.json)
         with jax.named_scope(part.SCOPE) if part.SCOPE else contextlib.nullcontext():
-            x = wsc(part.mixer(x, lp, cfg), "batch", "seq", "act_embed")
+            out = part.mixer(x, lp, cfg)
     elif part is not None:
-        x, new_kv = part.mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len)
+        out, new_kv = part.mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed)
+    if part is not None:
+        with jax.named_scope("attn"):
+            x = _onto(x, out, lp, "attn_post_norm", cfg, wsc)
     if "mlp_norm" in lp:
         with jax.named_scope("mlp"):
             x, aux = feed_forward(x, lp, cfg, token_mask, constrain=wsc)
-            x = wsc(x, "batch", "seq", "act_embed")
     return x, new_kv, aux
 
 
@@ -475,8 +501,10 @@ def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
     around it: each layer's gradient is then written once, into its row."""
     unit, n = pattern_period(cfg.layer_pattern)
     per_unit = {name: count // n for name, (count, _, _) in _layer_kinds(cfg).items()}
-    layer = _maybe_remat(
-        lambda h, lp: _block(h, lp, cfg, positions, segment_ids, token_mask=token_mask)[::2], cfg)
+    layer = {windowed: _maybe_remat(  # (a static argument of the rematerialised body: a body each)
+        lambda h, lp, windowed=windowed: _block(
+            h, lp, cfg, positions, segment_ids, token_mask=token_mask, windowed=windowed)[::2], cfg)
+        for windowed in {LAYER_KINDS[c].windowed for c in unit}}
 
     def period(h, stacks):
         at, auxs = dict.fromkeys(stacks, 0), []
@@ -484,7 +512,7 @@ def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
             name = LAYER_KINDS[c].stack
             with jax.named_scope("layer_params"):  # the layer's rows of its stack: copies a step pays for
                 lp = jax.tree.map(lambda a: a[at[name]], stacks[name])  # noqa: B023
-            h, aux = layer(h, lp)
+            h, aux = layer[LAYER_KINDS[c].windowed](h, lp)
             at[name] += 1
             if LAYER_KINDS[c].ff == "experts":
                 auxs.append(aux)

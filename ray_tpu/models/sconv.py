@@ -48,7 +48,7 @@ def n_params(cfg: ModelConfig) -> int:
 
 
 def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
-    """x [B, T, D] -> x + the layer's output."""
+    """x [B, T, D] -> the layer's output (llama._block adds it to x)."""
     dt = x.dtype
     with jax.named_scope("sconv"):
         with jax.named_scope("sconv_in_proj"):
@@ -57,4 +57,4 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
         with jax.named_scope("sconv_gate_conv"):
             y = (c.astype(jnp.float32) * _causal_conv(b * v, lp["sconv_w"], 0.0)).astype(dt)
         with jax.named_scope("sconv_out_proj"):
-            return x + jnp.einsum("bte,ed->btd", y, _w(lp["sconv_out"], dt))
+            return jnp.einsum("bte,ed->btd", y, _w(lp["sconv_out"], dt))

@@ -79,7 +79,7 @@ def _causal_conv(x: jax.Array, w: jax.Array, bias: jax.Array) -> jax.Array:
 
 
 def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
-    """x [B, T, D] -> x + the layer's output."""
+    """x [B, T, D] -> the layer's output (llama._block adds it to x)."""
     dt_ = x.dtype
     bsz, t, _ = x.shape
     h, p, g, n = cfg.ssm_n_heads, cfg.ssm_head_dim, cfg.ssm_n_groups, cfg.ssm_state
@@ -102,4 +102,4 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
         y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + cfg.norm_eps)
         y = (y.reshape(bsz, t, d_in) * lp["gate_norm"]).astype(dt_)
     with jax.named_scope("ssm_out_proj"):
-        return x + jnp.einsum("bte,ed->btd", y, _w(lp["out_proj"], dt_))
+        return jnp.einsum("bte,ed->btd", y, _w(lp["out_proj"], dt_))

@@ -54,6 +54,7 @@ def attention_reference(
     scale: Optional[float] = None,
     q_offset: Optional[jax.Array] = None,
     kv_valid_len: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Pure-XLA attention. Numerically the ground truth for the Pallas kernel tests.
 
@@ -61,6 +62,7 @@ def attention_reference(
     `segment_ids`: [B, Skv] int array; attention only within equal segments (packing).
     `q_offset`: kv index of query row 0 (decode-with-cache); default aligns the ends.
     `kv_valid_len`: kv slots >= this are masked out (padded cache tail).
+    `window` (causal): key j is kept for query i where 0 <= i - j < window.
     """
     # GQA as a grouped contraction: q heads are viewed as [Hkv, n_rep] and K/V
     # are never repeated. (Broadcasting K/V to H heads first is the same math,
@@ -78,7 +80,8 @@ def attention_reference(
         if q_offset is None:
             q_offset = skv - sq
         qi = jnp.arange(sq)[:, None] + q_offset
-        logits = jnp.where(kj <= qi, logits, -jnp.inf)
+        seen = kj <= qi if window is None else (kj <= qi) & (kj > qi - window)
+        logits = jnp.where(seen, logits, -jnp.inf)
     if kv_valid_len is not None:
         logits = jnp.where(kj < kv_valid_len, logits, -jnp.inf)
     if segment_ids is not None:
@@ -102,6 +105,7 @@ def attention_chunked(
     q_offset: Optional[jax.Array] = None,
     kv_valid_len: Optional[jax.Array] = None,
     block_kv: int = 512,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Online-softmax attention over KV blocks ("flash in XLA").
 
@@ -154,7 +158,8 @@ def attention_chunked(
         logits = logits * scale
         neg = jnp.float32(-1e30)  # finite: keeps fully-masked rows NaN-free
         if causal:
-            logits = jnp.where((kj <= qi)[None, None], logits, neg)
+            seen = kj <= qi if window is None else (kj <= qi) & (kj > qi - window)
+            logits = jnp.where(seen[None, None], logits, neg)
         valid = kv_valid_len if kv_valid_len is not None else skv
         logits = jnp.where((kj < valid)[None, None], logits, neg)
         if seg_c is not None:
@@ -212,7 +217,7 @@ def _log_fallback(q_shape, k_shape, impl: str) -> None:
     )
 
 
-def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotation=None):
+def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotation=None, window=None):
     """The Pallas kernel under an ambient mesh. GSPMD cannot partition a Mosaic
     kernel, and Mosaic refuses to lower while ANY mesh axis is still
     automatic, so the kernel is called per shard with every such axis made
@@ -226,7 +231,7 @@ def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotatio
     from .flash_attention import flash_attention
 
     def kernel(q, k, v, rows):
-        return flash_attention(q, k, v, causal=causal, scale=scale, segment_ids=rows.get("seg"),
+        return flash_attention(q, k, v, causal=causal, scale=scale, segment_ids=rows.get("seg"), window=window,
                                rope=None if rotation is None else (rows["pos"], rotation.theta))
 
     # what comes a row of the batch (or one row for all of it): segment ids, positions
@@ -257,6 +262,7 @@ def attention(
     impl: str = "auto",
     shard_spec=None,
     rotation: Optional[Rotation] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Dispatching attention. impl: auto|pallas|chunked|reference.
 
@@ -274,7 +280,12 @@ def attention(
     first, and so does the Pallas path at head width 64 (the rotate kernel's tiles are
     lane-dense [rows, D]; in jax.numpy the rotation fuses with the projections' epilogue
     and the pad to 128 lanes, as latent attention's does with its concatenation).
+
+    window: a sliding window over one causal sequence (key j is kept for query i where
+    0 <= i - j < window), on every path; the Pallas kernels skip the tiles outside the band.
     """
+    if window is not None and (not causal or q_offset is not None or kv_valid_len is not None):
+        raise NotImplementedError("an attention window under a KV cache (q_offset, kv_valid_len) or without `causal`")
     if impl == "auto":
         on_tpu = jax.default_backend() not in ("cpu", "gpu")
         # The pallas kernel's causal mask assumes query row i is absolute position i,
@@ -305,7 +316,7 @@ def attention(
         rotation = None
     if impl == "pallas":
         return _flash_per_shard(q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
-                                shard_spec=shard_spec, rotation=rotation)
+                                shard_spec=shard_spec, rotation=rotation, window=window)
     if impl == "chunked":
         return attention_chunked(
             q,
@@ -316,6 +327,7 @@ def attention(
             scale=scale,
             q_offset=q_offset,
             kv_valid_len=kv_valid_len,
+            window=window,
         )
     return attention_reference(
         q,
@@ -326,4 +338,5 @@ def attention(
         scale=scale,
         q_offset=q_offset,
         kv_valid_len=kv_valid_len,
+        window=window,
     )

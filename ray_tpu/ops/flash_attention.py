@@ -29,7 +29,11 @@ dimension runs over spans. A grid step costs 0.35-0.6 us on a v5e whatever it co
 
 Under `causal` the loop's bounds come from `program_id`: a tile wholly above the
 diagonal is not visited, and a span wholly above it names, in its index maps, the
-nearest span used, so that the pipeline issues no copy for it.
+nearest span used, so that the pipeline issues no copy for it. A `window` (key j is kept
+for query i where 0 <= i - j < window) is a second edge of the same kind, below the band:
+the forward and dQ loops start at the first kv tile the window reaches, dK/dV ends at the
+last q tile that still sees the kv tile, and a span wholly outside the band names the
+nearest one inside it. A windowed call's kernels carry `_window` behind their names.
 Every product feeds the MXU the inputs' own dtype (bf16 in training) and accumulates in
 f32; scores, exponentials, logsumexp, delta and all accumulators are f32. Per-row
 statistics and segment ids are kept 128 equal lanes wide inside a kernel and travel
@@ -130,20 +134,39 @@ class TileCounts(NamedTuple):
 
 
 def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim: int = 128,
-                itemsize: int = 2, n_rep: int = 1, kv_major: bool = False) -> TileCounts:
+                itemsize: int = 2, n_rep: int = 1, kv_major: bool = False,
+                window: Optional[int] = None) -> TileCounts:
     """What a (batch, query head) costs the forward and dQ kernels, or (`kv_major`) a
     (batch, kv head with its `n_rep` query heads) the dK/dV kernel: from the same
     `_tiling` the kernels' grids are built from. A causal tile is computed if any of
-    its scores is kept (kv position <= q position)."""
+    its scores is kept (kv position <= q position, and inside a `window` more than q
+    position - window): with 512 x 512 tiles and a window of 2,048 a q tile meets 5 kv
+    tiles where the band needs 4.0."""
     t = _tiling(sq, skv, bq, bkv, head_dim, itemsize, n_rep)
     nq, nk = sq // t.bq, skv // t.bkv
     steps, heads = (nk * (sq // t.q_span), n_rep) if kv_major else (nq * (skv // t.kv_span), 1)
     if not causal:
         return TileCounts(steps, heads * nq * nk, float(heads * nq * nk))
-    computed = sum(min(_last_kv_block(qi, t.bq, t.bkv) + 1, nk) for qi in range(nq))
-    m = min(sq, skv)
-    kept = m * (m + 1) // 2 + (sq - m) * skv  # row i keeps min(i + 1, skv) scores
+    window = _band(window, sq, skv, causal)
+    if window is None:
+        computed = sum(min(_last_kv_block(qi, t.bq, t.bkv) + 1, nk) for qi in range(nq))
+        m = min(sq, skv)
+        kept = m * (m + 1) // 2 + (sq - m) * skv  # row i keeps min(i + 1, skv) scores
+    else:  # the forward loop's own bounds (dK/dV walks the same tiles from the other side)
+        computed = sum(_last_kv_block(qi, t.bq, t.bkv) - _first_kv_block(qi, t.bq, t.bkv, window) + 1
+                       for qi in range(nq))
+        kept = window * (window + 1) // 2 + (sq - window) * window  # row i keeps min(i + 1, window) scores
     return TileCounts(steps, heads * computed, heads * kept / (t.bq * t.bkv))
+
+
+def _band(window: Optional[int], sq: int, skv: int, causal: bool) -> Optional[int]:
+    """The window the kernels build a second edge for: None where there is none or it
+    reaches the start of the sequence from every query (today's program)."""
+    if window is None:
+        return None
+    if window < 1 or not causal or sq != skv:
+        raise ValueError(f"window {window}: a causal band over one sequence (sq {sq}, skv {skv}, causal {causal})")
+    return None if window >= skv else int(window)
 
 
 def _interpret() -> bool:
@@ -183,6 +206,12 @@ def _pallas_call(kernel, *, name: str,
         **kw)
 
 
+def _named(kernel: str, window: Optional[int]) -> str:
+    """A flash kernel's name in the device trace: a windowed call's carries `_window`
+    behind it, so that a metric can tell the band's kernels from the triangle's."""
+    return kernel if window is None else f"{kernel}_window"
+
+
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
@@ -200,12 +229,40 @@ def _first_q_block(kj, bq: int, bkv: int):
     return (kj * bkv) // bq
 
 
+def _first_kv_block(qi, bq: int, bkv: int, window: int):
+    """The first kv block a q block's window reaches: that of its first row's oldest key."""
+    oldest = qi * bq - (window - 1)
+    return (max(oldest, 0) if isinstance(oldest, int) else jnp.maximum(oldest, 0)) // bkv
+
+
+def _last_q_block(kj, bq: int, bkv: int, window: int):
+    """The last q block (the sequence's end apart) whose window still reaches a kv block:
+    that of the newest query that sees its last key."""
+    return (kj * bkv + (bkv - 1) + (window - 1)) // bq
+
+
 def _kv_tiles_end(causal: bool, qi, sj, n: int, bq: int, bkv: int):
     """How many of span sj's `n` kv tiles q block qi walks: all, or up to the last
     that the causal diagonal reaches."""
     if not causal:
         return n
     return jnp.clip(_last_kv_block(qi, bq, bkv) + 1 - sj * n, 0, n)
+
+
+def _kv_tiles_start(window: Optional[int], qi, sj, n: int, bq: int, bkv: int):
+    """The first of span sj's `n` kv tiles that q block qi walks: 0, or the first its
+    window reaches."""
+    if window is None:
+        return 0
+    return jnp.clip(_first_kv_block(qi, bq, bkv, window) - sj * n, 0, n)
+
+
+def _q_tiles_end(window: Optional[int], kj, sp, n: int, bq: int, bkv: int):
+    """How many of span sp's `n` q tiles kv block kj walks (dK/dV): all, or up to the last
+    whose window still reaches it."""
+    if window is None:
+        return n
+    return jnp.clip(_last_q_block(kj, bq, bkv, window) + 1 - sp * n, 0, n)
 
 
 def _walk(lo, hi, n: int, tile) -> None:
@@ -226,7 +283,8 @@ def _at(t, block: int):
     return slice(None) if isinstance(t, int) else pl.ds(pl.multiple_of(t * block, block), block)
 
 
-def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, seg_row):
+def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, seg_row,
+          window: Optional[int] = None):
     """Which scores of a tile stay (None: all). `q_axis` is the axis query positions
     run along; `seg_col` [rows, 128] and `seg_row` [1, >= cols] are the segment ids
     of the tile's rows and columns. Every computed tile builds the causal mask, also
@@ -237,6 +295,8 @@ def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, 
         ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
                  - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
         keep = ahead >= kj * bkv - qi * bq
+        if window is not None:  # and q position - kv position < window
+            keep = keep & (ahead < kj * bkv - qi * bq + window)
     if seg_col is not None:
         same = _lanes_to(seg_col[:], shape[1]) == seg_row[:, :shape[1]]
         keep = same if keep is None else (keep & same)
@@ -263,6 +323,7 @@ def _fwd_kernel(
     causal: bool,
     bq: int,
     bkv: int,
+    window: Optional[int] = None,
 ):
     qi = pl.program_id(2)
     sj = pl.program_id(3)  # which span of K/V
@@ -279,7 +340,7 @@ def _fwd_kernel(
         v = v_ref[_at(t, bkv)]
         s = _dot(q_ref[:], k_ref[_at(t, bkv)], _NT) * scale  # [bq, bkv]
         keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
-                     None if seg_kv_ref is None else seg_kv_ref[t])
+                     None if seg_kv_ref is None else seg_kv_ref[t], window)
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
         # The running statistics stay 128 equal lanes wide: as [bq, 1] columns every
@@ -293,7 +354,7 @@ def _fwd_kernel(
         m_scr[:] = m_new
         acc_scr[:] = acc_scr[:] * _lanes_to(alpha, v.shape[1]) + _dot(p.astype(v.dtype), v, _NN)
 
-    _walk(0, _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
+    _walk(_kv_tiles_start(window, qi, sj, n, bq, bkv), _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
 
     @pl.when(sj == pl.num_programs(3) - 1)
     def _finalize():
@@ -304,14 +365,16 @@ def _fwd_kernel(
         lse_ref[:] = lse_scr[:].T[:1]  # rows become lanes; those past bq are never read
 
 
-def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg):
+def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None):
     """BlockSpecs of the forward and dQ grids (b, h, q block, kv span): q-side,
     kv-side, per-row statistics (`_rows`: a lane vector a q block), and the segment
     ids of rows and columns."""
     bq, bkv = t.bq, t.bkv
     n = t.kv_span // bkv
 
-    def kv_span(qi, sj):  # a span above the diagonal names the last span used
+    def kv_span(qi, sj):  # a span above the diagonal names the last span used, one below the band the first
+        if window is not None:
+            return jnp.clip(sj, _first_kv_block(qi, bq, bkv, window) // n, _last_kv_block(qi, bq, bkv) // n)
         return jnp.minimum(sj, _last_kv_block(qi, bq, bkv) // n) if causal else sj
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, sj: (bi, hi, qi, 0))
@@ -349,23 +412,24 @@ def _fwd(
     causal: bool,
     bq: int,
     bkv: int,
+    window: Optional[int] = None,
 ):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize)
     bq, bkv = t.bq, t.bkv
     has_seg = seg is not None
-    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, t, has_seg)
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, t, has_seg, window)
     args = [q, k, v] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def kernel(*refs):
         ins, segs, (o_ref, lse_ref, *scratch) = _unpack(refs, (2,) * 3, has_seg)
         _fwd_kernel(*ins, *segs, o_ref.at[0, 0], lse_ref.at[0, 0, 0], *scratch,
-                    scale=scale, causal=causal, bq=bq, bkv=bkv)
+                    scale=scale, causal=causal, bq=bq, bkv=bkv, window=window)
 
     out, lse = _pallas_call(
         kernel,
-        name="flash_attention_fwd",
+        name=_named("flash_attention_fwd", window),
         grid=(b, h, sq // bq, skv // t.kv_span),
         in_specs=[q_spec, kv_spec, kv_spec] + seg_specs,
         out_specs=[q_spec, stat_spec],
@@ -389,7 +453,7 @@ def _fwd(
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_q_ref, seg_kv_ref, dq_ref,
     dq_scr, lse_scr, delta_scr,
-    *, scale, causal, bq, bkv,
+    *, scale, causal, bq, bkv, window=None,
 ):
     """k_ref and v_ref are a span of K/V, [span, D]. lse_ref and delta_ref are [1, P];
     the tile [bq, bkv] wants them down its rows, so the q block's first step turns
@@ -409,7 +473,7 @@ def _bwd_dq_kernel(
         k = k_ref[_at(t, bkv)]
         s = _dot(q_ref[:], k, _NT) * scale  # [bq, bkv]
         keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
-                     None if seg_kv_ref is None else seg_kv_ref[t])
+                     None if seg_kv_ref is None else seg_kv_ref[t], window)
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
         p = jnp.exp(s - _lanes_to(lse_scr[:], s.shape[1]))
@@ -417,7 +481,7 @@ def _bwd_dq_kernel(
         ds = p * (dp - _lanes_to(delta_scr[:], s.shape[1])) * scale
         dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _walk(0, _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
+    _walk(_kv_tiles_start(window, qi, sj, n, bq, bkv), _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
 
     @pl.when(sj == pl.num_programs(3) - 1)
     def _():
@@ -427,7 +491,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_kv_ref, seg_q_ref,
     dk_ref, dv_ref, dk_scr, dv_scr,
-    *, scale, causal, bq, bkv,
+    *, scale, causal, bq, bkv, window=None,
 ):
     """One kv block against a span of q rows of the query heads of its group, on the
     transposed tile [bkv, bq]: q_ref and do_ref are [n_rep, span, D], lse_ref and
@@ -443,6 +507,7 @@ def _bwd_dkv_kernel(
 
     # the first of the span's q tiles that attends to this kv block
     lo = jnp.clip(_first_q_block(kj, bq, bkv) - sp * n, 0, n) if causal else 0
+    hi = _q_tiles_end(window, kj, sp, n, bq, bkv)
 
     def head(r):
         def tile(t):
@@ -451,7 +516,7 @@ def _bwd_dkv_kernel(
             do = do_ref[r, _at(t, bq)]
             st = _dot(k_ref[:], q, _NT) * scale  # [bkv, bq]
             keep = _keep(st.shape, 1, qi, kj, bq, bkv, causal, seg_kv_ref,
-                         None if seg_q_ref is None else seg_q_ref[t])
+                         None if seg_q_ref is None else seg_q_ref[t], window)
             if keep is not None:
                 st = jnp.where(keep, st, NEG_INF)
             pt = jnp.exp(st - lse_ref[r, t][:, :bq])
@@ -460,7 +525,7 @@ def _bwd_dkv_kernel(
             dst = pt * (dpt - delta_ref[r, t][:, :bq]) * scale
             dk_scr[:] += _dot(dst.astype(q.dtype), q, _NN)
 
-        _walk(lo, n, n, tile)
+        _walk(lo, hi, n, tile)
 
     _walk(0, n_rep, n_rep, head)
 
@@ -470,7 +535,7 @@ def _bwd_dkv_kernel(
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
+def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     n_rep = h // hkv
@@ -483,17 +548,17 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
     stats = [lse, _rows(delta, bq)]
 
     # --- dQ pass: grid (b, h, q blocks, kv spans)
-    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, t, has_seg)
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, t, has_seg, window)
     args = [q, k, v, dout, *stats] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def dq_kernel(*refs):
         ins, segs, (dq_ref, *scratch) = _unpack(refs, (2,) * 6, has_seg)
         _bwd_dq_kernel(*ins, *segs, dq_ref.at[0, 0], *scratch,
-                       scale=scale, causal=causal, bq=bq, bkv=bkv)
+                       scale=scale, causal=causal, bq=bq, bkv=bkv, window=window)
 
     dq = _pallas_call(
         dq_kernel,
-        name="flash_attention_bwd_dq",
+        name=_named("flash_attention_bwd_dq", window),
         grid=(b, h, sq // bq, skv // t.kv_span),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec] + seg_specs,
         out_specs=q_spec,
@@ -507,10 +572,14 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
 
     # --- dK/dV pass: grid (b, kv head, kv blocks, q spans), the last and the group's query
     # heads summed in the kernel. A q span no row of which sees the kv block names the
-    # first that does.
+    # first that does (or, past a window, the last).
     n = t.q_span // bq
+    last_span = sq // t.q_span - 1
 
     def q_span(kj, sp):
+        if window is not None:
+            return jnp.clip(sp, _first_q_block(kj, bq, bkv) // n,
+                            jnp.minimum(_last_q_block(kj, bq, bkv, window) // n, last_span))
         return jnp.maximum(sp, _first_q_block(kj, bq, bkv) // n) if causal else sp
 
     q_spec2 = pl.BlockSpec((1, n_rep, t.q_span, d),
@@ -532,11 +601,11 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv):
         # q, dO and the statistics keep the group's query heads as their leading dimension
         ins, segs, (dk_ref, dv_ref, *scratch) = _unpack(refs, (3, 2, 2, 3, 4, 4), has_seg)
         _bwd_dkv_kernel(*ins, *segs, dk_ref.at[0, 0], dv_ref.at[0, 0], *scratch,
-                        scale=scale, causal=causal, bq=bq, bkv=bkv)
+                        scale=scale, causal=causal, bq=bq, bkv=bkv, window=window)
 
     dk, dv = _pallas_call(
         dkv_kernel,
-        name="flash_attention_bwd_dkv",
+        name=_named("flash_attention_bwd_dkv", window),
         grid=(b, hkv, skv // bkv, sq // t.q_span),
         in_specs=in_specs2,
         out_specs=[kv_spec2, kv_spec2],
@@ -669,23 +738,23 @@ rope_to_heads.defvjp(_rope_fwd_rule, _rope_bwd_rule)
 NARROW_HEAD = 64
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_bhsd(q, k, v, seg, scale, causal, bq, bkv):
-    out, _ = _fwd(q, k, v, seg, scale, causal, bq, bkv)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_bhsd(q, k, v, seg, scale, causal, bq, bkv, window):
+    out, _ = _fwd(q, k, v, seg, scale, causal, bq, bkv, window)
     return out
 
 
-def _flash_fwd_rule(q, k, v, seg, scale, causal, bq, bkv):
+def _flash_fwd_rule(q, k, v, seg, scale, causal, bq, bkv, window):
     # the kernel's two results carry `FLASH_NAMES`: a policy that keeps both leaves nothing
     # in a rematerialised layer that reads the kernel, and JAX drops its second run there
     out, lse = (_named_bits(x, name)
-                for x, name in zip(_fwd(q, k, v, seg, scale, causal, bq, bkv), FLASH_NAMES))
+                for x, name in zip(_fwd(q, k, v, seg, scale, causal, bq, bkv, window), FLASH_NAMES))
     return out, (q, k, v, seg, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, bq, bkv, res, dout):
+def _flash_bwd_rule(scale, causal, bq, bkv, window, res, dout):
     q, k, v, seg, out, lse = res
-    dq, dk, dv = _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv)
+    dq, dk, dv = _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window)
     return dq, dk, dv, None
 
 
@@ -713,9 +782,12 @@ def flash_attention(
     block_q: int = BLOCK_Q,
     block_kv: int = BLOCK_KV,
     rope: Optional[tuple] = None,  # (positions [B or 1, S], theta): q and k come un-rotated
+    window: Optional[int] = None,  # key j is kept for query i where 0 <= i - j < window
 ) -> jax.Array:
     """BSHD flash attention. Sq must equal Skv when segment_ids are used, and with
     `rope`: then the rotate kernel runs in front of the flash kernels (`rope_to_heads`).
+    `window` (causal, one sequence) keeps the last `window` keys a query, its own among
+    them; one no shorter than the sequence is no window.
 
     Heads 64 wide (`NARROW_HEAD`) run the same three kernels, under the same names, on
     q, k and v padded with zero lanes to 128: the scores do not see zeros in q and k, the
@@ -736,5 +808,6 @@ def flash_attention(
     qt, kt = (_heads_major(q), _heads_major(k)) if rope is None else rope_to_heads(q, k, *rope)
     vt = _heads_major(v)
     seg = None if segment_ids is None else _segment_lanes(segment_ids, q.shape[1])
-    out = _flash_bhsd(qt, kt, vt, seg, scale, causal, block_q, block_kv)
+    out = _flash_bhsd(qt, kt, vt, seg, scale, causal, block_q, block_kv,
+                      _band(window, q.shape[1], k.shape[1], causal))
     return _heads_major(out)[..., :d]

@@ -49,7 +49,7 @@ def _mamba_8_head_shares(x):
     lp["gate_norm"] = 1 + 0.1 * jax.random.normal(jax.random.PRNGKey(5), lp["gate_norm"].shape)
     want = ref.mamba_layer(x, lp, model_of(whole)) - x
     mixer = jax.jit(lambda x, mine: ssm.mixer(x, mine, share))  # eight shares, one program
-    return want, [mixer(x, _mamba_share(lp, whole, i, 8)) - x for i in range(8)], 1
+    return want, [mixer(x, _mamba_share(lp, whole, i, 8)) for i in range(8)], 1  # (the mixer's output: `_block` adds it to x)
 
 
 def _attention_8_head_shares(x):

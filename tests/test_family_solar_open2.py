@@ -41,7 +41,7 @@ def _kda_8_head_shares(x):
         mine = {name: jnp.take(a, jnp.arange(2 * i, 2 * i + 2), axis=by_heads[name]) if name in by_heads else a
                 for name, a in lp.items()}
         assert mine["kda_f_down"].shape == (CFG.d_model, 16) and mine["kda_qkv"].shape == (CFG.d_model, 3, 2, 16)
-        parts.append(mixer(x, mine) - x)
+        parts.append(mixer(x, mine))  # (the mixer's output: `_block` adds it to x)
     return want, parts, 1
 
 

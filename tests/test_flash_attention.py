@@ -443,3 +443,128 @@ def test_flash_attention_at_unequal_head_parts(s):
     np.testing.assert_allclose(l1, l2, rtol=1e-5)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+# ------------------------------------------------- a sliding window: the band's second edge (PR 46)
+# Key j is kept for query i where 0 <= i - j < window. Tiles of 64 here where the chip's are
+# 512: a window of 256 over 4 tiles is then what 2,048 is over 512-row tiles; one case runs
+# the chip's own 512 x 512 tiles under a window of 2,048.
+WINDOWS = {
+    # name: (s, h, hkv, d, block, window, segment cuts, rotate, dtype[, span budget])
+    "shorter-than-a-tile": (512, 2, 2, 64, 64, 24, None, False, jnp.float32),
+    "a-tile": (512, 2, 1, 64, 64, 64, None, False, jnp.float32),
+    "four-tiles-gqa8-w128": (512, 8, 1, 128, 64, 256, None, False, jnp.float32),
+    "not-a-multiple-of-a-tile-gqa4": (512, 4, 1, 64, 64, 200, None, False, jnp.float32),
+    "one-less-than-two-tiles": (512, 2, 2, 64, 64, 127, None, False, jnp.float32),
+    "as-long-as-the-sequence": (256, 2, 1, 64, 64, 256, None, False, jnp.float32),
+    "segments-gqa2": (512, 4, 2, 64, 64, 200, (90, 300, 310), False, jnp.float32),
+    "segments-w128-shorter-than-a-tile": (512, 2, 1, 128, 64, 40, (100, 260), False, jnp.float32),
+    "rotated-w128-gqa4": (512, 4, 1, 128, 64, 200, None, True, jnp.float32),
+    "rotated-segments-w128": (512, 2, 2, 128, 64, 256, (70, 400), True, jnp.float32),
+    "bf16-rotated-w128-gqa8": (512, 8, 1, 128, 128, 256, None, True, jnp.bfloat16),
+    "bf16-segments-gqa2": (512, 4, 2, 64, 64, 100, (200,), False, jnp.bfloat16),
+    # spans shorter than the sequence: a span wholly outside the band names one inside it
+    "spans-of-2-gqa2": (512, 4, 2, 64, 64, 100, None, False, jnp.float32, 128 << 10),
+    "spans-of-2-segments-not-a-multiple": (512, 2, 2, 64, 64, 200, (150, 333), False, jnp.float32, 128 << 10),
+    "the-chips-tiles-2048": (4096, 1, 1, 128, 512, 2048, None, False, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(WINDOWS))
+def test_windowed_fwd_and_grads_match_the_reference(case, monkeypatch):
+    """The three windowed kernels (by name) in the interpreter against the plain softmax
+    with both edges in its mask: the output and all three gradients under a random
+    cotangent, one jitted program as its users run it."""
+    from ray_tpu.models.llama import rope
+
+    s, h, hkv, d, block, window, cuts, rotate, dtype, *budget = WINDOWS[case]
+    if budget:
+        monkeypatch.setattr(fa, "SPAN_VMEM_BYTES", budget[0])
+        t = fa._tiling(s, s, block, block, d, jnp.dtype(dtype).itemsize, h // hkv)
+        assert t.kv_span < s and t.q_span < s, t
+    b, theta = 2, 1e4
+    q, k, v, g = (_rand((b, s, heads, d), i, dtype) for i, heads in enumerate((h, hkv, hkv, h)))
+    seg = None if cuts is None else _packed(b, s, cuts)
+    pos = jnp.arange(s, dtype=jnp.int32)[None] * 2 + 5
+
+    def run(fn, *xs, **kw):
+        def loss(q, k, v):
+            o = fn(q, k, v, causal=True, segment_ids=seg, window=window, **kw)
+            return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32)), o
+        fn_ = jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)
+        (_, o), grads = jax.jit(fn_)(*xs)
+        return (o, *grads), _kernel_names(jax.make_jaxpr(fn_)(*xs).jaxpr)
+
+    got, names = run(flash_attention, q, k, v, block_q=block, block_kv=block,
+                     rope=(pos, theta) if rotate else None)
+    suffix = "" if window >= s else "_window"
+    assert sorted(n for n in names if n.startswith("flash")) == [
+        f"flash_attention_bwd_dkv{suffix}", f"flash_attention_bwd_dq{suffix}", f"flash_attention_fwd{suffix}"]
+
+    def plain(q, k, v, **kw):
+        if rotate:
+            q, k = rope(q, pos, theta), rope(k, pos, theta)
+        return attention_reference(q, k, v, **kw)
+
+    want, _ = run(plain, *(x.astype(jnp.float32) for x in (q, k, v)))
+    tol = 5e-3 if dtype == jnp.float32 else 3e-2
+    for name, a, ref in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == dtype and a.shape == ref.shape, name
+        scale = max(1.0, float(jnp.max(jnp.abs(ref))))
+        np.testing.assert_allclose(np.asarray(a, np.float32) / scale, np.asarray(ref) / scale,
+                                   rtol=0, atol=tol, err_msg=f"{case}: {name}")
+
+
+def test_the_window_is_a_band_on_every_path_and_none_is_todays_program():
+    """`attention_reference` and `attention_chunked` mask both edges (by hand here: a row
+    of the band's width); without a window, or with one no shorter than the sequence, the
+    Pallas path traces the program it always traced; what cannot take a window says so."""
+    from ray_tpu.ops.attention import attention, attention_chunked
+
+    b, s, h, d, window = 1, 96, 2, 16, 20
+    q, k, v = (_rand((b, s, h, d), i) for i in range(3))
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    band = (j <= i) & (i - j < window)
+    assert band.sum(-1).max() == window and band[5].sum() == 6
+    scores = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    probs = jax.nn.softmax(jnp.where(band, scores, -jnp.inf), axis=-1)
+    want = np.einsum("bhqk,bkhd->bqhd", probs, v)
+    np.testing.assert_allclose(attention_reference(q, k, v, window=window), want, atol=2e-6)
+    np.testing.assert_allclose(attention_chunked(q, k, v, window=window, block_kv=32), want, atol=2e-6)
+    np.testing.assert_allclose(attention(q, k, v, window=window, impl="reference"), want, atol=2e-6)
+
+    def text(**kw):
+        fn = jax.grad(lambda q, k, v: flash_attention(q, k, v, block_q=32, block_kv=32, **kw).sum(), argnums=(0, 1, 2))
+        return str(jax.make_jaxpr(fn)(q, k, v))
+
+    assert text() == text(window=None) == text(window=s) == text(window=10 * s)
+    assert text(window=window) != text() and "flash_attention_fwd_window" in text(window=window)
+    with pytest.raises(ValueError, match="a causal band"):
+        flash_attention(q, k, v, causal=False, window=window)
+    with pytest.raises(NotImplementedError, match="window under a KV cache"):
+        attention(q, k, v, window=window, q_offset=0)
+
+
+@pytest.mark.parametrize("s,block,window,n_rep", [
+    (16384, 512, 2048, 8), (8192, 512, 2048, 8), (4096, 512, 1000, 4), (2048, 512, 100, 1),
+    (1024, 256, 512, 2), (2048, 512, 513, 1), (2048, 512, 2048, 4), (2048, 512, 4096, 4)])
+def test_tile_counts_count_the_band(s, block, window, n_rep):
+    """`tile_counts(..., window=)` against a count by brute force over the tiles of the
+    (q, kv) plane: a tile is computed if any of its scores is kept. With 512 x 512 tiles
+    and a window of 2,048 a q tile meets 5 kv tiles where the band needs 4.0."""
+    n = s // block
+    first, last = np.arange(n) * block, np.arange(n) * block + block - 1
+    # some (i, j) of the tile has 0 <= i - j < window: the largest i - j >= 0, the smallest < window
+    computed = int(((last[:, None] - first[None, :] >= 0) & (first[:, None] - last[None, :] < window)).sum())
+    i = np.arange(s)
+    needed = float(np.minimum(i + 1, window).sum()) / block**2
+    fwd = tile_counts(s, s, True, block, block, window=window)
+    dkv = tile_counts(s, s, True, block, block, window=window, n_rep=n_rep, kv_major=True)
+    assert (fwd.tiles_computed, fwd.tiles_needed) == (computed, needed)
+    assert (dkv.tiles_computed, dkv.tiles_needed) == (n_rep * computed, n_rep * needed)
+    assert fwd.grid_steps == tile_counts(s, s, True, block, block).grid_steps  # the grid is the triangle's
+    if window >= s:
+        assert fwd == tile_counts(s, s, True, block, block)
+    if (s, window) == (16384, 2048):  # the cell's: 5 tiles a q tile where it needs 4.0 (both fewer at the start)
+        assert computed == 5 * 32 - 10 and needed == 4.0 * 32 - 8 + 1 / 256
+        assert needed / tile_counts(s, s, True, block, block).tiles_needed == pytest.approx(0.2344, abs=1e-3)

@@ -228,11 +228,11 @@ def test_the_gated_short_convolution_is_the_position_at_a_time_loop(taps):
         out.append((c[:, t] * jnp.einsum("bkd,kd->bd", window, lp["sconv_w"])) @ lp["sconv_out"])
         tail = window[:, 1:]
     want = x + jnp.stack(out, axis=1)
-    np.testing.assert_allclose(sconv.mixer(x, lp, cfg), want, atol=2e-5)
+    np.testing.assert_allclose(x + sconv.mixer(x, lp, cfg), want, atol=2e-5)  # (`_block` adds the mixer's output to x)
     np.testing.assert_allclose(lfm2_ref.conv_layer(x, lp, model_of(cfg)), want, atol=2e-5)
     # position 0 sees only its own z through the LAST tap
     first = (c[:, 0] * (b[:, 0] * v[:, 0]) * lp["sconv_w"][-1]) @ lp["sconv_out"]
-    np.testing.assert_allclose(sconv.mixer(x, lp, cfg)[:, 0] - x[:, 0], first, atol=2e-5)
+    np.testing.assert_allclose(sconv.mixer(x, lp, cfg)[:, 0], first, atol=2e-5)
     # causal: what comes later changes nothing earlier
     later = x.at[:, 5:].add(1.0)
     np.testing.assert_array_equal(sconv.mixer(later, lp, cfg)[:, :5], sconv.mixer(x, lp, cfg)[:, :5])

@@ -110,6 +110,75 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
         assert len(kernels) == (1 if "fwd" in path else 2), (path, kernels)
 
 
+def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_band_alone(one_chip, on_tpu):
+    """[2, 16384, 32 / 4, 128] inside a window of 2,048 with the rotation deferred into the
+    rotate kernel (trinitymini-train-ep16share-s16384's four `W` parts): forward, dQ and
+    dK/dV compile under their own names, which the accepted kernel metrics still find; the
+    grids are the triangle's (K and V one 16,384-row span, a group's Q/dO spans of 1,024);
+    and the loops' bounds and the index maps, the very functions the kernels call, over
+    every grid step: the tiles walked are the band's, a step wholly outside it walks none
+    and names the nearest span used."""
+    import numpy as np
+
+    from ray_tpu.ops import flash_attention as fa
+
+    b, s, h, kv, d, window, tile = 2, 16384, 32, 4, 128, 2048, 512
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+
+    def loss(q, k, v, pos):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True, window=window, rope=(pos, 1e4)).astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    compiled = jax.jit(grad).lower(q, k, k, pos).compile()
+    text = compiled.as_text()
+    for path, count in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 2)):
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
+            rx = re.compile(json.load(f)["args"]["pattern"])
+        kernels = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]
+        assert len(kernels) == count and all("_window" in ln.split(" = ")[0] for ln in kernels), (path, kernels)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", "train_attn_window_roofline_pct.json")) as f:
+        rx = re.compile(json.load(f)["args"]["pattern"])
+    assert len([ln for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]) == 3
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 1.0e9  # the kernels keep no scores
+    t = fa._tiling(s, s, tile, tile, d, 2, h // kv)
+    assert (t.kv_span, t.q_span) == (16384, 1024)
+    grids = _pallas_grids(jax.make_jaxpr(grad)(q, k, k, pos).jaxpr)
+    assert (b, h, s // tile, 1) in grids and (b, kv, s // tile, s // t.q_span) in grids
+    # the band, by brute force over the tiles: tile (qi, kj) holds a kept score
+    n = s // tile
+    first, last = np.arange(n) * tile, np.arange(n) * tile + tile - 1
+    band = (last[:, None] - first[None, :] >= 0) & (first[:, None] - last[None, :] < window)
+    assert band.sum() == fa.tile_counts(s, s, True, tile, tile, window=window).tiles_computed == 150
+    kv_spec = fa._q_major_specs(d, h // kv, True, t, False, window)[1]
+    for span_tiles, walk in ((t.kv_span // tile, "kv"), (t.q_span // tile, "q")):
+        spans = n // span_tiles
+        walked = np.zeros((n, n), bool)  # [qi, kj]
+        for own in range(n):  # the grid's third dimension: a q tile (forward, dQ) or a kv tile (dK/dV)
+            if walk == "kv":
+                first_used, last_used = int(fa._first_kv_block(own, tile, tile, window)) // span_tiles, fa._last_kv_block(own, tile, tile) // span_tiles
+            else:
+                first_used = fa._first_q_block(own, tile, tile) // span_tiles
+                last_used = min(fa._last_q_block(own, tile, tile, window), n - 1) // span_tiles
+            for sp in range(spans):
+                if walk == "kv":
+                    lo, hi = (int(x) for x in (fa._kv_tiles_start(window, own, sp, span_tiles, tile, tile),
+                                               fa._kv_tiles_end(True, own, sp, span_tiles, tile, tile)))
+                    walked[own, sp * span_tiles + lo:sp * span_tiles + max(lo, hi)] = True
+                else:
+                    lo = int(jnp.clip(fa._first_q_block(own, tile, tile) - sp * span_tiles, 0, span_tiles))
+                    hi = int(fa._q_tiles_end(window, own, sp, span_tiles, tile, tile))
+                    walked[sp * span_tiles + lo:sp * span_tiles + max(lo, hi), own] = True
+                # a span with nothing to walk names the nearest span used: no copy is issued for it
+                assert (hi > lo) == (first_used <= sp <= last_used), (walk, own, sp)
+                if walk == "kv":  # the forward and dQ kernels' own index map for K and V
+                    named = int(kv_spec.index_map(0, 0, own, sp)[2])
+                    assert named == int(np.clip(sp, first_used, last_used)), (own, sp, named)
+        np.testing.assert_array_equal(walked, band, err_msg=walk)
+
+
 def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
     """[1, 8192, 20/20, 256], the latent-attention cell's shape (glm47flash-train-
     ep8share-s8192): heads twice the lane width, no grouping, K and V of a head exactly
@@ -332,7 +401,12 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
     # at head width 64 on padded lanes; arguments 5.63 GB (16 B a parameter less the gradient).
     # PR 43: 5.193 -> 5.568 GB, the one attention part's `out` on its padded lanes [4, 32, 8192, 128]
     # bfloat16 (268 MB) and logsumexp (4 MB) kept, 0.27 GB, and 0.10 GB of the compiler's placing
-    ("lfm2-24b-a2b-train-ep8", 4, 2, 5.57)])
+    ("lfm2-24b-a2b-train-ep8", 4, 2, 5.57),
+    # PR 46: [2, 16384]: four attention parts inside a window of 2,048 (their kernels under their own
+    # names) and one full, gated, normed a head, a norm behind every part; four expert parts at 8 of 128
+    # over 32,768 tokens beside a shared expert; arguments 6.05 GB; the f32 logits [2, 16384, 25024] are
+    # 3.3 GB of the temporaries
+    ("trinity-mini-train-ep16", 4, 2, 8.75)])
 def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on_tpu, config, bodies,
                                                                     loops, temp_gb):
     """The whole step of each family cell as its configuration file states it, compiled for
@@ -341,13 +415,18 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     (88 MB in the Nemotron cell, 10.5 MB in GLM's) leaves the temporaries within 0.15 GB of
     what they were before it did (PR 35's programs: 3.83 and 6.01 GB), and XLA
     rematerialises nothing of its own to fit (PERF.md section 7, after PR 26 (2))."""
+    import importlib
+
     from ray_tpu.models import llama
+    from ray_tpu.models.config import LAYER_KINDS
     from ray_tpu.train import make_optimizer, make_train_step
     from ray_tpu.train.step import TrainState
 
+    attention_ops = importlib.import_module("ray_tpu.ops.attention")  # (the package re-exports the function under this name)
     cfg, file = _cell_file(config)
     trainer = file["trainer"]
     assert cfg.remat and cfg.remat_policy == "full" and trainer["mesh"] is None
+    fallbacks = attention_ops.xla_fallback_count
     tx = make_optimizer(**trainer["optimizer"])
     params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
     opt_state = _shapes(jax.eval_shape(tx.init, params), one_chip)
@@ -363,6 +442,12 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     blocks = _kernel_calls(text, "flash_attention_bwd_dq")[0]
     assert blocks >= 1 and _kernel_calls(text, "flash_attention_bwd_dkv") == (blocks, 0)
     assert _kernel_calls(text, "flash_attention_fwd") == (blocks, 0)
+    # a windowed part runs the windowed kernels, as often; no attention falls to an XLA path
+    windowed = sum(LAYER_KINDS[c].windowed for c in cfg.layer_pattern)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+        assert _kernel_calls(text, f"{kernel}_window") == (windowed, 0)
+    assert blocks + windowed == sum(LAYER_KINDS[c].mixer == "attn" for c in cfg.layer_pattern) + cfg.mtp_depth or not cfg.layer_pattern
+    assert attention_ops.xla_fallback_count == fallbacks
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < (temp_gb + 0.15) * 1e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
