@@ -31,9 +31,18 @@ current position's), kda_f_down [D, r], kda_f_up [r, H, K], kda_dt_bias [H, K], 
 [H], kda_beta [D, H], kda_g_down [D, r], kda_g_up [r, H, K], kda_o_norm [K], kda_out
 [H, K, D]. Packed documents and a KV cache are refused (llama._block): state and
 convolution would have to start again at a boundary, and no recurrent state is kept.
+
+Under remat `full` a layer keeps `[q~ | k~ | v~]` before the convolution, [B, T, 3 H K], by name
+(`IN_PROJ_NAME`, beside the layer's input: llama._maybe_remat): ONE array as the product wrote it
+and as the convolution reads it, forward and backward, so the rematerialised layer runs no q|k|v
+product. It is named BEHIND the reshape: named as [B, T, 3, H, K] the TPU compiler stored it with
+the positions minor and copied it into the kernels' layout twice a part (tests/test_tpu_compile.py).
+The norm, the low-rank pairs, beta (a tenth of the part's input work), the convolution, the scan
+(its inverses kept under every policy), the gate and W_o are made again.
 """
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import kda, short_conv
 from ray_tpu.ops.quant import as_weight as _w
@@ -43,8 +52,11 @@ from .config import ModelConfig
 from .ssm import _causal_conv
 
 # what llama.py's table of layer kinds reads of a mixer (its comment says what each is);
-# kept under every remat policy: the inverses of the scan's triangular systems
-LEAF, RECURRENT, SCOPE, KEPT = "kda_qkv", "Kimi-Delta-Attention", "attn", {"every": (kda.INVERSE_NAME,)}
+# kept under every remat policy: the inverses of the scan's triangular systems; under `full`
+# (the 'dots' policies keep every product): q | k | v as the input product wrote them
+IN_PROJ_NAME = "kda_qkv_proj"  # [B, T, 3 H K], as the convolution reads it
+LEAF, RECURRENT, SCOPE = "kda_qkv", "Kimi-Delta-Attention", "attn"
+KEPT = {"every": (kda.INVERSE_NAME,), "full": (IN_PROJ_NAME,)}
 AXES = {
     "kda_norm": ("embed",), "kda_qkv": ("embed", None, "heads", "head_dim"),
     "kda_conv": (None, None, "heads", "head_dim"),
@@ -112,17 +124,18 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     dt_, f32 = x.dtype, jnp.float32
     bsz, t, _ = x.shape
     h, width = cfg.kda_n_heads, cfg.kda_head_dim
+    channels = 3 * h * width
     with jax.named_scope("kda_in_proj"):
         u = rms_norm(x, lp["kda_norm"], cfg.norm_eps)
-        qkv = jnp.einsum("btd,dphk->btphk", u, _w(lp["kda_qkv"], dt_))
+        qkv = jnp.einsum("btd,dphk->btphk", u, _w(lp["kda_qkv"], dt_)).reshape(bsz, t, channels)
+        qkv = checkpoint_name(qkv, IN_PROJ_NAME)
         decay = jnp.einsum("btr,rhk->bthk", jnp.einsum("btd,dr->btr", u, _w(lp["kda_f_down"], dt_)),
                            _w(lp["kda_f_up"], dt_))
         gate = jnp.einsum("btr,rhk->bthk", jnp.einsum("btd,dr->btr", u, _w(lp["kda_g_down"], dt_)),
                           _w(lp["kda_g_up"], dt_))
         beta = jnp.einsum("btd,dh->bth", u, _w(lp["kda_beta"], dt_))
     with jax.named_scope("kda_conv"):
-        channels = 3 * h * width
-        qkv, conv_w = qkv.reshape(bsz, t, channels), lp["kda_conv"].reshape(-1, channels)
+        conv_w = lp["kda_conv"].reshape(-1, channels)
         if short_conv.takes_kernels(channels, 3, width, conv_w.shape[0]):
             q, k, v = short_conv.short_conv(qkv, conv_w, None, (width**-0.5, 1.0, None), width)
         else:

@@ -215,6 +215,21 @@ def _maybe_remat(body, cfg: ModelConfig):
       projections it runs anyway. Not under the 'dots' policies: both Mistral depths are
       the greatest that fit, and half a gigabyte more there turns into XLA's own `.remat`
       of MLP products (PERF.md section 7, after PR 26 (1)).
+    - a recurrent mixer's input product, ONE array as the einsum wrote it, named by the mixer
+      where it is made, so the rematerialised part runs no input product; its norm,
+      convolution, gate, scan and output are made again from that and the layer's input (a
+      pass over HBM each, and the weights' gradients read them):
+        - a gated short convolution's `[B | C | x]` (models/sconv.py): 12 KB a token a part
+          (3 x d_model x 2 B) beside the 4 KB of the layer's input, 403 MB for 0.825 TFLOP =
+          4.6 ms a part at [4, 8192] and width 2048;
+        - a Mamba-2 mixer's `[z | xBC | dt]` (models/ssm.py): 4.6 KB a token a part (2,320
+          channels x 2 B), 38 MB for 0.156 TFLOP = 0.9 ms a part at [1, 8192] and a share of
+          16 heads;
+        - a delta-rule mixer's q | k | v before the convolution (models/kda.py): 6 KB a token
+          a part (3 x 8 x 128 x 2 B), 50 MB for 0.206 TFLOP = 1.1 ms a part at [1, 8192] and a
+          share of 8 heads; the decay's and the gate's low-rank pairs and beta (a tenth of the
+          part's input work) are not kept.
+      Not under the 'dots' policies: `checkpoint_dots` keeps every product there already.
 
     Under EVERY policy:
     - what an expert layer's router made (moe.route names it: the choice, the scores, the

@@ -14,9 +14,15 @@ sequences. Leaves: sconv_norm [D], sconv_in [D, 3, D], sconv_w [taps, D] (the la
 the current position's; the published [D, 1, taps] transposed), sconv_out [D, D]. Packed
 documents and a KV cache are refused (llama._block): the convolution would have to start
 again at a boundary, and no tail of z is kept.
+
+Under remat `full` a layer keeps `[B | C | x]` [B, T, 3, D] by name (`IN_PROJ_NAME`, beside the
+layer's input: llama._maybe_remat): ONE array as the product wrote it, which b, c, x are views
+of forward and backward, so the rematerialised layer runs no input product. The norm, the
+gate, the float32 convolution and y are made again.
 """
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops.quant import as_weight as _w
 
@@ -26,7 +32,8 @@ from .ssm import _causal_conv
 
 # what llama.py's table of layer kinds reads of a mixer (its comment says what each is);
 # under `attn`, as the Kimi-Delta-Attention mixer
-LEAF, RECURRENT, SCOPE, KEPT = "sconv_in", "gated short-convolution", "attn", {}
+IN_PROJ_NAME = "sconv_bcx"  # [B | C | x] as the input product wrote it, [B, T, 3, D]
+LEAF, RECURRENT, SCOPE, KEPT = "sconv_in", "gated short-convolution", "attn", {"full": (IN_PROJ_NAME,)}
 AXES = {"sconv_norm": ("embed",), "sconv_in": ("embed", None, None), "sconv_w": (None, None),
         "sconv_out": (None, "embed")}
 
@@ -53,7 +60,8 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     with jax.named_scope("sconv"):
         with jax.named_scope("sconv_in_proj"):
             u = rms_norm(x, lp["sconv_norm"], cfg.norm_eps)
-            b, c, v = jnp.moveaxis(jnp.einsum("btd,dpe->btpe", u, _w(lp["sconv_in"], dt)), 2, 0)
+            bcx = checkpoint_name(jnp.einsum("btd,dpe->btpe", u, _w(lp["sconv_in"], dt)), IN_PROJ_NAME)
+            b, c, v = jnp.moveaxis(bcx, 2, 0)
         with jax.named_scope("sconv_gate_conv"):
             y = (c.astype(jnp.float32) * _causal_conv(b * v, lp["sconv_w"], 0.0)).astype(dt)
         with jax.named_scope("sconv_out_proj"):

@@ -20,9 +20,15 @@ conv_w [taps, d_inner + 2 G N] (the last tap is the current position's), conv_b,
 dt_bias / A_log / D [H], gate_norm [d_inner], out_proj [d_inner, D], ssm_norm [D].
 Packed documents are refused (llama._block): state and convolution would have to start
 again at a boundary.
+
+Under remat `full` a layer keeps `[z | xBC | dt]` [B, T, 2 d_inner + 2 G N + H] by name
+(`IN_PROJ_NAME`, beside the layer's input: llama._maybe_remat): ONE array as the product wrote
+it, which z, xBC, dt are slices of forward and backward, so the rematerialised layer runs no
+input product. The norm, the convolution, the scan, the gate and W_out are made again.
 """
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.ops import ssd
 from ray_tpu.ops.quant import as_weight as _w
@@ -31,7 +37,8 @@ from .attn import rms_norm
 from .config import ModelConfig
 
 # what llama.py's table of layer kinds reads of a mixer (its comment says what each is)
-LEAF, RECURRENT, SCOPE, KEPT = "in_proj", "Mamba-2", None, {}
+IN_PROJ_NAME = "ssm_zxbcdt"  # [z | xBC | dt] as the input product wrote it, [B, T, 2 d_inner + 2 G N + H]
+LEAF, RECURRENT, SCOPE, KEPT = "in_proj", "Mamba-2", None, {"full": (IN_PROJ_NAME,)}
 AXES = {
     "ssm_norm": ("embed",), "in_proj": ("embed", None), "conv_w": (None, None), "conv_b": (None,),
     "dt_bias": (None,), "A_log": (None,), "D": (None,), "gate_norm": (None,),
@@ -86,7 +93,7 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
     d_in, conv_dim = cfg.ssm_d_inner, cfg.ssm_conv_dim
     with jax.named_scope("ssm_in_proj"):
         u = rms_norm(x, lp["ssm_norm"], cfg.norm_eps)
-        joined = jnp.einsum("btd,de->bte", u, _w(lp["in_proj"], dt_))
+        joined = checkpoint_name(jnp.einsum("btd,de->bte", u, _w(lp["in_proj"], dt_)), IN_PROJ_NAME)
         z, xbc, dt = jnp.split(joined, [d_in, d_in + conv_dim], axis=-1)
     with jax.named_scope("ssm_conv"):
         xbc = jax.nn.silu(_causal_conv(xbc, lp["conv_w"], lp["conv_b"])).astype(dt_)

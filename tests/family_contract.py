@@ -18,6 +18,7 @@ import functools
 import importlib
 import json
 import os
+import re
 import subprocess
 import sys
 import tempfile
@@ -102,6 +103,10 @@ def pytest_generate_tests(metafunc):
         metafunc.parametrize("case", family.cases, ids=[case[0] for case in family.cases])
     if "part" in metafunc.fixturenames:
         metafunc.parametrize("part", list(family.shares))
+    if "kept" in metafunc.fixturenames:  # the family's recurrent mixer (its module), where it has one
+        mixers = {kind: mixer for kind, (mixer, _) in llama.MIXERS.items()
+                  if family.recurrent and mixer.RECURRENT == family.recurrent}
+        metafunc.parametrize("kept", list(mixers.values()), ids=list(mixers))
 
 
 @pytest.fixture(scope="module")
@@ -332,6 +337,120 @@ def test_the_shares_add_up_to_the_uncut_layer(family, part):
     want, parts, a_share = family.shares[part](x)
     np.testing.assert_allclose(sum(parts), want, atol=3e-5 * float(jnp.abs(want).max()))
     assert float(jnp.abs(parts[a_share]).max()) > 1e-3  # a share is a part, not nothing
+
+
+# ------------------------------------------------------------------- what a rematerialised recurrent mixer keeps
+
+def _mixer_part(cfg, mixer, policy, dtype=jnp.bfloat16):
+    """(a mixer's loss under `policy` as `llama._maybe_remat` runs the part, x, lp) at [2, 32, d_model]."""
+    cfg = dataclasses.replace(cfg, remat=policy != "none", remat_policy=policy)
+    part = llama._maybe_remat(lambda x, lp: mixer.mixer(x, lp, cfg), cfg)
+    loss = lambda x, lp: jnp.sum(jnp.square(part(x, lp).astype(jnp.float32)))  # noqa: E731
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 32, cfg.d_model), dtype)
+    return loss, x, mixer.init(jax.random.PRNGKey(1), cfg)
+
+
+def _value_and_grads(loss):
+    return jax.jit(jax.value_and_grad(loss, argnums=(0, 1)))
+
+
+def _equations(jaxpr):
+    """Every equation, nested jaxprs' too, in order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _without_the_product(monkeypatch, mixer, named=True):
+    """The mixer as it was before its input product was kept: `KEPT` without the name (and, with
+    `named` false, the product without its name)."""
+    monkeypatch.setattr(mixer, "KEPT", {policy: tuple(n for n in names if n != mixer.IN_PROJ_NAME)
+                                        for policy, names in mixer.KEPT.items()})
+    if not named:
+        monkeypatch.setattr(mixer, "checkpoint_name", lambda x, name: x)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+def test_a_mixer_that_keeps_its_input_product_gives_the_bits_of_one_that_keeps_everything(family, kept, dtype):
+    """Under `full` the backward pass reads the input product's result as the forward pass wrote it,
+    where it read an equal recomputation: the value and every gradient are those of
+    `remat_policy="none"`, bit for bit. (An operation a program, not one `jit`: the same operations
+    on the same values give the same bits, where XLA's fusions on the CPU sum a scan's float32
+    intermediates in another order in two programs that differ anywhere.)"""
+    assert kept.KEPT["full"] == (kept.IN_PROJ_NAME,)
+    full, x, lp = _mixer_part(family.tiny, kept, "full", dtype)
+    none, _, _ = _mixer_part(family.tiny, kept, "none", dtype)
+    mine, plain = (jax.value_and_grad(loss, argnums=(0, 1))(x, lp) for loss in (full, none))
+    assert len(jax.tree.leaves(mine)) == 2 + len(lp)
+    for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(plain)):
+        assert np.abs(np.asarray(a, np.float32)).max() > 0
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32))
+
+
+def test_a_rematerialised_mixer_keeps_its_inputs_and_the_named_product_and_runs_it_once(family, kept, capsys, monkeypatch):
+    """What `full` keeps of a recurrent mixer: the layer's inputs, the ONE array its input product
+    wrote, rounded once where it is made, and what the part keeps under every policy. The gradient's
+    program holds one product fewer than that of a part whose `KEPT` lacks the name: the input
+    product again in the rematerialised layer."""
+    loss, x, lp = _mixer_part(family.tiny, kept, "full")
+    program = lambda part: list(_equations(jax.make_jaxpr(_value_and_grads(part))(x, lp).jaxpr))  # noqa: E731
+    count = lambda eqns, p: sum(e.primitive.name == p for e in eqns)  # noqa: E731
+    eqns = program(loss)
+    product, = [e for e in eqns if e.primitive.name == "name" and e.params["name"] == kept.IN_PROJ_NAME]
+    shape = ",".join(map(str, product.outvars[0].aval.shape))
+    assert product.outvars[0].aval.shape[:2] == (2, 32) and product.outvars[0].aval.dtype == jnp.bfloat16
+    jax.ad_checkpoint.print_saved_residuals(loss, x, lp)
+    saved = [ln for ln in capsys.readouterr().out.strip().splitlines() if "_mixer_part" not in ln]  # (less the loss's own square)
+    inputs = [ln for ln in saved if "from the argument" in ln]
+    assert len(inputs) == 1 + len(lp) and inputs[0].startswith(f"bf16[2,32,{family.tiny.d_model}] from the argument x")
+    named = [ln for ln in saved if ln not in inputs]
+    assert len(named) == 1 + len(kept.KEPT.get("every", ())), named
+    (mine,) = [ln for ln in named if ln.startswith(f"bf16[{shape}] output of reduce_precision")]
+    assert os.path.basename(kept.__file__) in mine
+    _without_the_product(monkeypatch, kept)  # the name alone keeps nothing: the part says what a policy keeps
+    before = program(_mixer_part(family.tiny, kept, "full")[0])
+    assert count(before, "dot_general") == count(eqns, "dot_general") + 1
+    assert count(before, "reduce_precision") == count(eqns, "reduce_precision") - 1
+    assert count(before, "name") == count(eqns, "name") + 1  # (made twice, named twice)
+
+
+def _lines_up_to_order(text):
+    """A lowered program's lines with value numbers blanked, a barrier's types sorted, sorted: what two
+    programs that list the same residuals in another order share."""
+    def line(ln):
+        ln = re.sub(r"%\w+(#\d+)?", "%", ln)
+        if "optimization_barrier" in ln:
+            head, _, types = ln.partition(" : ")
+            ln = head + " : " + ", ".join(sorted(types.split(", ")))
+        return ln
+    return sorted(line(ln) for ln in text.splitlines())
+
+
+@pytest.mark.parametrize("policy", ["dots", "dots_no_batch", "none"])
+def test_the_products_name_changes_nothing_of_a_mixer_under_the_other_policies(family, kept, policy, monkeypatch):
+    """`checkpoint_dots` keeps the product already, and nothing is rematerialised under `none`: the
+    part's program, value and gradients, lowers to the text it had before the product carried a name.
+    (Under the `dots` policies jax may list a layer's residuals in another order, a named one first:
+    then the lines are the same up to the values' numbers.)"""
+    # (the number behind a private function's name is the lowering's own counter, and a name takes one)
+    text = lambda loss: re.sub(r"(@[A-Za-z_]\w*?)_\d+\b", r"\1", _value_and_grads(loss).lower(x, lp).as_text())  # noqa: E731
+    loss, x, lp = _mixer_part(family.tiny, kept, policy)
+    with_name = text(loss)
+    _without_the_product(monkeypatch, kept, named=False)
+    before = text(_mixer_part(family.tiny, kept, policy)[0])
+    assert before == with_name or (policy != "none" and _lines_up_to_order(before) == _lines_up_to_order(with_name))
+    assert "dot_general" in with_name
+
+
+def test_what_the_mixer_keeps_by_name_no_other_part_names(family, kept):
+    """`llama._maybe_remat` gathers every part's `KEPT` into one `save_only_these_names`: a name two
+    parts shared would keep under one part's policy what the other's made."""
+    parts = {**llama.MIXERS, **llama.FEED_FORWARD}
+    names = lambda part: {name for names in part.KEPT.values() for name in names}  # noqa: E731
+    assert all(set(part.KEPT) <= {"full", "dots", "dots_no_batch", "every"} for part, _ in parts.values())
+    others = set().union(*(names(part) for part, _ in parts.values() if part is not kept))
+    assert names(kept) and not names(kept) & others
 
 
 # ------------------------------------------------------------------- the step

@@ -355,6 +355,11 @@ def test_the_combine_follows_the_windows_rows_in_both_cells(one_chip, on_tpu, ce
     assert not re.search(rf"\[{8192 * k},\d+\]", text)
 
 
+def _xla_remats(text):
+    """The instructions XLA made again by itself to fit the program (`.remat` in their names)."""
+    return re.findall(r"^\s*(?:ROOT )?%[\w.\-]*\.remat\S*", text, re.M)
+
+
 def _instructions(text, op, scope=None):
     """The program's instructions of kind `op`, fused or not (under `scope`, by `op_name`)."""
     return [ln for ln in text.splitlines() if re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = .*?[\])}}] {op}\(", ln)
@@ -387,21 +392,28 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
 
 
 @pytest.mark.parametrize("config,bodies,loops,temp_gb", [
-    ("nemotron-3-super-train-tp8-ep64", 5, 2, 3.83),  # (a period of layers, unrolled: a body each)
+    # (a period of layers, unrolled: a body each). PR 48: 3.866 -> 4.058 GB, `[z | xBC | dt]` of five
+    # Mamba-2 parts kept from forward to backward, [1, 8192, 2320] bfloat16 = 38 MB a part, 0.19 GB
+    ("nemotron-3-super-train-tp8-ep64", 5, 2, 4.06),
     # (the scan over four layers has one body; the MTP module). PR 43: 6.045 -> 6.539 GB, remat `full`
     # keeps the forward flash kernel's `out` [1, 20, 8192, 256] bfloat16 (84 MB) and logsumexp (0.66 MB)
     # of six blocks, 0.51 GB, and runs the kernel 3 times a step where it ran 6
     ("glm-4.7-flash-train-ep8", 2, 0, 6.54),
     # PR 37: four expert parts at 8 of 320 (the pick a slot at a time, as at 22 of 512), three
     # delta-rule scans whose triangular systems are inverted once each and kept. PR 44: 4.650 ->
-    # 4.644 GB, the float32 `[1, 8195, 3072]` padded copies and the taps' products gone (the figure stays)
-    ("solar-open2-train-tp8-ep40", 4, 2, 4.67),
+    # 4.644 GB, the float32 `[1, 8195, 3072]` padded copies and the taps' products gone. PR 48: 4.644 ->
+    # 4.794 GB, q | k | v before the convolution of three delta-rule parts kept, [1, 8192, 3072] bfloat16 =
+    # 50 MB a part, 0.15 GB
+    ("solar-open2-train-tp8-ep40", 4, 2, 4.80),
     # PR 42: four expert parts at 4 of 64 over 32,768 tokens (the pick a slot at a time: 8.4 M mask
     # elements), no shared expert, beside four gated short convolutions, a dense part and attention
     # at head width 64 on padded lanes; arguments 5.63 GB (16 B a parameter less the gradient).
     # PR 43: 5.193 -> 5.568 GB, the one attention part's `out` on its padded lanes [4, 32, 8192, 128]
     # bfloat16 (268 MB) and logsumexp (4 MB) kept, 0.27 GB, and 0.10 GB of the compiler's placing
-    ("lfm2-24b-a2b-train-ep8", 4, 2, 5.57),
+    # PR 48 (PR 47's compile): 5.568 -> 6.673 GB, `[B | C | x]` of four conv parts kept from forward to
+    # backward, [4, 8192, 3, 2048] bfloat16 = 403 MB a part, 4 x 403 MB = 1.61 GB, of which the compiler
+    # places 0.51 GB where the backward pass's float32 intermediates lay before: 1.105 GB more
+    ("lfm2-24b-a2b-train-ep8", 4, 2, 6.68),
     # PR 46: [2, 16384]: four attention parts inside a window of 2,048 (their kernels under their own
     # names) and one full, gated, normed a head, a norm behind every part; four expert parts at 8 of 128
     # over 32,768 tokens beside a shared expert; arguments 6.05 GB; the f32 logits [2, 16384, 25024] are
@@ -436,7 +448,7 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     text = compiled.as_text()
     assert len(_instructions(text, "convolution", "moe_router")) == 3 * bodies
     assert len(_instructions(text, "while", "moe_router")) == loops * bodies
-    assert not re.search(r"^\s*(?:ROOT )?%[\w.\-]*\.remat", text, re.M)
+    assert not _xla_remats(text)
     # under `full` a rematerialised layer keeps the forward flash kernel's results: a call an
     # attention block forward, none made again, one of each backward kernel (PR 43)
     blocks = _kernel_calls(text, "flash_attention_bwd_dq")[0]
@@ -448,6 +460,14 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
         assert _kernel_calls(text, f"{kernel}_window") == (windowed, 0)
     assert blocks + windowed == sum(LAYER_KINDS[c].mixer == "attn" for c in cfg.layer_pattern) + cfg.mtp_depth or not cfg.layer_pattern
     assert attention_ops.xla_fallback_count == fallbacks
+    # a recurrent mixer keeps its input product's result under `full` (PR 48): the product once a part
+    # forward, none made again in the rematerialised layer, two backward; one stored copy a part,
+    # rounded in the product's own epilogue
+    period = llama.pattern_period(cfg.layer_pattern)[0] if cfg.layer_pattern else ""
+    for kind, (scope, einsum, extents) in _KEPT_PRODUCTS.items():
+        parts = period.count(kind)
+        assert _products(text, scope, einsum) == (parts, 0, 2 * parts), kind
+        assert _kept_copies(text, extents) == (parts, parts), kind
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < (temp_gb + 0.15) * 1e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
@@ -503,24 +523,21 @@ def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp, pos).compile().as_text()
     assert _kernel_calls(text, "flash_attention_fwd") == forward
     assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (1, 0)
-    assert not re.search(r"^\s*(?:ROOT )?%[\w.\-]*\.remat", text, re.M)
+    assert not _xla_remats(text)
 
 
 def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     """A Mamba-2 layer's share of the Nemotron-3-Super cell (16 heads of 64, 1 group, state
-    128, 8,192 positions in 64 chunks of 128), value and every gradient: plain XLA, no
-    kernel, the chunked scan's float32 intermediates beside the projections' under 2 GB."""
+    128, 8,192 positions in 64 chunks of 128), value and every gradient under the cell's remat:
+    plain XLA, no kernel, the chunked scan's float32 intermediates beside the projections' under
+    2 GB; `[z | xBC | dt]` `[1, 8192, 2320]` kept by name (`_rematerialised_mixer`, PR 48)."""
     from ray_tpu.models import ssm
 
     cfg = _nemotron_share()
     lp = _shapes(jax.eval_shape(lambda: ssm.init(jax.random.PRNGKey(0), cfg)), one_chip)
     assert lp["in_proj"].shape == (4096, 2 * 1024 + 2 * 128 + 16) and lp["out_proj"].shape == (1024, 4096)
     x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one_chip)
-
-    def loss(x, lp):
-        return jnp.sum(ssm.mixer(x, lp, cfg).astype(jnp.float32))
-
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
+    compiled = _rematerialised_mixer(ssm, "M", cfg, x, lp)
     assert "tpu_custom_call" not in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
@@ -560,22 +577,86 @@ def test_flash_attention_compiles_at_head_width_64(one_chip, on_tpu):
 def test_gated_short_convolution_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     """A gated short-convolution part of the LFM2 cell ([4, 8192] tokens, 2048 wide, 3 taps),
     value and every gradient under the cell's remat: plain XLA, no kernel, the float32
-    convolution beside the projections' outputs under 2.5 GB."""
-    from ray_tpu.models import llama, sconv
+    convolution beside the projections' outputs under 1.95 GB (1.880 by the compile, as before
+    PR 47: alone, the part's kept array lives no longer than the one made again did). Under
+    `full` the input product's result `[B | C | x]` is kept by name (`sconv.IN_PROJ_NAME`, PRs 47, 48):
+    the product runs once forward, not again in the rematerialised part (it did), twice backward;
+    the rounding jax.checkpoint gives a named residual is in the product's own epilogue; and the
+    one stored array `[4, 8192, 3, 2048]` is what forward and backward both read, through bitcasts."""
+    from ray_tpu.models import sconv
 
     cfg, _ = _cell_file("lfm2-24b-a2b-train-ep8")
     lp = _shapes(jax.eval_shape(lambda: sconv.init(jax.random.PRNGKey(0), cfg)), one_chip)
     assert lp["sconv_in"].shape == (2048, 3, 2048) and lp["sconv_w"].shape == (3, 2048)
     assert lp["sconv_out"].shape == (2048, 2048)
     x = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16, sharding=one_chip)
-    part = llama._maybe_remat(lambda x, lp: sconv.mixer(x, lp, cfg), cfg)
+    compiled = _rematerialised_mixer(sconv, "C", cfg, x, lp)
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.95e9
+
+
+def _rematerialised_mixer(mixer, kind, cfg, x, lp):
+    """A recurrent mixer under remat `full` as `llama._maybe_remat` runs a part, value and every
+    gradient, compiled: its input product once forward, not again in the rematerialised part, twice
+    backward; ONE stored copy of the kept result, rounded in the product's own fusion; no `.remat`."""
+    from ray_tpu.models import llama
+
+    assert cfg.remat and cfg.remat_policy == "full" and mixer.KEPT["full"] == (mixer.IN_PROJ_NAME,)
+    part = llama._maybe_remat(lambda x, lp: mixer.mixer(x, lp, cfg), cfg)
 
     def loss(x, lp):
-        return jnp.sum(part(x, lp).astype(jnp.float32))
+        with jax.named_scope("model"):  # as train/step.py
+            return jnp.sum(part(x, lp).astype(jnp.float32))
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
-    assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+    text = compiled.as_text()
+    scope, einsum, extents = _KEPT_PRODUCTS[kind]
+    assert _products(text, scope, einsum) == (1, 0, 2)
+    assert _kept_copies(text, extents) == (1, 1)
+    assert not _xla_remats(text)
+    return compiled
+
+
+# a recurrent mixer's pattern character -> (the scope of its input product, the product's einsum, the
+# kept result at its cell's shape in whichever order of its extents): `[B | C | x]` of the LFM2 cell,
+# `[z | xBC | dt]` of the Nemotron cell, q | k | v before the convolution of the Solar-Open2 cell
+_KEPT_PRODUCTS = {
+    "C": ("sconv_in_proj", "btd,dpe->btpe", r"bf16\[(?:4,8192,3,2048|3,4,8192,2048|4,3,8192,2048)\]"),
+    "M": ("ssm_in_proj", "btd,de->bte", r"bf16\[(?:1,)?8192,2320\]"),
+    "K": ("kda_in_proj", "btd,dphk->btphk", r"bf16\[(?:1,)?8192,(?:3072|3,8,128)\]"),
+}
+
+
+def _products(text, scope, einsum):
+    """The compiled program's products (XLA's `convolution`) of `einsum` under `scope`, by what ran
+    them: forward, the forward made again by a rematerialised layer, backward."""
+    products = [ln for ln in _instructions(text, "convolution", scope) if f"/{scope}/{einsum}/" in ln]
+    again = sum("rematted_computation" in ln for ln in products)
+    backward = sum("transpose(jvp(" in ln for ln in products) - again
+    return len(products) - again - backward, again, backward
+
+
+def _kept_copies(text, extents):
+    """(arrays with the `extents` of a mixer's kept product that the program makes and stores: results
+    of fusions, products, copies and transposes outside fused computations (bitcasts, a loop's tuple
+    plumbing and the asynchronous copies between fast memory and HBM, which change no layout, apart);
+    how many of them the named residual's `reduce-precision` is fused behind the product itself).
+    Equal, and one a part: ONE copy, rounded where the product wrote it, no pass of its own, none
+    transposed."""
+    stored, fused, in_fusion = 0, 0, False
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", ln)
+        if head:
+            in_fusion = head.group(1).startswith("fused_computation")
+        made = re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = {extents}\S* ([\w\-]+)\((%[\w.\-]+)", ln)
+        if not made:
+            continue
+        if made.group(1) == "reduce-precision":
+            assert in_fusion and made.group(2).startswith("%convolution"), ln  # not stand-alone
+            fused += 1
+        elif not in_fusion and made.group(1) in ("fusion", "convolution", "copy", "transpose"):
+            stored += 1
+    return stored, fused
 
 
 def _kernel_calls(text, name):
@@ -602,8 +683,11 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     convolution, silu and norms of q, k and v likewise two kernels and three calls (PR 44), the
     inverse the compiler's own triangular kernel once a block, no float32 array with the
     extents of the differences or the sub-chunks' factors of all chunks, the scan's float32
-    intermediates beside the projections' under 2 GB."""
-    from ray_tpu.models import kda, llama
+    intermediates beside the projections' under 2 GB; q | k | v before the convolution
+    `[1, 8192, 3072]` kept by name in the layout the convolution's kernels read
+    (`_rematerialised_mixer`, PR 48: named before the reshape it was stored positions-minor and
+    copied for the kernels, forward and backward)."""
+    from ray_tpu.models import kda
     from ray_tpu.ops.kda import _SOLVE, takes_kernels
 
     cfg, _ = _cell_file("solar-open2-train-tp8-ep40")
@@ -612,12 +696,7 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     assert lp["kda_f_down"].shape == lp["kda_g_down"].shape == (4096, 128)
     assert cfg.kda_chunk == 128 and takes_kernels(cfg.kda_chunk, cfg.kda_head_dim)
     x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one_chip)
-    part = llama._maybe_remat(lambda x, lp: kda.mixer(x, lp, cfg), cfg)
-
-    def loss(x, lp):
-        return jnp.sum(part(x, lp).astype(jnp.float32))
-
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
+    compiled = _rematerialised_mixer(kda, "K", cfg, x, lp)
     text = compiled.as_text()
     assert _kernel_calls(text, "kda_overlaps_fwd") == (1, 1) and _kernel_calls(text, "kda_overlaps_bwd") == (1, 0)
     assert _kernel_calls(text, "short_conv_fwd") == (1, 1) and _kernel_calls(text, "short_conv_bwd") == (1, 0)
