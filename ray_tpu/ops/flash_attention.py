@@ -30,10 +30,27 @@ dimension runs over spans. A grid step costs 0.35-0.6 us on a v5e whatever it co
 Under `causal` the loop's bounds come from `program_id`: a tile wholly above the
 diagonal is not visited, and a span wholly above it names, in its index maps, the
 nearest span used, so that the pipeline issues no copy for it. A `window` (key j is kept
-for query i where 0 <= i - j < window) is a second edge of the same kind, below the band:
-the forward and dQ loops start at the first kv tile the window reaches, dK/dV ends at the
-last q tile that still sees the kv tile, and a span wholly outside the band names the
-nearest one inside it. A windowed call's kernels carry `_window` behind their names.
+for query i where 0 <= i - j < window) is a second edge of the same kind, below the band,
+and a windowed call's work follows the band on both of its sides (PR 49). Its grids'
+last dimension runs over the spans a band reaches, counted from the band's own first
+(`_kv_spans`, `_q_spans`: at 16,384 positions inside a window of 2,048 the dK/dV grid is 3
+spans of 1,024 q rows a kv tile where the triangle's is 16); only where the sequence's end
+cuts a band short does a step walk nothing, and it names the last span used. Inside a
+step the walk (`Band`, `_walk_band`) computes the tiles between the band's two edges
+whole, and the backward kernels the two an edge crosses, the diagonal's and the one
+`_band_depth` tiles below it, in the `EDGE_PIECE`-row pieces that hold a kept score
+(`_edge_pieces`): of the diagonal's tile the upper half of the q rows meets the first
+half of the kv rows alone, of the far edge's the lower half meets the second half alone,
+so a q tile costs dQ and dK/dV 4.5 tiles where the band needs 4.0 and whole tiles made 5
+(`tile_counts`: 135 for 120.0 a head, 150 before and forward). A piece is a part of the
+rows that own the accumulators (q rows in dQ, kv rows in dK/dV), so a row's sums keep
+their order and the results their bits. Measured on a v5e at [2, 16384, 32 / 4, 128] bf16,
+window 2,048, a call alone (PERF.md, PR 49): forward 9.45 -> 9.42 ms, dQ 12.07 -> 11.45,
+dK/dV 16.92 -> 14.13 (the grid alone 14.85: 3,328 fewer steps, each of which ran the
+group's loop of 8 heads around an empty walk), dq, dk and dv bit-equal to the whole
+tiles'; 128-row pieces read 11.27 / 13.85 backward, 256 x 256 compute tiles 17.9 / 18.1
+/ 27.9. The forward kernel walks its edge tiles whole: in pieces it read 9.41 (`_fwd_kernel`).
+A windowed call's kernels carry `_window` behind their names.
 Every product feeds the MXU the inputs' own dtype (bf16 in training) and accumulates in
 f32; scores, exponentials, logsumexp, delta and all accumulators are f32. Per-row
 statistics and segment ids are kept 128 equal lanes wide inside a kernel and travel
@@ -49,6 +66,7 @@ attention needs.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -67,6 +85,14 @@ from .attention import FLASH_NAMES, ROTATED_NAMES
 # 2.48 / 3.00 / 3.43 and 512 x 512 with a tile a step 2.72 / 3.47 / 3.46.
 BLOCK_Q = 512
 BLOCK_KV = 512
+# Rows of the pieces a windowed call's backward kernels compute the band's two EDGE tiles
+# in (`_edge_rows`): the diagonal's tile and the one the window's far edge crosses keep
+# about half their scores each, and in 256-row pieces 3 of 4 hold one. Measured on a v5e at
+# [2, 16384, 32 / 4, 128] bf16 inside a window of 2,048, a call alone, forward / dQ / dK/dV
+# in ms (PERF.md, PR 49): whole tiles 9.45 / 12.07 / 16.92 on the triangle's dK/dV grid and
+# 9.42 / 12.07 / 14.85 on the band's; 256-row pieces 9.41 / 11.45 / 14.13; 128-row pieces
+# (5 of 8 hold a score) 9.84 / 11.27 / 13.85: the same sum, with twice the bodies to trace.
+EDGE_PIECE = 256
 NEG_INF = -1e30
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T: contract the minor dimension of both
 _NN = (((1,), (0,)), ((), ()))  # a @ b
@@ -129,32 +155,40 @@ def _tiling(sq, skv, bq, bkv, d, itemsize, n_rep=1) -> Tiling:
 
 class TileCounts(NamedTuple):
     grid_steps: int  # steps of the last two grid dimensions: those of one (batch, head)
-    tiles_computed: int  # compute tiles whose products run
+    tiles_computed: float  # compute tiles whose products run, an edge tile's pieces as their share of a tile
     tiles_needed: float  # the scores attention needs, in compute tiles
 
 
 def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim: int = 128,
-                itemsize: int = 2, n_rep: int = 1, kv_major: bool = False,
+                itemsize: int = 2, n_rep: int = 1, kernel: str = "fwd",
                 window: Optional[int] = None) -> TileCounts:
-    """What a (batch, query head) costs the forward and dQ kernels, or (`kv_major`) a
-    (batch, kv head with its `n_rep` query heads) the dK/dV kernel: from the same
-    `_tiling` the kernels' grids are built from. A causal tile is computed if any of
-    its scores is kept (kv position <= q position, and inside a `window` more than q
-    position - window): with 512 x 512 tiles and a window of 2,048 a q tile meets 5 kv
-    tiles where the band needs 4.0."""
+    """What a (batch, query head) costs the forward (`kernel` "fwd") or the dQ kernel
+    ("dq"), or a (batch, kv head with its `n_rep` query heads) the dK/dV kernel ("dkv"):
+    from the same `_tiling`, grid lengths and bands (`_kv_band`, `_q_band`) the kernels are
+    built from. A causal tile is computed if any of its scores is kept (kv position <= q
+    position, and inside a `window` more than q position - window); under a window the
+    backward kernels compute the two tiles an edge of the band crosses in the pieces that
+    hold one: with 512 x 512 tiles, 256-row pieces and a window of 2,048 a q tile meets 5
+    kv tiles forward and 4.5 backward where the band needs 4.0."""
     t = _tiling(sq, skv, bq, bkv, head_dim, itemsize, n_rep)
     nq, nk = sq // t.bq, skv // t.bkv
-    steps, heads = (nk * (sq // t.q_span), n_rep) if kv_major else (nq * (skv // t.kv_span), 1)
+    window = _band(window, sq, skv, causal)
+    steps, heads = ((nk * _q_spans(sq, skv, t, window), n_rep) if kernel == "dkv"
+                    else (nq * _kv_spans(sq, skv, t, window), 1))
     if not causal:
         return TileCounts(steps, heads * nq * nk, float(heads * nq * nk))
-    window = _band(window, sq, skv, causal)
     if window is None:
         computed = sum(min(_last_kv_block(qi, t.bq, t.bkv) + 1, nk) for qi in range(nq))
         m = min(sq, skv)
         kept = m * (m + 1) // 2 + (sq - m) * skv  # row i keeps min(i + 1, skv) scores
-    else:  # the forward loop's own bounds (dK/dV walks the same tiles from the other side)
-        computed = sum(_last_kv_block(qi, t.bq, t.bkv) - _first_kv_block(qi, t.bq, t.bkv, window) + 1
-                       for qi in range(nq))
+    else:
+        def share(pieces):
+            return sum(p.q[1] * p.kv[1] for p in pieces) / (t.bq * t.bkv)
+
+        bands = ([_q_band(kj, nq, t.bq, t.bkv, window) for kj in range(nk)] if kernel == "dkv"
+                 else [_kv_band(qi, t.bq, t.bkv, window, kernel == "dq") for qi in range(nq)])
+        computed = sum(b.last - b.first + 1 - b.cut_first * (1 - share(b.pieces_first))
+                       - b.cut_last * (1 - share(b.pieces_last)) for b in bands)
         kept = window * (window + 1) // 2 + (sq - window) * window  # row i keeps min(i + 1, window) scores
     return TileCounts(steps, heads * computed, heads * kept / (t.bq * t.bkv))
 
@@ -249,22 +283,6 @@ def _kv_tiles_end(causal: bool, qi, sj, n: int, bq: int, bkv: int):
     return jnp.clip(_last_kv_block(qi, bq, bkv) + 1 - sj * n, 0, n)
 
 
-def _kv_tiles_start(window: Optional[int], qi, sj, n: int, bq: int, bkv: int):
-    """The first of span sj's `n` kv tiles that q block qi walks: 0, or the first its
-    window reaches."""
-    if window is None:
-        return 0
-    return jnp.clip(_first_kv_block(qi, bq, bkv, window) - sj * n, 0, n)
-
-
-def _q_tiles_end(window: Optional[int], kj, sp, n: int, bq: int, bkv: int):
-    """How many of span sp's `n` q tiles kv block kj walks (dK/dV): all, or up to the last
-    whose window still reaches it."""
-    if window is None:
-        return n
-    return jnp.clip(_last_q_block(kj, bq, bkv, window) + 1 - sp * n, 0, n)
-
-
 def _walk(lo, hi, n: int, tile) -> None:
     """Run `tile(t)` for the compute tiles lo <= t < hi of a grid step's span of `n`.
     Under `causal` the bounds come from `program_id`, so a tile wholly on the masked
@@ -278,27 +296,189 @@ def _walk(lo, hi, n: int, tile) -> None:
         pl.when((lo <= 0) & (hi > 0))(lambda: tile(0))
 
 
-def _at(t, block: int):
-    """Rows of compute tile `t` in a span-long block."""
-    return slice(None) if isinstance(t, int) else pl.ds(pl.multiple_of(t * block, block), block)
+def _at(t, block: int, part: Optional[tuple] = None):
+    """Rows of compute tile `t` in a span-long block: all of them, or the `part` = (first,
+    how many) of them that a piece takes."""
+    if part is None:
+        return slice(None) if isinstance(t, int) else pl.ds(pl.multiple_of(t * block, block), block)
+    first, size = part
+    if isinstance(t, int):
+        return slice(t * block + first, t * block + first + size)
+    start = t * block + first if first else t * block
+    return pl.ds(pl.multiple_of(start, math.gcd(block, first)), size)
+
+
+class Piece(NamedTuple):
+    """The part of a compute tile that a product runs on: (first, how many) of the q
+    tile's rows against (first, how many) of the kv tile's, all static. Where a tile body
+    takes `piece=None` it computes the whole tile, and both parts are None."""
+    q: tuple
+    kv: tuple
+
+
+def _cut(part: Optional[tuple]):
+    """A tile-long block's or scratch buffer's rows that a piece takes: all, or its `part`."""
+    return slice(None) if part is None else slice(part[0], part[0] + part[1])
+
+
+def _lanes_of(ref, at: tuple, part: Optional[tuple]):
+    """The lane vector `ref[at]` ([1, P]: a tile's positions side by side), or of it the
+    `part` a piece takes: read so from the ref, whose lanes a load can start at any whole
+    vreg (a value cut there keeps an offset that Mosaic refuses to broadcast down the rows)."""
+    return ref[at] if part is None else ref[(*at, slice(None), _cut(part))]
+
+
+def _edge_rows(bq: int, bkv: int, window: Optional[int]) -> Optional[int]:
+    """Rows of the pieces a windowed call computes its two edge tiles in, or None where
+    every tile is computed whole: no window, tiles that are not square (the cut below is
+    made for a diagonal that runs corner to corner), or a tile that does not hold two pieces
+    (`EDGE_PIECE` is whole vregs of lanes; a shorter tile's halves would not be)."""
+    if window is None or bq != bkv or bq % EDGE_PIECE or bq == EDGE_PIECE:
+        return None
+    return EDGE_PIECE
+
+
+def _band_depth(window: int, block: int) -> int:
+    """How many (square) tiles below the diagonal's the tile lies that the window's far
+    edge crosses: `qi - _first_kv_block(qi)` and `_last_q_block(kj) - kj` away from the
+    sequence's two ends."""
+    return (window + block - 2) // block
+
+
+def _edge_pieces(depth: int, block: int, rows: int, window: int, kv_major: bool = False) -> tuple:
+    """The pieces of the tile `depth` tiles below the diagonal's (0: the diagonal's own)
+    that hold a kept score, 0 <= depth * block + r - c < window for query row r and key
+    row c of the tile: a piece for every `rows` query rows (`kv_major`: key rows, dK/dV's
+    side), against the pieces of the other side it reaches, which lie side by side. A
+    row's scores are all in its own piece, so its sums keep their order."""
+    pieces = []
+    for own in range(0, block, rows):
+        if kv_major:  # key rows own .. own + rows - 1: the query rows that see one of them
+            lo, hi = own - depth * block, own + rows - 1 + window - 1 - depth * block
+        else:  # query rows own .. own + rows - 1: the key rows one of them sees
+            lo, hi = depth * block + own - window + 1, depth * block + own + rows - 1
+        lo, hi = max(lo, 0) // rows * rows, (min(hi, block - 1) // rows + 1) * rows
+        if lo < hi:
+            pieces.append(Piece((lo, hi - lo), (own, rows)) if kv_major else Piece((own, rows), (lo, hi - lo)))
+    return tuple(pieces)
+
+
+class Band(NamedTuple):
+    """The compute tiles that one tile of the other side meets under a window, counted over
+    the whole sequence and walked in ascending order: `first` .. `last`, each whole; but where
+    `cut_first` (`cut_last`) is 1 and not 0, the first (last) in `pieces_first`
+    (`pieces_last`) alone."""
+    first: object
+    last: object
+    cut_first: object = 0
+    cut_last: object = 0
+    pieces_first: tuple = ()
+    pieces_last: tuple = ()
+
+
+def _flag(x):
+    return int(x) if isinstance(x, (bool, int)) else x.astype(jnp.int32)
+
+
+def _kv_band(qi, bq: int, bkv: int, window: int, pieces: bool = True) -> Band:
+    """The kv tiles q tile `qi` meets: from the one its window's far edge crosses,
+    `_band_depth` below the diagonal's, to the diagonal's; dQ computes the two in `pieces`
+    (nearer the sequence's start the first is tile 0, whole), the forward kernel whole."""
+    first, last = _first_kv_block(qi, bq, bkv, window), _last_kv_block(qi, bq, bkv)
+    rows = _edge_rows(bq, bkv, window) if pieces else None
+    if rows is None:
+        return Band(first, last)
+    depth = _band_depth(window, bq)  # 0: a window of one key, both edges in the diagonal's tile
+    return Band(first, last, _flag(qi >= depth) if depth else 0, 1,
+                _edge_pieces(depth, bq, rows, window) if depth else (), _edge_pieces(0, bq, rows, window))
+
+
+def _q_band(kj, nq: int, bq: int, bkv: int, window: int) -> Band:
+    """The q tiles kv tile `kj` meets (dK/dV): from the diagonal's, in pieces, to the last
+    whose window reaches it, in pieces (the sequence's last tile, where that comes first, whole)."""
+    first, far = _first_q_block(kj, bq, bkv), _last_q_block(kj, bq, bkv, window)
+    last = min(far, nq - 1) if isinstance(far, int) else jnp.minimum(far, nq - 1)
+    rows = _edge_rows(bq, bkv, window)
+    if rows is None:
+        return Band(first, last)
+    depth = _band_depth(window, bq)
+    return Band(first, last, 1, _flag(far <= nq - 1) if depth else 0,
+                _edge_pieces(0, bq, rows, window, True), _edge_pieces(depth, bq, rows, window, True) if depth else ())
+
+
+def _band_steps(band: Band, base, n: int):
+    """What the grid step whose span holds compute tiles base .. base + n - 1 does of a
+    band: (lo, hi, first edge, last edge): tiles lo <= t < hi of the span whole, and an
+    edge = (its tile in the span, whether this step computes it in pieces)."""
+    def edge(g, cut):
+        t = g - base
+        return t, (cut > 0) & (t >= 0) & (t < n)
+
+    return (jnp.clip(band.first + band.cut_first - base, 0, n),
+            jnp.clip(band.last + 1 - band.cut_last - base, 0, n),
+            edge(band.first, band.cut_first), edge(band.last, band.cut_last))
+
+
+def _walk_band(band: Band, base, n: int, tile) -> None:
+    """Run a grid step's share of a band (`_band_steps`): `tile(t)` for its whole tiles,
+    `tile(t, piece)` for an edge's pieces. The edges stand outside the loop: their
+    products have other shapes than a whole tile's."""
+    lo, hi, *edges = _band_steps(band, base, n)
+
+    def edge(at, pieces):
+        t, inside = at
+        if pieces:
+            @pl.when(inside)
+            def _():
+                for piece in pieces:
+                    tile(0 if n == 1 else t, piece)
+
+    edge(edges[0], band.pieces_first)
+    _walk(lo, hi, n, tile)
+    edge(edges[1], band.pieces_last)
+
+
+def _spans_reached(bands, n: int) -> int:
+    """The most spans of `n` tiles that one of `bands` (static) touches: the length of a
+    windowed grid's last dimension, which counts from a band's own first span."""
+    return max(b.last // n - b.first // n + 1 for b in bands)
+
+
+def _kv_span_of(step, qi, n: int, bq: int, bkv: int, window: Optional[int]):
+    """Which span of K/V a step of the forward and dQ grids' last dimension is: the step's
+    own number, or under a window that many past the first span the q tile's band reaches."""
+    return step if window is None else step + _first_kv_block(qi, bq, bkv, window) // n
+
+
+def _walk_kv(qi, sj, n: int, tile, causal: bool, bq: int, bkv: int, window: Optional[int],
+             pieces: bool) -> None:
+    """The forward and dQ kernels' walk over span sj's kv tiles; `pieces`: a band's two edge
+    tiles in their pieces (`tile(t, piece)`)."""
+    if window is None:
+        _walk(0, _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
+    else:
+        _walk_band(_kv_band(qi, bq, bkv, window, pieces), sj * n, n, tile)
 
 
 def _keep(shape, q_axis: int, qi, kj, bq: int, bkv: int, causal: bool, seg_col, seg_row,
-          window: Optional[int] = None):
-    """Which scores of a tile stay (None: all). `q_axis` is the axis query positions
-    run along; `seg_col` [rows, 128] and `seg_row` [1, >= cols] are the segment ids
-    of the tile's rows and columns. Every computed tile builds the causal mask, also
-    those the diagonal does not cross: a second, maskless tile body moved no kernel
+          window: Optional[int] = None, piece: Optional[Piece] = None):
+    """Which scores of a tile, or of a `piece` of it, stay (None: all). `q_axis` is the axis
+    query positions run along; `seg_col` [the tile's rows, 128] and `seg_row` [1, >= cols,
+    the piece's own: `_lanes_of`] are the segment ids of rows and columns. Every computed tile builds the causal mask,
+    also those the diagonal does not cross: a second, maskless tile body moved no kernel
     by 0.3 % on the chip (PERF.md, PR 26)."""
     keep = None
     if causal:  # kv position <= q position
         ahead = (jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
                  - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
-        keep = ahead >= kj * bkv - qi * bq
+        first = kj * bkv - qi * bq  # of the tile's corner; of the piece's
+        if piece is not None and piece.kv[0] != piece.q[0]:
+            first = first + (piece.kv[0] - piece.q[0])
+        keep = ahead >= first
         if window is not None:  # and q position - kv position < window
-            keep = keep & (ahead < kj * bkv - qi * bq + window)
+            keep = keep & (ahead < first + window)
     if seg_col is not None:
-        same = _lanes_to(seg_col[:], shape[1]) == seg_row[:, :shape[1]]
+        same = _lanes_to(seg_col[_cut(piece and piece[q_axis])], shape[1]) == seg_row[:, :shape[1]]
         keep = same if keep is None else (keep & same)
     return keep
 
@@ -326,10 +506,11 @@ def _fwd_kernel(
     window: Optional[int] = None,
 ):
     qi = pl.program_id(2)
-    sj = pl.program_id(3)  # which span of K/V
+    step = pl.program_id(3)
     n = k_ref.shape[0] // bkv
+    sj = _kv_span_of(step, qi, n, bq, bkv, window)  # which span of K/V
 
-    @pl.when(sj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -354,15 +535,35 @@ def _fwd_kernel(
         m_scr[:] = m_new
         acc_scr[:] = acc_scr[:] * _lanes_to(alpha, v.shape[1]) + _dot(p.astype(v.dtype), v, _NN)
 
-    _walk(_kv_tiles_start(window, qi, sj, n, bq, bkv), _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
+    # A band's edge tiles whole, where the backward kernels compute them in pieces: this
+    # tile is bound by what is done once a ROW (the two lane reductions, above), which a
+    # piece of half the rows halves and a tile's two pieces make whole again: 9.41 ms a
+    # call in pieces, 9.42 whole, for four more bodies to trace (PERF.md, PR 49).
+    _walk_kv(qi, sj, n, tile, causal, bq, bkv, window, pieces=False)
 
-    @pl.when(sj == pl.num_programs(3) - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _finalize():
         l = l_scr[:]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[:] = (acc_scr[:] / _lanes_to(l_safe, acc_scr.shape[1])).astype(o_ref.dtype)
         lse_scr[:bq] = m_scr[:] + jnp.log(l_safe)
         lse_ref[:] = lse_scr[:].T[:1]  # rows become lanes; those past bq are never read
+
+
+def _kv_spans(sq: int, skv: int, t: Tiling, window: Optional[int]) -> int:
+    """The length of the forward and dQ grids' last dimension: the sequence's spans of K/V,
+    or under a window the most that a q tile's band reaches."""
+    if window is None:
+        return skv // t.kv_span
+    return _spans_reached([_kv_band(qi, t.bq, t.bkv, window) for qi in range(sq // t.bq)], t.kv_span // t.bkv)
+
+
+def _q_spans(sq: int, skv: int, t: Tiling, window: Optional[int]) -> int:
+    """The same of the dK/dV grid: the sequence's spans of q rows, or those a kv tile's band reaches."""
+    if window is None:
+        return sq // t.q_span
+    return _spans_reached([_q_band(kj, sq // t.bq, t.bq, t.bkv, window) for kj in range(skv // t.bkv)],
+                          t.q_span // t.bq)
 
 
 def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None):
@@ -372,9 +573,9 @@ def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None):
     bq, bkv = t.bq, t.bkv
     n = t.kv_span // bkv
 
-    def kv_span(qi, sj):  # a span above the diagonal names the last span used, one below the band the first
+    def kv_span(qi, sj):  # a span above the diagonal names the last span used; a band's are counted from its first
         if window is not None:
-            return jnp.clip(sj, _first_kv_block(qi, bq, bkv, window) // n, _last_kv_block(qi, bq, bkv) // n)
+            sj = _kv_span_of(sj, qi, n, bq, bkv, window)
         return jnp.minimum(sj, _last_kv_block(qi, bq, bkv) // n) if causal else sj
 
     q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi, sj: (bi, hi, qi, 0))
@@ -430,7 +631,7 @@ def _fwd(
     out, lse = _pallas_call(
         kernel,
         name=_named("flash_attention_fwd", window),
-        grid=(b, h, sq // bq, skv // t.kv_span),
+        grid=(b, h, sq // bq, _kv_spans(sq, skv, t, window)),
         in_specs=[q_spec, kv_spec, kv_spec] + seg_specs,
         out_specs=[q_spec, stat_spec],
         out_shape=[
@@ -459,31 +660,34 @@ def _bwd_dq_kernel(
     the tile [bq, bkv] wants them down its rows, so the q block's first step turns
     them once into [bq, 128] with equal lanes."""
     qi = pl.program_id(2)
-    sj = pl.program_id(3)
+    step = pl.program_id(3)
     n = k_ref.shape[0] // bkv
+    sj = _kv_span_of(step, qi, n, bq, bkv, window)
 
-    @pl.when(sj == 0)
+    @pl.when(step == 0)
     def _():
         dq_scr[:] = jnp.zeros_like(dq_scr)
         for row_ref, col_scr in ((lse_ref, lse_scr), (delta_ref, delta_scr)):
             col_scr[:] = jnp.broadcast_to(row_ref[:], (128, row_ref.shape[1])).T[:bq]
 
-    def tile(t):
+    def tile(t, piece=None):
+        q_part, kv_part = piece or (None, None)
+        rows = _cut(q_part)
         kj = sj * n + t
-        k = k_ref[_at(t, bkv)]
-        s = _dot(q_ref[:], k, _NT) * scale  # [bq, bkv]
+        k = k_ref[_at(t, bkv, kv_part)]
+        s = _dot(q_ref[rows], k, _NT) * scale  # [bq, bkv]
         keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
-                     None if seg_kv_ref is None else seg_kv_ref[t], window)
+                     None if seg_kv_ref is None else _lanes_of(seg_kv_ref, (t,), kv_part), window, piece)
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
-        p = jnp.exp(s - _lanes_to(lse_scr[:], s.shape[1]))
-        dp = _dot(do_ref[:], v_ref[_at(t, bkv)], _NT)
-        ds = p * (dp - _lanes_to(delta_scr[:], s.shape[1])) * scale
-        dq_scr[:] += _dot(ds.astype(k.dtype), k, _NN)
+        p = jnp.exp(s - _lanes_to(lse_scr[rows], s.shape[1]))
+        dp = _dot(do_ref[rows], v_ref[_at(t, bkv, kv_part)], _NT)
+        ds = p * (dp - _lanes_to(delta_scr[rows], s.shape[1])) * scale
+        dq_scr[rows] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _walk(_kv_tiles_start(window, qi, sj, n, bq, bkv), _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
+    _walk_kv(qi, sj, n, tile, causal, bq, bkv, window, pieces=True)
 
-    @pl.when(sj == pl.num_programs(3) - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _():
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -491,45 +695,54 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_kv_ref, seg_q_ref,
     dk_ref, dv_ref, dk_scr, dv_scr,
-    *, scale, causal, bq, bkv, window=None,
+    *, scale, causal, bq, bkv, window=None, nq=None,
 ):
     """One kv block against a span of q rows of the query heads of its group, on the
     transposed tile [bkv, bq]: q_ref and do_ref are [n_rep, span, D], lse_ref and
-    delta_ref [n_rep, span // bq, 1, P], seg_q_ref [span // bq, 1, P]."""
+    delta_ref [n_rep, span // bq, 1, P], seg_q_ref [span // bq, 1, P]. `nq`: the q tiles
+    of the sequence, which a windowed call's band ends at."""
     kj = pl.program_id(2)
-    sp = pl.program_id(3)  # which span of q rows
+    step = pl.program_id(3)
     n_rep, n = q_ref.shape[0], q_ref.shape[1] // bq
+    # which span of q rows: under a window counted from the first the kv block's band reaches
+    sp = step if window is None else step + _first_q_block(kj, bq, bkv) // n
 
-    @pl.when(sp == 0)
+    @pl.when(step == 0)
     def _():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    # the first of the span's q tiles that attends to this kv block
-    lo = jnp.clip(_first_q_block(kj, bq, bkv) - sp * n, 0, n) if causal else 0
-    hi = _q_tiles_end(window, kj, sp, n, bq, bkv)
+    if window is None:  # the first of the span's q tiles that attends to this kv block
+        lo = jnp.clip(_first_q_block(kj, bq, bkv) - sp * n, 0, n) if causal else 0
+    else:
+        band = _q_band(kj, nq, bq, bkv, window)
 
     def head(r):
-        def tile(t):
+        def tile(t, piece=None):
+            cols, kv_part = piece or (None, None)
+            rows = _cut(kv_part)
             qi = sp * n + t
-            q = q_ref[r, _at(t, bq)]
-            do = do_ref[r, _at(t, bq)]
-            st = _dot(k_ref[:], q, _NT) * scale  # [bkv, bq]
+            q = q_ref[r, _at(t, bq, cols)]
+            do = do_ref[r, _at(t, bq, cols)]
+            st = _dot(k_ref[rows], q, _NT) * scale  # [bkv, bq]
             keep = _keep(st.shape, 1, qi, kj, bq, bkv, causal, seg_kv_ref,
-                         None if seg_q_ref is None else seg_q_ref[t], window)
+                         None if seg_q_ref is None else _lanes_of(seg_q_ref, (t,), cols), window, piece)
             if keep is not None:
                 st = jnp.where(keep, st, NEG_INF)
-            pt = jnp.exp(st - lse_ref[r, t][:, :bq])
-            dv_scr[:] += _dot(pt.astype(do.dtype), do, _NN)
-            dpt = _dot(v_ref[:], do, _NT)
-            dst = pt * (dpt - delta_ref[r, t][:, :bq]) * scale
-            dk_scr[:] += _dot(dst.astype(q.dtype), q, _NN)
+            pt = jnp.exp(st - _lanes_of(lse_ref, (r, t), cols)[:, :st.shape[1]])
+            dv_scr[rows] += _dot(pt.astype(do.dtype), do, _NN)
+            dpt = _dot(v_ref[rows], do, _NT)
+            dst = pt * (dpt - _lanes_of(delta_ref, (r, t), cols)[:, :st.shape[1]]) * scale
+            dk_scr[rows] += _dot(dst.astype(q.dtype), q, _NN)
 
-        _walk(lo, hi, n, tile)
+        if window is None:
+            _walk(lo, n, n, tile)
+        else:
+            _walk_band(band, sp * n, n, tile)
 
     _walk(0, n_rep, n_rep, head)
 
-    @pl.when(sp == pl.num_programs(3) - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
@@ -559,7 +772,7 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
     dq = _pallas_call(
         dq_kernel,
         name=_named("flash_attention_bwd_dq", window),
-        grid=(b, h, sq // bq, skv // t.kv_span),
+        grid=(b, h, sq // bq, _kv_spans(sq, skv, t, window)),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec] + seg_specs,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
@@ -572,14 +785,13 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
 
     # --- dK/dV pass: grid (b, kv head, kv blocks, q spans), the last and the group's query
     # heads summed in the kernel. A q span no row of which sees the kv block names the
-    # first that does (or, past a window, the last).
+    # first that does; under a window the spans are counted from that one, and one past the
+    # band's last (only the sequence's end cuts a band short of the grid) names the last.
     n = t.q_span // bq
-    last_span = sq // t.q_span - 1
 
     def q_span(kj, sp):
         if window is not None:
-            return jnp.clip(sp, _first_q_block(kj, bq, bkv) // n,
-                            jnp.minimum(_last_q_block(kj, bq, bkv, window) // n, last_span))
+            return jnp.minimum(sp + _first_q_block(kj, bq, bkv) // n, _q_band(kj, sq // bq, bq, bkv, window).last // n)
         return jnp.maximum(sp, _first_q_block(kj, bq, bkv) // n) if causal else sp
 
     q_spec2 = pl.BlockSpec((1, n_rep, t.q_span, d),
@@ -601,12 +813,12 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
         # q, dO and the statistics keep the group's query heads as their leading dimension
         ins, segs, (dk_ref, dv_ref, *scratch) = _unpack(refs, (3, 2, 2, 3, 4, 4), has_seg)
         _bwd_dkv_kernel(*ins, *segs, dk_ref.at[0, 0], dv_ref.at[0, 0], *scratch,
-                        scale=scale, causal=causal, bq=bq, bkv=bkv, window=window)
+                        scale=scale, causal=causal, bq=bq, bkv=bkv, window=window, nq=sq // bq)
 
     dk, dv = _pallas_call(
         dkv_kernel,
         name=_named("flash_attention_bwd_dkv", window),
-        grid=(b, hkv, skv // bkv, sq // t.q_span),
+        grid=(b, hkv, skv // bkv, _q_spans(sq, skv, t, window)),
         in_specs=in_specs2,
         out_specs=[kv_spec2, kv_spec2],
         out_shape=[

@@ -113,11 +113,13 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
 def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_band_alone(one_chip, on_tpu):
     """[2, 16384, 32 / 4, 128] inside a window of 2,048 with the rotation deferred into the
     rotate kernel (trinitymini-train-ep16share-s16384's four `W` parts): forward, dQ and
-    dK/dV compile under their own names, which the accepted kernel metrics still find; the
-    grids are the triangle's (K and V one 16,384-row span, a group's Q/dO spans of 1,024);
-    and the loops' bounds and the index maps, the very functions the kernels call, over
-    every grid step: the tiles walked are the band's, a step wholly outside it walks none
-    and names the nearest span used."""
+    dK/dV compile under their own names, which the accepted kernel metrics still find; K and
+    V are one 16,384-row span, a group's Q/dO spans of 1,024 of which the dK/dV grid holds
+    the 3 a kv tile's band reaches (16 before PR 49); and the kernels' own bands, steps and
+    index maps over every grid step: what is computed is the band's tiles, by the backward
+    kernels the two an edge crosses in their 256-row pieces (135 tiles' worth a head for
+    120.0 needed; forward 150, as all three before), and no step of the dK/dV grid walks
+    nothing but the steps past the sequence's end."""
     import numpy as np
 
     from ray_tpu.ops import flash_attention as fa
@@ -145,38 +147,83 @@ def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_ban
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 1.0e9  # the kernels keep no scores
     t = fa._tiling(s, s, tile, tile, d, 2, h // kv)
     assert (t.kv_span, t.q_span) == (16384, 1024)
-    grids = _pallas_grids(jax.make_jaxpr(grad)(q, k, k, pos).jaxpr)
-    assert (b, h, s // tile, 1) in grids and (b, kv, s // tile, s // t.q_span) in grids
-    # the band, by brute force over the tiles: tile (qi, kj) holds a kept score
     n = s // tile
+    q_spans = fa._q_spans(s, s, t, window)
+    assert (fa._kv_spans(s, s, t, window), q_spans, s // t.q_span) == (1, 3, 16)
+    grids = _pallas_grids(jax.make_jaxpr(grad)(q, k, k, pos).jaxpr)
+    assert (b, h, n, 1) in grids and (b, kv, n, q_spans) in grids and (b, kv, n, s // t.q_span) not in grids
+    # the band, by brute force over the tiles: tile (qi, kj) holds a kept score; the share of each computed
     first, last = np.arange(n) * tile, np.arange(n) * tile + tile - 1
     band = (last[:, None] - first[None, :] >= 0) & (first[:, None] - last[None, :] < window)
-    assert band.sum() == fa.tile_counts(s, s, True, tile, tile, window=window).tiles_computed == 150
+    assert band.sum() == 150 == fa.tile_counts(s, s, True, tile, tile, window=window).tiles_computed
+    assert fa.tile_counts(s, s, True, tile, tile, window=window, kernel="dq").tiles_computed == 135
     kv_spec = fa._q_major_specs(d, h // kv, True, t, False, window)[1]
-    for span_tiles, walk in ((t.kv_span // tile, "kv"), (t.q_span // tile, "q")):
-        spans = n // span_tiles
-        walked = np.zeros((n, n), bool)  # [qi, kj]
+    for kernel, span_tiles, steps in (("fwd", t.kv_span // tile, 1), ("dq", t.kv_span // tile, 1), ("dkv", t.q_span // tile, q_spans)):
+        walk = "q" if kernel == "dkv" else "kv"
+        share = np.zeros((n, n))  # [qi, kj]
+        idle = []
         for own in range(n):  # the grid's third dimension: a q tile (forward, dQ) or a kv tile (dK/dV)
-            if walk == "kv":
-                first_used, last_used = int(fa._first_kv_block(own, tile, tile, window)) // span_tiles, fa._last_kv_block(own, tile, tile) // span_tiles
-            else:
-                first_used = fa._first_q_block(own, tile, tile) // span_tiles
-                last_used = min(fa._last_q_block(own, tile, tile, window), n - 1) // span_tiles
-            for sp in range(spans):
-                if walk == "kv":
-                    lo, hi = (int(x) for x in (fa._kv_tiles_start(window, own, sp, span_tiles, tile, tile),
-                                               fa._kv_tiles_end(True, own, sp, span_tiles, tile, tile)))
-                    walked[own, sp * span_tiles + lo:sp * span_tiles + max(lo, hi)] = True
-                else:
-                    lo = int(jnp.clip(fa._first_q_block(own, tile, tile) - sp * span_tiles, 0, span_tiles))
-                    hi = int(fa._q_tiles_end(window, own, sp, span_tiles, tile, tile))
-                    walked[sp * span_tiles + lo:sp * span_tiles + max(lo, hi), own] = True
-                # a span with nothing to walk names the nearest span used: no copy is issued for it
-                assert (hi > lo) == (first_used <= sp <= last_used), (walk, own, sp)
+            mine = fa._q_band(own, n, tile, tile, window) if walk == "q" else fa._kv_band(own, tile, tile, window, kernel == "dq")
+            first_used, last_used = int(mine.first) // span_tiles, int(mine.last) // span_tiles
+            for step in range(steps):
+                sp = first_used + step  # a windowed grid counts its spans from the band's first
+                lo, hi, (t1, in1), (t2, in2) = fa._band_steps(mine, sp * span_tiles, span_tiles)
+                done = [(sp * span_tiles + x, 1.0) for x in range(int(lo), int(hi))]
+                for at, inside, pieces in ((t1, in1, mine.pieces_first), (t2, in2, mine.pieces_last)):
+                    if bool(inside):
+                        done.append((sp * span_tiles + int(at), sum(p.q[1] * p.kv[1] for p in pieces) / tile**2))
+                for other, part in done:
+                    share[(own, other) if walk == "kv" else (other, own)] += part
+                # only a span past the band's last walks nothing, and it names the last one used: no copy is issued
+                assert bool(done) == (sp <= last_used), (walk, own, sp)
+                if not done:
+                    idle.append((own, step))
                 if walk == "kv":  # the forward and dQ kernels' own index map for K and V
-                    named = int(kv_spec.index_map(0, 0, own, sp)[2])
-                    assert named == int(np.clip(sp, first_used, last_used)), (own, sp, named)
-        np.testing.assert_array_equal(walked, band, err_msg=walk)
+                    assert int(kv_spec.index_map(0, 0, own, step)[2]) == min(sp, last_used), (own, step)
+        # whole tiles, but backward on the two edges, where 3 of a tile's 4 pieces hold a kept score
+        assert set(np.unique(share[band])) == ({1.0} if kernel == "fwd" else {0.75, 1.0}) and not share[~band].any(), kernel
+        assert share.sum() == (150 if kernel == "fwd" else 135), kernel
+        # dK/dV: the band of a kv tile in the sequence's last 2,048 positions ends with the sequence
+        assert idle == ([] if walk == "kv" else [(kj, st) for kj in range(n) for st in range(3) if kj // 2 + st > 15])
+        assert len(idle) in (0, 6)  # of 96 steps a (batch, kv head); 416 of 512 before
+
+
+def test_an_unwindowed_call_lowers_to_the_kernels_it_lowered_to(one_chip, on_tpu):
+    """[2, 16384, 32 / 4, 128] without a window (Trinity-Mini's full part; the six other
+    cells pass none either): the Mosaic bodies of forward, dQ and dK/dV, decoded and printed
+    without source locations, are the text they were at the parent of PR 49 (sha256 of it),
+    with and without segment ids. A change that means to move the un-windowed kernels
+    replaces the digests, and says so."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    b, s, h, kv, d = 2, 16384, 32, 4, 128
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+    was = {
+        False: ["80956e8bb4c0098a", "6addad1f9a3a3bd3", "82b7f2e4eb1f2f2a"],
+        True: ["33f3a81fd5eb83bb", "385dfe5649f5a98c", "3b5d8d4e375bb10e"],
+    }
+    for packed, digests in was.items():
+        def loss(q, k, v, seg):
+            return jnp.sum(flash_attention(q, k, v, causal=True, segment_ids=seg if packed else None).astype(jnp.float32))
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, seg).as_text()
+        bodies = re.findall(r"body\\22: \\22([A-Za-z0-9+/=]+)\\22", text)
+        got = []
+        for body in bodies:
+            ctx = mlir.make_ir_context()
+            ctx.allow_unregistered_dialects = True
+            with ctx:
+                asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+            got.append(hashlib.sha256(asm.encode()).hexdigest()[:16])
+        assert got == digests, (packed, got)
 
 
 def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
