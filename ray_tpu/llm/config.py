@@ -197,6 +197,9 @@ def _served(cfg):
                           "window masks none (llm/model_runner.py, llm/paged.py)"),
         (cfg.part_post_norm, "a norm behind each part in the decode window (llm/model_runner.py adds "
                              "attention's output itself)"),
+        (cfg.diffusion_block, "generation by diffusion over blocks: the engine's step yields one token a sequence, "
+                              "where a block of diffusion_block tokens takes several denoising passes and the "
+                              "cache commits a block (llm/engine.py, llm/model_runner.py)"),
         ("C" in cfg.layer_pattern, "a gated short convolution's tail of conv_taps - 1 positions a slot "
                                    "and layer (models/sconv.py keeps none)"),
     ) if has]

@@ -165,8 +165,17 @@ def attn_out(x: jax.Array, attn: jax.Array, lp: dict) -> jax.Array:
 def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed=False):
     """The block's attention: (attention's output, which llama._block adds to x; updated
     (k, v) if caching). `windowed` (a `W` part of the pattern): inside cfg.attn_window and
-    rotated whatever cfg.attention_rotation says, which is the `*` parts'."""
+    rotated whatever cfg.attention_rotation says, which is the `*` parts'. Under
+    cfg.diffusion_block x is the doubled row [noised ; clean] and the mask is the
+    block-diffusion objective's (ops/attention.py:block_diffusion_keep)."""
     window = cfg.attn_window if windowed else None
+    doubled = cfg.diffusion_block or None  # x is the block-diffusion objective's row [noised ; clean]
+    if doubled and (cache_kv is not None or segment_ids is not None or window or cfg.latent_attention
+                    or cfg.attention_impl in ("ring", "ulysses")):
+        raise NotImplementedError(
+            "block-diffusion attention (cfg.diffusion_block) under a KV cache (generation a block at a time is "
+            "not built: llm/engine.py yields one token a step), over packed documents (segment_ids), inside "
+            "a window, on latent attention or on the ring / Ulysses paths")
     if window and (cache_kv is not None or cfg.attention_impl in ("ring", "ulysses")):
         raise NotImplementedError(
             "an attention window under a KV cache (no window of the cache is kept or masked) or on the "
@@ -215,6 +224,11 @@ def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed=Fals
                 attn = ra.ring_attention_sharded(
                     q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl
                 )
+        elif doubled:  # the core alone under a scope of its own: the rotation and the kernels
+            with jax.named_scope("attn_bd"):
+                attn = attention(q, k, v, causal=False, block_diffusion=doubled, impl=cfg.attention_impl,
+                                 shard_spec=auto_spec("batch", None, "act_heads", None),
+                                 rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
         else:
             attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
                              shard_spec=auto_spec("batch", None, "act_heads", None), window=window,

@@ -130,6 +130,46 @@ def _lfm2_moe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+# What sdar_moe's config.json does not state and the released model fixes: the chat model's
+# default block length, and the id of `<|MASK|>` in its tokenizer (the first id behind
+# Qwen3's own special tokens); keys of these names override them
+_SDAR_BLOCK_LENGTH = 4
+_SDAR_MASK_TOKEN = 151669
+
+
+def _sdar_moe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `sdar_moe` keys (SDAR-30B-A3B) as ModelConfig fields: qwen3_moe's block (rotated
+    GQA at a published head width whose q and k are normed a head, then SOFTMAX-routed
+    SwiGLU experts: scores over all experts, the k largest, gates normalised over them; no
+    shared expert, no selection bias) in every layer, and the block-diffusion objective
+    (`diffusion_block`, `diffusion_mask_token`: config.json states neither; `block_length`
+    and `mask_token_id` are read where a file has them). No auxiliary router loss
+    (config.json states no coefficient). Every expert is held; a share is an override
+    (`experts_held`). What the program does not run is refused by name; what config.json
+    does not state is benchmarks/configs/sdar-30b-a3b-train-ep8.json's `assumed`. Weights'
+    names are not mapped."""
+    refused = [what for has, what in (
+        (hf.get("attention_bias", False), "biases on the attention projections (attention_bias true)"),
+        (hf.get("decoder_sparse_step", 1) != 1 or hf.get("mlp_only_layers"),
+         "dense layers among the expert layers (decoder_sparse_step, mlp_only_layers)"),
+        (not hf.get("norm_topk_prob", True), "gates that are not normalised (norm_topk_prob false)"),
+        (hf.get("use_sliding_window", False), "window attention (use_sliding_window)"),
+        (hf.get("rope_scaling") is not None, f"rope_scaling {hf.get('rope_scaling')!r}"),
+        (hf.get("hidden_act", "silu") != "silu", f"hidden_act {hf.get('hidden_act')!r}"),
+        (not hf.get("num_experts", 0), "layers without routed experts (the dense family is sdar)"),
+    ) if has]
+    if refused:
+        raise ValueError("sdar_moe as this config.json states it is not supported: " + "; ".join(refused))
+    return dict(
+        attn_head_dim=hf.get("head_dim", 0), attn_qk_norm=True,
+        n_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+        d_ff_expert=hf["moe_intermediate_size"], n_shared_experts=0,
+        moe_capacity_factor=0.0, moe_aux_loss_coef=0.0, moe_scoring="softmax", moe_select_bias=False,
+        diffusion_block=hf.get("block_length", _SDAR_BLOCK_LENGTH),
+        diffusion_mask_token=hf.get("mask_token_id", min(_SDAR_MASK_TOKEN, hf["vocab_size"] - 1)),
+    )
+
+
 def _afmoe_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     """The `afmoe` keys (Trinity-Mini) as ModelConfig fields. A published layer is attention
     then a feed-forward part, each between a norm on its input and one on its output
@@ -305,7 +345,7 @@ def _glm4_moe_lite_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
 # a family's keys as ModelConfig fields, by its `model_type` (config_from_hf)
 _FAMILY_FIELDS = {"glm4_moe_lite": _glm4_moe_lite_fields, "nemotron_h": _nemotron_h_fields,
                   "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields,
-                  "afmoe": _afmoe_fields}
+                  "afmoe": _afmoe_fields, "sdar_moe": _sdar_moe_fields}
 
 
 def config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:

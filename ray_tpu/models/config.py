@@ -172,6 +172,15 @@ class ModelConfig:
     # feed-forward parts alike (afmoe's sandwich norms): x + RMSNorm(part(RMSNorm(x)))
     part_post_norm: bool = False
     embed_scale: float = 0.0  # the embedding's output times this (0 = none; afmoe's mup_enabled: sqrt(d_model))
+    # The training objective: 0 is next-token prediction. > 0 is block diffusion (sdar_moe; BD3-LMs,
+    # arXiv:2503.09573) in blocks of this many positions (a power of two): llama.loss_fn noises a
+    # sequence to `diffusion_mask_token` where the batch's `masked` says, runs [noised ; clean] as
+    # one row of twice the length with the positions repeated, under the block-diffusion mask
+    # (ops/attention.py:block_diffusion_keep), and reads the noised half's logits at a token's
+    # OWN position, weighted by 1 / p_mask. A forward pass of such a configuration takes the
+    # doubled row.
+    diffusion_block: int = 0
+    diffusion_mask_token: int = 0
 
     def __post_init__(self):
         # JSON hands a list; the dataclass is a static (hashed) argument of jitted programs
@@ -194,6 +203,13 @@ class ModelConfig:
             raise NotImplementedError(
                 f"mtp_layer_pattern {self.mtp_layer_pattern!r}: an MTP module is attention then "
                 "an expert layer ('*E')")
+        if self.diffusion_block and (self.diffusion_block & (self.diffusion_block - 1) or self.mtp_depth
+                                     or self.layer_pattern or self.pipeline_stages > 1
+                                     or not 0 <= self.diffusion_mask_token < self.vocab_size):
+            raise NotImplementedError(
+                f"the block-diffusion objective (diffusion_block {self.diffusion_block}) with blocks that are no "
+                "power of two, MTP modules, a pattern of single-part layers, pipeline stages, or a mask token "
+                f"({self.diffusion_mask_token}) outside the vocabulary")
         if self.mlp_activation not in ("silu_gated", "relu2"):
             raise ValueError(f"unknown mlp_activation {self.mlp_activation!r} (silu_gated | relu2)")
 
@@ -555,6 +571,36 @@ register_config(
         moe_scoring="sigmoid",
         moe_route_scale=2.826,
         moe_select_bias=True,
+    )
+)
+register_config(
+    # Toy of the sdar_moe family (SDAR-30B-A3B) for the CPU tests: the block every layer is
+    # (rotated GQA whose q and k are normed a head, then SOFTMAX-routed SwiGLU experts with
+    # no shared expert, no selection bias and no dense layer), an untied head, and the
+    # block-diffusion objective in blocks of 4 with the vocabulary's last row as the mask
+    # token. Everything held; tests cut shares of the experts.
+    ModelConfig(
+        name="sdar-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=96,
+        max_seq_len=128,
+        rope_theta=1e6,
+        norm_eps=1e-6,
+        dtype="float32",
+        attn_head_dim=24,
+        attn_qk_norm=True,
+        n_experts=16,
+        moe_top_k=4,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=40,
+        moe_scoring="softmax",
+        diffusion_block=4,
+        diffusion_mask_token=255,
     )
 )
 register_config(

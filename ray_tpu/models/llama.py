@@ -556,6 +556,7 @@ def forward(
     cache: Optional[KVCache] = None,
     return_aux: bool = False,
     token_mask: Optional[jax.Array] = None,  # [B, S] 1=real; MoE capacity masking
+    head_rows: Optional[int] = None,  # the head reads a sequence's first `head_rows` positions alone
 ):
     """tokens [B, S] -> (logits [B, S, vocab] f32, updated cache or None).
 
@@ -563,8 +564,16 @@ def forward(
     dense configs) as a third element; for the dropless expert layer, which has no such
     loss, what each expert layer counted and chose and what the MTP modules go on from
     ({"load": [layers, E], "chosen": [layers, B * S, k], "hidden": the last block's
-    output before the final norm [B, S, D]})."""
+    output before the final norm [B, S, D]}).
+
+    Under cfg.diffusion_block `tokens` is the block-diffusion objective's doubled row
+    [noised ; clean] with `positions` repeated (`block_diffusion_loss` builds both), and
+    `head_rows` cuts the noised half out before the final norm and the head."""
     b, s = tokens.shape
+    if cfg.diffusion_block and s % (2 * cfg.diffusion_block):  # (a cache, packed documents: the mixer refuses them)
+        raise NotImplementedError(
+            f"the block-diffusion objective (cfg.diffusion_block) takes one doubled row [noised ; clean] of whole "
+            f"blocks of {cfg.diffusion_block}, not {s} positions")
     if positions is None:  # one row, which every row of the batch shares
         start = cache.length if cache is not None else 0
         positions = jnp.arange(s)[None, :] + start
@@ -603,6 +612,9 @@ def forward(
         if cache is not None:
             new_cache = KVCache(k=new_kv[0], v=new_kv[1], length=cache.length + s)
 
+    if head_rows is not None:
+        with jax.named_scope("lm_head"), jax.named_scope("bd_rows"):
+            x = x[:, :head_rows]
     with jax.named_scope("lm_head"):
         logits = wsc(output_head(params, x, cfg), "batch", "seq", "act_vocab")
     if return_aux:
@@ -647,7 +659,7 @@ def balance_router_bias(old: Params, new: Params, load: jax.Array, cfg: ModelCon
     n = load.shape[0] - cfg.mtp_depth
     out = dict(new)
     for name, rows in (("layers", load[:n]), ("mtp", load[n:])):
-        if name in new:
+        if "router_bias" in new.get(name, {}):
             out[name] = dict(new[name], router_bias=moe.balance_bias(
                 old[name]["router_bias"], rows, cfg.moe_bias_update_rate))
     return out
@@ -661,6 +673,60 @@ def _cross_entropy(logits: jax.Array, targets: jax.Array, mask: jax.Array):
     return -((tgt - lse) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
+def _expert_counters(load: jax.Array, chosen: jax.Array, cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """The dropless layers' counters of a step, a row an expert layer (the MTP modules' last):
+    what the balance rule reads, what fell on the experts held here, the windows of the
+    layer's buffer that load took (1: the step fitted the rows the held experts can expect;
+    more: it overflowed them and was served all the same), and the experts each token chose
+    [.., rows, k]."""
+    lo, hi = moe.held_range(cfg)
+    held = load[:, lo:hi].sum(-1)
+    return dict(
+        expert_load=load, held_assignments=held,
+        fullest_held_expert_rows=load[:, lo:hi].max(-1), experts_chosen=chosen,
+        expert_windows=moe.windows_walked(
+            held.astype(jnp.int32), moe.window_rows(cfg, chosen.shape[-2])))
+
+
+def block_diffusion_rows(tokens: jax.Array, masked: jax.Array, cfg: ModelConfig):
+    """The block-diffusion objective's row: tokens [B, L] noised to cfg.diffusion_mask_token
+    where `masked`, then the sequence as it is, as ONE row [noised ; clean] of 2L ids, and its
+    positions 0..L-1 twice ([1, 2L]: every row of the batch shares them)."""
+    with jax.named_scope("embed"), jax.named_scope("bd_rows"):
+        noised = jnp.where(masked, jnp.asarray(cfg.diffusion_mask_token, tokens.dtype), tokens)
+        return jnp.concatenate([noised, tokens], axis=1), jnp.tile(jnp.arange(tokens.shape[1]), 2)[None]
+
+
+def block_diffusion_loss(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig):
+    """The block-diffusion objective (cfg.diffusion_block; BD3-LMs, arXiv:2503.09573, as SDAR
+    trains it). batch: tokens [B, L], and the realised noise a loader made
+    (train/diffusion.py:block_diffusion_noise): masked [B, L] (which positions the noised
+    copy hides) and p_mask [B] (the rate they were drawn at). Every sequence goes through
+    the layers twice, noised and clean, as one row of 2L positions under the
+    block-diffusion mask; the head reads the noised half, and the loss is the cross
+    entropy at the masked positions, each at the token's OWN position, weighted by
+    1 / p_mask, over B x L. Beside it `ce_loss`, their plain mean, and `masked_tokens`,
+    their count; the experts' counters are over all 2L rows a sequence."""
+    tokens, masked, p_mask = batch["tokens"], batch["masked"].astype(bool), batch["p_mask"]
+    if "segment_ids" in batch or "loss_mask" in batch or cfg.n_experts and not cfg.moe_dropless:
+        raise NotImplementedError(
+            "the block-diffusion objective over packed documents (segment_ids), under a loss mask of the "
+            "batch's own (the noise is the mask) or over the capacity-based experts")
+    b, n = tokens.shape
+    row, positions = block_diffusion_rows(tokens, masked, cfg)
+    logits, _, aux = forward(params, row, cfg, positions=positions, return_aux=True, head_rows=n)
+    with jax.named_scope("loss"):
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        ce = (lse - jnp.take_along_axis(logits, tokens[..., None], axis=-1)[..., 0]) * masked
+        count = masked.sum().astype(jnp.float32)
+        loss = (ce / p_mask.astype(jnp.float32)[:, None]).sum() / (b * n)
+        metrics = {"loss": loss, "ce_loss": ce.sum() / jnp.maximum(count, 1.0), "masked_tokens": count,
+                   "tokens": jnp.asarray(b * n, jnp.float32)}
+    if isinstance(aux, dict):
+        metrics.update(_expert_counters(aux["load"], aux["chosen"], cfg))
+    return loss, metrics
+
+
 def loss_fn(
     params: Params,
     batch: Dict[str, jax.Array],
@@ -668,7 +734,10 @@ def loss_fn(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """Next-token cross entropy. batch: tokens [B,S]; optional loss_mask/segment_ids.
     With MTP modules (cfg.mtp_depth) the mean of their losses is added at
-    cfg.mtp_loss_weight; module m predicts the token m + 1 ahead."""
+    cfg.mtp_loss_weight; module m predicts the token m + 1 ahead. The configuration chooses
+    the objective: under cfg.diffusion_block it is `block_diffusion_loss`."""
+    if cfg.diffusion_block:
+        return block_diffusion_loss(params, batch, cfg)
     tokens = batch["tokens"]
     seg = batch.get("segment_ids")
     logits, _, aux = forward(
@@ -698,15 +767,5 @@ def loss_fn(
         load = jnp.concatenate([load] + [a["load"][None] for _, a in heads])
         chosen = jnp.concatenate([chosen] + [a["chosen"][None] for _, a in heads])
     if load is not None:
-        lo, hi = moe.held_range(cfg)
-        held = load[:, lo:hi].sum(-1)
-        # a row an expert layer, the MTP modules' last: what the balance rule reads, what
-        # fell on the experts held here, the windows of the layer's buffer that load took
-        # (1: the step fitted the rows the held experts can expect; more: it overflowed
-        # them and was served all the same), and the experts each token chose [.., B * S, k]
-        metrics.update(
-            expert_load=load, held_assignments=held,
-            fullest_held_expert_rows=load[:, lo:hi].max(-1), experts_chosen=chosen,
-            expert_windows=moe.windows_walked(
-                held.astype(jnp.int32), moe.window_rows(cfg, chosen.shape[-2])))
+        metrics.update(_expert_counters(load, chosen, cfg))
     return loss, {"loss": loss, "ce_loss": ce, **metrics}

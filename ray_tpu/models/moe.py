@@ -165,7 +165,8 @@ _ROW_TILE = 512
 
 def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: ModelConfig):
     """x [T, D] -> (experts chosen [T, k] int32, their gates [T, k] f32), as glm4_moe_lite
-    states it. Sigmoid scores in float32, products at the highest precision (2 % of a
+    states it. Sigmoid scores in float32 (cfg.moe_scoring "softmax", sdar_moe's: a softmax
+    over ALL experts, with no bias), products at the highest precision (2 % of a
     layer's operations); the k largest of score + bias are chosen; the gates are the
     chosen SCORES (the bias selects and never weights), normalised over the k, times
     moe_route_scale. (Against a float32 reference 2 % of tokens choose another expert at
@@ -186,16 +187,19 @@ def route(x: jax.Array, router_w: jax.Array, bias: Optional[jax.Array], cfg: Mod
     A name on the scores alone does not do that under plain differentiation:
     `jax.nn.sigmoid`'s own derivative rule keeps ITS output, the value before the name,
     which no policy can save, and the product is made again for it."""
-    if cfg.moe_scoring != "sigmoid":
+    if cfg.moe_scoring not in ("sigmoid", "softmax"):
+        raise NotImplementedError(f"the dropless layer scores by sigmoid or softmax, not {cfg.moe_scoring!r}")
+    if cfg.moe_scoring == "softmax" and bias is not None:
         raise NotImplementedError(
-            f"the dropless layer scores by sigmoid; {cfg.moe_scoring!r} is the capacity path's (moe_mlp)")
+            "softmax scores with a selection bias: sdar_moe, the family that scores by softmax over all "
+            "experts, chooses by the scores alone (`bias=None`)")
     if cfg.moe_n_group > 1:
         raise NotImplementedError(
             f"group-limited routing (n_group {cfg.moe_n_group}): the choice is over all experts at once")
     if not cfg.moe_norm_topk:
         raise NotImplementedError(
             "gates that are not normalised over the chosen experts (norm_topk_prob false)")
-    idx, gates = _score_and_pick(x, router_w, bias, cfg.moe_top_k)
+    idx, gates = _score_and_pick(x, router_w, bias, cfg.moe_top_k, cfg.moe_scoring)
     gates = gates / (gates.sum(-1, keepdims=True) + cfg.moe_gate_eps)
     return idx, gates * cfg.moe_route_scale
 
@@ -223,8 +227,9 @@ def _router_product(eq: str, a: jax.Array, b: jax.Array) -> jax.Array:
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def _score_and_pick_fwd(x, router_w, bias, k: int):
-    scores = jax.nn.sigmoid(_router_product("td,de->te", x, router_w))
+def _score_and_pick_fwd(x, router_w, bias, k: int, scoring: str = "sigmoid"):
+    logits = _router_product("td,de->te", x, router_w)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" else jax.nn.softmax(logits, axis=-1)
     _, idx = jax.lax.top_k(scores if bias is None else scores + bias[None, :], k)
     idx = checkpoint_name(idx.astype(jnp.int32), CHOSEN_NAME)
     scores = checkpoint_name(scores, SCORES_NAME)
@@ -232,18 +237,20 @@ def _score_and_pick_fwd(x, router_w, bias, k: int):
     return (idx, picked), (x, router_w, scores, idx)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _score_and_pick(x, router_w, bias, k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _score_and_pick(x, router_w, bias, k: int, scoring: str = "sigmoid"):
     """x [T, D], router_w [D, E], bias [E] or None -> (the k experts of the largest
-    score + bias [T, k] int32, their scores [T, k] f32)."""
-    return _score_and_pick_fwd(x, router_w, bias, k)[0]
+    score + bias [T, k] int32, their scores [T, k] f32); `scoring`: sigmoid an expert, or
+    softmax over all of them."""
+    return _score_and_pick_fwd(x, router_w, bias, k, scoring)[0]
 
 
-def _score_and_pick_bwd(k, kept, cotangents):
+def _score_and_pick_bwd(k, scoring, kept, cotangents):
     """The pick's transpose in one pass: a token's k experts are distinct, so a lane of
     its row of d_scores takes its value from at most one slot (a select a slot over
     [T, E], fused; no loop, no accumulator, no scatter); then the sigmoid's derivative
-    from the kept scores and the two products. The bias selects: its gradient is zero."""
+    (the softmax's: s (d_s - sum(d_s s)) a token) from the kept scores and the two
+    products. The bias selects: its gradient is zero."""
     x, router_w, scores, idx = kept
     d_picked = cotangents[1]
     lanes = jnp.arange(scores.shape[-1])
@@ -254,7 +261,11 @@ def _score_and_pick_bwd(k, kept, cotangents):
     # selects into BOTH products as their producer and makes them again for every tile
     # of each product's other extent (+0.39 and +0.20 ms a layer on the chip, PERF.md
     # section 6, PR 36)
-    d_logits = jax.lax.optimization_barrier(d_scores * scores * (1 - scores))
+    if scoring == "sigmoid":
+        d_logits = jax.lax.optimization_barrier(d_scores * scores * (1 - scores))
+    else:
+        d_logits = jax.lax.optimization_barrier(
+            scores * (d_scores - jnp.sum(d_scores * scores, axis=-1, keepdims=True)))
     dx = _router_product("te,de->td", d_logits, router_w).astype(x.dtype)
     dw = _router_product("td,te->de", x, d_logits).astype(router_w.dtype)
     return dx, dw, None

@@ -36,6 +36,17 @@ class Rotation(NamedTuple):
     apply: Callable[[jax.Array, jax.Array, float], jax.Array]
 
 
+def block_diffusion_keep(qi: jax.Array, kj: jax.Array, half: int, block: int) -> jax.Array:
+    """The block-diffusion mask (BD3-LMs, arXiv:2503.09573) over a row laid [noised ; clean],
+    `half` positions each: whether query row `qi` keeps key row `kj` (broadcast against each
+    other). A key is kept iff it is clean and its block lies before the query's, or it is of
+    the query's half and block: a noised block sees the clean blocks before it and itself,
+    both ways inside the block; a clean block the clean blocks up to and including itself."""
+    clean_q, clean_k = qi >= half, kj >= half
+    block_q, block_k = (qi - half * clean_q) // block, (kj - half * clean_k) // block
+    return (clean_k & (block_k < block_q)) | ((clean_k == clean_q) & (block_k == block_q))
+
+
 def _repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
     """[B, S, Hkv, D] -> [B, S, Hkv*n_rep, D] by head repetition (GQA)."""
     if n_rep == 1:
@@ -55,6 +66,7 @@ def attention_reference(
     q_offset: Optional[jax.Array] = None,
     kv_valid_len: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Pure-XLA attention. Numerically the ground truth for the Pallas kernel tests.
 
@@ -63,6 +75,8 @@ def attention_reference(
     `q_offset`: kv index of query row 0 (decode-with-cache); default aligns the ends.
     `kv_valid_len`: kv slots >= this are masked out (padded cache tail).
     `window` (causal): key j is kept for query i where 0 <= i - j < window.
+    `block_diffusion` (not causal): the row is [noised ; clean], in blocks of this many
+    positions (`block_diffusion_keep`).
     """
     # GQA as a grouped contraction: q heads are viewed as [Hkv, n_rep] and K/V
     # are never repeated. (Broadcasting K/V to H heads first is the same math,
@@ -82,6 +96,9 @@ def attention_reference(
         qi = jnp.arange(sq)[:, None] + q_offset
         seen = kj <= qi if window is None else (kj <= qi) & (kj > qi - window)
         logits = jnp.where(seen, logits, -jnp.inf)
+    if block_diffusion is not None:
+        logits = jnp.where(block_diffusion_keep(jnp.arange(sq)[:, None], kj, sq // 2, block_diffusion),
+                           logits, -jnp.inf)
     if kv_valid_len is not None:
         logits = jnp.where(kj < kv_valid_len, logits, -jnp.inf)
     if segment_ids is not None:
@@ -106,6 +123,7 @@ def attention_chunked(
     kv_valid_len: Optional[jax.Array] = None,
     block_kv: int = 512,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Online-softmax attention over KV blocks ("flash in XLA").
 
@@ -160,6 +178,8 @@ def attention_chunked(
         if causal:
             seen = kj <= qi if window is None else (kj <= qi) & (kj > qi - window)
             logits = jnp.where(seen[None, None], logits, neg)
+        if block_diffusion is not None:
+            logits = jnp.where(block_diffusion_keep(qi, kj, sq // 2, block_diffusion)[None, None], logits, neg)
         valid = kv_valid_len if kv_valid_len is not None else skv
         logits = jnp.where((kj < valid)[None, None], logits, neg)
         if seg_c is not None:
@@ -217,7 +237,8 @@ def _log_fallback(q_shape, k_shape, impl: str) -> None:
     )
 
 
-def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotation=None, window=None):
+def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotation=None, window=None,
+                     block_diffusion=None):
     """The Pallas kernel under an ambient mesh. GSPMD cannot partition a Mosaic
     kernel, and Mosaic refuses to lower while ANY mesh axis is still
     automatic, so the kernel is called per shard with every such axis made
@@ -232,6 +253,7 @@ def _flash_per_shard(q, k, v, *, causal, segment_ids, scale, shard_spec, rotatio
 
     def kernel(q, k, v, rows):
         return flash_attention(q, k, v, causal=causal, scale=scale, segment_ids=rows.get("seg"), window=window,
+                               block_diffusion=block_diffusion,
                                rope=None if rotation is None else (rows["pos"], rotation.theta))
 
     # what comes a row of the batch (or one row for all of it): segment ids, positions
@@ -263,6 +285,7 @@ def attention(
     shard_spec=None,
     rotation: Optional[Rotation] = None,
     window: Optional[int] = None,
+    block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Dispatching attention. impl: auto|pallas|chunked|reference.
 
@@ -283,7 +306,18 @@ def attention(
 
     window: a sliding window over one causal sequence (key j is kept for query i where
     0 <= i - j < window), on every path; the Pallas kernels skip the tiles outside the band.
+
+    block_diffusion: q, k and v are one row laid [noised ; clean] (the block-diffusion
+    objective's doubled row, models/llama.py), in blocks of this many positions, and a query
+    keeps what `block_diffusion_keep` says, on every path; the Pallas kernels walk the tiles
+    the mask's three parts reach and no others (ops/flash_attention.py:BlockDiffusion).
     """
+    if block_diffusion is not None and (causal or window is not None or segment_ids is not None
+                                        or q_offset is not None or kv_valid_len is not None
+                                        or q.shape[1] != k.shape[1] or q.shape[1] % 2):
+        raise NotImplementedError(
+            "block-diffusion attention beside `causal`, a window, packed documents (segment_ids) or a KV cache "
+            "(q_offset, kv_valid_len): the mask is over one doubled row [noised ; clean]")
     if window is not None and (not causal or q_offset is not None or kv_valid_len is not None):
         raise NotImplementedError("an attention window under a KV cache (q_offset, kv_valid_len) or without `causal`")
     if impl == "auto":
@@ -294,7 +328,7 @@ def attention(
         # geometries the kernel can't tile must fall back to XLA or TPU compile fails
         from . import flash_attention as _fa
 
-        tileable = _fa.supports(q.shape[1], k.shape[1], q.shape[-1])
+        tileable = _fa.supports(q.shape[1], k.shape[1], q.shape[-1], block_diffusion=block_diffusion)
         if (on_tpu and tileable and q_offset is None and kv_valid_len is None
                 and (same_len or not causal)):
             impl = "pallas"
@@ -316,7 +350,8 @@ def attention(
         rotation = None
     if impl == "pallas":
         return _flash_per_shard(q, k, v, causal=causal, segment_ids=segment_ids, scale=scale,
-                                shard_spec=shard_spec, rotation=rotation, window=window)
+                                shard_spec=shard_spec, rotation=rotation, window=window,
+                                block_diffusion=block_diffusion)
     if impl == "chunked":
         return attention_chunked(
             q,
@@ -328,6 +363,7 @@ def attention(
             q_offset=q_offset,
             kv_valid_len=kv_valid_len,
             window=window,
+            block_diffusion=block_diffusion,
         )
     return attention_reference(
         q,
@@ -339,4 +375,5 @@ def attention(
         q_offset=q_offset,
         kv_valid_len=kv_valid_len,
         window=window,
+        block_diffusion=block_diffusion,
     )

@@ -107,7 +107,7 @@ SPAN_VMEM_BYTES = VMEM_LIMIT_BYTES // 2
 
 
 def supports(sq: int, skv: int, head_dim: int, block_q: int = BLOCK_Q,
-             block_kv: int = BLOCK_KV) -> bool:
+             block_kv: int = BLOCK_KV, block_diffusion: Optional[int] = None) -> bool:
     """Whether the kernels can tile this geometry. Mosaic tiles the lane (last) dim at 128
     and sublanes at 8, and a sequence longer than one compute tile must be a whole number of
     them (`_block_sizes`): head_dim 16, seq 20 or seq 520 would fail the TPU compile ("slice
@@ -115,6 +115,12 @@ def supports(sq: int, skv: int, head_dim: int, block_q: int = BLOCK_Q,
     def seq_ok(n: int, block: int) -> bool:
         return n % 8 == 0 and (n <= block or n % block == 0)
 
+    if block_diffusion is not None:  # the halves of the doubled row, each whole tiles of whole blocks
+        try:
+            bd = _block_diffusion(block_diffusion, sq, skv, block_q, block_kv)
+        except ValueError:
+            return False
+        sq = skv = bd.half
     return (head_dim == NARROW_HEAD or head_dim % 128 == 0) and seq_ok(sq, block_q) and seq_ok(skv, block_kv)
 
 
@@ -161,7 +167,7 @@ class TileCounts(NamedTuple):
 
 def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim: int = 128,
                 itemsize: int = 2, n_rep: int = 1, kernel: str = "fwd",
-                window: Optional[int] = None) -> TileCounts:
+                window: Optional[int] = None, block_diffusion: Optional[int] = None) -> TileCounts:
     """What a (batch, query head) costs the forward (`kernel` "fwd") or the dQ kernel
     ("dq"), or a (batch, kv head with its `n_rep` query heads) the dK/dV kernel ("dkv"):
     from the same `_tiling`, grid lengths and bands (`_kv_band`, `_q_band`) the kernels are
@@ -169,12 +175,22 @@ def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim:
     position, and inside a `window` more than q position - window); under a window the
     backward kernels compute the two tiles an edge of the band crosses in the pieces that
     hold one: with 512 x 512 tiles, 256-row pieces and a window of 2,048 a q tile meets 5
-    kv tiles forward and 4.5 backward where the band needs 4.0."""
+    kv tiles forward and 4.5 backward where the band needs 4.0. Under `block_diffusion` (the
+    row [noised ; clean], `sq` its whole length) a tile is computed if the mask's three parts
+    keep one of its scores (`BlockDiffusion`), and the scores kept are half x (half + block)."""
+    bd = _block_diffusion(block_diffusion, sq, skv, bq, bkv, causal, window)
+    if bd is not None:
+        bq, bkv = min(bq, bd.half), min(bkv, bd.half)
     t = _tiling(sq, skv, bq, bkv, head_dim, itemsize, n_rep)
     nq, nk = sq // t.bq, skv // t.bkv
     window = _band(window, sq, skv, causal)
     steps, heads = ((nk * _q_spans(sq, skv, t, window), n_rep) if kernel == "dkv"
                     else (nq * _kv_spans(sq, skv, t, window), 1))
+    if bd is not None:
+        ranges = ([_bd_q_ranges(kj, t.bq, t.bkv, bd) for kj in range(nk)] if kernel == "dkv"
+                  else [_bd_kv_ranges(qi, t.bq, t.bkv, bd) for qi in range(nq)])
+        computed = sum(hi - lo for tile in ranges for lo, hi in tile)
+        return TileCounts(steps, heads * computed, heads * bd.half * (bd.half + bd.block) / (t.bq * t.bkv))
     if not causal:
         return TileCounts(steps, heads * nq * nk, float(heads * nq * nk))
     if window is None:
@@ -201,6 +217,97 @@ def _band(window: Optional[int], sq: int, skv: int, causal: bool) -> Optional[in
     if window < 1 or not causal or sq != skv:
         raise ValueError(f"window {window}: a causal band over one sequence (sq {sq}, skv {skv}, causal {causal})")
     return None if window >= skv else int(window)
+
+
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion mask (BD3-LMs, arXiv:2503.09573) over a row laid [noised ; clean],
+    `half` positions each, in blocks of `block` positions: key c is kept for query r iff c is
+    clean and its block lies before r's (for a clean r: before or at), or c and r are of one
+    half and one block. `half` is whole compute tiles and a tile whole blocks, so a tile lies in
+    one half, a noised q tile meets the noised kv tiles over its own rows and the clean ones up
+    to the block before its last row's, a clean q tile the clean ones up to its last row
+    (`_bd_kv_ranges`; transposed: `_bd_q_ranges`), and the kernels walk those and no others:
+    at [2 x 8192, 512] 288 tiles a head each way where the kept scores are 256.1 tiles' worth,
+    the triangle over the doubled row would compute 528 and a dense walk 1,024."""
+    block: int
+    half: int
+
+
+def _block_diffusion(block: Optional[int], sq: int, skv: int, bq: int, bkv: int, causal: bool = False,
+                     window=None, segment_ids=None) -> Optional[BlockDiffusion]:
+    """The mask the kernels walk for `block_diffusion=block`, or None where there is none;
+    what they cannot tile is refused (`supports` says so beforehand)."""
+    if block is None:
+        return None
+    half = sq // 2
+    tiles = [min(b, half) for b in (bq, bkv)]
+    if (causal or window is not None or segment_ids is not None or sq != skv or sq % 2 or block < 1
+            or block & (block - 1) or any(half % t or t % block for t in tiles)):
+        raise ValueError(
+            f"block_diffusion {block}: one row [noised ; clean] (sq {sq}, skv {skv}) whose halves are whole tiles "
+            f"({tiles}) of whole blocks, a power of two long; no `causal`, window or segment ids beside it")
+    return BlockDiffusion(int(block), half)
+
+
+def _bd_kv_ranges(qi, bq: int, bkv: int, bd: BlockDiffusion):
+    """The kv tiles q tile `qi` meets under the block-diffusion mask, as two half-open ranges
+    in ascending order: (the noised tiles over its own positions; (0, 0) for a clean q tile,
+    the clean tiles up to the last key its last row keeps)."""
+    nq, nk = bd.half // bq, bd.half // bkv
+    clean = _flag(qi >= nq)
+    i = qi - nq * clean
+    own = ((i * bq) // bkv * (1 - clean), (((i + 1) * bq - 1) // bkv + 1) * (1 - clean))
+    # a noised row keeps the clean keys before its block, a clean row those up to its block's end
+    return own, (nk, nk + ((i + 1) * bq - 1 - bd.block * (1 - clean)) // bkv + 1)
+
+
+def _bd_q_ranges(kj, bq: int, bkv: int, bd: BlockDiffusion):
+    """The same transposed (dK/dV): the q tiles kv tile `kj` meets, as three half-open ranges in
+    ascending order, an empty one (0, 0): (the noised tiles over a noised kv tile's own
+    positions, the noised tiles from the block behind a clean kv tile's first key, the clean
+    tiles from that key's)."""
+    nq, nk = bd.half // bq, bd.half // bkv
+    clean = _flag(kj >= nk)
+    j = kj - nk * clean
+    own = ((j * bkv) // bq * (1 - clean), (((j + 1) * bkv - 1) // bq + 1) * (1 - clean))
+    behind = (j * bkv + bd.block) // bq
+    behind = min(behind, nq) if isinstance(behind, int) else jnp.minimum(behind, nq)
+    return own, (behind * clean, nq * clean), ((nq + (j * bkv) // bq) * clean, 2 * nq * clean)
+
+
+def _nearest_span(step, n: int, first, second):
+    """Which span of `n` tiles a grid step fetches where its tiles are walked in two half-open
+    ranges (`first` before `second`, either possibly empty): the step's own where that holds a
+    tile of one, else the nearest before it that does (the first used, for the steps in front
+    of it), so that the pipeline issues no copy for a span of which nothing is read."""
+    (a_lo, a_hi), (b_lo, b_hi) = first, second
+    in_a = jnp.clip(step, a_lo // n, jnp.maximum(a_hi - 1, a_lo) // n)
+    in_b = jnp.clip(step, b_lo // n, jnp.maximum(b_hi - 1, b_lo) // n)
+    return jnp.where((a_hi > a_lo) & ((step < b_lo // n) | (b_hi <= b_lo)), in_a, in_b)
+
+
+def _walk_ranges(ranges, base, n: int, tile) -> None:
+    """Run a grid step's share of each of `ranges` = ((first, one past the last), keywords
+    of `tile`): its span holds compute tiles base .. base + n - 1."""
+    for (lo, hi), kw in ranges:
+        _walk(jnp.clip(lo - base, 0, n), jnp.clip(hi - base, 0, n), n, functools.partial(tile, **kw))
+
+
+def _keep_bd(shape, q_axis: int, qi, kj, bq: int, bkv: int, bd: BlockDiffusion, own: bool):
+    """Which scores of a tile stay under the block-diffusion mask. `own`: q and kv tile are
+    both noised (a key is kept inside its query's block), else the kv tile is clean (a key is
+    kept before its query's block, for a clean query up to that block's end). With r and c a
+    score's row and column in the tile and `first` the q tile's first position less the kv
+    tile's, each in its half: a key's offset from its query's block is c - (r - r mod block) -
+    first (a tile is whole blocks, so r mod block is the position's own)."""
+    nq, nk = bd.half // bq, bd.half // bkv
+    clean_q = _flag(qi >= nq)
+    first = (qi - nq * clean_q) * bq - (kj if own else kj - nk) * bkv
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    offset = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis) - (rows & -bd.block)
+    if own:
+        return (offset >= first) & (offset < first + bd.block)
+    return offset < first + bd.block * clean_q
 
 
 def _interpret() -> bool:
@@ -240,9 +347,12 @@ def _pallas_call(kernel, *, name: str,
         **kw)
 
 
-def _named(kernel: str, window: Optional[int]) -> str:
+def _named(kernel: str, window: Optional[int], bd: Optional[BlockDiffusion] = None) -> str:
     """A flash kernel's name in the device trace: a windowed call's carries `_window`
-    behind it, so that a metric can tell the band's kernels from the triangle's."""
+    behind it and a block-diffusion call's `_bd`, so that a metric can tell the band's and
+    the doubled row's kernels from the triangle's."""
+    if bd is not None:
+        return f"{kernel}_bd"
     return kernel if window is None else f"{kernel}_window"
 
 
@@ -451,10 +561,14 @@ def _kv_span_of(step, qi, n: int, bq: int, bkv: int, window: Optional[int]):
 
 
 def _walk_kv(qi, sj, n: int, tile, causal: bool, bq: int, bkv: int, window: Optional[int],
-             pieces: bool) -> None:
+             pieces: bool, bd: Optional[BlockDiffusion] = None) -> None:
     """The forward and dQ kernels' walk over span sj's kv tiles; `pieces`: a band's two edge
-    tiles in their pieces (`tile(t, piece)`)."""
-    if window is None:
+    tiles in their pieces (`tile(t, piece)`); under the block-diffusion mask the q tile's two
+    ranges, its own noised tiles as `tile(t, own=True)`."""
+    if bd is not None:
+        own, seen = _bd_kv_ranges(qi, bq, bkv, bd)
+        _walk_ranges(((own, {"own": True}), (seen, {})), sj * n, n, tile)
+    elif window is None:
         _walk(0, _kv_tiles_end(causal, qi, sj, n, bq, bkv), n, tile)
     else:
         _walk_band(_kv_band(qi, bq, bkv, window, pieces), sj * n, n, tile)
@@ -504,6 +618,7 @@ def _fwd_kernel(
     bq: int,
     bkv: int,
     window: Optional[int] = None,
+    bd: Optional[BlockDiffusion] = None,
 ):
     qi = pl.program_id(2)
     step = pl.program_id(3)
@@ -516,12 +631,15 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def tile(t):
+    def tile(t, own=False):
         kj = sj * n + t
         v = v_ref[_at(t, bkv)]
         s = _dot(q_ref[:], k_ref[_at(t, bkv)], _NT) * scale  # [bq, bkv]
-        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
-                     None if seg_kv_ref is None else seg_kv_ref[t], window)
+        if bd is not None:
+            keep = _keep_bd(s.shape, 0, qi, kj, bq, bkv, bd, own)
+        else:
+            keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
+                         None if seg_kv_ref is None else seg_kv_ref[t], window)
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
         # The running statistics stay 128 equal lanes wide: as [bq, 1] columns every
@@ -539,7 +657,7 @@ def _fwd_kernel(
     # tile is bound by what is done once a ROW (the two lane reductions, above), which a
     # piece of half the rows halves and a tile's two pieces make whole again: 9.41 ms a
     # call in pieces, 9.42 whole, for four more bodies to trace (PERF.md, PR 49).
-    _walk_kv(qi, sj, n, tile, causal, bq, bkv, window, pieces=False)
+    _walk_kv(qi, sj, n, tile, causal, bq, bkv, window, pieces=False, bd=bd)
 
     @pl.when(step == pl.num_programs(3) - 1)
     def _finalize():
@@ -551,7 +669,8 @@ def _fwd_kernel(
 
 
 def _kv_spans(sq: int, skv: int, t: Tiling, window: Optional[int]) -> int:
-    """The length of the forward and dQ grids' last dimension: the sequence's spans of K/V,
+    """The length of the forward and dQ grids' last dimension: the sequence's spans of K/V
+    (under the block-diffusion mask too: a noised q tile's two ranges lie a half apart),
     or under a window the most that a q tile's band reaches."""
     if window is None:
         return skv // t.kv_span
@@ -566,7 +685,7 @@ def _q_spans(sq: int, skv: int, t: Tiling, window: Optional[int]) -> int:
                           t.q_span // t.bq)
 
 
-def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None):
+def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None, bd=None):
     """BlockSpecs of the forward and dQ grids (b, h, q block, kv span): q-side,
     kv-side, per-row statistics (`_rows`: a lane vector a q block), and the segment
     ids of rows and columns."""
@@ -574,6 +693,8 @@ def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None):
     n = t.kv_span // bkv
 
     def kv_span(qi, sj):  # a span above the diagonal names the last span used; a band's are counted from its first
+        if bd is not None:  # one that holds none of the q tile's two ranges names the nearest that does
+            return _nearest_span(sj, n, *_bd_kv_ranges(qi, bq, bkv, bd))
         if window is not None:
             sj = _kv_span_of(sj, qi, n, bq, bkv, window)
         return jnp.minimum(sj, _last_kv_block(qi, bq, bkv) // n) if causal else sj
@@ -614,23 +735,24 @@ def _fwd(
     bq: int,
     bkv: int,
     window: Optional[int] = None,
+    bd: Optional[BlockDiffusion] = None,
 ):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize)
     bq, bkv = t.bq, t.bkv
     has_seg = seg is not None
-    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, t, has_seg, window)
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, t, has_seg, window, bd)
     args = [q, k, v] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def kernel(*refs):
         ins, segs, (o_ref, lse_ref, *scratch) = _unpack(refs, (2,) * 3, has_seg)
         _fwd_kernel(*ins, *segs, o_ref.at[0, 0], lse_ref.at[0, 0, 0], *scratch,
-                    scale=scale, causal=causal, bq=bq, bkv=bkv, window=window)
+                    scale=scale, causal=causal, bq=bq, bkv=bkv, window=window, bd=bd)
 
     out, lse = _pallas_call(
         kernel,
-        name=_named("flash_attention_fwd", window),
+        name=_named("flash_attention_fwd", window, bd),
         grid=(b, h, sq // bq, _kv_spans(sq, skv, t, window)),
         in_specs=[q_spec, kv_spec, kv_spec] + seg_specs,
         out_specs=[q_spec, stat_spec],
@@ -654,7 +776,7 @@ def _fwd(
 def _bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_q_ref, seg_kv_ref, dq_ref,
     dq_scr, lse_scr, delta_scr,
-    *, scale, causal, bq, bkv, window=None,
+    *, scale, causal, bq, bkv, window=None, bd=None,
 ):
     """k_ref and v_ref are a span of K/V, [span, D]. lse_ref and delta_ref are [1, P];
     the tile [bq, bkv] wants them down its rows, so the q block's first step turns
@@ -670,14 +792,17 @@ def _bwd_dq_kernel(
         for row_ref, col_scr in ((lse_ref, lse_scr), (delta_ref, delta_scr)):
             col_scr[:] = jnp.broadcast_to(row_ref[:], (128, row_ref.shape[1])).T[:bq]
 
-    def tile(t, piece=None):
+    def tile(t, piece=None, own=False):
         q_part, kv_part = piece or (None, None)
         rows = _cut(q_part)
         kj = sj * n + t
         k = k_ref[_at(t, bkv, kv_part)]
         s = _dot(q_ref[rows], k, _NT) * scale  # [bq, bkv]
-        keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
-                     None if seg_kv_ref is None else _lanes_of(seg_kv_ref, (t,), kv_part), window, piece)
+        if bd is not None:
+            keep = _keep_bd(s.shape, 0, qi, kj, bq, bkv, bd, own)
+        else:
+            keep = _keep(s.shape, 0, qi, kj, bq, bkv, causal, seg_q_ref,
+                         None if seg_kv_ref is None else _lanes_of(seg_kv_ref, (t,), kv_part), window, piece)
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
         p = jnp.exp(s - _lanes_to(lse_scr[rows], s.shape[1]))
@@ -685,7 +810,7 @@ def _bwd_dq_kernel(
         ds = p * (dp - _lanes_to(delta_scr[rows], s.shape[1])) * scale
         dq_scr[rows] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _walk_kv(qi, sj, n, tile, causal, bq, bkv, window, pieces=True)
+    _walk_kv(qi, sj, n, tile, causal, bq, bkv, window, pieces=True, bd=bd)
 
     @pl.when(step == pl.num_programs(3) - 1)
     def _():
@@ -695,7 +820,7 @@ def _bwd_dq_kernel(
 def _bwd_dkv_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_kv_ref, seg_q_ref,
     dk_ref, dv_ref, dk_scr, dv_scr,
-    *, scale, causal, bq, bkv, window=None, nq=None,
+    *, scale, causal, bq, bkv, window=None, nq=None, bd=None,
 ):
     """One kv block against a span of q rows of the query heads of its group, on the
     transposed tile [bkv, bq]: q_ref and do_ref are [n_rep, span, D], lse_ref and
@@ -712,21 +837,26 @@ def _bwd_dkv_kernel(
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    if window is None:  # the first of the span's q tiles that attends to this kv block
+    if bd is not None:  # the kv tile's three ranges of q tiles, two of them empty or one
+        own, noised, cleans = _bd_q_ranges(kj, bq, bkv, bd)
+    elif window is None:  # the first of the span's q tiles that attends to this kv block
         lo = jnp.clip(_first_q_block(kj, bq, bkv) - sp * n, 0, n) if causal else 0
     else:
         band = _q_band(kj, nq, bq, bkv, window)
 
     def head(r):
-        def tile(t, piece=None):
+        def tile(t, piece=None, own=False):
             cols, kv_part = piece or (None, None)
             rows = _cut(kv_part)
             qi = sp * n + t
             q = q_ref[r, _at(t, bq, cols)]
             do = do_ref[r, _at(t, bq, cols)]
             st = _dot(k_ref[rows], q, _NT) * scale  # [bkv, bq]
-            keep = _keep(st.shape, 1, qi, kj, bq, bkv, causal, seg_kv_ref,
-                         None if seg_q_ref is None else _lanes_of(seg_q_ref, (t,), cols), window, piece)
+            if bd is not None:
+                keep = _keep_bd(st.shape, 1, qi, kj, bq, bkv, bd, own)
+            else:
+                keep = _keep(st.shape, 1, qi, kj, bq, bkv, causal, seg_kv_ref,
+                             None if seg_q_ref is None else _lanes_of(seg_q_ref, (t,), cols), window, piece)
             if keep is not None:
                 st = jnp.where(keep, st, NEG_INF)
             pt = jnp.exp(st - _lanes_of(lse_ref, (r, t), cols)[:, :st.shape[1]])
@@ -735,7 +865,9 @@ def _bwd_dkv_kernel(
             dst = pt * (dpt - _lanes_of(delta_ref, (r, t), cols)[:, :st.shape[1]]) * scale
             dk_scr[rows] += _dot(dst.astype(q.dtype), q, _NN)
 
-        if window is None:
+        if bd is not None:
+            _walk_ranges(((own, {"own": True}), (noised, {}), (cleans, {})), sp * n, n, tile)
+        elif window is None:
             _walk(lo, n, n, tile)
         else:
             _walk_band(band, sp * n, n, tile)
@@ -748,7 +880,7 @@ def _bwd_dkv_kernel(
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
+def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=None):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     n_rep = h // hkv
@@ -761,17 +893,17 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
     stats = [lse, _rows(delta, bq)]
 
     # --- dQ pass: grid (b, h, q blocks, kv spans)
-    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, t, has_seg, window)
+    q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, t, has_seg, window, bd)
     args = [q, k, v, dout, *stats] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def dq_kernel(*refs):
         ins, segs, (dq_ref, *scratch) = _unpack(refs, (2,) * 6, has_seg)
         _bwd_dq_kernel(*ins, *segs, dq_ref.at[0, 0], *scratch,
-                       scale=scale, causal=causal, bq=bq, bkv=bkv, window=window)
+                       scale=scale, causal=causal, bq=bq, bkv=bkv, window=window, bd=bd)
 
     dq = _pallas_call(
         dq_kernel,
-        name=_named("flash_attention_bwd_dq", window),
+        name=_named("flash_attention_bwd_dq", window, bd),
         grid=(b, h, sq // bq, _kv_spans(sq, skv, t, window)),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec] + seg_specs,
         out_specs=q_spec,
@@ -790,6 +922,9 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
     n = t.q_span // bq
 
     def q_span(kj, sp):
+        if bd is not None:  # a noised kv tile's one range, a clean one's two
+            own, noised, cleans = _bd_q_ranges(kj, bq, bkv, bd)
+            return _nearest_span(sp, n, (own[0] + noised[0], own[1] + noised[1]), cleans)
         if window is not None:
             return jnp.minimum(sp + _first_q_block(kj, bq, bkv) // n, _q_band(kj, sq // bq, bq, bkv, window).last // n)
         return jnp.maximum(sp, _first_q_block(kj, bq, bkv) // n) if causal else sp
@@ -813,11 +948,11 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None):
         # q, dO and the statistics keep the group's query heads as their leading dimension
         ins, segs, (dk_ref, dv_ref, *scratch) = _unpack(refs, (3, 2, 2, 3, 4, 4), has_seg)
         _bwd_dkv_kernel(*ins, *segs, dk_ref.at[0, 0], dv_ref.at[0, 0], *scratch,
-                        scale=scale, causal=causal, bq=bq, bkv=bkv, window=window, nq=sq // bq)
+                        scale=scale, causal=causal, bq=bq, bkv=bkv, window=window, nq=sq // bq, bd=bd)
 
     dk, dv = _pallas_call(
         dkv_kernel,
-        name=_named("flash_attention_bwd_dkv", window),
+        name=_named("flash_attention_bwd_dkv", window, bd),
         grid=(b, hkv, skv // bkv, _q_spans(sq, skv, t, window)),
         in_specs=in_specs2,
         out_specs=[kv_spec2, kv_spec2],
@@ -950,23 +1085,23 @@ rope_to_heads.defvjp(_rope_fwd_rule, _rope_bwd_rule)
 NARROW_HEAD = 64
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_bhsd(q, k, v, seg, scale, causal, bq, bkv, window):
-    out, _ = _fwd(q, k, v, seg, scale, causal, bq, bkv, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+def _flash_bhsd(q, k, v, seg, scale, causal, bq, bkv, window, bd):
+    out, _ = _fwd(q, k, v, seg, scale, causal, bq, bkv, window, bd)
     return out
 
 
-def _flash_fwd_rule(q, k, v, seg, scale, causal, bq, bkv, window):
+def _flash_fwd_rule(q, k, v, seg, scale, causal, bq, bkv, window, bd):
     # the kernel's two results carry `FLASH_NAMES`: a policy that keeps both leaves nothing
     # in a rematerialised layer that reads the kernel, and JAX drops its second run there
     out, lse = (_named_bits(x, name)
-                for x, name in zip(_fwd(q, k, v, seg, scale, causal, bq, bkv, window), FLASH_NAMES))
+                for x, name in zip(_fwd(q, k, v, seg, scale, causal, bq, bkv, window, bd), FLASH_NAMES))
     return out, (q, k, v, seg, out, lse)
 
 
-def _flash_bwd_rule(scale, causal, bq, bkv, window, res, dout):
+def _flash_bwd_rule(scale, causal, bq, bkv, window, bd, res, dout):
     q, k, v, seg, out, lse = res
-    dq, dk, dv = _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window)
+    dq, dk, dv = _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window, bd)
     return dq, dk, dv, None
 
 
@@ -995,11 +1130,14 @@ def flash_attention(
     block_kv: int = BLOCK_KV,
     rope: Optional[tuple] = None,  # (positions [B or 1, S], theta): q and k come un-rotated
     window: Optional[int] = None,  # key j is kept for query i where 0 <= i - j < window
+    block_diffusion: Optional[int] = None,  # the row is [noised ; clean], in blocks of this many positions
 ) -> jax.Array:
     """BSHD flash attention. Sq must equal Skv when segment_ids are used, and with
     `rope`: then the rotate kernel runs in front of the flash kernels (`rope_to_heads`).
     `window` (causal, one sequence) keeps the last `window` keys a query, its own among
-    them; one no shorter than the sequence is no window.
+    them; one no shorter than the sequence is no window. `block_diffusion` (not `causal`, one
+    row of two halves) keeps what the block-diffusion mask keeps (`BlockDiffusion`); its
+    kernels carry `_bd` behind their names.
 
     Heads 64 wide (`NARROW_HEAD`) run the same three kernels, under the same names, on
     q, k and v padded with zero lanes to 128: the scores do not see zeros in q and k, the
@@ -1020,6 +1158,9 @@ def flash_attention(
     qt, kt = (_heads_major(q), _heads_major(k)) if rope is None else rope_to_heads(q, k, *rope)
     vt = _heads_major(v)
     seg = None if segment_ids is None else _segment_lanes(segment_ids, q.shape[1])
+    bd = _block_diffusion(block_diffusion, q.shape[1], k.shape[1], block_q, block_kv, causal, window, segment_ids)
+    if bd is not None:  # a half is whole tiles
+        block_q, block_kv = min(block_q, bd.half), min(block_kv, bd.half)
     out = _flash_bhsd(qt, kt, vt, seg, scale, causal, block_q, block_kv,
-                      _band(window, q.shape[1], k.shape[1], causal))
+                      _band(window, q.shape[1], k.shape[1], causal), bd)
     return _heads_major(out)[..., :d]

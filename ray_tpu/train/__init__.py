@@ -54,6 +54,7 @@ from .session import (  # noqa: F401
     step_phase,
 )
 from .step import TrainState, init_state, make_optimizer, make_train_step  # noqa: F401
+from .diffusion import block_diffusion_noise  # noqa: F401
 from . import grad_sync  # noqa: F401
 from .grad_sync import GradSyncConfig  # noqa: F401
 from . import mpmd_pipeline  # noqa: F401

@@ -78,6 +78,10 @@ class Family:
     made_up: Callable  # fn(flops, config, model) -> (result, [(reader, metric file or args, ctx overrides, value or None)])
     metrics: frozenset  # what the manifest reports for the cell
     own_metrics: Tuple[str, ...]  # those that came with the family: a file each
+    # the batch the family's objective takes, made of seeded token ids [B, T]: fn(cfg, tokens) -> what
+    # `llama.loss_fn` and the reference's `loss` are handed (None: the ids themselves, next-token prediction
+    # over T - 1 positions; a family of another objective keeps T - 1 positions too)
+    batch_of: Optional[Callable] = None
 
     @property
     def ref(self):
@@ -85,6 +89,10 @@ class Family:
 
     def cell_config(self):
         return cell_config(self.config)
+
+    def batch_for(self, cfg, shape=(2, 41), seed=1):
+        t = tokens(cfg, shape, seed)
+        return t if self.batch_of is None else self.batch_of(cfg, t)
 
 
 def cell_config(name, directory="configs"):
@@ -160,10 +168,15 @@ def tokens(cfg, shape=(2, 41), seed=1):
     return jax.random.randint(jax.random.PRNGKey(seed), shape, 0, cfg.vocab_size)
 
 
+def as_batch(t):
+    """What `llama.loss_fn` takes: token ids as they are, or a batch a family made (`Family.batch_for`)."""
+    return t if isinstance(t, dict) else {"tokens": t}
+
+
 @functools.partial(jax.jit, static_argnums=2)
 def system(p, t, cfg):
     """((loss, metrics), gradients) of the system, as one program."""
-    return jax.value_and_grad(llama.loss_fn, has_aux=True)(p, {"tokens": t}, cfg)
+    return jax.value_and_grad(llama.loss_fn, has_aux=True)(p, as_batch(t), cfg)
 
 
 def reference(ref, cfg, dtype=jnp.float32, parts=False):
@@ -244,7 +257,7 @@ def test_loss_and_every_gradient_match_the_reference(family, case):
     _, cfg, periods = case
     if cfg.layer_pattern:
         assert llama.pattern_period(cfg.layer_pattern)[1] == periods
-    p, t = params(cfg, family.unsettle), tokens(cfg, (family.batch, 41))
+    p, t = params(cfg, family.unsettle), family.batch_for(cfg, (family.batch, 41))
     assert ("mtp" in p) == bool(cfg.mtp_depth) and ("lm_head" in p) != cfg.tie_embeddings
     (loss, m), grads = system(p, t, cfg)
     (r_loss, parts), r_grads = reference(family.ref, cfg, parts=True)(p, t, None)
@@ -277,9 +290,9 @@ def test_bfloat16_activations_err_as_the_rounded_reference_does(family):
     against the float32 reference, loss and every leaf's gradient, in multiples of the
     error the same plain reference makes in bfloat16, on the experts the system chose."""
     cfg = dataclasses.replace(family.tiny, dtype="bfloat16")
-    p, t = params(cfg, family.unsettle), tokens(cfg, (2, 65))
+    p, t = params(cfg, family.unsettle), family.batch_for(cfg, (2, 65))
     (loss, m), grads = system(p, t, cfg)
-    chosen = [np.asarray(c).reshape(2, 64, -1) for c in m["experts_chosen"]]
+    chosen = [np.asarray(c).reshape(2, -1, c.shape[-1]) for c in m["experts_chosen"]]  # 64 rows a sequence, or 2 x 64
     exact, e_grads = reference(family.ref, cfg)(p, t, chosen)
     coarse, c_grads = reference(family.ref, cfg, jnp.bfloat16)(p, t, chosen)
     assert abs(float(loss - exact)) < 3 * abs(float(coarse - exact)) + 1e-3 * float(exact)
@@ -297,7 +310,7 @@ def test_the_reference_and_the_benchmarks_copy_agree(family):
     with open(ref.__file__) as a, open(copy.__file__) as b:
         assert a.read() == b.read()
     cfg = dataclasses.replace(family.tiny, experts_held=(1, 2))
-    p, t = params(cfg, family.unsettle), tokens(cfg)
+    p, t = params(cfg, family.unsettle), family.batch_for(cfg)
     mine, theirs = (jax.jit(lambda p, t, fn=module.position_losses: fn(p, t, model_of(cfg)))(p, t)
                     for module in (ref, copy))
     assert len(jax.tree.leaves(mine)) == len(jax.tree.leaves(theirs)) > 3
@@ -309,12 +322,13 @@ def test_the_coarse_reference_is_the_same_code_rounded(family):
     """bfloat16: the yardstick. Near the float32 reference, not equal to it; the decays'
     own leaves stay float32."""
     cfg, ref = family.tiny, family.ref
-    p, t = params(cfg, family.unsettle), tokens(cfg)
+    p, t = params(cfg, family.unsettle), family.batch_for(cfg)
     exact, coarse = (jax.jit(lambda p, t, dtype=dtype: ref.loss(p, t, model_of(cfg), dtype))(p, t)
                      for dtype in (jnp.float32, jnp.bfloat16))
     assert 1e-6 < abs(float(coarse - exact)) / float(exact) < 2e-2
     assert frozenset(getattr(ref, "FLOAT32_LEAVES", ())) == family.float32_leaves
-    assert jax.eval_shape(lambda: ref.next_token_losses(p, t, model_of(cfg))).shape == (2, 40)
+    one_a_position = getattr(ref, "next_token_losses", lambda *a: ref.position_losses(*a)[0])
+    assert jax.eval_shape(lambda: one_a_position(p, t, model_of(cfg))).shape == (2, 40)
 
 
 def test_packed_documents_and_a_cache_are_refused_by_name(family):
@@ -323,8 +337,13 @@ def test_packed_documents_and_a_cache_are_refused_by_name(family):
     if family.recurrent:  # (refused while the program is traced: nothing has to run)
         with pytest.raises(NotImplementedError, match=f"{family.recurrent} layer over packed documents"):
             jax.eval_shape(lambda p: llama.loss_fn(p, {"tokens": t, "segment_ids": jnp.ones_like(t)}, cfg), p)
-    with pytest.raises(NotImplementedError, match="KV cache over layers of more than one kind"):
-        jax.eval_shape(lambda p: llama.forward(p, t, cfg, cache=llama.init_kv_cache(cfg, 2, 64)), p)
+    if cfg.diffusion_block:  # (the objective's own refusals: its row is doubled, its batch carries the noise)
+        packed = {**family.batch_for(cfg, (2, 33)), "segment_ids": jnp.ones((2, 32))}
+        with pytest.raises(NotImplementedError, match="block-diffusion objective over packed documents"):
+            jax.eval_shape(lambda p: llama.loss_fn(p, packed, cfg), p)
+    refused = "block-diffusion attention .* under a KV cache" if cfg.diffusion_block else "KV cache over layers of more than one kind"
+    with pytest.raises(NotImplementedError, match=refused):
+        jax.eval_shape(lambda p: llama.forward(p, t[:, :32], cfg, cache=llama.init_kv_cache(cfg, 2, 64)), p)
 
 
 # ------------------------------------------------------------------- the shares
@@ -464,8 +483,8 @@ def first_step(family):
     with jax.default_matmul_precision("highest"):
         tx = make_optimizer(learning_rate=1e-3, warmup_steps=1, total_steps=10)
         state = jax.jit(lambda key: init_state(key, cfg, tx))(jax.random.PRNGKey(0))
-        t = tokens(cfg, (2, 33))
-        after, m = make_train_step(cfg, tx, donate=False)(state, {"tokens": t})
+        t = family.batch_for(cfg, (2, 33))
+        after, m = make_train_step(cfg, tx, donate=False)(state, as_batch(t))
     return state, after, m, tx, t
 
 
@@ -473,13 +492,16 @@ def test_the_bias_moves_by_the_balance_rule_a_row_an_expert_part_in_pattern_orde
     cfg = family.tiny
     state, after, m, _, _ = first_step
     load = np.asarray(m["expert_load"])
-    assert load.shape == (after.params["layers"]["router_bias"].shape[0] + cfg.mtp_depth, cfg.n_experts)
-    assert (load.sum(-1) == 2 * 32 * cfg.moe_top_k).all()
+    assert load.shape == (after.params["layers"]["router"].shape[0] + cfg.mtp_depth, cfg.n_experts)
+    rows = 2 * 32 * (2 if cfg.diffusion_block else 1)  # (a doubled row: both halves choose)
+    assert (load.sum(-1) == rows * cfg.moe_top_k).all() and m["experts_chosen"].shape[1] == rows
+    assert all(("router_bias" in stack) == cfg.moe_select_bias
+               for stack in after.params.values() if isinstance(stack, dict) and "router" in stack)
     rule = lambda b, rows: b + cfg.moe_bias_update_rate * np.sign(rows.mean(-1, keepdims=True) - rows)  # noqa: E731
     n = load.shape[0] - cfg.mtp_depth  # the pattern's expert parts, then the MTP modules'
     assert ("mtp" in after.params) == bool(cfg.mtp_depth)
     for name, rows in (("layers", load[:n]), ("mtp", load[n:])):
-        if name in after.params:
+        if cfg.moe_select_bias and name in after.params:  # (a family without the bias has nothing for the rule to move)
             before = np.asarray(state.params[name]["router_bias"])
             np.testing.assert_allclose(after.params[name]["router_bias"], rule(before, rows), atol=1e-7)
             assert np.abs(rule(before, rows) - before).max() > 0
@@ -496,11 +518,11 @@ def test_the_compiled_step_names_the_mixers_scopes(family):
     from benchmarks.lib import scope_seconds
 
     cfg = family.tiny
-    p, t = params(cfg, family.unsettle), tokens(cfg, (1, 33))
+    p, t = params(cfg, family.unsettle), family.batch_for(cfg, (1, 33))
 
     def loss(p):  # as train/step.py names the model: the outermost scope is the transformations'
         with jax.named_scope("model"):
-            return llama.loss_fn(p, {"tokens": t}, cfg)[0]
+            return llama.loss_fn(p, as_batch(t), cfg)[0]
 
     text = jax.jit(jax.grad(loss)).lower(p).compile().as_text()
     by_instruction = scope_seconds.scopes_by_instruction(text)
@@ -517,7 +539,8 @@ def test_configuration_files_program_group_equals_its_published_keys(family):
     for published, field in family.pairs.items():
         assert getattr(cfg, field) == config[published], (published, field)
     assert sorted(config["reduced"]) == sorted(config["published"])
-    assert cfg.moe_dropless and cfg.moe_select_bias and cfg.moe_scoring == "sigmoid"
+    assert cfg.moe_dropless and (cfg.moe_scoring, cfg.moe_select_bias) == (family.tiny.moe_scoring, family.tiny.moe_select_bias)
+    assert (cfg.moe_scoring, cfg.moe_select_bias) in (("sigmoid", True), ("softmax", False))
     assert abs(cfg.n_params - family.cell_params) < 0.1e6  # the issue's arithmetic
     shapes = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
     held = sum(int(np.prod(a.shape)) for path, a in jax.tree_util.tree_flatten_with_path(shapes)[0]
@@ -626,7 +649,8 @@ def test_the_new_cell_rehearses_on_the_cpu(family):
     assert all(window["checks"].values()), window["checks"]
     assert {"selection_agrees_beyond_margin", "step_losses_match_reference",
             "step_gradients_match_reference", "step_update_follows_its_moments",
-            "router_bias_moved_by_the_rule"} <= set(window["checks"])
+            "router_bias_moved_by_the_rule" if family.tiny.moe_select_bias else "masked_tokens_are_the_batchs",
+            } <= set(window["checks"])
     assert window["parity"]["gradient"]["rows"] > rows
     assert set(window["parity"]["losses"]) == losses
     assert window["parity"]["positions"] == positions
@@ -639,4 +663,4 @@ def test_the_new_cell_rehearses_on_the_cpu(family):
 
 __all__ = [name for name in dir() if name.startswith("test_")] + [
     "Family", "pytest_generate_tests", "family", "highest", "_highest", "first_step", "ROOT", "cell_config", "model_of", "seeded", "params",
-    "tokens", "system", "reference", "leaves_match", "config_from", "published_keys", "head_shares", "expert_shares"]
+    "tokens", "system", "as_batch", "reference", "leaves_match", "config_from", "published_keys", "head_shares", "expert_shares"]

@@ -293,10 +293,17 @@ def test_every_remat_policy_weighs_what_the_forward_pass_weighed(family):
                                        err_msg=f"{policy} {jax.tree_util.keystr(path)}")
 
 
-def test_route_takes_sigmoid_scores_only():
-    with pytest.raises(NotImplementedError, match="sigmoid"):
-        moe.route(jnp.zeros((4, GLM.d_model)), jnp.zeros((GLM.d_model, GLM.n_experts)), None,
-                  dataclasses.replace(GLM, moe_scoring="softmax"))
+def test_route_takes_sigmoid_or_softmax_scores_and_no_bias_beside_softmax():
+    """Softmax over all experts (PR 50; its own tests: tests/test_family_sdar_moe.py) chooses by the
+    scores alone; any other scoring is refused by name."""
+    x, w = jnp.zeros((4, GLM.d_model)), jnp.zeros((GLM.d_model, GLM.n_experts))
+    idx, gates = moe.route(x, w, None, dataclasses.replace(GLM, moe_scoring="softmax", moe_route_scale=1.0))
+    assert idx.shape == gates.shape == (4, GLM.moe_top_k)
+    np.testing.assert_allclose(gates, 1 / GLM.moe_top_k, rtol=1e-6)  # equal scores: equal gates, summing to one
+    with pytest.raises(NotImplementedError, match="softmax scores with a selection bias"):
+        moe.route(x, w, jnp.zeros((GLM.n_experts,)), dataclasses.replace(GLM, moe_scoring="softmax"))
+    with pytest.raises(NotImplementedError, match="sigmoid or softmax"):
+        moe.route(x, w, None, dataclasses.replace(GLM, moe_scoring="tanh"))
 
 
 # ------------------------------------------------- nemotron_h's: 22 of 512, latent relu2 experts

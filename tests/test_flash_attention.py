@@ -113,18 +113,31 @@ TILINGS = {
     "two-spans-noncausal-gqa2": (1, 256, 4, 2, 64, 64, 64, False, None, jnp.float32, 128 << 10),
     "bf16-two-spans-segments-gqa4": (1, 512, 8, 2, 128, 128, 128, True, (90, 300), jnp.bfloat16,
                                      256 << 10),
+    # the block-diffusion mask (a twelfth field: the block length; `s` is the doubled row [noised ;
+    # clean], a half of it whole tiles of whole blocks): a noised q tile walks its own noised kv
+    # tile and the clean ones before its last block, a clean one the clean ones up to itself
+    "bd4-4-tiles-a-half": (1, 512, 2, 2, 64, 64, 64, False, None, jnp.float32, None, 4),
+    "bd16-gqa8-w128": (1, 512, 8, 1, 128, 64, 64, False, None, jnp.float32, None, 16),
+    "bd4-unequal-tiles-gqa2": (1, 512, 4, 2, 64, 64, 128, False, None, jnp.float32, None, 4),
+    "bd4-wide-q-gqa2": (1, 512, 4, 2, 64, 128, 64, False, None, jnp.float32, None, 4),
+    "bd64-a-block-a-tile": (1, 512, 2, 1, 64, 64, 64, False, None, jnp.float32, None, 64),
+    "bd8-batch2-one-tile-a-half": (2, 256, 2, 2, 64, 128, 128, False, None, jnp.float32, None, 8),
+    "bd4-a-half-shorter-than-a-tile": (1, 128, 2, 1, 64, 512, 512, False, None, jnp.float32, None, 4),
+    "bd4-spans-of-2-gqa8": (1, 512, 8, 1, 64, 64, 64, False, None, jnp.float32, 128 << 10, 4),
+    "bd16-spans-of-4-and-1-gqa4": (1, 1024, 4, 1, 64, 64, 64, False, None, jnp.float32, 256 << 10, 16),
+    "bf16-bd4-the-chips-tiles-gqa8": (1, 2048, 8, 1, 128, 512, 512, False, None, jnp.bfloat16, None, 4),
 }
 
 
 @pytest.mark.parametrize("case", list(TILINGS))
 def test_fwd_and_grads_over_tilings(case, monkeypatch):
-    b, s, h, hkv, d, bq, bkv, causal, cuts, dtype, *budget = TILINGS[case]
+    b, s, h, hkv, d, bq, bkv, causal, cuts, dtype, budget, bd = (*TILINGS[case], None, None)[:12]
     if budget:
-        monkeypatch.setattr(fa, "SPAN_VMEM_BYTES", budget[0])
+        monkeypatch.setattr(fa, "SPAN_VMEM_BYTES", budget)
     t = fa._tiling(s, s, bq, bkv, d, jnp.dtype(dtype).itemsize, h // hkv)
     if budget:  # both sides' spans are whole tiles, and shorter than the sequence
         assert bkv <= t.kv_span < s and bq <= t.q_span < s, t
-    else:
+    elif case != "bf16-bd4-the-chips-tiles-gqa8":  # (the cell's own: K/V one span, a group of 8 query heads' Q/dO two)
         assert (t.kv_span, t.q_span) == (s, s), t
     q = _rand((b, s, h, d), 0, dtype)
     k, v = _rand((b, s, hkv, d), 1, dtype), _rand((b, s, hkv, d), 2, dtype)
@@ -133,13 +146,20 @@ def test_fwd_and_grads_over_tilings(case, monkeypatch):
 
     def run(fn, *xs, **kw):
         def loss(q, k, v):
-            o = fn(q, k, v, causal=causal, segment_ids=seg, **kw)
+            o = fn(q, k, v, causal=causal, segment_ids=seg, block_diffusion=bd, **kw)
             return jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32)), o
         (_, o), grads = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True))(*xs)  # one program, as its users run it
         return (o, *grads)
 
     got = run(flash_attention, q, k, v, block_q=bq, block_kv=bkv)
     want = run(attention_reference, *(x.astype(jnp.float32) for x in (q, k, v)))
+    if bd:  # the mask by hand, once: query r keeps key c as the objective states it
+        r, c = np.arange(s)[:, None], np.arange(s)[None, :]
+        blk = lambda x: (x % (s // 2)) // bd  # noqa: E731
+        kept = ((c >= s // 2) & (blk(c) < blk(r))) | (((c >= s // 2) == (r >= s // 2)) & (blk(c) == blk(r)))
+        from ray_tpu.ops.attention import block_diffusion_keep
+        np.testing.assert_array_equal(np.asarray(block_diffusion_keep(jnp.asarray(r), jnp.asarray(c), s // 2, bd)), kept)
+        assert kept.sum() == (s // 2) * (s // 2 + bd) and not kept[s // 2:, :s // 2].any()
     # bf16: inputs, p and ds reach the MXU with 8 bits of mantissa, and the results
     # are rounded to bf16; statistics and accumulators are f32 either way
     tol = 5e-3 if dtype == jnp.float32 else 3e-2
@@ -168,6 +188,61 @@ def test_tile_counts(s, causal, steps, computed, needed, dkv_steps):
     assert tile_counts(s, s, causal, 512, 512, n_rep=4, kernel="dkv") == (
         dkv_steps, 4 * computed, 4 * needed)
     assert steps == nq < nq * nq
+
+
+def test_tile_counts_under_the_block_diffusion_mask():
+    """The doubled row of the block-diffusion cell, [2 x 8192] in 512-row tiles, blocks of 4:
+    288 tiles a head each way (2 x 136 + 16) where the kept scores are 8192 x 8196 = 256.1
+    tiles' worth, the triangle over the doubled row computes 528 and a dense walk 1,024; K/V
+    are one span (a grid step a q tile), a group of 8 query heads' Q/dO spans of 1,024 rows."""
+    s = 16384
+    for kernel in ("fwd", "dq"):
+        assert tile_counts(s, s, False, 512, 512, kernel=kernel, block_diffusion=4) == (32, 288, 8192 * 8196 / 512**2)
+    assert tile_counts(s, s, False, 512, 512, n_rep=8, kernel="dkv", block_diffusion=4) == (
+        32 * 16, 8 * 288, 8 * 8192 * 8196 / 512**2)
+    assert tile_counts(s, s, True, 512, 512).tiles_computed == 528 and tile_counts(s, s, False, 512, 512).tiles_computed == 1024
+    with pytest.raises(ValueError, match="block_diffusion 3"):
+        tile_counts(s, s, False, 512, 512, block_diffusion=3)  # no power of two
+    with pytest.raises(ValueError, match="block_diffusion 4"):
+        tile_counts(s, s, True, 512, 512, block_diffusion=4)  # not beside `causal`
+    assert fa.supports(s, s, 128, block_diffusion=4) and not fa.supports(s, s, 128, block_diffusion=1024)
+    assert not fa.supports(1000, 1000, 128, block_diffusion=4) and fa.supports(96, 96, 128, block_diffusion=4)
+
+
+@pytest.mark.parametrize("half,bq,bkv,block,span", [
+    (8192, 512, 512, 4, 32), (2048, 512, 512, 512, 8), (1024, 128, 256, 8, 2), (1024, 256, 128, 128, 4),
+    (512, 64, 64, 64, 1), (512, 64, 64, 2, 3)])
+def test_the_block_diffusion_walk_computes_every_needed_tile_once(half, bq, bkv, block, span):
+    """The kernels' own ranges, steps and index maps by brute force over the doubled row: a
+    tile is walked iff the mask keeps one of its scores, once, by the grid step whose span
+    holds it; a step whose span holds none of its tile's ranges fetches the nearest span that
+    does. Forward and dQ (a q tile's kv tiles, spans of `span` kv tiles) and dK/dV (a kv
+    tile's q tiles, the same spans of q tiles)."""
+    from ray_tpu.ops.attention import block_diffusion_keep
+
+    bd = fa.BlockDiffusion(block, half)
+    r, c = np.arange(2 * half)[:, None], np.arange(2 * half)[None, :]
+    kept = np.asarray(block_diffusion_keep(jnp.asarray(r), jnp.asarray(c), half, block))
+    nq, nk = 2 * half // bq, 2 * half // bkv
+    needed = kept.reshape(nq, bq, nk, bkv).any(axis=(1, 3))  # [q tile, kv tile]
+    assert tile_counts(2 * half, 2 * half, False, bq, bkv, block_diffusion=block).tiles_computed == needed.sum()
+    assert tile_counts(2 * half, 2 * half, False, bq, bkv, block_diffusion=block).tiles_needed == kept.sum() / (bq * bkv)
+    for major, n_own, n_other in (("q", nq, nk), ("kv", nk, nq)):
+        if n_other % span:
+            continue
+        walked = np.zeros((n_own, n_other), int)
+        for own in range(n_own):
+            ranges = fa._bd_kv_ranges(own, bq, bkv, bd) if major == "q" else fa._bd_q_ranges(own, bq, bkv, bd)
+            spans_used = {t // span for lo, hi in ranges for t in range(lo, hi)}
+            first = (ranges[0] if major == "q" else tuple(a + b for a, b in zip(ranges[0], ranges[1])))
+            for step in range(n_other // span):
+                for lo, hi in ranges:
+                    for t in range(int(np.clip(lo - step * span, 0, span)), int(np.clip(hi - step * span, 0, span))):
+                        walked[own, step * span + t] += 1
+                fetched = int(fa._nearest_span(step, span, first, ranges[-1]))
+                assert fetched in spans_used and (fetched == step) == (step in spans_used), (major, own, step, fetched)
+        np.testing.assert_array_equal(walked, needed if major == "q" else needed.T)
+
 
 
 def test_span_is_derived():

@@ -536,6 +536,60 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
         assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
 
 
+def test_the_block_diffusion_cells_step_compiles_inside_its_memory_and_walks_288_tiles_a_head(one_chip, on_tpu):
+    """The whole step of `sdar30b-train-ep8share-s8192` as its configuration file states it (five
+    layers; the batch a loader makes: tokens, masked, p_mask), compiled for the described chip:
+    [1, 16384] rows through the layers under the block-diffusion mask. The three kernels carry
+    `_bd` behind their names, which the accepted kernel metrics and the cell's own roofline
+    metric find; the forward kernel runs once a layer (its `out` and logsumexp kept under
+    `full`); three router products a layer and the pick's loops, nothing made again by XLA, no
+    attention on an XLA path; 15.18 of 15.75 GB (PR 50: six layers were 17.31 and do not fit).
+    The grids: a step a q tile forward and dQ (K/V of the doubled row are one span), 16 spans of
+    a group's Q/dO a kv tile; 288 tiles a head each way by `tile_counts`."""
+    import importlib
+
+    from ray_tpu.models import llama
+    from ray_tpu.ops import flash_attention as fa
+    from ray_tpu.train import make_optimizer, make_train_step
+    from ray_tpu.train.step import TrainState
+
+    attention_ops = importlib.import_module("ray_tpu.ops.attention")
+    cfg, file = _cell_file("sdar-30b-a3b-train-ep8")
+    trainer = file["trainer"]
+    b, n = trainer["batch"], trainer["seq"]
+    assert cfg.remat and cfg.remat_policy == "full" and (cfg.diffusion_block, cfg.n_layers, b, n) == (4, 5, 1, 8192)
+    fallbacks = attention_ops.xla_fallback_count
+    tx = make_optimizer(**trainer["optimizer"])
+    params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
+    state = TrainState(step=_scalar(one_chip), params=params, opt_state=_shapes(jax.eval_shape(tx.init, params), one_chip))
+    batch = {"tokens": jax.ShapeDtypeStruct((b, n), jnp.int32, sharding=one_chip),
+             "masked": jax.ShapeDtypeStruct((b, n), jnp.bool_, sharding=one_chip),
+             "p_mask": jax.ShapeDtypeStruct((b,), jnp.float32, sharding=one_chip)}
+    step = make_train_step(cfg, tx)
+    compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    for path, count in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 2), ("train_attn_bd_roofline_pct", 3)):
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
+            rx = re.compile(json.load(f)["args"]["pattern"])
+        kernels = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]
+        assert len(kernels) == count and all("_bd" in ln.split(" = ")[0] for ln in kernels), (path, kernels)
+    assert _kernel_calls(text, "flash_attention_fwd_bd") == _kernel_calls(text, "flash_attention_bwd_dq_bd") == (1, 0)
+    assert _kernel_calls(text, "flash_attention_bwd_dkv_bd") == (1, 0) and _kernel_calls(text, "flash_attention_fwd") == (0, 0)
+    assert len(_instructions(text, "convolution", "moe_router")) == 3 and len(_instructions(text, "while", "moe_router")) == 2
+    assert not _xla_remats(text) and attention_ops.xla_fallback_count == fallbacks
+    memory = compiled.memory_analysis()
+    assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7 and cfg.n_params == 550984960
+    assert memory.temp_size_in_bytes < (8.57 + 0.15) * 1e9
+    assert 0.25 * 15.75e9 < memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
+    t = fa._tiling(2 * n, 2 * n, 512, 512, 128, 2, 8)
+    assert (t.kv_span, t.q_span) == (16384, 1024)
+    grids = _pallas_grids(jax.make_jaxpr(step._jitted)(state, batch).jaxpr)
+    assert (b, 32, 32, 1) in grids and (b, 4, 32, 16) in grids
+    for kernel, heads in (("fwd", 1), ("dq", 1), ("dkv", 8)):
+        counts = fa.tile_counts(2 * n, 2 * n, False, 512, 512, n_rep=heads, kernel=kernel, block_diffusion=4)
+        assert counts.tiles_computed == heads * 288 and round(counts.tiles_needed / heads, 1) == 256.1
+
+
 @pytest.mark.parametrize("config,forward", [
     ("glm-4.7-flash-train-ep8", (1, 0)),  # `full`, [1, 8192, 20 / 20, 256] behind the latent projections
     ("lfm2-24b-a2b-train-ep8", (1, 0)),   # `full`, [4, 8192, 32 / 8, 64] on padded lanes
