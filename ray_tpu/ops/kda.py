@@ -45,8 +45,9 @@ of ops/kda_overlaps.py wherever they tile it (`kda_overlaps.supports`: channels 
 factors live and die in fast memory in both passes and the backward pass keeps q, k and G
 alone (kk and b carry no name for the remat policies: kept they would spare a rematerialised
 layer the forward kernel's second run, 1.1 ms, for 201 MB a step, which takes the
-Solar-Open2 step's temporaries from 4.63 to 4.83 GB, over what its compile test allows:
-PERF.md section 6, PR 38). Any other shape (a width of 16, a chunk of 16: tier-1's) runs
+Solar-Open2 step's temporaries from 4.79 to 5.00 GB, over what its compile test allows; the
+second half's kernels freed 3 MB of them, not 200: PERF.md section 6, PRs 38 and 51). Any
+other shape (a width of 16, a chunk of 16: tier-1's) runs
 `_decayed_overlaps`, the same sums in `jax.numpy` differentiated by JAX, which is also what
 the kernels are tested against; there every chunk's differences are alive at once, which
 only a small shape affords. The shape alone chooses: no flag, and nothing to set. Under an ambient mesh
@@ -66,10 +67,25 @@ two products (plain differentiation solves two more systems), and it carries a n
 head, so that a rematerialised layer does not substitute again (on a v5e 5.2 ms a layer
 and pass at blocks of 128, 1.0 at 32: PERF.md section 6, PR 37).
 
-Everything outside the overlaps (the inverse, the chunks' four matrices, the join) is plain
-`jax.numpy`, float32 operands at the highest matrix precision, differentiated by JAX; the
-kernels' products run at the same precision and no operand anywhere is narrower than
-float32. G is summed once a scan and handed to both halves. PERF.md section 5 has the trace.
+The chunks' four matrices P, O0, M, N are made the same way: `chunk_parts` hands every chunk
+to the two Pallas kernels of ops/kda_parts.py wherever `takes_kernels` says so (the same rule
+routes both halves), a chunk of a few heads a grid step, and then exp G, beta [K exp G | V],
+[W | U0] = (I + A)^-1 [..] and Kend live and die in fast memory: the forward kernel reads q,
+k, v, G, beta, (I + A)^-1 and B and writes P, O0, M, N alone; the backward kernel keeps
+nothing but those seven inputs, makes [W | U0] again and writes their seven gradients (10 and
+16 blocks of [Q, Q] a chunk and head, 1.3 and 2.1 MB of VMEM with the pipeline's second
+buffers at 128). Any other shape, and any shape under a mesh, runs `_chunk_parts`: the same
+algebra in `jax.numpy`, differentiated by JAX, every chunk's [Q, K + V] intermediates
+through HBM; it is what the kernels are tested against. Both halves' kernels read q, k and v
+where the mixer wrote them, [B, T, H x K] with a position's heads side by side, and write
+their gradients there; only the `jax.numpy` forms take copies with the chunks leading.
+
+What is left outside the kernels is plain `jax.numpy`, differentiated by JAX: the running sum
+G (made once a scan in the mixer's order of the positions, transposed once and handed to both
+halves), the strict mask and beta over the keys' overlaps, the inverse (the compiler's
+substitution, above), the join and the output product P S_0 + O0. Every operand anywhere is
+float32 and every product, XLA's or a kernel's, runs at the highest matrix precision.
+PERF.md section 5 has the trace.
 """
 import jax
 import jax.numpy as jnp
@@ -77,7 +93,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.parallel.sharding import partitioned_by_gspmd
 
-from . import kda_overlaps
+from . import kda_overlaps, kda_parts
 
 _HI = jax.lax.Precision.HIGHEST
 _SUB = 32  # positions of a sub-chunk: the differences are [_SUB, _SUB, K] a sub-chunk
@@ -94,12 +110,19 @@ def takes_kernels(size: int, width: int) -> bool:
     return kda_overlaps.supports(size, _sub(size), width) and not partitioned_by_gspmd()
 
 
+def _lead(x):
+    """[B, chunks, Q, H, ...] (the positions in the mixer's order, cut in chunks) -> [chunks, B, H, Q, ...]."""
+    return x.transpose(1, 0, 3, 2, *range(4, x.ndim))
+
+
 def overlaps(q, k, run):
-    """`_decayed_overlaps`' two sums, by the Pallas kernels where they tile the shape, else by it."""
-    size, width = k.shape[-2:]
+    """`_decayed_overlaps`' two sums [chunks, B, H, Q, Q] from q, k [B, chunks, Q, H, K] and run
+    [chunks, B, H, Q, K]: by the Pallas kernels where they tile the shape (they read q and k out of
+    the mixer's order in place), else by it."""
+    size, width = run.shape[-2:]
     if takes_kernels(size, width):
         return kda_overlaps.overlaps(q, k, run, _sub(size))
-    return _decayed_overlaps(q, k, run)
+    return _decayed_overlaps(_lead(q), _lead(k), run)
 
 
 def _decayed_overlaps(q, k, run):
@@ -172,20 +195,28 @@ _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
 def _overlaps(q, k, run, beta):
-    """Every chunk at once, every leading axis a batch -> (A, B) [..., Q, Q] of the module's
-    docstring."""
-    size = q.shape[-2]
+    """Every chunk at once: q, k [B, chunks, Q, H, K], run [chunks, B, H, Q, K], beta [chunks, B, H, Q]
+    -> (A, B) [chunks, B, H, Q, Q] of the module's docstring."""
+    size = run.shape[-2]
     kk, b = overlaps(q, k, run)
     return jnp.where(jnp.tril(jnp.ones((size, size), bool), -1), kk * beta[..., :, None], 0.0), b
 
 
-def _chunk_parts(q, k, v, run, beta, a, b):
+def chunk_parts(q, k, v, run, beta, inverse, b):
+    """`_chunk_parts`' four matrices [chunks, B, H, ., .] from q, k, v [B, chunks, Q, H, K] and run,
+    beta, inverse, b [chunks, B, H, ...]: by the Pallas kernels where they tile the shape, else by it."""
+    size, width = run.shape[-2:]
+    if takes_kernels(size, width):
+        return kda_parts.parts(q, k, v, run, beta, inverse, b)
+    return _chunk_parts(_lead(q), _lead(k), _lead(v), run, beta, inverse, b)
+
+
+def _chunk_parts(q, k, v, run, beta, inverse, b):
     """Every chunk at once, every leading axis a batch: q, k, v, run (G) [..., Q, K], beta
-    [..., Q], a and b [..., Q, Q] -> P [..., Q, K], O0 [..., Q, V], M [..., K, K], N
-    [..., K, V] of the module's docstring."""
+    [..., Q], inverse ((I + A)^-1) and b [..., Q, Q] -> P [..., Q, K], O0 [..., Q, V], M
+    [..., K, K], N [..., K, V] of the module's docstring."""
     from_start = jnp.exp(run)
     rhs = jnp.concatenate([k * from_start, v], -1) * beta[..., None]
-    inverse = _unit_lower_inverse(a)
     solved = jnp.einsum("...ts,...sc->...tc", inverse, rhs, precision=_HI)
     w, u0 = solved[..., :k.shape[-1]], solved[..., k.shape[-1]:]
     k_end = k * jnp.exp(run[..., -1:, :] - run)
@@ -208,13 +239,16 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     nc = t // chunk
     f32 = jnp.float32
 
-    def chunks(x):  # [B, T, H, ...] -> [chunks, B, H, Q, ...]
-        x = x.astype(f32).reshape(bsz, nc, chunk, h, *x.shape[3:])
-        return x.transpose(1, 0, 3, 2, *range(4, x.ndim))
+    def split(x):  # [B, T, H, ...] -> [B, chunks, Q, H, ...]
+        return x.astype(f32).reshape(bsz, nc, chunk, h, *x.shape[3:])
 
-    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
-    run = jnp.cumsum(g, axis=-2)  # G, [chunks, B, H, Q, K]: both halves read it
-    p, o0, m, n = _chunk_parts(q, k, v, run, beta, *_overlaps(q, k, run, beta))
+    # q, k, v stay in the mixer's order, a position's heads side by side: the kernels read a chunk of a
+    # head out of it, and only `_decayed_overlaps` and `_chunk_parts` take [chunks, B, H, Q, K] copies.
+    # G is summed in that order too (a head's channels a register a position) and then leads with the
+    # chunks, as beta and what the halves hand on do
+    q, k, v, run, beta = split(q), split(k), split(v), _lead(jnp.cumsum(split(g), axis=2)), _lead(split(beta))
+    a, b = _overlaps(q, k, run, beta)
+    p, o0, m, n = chunk_parts(q, k, v, run, beta, _unit_lower_inverse(a), b)
 
     def join(state, mn):  # the state each chunk starts from
         m_c, n_c = mn

@@ -1,10 +1,10 @@
 """A chunk's decayed overlaps (ops/kda.py's A before beta and B) as two Pallas TPU kernels
-behind one `jax.custom_vjp`: for q, k and G = the running sum of the log decays inside the
-chunk, each [..., Q, K] float32,
+behind one `jax.custom_vjp`: for a chunk of a head's q, k and G = the running sum of the log
+decays inside the chunk, each [Q, K] float32,
 
     kk_ts = sum_c k_tc k_sc exp(G_tc - G_sc)      b_ts = sum_c q_tc k_sc exp(G_tc - G_sc)
 
-for s <= t and 0 elsewhere, both [..., Q, Q]: `kda._decayed_overlaps` letter for letter,
+for s <= t and 0 elsewhere, both [Q, Q]: `kda._decayed_overlaps` letter for letter,
 the same sub-chunks and the same two kinds of pairs (ops/kda.py's docstring has the algebra
 and its bound). What differs is where the intermediates live: a grid step holds one chunk
 of one head, q, k, G and the results 64 KB each at 128 x 128, and the pairs' decays, the
@@ -30,8 +30,14 @@ depend on G_b), so it gets no gradient. Its sums run over the rows of a register
 or accumulate over the loop on s; the cotangents come a second time transposed (XLA's,
 [Q, Q]) so that no product contracts a left operand's rows.
 
+q and k are read where the mixer wrote them, [B, chunks x Q, H x K] with a position's heads
+side by side (`rows_block`), and dq, dk written there; G comes, and dG goes, with the chunks
+leading, [chunks, B, H, Q, K] (ops/kda_parts.py's docstring has why), as kk and b do.
+
 `supports` says which shapes the kernels tile; off a TPU they run in Pallas' interpreter
-(`flash_attention._interpret`'s rule).
+(`flash_attention._interpret`'s rule). The scan's second half, which reads b and the inverse
+made from kk, is two kernels of the same kind beside these (ops/kda_parts.py; `supports`
+routes both halves, through `kda.takes_kernels`).
 """
 import functools
 
@@ -176,38 +182,55 @@ def _bwd_kernel(q_ref, k_ref, g_ref, dkk_ref, db_ref, dkk_t_ref, db_t_ref, dq_re
     dk_ref[:] = as_row + as_column
 
 
-def _call(kernel, name: str, operands, out_widths, sub: int, scratch=()):
-    """One grid step a chunk and head: operands [N, Q, .] -> results [N, Q, w] for w in `out_widths`."""
-    n, size = operands[0].shape[:2]
+def rows_block(size: int, width: int, per: int, batch: int, chunks: int, heads: int):
+    """The block of an array in the mixer's own order of the positions, [B x chunks, Q, H x width]
+    (q as the convolution wrote it: a position's heads side by side), that step i of a grid over
+    n = (chunk, row of the batch, head) in `per` heads a step reads or writes: the chunk's Q
+    positions of those heads, rows of per x width lanes. No transposed copy of q, k or v is made for
+    the kernels, and none of their gradients."""
+    def at(i):
+        chunk_row, head = (i * per) // heads, (i * per) % heads
+        return (chunk_row % batch) * chunks + chunk_row // batch, 0, head // per
+
+    return pl.BlockSpec((None, size, per * width), at)
+
+
+def _call(kernel, name: str, rows, chunked, outs, sub: int, scratch=()):
+    """One grid step a chunk and head: `rows` [B, chunks, Q, H, K] through `rows_block`, `chunked`
+    [chunks, B, H, Q, w] in the grid's own order -> results of either kind, "rows" or a width w, by `outs`."""
+    batch, chunks, size, heads, width = rows[0].shape
+    n, in_rows = chunks * batch * heads, (batch * chunks, size, heads * width)
+    row = rows_block(size, width, 1, batch, chunks, heads)
     block = lambda w: pl.BlockSpec((None, size, w), lambda i: (i, 0, 0))  # noqa: E731
-    return pl.pallas_call(
+    results = pl.pallas_call(
         functools.partial(kernel, sub=sub), name=name, interpret=_fa._interpret(), grid=(n,),
-        in_specs=[block(x.shape[-1]) for x in operands], out_specs=[block(w) for w in out_widths],
-        out_shape=[jax.ShapeDtypeStruct((n, size, w), jnp.float32) for w in out_widths],
+        in_specs=[row] * len(rows) + [block(x.shape[-1]) for x in chunked],
+        out_specs=[row if w == "rows" else block(w) for w in outs],
+        out_shape=[jax.ShapeDtypeStruct(in_rows if w == "rows" else (n, size, w), jnp.float32) for w in outs],
         scratch_shapes=list(scratch),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)))(*operands)
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)))(
+            *[x.reshape(in_rows) for x in rows], *[x.reshape(n, size, x.shape[-1]) for x in chunked])
+    return [r.reshape(rows[0].shape) if w == "rows" else r.reshape(chunks, batch, heads, size, w)
+            for r, w in zip(results, outs)]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def overlaps(q, k, run, sub: int):
-    """(kk, b) [..., Q, Q] of the module's docstring from q, k, run [..., Q, K] float32."""
+    """(kk, b) [chunks, B, H, Q, Q] of the module's docstring from q, k [B, chunks, Q, H, K], the
+    positions in the mixer's order, and run [chunks, B, H, Q, K], float32."""
     return _overlaps_fwd(q, k, run, sub)[0]
 
 
 def _overlaps_fwd(q, k, run, sub):
-    *lead, size, width = q.shape
-    kk, b = _call(_fwd_kernel, "kda_overlaps_fwd", [x.reshape(-1, size, width) for x in (q, k, run)],
-                  (size, size), sub)
-    return (kk.reshape(*lead, size, size), b.reshape(*lead, size, size)), (q, k, run)
+    size = run.shape[-2]
+    return tuple(_call(_fwd_kernel, "kda_overlaps_fwd", (q, k), (run,), (size, size), sub)), (q, k, run)
 
 
 def _overlaps_bwd(sub, kept, cts):
     q, k, run = kept
-    *lead, size, width = q.shape
-    flat = [x.reshape(-1, size, width) for x in kept] + [x.reshape(-1, size, size) for x in cts]
-    dq, dk, dg = _call(_bwd_kernel, "kda_overlaps_bwd", flat + [x.mT for x in flat[3:]], (width,) * 3, sub,
-                       scratch=[pltpu.VMEM((size, width), jnp.float32)])
-    return tuple(x.reshape(q.shape) for x in (dq, dk, dg))
+    size, width = run.shape[-2:]
+    return tuple(_call(_bwd_kernel, "kda_overlaps_bwd", (q, k), [run, *cts, *(x.mT for x in cts)],
+                       ("rows", "rows", width), sub, scratch=[pltpu.VMEM((size, width), jnp.float32)]))
 
 
 overlaps.defvjp(_overlaps_fwd, _overlaps_bwd)

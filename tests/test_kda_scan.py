@@ -1,6 +1,7 @@
-"""The gated delta rule in chunks (ops/kda.py) and its two Pallas kernels (ops/kda_overlaps.py,
-in the interpreter here) against the recurrence a position at a time (the solar_open2
-reference's), at a small size on the CPU; and that the shape alone says which path runs."""
+"""The gated delta rule in chunks (ops/kda.py) and its four Pallas kernels (the overlaps',
+ops/kda_overlaps.py, and the chunks' four matrices', ops/kda_parts.py, in the interpreter
+here) against the recurrence a position at a time (the solar_open2 reference's), at a small
+size on the CPU; and that the shape alone says which path runs."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -93,17 +94,18 @@ def _pallas_calls(fn, *args):
 @pytest.mark.parametrize("chunk,sub,t", [(32, 8, 128), (32, 32, 64), (128, 32, 256)])
 @pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2"])
 def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, t, monkeypatch):
-    """At a width of 128 the overlaps go to the two Pallas kernels (ops/kda_overlaps.py, in
-    the interpreter here): the scan's output and the gradient of q, k, v, g and beta against
-    the same scan with `_decayed_overlaps` in their place and against the recurrence a
-    position at a time, 2 heads, 2 to 4 chunks, sub-chunks of 8 and 32."""
+    """At a width of 128 both halves go to their Pallas kernels (ops/kda_overlaps.py and
+    ops/kda_parts.py, in the interpreter here): the scan's output and the gradient of q, k, v,
+    g and beta against the same scan with `_decayed_overlaps` and `_chunk_parts` in their
+    place and against the recurrence a position at a time, 2 heads, 2 to 4 chunks, sub-chunks
+    of 8 and 32."""
     monkeypatch.setattr(kda_op, "_SUB", sub)
     args = _scan_inputs(t, regime, b=1, h=2, width=128)
     if regime == "below_minus_100_a_chunk":
         assert float(args[3].reshape(1, t // chunk, chunk, 2, 128).sum(2).max()) < -100
     scan = lambda *a: kda_op.kda_scan(*a, chunk)  # noqa: E731
-    assert sorted(set(_pallas_calls(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=(0, 1, 3)), *args))) == [
-        "kda_overlaps_bwd", "kda_overlaps_fwd"]
+    assert sorted(_pallas_calls(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=(0, 1, 3)), *args)) == [
+        "kda_overlaps_bwd", "kda_overlaps_fwd", "kda_parts_bwd", "kda_parts_fwd"]
     cot = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     got, mine = _value_and_pull(scan, args, cot)
     want, theirs = _value_and_pull(ref.recurrence, args, cot)
@@ -122,14 +124,52 @@ def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, 
         np.testing.assert_allclose(x, theirs, atol=3e-5 * top + 1e-6, err_msg=name)
 
 
+def _parts_inputs(t, regime, chunk, seed=0, b=1):
+    """What `kda_scan` hands the second half at `_scan_inputs`' draws: q, k, v [B, chunks, Q, H, K] (the
+    positions in the mixer's order), G [chunks, B, H, Q, K], beta [chunks, B, H, Q], T = (I + A)^-1 and b
+    [chunks, B, H, Q, Q]."""
+    q, k, v, g, beta = (x.reshape(b, t // chunk, chunk, *x.shape[2:])
+                        for x in _scan_inputs(t, regime, seed=seed, b=b, h=2, width=128))
+    run, beta = kda_op._lead(jnp.cumsum(g, 2)), kda_op._lead(beta)
+    a, b = kda_op._overlaps(q, k, run, beta)
+    return q, k, v, run, beta, kda_op._unit_lower_inverse(a), b
+
+
+@pytest.mark.parametrize("chunk,sub,t,b", [(32, 8, 128, 1), (32, 32, 64, 2), (128, 32, 256, 1)])
+@pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2"])
+def test_the_second_halfs_kernels_are_chunk_parts(regime, chunk, sub, t, b, monkeypatch):
+    """ops/kda_parts.py's two kernels (in the interpreter here) against `_chunk_parts` in
+    `jax.numpy` differentiated by JAX, at the shapes the test above walks (one of them with two
+    rows of a batch, which the kernels' blocks find in the mixer's order): P, O0, M, N and the
+    pull-back of a random cotangent to all seven inputs, q, k, v, G, beta, T and b, each to a
+    float32 rounding of its largest entry (or of the terms of order 1 it is a difference of,
+    where the decays leave all but nothing of it)."""
+    monkeypatch.setattr(kda_op, "_SUB", sub)
+    args = _parts_inputs(t, regime, chunk, b=b)
+    assert kda_op.takes_kernels(chunk, 128)
+    assert _pallas_calls(lambda *a: jax.vjp(kda_op.chunk_parts, *a)[1], *args) == ["kda_parts_fwd"]
+    plain = lambda *a: kda_op._chunk_parts(*(kda_op._lead(x) for x in a[:3]), *a[3:])  # noqa: E731
+    want = jax.eval_shape(plain, *args)
+    cot = tuple(jax.random.normal(key, x.shape) for key, x in zip(jax.random.split(jax.random.PRNGKey(9), 4), want))
+    got, mine = _value_and_pull(kda_op.chunk_parts, args, cot)
+    want, theirs = _value_and_pull(plain, args, cot)
+    for name, x, y in zip("P O0 M N".split(), got, want):
+        assert x.shape == y.shape and np.isfinite(np.asarray(x)).all(), name
+        np.testing.assert_allclose(x, y, atol=2e-6 * float(jnp.abs(y).max()) + 1e-30, err_msg=name)
+    for name, x, y in zip("q k v G beta T b".split(), mine, theirs):
+        assert x.shape == y.shape and np.isfinite(np.asarray(x)).all(), name
+        np.testing.assert_allclose(x, y, atol=5e-6 * float(jnp.abs(y).max()) + 1e-6, err_msg=name)
+
+
 def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
     """The case above on the kernel path (width 128, chunks of 32): decays of exp(-3000) a
     position, chunks whose sums fall to -8e4; a value and gradients that are finite say that
     no exponential saw a positive number (exp(3000) is inf, and 0 x inf poisons a sum), the
-    masks cut before it in both kernels, and the factors across sub-chunks are <= 1."""
+    masks cut before it in both of the overlaps' kernels, the factors across sub-chunks are
+    <= 1, and the second half's kernels take exp G and exp(G_Q - G) of sums that only fall."""
     q, k, v, _, beta = _scan_inputs(64, "mixed", b=1, h=2, width=128)
     g = jnp.full(q.shape, -3000.0).at[:, ::5].set(-1e-3)
-    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, 32), q, k, v, g, beta) == ["kda_overlaps_fwd"]
+    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, 32), q, k, v, g, beta) == ["kda_overlaps_fwd", "kda_parts_fwd"]
     want = ref.recurrence(q, k, v, g, beta)
     got, grads = jax.value_and_grad(lambda *a: jnp.sum(kda_op.kda_scan(*a, 32) * want), argnums=(0, 1, 2, 3, 4))(
         q, k, v, g, beta)
@@ -151,18 +191,20 @@ def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
 ])
 def test_the_shape_alone_says_which_path_runs(chunk, width, sub, kernels, monkeypatch):
     """`kda.takes_kernels` reads the chunk and the width (and the sub-chunk they imply); the
-    scan's jaxpr holds the forward kernel exactly where it says so. Nobody sets it."""
+    scan's jaxpr holds both halves' forward kernels exactly where it says so. Nobody sets it."""
     monkeypatch.setattr(kda_op, "_SUB", sub)
     assert kda_op.takes_kernels(chunk, width) == kernels
     assert kda_op.kda_overlaps.supports(chunk, kda_op._sub(chunk), width) == kernels
     args = _scan_inputs(2 * chunk, "mixed", b=1, h=2, width=width)
-    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, chunk), *args) == (["kda_overlaps_fwd"] if kernels else [])
+    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, chunk), *args) == (
+        ["kda_overlaps_fwd", "kda_parts_fwd"] if kernels else [])
 
 
 def test_under_a_mesh_that_shards_the_heads_the_jnp_path_runs():
     """GSPMD cannot partition a Mosaic call: with an axis of the ambient mesh still automatic
-    the scan runs `_decayed_overlaps` at the kernels' own shape, partitioned by the compiler,
-    and is the single-device scan's value; with every axis of size one the kernels run."""
+    the scan runs `_decayed_overlaps` and `_chunk_parts` at the kernels' own shape, partitioned
+    by the compiler, and is the single-device scan's value; with every axis of size one both
+    halves' kernels run."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
@@ -179,7 +221,7 @@ def test_under_a_mesh_that_shards_the_heads_the_jnp_path_runs():
         assert got.sharding.spec[2] == "tp"
     np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()))
     with use_mesh(build_mesh(MeshSpec(dp=1), jax.devices()[:1])):
-        assert kda_op.takes_kernels(32, 128) and _pallas_calls(scan, *args) == ["kda_overlaps_fwd"]
+        assert kda_op.takes_kernels(32, 128) and _pallas_calls(scan, *args) == ["kda_overlaps_fwd", "kda_parts_fwd"]
 
 
 def test_the_scan_asserts_whole_chunks():

@@ -450,7 +450,9 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
     # delta-rule scans whose triangular systems are inverted once each and kept. PR 44: 4.650 ->
     # 4.644 GB, the float32 `[1, 8195, 3072]` padded copies and the taps' products gone. PR 48: 4.644 ->
     # 4.794 GB, q | k | v before the convolution of three delta-rule parts kept, [1, 8192, 3072] bfloat16 =
-    # 50 MB a part, 0.15 GB
+    # 50 MB a part, 0.15 GB. PR 51: 4.7938 -> 4.7907 GB, 3 MB less: the scan's second half in kernels
+    # (its [.., 128, 256] right-hand sides and solutions and the [chunks, B, H, Q, K] copies of q, k, v
+    # gone) is not where the step's temporaries peak, so `kk` and `b` (0.2 GB: 4.996) stay unnamed
     ("solar-open2-train-tp8-ep40", 4, 2, 4.80),
     # PR 42: four expert parts at 4 of 64 over 32,768 tokens (the pick a slot at a time: 8.4 M mask
     # elements), no shared expert, beside four gated short convolutions, a dense part and attention
@@ -527,6 +529,10 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
         assert _kernel_calls(text, "kda_overlaps_fwd") == (parts, parts)
         assert _kernel_calls(text, "kda_overlaps_bwd") == (parts, 0)
         assert not re.search(_OVERLAPS_INTERMEDIATES, text)
+        # the chunks' four matrices likewise (PR 51): [W | U0] and what it is made from stay in fast memory
+        assert _kernel_calls(text, "kda_parts_fwd") == (parts, parts)
+        assert _kernel_calls(text, "kda_parts_bwd") == (parts, 0)
+        assert not re.search(_PARTS_INTERMEDIATES, text)
         # the convolution, silu and norms of q, k and v: ONE call a part and pass whatever the three
         # (9 a step; a call for each of q, k, v was 27, and 3 s of every first step: PR 44), and the
         # plain form's float32 copy of q|k|v padded by the taps is gone with its shifted products
@@ -776,15 +782,22 @@ _CONV_PADDED_COPY = r"f32\[1,8195,3072\]"
 _OVERLAPS_INTERMEDIATES = r"f32\[(\d+,)+(32,32,128|4,128,128)\]"
 
 
+# float32 arrays of every chunk with the extents of `_chunk_parts`' right-hand sides and solutions,
+# beta [k exp G | v] and [W | U0], [.., 128, 256]: what the second half wrote to HBM before its kernels
+_PARTS_INTERMEDIATES = r"f32\[(\d+,)+128,256\]"
+
+
 def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     """A Kimi-Delta-Attention part's share of the Solar-Open2 cell (8 heads of 128, 8,192
     positions in 64 chunks of 128), value and every gradient under the cell's remat: the
     overlaps are the two Pallas kernels by name (forward, forward again in the
     rematerialised layer, backward: ISSUE 38's item 5 was not taken, PERF.md section 6), the
+    chunks' four matrices two more with the same three calls (PR 51: nine custom calls a part),
     convolution, silu and norms of q, k and v likewise two kernels and three calls (PR 44), the
     inverse the compiler's own triangular kernel once a block, no float32 array with the
-    extents of the differences or the sub-chunks' factors of all chunks, the scan's float32
-    intermediates beside the projections' under 2 GB; q | k | v before the convolution
+    extents of the differences, the sub-chunks' factors or the second half's right-hand
+    sides and solutions of all chunks, the scan's float32 intermediates beside the
+    projections' under 2 GB; q | k | v before the convolution
     `[1, 8192, 3072]` kept by name in the layout the convolution's kernels read
     (`_rematerialised_mixer`, PR 48: named before the reshape it was stored positions-minor and
     copied for the kernels, forward and backward)."""
@@ -801,10 +814,11 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     text = compiled.as_text()
     assert _kernel_calls(text, "kda_overlaps_fwd") == (1, 1) and _kernel_calls(text, "kda_overlaps_bwd") == (1, 0)
     assert _kernel_calls(text, "short_conv_fwd") == (1, 1) and _kernel_calls(text, "short_conv_bwd") == (1, 0)
-    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 6
+    assert _kernel_calls(text, "kda_parts_fwd") == (1, 1) and _kernel_calls(text, "kda_parts_bwd") == (1, 0)
+    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 9
     assert not re.search(_CONV_PADDED_COPY, text)
     assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == cfg.kda_chunk // _SOLVE
-    assert not re.search(_OVERLAPS_INTERMEDIATES, text)
+    assert not re.search(_OVERLAPS_INTERMEDIATES, text) and not re.search(_PARTS_INTERMEDIATES, text)
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
