@@ -25,6 +25,13 @@ from .config import ModelConfig
 # keeps the rotated q and k beside the matmul outputs, the flash kernel's `out` and logsumexp
 # under `full` (llama._maybe_remat has why)
 LEAF, RECURRENT, SCOPE = "attn_norm", None, None
+# the part's five pieces by name inside `attn`, as the recurrent mixers name theirs (`ssm_in_proj` ..
+# `ssm_out_proj`): every operation of an attention part carries exactly one, forward, made again and
+# backward (tests/test_attention_scopes.py), and none starts with `attn_window`, `attn_full` or
+# `attn_bd`, which are prefixes the benchmark's older patterns match. `qkv_proj` and `_out_proj` open
+# theirs themselves, so the serving programs that call them carry the same names
+SCOPES = ("attn_in_proj", "attn_head_norm", "attn_core", "attn_gate", "attn_out_proj")
+OUT_SCOPE = SCOPES[-1]  # llama._block puts an attention part's residual (and the norm behind it) there
 KEPT = {"dots": ROTATED_NAMES, "dots_no_batch": ROTATED_NAMES, "full": FLASH_NAMES}
 
 
@@ -107,17 +114,19 @@ def qkv_proj(x: jax.Array, lp: dict, cfg: ModelConfig, positions: Optional[jax.A
     Without positions q and k come back un-rotated: the caller hands the rotation on
     (latent attention rotates a slice of its heads and always needs them)."""
     dt = x.dtype
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    if cfg.latent_attention:
-        return _latent_qkv(h, lp, cfg, positions)
-    q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
-    k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
-    v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
-    if "q_head_norm" in lp:
-        q, k = rms_norm(q, lp["q_head_norm"], cfg.norm_eps), rms_norm(k, lp["k_head_norm"], cfg.norm_eps)
-    if positions is None:
-        return q, k, v
-    return rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta), v
+    with jax.named_scope("attn_in_proj"):  # (the latent path keeps `mla_q` / `mla_kv` inside it)
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        if cfg.latent_attention:
+            return _latent_qkv(h, lp, cfg, positions)
+        q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wk"], dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wv"], dt))
+    with jax.named_scope("attn_head_norm"):  # and the rotation where it is not the kernels' own pass
+        if "q_head_norm" in lp:
+            q, k = rms_norm(q, lp["q_head_norm"], cfg.norm_eps), rms_norm(k, lp["k_head_norm"], cfg.norm_eps)
+        if positions is None:
+            return q, k, v
+        return rope(q, positions, cfg.rope_theta), rope(k, positions, cfg.rope_theta), v
 
 
 def rope_pairs_to_halves(d: int):
@@ -154,7 +163,8 @@ def _latent_qkv(h: jax.Array, lp: dict, cfg: ModelConfig, positions: jax.Array):
 
 
 def _out_proj(attn: jax.Array, lp: dict, dt) -> jax.Array:
-    return jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], dt))
+    with jax.named_scope(OUT_SCOPE):
+        return jnp.einsum("bshk,hkd->bsd", attn, _w(lp["wo"], dt))
 
 
 def attn_out(x: jax.Array, attn: jax.Array, lp: dict) -> jax.Array:
@@ -191,50 +201,52 @@ def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed=Fals
         deferred = (rotate and cache_kv is None and cfg.attention_impl not in ("ring", "ulysses")
                     and not cfg.latent_attention)
         q, k, v = qkv_proj(x, lp, cfg, positions if rotate and not deferred else None)
-        q = wsc(q, "batch", "seq", "act_heads", "head_dim")
+        with jax.named_scope("attn_core"):
+            q = wsc(q, "batch", "seq", "act_heads", "head_dim")
 
-        new_kv = None
-        if cache_kv is not None:
-            ck, cv = cache_kv
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_len, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_len, axis=1)
-            new_kv = (ck, cv)
-            attn = attention(
-                q, ck, cv, causal=True, q_offset=cache_len, kv_valid_len=cache_len + q.shape[1]
-            )
-        elif cfg.attention_impl in ("ring", "ulysses"):
-            # Sequence-parallel attention: activations stay seq-sharded over "sp"; KV chunks
-            # ride the ICI ring (ops/ring_attention.py). If "sp" is already bound manually
-            # (pipeline stage traced with extra_manual=("sp",)), call the collective form
-            # directly — nested shard_map is not composable.
-            from ray_tpu.ops import ring_attention as ra
-            from ray_tpu.parallel.sharding import active_manual_axes
-
-            if "sp" in active_manual_axes():
-                if cfg.attention_impl == "ring":
-                    attn = ra.ring_attention(q, k, v, causal=True, segment_ids=segment_ids)
-                else:
-                    if segment_ids is not None:
-                        # mirror ring_attention_sharded's refusal — dropping the
-                        # packing mask here would silently attend across documents
-                        raise NotImplementedError(
-                            "segment_ids only supported with impl='ring'")
-                    attn = ra.ulysses_attention(q, k, v, causal=True)
-            else:
-                attn = ra.ring_attention_sharded(
-                    q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl
+            new_kv = None
+            if cache_kv is not None:
+                ck, cv = cache_kv
+                ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_len, axis=1)
+                cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_len, axis=1)
+                new_kv = (ck, cv)
+                attn = attention(
+                    q, ck, cv, causal=True, q_offset=cache_len, kv_valid_len=cache_len + q.shape[1]
                 )
-        elif doubled:  # the core alone under a scope of its own: the rotation and the kernels
-            with jax.named_scope("attn_bd"):
-                attn = attention(q, k, v, causal=False, block_diffusion=doubled, impl=cfg.attention_impl,
-                                 shard_spec=auto_spec("batch", None, "act_heads", None),
+            elif cfg.attention_impl in ("ring", "ulysses"):
+                # Sequence-parallel attention: activations stay seq-sharded over "sp"; KV chunks
+                # ride the ICI ring (ops/ring_attention.py). If "sp" is already bound manually
+                # (pipeline stage traced with extra_manual=("sp",)), call the collective form
+                # directly — nested shard_map is not composable.
+                from ray_tpu.ops import ring_attention as ra
+                from ray_tpu.parallel.sharding import active_manual_axes
+
+                if "sp" in active_manual_axes():
+                    if cfg.attention_impl == "ring":
+                        attn = ra.ring_attention(q, k, v, causal=True, segment_ids=segment_ids)
+                    else:
+                        if segment_ids is not None:
+                            # mirror ring_attention_sharded's refusal — dropping the
+                            # packing mask here would silently attend across documents
+                            raise NotImplementedError(
+                                "segment_ids only supported with impl='ring'")
+                        attn = ra.ulysses_attention(q, k, v, causal=True)
+                else:
+                    attn = ra.ring_attention_sharded(
+                        q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl
+                    )
+            elif doubled:  # the core alone under a scope of its own: the rotation and the kernels
+                with jax.named_scope("attn_bd"):
+                    attn = attention(q, k, v, causal=False, block_diffusion=doubled, impl=cfg.attention_impl,
+                                     shard_spec=auto_spec("batch", None, "act_heads", None),
+                                     rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
+            else:
+                attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
+                                 shard_spec=auto_spec("batch", None, "act_heads", None), window=window,
                                  rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
-        else:
-            attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
-                             shard_spec=auto_spec("batch", None, "act_heads", None), window=window,
-                             rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
         if "wo_gate" in lp:  # a channel of the output, from the layer's normed input
-            gate = jnp.einsum("bsd,dhk->bshk", rms_norm(x, lp["attn_norm"], cfg.norm_eps),
-                              _w(lp["wo_gate"], x.dtype))
-            attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
+            with jax.named_scope("attn_gate"):
+                gate = jnp.einsum("bsd,dhk->bshk", rms_norm(x, lp["attn_norm"], cfg.norm_eps),
+                                  _w(lp["wo_gate"], x.dtype))
+                attn = (attn.astype(jnp.float32) * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(attn.dtype)
         return _out_proj(attn, lp, x.dtype), new_kv

@@ -55,6 +55,11 @@ _dense = types.SimpleNamespace(
     n_params=lambda cfg: len(moe.mlp_leaves(cfg)) * cfg.d_model * cfg.d_ff, KEPT={})
 MIXERS = {"attn": (attn, slice(0, 4)), "ssm": (ssm, 0), "kda": (kda, 0), "sconv": (sconv, 0)}
 FEED_FORWARD = {"experts": (moe, 4), "dense": (_dense, slice(4, 7))}
+# the scope around the loop over layers (a `lax.scan` over a stack's layers or a pattern's periods, or one
+# period run as it stands). A layer's operations carry their part's name inside it; what carries this name
+# and no part's is the loop's own: the residual stacks' stores and reads, index arithmetic, the gradient
+# stacks' zeros, the expert layers' counts stacked (benchmarks/metrics/train_layer_stack_pct.json)
+LAYER_LOOP = "layer_stack"
 
 
 # ---------------------------------------------------------------------------- init
@@ -418,7 +423,10 @@ def _block(
     elif part is not None:
         out, new_kv = part.mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed)
     if part is not None:
-        with jax.named_scope("attn"):
+        # an attention part's residual counts with its output product, so that everything under `attn`
+        # and no recurrent mixer's name carries one of attn.SCOPES
+        piece = contextlib.nullcontext() if part.RECURRENT else jax.named_scope(attn.OUT_SCOPE)
+        with jax.named_scope("attn"), piece:
             x = _onto(x, out, lp, "attn_post_norm", cfg, wsc)
     if "mlp_norm" in lp:
         with jax.named_scope("mlp"):
@@ -487,7 +495,8 @@ def _pipeline_layers(
 
         aux0 = vary_like(jnp.zeros((), jnp.float32), xm)
         fn = _maybe_remat(body, cfg)
-        (out, aux), _ = jax.lax.scan(fn, (xm, aux0), stage_params)
+        with jax.named_scope(LAYER_LOOP):
+            (out, aux), _ = jax.lax.scan(fn, (xm, aux0), stage_params)
         return (out, aux) if has_experts else out
 
     m = cfg.pipeline_microbatches or pp
@@ -534,13 +543,14 @@ def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
         return h, jax.tree.map(lambda *a: jnp.stack(a), *auxs) if auxs else None
 
     stacks = {name: params[name] for name in per_unit}
-    if n == 1:
-        x, auxs = period(x, stacks)
-    else:
-        x, auxs = jax.lax.scan(period, x, {
-            name: jax.tree.map(lambda a: a.reshape(n, per_unit[name], *a.shape[1:]), stack)  # noqa: B023
-            for name, stack in stacks.items()})
-        auxs = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), auxs)
+    with jax.named_scope(LAYER_LOOP):
+        if n == 1:
+            x, auxs = period(x, stacks)
+        else:
+            x, auxs = jax.lax.scan(period, x, {
+                name: jax.tree.map(lambda a: a.reshape(n, per_unit[name], *a.shape[1:]), stack)  # noqa: B023
+                for name, stack in stacks.items()})
+            auxs = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), auxs)
     if cfg.moe_dropless and auxs is not None:
         return x, dict(auxs, hidden=x)
     return x, jnp.zeros((), jnp.float32) if auxs is None else auxs.sum()
@@ -604,9 +614,10 @@ def forward(
             return h, (new_kv, aux)
 
         for name in _layer_kinds(cfg):
-            x, (new_kv, auxs) = jax.lax.scan(
-                _maybe_remat(body, cfg), x,
-                (params[name], None if cache is None else (cache.k, cache.v)))
+            with jax.named_scope(LAYER_LOOP):
+                x, (new_kv, auxs) = jax.lax.scan(
+                    _maybe_remat(body, cfg), x,
+                    (params[name], None if cache is None else (cache.k, cache.v)))
         # the last stack's: the expert layers' where there are any
         aux_total = dict(auxs, hidden=x) if cfg.moe_dropless else auxs.sum()
         if cache is not None:

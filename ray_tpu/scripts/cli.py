@@ -200,7 +200,7 @@ def _render_status(s: dict) -> str:
         setup = " ".join(f"{k.rpartition('.')[2]}:{v:.1f}s"
                          for k, v in sorted((tn.get("setup_seconds") or {}).items()))
         lines.append(
-            f"train      steps={tn.get('steps', 0)} loop/step[{laps or '-'}] "
+            f"train      steps={tn.get('steps', 0)} slow={tn.get('slow_steps', 0)} loop/step[{laps or '-'}] "
             f"compiles={tn.get('compiles', 0)} gc={tn.get('gc_pause_ms', 0):.1f}ms/"
             f"{tn.get('gc_collections', 0)} group_failures={tn.get('group_failures', 0)} "
             f"setup[{setup or '-'}]")

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import logging
 import os
 import queue
 import shutil
@@ -26,6 +27,7 @@ from ray_tpu.util import telemetry
 
 from .checkpoint import Checkpoint
 
+LOGGER = logging.getLogger(__name__)
 _session_lock = threading.Lock()
 _session: Optional["_TrainSession"] = None
 
@@ -43,25 +45,35 @@ DISPATCH, REPORT, DATA, USER = range(len(LOOP_SPANS))
 _LOOP_COUNTERS = ("train_loop_dispatch_ns_total", "train_loop_report_ns_total",
                   "train_loop_data_ns_total", "train_loop_user_ns_total")
 _STEPS = "train_steps_total"
+_SLOW = "train_slow_steps_total"
 _STEP_INTERVAL_BOUNDARIES = [0.001 * 2 ** (i / 2) for i in range(34)]  # 1 ms .. 92 s
+# A slow step leaves a record (`_slow_step`): one whose entry-to-entry interval is more than
+# SLOW_FACTOR x the median of `train_step_interval_seconds`. The threshold is read from the
+# histogram's buckets at the loop's FIRST_REFRESH-th step and every REFRESH_EVERY steps after, so
+# that a step pays one comparison, and no step before that has one (the first compiles).
+SLOW_FACTOR, FIRST_REFRESH, REFRESH_EVERY = 4, 16, 64
 
 
 class _Loop:
-    """One thread's laps, its count of steps and when it last entered a step. A
-    session's `train_loop` thread has one; outside a session (a bare process, the
-    tests) a thread gets one at its first step."""
+    """One thread's laps, its count of steps and when it last entered a step; for the
+    record of a slow step the threshold (SLOW_FACTOR x the median), the step at which it is read
+    again, the process's integers at the last entry (`_held`) and the operating system's
+    numbers at the last refresh (`_os_numbers`). A session's `train_loop` thread has one;
+    outside a session (a bare process, the tests) a thread gets one at its first step."""
 
-    __slots__ = ("clock", "last_step_ns", "thread")
+    __slots__ = ("clock", "last_step_ns", "thread", "slow_after_ns", "refresh_at", "at_entry", "at_refresh")
 
     def __init__(self):
-        self.clock = telemetry.LapClock(LOOP_SPANS, _LOOP_COUNTERS + (_STEPS,), "train")
+        self.clock = telemetry.LapClock(LOOP_SPANS, _LOOP_COUNTERS + (_STEPS, _SLOW), "train")
         self.last_step_ns = 0
         self.thread = threading.current_thread()
+        self.slow_after_ns, self.refresh_at = 1 << 62, FIRST_REFRESH
+        self.at_entry = self.at_refresh = None
 
 
 _loops_lock = threading.Lock()
 _loops: list = []  # the live threads'
-_retired: Dict[str, int] = dict.fromkeys(_LOOP_COUNTERS + (_STEPS,), 0)  # the ended threads', summed
+_retired: Dict[str, int] = dict.fromkeys(_LOOP_COUNTERS + (_STEPS, _SLOW), 0)  # the ended threads', summed
 _local = threading.local()
 _setup_seconds: Dict[str, float] = {}  # phase -> seconds, this process's (record_setup)
 
@@ -78,7 +90,7 @@ def _loop() -> _Loop:
         # carried to the head from the processes that run steps only, so that the
         # `train` row of cluster_status() sums the train workers' own
         telemetry.export_counters(
-            metrics, _LOOP_COUNTERS + (_STEPS, "compiles_total", "compile_ns_total")
+            metrics, _LOOP_COUNTERS + (_STEPS, _SLOW, "compiles_total", "compile_ns_total")
             + tuple(core_worker.process_counters()),
             "the train loop's laps, steps and compiles, its process's tasks and "
             "collector pauses (ray_tpu.train.metrics)")
@@ -110,16 +122,93 @@ def _step_interval():
         boundaries=_STEP_INTERVAL_BOUNDARIES)
 
 
+def _held(totals: Dict[str, int]) -> tuple:
+    """The integers the process already holds that say what a step's interval went to: the
+    four laps' totals, the collector's pauses, the programs compiled. Dictionary reads."""
+    dispatch, report, data, user = _LOOP_COUNTERS
+    return (totals[dispatch], totals[report], totals[data], totals[user],
+            core_worker.process_counters()["gc_pause_ns_total"], telemetry.compile_counters()["compiles_total"])
+
+
+def _os_numbers(steps: int) -> Dict[str, int]:
+    """What the operating system says of the process and of the calling thread: CPU time, the
+    thread's voluntary and involuntary context switches, its wait on the run queue. SYSTEM
+    CALLS, so never on a step's path (PERF.md section 6, PR 35: a burst of them on the loop's
+    thread changes which of the machine's two step times a process runs at): read at a
+    refresh and after a slow step has happened, beside the loop's count of `steps` and the
+    instant. A field the platform has not is left out."""
+    out = {"steps": steps, "at_ns": time.perf_counter_ns(), "process_cpu_ns": time.process_time_ns()}
+    try:
+        import resource
+
+        usage = resource.getrusage(resource.RUSAGE_THREAD)
+        out["voluntary_switches"], out["involuntary_switches"] = usage.ru_nvcsw, usage.ru_nivcsw
+    except (ImportError, AttributeError, ValueError, OSError):
+        pass  # no `resource`, or no RUSAGE_THREAD: not Linux
+    try:
+        with open("/proc/thread-self/schedstat") as f:
+            out["thread_cpu_ns"], out["run_queue_wait_ns"] = (int(n) for n in f.read().split()[:2])
+    except (OSError, ValueError):
+        pass
+    return out
+
+
+def _refresh(loop: _Loop, steps: int) -> None:
+    """The threshold from the histogram's buckets, and the operating system's numbers to
+    hold a slow step's against; the next at `steps + REFRESH_EVERY`."""
+    from ray_tpu.util.metrics import histogram_quantile
+
+    median = histogram_quantile(_step_interval()._export(), 0.5)
+    if median:
+        loop.slow_after_ns = int(SLOW_FACTOR * median * 1e9)
+    loop.refresh_at = steps + REFRESH_EVERY
+    loop.at_refresh = _os_numbers(steps)
+
+
+def _slow_step(loop: _Loop, step: int, interval_ns: int) -> None:
+    """One warning line and one count for the step that took `interval_ns` entry to entry:
+    how the interval divides over the loop's laps, the collector's pauses and the compiles
+    of the same step, and what the operating system counted since the last refresh (at
+    most REFRESH_EVERY steps back, so a stall of seconds dominates it). A step that stood
+    still with no CPU used, no collection and no compile is told from one the program
+    held up. With the ring off."""
+    totals = loop.clock.totals
+    totals[_SLOW] += 1
+    *laps, gc_ns, compiles = (now - then for now, then in zip(_held(totals), loop.at_entry))
+    then, now = loop.at_refresh, _os_numbers(step)
+    ms = 1e-6
+    since = [f"{label} {(now[key] - then[key]) * scale:.{digits}f}{unit}" for key, label, scale, digits, unit in (
+        ("process_cpu_ns", "process CPU", ms, 1, " ms"), ("thread_cpu_ns", "loop thread CPU", ms, 1, " ms"),
+        ("run_queue_wait_ns", "run-queue wait", ms, 1, " ms"), ("voluntary_switches", "voluntary switches", 1, 0, ""),
+        ("involuntary_switches", "involuntary", 1, 0, "")) if key in now and key in then]
+    LOGGER.warning(
+        "slow train step: step %d took %.1f ms entry to entry against a median of %.1f ms; laps %s ms; "
+        "collector pauses %.1f ms, compiles %d; in the %d steps and %.1f ms since the threshold was read: %s",
+        step, interval_ns * ms, loop.slow_after_ns / SLOW_FACTOR * ms,
+        " ".join(f"{name.rpartition('.')[2]} {n * ms:.1f}" for name, n in zip(LOOP_SPANS, laps)),
+        gc_ns * ms, compiles, now["steps"] - then["steps"], (now["at_ns"] - then["at_ns"]) * ms, ", ".join(since))
+    loop.at_refresh = now  # the next slow step's numbers are its own
+
+
 def enter_step() -> Optional[int]:
     """The step callable is entered (train/step.py): the `dispatch` lap begins, the step
-    is counted, the time since the last entry is observed. -> what `leave_step` takes."""
+    is counted, the time since the last entry is observed and, past the threshold, left
+    on record (`_slow_step`). -> what `leave_step` takes."""
     loop = _loop()
     back = loop.clock.lap
     now = loop.clock.enter(DISPATCH)
+    totals = loop.clock.totals
+    steps = totals[_STEPS]  # (the count of the step whose interval ends here)
     if loop.last_step_ns:
-        _step_interval().observe((now - loop.last_step_ns) * 1e-9)
+        interval = now - loop.last_step_ns
+        _step_interval().observe(interval * 1e-9)
+        if interval > loop.slow_after_ns:
+            _slow_step(loop, steps, interval)
     loop.last_step_ns = now
-    loop.clock.totals[_STEPS] += 1
+    totals[_STEPS] = steps + 1
+    if steps + 1 == loop.refresh_at:
+        _refresh(loop, steps + 1)
+    loop.at_entry = _held(totals)
     return back
 
 
@@ -165,7 +254,9 @@ def record_setup(phase: str, start_wall_ns: int, dur_ns: int, **args: Any) -> No
 def metrics() -> Dict[str, Any]:
     """This process's always-on integers of the training path, as `JaxLLMEngine.metrics()`
     gives the engine's: `train_loop_{dispatch,report,data,user}_ns_total` (the laps of
-    every thread that ran steps), `train_steps_total`, `compiles_total` /
+    every thread that ran steps), `train_steps_total`, `train_slow_steps_total` (steps
+    of more than SLOW_FACTOR x the median interval, each a warning line of the worker's
+    log: `_slow_step`), `compiles_total` /
     `compile_ns_total` (the process's: a step that compiled again shows here), the
     worker's `worker_tasks_total` / `worker_task_ns_total` and the collector's
     `gc_pause_ns_total` / `gc_collections_total` (core/worker.py), and `setup_seconds`

@@ -507,6 +507,8 @@ def cluster_status() -> Dict[str, Any]:
             for lap in ("dispatch", "report", "data", "user")} if steps else {},
         "step_interval_p50_s": m.histogram_quantile(interval, 0.5) if interval else None,
         "step_interval_p99_s": m.histogram_quantile(interval, 0.99) if interval else None,
+        # steps of more than 4 x the median interval: each left a line in its worker's log
+        "slow_steps": int(counter_total("train_slow_steps_total")),
         "compiles": int(counter_total("compiles_total")),
         "compile_s": round(counter_total("compile_ns_total") / 1e9, 3),
         "gc_pause_ms": round(counter_total("gc_pause_ns_total") / 1e6, 3),

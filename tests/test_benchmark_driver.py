@@ -2,11 +2,14 @@
 instructions are joined to the scopes that made them (benchmarks/lib/scope_seconds.py), and how
 the train_family driver reads a step's own gradient and update out of the state it left
 (benchmarks/drivers/train_family.py). (The readers of each cell: the families' files.)"""
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from family_contract import _highest, highest, tokens  # noqa: F401  (autouse; and benchmarks/ is importable)
+from family_contract import ROOT, _highest, highest, tokens  # noqa: F401  (autouse; and benchmarks/ is importable)
 from ray_tpu.models import get_config, llama, moe
 
 GLM = get_config("glm-tiny")
@@ -86,3 +89,71 @@ def test_the_drivers_step_parity_reads_the_steps_own_gradient_and_update():
     ruled, load = driver.balance_rule(np.zeros((1, 4)), chosen, 4, 0.5)
     np.testing.assert_array_equal(load, [[3, 1, 1, 1]])
     np.testing.assert_allclose(ruled, moe.balance_bias(jnp.zeros((1, 4)), jnp.asarray(load, jnp.float32), 0.5))
+
+
+# ------------------------------------------------------------------- the names of PR 52 in the manifest
+
+# metric -> (what a configuration must have for the pattern to match something there, operations of a
+# made-up trace by the scopes they carry -> whether the metric counts them)
+_NEW_METRICS = {
+    "train_attn_proj_pct": (lambda cfg: True, {("attn", "attn_in_proj"): True, ("attn", "attn_out_proj", "mlp"): True,
+                                               ("attn", "attn_core"): False, ("attn", "sconv", "sconv_in_proj"): False}),
+    "train_attn_core_pct": (lambda cfg: True, {("attn", "attn_core", "attn_bd"): True, ("attn", "attn_window", "attn_core"): True,
+                                               ("attn", "attn_in_proj"): False}),
+    "train_attn_passes_pct": (lambda cfg: cfg.attn_qk_norm or cfg.attn_output_gate,
+                              {("attn", "attn_head_norm"): True, ("attn", "attn_gate", "attn_core"): True, ("attn", "attn_core"): False}),
+    "train_mla_proj_pct": (lambda cfg: cfg.latent_attention, {("attn", "attn_in_proj", "mla_q"): True, ("attn", "attn_in_proj", "mla_kv"): True,
+                                                              ("attn", "attn_in_proj"): False}),
+    "train_moe_dispatch_pct": (lambda cfg: cfg.n_experts, {("mlp", "moe_dispatch"): True, ("mlp", "moe_combine"): False}),
+    "train_moe_combine_pct": (lambda cfg: cfg.n_experts, {("mlp", "moe_combine"): True, ("mlp", "moe_dispatch", "moe_combine"): True,
+                                                          ("mlp", "moe_experts"): False}),
+    # (a cell whose one period runs as it stands has no loop: 0.00-0.02 ms a step under the name, PR 52's chip runs)
+    "train_layer_stack_pct": (lambda cfg: not cfg.layer_pattern or llama.pattern_period(cfg.layer_pattern)[1] > 1, {("model", "layer_stack"): True, ("layer_stack", "while"): True,
+                                                             ("layer_stack", "attn", "attn_core"): False, ("layer_stack", "layer_params"): False,
+                                                             ("layer_stack", "kda_scan"): False, ("layer_stack", "sconv"): False,
+                                                             ("optimizer",): False}),
+}
+
+
+@pytest.mark.parametrize("name", _NEW_METRICS)
+def test_a_metric_of_the_new_names_has_its_file_its_reader_and_cells_that_have_the_part(name):
+    """Each per-layer metric PR 52 lists: a `metrics/<name>.json` over a reader that exists, a manifest
+    entry in a layer of PERF.md's list, cells that exist, whose driver makes the scope join
+    (`train_family`, `train_diffusion`) and whose configuration has the part the pattern names; and on a
+    made-up trace the reader counts what carries the name and leaves out what does not."""
+    import importlib
+    import json
+
+    from benchmarks.lib import modelcfg
+
+    has_part, operations = _NEW_METRICS[name]
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    with open(os.path.join(ROOT, "benchmarks", "metrics", f"{name}.json")) as f:
+        metric = json.load(f)
+    entry = next(m for m in manifest["per_layer"] if m["name"] == name)
+    assert (entry["unit"], entry["source"], entry["moves"], entry["better"]) == ("%", "device_trace", "train_tokens_per_s", "lower")
+    with open(os.path.join(ROOT, "PERF.md")) as f:  # a layer as PERF.md's list of layers has it, letter for letter
+        assert f"\n| {entry['layer']} |" in f.read()
+    assert metric["name"] == name and metric["reader"] in ("trace_scope_share", "trace_scope_share_without")
+    reader = importlib.import_module(f"benchmarks.readers.{metric['reader']}")
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    assert entry["workloads"] and set(entry["workloads"]) <= set(cells)
+    joined = []
+    for cell, w in cells.items():
+        with open(os.path.join(ROOT, "benchmarks", "workloads", f"{cell}.json")) as f:
+            driver = json.load(f)["driver"]
+        with open(os.path.join(ROOT, w["config"] and next(c["file"] for c in manifest["configs"] if c["name"] == w["config"]))) as f:
+            cfg = modelcfg.model_config(modelcfg.model_keys(json.load(f)))
+        if driver in ("train_family", "train_diffusion") and has_part(cfg):
+            joined.append(cell)
+    assert entry["workloads"] == joined  # every cell that can read it, in the manifest's order, and no other
+    seconds = {f"%op.{i} = x(": 1.0 + i for i in range(len(operations))}
+    scopes = {op: sorted(names) for op, names in zip(seconds, operations)}
+    trace = {"busy_s": 100.0, "op_seconds": seconds, "op_scopes": scopes}
+    want = sum(s for s, counts in zip(seconds.values(), operations.values()) if counts)
+    assert reader.read({"result": {"trace": trace}}, **metric["args"]) == pytest.approx(want)
+    nothing = {op: ["optimizer"] for op in seconds}  # the parent's program: no such name, nothing to read, nothing raised
+    assert reader.read({"result": {"trace": {**trace, "op_scopes": nothing}}}, **metric["args"]) is None
+    assert reader.read({"result": {"trace": {**trace, "op_scopes": {}}}}, **metric["args"]) is None
+    assert reader.read({"result": {}}, **metric["args"]) is None

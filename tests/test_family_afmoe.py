@@ -175,7 +175,7 @@ FAMILY = Family(
     batch=3, least_leaves=20, float32_leaves=frozenset(), recurrent=None,
     shares={"16_expert_shares_seeded": _16_expert_shares("seeded"),
             "16_expert_shares_all_on_one_share": _16_expert_shares("all_on_one_share")},
-    scopes=frozenset({"attn_window", "attn_full", "moe_router", "moe_experts", "moe_shared", "attn", "mlp", "lm_head"}),
+    scopes=frozenset({"attn_window", "attn_full", "moe_router", "moe_experts", "moe_shared", "attn", "mlp", "lm_head", "attn_in_proj", "attn_head_norm", "attn_core", "attn_gate", "attn_out_proj", "moe_dispatch", "moe_combine", "layer_stack"}),
     mixer_scopes=frozenset({"attn_window", "attn_full"}), outer=frozenset({"attn"}), absent=frozenset({"sconv", "kda_scan"}),
     rehearsal=("3000000007", 40, frozenset({"loss", "ce_loss"}), 2 * 64),
     pairs={  # published key -> ModelConfig field (n_experts: once more under `program`; the heads: _config_file)
@@ -203,7 +203,11 @@ FAMILY = Family(
         "train_attn_fwd_kernel_pct", "train_attn_bwd_kernel_pct", "train_moe_pct", "train_moe_gmm_mxu_pct",
         "train_moe_imbalance", "train_moe_router_pct", "train_optimizer_pct", "train_head_loss_pct",
         "train_scoped_pct", "train_attn_window_pct", "train_attn_full_pct", "train_attn_window_roofline_pct",
-        "train_mfu_swa_moe_pct"}),
+        "train_mfu_swa_moe_pct",
+        # PR 52: the attention part's pieces, the expert layer's dispatch and combine (the layer
+        # loop's own is next to nothing where one period runs unrolled: not listed)
+        "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
+        "train_attn_passes_pct"}),
     own_metrics=("train_attn_window_pct", "train_attn_full_pct", "train_attn_window_roofline_pct", "train_mfu_swa_moe_pct"),
 )
 

@@ -122,8 +122,8 @@ FAMILY = Family(
     cases=(("held0", CFG, 1), ("held1", dataclasses.replace(CFG, experts_held=(1, 2)), 1)),
     batch=2, least_leaves=50, float32_leaves=frozenset(), recurrent=None,
     shares={"8_expert_shares": _8_expert_shares},
-    scopes=frozenset({"moe_router", "moe_experts", "moe_shared", "attn", "mlp", "mtp", "lm_head"}),
-    mixer_scopes=frozenset({"mla_q", "mla_kv"}), outer=frozenset({"attn"}), absent=frozenset(),
+    scopes=frozenset({"moe_router", "moe_experts", "moe_shared", "attn", "mlp", "mtp", "lm_head", "attn_in_proj", "attn_core", "attn_out_proj", "moe_dispatch", "moe_combine", "layer_stack"}),
+    mixer_scopes=frozenset({"mla_q", "mla_kv"}), outer=frozenset({"attn"}), absent=frozenset({"attn_head_norm", "attn_gate"}),
     rehearsal=("3000000001", 50, frozenset({"loss", "ce_loss", "mtp_loss"}), 2 * (64 + 63)),
     pairs=PAIRS, cell_params=706.5e6, config_file=_config_file, published_params=30.59e9, published=_published,
     hf_base=dict(model_type="glm4_moe_lite", vocab_size=256, hidden_size=64, num_hidden_layers=3,
@@ -141,7 +141,10 @@ FAMILY = Family(
         "setup_s", "train_tokens_per_s", "train_step_ms", "train_device_idle_pct", "train_device_step_ms",
         "train_attn_fwd_kernel_pct", "train_attn_bwd_kernel_pct", "train_moe_pct", "train_moe_gmm_mxu_pct",
         "train_moe_imbalance", "train_moe_router_pct", "train_optimizer_pct", "train_head_loss_pct",
-        "train_scoped_pct", "train_mfu_mla_moe_pct"}),
+        "train_scoped_pct", "train_mfu_mla_moe_pct",
+        # PR 52: the attention part's pieces, the expert layer's dispatch and combine, the layer loop's own
+        "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
+        "train_layer_stack_pct", "train_mla_proj_pct"}),
     own_metrics=("train_mfu_mla_moe_pct", "train_moe_pct", "train_moe_gmm_mxu_pct", "train_moe_imbalance"),
 )
 

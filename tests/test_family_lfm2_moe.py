@@ -166,9 +166,9 @@ FAMILY = Family(
     batch=3, least_leaves=10, float32_leaves=frozenset(), recurrent="gated short-convolution",
     shares={"8_expert_shares_seeded": _8_expert_shares("seeded"),
             "8_expert_shares_all_on_one_share": _8_expert_shares("all_on_one_share")},
-    scopes=frozenset({"sconv", "moe_router", "moe_experts", "attn", "mlp", "lm_head"}),
+    scopes=frozenset({"sconv", "moe_router", "moe_experts", "attn", "mlp", "lm_head", "attn_in_proj", "attn_head_norm", "attn_core", "attn_out_proj", "moe_dispatch", "moe_combine", "layer_stack"}),
     mixer_scopes=frozenset({"sconv_in_proj", "sconv_gate_conv", "sconv_out_proj"}),
-    outer=frozenset({"attn", "sconv"}), absent=frozenset({"moe_shared"}),
+    outer=frozenset({"attn", "sconv"}), absent=frozenset({"moe_shared", "attn_gate"}),
     rehearsal=("3000000007", 25, frozenset({"loss", "ce_loss"}), 2 * 64),
     pairs={  # published key -> ModelConfig field (norm_eps, rope_theta and n_experts: once more under `program`)
         "hidden_size": "d_model", "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
@@ -192,7 +192,11 @@ FAMILY = Family(
         "train_attn_fwd_kernel_pct", "train_attn_bwd_kernel_pct", "train_moe_pct", "train_moe_gmm_mxu_pct",
         "train_moe_imbalance", "train_moe_router_pct", "train_optimizer_pct", "train_head_loss_pct",
         "train_scoped_pct", "train_sconv_pct", "train_sconv_roofline_pct", "train_attn_w64_roofline_pct",
-        "train_mfu_sconv_moe_pct"}),
+        "train_mfu_sconv_moe_pct",
+        # PR 52: the attention part's pieces, the expert layer's dispatch and combine (the layer
+        # loop's own is next to nothing where one period runs unrolled: not listed)
+        "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
+        "train_attn_passes_pct"}),
     own_metrics=("train_sconv_pct", "train_sconv_roofline_pct", "train_attn_w64_roofline_pct", "train_mfu_sconv_moe_pct"),
 )
 

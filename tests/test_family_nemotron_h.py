@@ -172,9 +172,9 @@ FAMILY = Family(
     batch=2, least_leaves=12, float32_leaves=frozenset({"A_log", "dt_bias", "D"}), recurrent="Mamba-2",
     shares={"mamba_8_head_shares": _mamba_8_head_shares, "attention_8_head_shares": _attention_8_head_shares,
             "64_expert_shares": _64_expert_shares},
-    scopes=frozenset({"moe_latent", "moe_router", "moe_experts", "moe_shared", "attn", "mlp"}),
+    scopes=frozenset({"moe_latent", "moe_router", "moe_experts", "moe_shared", "attn", "mlp", "attn_in_proj", "attn_core", "attn_out_proj", "moe_dispatch", "moe_combine", "layer_stack"}),
     mixer_scopes=frozenset({"ssm_in_proj", "ssm_conv", "ssm_scan", "ssm_norm", "ssm_out_proj"}),
-    outer=frozenset(), absent=frozenset(),
+    outer=frozenset(), absent=frozenset({"attn_head_norm", "attn_gate"}),
     rehearsal=("3000000007", 40, frozenset({"loss", "ce_loss"}), 2 * 64),  # the cell's cut has no MTP term
     pairs=PAIRS, cell_params=700.9e6, config_file=_config_file,
     published_params=123.612e9, published=_published,
@@ -200,6 +200,9 @@ FAMILY = Family(
         "train_ssm_pct", "train_ssm_scan_roofline_pct", "train_mfu_ssm_moe_pct",
         # PR 35: the device's own step and the shares of the scopes it made readable
         "train_device_step_ms", "train_moe_router_pct", "train_optimizer_pct", "train_head_loss_pct",
-        "train_scoped_pct"}),
+        "train_scoped_pct",
+        # PR 52: the attention part's pieces, the expert layer's dispatch and combine (the layer
+        # loop's own is next to nothing where one period runs unrolled: not listed)
+        "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct"}),
     own_metrics=("train_ssm_pct", "train_ssm_scan_roofline_pct", "train_mfu_ssm_moe_pct"),
 )

@@ -174,9 +174,9 @@ FAMILY = Family(
            ("blocks-of-8-held2", dataclasses.replace(CFG, diffusion_block=8, experts_held=(0, 2)), 1)),
     batch=3, least_leaves=15, float32_leaves=frozenset(), recurrent=None,
     shares={"8_expert_shares_seeded": _8_expert_shares},
-    scopes=frozenset({"attn_bd", "bd_rows", "moe_router", "moe_experts", "attn", "mlp", "lm_head", "embed", "loss"}),
+    scopes=frozenset({"attn_bd", "bd_rows", "moe_router", "moe_experts", "attn", "mlp", "lm_head", "embed", "loss", "attn_in_proj", "attn_head_norm", "attn_core", "attn_out_proj", "moe_dispatch", "moe_combine", "layer_stack"}),
     mixer_scopes=frozenset({"attn_bd"}), outer=frozenset({"attn"}),
-    absent=frozenset({"sconv", "kda_scan", "attn_window", "attn_full", "moe_shared"}),
+    absent=frozenset({"sconv", "kda_scan", "attn_window", "attn_full", "moe_shared", "attn_gate"}),
     rehearsal=("3000000007", 20, frozenset({"loss", "ce_loss"}), 2 * 64),
     pairs={  # published key -> ModelConfig field (n_experts: once more under `program`; the heads: _config_file)
         "hidden_size": "d_model", "num_key_value_heads": "n_kv_heads", "head_dim": "attn_head_dim",
@@ -199,7 +199,10 @@ FAMILY = Family(
         "train_attn_fwd_kernel_pct", "train_attn_bwd_kernel_pct", "train_moe_pct", "train_moe_gmm_mxu_pct",
         "train_moe_imbalance", "train_moe_router_pct", "train_optimizer_pct", "train_head_loss_pct",
         "train_scoped_pct", "train_mfu_bd_moe_pct", "train_attn_bd_roofline_pct", "train_attn_bd_pct",
-        "train_bd_rows_pct", "train_bd_masked_share_pct"}),
+        "train_bd_rows_pct", "train_bd_masked_share_pct",
+        # PR 52: the attention part's pieces, the expert layer's dispatch and combine, the layer loop's own
+        "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
+        "train_layer_stack_pct", "train_attn_passes_pct"}),
     own_metrics=("train_mfu_bd_moe_pct", "train_attn_bd_roofline_pct", "train_attn_bd_pct", "train_bd_rows_pct",
                  "train_bd_masked_share_pct"),
     batch_of=_batch,

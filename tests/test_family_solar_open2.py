@@ -152,9 +152,9 @@ FAMILY = Family(
     recurrent="Kimi-Delta-Attention",
     shares={"kda_8_head_shares": _kda_8_head_shares, "gated_gqa_8_head_shares": _gated_gqa_8_head_shares,
             "40_expert_shares": _40_expert_shares},
-    scopes=frozenset({"moe_router", "moe_experts", "moe_shared", "attn", "mlp"}),
+    scopes=frozenset({"moe_router", "moe_experts", "moe_shared", "attn", "mlp", "attn_in_proj", "attn_core", "attn_gate", "attn_out_proj", "moe_dispatch", "moe_combine", "layer_stack"}),
     mixer_scopes=frozenset({"kda_in_proj", "kda_conv", "kda_scan", "kda_norm_gate", "kda_out_proj"}),
-    outer=frozenset({"attn"}), absent=frozenset(),
+    outer=frozenset({"attn"}), absent=frozenset({"attn_head_norm"}),
     rehearsal=("3000000007", 30, frozenset({"loss", "ce_loss"}), 2 * 64),
     pairs={  # published key -> ModelConfig field
         "hidden_size": "d_model", "num_key_value_heads": "n_kv_heads",
@@ -187,7 +187,11 @@ FAMILY = Family(
         "train_attn_fwd_kernel_pct", "train_attn_bwd_kernel_pct", "train_moe_pct", "train_moe_gmm_mxu_pct",
         "train_moe_imbalance", "train_moe_router_pct", "train_optimizer_pct", "train_head_loss_pct",
         "train_scoped_pct", "train_kda_pct", "train_kda_scan_roofline_pct", "train_mfu_kda_moe_pct",
-        "train_kda_conv_pct"}),
+        "train_kda_conv_pct",
+        # PR 52: the attention part's pieces, the expert layer's dispatch and combine (the layer
+        # loop's own is next to nothing where one period runs unrolled: not listed)
+        "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
+        "train_attn_passes_pct"}),
     own_metrics=("train_kda_pct", "train_kda_scan_roofline_pct", "train_mfu_kda_moe_pct", "train_kda_conv_pct"),
 )
 

@@ -631,6 +631,34 @@ def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full
     assert _kernel_calls(text, "flash_attention_fwd") == forward
     assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (1, 0)
     assert not _xla_remats(text)
+    _every_instruction_of_the_part_carries_a_piece(text, cfg)
+
+
+def _every_instruction_of_the_part_carries_a_piece(text, cfg):
+    """In an attention part's compiled text every instruction whose `op_name` lies under `attn`
+    carries one of the part's five names (models/attn.py:SCOPES; a fusion its parts'), in the
+    forward pass, made again (`rematted_computation`) and in the backward pass
+    (`transpose(jvp(..))`): a `custom_vjp`'s backward rule is traced under its call site's names,
+    so the backward flash kernels and the rotate kernel's lie under `attn_core` (PR 52)."""
+    from ray_tpu.models import attn
+
+    paths = [p for p in re.findall(r'op_name="([^"]*)"', text) if "/attn/" in p]
+    assert paths
+    stray = [p for p in paths if sum(f"/{piece}/" in p for piece in attn.SCOPES) != 1]
+    assert not stray, stray[:5]
+    passes = {"forward": [p for p in paths if "transpose(" not in p],
+              "again": [p for p in paths if "rematted_computation" in p],
+              "backward": [p for p in paths if "transpose(" in p and "rematted_computation" not in p]}
+    wanted = {"attn_in_proj", "attn_core", "attn_out_proj"} | ({"attn_head_norm"} if cfg.attn_qk_norm else set())
+    for which, found in passes.items():
+        assert wanted <= {piece for piece in attn.SCOPES for p in found if f"/{piece}/" in p}, which
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and "op_name=" in ln]
+    assert kernels and all("/attn_core/" in ln for ln in kernels)
+    assert {k for k in ("fwd", "bwd_dq", "bwd_dkv") for ln in kernels if f"flash_attention_{k}/" in ln} == {"fwd", "bwd_dq", "bwd_dkv"}
+    if cfg.latent_attention:
+        assert all("/attn_in_proj/" in p for p in paths if "/mla_q/" in p or "/mla_kv/" in p)
+    elif cfg.head_dim >= 128:  # (narrower heads rotate in `jax.numpy`, in front of the padded kernels)
+        assert any("rope_fwd/" in ln for ln in kernels) and any("rope_bwd/" in ln for ln in kernels)
 
 
 def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):

@@ -22,7 +22,7 @@ from ray_tpu.train import init_state, make_optimizer, make_train_step, session
 from ray_tpu.util import telemetry
 
 LAPS = list(session._LOOP_COUNTERS)
-COUNTERS = LAPS + ["train_steps_total", "compiles_total", "compile_ns_total",
+COUNTERS = LAPS + ["train_steps_total", "train_slow_steps_total", "compiles_total", "compile_ns_total",
                    "worker_tasks_total", "worker_task_ns_total",
                    "gc_pause_ns_total", "gc_collections_total"]
 
@@ -307,6 +307,80 @@ def test_outside_a_session_the_callable_counts_into_the_same_integers():
     assert sum(v["count"] for v in hist["values"].values()) >= 5
 
 
+def _fresh_intervals(monkeypatch):
+    """The histogram of step intervals a process shares, replaced by one of this test's own: the
+    threshold is read from the steps the test drives and from no other test's."""
+    from ray_tpu.util.metrics import Histogram
+
+    hist = Histogram("train_step_interval_seconds_of_a_test", boundaries=session._STEP_INTERVAL_BOUNDARIES)
+    monkeypatch.setattr(session, "_step_interval", lambda: hist)
+    return hist
+
+
+def _drive(seconds_of_step):
+    """A step callable under the loop's clock, called once for every entry of `seconds_of_step`
+    on a thread (and so a loop) of its own. -> what `train.metrics()` read before and after."""
+    step = session.CountedStep(time.sleep)
+    reads = []
+
+    def run():
+        try:
+            reads.append(train.metrics())
+            for seconds in seconds_of_step:
+                step(seconds)
+            reads.append(train.metrics())
+        finally:
+            session._end_loop()
+
+    t = threading.Thread(target=run)
+    t.start()
+    t.join()
+    return reads
+
+
+def test_a_slow_step_leaves_one_line_and_one_count(monkeypatch, caplog):
+    """40 steps of 2 ms, one of 150 (an injected stall), 9 of 2 ms again: past the 16th step the
+    threshold is 4 x the median read from the histogram's buckets, so the stall, and no other
+    step, is one warning line of the worker's log (the interval and the median, the laps it
+    went to, the collector's pauses and the compiles of that step, the operating system's
+    numbers since the threshold was read) and one count of `train_slow_steps_total`."""
+    _fresh_intervals(monkeypatch)
+    with caplog.at_level("WARNING", logger=session.LOGGER.name):
+        before, after = _drive([0.002] * 40 + [0.15] + [0.002] * 9)
+    assert after["train_steps_total"] - before["train_steps_total"] == 50
+    assert after["train_slow_steps_total"] - before["train_slow_steps_total"] == 1
+    lines = [r.getMessage() for r in caplog.records if r.name == session.LOGGER.name]
+    assert len(lines) == 1 and "\n" not in lines[0], lines
+    line = lines[0]
+    found = re.search(r"step (\d+) took ([\d.]+) ms entry to entry against a median of ([\d.]+) ms; "
+                      r"laps dispatch ([\d.]+) report ([\d.]+) data ([\d.]+) user ([\d.]+) ms; "
+                      r"collector pauses ([\d.]+) ms, compiles (\d+); in the (\d+) steps and ([\d.]+) ms since", line)
+    assert found, line
+    step, took, median, dispatch, report, data, user, _, compiles, since_steps, since_ms = map(float, found.groups())
+    assert step == 41 and 150 <= took < 600 and 1.5 < median < took / session.SLOW_FACTOR
+    assert abs(dispatch + report + data + user - took) < 0.5  # the laps divide the interval (ms, rounded)
+    assert dispatch >= 150  # `time.sleep` stalled inside the callable: the program's own lap
+    assert compiles == 0 and since_steps == 41 - session.FIRST_REFRESH and since_ms > took
+    if os.path.exists("/proc/thread-self/schedstat"):  # Linux: every field of the operating system's
+        assert "process CPU" in line and "loop thread CPU" in line and "run-queue wait" in line
+        assert re.search(r"voluntary switches \d+, involuntary \d+$", line), line
+
+
+def test_no_step_is_slow_before_the_threshold_is_read_and_the_record_costs_no_system_call_a_step(monkeypatch, caplog):
+    """A stall among a loop's first steps (where a program compiles) is no record: the threshold
+    is read at the 16th step. And the operating system is asked for its numbers at a refresh
+    and after a slow step, never at a step's entry."""
+    _fresh_intervals(monkeypatch)
+    asked = []
+    real = session._os_numbers
+    monkeypatch.setattr(session, "_os_numbers", lambda steps: asked.append(steps) or real(steps))
+    with caplog.at_level("WARNING", logger=session.LOGGER.name):
+        before, after = _drive([0.001] * 5 + [0.1] + [0.001] * (session.FIRST_REFRESH + session.REFRESH_EVERY))
+    assert after["train_slow_steps_total"] == before["train_slow_steps_total"]
+    assert not [r for r in caplog.records if r.name == session.LOGGER.name]
+    assert asked == [session.FIRST_REFRESH, session.FIRST_REFRESH + session.REFRESH_EVERY]  # two refreshes in 86 steps
+
+
 def test_a_lap_inside_a_lap_hands_the_thread_back_to_the_one_that_was_open():
     seen = {}
 
@@ -376,7 +450,7 @@ def test_cluster_status_and_the_status_row_show_the_train_loops_clock(rt):
     session.record_setup("train.setup.worker_group", time.time_ns(), 1_500_000_000)
     status = state_api.cluster_status()
     tn = status["train"]
-    assert tn["steps"] >= 3
+    assert tn["steps"] >= 3 and tn["slow_steps"] == train.metrics()["train_slow_steps_total"]
     assert set(tn["loop_ms_per_step"]) == {"dispatch", "report", "data", "user"}
     assert tn["loop_ms_per_step"]["dispatch"] > 0
     assert tn["compiles"] >= 1 and tn["gc_collections"] >= 0
@@ -384,6 +458,7 @@ def test_cluster_status_and_the_status_row_show_the_train_loops_clock(rt):
     assert "group_failures" in tn
     row = [ln for ln in _render_status(status).splitlines() if ln.startswith("train      steps=")]
     assert row and "loop/step[dispatch:" in row[0] and "worker_group:1.5s" in row[0], row
+    assert f" slow={tn['slow_steps']} " in row[0], row
 
 
 def test_the_head_and_loss_share_leaves_out_what_is_fused_into_its_neighbours():
