@@ -9,16 +9,28 @@ public wrapper takes BSHD like the rest of the framework. GQA is handled in the
 BlockSpec index maps (kv head = q head // n_rep) — repeated KV heads are never
 materialized.
 
-Backward follows the standard two-kernel split: one pass computes dQ, one computes
-dK/dV, both recomputing a tile's probabilities from the saved logsumexp. The dK/dV
-pass works on the TRANSPOSED tile (kv rows, q columns): its four products are then
-plain or transposed-right-hand matmuls, the row statistics (logsumexp, delta) come in
-as lane vectors of `block_q` floats, and a kv head's group of query heads is summed in
-the kernel, so dK/dV are written once per kv head.
+The backward is ONE kernel a call (`_bwd_fused_kernel`, PR 53) wherever K and V of a kv
+head are one span (`_fuses`: up to 16,384 rows at head_dim 128 in bf16, 8,192 at 256:
+every shape the benchmark's cells have). A tile's probabilities are recomputed from the
+saved logsumexp ONCE and dV, dK and dQ are all written from them: five products and one
+exponential a tile (S, dV, dP, dK, dQ), where the standard two-kernel split, one pass for
+dQ and one for dK/dV, makes the scores, the exponentials and dP in each: seven and two.
+The kernel works on the TRANSPOSED tile (kv rows, q columns), as the dK/dV pass always
+did: its first four products are then plain or transposed-right-hand matmuls and the row
+statistics (logsumexp, delta) come in as lane vectors of `block_q` floats; the fifth,
+dQ += dS k, takes the one [bkv, bq] transpose a tile, of dS in the inputs' dtype, which
+the products do not wait on. A grid step owns one q tile (dQ is its own, in scratch),
+and dK and dV of the kv head stay in VMEM as f32 [Skv, D] over the steps of the group's
+query heads and q tiles, which the grid runs in order, and are rounded and written once a
+kv head: nothing that crosses grid steps goes through HBM. The VMEM asked for is derived
+from the shapes (K, V and dK, dV's blocks twice, the sums once: 64 MiB of a core's 128 at
+16,384 x 128, 22 MiB at 2,048). Where K and V are longer than a span the two kernels run
+as they did, `_bwd_dq_kernel` and `_bwd_dkv_kernel`, the latter summing a kv head's group
+of query heads in the kernel.
 
 The block a grid step fetches is not the tile a product computes. A step of the
-forward and dQ kernels owns one q tile and a SPAN of K/V rows, a step of dK/dV one kv
-tile and a span of the q rows of its group's query heads; a `fori_loop` inside the
+forward, backward and dQ kernels owns one q tile and a SPAN of K/V rows, a step of dK/dV
+one kv tile and a span of the q rows of its group's query heads; a `fori_loop` inside the
 kernel walks the span's compute tiles (`block_q` x `block_kv`), in ascending
 order, with the running statistics and accumulators in VMEM scratch throughout. The
 span is derived (`_tiling`): all of the sequence where its blocks fit `SPAN_VMEM_BYTES`
@@ -33,35 +45,46 @@ nearest span used, so that the pipeline issues no copy for it. A `window` (key j
 for query i where 0 <= i - j < window) is a second edge of the same kind, below the band,
 and a windowed call's work follows the band on both of its sides (PR 49). Its grids'
 last dimension runs over the spans a band reaches, counted from the band's own first
-(`_kv_spans`, `_q_spans`: at 16,384 positions inside a window of 2,048 the dK/dV grid is 3
-spans of 1,024 q rows a kv tile where the triangle's is 16); only where the sequence's end
+(`_kv_spans`, `_q_spans`: at 16,384 positions inside a window of 2,048 K and V are one span
+and a step walks its q tile's band; a dK/dV kernel of its own would run 3 spans of 1,024 q
+rows a kv tile where the triangle's grid is 16); only where the sequence's end
 cuts a band short does a step walk nothing, and it names the last span used. Inside a
 step the walk (`Band`, `_walk_band`) computes the tiles between the band's two edges
-whole, and the backward kernels the two an edge crosses, the diagonal's and the one
+whole, and the backward kernel (or two) the two an edge crosses, the diagonal's and the one
 `_band_depth` tiles below it, in the `EDGE_PIECE`-row pieces that hold a kept score
 (`_edge_pieces`): of the diagonal's tile the upper half of the q rows meets the first
 half of the kv rows alone, of the far edge's the lower half meets the second half alone,
-so a q tile costs dQ and dK/dV 4.5 tiles where the band needs 4.0 and whole tiles made 5
+so a q tile costs the backward 4.5 tiles where the band needs 4.0 and whole tiles made 5
 (`tile_counts`: 135 for 120.0 a head, 150 before and forward). A piece is a part of the
-rows that own the accumulators (q rows in dQ, kv rows in dK/dV), so a row's sums keep
-their order and the results their bits. Measured on a v5e at [2, 16384, 32 / 4, 128] bf16,
-window 2,048, a call alone (PERF.md, PR 49): forward 9.45 -> 9.42 ms, dQ 12.07 -> 11.45,
-dK/dV 16.92 -> 14.13 (the grid alone 14.85: 3,328 fewer steps, each of which ran the
-group's loop of 8 heads around an empty walk), dq, dk and dv bit-equal to the whole
-tiles'; 128-row pieces read 11.27 / 13.85 backward, 256 x 256 compute tiles 17.9 / 18.1
-/ 27.9. The forward kernel walks its edge tiles whole: in pieces it read 9.41 (`_fwd_kernel`).
-A windowed call's kernels carry `_window` behind their names.
+rows that own the accumulators (q rows in the one kernel and in dQ, kv rows in dK/dV), so
+those rows' sums keep their order and their results their bits. Measured on a v5e at
+[2, 16384, 32 / 4, 128] bf16, window 2,048, a call alone with the two backward kernels
+(PERF.md, PR 49): forward 9.45 -> 9.42 ms, dQ 12.07 -> 11.45, dK/dV 16.92 -> 14.13 (the grid
+alone 14.85: 3,328 fewer steps, each of which ran the group's loop of 8 heads around an
+empty walk), dq, dk and dv bit-equal to the whole tiles'; 128-row pieces read 11.27 / 13.85
+backward, 256 x 256 compute tiles 17.9 / 18.1 / 27.9. The forward kernel walks its edge
+tiles whole: in pieces it read 9.41 (`_fwd_kernel`). With the one backward kernel, in the
+Trinity cell's step (PERF.md, PR 53): forward 9.45 ms, backward 17.97 where dQ and dK/dV
+took 11.65 + 14.17, 82 % of the MXU's bf16 peak on the 135 tiles' worth it computes and
+73 % on the band's 120. A windowed call's kernels carry `_window` behind their names.
 Every product feeds the MXU the inputs' own dtype (bf16 in training) and accumulates in
 f32; scores, exponentials, logsumexp, delta and all accumulators are f32. Per-row
 statistics and segment ids are kept 128 equal lanes wide inside a kernel and travel
 between kernels as lane vectors (`_rows`): as [rows, 1] columns every use of them is a
 lane broadcast.
 
-Measured on a v5e at [6, 2048, 32/8, 128] bf16 causal, 512 x 512 tiles (PERF.md, PR 28):
-forward 2.05 ms, dQ 2.65 ms, dK/dV 3.00 ms a call, which is 64 / 74 / 87 % of the MXU's
-bf16 peak on the products the kernels execute (`tile_counts`: 10 tiles a (batch, head),
-the masked halves of the diagonal's included) and 51 / 59 / 70 % on the 8 that causal
-attention needs.
+Measured on a v5e, bf16 causal, 512 x 512 tiles, each kernel's own time in a traced train
+step (PERF.md, PR 53; a tile's product is 67.1 MFLOP, two of them forward and five backward):
+at [6, 2048, 32/8, 128] forward 2.05 ms and backward 3.78 a call (dQ 2.66 + dK/dV 3.00 = 5.66
+before), which is 64 / 87 % of the MXU's bf16 peak on the products the kernels execute
+(`tile_counts`: 10 tiles a (batch, head), the masked halves of the diagonal's included) and
+51 / 69 % on the 8 that causal attention needs; at [2, 16384, 32/4, 128] forward 30.67 and
+backward 63.37 (40.95 + 52.32 = 93.26 before): 75 / 91 % on the 528 tiles a head executed,
+73 / 88 % on the 512.03 needed; at [1, 8192, 20/20, 256] 4.49 and 10.03 (6.10 + 8.27), at
+[4, 8192, 32/8, 64] on padded lanes 16.14 and 32.99 (21.57 + 26.94), under the
+block-diffusion mask at [1, 2 x 8192, 32/4, 128] 8.57 and 17.49 (11.45 + 15.65). The
+backward asks for 64 MiB of VMEM at the three long shapes (of which Mosaic reports 34 used
+at 16,384 x 128 and 52 at 8,192 x 256), 22 MiB at 2,048.
 """
 from __future__ import annotations
 
@@ -168,11 +191,14 @@ class TileCounts(NamedTuple):
 def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim: int = 128,
                 itemsize: int = 2, n_rep: int = 1, kernel: str = "fwd",
                 window: Optional[int] = None, block_diffusion: Optional[int] = None) -> TileCounts:
-    """What a (batch, query head) costs the forward (`kernel` "fwd") or the dQ kernel
-    ("dq"), or a (batch, kv head with its `n_rep` query heads) the dK/dV kernel ("dkv"):
-    from the same `_tiling`, grid lengths and bands (`_kv_band`, `_q_band`) the kernels are
-    built from. A causal tile is computed if any of its scores is kept (kv position <= q
-    position, and inside a `window` more than q position - window); under a window the
+    """What a (batch, query head) costs the forward (`kernel` "fwd") or the backward kernel
+    ("dq": the one kernel of a call whose K and V are a span, `_fuses`, which makes a tile's
+    five products once, and the dQ kernel of a longer one, which makes three of the seven;
+    both walk a q tile's kv tiles), or a (batch, kv head with its `n_rep` query heads) that
+    longer call's dK/dV kernel ("dkv"): from the same `_tiling`, grid lengths and bands
+    (`_kv_band`, `_q_band`) the kernels are built from. A causal tile is computed if any of
+    its scores is kept (kv position <= q position, and inside a `window` more than q
+    position - window); under a window the
     backward kernels compute the two tiles an edge of the band crosses in the pieces that
     hold one: with 512 x 512 tiles, 256-row pieces and a window of 2,048 a q tile meets 5
     kv tiles forward and 4.5 backward where the band needs 4.0. Under `block_diffusion` (the
@@ -335,15 +361,15 @@ def _rows(x, block: int):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
-def _pallas_call(kernel, *, name: str,
-                 semantics=("parallel", "parallel", "parallel", "arbitrary"), **kw):
+def _pallas_call(kernel, *, name: str, semantics=("parallel", "parallel", "parallel", "arbitrary"),
+                 vmem_limit_bytes: int = VMEM_LIMIT_BYTES, **kw):
     """The flash kernels' grid has four dimensions, the last of which accumulates.
     `name` is the operation's name in the device trace (the benchmark's kernel
     metrics select by it)."""
     return pl.pallas_call(
         kernel, name=name, interpret=_interpret(),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=semantics, vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            dimension_semantics=semantics, vmem_limit_bytes=vmem_limit_bytes),
         **kw)
 
 
@@ -435,7 +461,7 @@ def _lanes_of(ref, at: tuple, part: Optional[tuple]):
     """The lane vector `ref[at]` ([1, P]: a tile's positions side by side), or of it the
     `part` a piece takes: read so from the ref, whose lanes a load can start at any whole
     vreg (a value cut there keeps an offset that Mosaic refuses to broadcast down the rows)."""
-    return ref[at] if part is None else ref[(*at, slice(None), _cut(part))]
+    return ref[(*at, slice(None), _cut(part))]
 
 
 def _edge_rows(bq: int, bkv: int, window: Optional[int]) -> Optional[int]:
@@ -713,7 +739,7 @@ def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None, bd=None):
     return q_spec, kv_spec, stat_spec, seg_specs
 
 
-def _unpack(refs, keeps, has_seg: bool):
+def _unpack(refs, keeps, has_seg: bool, seg_keeps=(2, 3)):
     """(inputs, segment-id pair, the remaining refs): each input's block indexed down
     to as many trailing dimensions as `keeps` says, the segment ids of the tile's rows
     to two, and those of the span's columns, a lane vector a tile, to three."""
@@ -721,7 +747,7 @@ def _unpack(refs, keeps, has_seg: bool):
         return r.at[(0,) * (len(r.shape) - keep)]
 
     n_in = len(keeps)
-    segs = [last(refs[n_in], 2), last(refs[n_in + 1], 3)] if has_seg else [None, None]
+    segs = [last(refs[n_in + i], keep) for i, keep in enumerate(seg_keeps)] if has_seg else [None, None]
     return [last(r, keep) for r, keep in zip(refs, keeps)], segs, refs[n_in + 2 * has_seg:]
 
 
@@ -771,6 +797,60 @@ def _fwd(
 
 
 # ------------------------------------------------------------------ backward kernels
+
+
+def _bwd_fused_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, seg_kv_ref, seg_q_ref,
+    dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+    *, scale, causal, bq, bkv, n_rep, window=None, bd=None,
+):
+    """The whole backward of one q tile against ALL of K and V ([Skv, D], `_fuses`), on the
+    transposed tile [bkv, bq] as dK/dV's: a kept tile's scores, exponentials and dP are made
+    once and dV, dK and dQ written from them, five products where the two kernels run seven.
+    dQ is the step's own (`dq_scr`); dK and dV of the kv head are summed over the steps of its
+    group's query heads and q tiles in `dk_scr`, `dv_scr` ([Skv, D] f32, which stay in VMEM:
+    the grid's heads and q tiles run in order) and written by the group's last step. lse_ref,
+    delta_ref and seg_q_ref are the q tile's lane vectors [1, P], seg_kv_ref [Skv, 128]. The
+    walk is dQ's (`_walk_kv`, a band's edge tiles in its pieces), so a q row's sums keep
+    that kernel's order."""
+    hi, qi = pl.program_id(1), pl.program_id(2)
+    n = k_ref.shape[0] // bkv
+    rep, nq = hi % n_rep, pl.num_programs(2)
+
+    def clear(t):
+        dk_scr[_at(t, bkv)] = jnp.zeros((bkv, dk_scr.shape[1]), jnp.float32)
+        dv_scr[_at(t, bkv)] = jnp.zeros((bkv, dv_scr.shape[1]), jnp.float32)
+
+    pl.when((rep == 0) & (qi == 0))(lambda: _walk(0, n, n, clear))
+    dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    def tile(t, piece=None, own=False):
+        cols, kv_part = piece or (None, None)
+        at = _at(t, bkv, kv_part)
+        q, do, k = q_ref[_cut(cols)], do_ref[_cut(cols)], k_ref[at]
+        st = _dot(k, q, _NT) * scale  # [bkv, bq]
+        if bd is not None:
+            keep = _keep_bd(st.shape, 1, qi, t, bq, bkv, bd, own)
+        else:
+            keep = _keep(st.shape, 1, qi, t, bq, bkv, causal, None if seg_kv_ref is None else seg_kv_ref.at[_at(t, bkv)],
+                         None if seg_q_ref is None else _lanes_of(seg_q_ref, (), cols), window, piece)
+        if keep is not None:
+            st = jnp.where(keep, st, NEG_INF)
+        pt = jnp.exp(st - _lanes_of(lse_ref, (), cols)[:, :st.shape[1]])
+        dv_scr[at] += _dot(pt.astype(do.dtype), do, _NN)
+        dpt = _dot(v_ref[at], do, _NT)
+        dst = (pt * (dpt - _lanes_of(delta_ref, (), cols)[:, :st.shape[1]]) * scale).astype(q.dtype)
+        dk_scr[at] += _dot(dst, q, _NN)
+        dq_scr[_cut(cols)] += _dot(dst.T, k, _NN)  # the one transpose a tile, in fast memory
+
+    _walk_kv(qi, 0, n, tile, causal, bq, bkv, window, pieces=True, bd=bd)
+    dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+
+    def write(t):
+        dk_ref[_at(t, bkv)] = dk_scr[_at(t, bkv)].astype(dk_ref.dtype)
+        dv_ref[_at(t, bkv)] = dv_scr[_at(t, bkv)].astype(dv_ref.dtype)
+
+    pl.when((rep == n_rep - 1) & (qi == nq - 1))(lambda: _walk(0, n, n, write))
 
 
 def _bwd_dq_kernel(
@@ -880,6 +960,58 @@ def _bwd_dkv_kernel(
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
+def _fuses(t: Tiling, skv: int) -> bool:
+    """Whether a call's backward is ONE kernel (`_bwd_fused_kernel`): where K and V are one span
+    (`_tiling`: their blocks, both pipeline buffers, fit `SPAN_VMEM_BYTES`), because then a kv
+    head's dK and dV fit beside them as f32 [Skv, D], in as many bytes again (two arrays of
+    four bytes where K/V are two arrays twice over of two), and so do their output blocks. At
+    head width 128 in bf16 that is 16,384 positions, at 256 8,192: every shape the cells
+    have. A longer sequence runs the two kernels that re-make a tile's scores."""
+    return t.kv_span == skv
+
+
+def _bwd_fused(q, k, v, seg, dout, stats, scale, causal, t: Tiling, window, bd):
+    """dq, dk, dv of a call whose K and V are one span (`_fuses`), from ONE kernel: grid (b, h, q
+    blocks, 1), the heads of a group and their q blocks run in order over the kv head's K, V, dK
+    and dV, whose blocks' index maps do not move with them."""
+    b, h, sq, d = q.shape
+    skv, n_rep, bq, bkv = k.shape[2], h // k.shape[1], t.bq, t.bkv
+    has_seg = seg is not None
+    q_spec, kv_spec, stat_spec, _ = _q_major_specs(d, n_rep, causal, t, False, window, bd)
+    args = [q, k, v, dout, *stats]
+    in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
+    if has_seg:  # of the tile's rows (kv) and columns (q), as dK/dV's
+        args += [seg["kv_col"], _rows(seg["q"], bq)]
+        in_specs += [pl.BlockSpec((1, skv, 128), lambda bi, hi, qi, sj: (bi, 0, 0)),
+                     pl.BlockSpec((1, 1, 1, _lane_pad(bq)), lambda bi, hi, qi, sj: (bi, qi, 0, 0))]
+
+    def kernel(*refs):
+        ins, segs, (dq_ref, dk_ref, dv_ref, *scratch) = _unpack(refs, (2,) * 6, has_seg, (2, 2))
+        _bwd_fused_kernel(*ins, *segs, dq_ref.at[0, 0], dk_ref.at[0, 0], dv_ref.at[0, 0], *scratch,
+                          scale=scale, causal=causal, bq=bq, bkv=bkv, n_rep=n_rep, window=window, bd=bd)
+
+    rows = skv * _lane_pad(d)
+    return _pallas_call(
+        kernel,
+        name=_named("flash_attention_bwd_dkv_dq", window, bd),
+        semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
+        # K, V and dK, dV's blocks twice (the pipeline's), their sums once, the rows' segment
+        # ids; and what the two kernels leave the compute tile
+        vmem_limit_bytes=(VMEM_LIMIT_BYTES - SPAN_VMEM_BYTES + 2 * rows * (4 * k.dtype.itemsize + 4)
+                          + has_seg * 2 * skv * 128 * 4),
+        grid=(b, h, sq // bq, 1),
+        in_specs=in_specs,
+        # dQ is written over dO, block for block (a grid step reads the one and writes the other of
+        # its own q tile, once): with all three gradients live at once the GLM step's temporaries
+        # were 0.17 GB above the two kernels' (6.71 for 6.54 GB, compiled for a v5e; PERF.md, PR 53)
+        input_output_aliases={3: 0},
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32), pltpu.VMEM((skv, d), jnp.float32),
+                        pltpu.VMEM((skv, d), jnp.float32)],
+    )(*args)
+
+
 def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=None):
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -891,6 +1023,8 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=N
     # delta_i = sum_d(dO * O): rowwise, cheap in XLA.
     delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     stats = [lse, _rows(delta, bq)]
+    if _fuses(t, skv):
+        return _bwd_fused(q, k, v, seg, dout, stats, scale, causal, t, window, bd)
 
     # --- dQ pass: grid (b, h, q blocks, kv spans)
     q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, t, has_seg, window, bd)

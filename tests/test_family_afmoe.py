@@ -149,8 +149,16 @@ def _made_up(flops, config, model):
                 "op_scopes": {"%fusion.9 = f32[4]": ["mlp"]}}
     bare = {"result": {**result, "trace": mlp_only}}
     kernels = {"pattern": "^%?\\w*flash_attention_\\w*window", "work": "window_attention_step_work"}
+    # the trace of a program whose backward is ONE kernel a call (PR 53; the one above is its parent's)
+    one = {"result": {**result, "trace": {"busy_s": 4.0, "op_scopes": scopes, "op_seconds": {
+        **{op: s for op, s in ops.items() if "_bwd_" not in op},
+        "%transpose_jvp_flash_attention_bwd_dkv_dq_window__.4 = (bf16[4]) custom-call()": 0.10,
+        "%transpose_jvp_flash_attention_bwd_dkv_dq__.1 = (bf16[4]) custom-call()": 0.2}}}}
     return result, [
         ("train_kernel_roofline", "train_attn_window_roofline_pct", {}, 100 * 5 * band["flops"] / 197e12 / 0.20),
+        ("train_kernel_roofline", "train_attn_window_roofline_pct", one, 100 * 5 * band["flops"] / 197e12 / 0.16),
+        ("trace_op_share", "train_attn_bwd_kernel_pct", {}, 100 * (0.05 + 0.09 + 0.3) / 4.0),
+        ("trace_op_share", "train_attn_bwd_kernel_pct", one, 100 * (0.10 + 0.2) / 4.0),
         ("trace_scope_share", "train_attn_window_pct", {}, 100 * (0.04 + 0.03) / 4.0),
         ("trace_scope_share", "train_attn_full_pct", {}, 100 * (0.06 + 0.2) / 4.0),
         ("train_kernel_roofline", kernels, bare, None),

@@ -144,7 +144,14 @@ def _made_up(flops, config, model):
     bare = {"result": {**result, "trace": mlp_only}}
     unscoped = {"result": {**result, "trace": {**mlp_only, "op_seconds": ops}}}
     kernels = {"pattern": "flash_attention_", "work": "attention_step_work"}
+    # the trace of a program whose backward is ONE kernel a call (PR 53; the one above is its parent's)
+    one = {"result": {**result, "trace": {"busy_s": 2.0, "op_scopes": scopes, "op_seconds": {
+        **{op: s for op, s in ops.items() if "_bwd_" not in op},
+        "%transpose_jvp_flash_attention_bwd_dkv_dq__.1 = (bf16[4]) custom-call()": 0.10}}}}
     return result, [
+        ("train_kernel_roofline", "train_attn_w64_roofline_pct", one, 100 * 5 * core["flops"] / 197e12 / 0.16),
+        ("trace_op_share", "train_attn_bwd_kernel_pct", {}, 100 * (0.05 + 0.09) / 2.0),
+        ("trace_op_share", "train_attn_bwd_kernel_pct", one, 100 * 0.10 / 2.0),
         ("train_scan_roofline", "train_sconv_roofline_pct", {}, 100 * 5 * conv["flops"] / 197e12 / 0.10),
         ("trace_scope_share", "train_sconv_pct", {}, 100 * 0.10 / 2.0),
         ("train_kernel_roofline", "train_attn_w64_roofline_pct", {}, 100 * 5 * core["flops"] / 197e12 / 0.20),

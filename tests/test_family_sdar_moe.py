@@ -151,7 +151,14 @@ def _made_up(flops, config, model):
                 "op_scopes": {"%fusion.9 = f32[4]": ["mlp"]}}
     bare = {"result": {**result, "trace": mlp_only, "series": {"step_s": [0.4]}}}
     kernels = {"pattern": "^%?\\w*flash_attention_\\w*_bd[\\w.]* = ", "work": "block_diffusion_attention_step_work"}
+    # the trace of a program whose backward is ONE kernel a call (PR 53; the one above is its parent's)
+    one = {"result": {**result, "trace": {"busy_s": 4.0, "op_scopes": scopes, "op_seconds": {
+        **{op: s for op, s in ops.items() if "_bwd_" not in op},
+        "%transpose_jvp_flash_attention_bwd_dkv_dq_bd__.4 = (bf16[4]) custom-call()": 0.5}}}}
     return result, [
+        ("train_kernel_roofline", "train_attn_bd_roofline_pct", one, 100 * 5 * work["flops"] / 197e12 / 0.75),
+        ("trace_op_share", "train_attn_bwd_kernel_pct", {}, 100 * (0.35 + 0.4) / 4.0),
+        ("trace_op_share", "train_attn_bwd_kernel_pct", one, 100 * 0.5 / 4.0),
         ("train_kernel_roofline", "train_attn_bd_roofline_pct", {}, 100 * 5 * work["flops"] / 197e12 / 1.0),
         ("trace_scope_share", "train_attn_bd_pct", {}, 100 * (0.04 + 0.25) / 4.0),
         ("trace_scope_share", "train_bd_rows_pct", {}, 100 * 0.01 / 4.0),

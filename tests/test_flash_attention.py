@@ -14,6 +14,24 @@ def _rand(shape, key, dtype=jnp.float32):
     return jax.random.normal(jax.random.PRNGKey(key), shape, jnp.float32).astype(dtype)
 
 
+def _kernel_names(jaxpr):
+    """The names of a program's Pallas kernels, nested calls included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _kernel_names(sub)
+    return names
+
+
+def _flash_names(suffix="", one_backward=True):
+    """A differentiated flash call's kernels, sorted: the forward kernel and ONE backward kernel
+    where K and V are one span (`fa._fuses`; PR 53), else dQ's and dK/dV's beside it."""
+    backward = ["bwd_dkv_dq"] if one_backward else ["bwd_dkv", "bwd_dq"]
+    return [f"flash_attention_{kernel}{suffix}" for kernel in (*backward, "fwd")]
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_fwd_matches_reference(causal):
     b, s, h, d = 2, 128, 4, 64
@@ -68,8 +86,9 @@ def _packed(b, s, cuts):
                             .astype(jnp.int32), (b, s))
 
 
-# Tilings of the (q, kv) plane: every case runs the forward kernel and both backward
-# kernels against the f32 reference on f32 copies of the same inputs, with a random
+# Tilings of the (q, kv) plane: every case runs the forward kernel and the backward (ONE
+# kernel where K and V are a span, PR 53; dQ's and dK/dV's two under a budget that cuts them
+# in spans) against the f32 reference on f32 copies of the same inputs, with a random
 # cotangent. Under `causal` a tiling with more than one block a side has tiles below
 # the diagonal, on it, and above it (not visited). A grid step fetches a span of the
 # other side's sequence, all of it where it fits `SPAN_VMEM_BYTES`, and walks the
@@ -152,6 +171,9 @@ def test_fwd_and_grads_over_tilings(case, monkeypatch):
         return (o, *grads)
 
     got = run(flash_attention, q, k, v, block_q=bq, block_kv=bkv)
+    names = _kernel_names(jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+        q, k, v, causal=causal, segment_ids=seg, block_diffusion=bd, block_q=bq, block_kv=bkv).sum()))(q).jaxpr)
+    assert sorted(names) == _flash_names("_bd" if bd else "", one_backward=not budget), names
     want = run(attention_reference, *(x.astype(jnp.float32) for x in (q, k, v)))
     if bd:  # the mask by hand, once: query r keeps key c as the objective states it
         r, c = np.arange(s)[:, None], np.arange(s)[None, :]
@@ -254,8 +276,9 @@ def test_span_is_derived():
 
 
 def test_products_take_the_inputs_dtype():
-    """Every matrix product of the three kernels is fed the call's own dtype and
-    accumulates in f32; nothing else in the kernels is bf16."""
+    """Every matrix product of the kernels is fed the call's own dtype and accumulates in
+    f32; nothing else in the kernels is bf16 (but the transposed dS, which is the fifth
+    product's operand). The backward kernel makes five products a tile: S, dV, dP, dK, dQ."""
     q = _rand((1, 128, 4, 64), 0, jnp.bfloat16)
     k = _rand((1, 128, 2, 64), 1, jnp.bfloat16)
 
@@ -272,7 +295,7 @@ def test_products_take_the_inputs_dtype():
         return found
 
     found = dots(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, k).jaxpr, [])
-    assert len(found) == 2 + 3 + 4  # forward, dQ, dK/dV
+    assert len(found) == 2 + 5  # forward; backward (the two kernels' 3 + 4 before PR 53)
     for ins, out in found:
         assert ins == (jnp.bfloat16, jnp.bfloat16) and out == jnp.float32, (ins, out)
 
@@ -372,17 +395,6 @@ def _attention_block(seg):
     return block
 
 
-def _kernel_names(jaxpr):
-    """The names of a program's Pallas kernels, nested calls included."""
-    names = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            names.append(eqn.params["name"])
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            names += _kernel_names(sub)
-    return names
-
-
 def _remat_cfg(policy):
     import dataclasses
 
@@ -418,7 +430,7 @@ def test_a_block_under_remat_full_is_the_block_bit_for_bit(d, segments):
         return jax.jit(fn)(x, w), _kernel_names(jax.make_jaxpr(fn)(x, w).jaxpr)
 
     plain, names = run(block)
-    assert sorted(names) == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq", "flash_attention_fwd"]
+    assert sorted(names) == _flash_names()
     full, names_full = run(llama._maybe_remat(block, _remat_cfg("full")))
     assert sorted(names_full) == sorted(names), names_full  # the forward kernel once
     for a, r in zip(jax.tree.leaves(full), jax.tree.leaves(plain)):
@@ -474,8 +486,8 @@ def test_the_flash_kernels_run_at_head_width_64_on_padded_lanes():
         return jnp.sum(w * attention_reference(q, k, v, causal=True))
 
     jaxpr = str(jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(q, k, v))
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
-        assert f"name={name}" in jaxpr, name
+    for name in _flash_names():
+        assert f"name={name} " in jaxpr or f"name={name}\n" in jaxpr, name
     out = fa.flash_attention(q, k, v, causal=True, block_q=128, block_kv=128)
     assert out.shape == q.shape
     np.testing.assert_allclose(out, attention_reference(q, k, v, causal=True), atol=2e-5)
@@ -596,8 +608,7 @@ def test_windowed_fwd_and_grads_match_the_reference(case, monkeypatch):
     got, names = run(flash_attention, q, k, v, block_q=block, block_kv=block,
                      rope=(pos, theta) if rotate else None)
     suffix = "" if window >= s else "_window"
-    assert sorted(n for n in names if n.startswith("flash")) == [
-        f"flash_attention_bwd_dkv{suffix}", f"flash_attention_bwd_dq{suffix}", f"flash_attention_fwd{suffix}"]
+    assert sorted(n for n in names if n.startswith("flash")) == _flash_names(suffix, one_backward=not budget)
 
     def plain(q, k, v, **kw):
         if rotate:

@@ -74,9 +74,11 @@ def _pallas_grids(jaxpr):
     (1, 32768, 32, 8, False),  # K and V of a kv head do not fit a grid step: two spans of 16,384
 ])
 def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
-    from ray_tpu.ops.flash_attention import flash_attention, tile_counts
+    from ray_tpu.ops.flash_attention import _fuses, _tiling, flash_attention, tile_counts
 
     d = 128
+    one_backward = _fuses(_tiling(s, s, 512, 512, d, 2, h // kv), s)  # K and V of a kv head are one span (PR 53)
+    assert one_backward == (s <= 16384)
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
     k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
     seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip) if segments else None
@@ -89,7 +91,8 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
         q, k, v, causal=True, segment_ids=seg)).lower(q, k, k, seg).compile()
     bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, seg).compile()
     assert "tpu_custom_call" in fwd.as_text()
-    assert bwd.as_text().count("tpu_custom_call") >= 2  # dQ and dK/dV kernels
+    # the forward kernel and the backward's one (PR 53), or dQ's and dK/dV's where K and V are two spans
+    assert bwd.as_text().count("tpu_custom_call") == (2 if one_backward else 3)
     # a grid step owns a span of K/V and walks its 512-wide tiles in the kernel: the
     # forward program's grid is the short one, not a step a (q tile, kv tile)
     grid, = _pallas_grids(jax.make_jaxpr(
@@ -107,19 +110,22 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
             rx = re.compile(json.load(f)["args"]["pattern"])
         kernels = [ln.strip() for ln in bwd.as_text().splitlines()
                    if "tpu_custom_call" in ln and rx.search(ln.strip())]
-        assert len(kernels) == (1 if "fwd" in path else 2), (path, kernels)
+        assert len(kernels) == (1 if "fwd" in path or one_backward else 2), (path, kernels)
+        assert "fwd" in path or all(("bwd_dkv_dq" in ln.split(" = ")[0]) == one_backward for ln in kernels), kernels
 
 
 def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_band_alone(one_chip, on_tpu):
     """[2, 16384, 32 / 4, 128] inside a window of 2,048 with the rotation deferred into the
-    rotate kernel (trinitymini-train-ep16share-s16384's four `W` parts): forward, dQ and
-    dK/dV compile under their own names, which the accepted kernel metrics still find; K and
-    V are one 16,384-row span, a group's Q/dO spans of 1,024 of which the dK/dV grid holds
-    the 3 a kv tile's band reaches (16 before PR 49); and the kernels' own bands, steps and
-    index maps over every grid step: what is computed is the band's tiles, by the backward
-    kernels the two an edge crosses in their 256-row pieces (135 tiles' worth a head for
-    120.0 needed; forward 150, as all three before), and no step of the dK/dV grid walks
-    nothing but the steps past the sequence's end."""
+    rotate kernel (trinitymini-train-ep16share-s16384's four `W` parts): the forward kernel
+    and the ONE backward kernel (PR 53: K and V are one 16,384-row span, so dK and dV of a kv
+    head stay in VMEM beside them, 64 MiB asked for and granted) compile under their own names,
+    which the accepted kernel metrics still find; and the kernels' own bands, steps and index
+    maps over every grid step: what is computed is the band's tiles, by the backward kernel
+    (dQ's walk, q tile by q tile) the two an edge crosses in their 256-row pieces (135 tiles'
+    worth a head for 120.0 needed; forward 150, as all three before PR 49). The two kernels
+    that run where K and V are longer than a span keep their own walks: a group's Q/dO in spans
+    of 1,024 of which the dK/dV grid holds the 3 a kv tile's band reaches, and no step of it
+    walks nothing but the steps past the sequence's end."""
     import numpy as np
 
     from ray_tpu.ops import flash_attention as fa
@@ -135,14 +141,19 @@ def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_ban
     grad = jax.grad(loss, argnums=(0, 1, 2))
     compiled = jax.jit(grad).lower(q, k, k, pos).compile()
     text = compiled.as_text()
-    for path, count in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 2)):
+    for path, name in (("train_attn_fwd_kernel_pct", "fwd"), ("train_attn_bwd_kernel_pct", "bwd_dkv_dq")):
         with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
             rx = re.compile(json.load(f)["args"]["pattern"])
         kernels = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]
-        assert len(kernels) == count and all("_window" in ln.split(" = ")[0] for ln in kernels), (path, kernels)
+        assert len(kernels) == 1 and f"flash_attention_{name}_window_" in kernels[0].split(" = ")[0], (path, kernels)
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", "train_attn_window_roofline_pct.json")) as f:
         rx = re.compile(json.load(f)["args"]["pattern"])
-    assert len([ln for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]) == 3
+    assert len([ln for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]) == 2
+    # the backward kernel's VMEM request is derived from the shapes and granted: 16 MiB for the compute tile
+    # and 3 x 16 for K, V, dK, dV's blocks and the f32 sums, of which the compiled kernel uses 34 MiB
+    asked, used = re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\].*?'
+                            r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\]', kernels[0]).groups()
+    assert int(asked) == 64 << 20 and (32 << 20) < int(used) < int(asked)
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 1.0e9  # the kernels keep no scores
     t = fa._tiling(s, s, tile, tile, d, 2, h // kv)
@@ -151,7 +162,7 @@ def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_ban
     q_spans = fa._q_spans(s, s, t, window)
     assert (fa._kv_spans(s, s, t, window), q_spans, s // t.q_span) == (1, 3, 16)
     grids = _pallas_grids(jax.make_jaxpr(grad)(q, k, k, pos).jaxpr)
-    assert (b, h, n, 1) in grids and (b, kv, n, q_spans) in grids and (b, kv, n, s // t.q_span) not in grids
+    assert fa._fuses(t, s) and grids.count((b, h, n, 1)) == 2 and not any(g[1] == kv for g in grids if len(g) == 4)
     # the band, by brute force over the tiles: tile (qi, kj) holds a kept score; the share of each computed
     first, last = np.arange(n) * tile, np.arange(n) * tile + tile - 1
     band = (last[:, None] - first[None, :] >= 0) & (first[:, None] - last[None, :] < window)
@@ -190,10 +201,14 @@ def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_ban
 
 def test_an_unwindowed_call_lowers_to_the_kernels_it_lowered_to(one_chip, on_tpu):
     """[2, 16384, 32 / 4, 128] without a window (Trinity-Mini's full part; the six other
-    cells pass none either): the Mosaic bodies of forward, dQ and dK/dV, decoded and printed
-    without source locations, are the text they were at the parent of PR 49 (sha256 of it),
-    with and without segment ids. A change that means to move the un-windowed kernels
-    replaces the digests, and says so."""
+    cells pass none either): the Mosaic bodies of the forward kernel and of the ONE backward
+    kernel, decoded and printed without source locations, are the text they were (sha256 of
+    it), with and without segment ids: the forward kernel's at the parent of PR 49, the
+    backward's as PR 53 wrote it in place of dQ's and dK/dV's (`6addad1f9a3a3bd3`,
+    `82b7f2e4eb1f2f2a`; packed `385dfe5649f5a98c`, `3b5d8d4e375bb10e`). At [1, 32768, 32 / 8,
+    128] K and V of a kv head are two spans and those two kernels run (`_fuses`): all three
+    bodies are the text they were at PR 53's parent. A change that means to move the
+    un-windowed kernels replaces the digests, and says so."""
     import base64
     import hashlib
 
@@ -202,15 +217,18 @@ def test_an_unwindowed_call_lowers_to_the_kernels_it_lowered_to(one_chip, on_tpu
 
     from ray_tpu.ops.flash_attention import flash_attention
 
-    b, s, h, kv, d = 2, 16384, 32, 4, 128
-    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
-    k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
-    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
-    was = {
-        False: ["80956e8bb4c0098a", "6addad1f9a3a3bd3", "82b7f2e4eb1f2f2a"],
-        True: ["33f3a81fd5eb83bb", "385dfe5649f5a98c", "3b5d8d4e375bb10e"],
+    d = 128
+    was = {  # (b, s, h, kv, packed): forward, then the backward's
+        (2, 16384, 32, 4, False): ["80956e8bb4c0098a", "831a9af23037739b"],
+        (2, 16384, 32, 4, True): ["33f3a81fd5eb83bb", "4d92c7fd04a254d4"],
+        (1, 32768, 32, 8, False): ["d2bec9831c724fb3", "cdc19bd57b229996", "cf085ce9dc4181d6"],
+        (1, 32768, 32, 8, True): ["c6d29eb38e065aa6", "1c5043421b33c712", "32d43e1f9c72e4cc"],
     }
-    for packed, digests in was.items():
+    for (b, s, h, kv, packed), digests in was.items():
+        q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+        k = jax.ShapeDtypeStruct((b, s, kv, d), jnp.bfloat16, sharding=one_chip)
+        seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one_chip)
+
         def loss(q, k, v, seg):
             return jnp.sum(flash_attention(q, k, v, causal=True, segment_ids=seg if packed else None).astype(jnp.float32))
 
@@ -223,13 +241,14 @@ def test_an_unwindowed_call_lowers_to_the_kernels_it_lowered_to(one_chip, on_tpu
             with ctx:
                 asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
             got.append(hashlib.sha256(asm.encode()).hexdigest()[:16])
-        assert got == digests, (packed, got)
+        assert got == digests, (s, packed, got)
 
 
 def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
     """[1, 8192, 20/20, 256], the latent-attention cell's shape (glm47flash-train-
     ep8share-s8192): heads twice the lane width, no grouping, K and V of a head exactly
-    the span budget; forward, dQ and dK/dV under the names the trace metrics select by."""
+    the span budget, dK and dV of a head as much again in f32; the forward kernel and the one
+    backward kernel (PR 53) under the names the trace metrics select by."""
     from ray_tpu.ops.flash_attention import flash_attention, tile_counts
 
     b, s, h, d = 1, 8192, 20, 256
@@ -240,11 +259,12 @@ def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile().as_text()
     calls = [ln.strip().split(" = ")[0] for ln in text.splitlines() if "tpu_custom_call" in ln]
-    for name in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+    assert len(calls) == 2
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dkv_dq"):
         assert sum(name in c for c in calls) == 1, (name, calls)
     grids = _pallas_grids(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr)
     steps = tile_counts(s, s, True, 512, 512, head_dim=d).grid_steps
-    assert (b, h, 16, steps // 16) in grids and (b, h, 16, 2) in grids, grids  # one kv span; two q spans
+    assert grids == [(b, h, 16, steps // 16)] * 2, grids  # one kv span, forward and backward
 
 
 def _glm_share():
@@ -499,14 +519,15 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
     assert len(_instructions(text, "while", "moe_router")) == loops * bodies
     assert not _xla_remats(text)
     # under `full` a rematerialised layer keeps the forward flash kernel's results: a call an
-    # attention block forward, none made again, one of each backward kernel (PR 43)
-    blocks = _kernel_calls(text, "flash_attention_bwd_dq")[0]
-    assert blocks >= 1 and _kernel_calls(text, "flash_attention_bwd_dkv") == (blocks, 0)
-    assert _kernel_calls(text, "flash_attention_fwd") == (blocks, 0)
+    # attention block forward, none made again (PR 43), ONE backward kernel a call (PR 53)
+    blocks = _kernel_calls(text, "flash_attention_bwd_dkv_dq")[0]
+    assert blocks >= 1 and _kernel_calls(text, "flash_attention_fwd") == (blocks, 0)
     # a windowed part runs the windowed kernels, as often; no attention falls to an XLA path
     windowed = sum(LAYER_KINDS[c].windowed for c in cfg.layer_pattern)
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq", "flash_attention_bwd_dkv"):
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv_dq"):
         assert _kernel_calls(text, f"{kernel}_window") == (windowed, 0)
+    for suffix in ("", "_window"):  # the two kernels of a sequence longer than a span of K and V run nowhere
+        assert _kernel_calls(text, f"flash_attention_bwd_dq{suffix}") == _kernel_calls(text, f"flash_attention_bwd_dkv{suffix}") == (0, 0)
     assert blocks + windowed == sum(LAYER_KINDS[c].mixer == "attn" for c in cfg.layer_pattern) + cfg.mtp_depth or not cfg.layer_pattern
     assert attention_ops.xla_fallback_count == fallbacks
     # a recurrent mixer keeps its input product's result under `full` (PR 48): the product once a part
@@ -545,13 +566,13 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
 def test_the_block_diffusion_cells_step_compiles_inside_its_memory_and_walks_288_tiles_a_head(one_chip, on_tpu):
     """The whole step of `sdar30b-train-ep8share-s8192` as its configuration file states it (five
     layers; the batch a loader makes: tokens, masked, p_mask), compiled for the described chip:
-    [1, 16384] rows through the layers under the block-diffusion mask. The three kernels carry
-    `_bd` behind their names, which the accepted kernel metrics and the cell's own roofline
+    [1, 16384] rows through the layers under the block-diffusion mask. The two kernels (forward;
+    the ONE backward kernel, PR 53) carry `_bd` behind their names, which the accepted kernel metrics and the cell's own roofline
     metric find; the forward kernel runs once a layer (its `out` and logsumexp kept under
     `full`); three router products a layer and the pick's loops, nothing made again by XLA, no
     attention on an XLA path; 15.18 of 15.75 GB (PR 50: six layers were 17.31 and do not fit).
-    The grids: a step a q tile forward and dQ (K/V of the doubled row are one span), 16 spans of
-    a group's Q/dO a kv tile; 288 tiles a head each way by `tile_counts`."""
+    The grids: a step a q tile forward and backward (K/V of the doubled row are one span, and so
+    dK and dV of a kv head stay in VMEM); 288 tiles a head each way by `tile_counts`."""
     import importlib
 
     from ray_tpu.models import llama
@@ -574,13 +595,14 @@ def test_the_block_diffusion_cells_step_compiles_inside_its_memory_and_walks_288
     step = make_train_step(cfg, tx)
     compiled = step.lower(state, batch).compile()
     text = compiled.as_text()
-    for path, count in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 2), ("train_attn_bd_roofline_pct", 3)):
+    for path, count in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 1), ("train_attn_bd_roofline_pct", 2)):
         with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
             rx = re.compile(json.load(f)["args"]["pattern"])
         kernels = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]
         assert len(kernels) == count and all("_bd" in ln.split(" = ")[0] for ln in kernels), (path, kernels)
-    assert _kernel_calls(text, "flash_attention_fwd_bd") == _kernel_calls(text, "flash_attention_bwd_dq_bd") == (1, 0)
-    assert _kernel_calls(text, "flash_attention_bwd_dkv_bd") == (1, 0) and _kernel_calls(text, "flash_attention_fwd") == (0, 0)
+    assert _kernel_calls(text, "flash_attention_fwd_bd") == _kernel_calls(text, "flash_attention_bwd_dkv_dq_bd") == (1, 0)
+    assert _kernel_calls(text, "flash_attention_bwd_dq_bd") == _kernel_calls(text, "flash_attention_bwd_dkv_bd") == (0, 0)
+    assert _kernel_calls(text, "flash_attention_fwd") == (0, 0)
     assert len(_instructions(text, "convolution", "moe_router")) == 3 and len(_instructions(text, "while", "moe_router")) == 2
     assert not _xla_remats(text) and attention_ops.xla_fallback_count == fallbacks
     memory = compiled.memory_analysis()
@@ -590,8 +612,8 @@ def test_the_block_diffusion_cells_step_compiles_inside_its_memory_and_walks_288
     t = fa._tiling(2 * n, 2 * n, 512, 512, 128, 2, 8)
     assert (t.kv_span, t.q_span) == (16384, 1024)
     grids = _pallas_grids(jax.make_jaxpr(step._jitted)(state, batch).jaxpr)
-    assert (b, 32, 32, 1) in grids and (b, 4, 32, 16) in grids
-    for kernel, heads in (("fwd", 1), ("dq", 1), ("dkv", 8)):
+    assert fa._fuses(t, 2 * n) and grids.count((b, 32, 32, 1)) == 2 and (b, 4, 32, 16) not in grids  # forward; backward (PR 53)
+    for kernel, heads in (("fwd", 1), ("dq", 1), ("dkv", 8)):  # ("dq": the one backward kernel's walk too)
         counts = fa.tile_counts(2 * n, 2 * n, False, 512, 512, n_rep=heads, kernel=kernel, block_diffusion=4)
         assert counts.tiles_computed == heads * 288 and round(counts.tiles_needed / heads, 1) == 256.1
 
@@ -607,7 +629,7 @@ def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full
     logsumexp are kept by name (`ops.attention.FLASH_NAMES`) and the program holds ONE
     `flash_attention_fwd`, where it held a second in the rematerialised layer (PR 43); under
     `dots`, which keeps neither (PERF.md section 7, after PR 26 (1)), it holds two as before.
-    One `_bwd_dq` and one `_bwd_dkv` either way."""
+    One backward kernel, `_bwd_dkv_dq`, either way (PR 53)."""
     from ray_tpu.models import attn, llama
 
     cfg, file = _cell_file(config)
@@ -629,7 +651,8 @@ def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full
 
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp, pos).compile().as_text()
     assert _kernel_calls(text, "flash_attention_fwd") == forward
-    assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (1, 0)
+    assert _kernel_calls(text, "flash_attention_bwd_dkv_dq") == (1, 0)
+    assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (0, 0)
     assert not _xla_remats(text)
     _every_instruction_of_the_part_carries_a_piece(text, cfg)
 
@@ -639,7 +662,7 @@ def _every_instruction_of_the_part_carries_a_piece(text, cfg):
     carries one of the part's five names (models/attn.py:SCOPES; a fusion its parts'), in the
     forward pass, made again (`rematted_computation`) and in the backward pass
     (`transpose(jvp(..))`): a `custom_vjp`'s backward rule is traced under its call site's names,
-    so the backward flash kernels and the rotate kernel's lie under `attn_core` (PR 52)."""
+    so the backward flash kernel and the rotate kernel's lie under `attn_core` (PR 52)."""
     from ray_tpu.models import attn
 
     paths = [p for p in re.findall(r'op_name="([^"]*)"', text) if "/attn/" in p]
@@ -654,7 +677,7 @@ def _every_instruction_of_the_part_carries_a_piece(text, cfg):
         assert wanted <= {piece for piece in attn.SCOPES for p in found if f"/{piece}/" in p}, which
     kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and "op_name=" in ln]
     assert kernels and all("/attn_core/" in ln for ln in kernels)
-    assert {k for k in ("fwd", "bwd_dq", "bwd_dkv") for ln in kernels if f"flash_attention_{k}/" in ln} == {"fwd", "bwd_dq", "bwd_dkv"}
+    assert {k for k in ("fwd", "bwd_dkv_dq") for ln in kernels if f"flash_attention_{k}/" in ln} == {"fwd", "bwd_dkv_dq"}
     if cfg.latent_attention:
         assert all("/attn_in_proj/" in p for p in paths if "/mla_q/" in p or "/mla_kv/" in p)
     elif cfg.head_dim >= 128:  # (narrower heads rotate in `jax.numpy`, in front of the padded kernels)
@@ -679,7 +702,7 @@ def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
 
 def test_flash_attention_compiles_at_head_width_64(one_chip, on_tpu):
     """[4, 8192, 32 / 8, 64], the LFM2 cell's shape (lfm2moe-train-ep8share-b4-s8192): heads
-    half the lane width run the same three kernels on zero-padded lanes (Mosaic refuses a
+    half the lane width run the same kernels (forward; the backward's one, PR 53) on zero-padded lanes (Mosaic refuses a
     block 64 lanes wide: "must be aligned to tiling (128)"), under the names the trace
     metrics select by; the rotation in front of them is jax.numpy's (no rotate kernel)."""
     from ray_tpu.models.llama import rope
@@ -697,9 +720,9 @@ def test_flash_attention_compiles_at_head_width_64(one_chip, on_tpu):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, k, pos).compile().as_text()
     calls = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert len(calls) == 3 and not any("rope_" in ln.split(" = ")[0] for ln in calls)
-    for path, n in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 2),
-                    ("train_attn_w64_roofline_pct", 3)):
+    assert len(calls) == 2 and not any("rope_" in ln.split(" = ")[0] for ln in calls)
+    for path, n in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 1),
+                    ("train_attn_w64_roofline_pct", 2)):
         with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
             rx = re.compile(json.load(f)["args"]["pattern"])
         assert sum(bool(rx.search(ln)) for ln in calls) == n, (path, calls)
