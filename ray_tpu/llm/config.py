@@ -185,7 +185,8 @@ def _served(cfg):
     """The model configuration, if the engine can serve it; else what it lacks, by name
     (models/llama.py trains these; ROADMAP.md B4-B6 has the serving side)."""
     missing = [what for has, what in (
-        (cfg.latent_attention, "a paged cache of latents (llm/paged.py holds K and V a head)"),
+        (cfg.latent_attention, "a paged cache of latents (llm/paged.py holds K and V a head, both head_dim wide: "
+                               "none of a latent and a shared key, nor of v heads of another width than k's)"),
         (cfg.n_dense_layers, "a layer loop over stacks of more than one kind (llm/model_runner.py)"),
         (cfg.moe_dropless, "the dropless expert layer under decode (inactive slots are not masked)"),
         (cfg.mtp_depth, "multi-token-prediction modules as drafts of the verify window"),

@@ -1,6 +1,7 @@
 """Attention as a layer's mixer, behind its own norm and residual: grouped-query heads
 (an output gate a channel, a norm a head of q and k where the family has them) or latent
-attention (MLA), rotated or not. One shape with the other mixers (models/ssm.py, kda.py,
+attention (MLA: q through a latent of its own or by one product, v heads of their own width
+beside q's and k's), rotated or not. One shape with the other mixers (models/ssm.py, kda.py,
 sconv.py): `AXES`, `init`, `mixer`, `n_params` and what llama.py's table of layer kinds
 reads of each. `rms_norm` and `rope` are here because attention is their first user; the
 serving programs (llm/model_runner.py) call the parts, `qkv_proj` and `attn_out`, around
@@ -65,14 +66,15 @@ def init(ks: jax.Array, cfg: ModelConfig) -> dict:
         raise NotImplementedError("an output gate or a norm a head on latent attention")
     qr, kvr, rd = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     ka, kb = jax.random.split(ks[1])
+    # q through its latent, or (q_lora_rank 0: Kimi Linear's q_lora_rank null) by one product
+    q = ({"wq_a": norm(ks[0], (d, qr), s_in), "q_norm": jnp.ones((qr,), jnp.float32),
+          "wq_b": norm(ka, (qr, nh, hd), qr**-0.5)} if qr else {"wq": norm(ks[0], (d, nh, hd), s_in)})
     return {
-        **out,
-        "wq_a": norm(ks[0], (d, qr), s_in), "q_norm": jnp.ones((qr,), jnp.float32),
-        "wq_b": norm(ka, (qr, nh, hd), qr**-0.5),
+        **out, **q,
         # the latent and, behind it, the rotated key every head shares
         "wkv_a": norm(kb, (d, kvr + rd), s_in), "kv_norm": jnp.ones((kvr,), jnp.float32),
-        "wkv_b": norm(ks[2], (kvr, nh, cfg.qk_nope_head_dim + cfg.v_head_dim), kvr**-0.5),
-        "wo": norm(ks[3], (nh, cfg.v_head_dim, d), s_out),
+        "wkv_b": norm(ks[2], (kvr, nh, cfg.qk_nope_head_dim + cfg.v_dim), kvr**-0.5),
+        "wo": norm(ks[3], (nh, cfg.v_dim, d), s_out),
     }
 
 
@@ -80,10 +82,11 @@ def n_params(cfg: ModelConfig) -> int:
     """What `init` makes, counted."""
     d, h = cfg.d_model, cfg.heads_held
     if cfg.latent_attention:
-        return (d * cfg.q_lora_rank + cfg.q_lora_rank * h * cfg.head_dim
+        qr = cfg.q_lora_rank
+        return ((d * qr + qr * h * cfg.head_dim + qr if qr else d * h * cfg.head_dim)
                 + d * (cfg.kv_lora_rank + cfg.qk_rope_head_dim)
-                + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_head_dim)
-                + h * cfg.v_head_dim * d + cfg.q_lora_rank + cfg.kv_lora_rank + d)
+                + cfg.kv_lora_rank * h * (cfg.qk_nope_head_dim + cfg.v_dim)
+                + h * cfg.v_dim * d + cfg.kv_lora_rank + d)
     return (d * cfg.head_dim * ((2 + cfg.attn_output_gate) * h + 2 * cfg.kv_heads_held)
             + 2 * cfg.head_dim * cfg.attn_qk_norm + d)
 
@@ -110,9 +113,9 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 def qkv_proj(x: jax.Array, lp: dict, cfg: ModelConfig, positions: Optional[jax.Array]):
     """Attention's inputs for one layer: norm, the projections, a norm a head of q and k
     where the layer has one (cfg.attn_qk_norm: before the rotation), RoPE.
-    x [B, S, D], positions [B, S] -> q [B, S, H, hd], k and v [B, S, KV, hd].
-    Without positions q and k come back un-rotated: the caller hands the rotation on
-    (latent attention rotates a slice of its heads and always needs them)."""
+    x [B, S, D], positions [B, S] -> q [B, S, H, hd], k [B, S, KV, hd] and v [B, S, KV, cfg.v_dim].
+    Without positions q and k come back un-rotated: the caller hands the rotation on, or
+    there is none (latent attention rotates a slice of its heads here, and only here)."""
     dt = x.dtype
     with jax.named_scope("attn_in_proj"):  # (the latent path keeps `mla_q` / `mla_kv` inside it)
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
@@ -137,24 +140,29 @@ def rope_pairs_to_halves(d: int):
     return [2 * i for i in range(d // 2)] + [2 * i + 1 for i in range(d // 2)]
 
 
-def _latent_qkv(h: jax.Array, lp: dict, cfg: ModelConfig, positions: jax.Array):
+def _latent_qkv(h: jax.Array, lp: dict, cfg: ModelConfig, positions: Optional[jax.Array]):
     """Latent attention's q, k and v from the normed input h [B, S, D]: q through its
-    low-rank latent; k's un-rotated part and v from the shared latent, k's rotated part
-    one key for all heads. Nothing is absorbed: what comes out is plain multi-head
-    attention's input, [B, S, H, nope + rope] twice and [B, S, H, v]."""
+    low-rank latent where the layer has one, else by one product; k's first part and v from
+    the shared latent, k's second part one key for all heads; the second parts of q and k
+    rotated where there are `positions` (None: cfg.attention_rotation false, Kimi Linear's
+    mla_use_nope: both are used as they come). Nothing is absorbed: what comes out is plain
+    multi-head attention's input, [B, S, H, nope + rope] twice and [B, S, H, v], v at its own
+    width."""
     dt = h.dtype
     nope, kvr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    if cfg.v_head_dim != cfg.head_dim:
-        raise NotImplementedError(
-            f"latent attention with v heads {cfg.v_head_dim} wide beside q/k heads "
-            f"{cfg.head_dim} wide: ops.attention takes one width")
     with jax.named_scope("mla_q"):
-        cq = rms_norm(jnp.einsum("bsd,dr->bsr", h, _w(lp["wq_a"], dt)), lp["q_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsr,rhk->bshk", cq, _w(lp["wq_b"], dt))
-        q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+        if "wq_a" in lp:
+            cq = rms_norm(jnp.einsum("bsd,dr->bsr", h, _w(lp["wq_a"], dt)), lp["q_norm"], cfg.norm_eps)
+            q = jnp.einsum("bsr,rhk->bshk", cq, _w(lp["wq_b"], dt))
+        else:
+            q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
+        if positions is not None:
+            q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
     with jax.named_scope("mla_kv"):
         ckv = jnp.einsum("bsd,dr->bsr", h, _w(lp["wkv_a"], dt))
-        k_rot = rope(ckv[:, :, None, kvr:], positions, cfg.rope_theta)  # [B, S, 1, rope]
+        k_rot = ckv[:, :, None, kvr:]  # [B, S, 1, rope]
+        if positions is not None:
+            k_rot = rope(k_rot, positions, cfg.rope_theta)
         kv = jnp.einsum("bsr,rhk->bshk", rms_norm(ckv[..., :kvr], lp["kv_norm"], cfg.norm_eps),
                         _w(lp["wkv_b"], dt))
         k = jnp.concatenate(

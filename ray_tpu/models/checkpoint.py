@@ -342,10 +342,66 @@ def _glm4_moe_lite_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+def _kimi_linear_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `kimi_linear` keys (Kimi-Linear-48B-A3B) as ModelConfig fields. A published layer is
+    a token mixer then a feed-forward part, each behind its own norm and residual: two
+    characters of the layer pattern, the mixer `K` where `linear_attn_config.kda_layers` (1-based)
+    lists the layer (Kimi Delta Attention, beta in (0, 1) unless a `kda_allow_neg_eigval` key
+    says otherwise) and `*` where `full_attn_layers` does (latent attention: `q_lora_rank` null is
+    q by one product, `mla_use_nope` true is no rotation anywhere, `v_head_dim` its own width),
+    the feed-forward part `-` in the `first_k_dense_replace` leading layers and `E` after them
+    (sigmoid scores, the choice by score + bias, gates renormalised and scaled, beside shared
+    experts), so n_layers counts parts. `use_grouped_topk` with one group is the plain top-k.
+    Everything is held; a share is an override (`kda_n_heads`, `experts_held`). What the program
+    does not run is refused by name; what config.json does not state is
+    benchmarks/configs/kimi-linear-48b-a3b-train-ep32.json's `assumed`. Weights' names are not
+    mapped."""
+    linear = hf.get("linear_attn_config") or {}
+    n = hf["num_hidden_layers"]
+    kda_at, full_at = (list(linear.get(key) or ()) for key in ("kda_layers", "full_attn_layers"))
+    refused = [what for has, what in (
+        (linear.get("head_dim") is None or linear.get("num_heads") is None,
+         "no linear_attn_config (head_dim, num_heads)"),
+        (sorted(kda_at + full_at) != list(range(1, n + 1)),
+         "linear_attn_config's kda_layers and full_attn_layers do not name every layer 1..num_hidden_layers once"),
+        (full_at and not hf.get("kv_lora_rank"), "full attention layers without a latent (kv_lora_rank)"),
+        (hf.get("num_key_value_heads", hf["num_attention_heads"]) != hf["num_attention_heads"],
+         "latent attention with fewer key/value heads than query heads (num_key_value_heads)"),
+        (hf.get("num_expert_group", 1) != 1 or hf.get("topk_group", 1) != 1,
+         "group-limited routing (num_expert_group / topk_group > 1)"),
+        (hf.get("num_nextn_predict_layers", 0), "MTP modules (num_nextn_predict_layers)"),
+        (not hf.get("moe_renormalize", True), "gates that are not normalised (moe_renormalize false)"),
+        (hf.get("moe_router_activation_func", "sigmoid") != "sigmoid",
+         f"moe_router_activation_func {hf.get('moe_router_activation_func')!r}"),
+        (hf.get("moe_layer_freq", 1) != 1, "dense layers among the expert layers (moe_layer_freq)"),
+        (hf.get("rope_scaling") is not None, f"rope_scaling {hf.get('rope_scaling')!r}"),
+        (hf.get("hidden_act", "silu") != "silu", f"hidden_act {hf.get('hidden_act')!r}"),
+        (not hf.get("num_experts", 0), "layers without routed experts"),
+    ) if has]
+    if refused:
+        raise ValueError("kimi_linear as this config.json states it is not supported: " + "; ".join(refused))
+    dense = hf.get("first_k_dense_replace", 0)
+    pattern = "".join(("K" if i + 1 in kda_at else "*") + ("-" if i < dense else "E") for i in range(n))
+    return dict(
+        n_layers=len(pattern), layer_pattern=pattern,
+        max_seq_len=hf.get("model_max_length", hf.get("max_position_embeddings", 2048)),
+        q_lora_rank=hf.get("q_lora_rank") or 0, kv_lora_rank=hf.get("kv_lora_rank") or 0,
+        qk_nope_head_dim=hf.get("qk_nope_head_dim", 0), qk_rope_head_dim=hf.get("qk_rope_head_dim", 0),
+        v_head_dim=hf.get("v_head_dim", 0), attention_rotation=not hf.get("mla_use_nope", False),
+        kda_n_heads=linear["num_heads"], kda_head_dim=linear["head_dim"],
+        kda_conv_taps=linear.get("short_conv_kernel_size", 4),
+        kda_neg_eigval=bool(hf.get("kda_allow_neg_eigval", False)),
+        n_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_token"],
+        d_ff_expert=hf["moe_intermediate_size"], n_shared_experts=hf.get("num_shared_experts", 0),
+        moe_capacity_factor=0.0, moe_aux_loss_coef=0.0, moe_scoring="sigmoid", moe_select_bias=True,
+        moe_route_scale=float(hf.get("routed_scaling_factor", 1.0)),
+    )
+
+
 # a family's keys as ModelConfig fields, by its `model_type` (config_from_hf)
 _FAMILY_FIELDS = {"glm4_moe_lite": _glm4_moe_lite_fields, "nemotron_h": _nemotron_h_fields,
                   "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields,
-                  "afmoe": _afmoe_fields, "sdar_moe": _sdar_moe_fields}
+                  "afmoe": _afmoe_fields, "sdar_moe": _sdar_moe_fields, "kimi_linear": _kimi_linear_fields}
 
 
 def config_to_hf(cfg: ModelConfig) -> Dict[str, Any]:

@@ -66,10 +66,11 @@ class ModelConfig:
     # model_type=mixtral; irrelevant when moe_top_k > 1 (both renormalize).
     moe_top1_renorm: bool = False
     # --- what a published config.json of another family states (0 / default = absent) ---
-    # Latent attention (MLA): q through a rank-`q_lora_rank` latent, k and v through one
-    # of rank `kv_lora_rank` beside a rotated key of `qk_rope_head_dim` shared by all
-    # heads; a head's q and k are `qk_nope_head_dim` un-rotated + `qk_rope_head_dim`
-    # rotated wide, its v `v_head_dim`.
+    # Latent attention (MLA): q through a rank-`q_lora_rank` latent (0 beside kv_lora_rank > 0:
+    # by one product, no latent and no norm), k and v through one of rank `kv_lora_rank` beside
+    # a rotated key of `qk_rope_head_dim` shared by all heads; a head's q and k are
+    # `qk_nope_head_dim` un-rotated + `qk_rope_head_dim` rotated wide (attention_rotation false:
+    # neither part is rotated), its v `v_head_dim`, which need not be as wide as q and k.
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -219,10 +220,16 @@ class ModelConfig:
 
     @property
     def head_dim(self) -> int:
-        """Width of a head's q and k (and v, which every family here has as wide)."""
+        """Width of a head's q and k (v's is `v_dim`)."""
         if self.latent_attention:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.attn_head_dim or self.d_model // self.n_heads
+
+    @property
+    def v_dim(self) -> int:
+        """Width of a head's v and of attention's output a head: latent attention's published
+        v_head_dim (Kimi Linear: 128 beside q and k of 192), else q's and k's."""
+        return self.v_head_dim if self.latent_attention else self.head_dim
 
     @property
     def heads_held(self) -> int:
@@ -601,6 +608,45 @@ register_config(
         moe_scoring="softmax",
         diffusion_block=4,
         diffusion_mask_token=255,
+    )
+)
+register_config(
+    # Toy of the kimi_linear family (Kimi-Linear-48B-A3B) for the CPU tests: every published layer
+    # two parts of the pattern (a mixer, then a feed-forward part); Kimi-Delta-Attention mixers with
+    # beta in (0, 1) three to one with latent attention that has NO q latent, NO rotation and v
+    # heads (16) narrower than q's and k's (16 + 8); a leading dense layer, then sigmoid-routed
+    # SwiGLU experts beside a shared one, the gates scaled. Everything held; tests cut shares of
+    # the experts.
+    ModelConfig(
+        name="kimi-linear-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=10,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=96,
+        max_seq_len=128,
+        rope_theta=1e4,
+        dtype="float32",
+        layer_pattern="K-KEKE*EKE",
+        kda_n_heads=4,
+        kda_head_dim=16,
+        kda_chunk=8,
+        kda_neg_eigval=False,
+        attention_rotation=False,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        n_experts=16,
+        moe_top_k=3,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=40,
+        n_shared_experts=1,
+        moe_scoring="sigmoid",
+        moe_route_scale=2.446,
+        moe_select_bias=True,
     )
 )
 register_config(

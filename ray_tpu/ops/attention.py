@@ -70,7 +70,7 @@ def attention_reference(
 ) -> jax.Array:
     """Pure-XLA attention. Numerically the ground truth for the Pallas kernel tests.
 
-    q: [B, Sq, H, D]; k/v: [B, Skv, Hkv, D]. Returns [B, Sq, H, D].
+    q: [B, Sq, H, D]; k: [B, Skv, Hkv, D]; v: [B, Skv, Hkv, Dv]. Returns [B, Sq, H, Dv].
     `segment_ids`: [B, Skv] int array; attention only within equal segments (packing).
     `q_offset`: kv index of query row 0 (decode-with-cache); default aligns the ends.
     `kv_valid_len`: kv slots >= this are masked out (padded cache tail).
@@ -108,7 +108,7 @@ def attention_reference(
     # Rows with no valid kv (fully masked) softmax to NaN; zero them instead.
     probs = jnp.nan_to_num(jax.nn.softmax(logits, axis=-1))
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs.astype(v.dtype), v)
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[-1]).astype(q.dtype)
 
 
 def attention_chunked(
@@ -154,7 +154,7 @@ def attention_chunked(
     # inside the scan body so the repeated copies never exist over the full Skv.
     hkv = k.shape[2]
     kb = k.reshape(b, n_blk, block_kv, hkv, d).transpose(1, 0, 2, 3, 4)
-    vb = v.reshape(b, n_blk, block_kv, hkv, d).transpose(1, 0, 2, 3, 4)
+    vb = v.reshape(b, n_blk, block_kv, hkv, v.shape[-1]).transpose(1, 0, 2, 3, 4)
     seg_b = (
         None
         if segment_ids is None
@@ -199,7 +199,7 @@ def attention_chunked(
 
     m0 = jnp.full((b, h, sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, h, sq), jnp.float32)
-    acc0 = jnp.zeros((b, sq, h, d), jnp.float32)
+    acc0 = jnp.zeros((b, sq, h, v.shape[-1]), jnp.float32)
     xs = (blk_idx, kb, vb) if seg_b is None else (blk_idx, kb, vb, seg_b)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), xs)
     l_t = l.transpose(0, 2, 1)[..., None]  # [B, Sq, H, 1]
@@ -232,7 +232,7 @@ def _log_fallback(q_shape, k_shape, impl: str) -> None:
     xla_fallback_count += 1
     logging.getLogger(__name__).warning(
         "attention: TPU shape q=%s kv=%s is not Mosaic-tileable "
-        "(head_dim not 64 or a multiple of 128, or seq block alignment); using %s XLA path",
+        "(a head width of q/k or of v that is no multiple of 64, or seq block alignment); using %s XLA path",
         tuple(q_shape), tuple(k_shape), impl,
     )
 
@@ -328,7 +328,8 @@ def attention(
         # geometries the kernel can't tile must fall back to XLA or TPU compile fails
         from . import flash_attention as _fa
 
-        tileable = _fa.supports(q.shape[1], k.shape[1], q.shape[-1], block_diffusion=block_diffusion)
+        tileable = _fa.supports(q.shape[1], k.shape[1], q.shape[-1], block_diffusion=block_diffusion,
+                                v_head_dim=v.shape[-1])
         if (on_tpu and tileable and q_offset is None and kv_valid_len is None
                 and (same_len or not causal)):
             impl = "pallas"

@@ -7,7 +7,10 @@ running (max, sum, acc) statistics.
 Layout inside the kernel is [B, H, S, D] ("BHSD") so the S×D tiles are contiguous; the
 public wrapper takes BSHD like the rest of the framework. GQA is handled in the
 BlockSpec index maps (kv head = q head // n_rep) — repeated KV heads are never
-materialized.
+materialized. q and k share a width D and v, the output and dO another, Dv (PR 54:
+latent attention's 192 beside 128): the scores, dK and dQ are D wide, the weighted values,
+dP's contraction and dV are Dv wide, every block, accumulator and resident sum at its own.
+Where the two are equal (every other family) the programs are the ones they were.
 
 The backward is ONE kernel a call (`_bwd_fused_kernel`, PR 53) wherever K and V of a kv
 head are one span (`_fuses`: up to 16,384 rows at head_dim 128 in bf16, 8,192 at 256:
@@ -130,11 +133,14 @@ SPAN_VMEM_BYTES = VMEM_LIMIT_BYTES // 2
 
 
 def supports(sq: int, skv: int, head_dim: int, block_q: int = BLOCK_Q,
-             block_kv: int = BLOCK_KV, block_diffusion: Optional[int] = None) -> bool:
+             block_kv: int = BLOCK_KV, block_diffusion: Optional[int] = None,
+             v_head_dim: Optional[int] = None) -> bool:
     """Whether the kernels can tile this geometry. Mosaic tiles the lane (last) dim at 128
     and sublanes at 8, and a sequence longer than one compute tile must be a whole number of
     them (`_block_sizes`): head_dim 16, seq 20 or seq 520 would fail the TPU compile ("slice
-    shape must be aligned to tiling"). Heads 64 wide run on padded lanes (`NARROW_HEAD`)."""
+    shape must be aligned to tiling"). `head_dim` is q's and k's width and `v_head_dim` v's
+    and the output's (None: as wide): each whole vregs of 128 lanes, or whole halves of one
+    (64, 192: `NARROW_HEAD`), which run on lanes padded with zeros to the next whole vreg."""
     def seq_ok(n: int, block: int) -> bool:
         return n % 8 == 0 and (n <= block or n % block == 0)
 
@@ -144,7 +150,8 @@ def supports(sq: int, skv: int, head_dim: int, block_q: int = BLOCK_Q,
         except ValueError:
             return False
         sq = skv = bd.half
-    return (head_dim == NARROW_HEAD or head_dim % 128 == 0) and seq_ok(sq, block_q) and seq_ok(skv, block_kv)
+    widths = (head_dim, head_dim if v_head_dim is None else v_head_dim)
+    return all(w > 0 and w % NARROW_HEAD == 0 for w in widths) and seq_ok(sq, block_q) and seq_ok(skv, block_kv)
 
 
 def _block_sizes(sq: int, skv: int, bq: int, bkv: int):
@@ -174,9 +181,10 @@ class Tiling(NamedTuple):
     q_span: int
 
 
-def _tiling(sq, skv, bq, bkv, d, itemsize, n_rep=1) -> Tiling:
+def _tiling(sq, skv, bq, bkv, d, itemsize, n_rep=1, dv=None) -> Tiling:
+    """`d`: q's and k's width; `dv`: v's, the output's and dO's (None: as wide)."""
     bq, bkv = _block_sizes(sq, skv, bq, bkv)
-    row = 2 * 2 * d * itemsize  # two arrays a side, two pipeline buffers each
+    row = 2 * (d + (d if dv is None else dv)) * itemsize  # two arrays a side (K, V; Q, dO), two pipeline buffers each
     # dK/dV: of every query head of the group, and the rows' logsumexp and delta, which
     # a lane vector a tile holds in 8 sublanes
     return Tiling(bq, bkv, _span(skv, bkv, row), _span(sq, bq, n_rep * (row + 2 * 2 * 4 * 8)))
@@ -190,7 +198,8 @@ class TileCounts(NamedTuple):
 
 def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim: int = 128,
                 itemsize: int = 2, n_rep: int = 1, kernel: str = "fwd",
-                window: Optional[int] = None, block_diffusion: Optional[int] = None) -> TileCounts:
+                window: Optional[int] = None, block_diffusion: Optional[int] = None,
+                v_head_dim: Optional[int] = None) -> TileCounts:
     """What a (batch, query head) costs the forward (`kernel` "fwd") or the backward kernel
     ("dq": the one kernel of a call whose K and V are a span, `_fuses`, which makes a tile's
     five products once, and the dQ kernel of a longer one, which makes three of the seven;
@@ -207,7 +216,7 @@ def tile_counts(sq: int, skv: int, causal: bool, bq: int, bkv: int, *, head_dim:
     bd = _block_diffusion(block_diffusion, sq, skv, bq, bkv, causal, window)
     if bd is not None:
         bq, bkv = min(bq, bd.half), min(bkv, bd.half)
-    t = _tiling(sq, skv, bq, bkv, head_dim, itemsize, n_rep)
+    t = _tiling(sq, skv, bq, bkv, head_dim, itemsize, n_rep, v_head_dim)
     nq, nk = sq // t.bq, skv // t.bkv
     window = _band(window, sq, skv, causal)
     steps, heads = ((nk * _q_spans(sq, skv, t, window), n_rep) if kernel == "dkv"
@@ -739,6 +748,12 @@ def _q_major_specs(d, n_rep, causal, t: Tiling, has_seg, window=None, bd=None):
     return q_spec, kv_spec, stat_spec, seg_specs
 
 
+def _v_wide_specs(dv, d, q_spec, kv_spec, *args):
+    """(the output's and dO's, v's) BlockSpecs: q's and k's own where v is as wide (every
+    family but one: the program is then the one it was), else the same blocks `dv` wide."""
+    return (q_spec, kv_spec) if dv == d else _q_major_specs(dv, *args)[:2]
+
+
 def _unpack(refs, keeps, has_seg: bool, seg_keeps=(2, 3)):
     """(inputs, segment-id pair, the remaining refs): each input's block indexed down
     to as many trailing dimensions as `keeps` says, the segment ids of the tile's rows
@@ -754,7 +769,7 @@ def _unpack(refs, keeps, has_seg: bool, seg_keeps=(2, 3)):
 def _fwd(
     q: jax.Array,  # [B, H, Sq, D]
     k: jax.Array,  # [B, Hkv, Skv, D]
-    v: jax.Array,
+    v: jax.Array,  # [B, Hkv, Skv, Dv]
     seg: Optional[dict],  # _segment_lanes(), or None
     scale: float,
     causal: bool,
@@ -764,11 +779,12 @@ def _fwd(
     bd: Optional[BlockDiffusion] = None,
 ):
     b, h, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
-    t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize)
+    _, hkv, skv, dv = v.shape
+    t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize, dv=dv)
     bq, bkv = t.bq, t.bkv
     has_seg = seg is not None
     q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, h // hkv, causal, t, has_seg, window, bd)
+    o_spec, v_spec = _v_wide_specs(dv, d, q_spec, kv_spec, h // hkv, causal, t, False, window, bd)
     args = [q, k, v] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def kernel(*refs):
@@ -780,16 +796,16 @@ def _fwd(
         kernel,
         name=_named("flash_attention_fwd", window, bd),
         grid=(b, h, sq // bq, _kv_spans(sq, skv, t, window)),
-        in_specs=[q_spec, kv_spec, kv_spec] + seg_specs,
-        out_specs=[q_spec, stat_spec],
+        in_specs=[q_spec, kv_spec, v_spec] + seg_specs,
+        out_specs=[o_spec, stat_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq // bq, 1, _lane_pad(bq)), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((_lane_pad(bq), 128), jnp.float32),
         ],
     )(*args)
@@ -963,10 +979,11 @@ def _bwd_dkv_kernel(
 def _fuses(t: Tiling, skv: int) -> bool:
     """Whether a call's backward is ONE kernel (`_bwd_fused_kernel`): where K and V are one span
     (`_tiling`: their blocks, both pipeline buffers, fit `SPAN_VMEM_BYTES`), because then a kv
-    head's dK and dV fit beside them as f32 [Skv, D], in as many bytes again (two arrays of
-    four bytes where K/V are two arrays twice over of two), and so do their output blocks. At
-    head width 128 in bf16 that is 16,384 positions, at 256 8,192: every shape the cells
-    have. A longer sequence runs the two kernels that re-make a tile's scores."""
+    head's dK and dV fit beside them as f32 [Skv, D] and [Skv, Dv], in as many bytes again (two
+    arrays of four bytes where K/V are two arrays twice over of two), and so do their output
+    blocks. At head width 128 in bf16 that is 16,384 positions, at 256 (and at 256 | 128, q and
+    k of 192 on their padded lanes beside v) 8,192: every shape the cells have. A longer
+    sequence runs the two kernels that re-make a tile's scores."""
     return t.kv_span == skv
 
 
@@ -975,11 +992,12 @@ def _bwd_fused(q, k, v, seg, dout, stats, scale, causal, t: Tiling, window, bd):
     blocks, 1), the heads of a group and their q blocks run in order over the kv head's K, V, dK
     and dV, whose blocks' index maps do not move with them."""
     b, h, sq, d = q.shape
-    skv, n_rep, bq, bkv = k.shape[2], h // k.shape[1], t.bq, t.bkv
+    skv, n_rep, bq, bkv, dv = k.shape[2], h // k.shape[1], t.bq, t.bkv, v.shape[3]
     has_seg = seg is not None
     q_spec, kv_spec, stat_spec, _ = _q_major_specs(d, n_rep, causal, t, False, window, bd)
+    do_spec, v_spec = _v_wide_specs(dv, d, q_spec, kv_spec, n_rep, causal, t, False, window, bd)
     args = [q, k, v, dout, *stats]
-    in_specs = [q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec]
+    in_specs = [q_spec, kv_spec, v_spec, do_spec, stat_spec, stat_spec]
     if has_seg:  # of the tile's rows (kv) and columns (q), as dK/dV's
         args += [seg["kv_col"], _rows(seg["q"], bq)]
         in_specs += [pl.BlockSpec((1, skv, 128), lambda bi, hi, qi, sj: (bi, 0, 0)),
@@ -990,33 +1008,34 @@ def _bwd_fused(q, k, v, seg, dout, stats, scale, causal, t: Tiling, window, bd):
         _bwd_fused_kernel(*ins, *segs, dq_ref.at[0, 0], dk_ref.at[0, 0], dv_ref.at[0, 0], *scratch,
                           scale=scale, causal=causal, bq=bq, bkv=bkv, n_rep=n_rep, window=window, bd=bd)
 
-    rows = skv * _lane_pad(d)
+    lanes = skv * (_lane_pad(d) + _lane_pad(dv))  # of a row of K and V, and of dK and dV
     return _pallas_call(
         kernel,
         name=_named("flash_attention_bwd_dkv_dq", window, bd),
         semantics=("parallel", "arbitrary", "arbitrary", "arbitrary"),
         # K, V and dK, dV's blocks twice (the pipeline's), their sums once, the rows' segment
         # ids; and what the two kernels leave the compute tile
-        vmem_limit_bytes=(VMEM_LIMIT_BYTES - SPAN_VMEM_BYTES + 2 * rows * (4 * k.dtype.itemsize + 4)
+        vmem_limit_bytes=(VMEM_LIMIT_BYTES - SPAN_VMEM_BYTES + lanes * (4 * k.dtype.itemsize + 4)
                           + has_seg * 2 * skv * 128 * 4),
         grid=(b, h, sq // bq, 1),
         in_specs=in_specs,
         # dQ is written over dO, block for block (a grid step reads the one and writes the other of
         # its own q tile, once): with all three gradients live at once the GLM step's temporaries
-        # were 0.17 GB above the two kernels' (6.71 for 6.54 GB, compiled for a v5e; PERF.md, PR 53)
-        input_output_aliases={3: 0},
-        out_specs=[q_spec, kv_spec, kv_spec],
+        # were 0.17 GB above the two kernels' (6.71 for 6.54 GB, compiled for a v5e; PERF.md, PR 53).
+        # Where v is not as wide as q, dO is not dQ's shape and dQ is an array of its own
+        input_output_aliases={3: 0} if dv == d else {},
+        out_specs=[q_spec, kv_spec, v_spec],
         out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32), pltpu.VMEM((skv, d), jnp.float32),
-                        pltpu.VMEM((skv, d), jnp.float32)],
+                        pltpu.VMEM((skv, dv), jnp.float32)],
     )(*args)
 
 
 def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=None):
     b, h, sq, d = q.shape
-    _, hkv, skv, _ = k.shape
+    _, hkv, skv, dv = v.shape
     n_rep = h // hkv
-    t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize, n_rep)
+    t = _tiling(sq, skv, bq, bkv, d, k.dtype.itemsize, n_rep, dv)
     bq, bkv = t.bq, t.bkv
     has_seg = seg is not None
 
@@ -1028,6 +1047,7 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=N
 
     # --- dQ pass: grid (b, h, q blocks, kv spans)
     q_spec, kv_spec, stat_spec, seg_specs = _q_major_specs(d, n_rep, causal, t, has_seg, window, bd)
+    do_spec, v_spec = _v_wide_specs(dv, d, q_spec, kv_spec, n_rep, causal, t, False, window, bd)
     args = [q, k, v, dout, *stats] + ([seg["q_col"], _rows(seg["kv"], bkv)] if has_seg else [])
 
     def dq_kernel(*refs):
@@ -1039,7 +1059,7 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=N
         dq_kernel,
         name=_named("flash_attention_bwd_dq", window, bd),
         grid=(b, h, sq // bq, _kv_spans(sq, skv, t, window)),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec] + seg_specs,
+        in_specs=[q_spec, kv_spec, v_spec, do_spec, stat_spec, stat_spec] + seg_specs,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[
@@ -1063,12 +1083,16 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=N
             return jnp.minimum(sp + _first_q_block(kj, bq, bkv) // n, _q_band(kj, sq // bq, bq, bkv, window).last // n)
         return jnp.maximum(sp, _first_q_block(kj, bq, bkv) // n) if causal else sp
 
-    q_spec2 = pl.BlockSpec((1, n_rep, t.q_span, d),
-                           lambda bi, hk, kj, sp: (bi, hk, q_span(kj, sp), 0))
-    kv_spec2 = pl.BlockSpec((1, 1, bkv, d), lambda bi, hk, kj, sp: (bi, hk, kj, 0))
+    def q_like(width):
+        return pl.BlockSpec((1, n_rep, t.q_span, width), lambda bi, hk, kj, sp: (bi, hk, q_span(kj, sp), 0))
+
+    def kv_like(width):
+        return pl.BlockSpec((1, 1, bkv, width), lambda bi, hk, kj, sp: (bi, hk, kj, 0))
+
+    (q_spec2, kv_spec2), (do_spec2, v_spec2) = ((q_like(w), kv_like(w)) for w in (d, dv))
     stat_spec2 = pl.BlockSpec((1, n_rep, n, 1, _lane_pad(bq)),
                               lambda bi, hk, kj, sp: (bi, hk, q_span(kj, sp), 0, 0))
-    in_specs2 = [q_spec2, kv_spec2, kv_spec2, q_spec2, stat_spec2, stat_spec2]
+    in_specs2 = [q_spec2, kv_spec2, v_spec2, do_spec2, stat_spec2, stat_spec2]
     args2 = [q, k, v, dout, *stats]
     if has_seg:
         in_specs2 += [
@@ -1089,14 +1113,14 @@ def _bwd(q, k, v, seg, out, lse, dout, scale, causal, bq, bkv, window=None, bd=N
         name=_named("flash_attention_bwd_dkv", window, bd),
         grid=(b, hkv, skv // bkv, _q_spans(sq, skv, t, window)),
         in_specs=in_specs2,
-        out_specs=[kv_spec2, kv_spec2],
+        out_specs=[kv_spec2, v_spec2],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, skv, d), k.dtype),
-            jax.ShapeDtypeStruct((b, hkv, skv, d), v.dtype),
+            jax.ShapeDtypeStruct((b, hkv, skv, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((bkv, d), jnp.float32),
-            pltpu.VMEM((bkv, d), jnp.float32),
+            pltpu.VMEM((bkv, dv), jnp.float32),
         ],
     )(*args2)
     return dq, dk, dv
@@ -1213,9 +1237,11 @@ rope_to_heads.defvjp(_rope_fwd_rule, _rope_bwd_rule)
 
 # ----------------------------------------------------------------------- public API
 
-# The one head width below the lane width that runs the kernels (`flash_attention`): half
-# a vreg, so the padded products cost the MXU what the narrow ones would (a 128 x 128
-# array contracts 64 in the passes of 128, and writes 64 columns in the passes of 128).
+# Half a vreg of lanes: a head width that is whole halves and not whole vregs (64; 192, which
+# latent attention's q and k have beside a v of 128) runs the kernels on lanes padded with zeros
+# to the next whole vreg (`flash_attention`). The padded products cost the MXU what the narrow
+# ones would: a 128 x 128 array contracts 64 in the passes of 128, and 192 in the two of 256, and
+# writes 64 columns in the passes of 128.
 NARROW_HEAD = 64
 
 
@@ -1255,7 +1281,7 @@ def _segment_lanes(segment_ids: jax.Array, sq: int) -> dict:
 def flash_attention(
     q: jax.Array,  # [B, Sq, H, D]
     k: jax.Array,  # [B, Skv, Hkv, D]
-    v: jax.Array,
+    v: jax.Array,  # [B, Skv, Hkv, Dv]
     *,
     causal: bool = True,
     segment_ids: Optional[jax.Array] = None,  # [B, Skv]
@@ -1273,6 +1299,10 @@ def flash_attention(
     row of two halves) keeps what the block-diffusion mask keeps (`BlockDiffusion`); its
     kernels carry `_bd` behind their names.
 
+    v's heads (and the output's) have a width of their own, Dv: the scores contract D, and the
+    weighted values, dV and the output are Dv wide, with dK and dQ at D (latent attention's
+    192 beside 128: no product runs on a v padded to q's width).
+
     Heads 64 wide (`NARROW_HEAD`) run the same three kernels, under the same names, on
     q, k and v padded with zero lanes to 128: the scores do not see zeros in q and k, the
     output's padded lanes are zero and are cut, and the cut's transpose pads dO, so dq, dk
@@ -1281,14 +1311,16 @@ def flash_attention(
     block of 64), so a kernel of its own at 64 would move the same bytes; what the padding
     adds is the pad and the cut themselves, which XLA fuses into the producer of q, k, v
     and the consumer of the output. They come rotated (`rope` is refused: the rotate
-    kernel's tiles are whole vregs)."""
-    d = q.shape[-1]
+    kernel's tiles are whole vregs). So does every width of whole halves of a vreg, each of D
+    and Dv by itself: q and k 192 wide run on 256 lanes."""
+    d, dv = q.shape[-1], v.shape[-1]
     scale = scale if scale is not None else 1.0 / (d**0.5)
-    if d == NARROW_HEAD:
+    if d % 128:
         if rope is not None:
             raise NotImplementedError(f"the rotate kernel at head width {d}: hand q and k over rotated")
-        lanes = ((0, 0),) * 3 + ((0, _lane_pad(d) - d),)
-        q, k, v = (jnp.pad(x, lanes) for x in (q, k, v))
+        q, k = (jnp.pad(x, ((0, 0),) * 3 + ((0, _lane_pad(d) - d),)) for x in (q, k))
+    if dv % 128:
+        v = jnp.pad(v, ((0, 0),) * 3 + ((0, _lane_pad(dv) - dv),))
     qt, kt = (_heads_major(q), _heads_major(k)) if rope is None else rope_to_heads(q, k, *rope)
     vt = _heads_major(v)
     seg = None if segment_ids is None else _segment_lanes(segment_ids, q.shape[1])
@@ -1297,4 +1329,4 @@ def flash_attention(
         block_q, block_kv = min(block_q, bd.half), min(block_kv, bd.half)
     out = _flash_bhsd(qt, kt, vt, seg, scale, causal, block_q, block_kv,
                       _band(window, q.shape[1], k.shape[1], causal), bd)
-    return _heads_major(out)[..., :d]
+    return _heads_major(out)[..., :dv]

@@ -19,7 +19,8 @@ import re
 import sys
 
 CONFIGS = ("glm-4.7-flash-train-ep8", "nemotron-3-super-train-tp8-ep64", "solar-open2-train-tp8-ep40",
-           "lfm2-24b-a2b-train-ep8", "trinity-mini-train-ep16", "sdar-30b-a3b-train-ep8")
+           "lfm2-24b-a2b-train-ep8", "trinity-mini-train-ep16", "sdar-30b-a3b-train-ep8",
+           "kimi-linear-48b-a3b-train-ep32")
 
 
 def compile_steps(tree: str, out: str, configs) -> None:

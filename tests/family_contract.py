@@ -82,6 +82,9 @@ class Family:
     # `llama.loss_fn` and the reference's `loss` are handed (None: the ids themselves, next-token prediction
     # over T - 1 positions; a family of another objective keeps T - 1 positions too)
     batch_of: Optional[Callable] = None
+    # whether the `kept` cases (what a rematerialised recurrent mixer keeps) run from this family's file: a second
+    # family of one mixer module leaves them to the first's
+    kept_here: bool = True
 
     @property
     def ref(self):
@@ -113,7 +116,7 @@ def pytest_generate_tests(metafunc):
         metafunc.parametrize("part", list(family.shares))
     if "kept" in metafunc.fixturenames:  # the family's recurrent mixer (its module), where it has one
         mixers = {kind: mixer for kind, (mixer, _) in llama.MIXERS.items()
-                  if family.recurrent and mixer.RECURRENT == family.recurrent}
+                  if family.recurrent and family.kept_here and mixer.RECURRENT == family.recurrent}
         metafunc.parametrize("kept", list(mixers.values()), ids=list(mixers))
 
 

@@ -12,7 +12,7 @@ import jax.numpy as jnp
 import pytest
 
 from ray_tpu.models import attn, llama
-from ray_tpu.models.config import get_config
+from ray_tpu.models.config import LAYER_KINDS, get_config
 
 # a tiny model of each kind of attention part, by what its layers hold
 KINDS = {
@@ -20,6 +20,7 @@ KINDS = {
     "qk-normed-gated-windowed-beside-full": "trinity-tiny",  # a pattern's period, a norm behind every part
     "latent": "glm-tiny",                                  # a leading dense stack, a scan, the MTP module's block
     "block-diffusion": "sdar-tiny",                        # the doubled row under `attn_bd`
+    "latent-without-a-q-latent-in-a-pattern": "kimi-linear-tiny",  # a `*` part beside delta-rule mixers; no rotation
 }
 RECURRENT = re.compile(r"^(ssm_|kda_|sconv)")
 
@@ -70,7 +71,12 @@ def test_every_equation_of_an_attention_part_carries_exactly_one_of_the_five_nam
              if "attn" in names and not any(RECURRENT.match(n) for n in names)]
     assert under
     stray = [(p, names) for p, names in under if len({n for n in names if n in attn.SCOPES}) != 1]
-    assert not stray, stray[:5]
+    # (beside attention parts a pattern's recurrent mixers add their output to the stream under `attn` and no
+    # piece's name, `llama._block`: one `add` a part, which is not an attention part's)
+    recurrent = sum(llama.MIXERS[LAYER_KINDS[c].mixer][0].RECURRENT is not None
+                    for c in cfg.layer_pattern if LAYER_KINDS[c].mixer)
+    residuals = [e for e in stray if e[0] == "add" and e[1][-1] == "attn" and not set(e[1]) & set(attn.SCOPES)]
+    assert len(residuals) == recurrent and len(stray) == recurrent, stray[:5]
     wanted = {"attn_in_proj", "attn_core", "attn_out_proj"}
     wanted |= {"attn_head_norm"} if cfg.attn_qk_norm else set()
     wanted |= {"attn_gate"} if cfg.attn_output_gate else set()
@@ -81,7 +87,12 @@ def test_every_equation_of_an_attention_part_carries_exactly_one_of_the_five_nam
     for which, holds in passes.items():
         found = {n for _, names in under if holds(names) for n in names if n in attn.SCOPES}
         # (a head norm's scope also holds the `jax.numpy` rotation, so it may be there without the norm)
-        assert wanted <= found <= wanted | {"attn_head_norm"}, (which, found)
+        need = wanted
+        if which == "again" and cfg.layer_pattern and not cfg.part_post_norm:
+            # a part alone in its layer, nothing behind its output but the residual: the backward pass reads
+            # no result of the output product, and the rematerialised layer does not make it again
+            need = wanted - {"attn_out_proj"}
+        assert need <= found <= wanted | {"attn_head_norm"}, (which, found)
     if cfg.latent_attention:  # the latent path keeps its own names inside the input's
         assert all("attn_in_proj" in names for _, names in under if "mla_q" in names or "mla_kv" in names)
         assert any("mla_q" in names for _, names in under) and any("mla_kv" in names for _, names in under)
@@ -105,6 +116,9 @@ def test_every_equation_of_a_layer_carries_the_layer_loops_name(kind):
     inside = [(p, names) for p, names in equations
               if any(n in parts for n in names) and "mtp" not in names and "model" in names]
     assert inside and not [e for e in inside if llama.LAYER_LOOP not in e[1]][:5]
+    if "-" in cfg.layer_pattern:  # a pattern's dense part lies under `mlp`, as a block's does
+        assert any(p == "dot_general" and "mlp" in names and not any(n.startswith("moe_") for n in names)
+                   for p, names in inside)
     own = [(p, names) for p, names in equations
            if llama.LAYER_LOOP in names and not any(n in parts for n in names)]
     # the loop's own: the `scan` (whose lowering stores and reads the stacks), or in a period run as it

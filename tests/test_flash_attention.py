@@ -473,7 +473,7 @@ def test_the_flash_kernels_run_at_head_width_64_on_padded_lanes():
     from ray_tpu.ops.attention import Rotation, attention
 
     assert fa.supports(8192, 8192, 64) and fa.supports(8192, 8192, 128) and fa.supports(8192, 8192, 256)
-    assert not fa.supports(8192, 8192, 192) and not fa.supports(8192, 8192, 32) and not fa.supports(8191, 8191, 64)
+    assert not fa.supports(8192, 8192, 96) and not fa.supports(8192, 8192, 32) and not fa.supports(8191, 8191, 64)
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q = jax.random.normal(ks[0], (1, 256, 4, 64), jnp.float32)
     k, v = (jax.random.normal(key, (1, 256, 1, 64), jnp.float32) for key in ks[1:3])
@@ -502,9 +502,63 @@ def test_the_flash_kernels_run_at_head_width_64_on_padded_lanes():
     np.testing.assert_allclose(rotated, want, atol=2e-5)
 
 
+@pytest.mark.usefixtures("highest")
+@pytest.mark.parametrize("group,packed", [(1, False), (2, True)], ids=["mha-one-document", "gqa2-packed"])
+def test_the_flash_kernels_run_q_and_k_192_wide_beside_v_128_wide(group, packed, monkeypatch):
+    """[1, 256, 4 / (4 or 2), 192 | 128] through Pallas' interpreter in tiles of 128 (latent attention
+    without a q latent: Kimi Linear's heads): the SAME kernels by name, causal, with and without
+    segment ids, forward and dq, dk, dv against the plain softmax scaled by 192^-1/2; the output and dv
+    come back 128 wide, dq and dk 192; and the two kernels that run where K and V are longer than a span
+    say the same. `supports` says which pairs tile: each width whole halves of a vreg."""
+    from ray_tpu.ops.attention import attention
+
+    assert fa.supports(8192, 8192, 192, v_head_dim=128) and fa.supports(8192, 8192, 192) and fa.supports(8192, 8192, 128, v_head_dim=64)
+    assert not fa.supports(8192, 8192, 192, v_head_dim=96) and not fa.supports(8192, 8192, 96, v_head_dim=128)
+    assert not fa.supports(8191, 8191, 192, v_head_dim=128)
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(ks[0], (1, 256, 4, 192), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 256, 4 // group, 192), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 256, 4 // group, 128), jnp.float32)
+    w = jax.random.normal(ks[3], (1, 256, 4, 128), jnp.float32)
+    seg = _packed(1, 256, (70, 150, 201)) if packed else None
+
+    def flash(q, k, v):
+        return jnp.sum(w * fa.flash_attention(q, k, v, causal=True, segment_ids=seg, block_q=128, block_kv=128))
+
+    def plain(q, k, v):
+        return jnp.sum(w * attention_reference(q, k, v, causal=True, segment_ids=seg))
+
+    names = _kernel_names(jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    assert sorted(names) == _flash_names(), names
+    out = fa.flash_attention(q, k, v, causal=True, segment_ids=seg, block_q=128, block_kv=128)
+    assert out.shape == (1, 256, 4, 128)
+    want = attention_reference(q, k, v, causal=True, segment_ids=seg)
+    np.testing.assert_allclose(out, want, atol=2e-5)
+    np.testing.assert_allclose(attention(q, k, v, causal=True, segment_ids=seg, impl="pallas"), want, atol=2e-5)
+    np.testing.assert_allclose(attention(q, k, v, causal=True, segment_ids=seg, impl="chunked"), want, atol=2e-5)
+    # the scale is q's and k's width's, not the padded lanes' and not v's
+    scaled = attention_reference(q, k, v, causal=True, segment_ids=seg, scale=192 ** -0.5)
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(scaled))
+    one = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    for mine, ref in zip(one, jax.grad(plain, argnums=(0, 1, 2))(q, k, v)):
+        assert mine.shape == ref.shape
+        np.testing.assert_allclose(mine, ref, atol=3e-5 * float(jnp.abs(ref).max()))
+    with pytest.raises(NotImplementedError, match="head width 192"):
+        fa.flash_attention(q, k, v, rope=(jnp.arange(256)[None], 1e6))
+    # K and V (256 + 128 lanes) in two spans of a tile each: dQ's and dK/dV's kernels, the same gradients
+    t = fa._tiling(256, 256, 128, 128, 256, 4, group, 128)
+    assert fa._fuses(t, 256) and (t.kv_span, t.q_span) == (256, 256)
+    monkeypatch.setattr(fa, "SPAN_VMEM_BYTES", 128 * 2 * (256 + 128) * 4)  # a tile of K and V, both pipeline buffers
+    assert not fa._fuses(fa._tiling(256, 256, 128, 128, 256, 4, group, 128), 256)
+    names = _kernel_names(jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    assert sorted(names) == _flash_names(one_backward=False), names
+    for mine, ref in zip(jax.grad(flash, argnums=(0, 1, 2))(q, k, v), one):
+        np.testing.assert_allclose(mine, ref, atol=2e-5 * float(jnp.abs(ref).max()))
+
+
 def test_flash_kernels_tile_width_256():
     assert fa.supports(8192, 8192, 256) and not fa.supports(8191, 8191, 256)
-    assert not fa.supports(8192, 8192, 192)
+    assert not fa.supports(8192, 8192, 160)
     # K and V of one head at 8,192 x 256 bf16 are the span budget, exactly: one span
     t = fa._tiling(8192, 8192, 512, 512, 256, 2)
     assert (t.kv_span, t.q_span) == (8192, 4096)
@@ -512,6 +566,11 @@ def test_flash_kernels_tile_width_256():
     assert (fwd.grid_steps, fwd.tiles_computed) == (16, 136) and fwd.tiles_needed == 128.015625
     dkv = fa.tile_counts(8192, 8192, True, 512, 512, head_dim=256, kernel="dkv")
     assert (dkv.grid_steps, dkv.tiles_computed) == (32, 136)
+    # beside v 128 wide (q and k of 192 on their 256 lanes) K and V are three quarters of the budget: one span,
+    # and a kv head's group of up to 5 query heads' Q and dO are one span too
+    t = fa._tiling(8192, 8192, 512, 512, 256, 2, dv=128)
+    assert (t.kv_span, t.q_span) == (8192, 8192) and fa._fuses(t, 8192)
+    assert fa.tile_counts(8192, 8192, True, 512, 512, head_dim=256, v_head_dim=128) == fwd
 
 
 @pytest.mark.usefixtures("highest")

@@ -27,21 +27,32 @@ def _grads(fn, q, k, v, g, **kw):
 
 
 def _inputs(group, width, dtype=jnp.float32):
-    return (_rand((1, S, heads, width), i, dtype) for i, heads in enumerate((group, 1, 1, group)))
+    """q, k, v and the output's cotangent; `width` a number, or (q's and k's, v's and the output's)."""
+    qk, v = width if isinstance(width, tuple) else (width, width)
+    return (_rand((1, S, heads, w), i, dtype) for i, (heads, w) in enumerate(((group, qk), (1, qk), (1, v), (group, v))))
 
 
 # (the block-diffusion mask takes one document a row, `_block_diffusion`)
 CASES = [(walk, group, packed, width) for walk in WALKS for group in (1, 4, 8) for packed in (False, True)
          for width in (64, 128, 256) if not (packed and walk == "bd")]
+# q and k 192 wide (on 256 lanes) beside v 128 wide: latent attention without a q latent (Kimi Linear); and the
+# other way round, v the wider
+CASES += [("causal", 1, False, (192, 128)), ("causal", 4, True, (192, 128)), ("window", 4, True, (192, 128)),
+          ("bd", 4, False, (192, 128)), ("causal", 4, True, (128, 256))]
+
+
+def _width_id(d):
+    return f"w{d[0]}v{d[1]}" if isinstance(d, tuple) else f"w{d}"
 
 
 @pytest.mark.parametrize("walk,group,packed,width", CASES,
-                         ids=[f"{w}-gqa{g}-{'packed' if p else 'one-document'}-w{d}" for w, g, p, d in CASES])
+                         ids=[f"{w}-gqa{g}-{'packed' if p else 'one-document'}-{_width_id(d)}" for w, g, p, d in CASES])
 def test_the_one_backward_kernel_matches_the_reference(walk, group, packed, width):
     """dq, dk and dv of the one backward kernel (by name) under a random cotangent, at the
     tolerance the dQ and dK/dV kernels' cases hold (`test_fwd_and_grads_over_tilings`): a group
     of 1, 4 and 8 query heads summed in the kernel's resident dK and dV, segment ids down the
-    tile's rows and along its columns, heads of 64 on padded lanes, 128 and 256."""
+    tile's rows and along its columns, heads of 64 on padded lanes, 128 and 256; q and k 192 wide on
+    256 lanes beside v 128 wide (dq and dk come back 192 wide, dv 128: the reference's shapes)."""
     kw, suffix = WALKS[walk]
     q, k, v, g = _inputs(group, width)
     kw = dict(kw, segment_ids=_packed(1, S, (70, 150, 201)) if packed else None)

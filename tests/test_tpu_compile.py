@@ -267,6 +267,44 @@ def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
     assert grids == [(b, h, 16, steps // 16)] * 2, grids  # one kv span, forward and backward
 
 
+def test_flash_attention_compiles_at_q_k_192_beside_v_128(one_chip, on_tpu):
+    """[1, 8192, 32/32, 192 | 128], the kimilinear-train-ep32share-s8192 cell's shape (latent attention
+    without a q latent: q and k 128 + 64 wide, v 128): q and k on 256 lanes beside v on its own 128, so
+    K and V of a head are one span (three quarters of the budget) and the backward is the ONE kernel,
+    dK resident at 256 lanes and dV at 128: it asks Mosaic for 16 MiB + 8,192 rows x 384 lanes x 12 B =
+    52 MiB of VMEM and the compiled kernel uses under 40 (GLM's 256 | 256 asks for 64); the kernels
+    carry the names the trace metrics select by; dq and dk come back 192 wide, dv and the output 128."""
+    from ray_tpu.ops.flash_attention import _fuses, _tiling, flash_attention
+
+    b, s, h, d, dv = 1, 8192, 32, 192, 128
+    assert _fuses(_tiling(s, s, 512, 512, 256, 2, 1, dv), s)
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True).astype(jnp.float32))
+
+    grad = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    assert [a.shape[-1] for a in jax.eval_shape(grad, q, q, v)] == [d, d, dv]
+    compiled = grad.lower(q, q, v).compile()
+    text = compiled.as_text()
+    calls = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2
+    for path, name in (("train_attn_fwd_kernel_pct", "fwd"), ("train_attn_bwd_kernel_pct", "bwd_dkv_dq"),
+                       ("train_attn_mla_roofline_pct", None)):
+        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
+            rx = re.compile(json.load(f)["args"]["pattern"])
+        found = [ln for ln in calls if rx.search(ln)]
+        assert len(found) == (2 if name is None else 1), (path, found)
+        assert name is None or f"flash_attention_{name}" in found[0].split(" = ")[0]
+    backward, = [ln for ln in calls if "bwd_dkv_dq" in ln.split(" = ")[0]]
+    asked, used = re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\].*?'
+                            r'"used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\]', backward).groups()
+    assert int(asked) == (16 << 20) + s * (256 + 128) * 12 == 52 << 20 and (32 << 20) < int(used) < (40 << 20)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 1.0e9  # the kernels keep no scores
+
+
 def _glm_share():
     from ray_tpu.models.config import ModelConfig
 
@@ -487,7 +525,15 @@ def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tp
     # names) and one full, gated, normed a head, a norm behind every part; four expert parts at 8 of 128
     # over 32,768 tokens beside a shared expert; arguments 6.05 GB; the f32 logits [2, 16384, 25024] are
     # 3.3 GB of the temporaries
-    ("trinity-mini-train-ep16", 4, 2, 8.75)])
+    ("trinity-mini-train-ep16", 4, 2, 8.75),
+    # PR 54: [1, 8192]: four delta-rule parts at all 32 heads of 128 (their q | k | v [1, 8192, 12288] kept:
+    # 0.2 GB a part), one latent attention part WITHOUT a q latent whose kernels run q/k 192 | v 128 (the
+    # kernels under their own names: no XLA fallback), a dense part, four expert parts at 8 of 256 (the
+    # pick a slot at a time) beside a shared expert; arguments 7.23 GB; 12.86 of 15.75 GB in all
+    # (marked slow: this file is tier-1's longest, one worker's from its start to the run's end, and a seventh
+    # whole step is two minutes more of it than the run's limit leaves: `-m slow -k kimi` runs it, ~2.5 min; tier-1
+    # holds the cell's kernels, names and scoped memory in `test_flash_attention_compiles_at_q_k_192_beside_v_128`)
+    pytest.param("kimi-linear-48b-a3b-train-ep32", 4, 2, 5.64, marks=pytest.mark.slow)])
 def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on_tpu, config, bodies,
                                                                     loops, temp_gb):
     """The whole step of each family cell as its configuration file states it, compiled for
@@ -781,7 +827,7 @@ def _rematerialised_mixer(mixer, kind, cfg, x, lp):
 _KEPT_PRODUCTS = {
     "C": ("sconv_in_proj", "btd,dpe->btpe", r"bf16\[(?:4,8192,3,2048|3,4,8192,2048|4,3,8192,2048)\]"),
     "M": ("ssm_in_proj", "btd,de->bte", r"bf16\[(?:1,)?8192,2320\]"),
-    "K": ("kda_in_proj", "btd,dphk->btphk", r"bf16\[(?:1,)?8192,(?:3072|3,8,128)\]"),
+    "K": ("kda_in_proj", "btd,dphk->btphk", r"bf16\[(?:1,)?8192,(?:3072|3,8,128|12288|3,32,128)\]"),
 }
 
 
@@ -826,7 +872,7 @@ def _kernel_calls(text, name):
 
 
 # q|k|v `[1, 8192, 3072]` in float32 with the taps' 3 rows of zeros in front: `ssm._causal_conv`'s copy
-_CONV_PADDED_COPY = r"f32\[1,8195,3072\]"
+_CONV_PADDED_COPY = r"f32\[1,8195,(3072|12288)\]"
 
 # float32 arrays of every chunk with the extents of a sub-chunk's differences [.., 32, 32, 128]
 # or of the sub-chunks' factors [.., 4, 128, 128]: what `_decayed_overlaps` wrote to HBM
