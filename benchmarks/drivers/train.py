@@ -24,7 +24,7 @@ def _loop(config: dict) -> None:
     import numpy as np
 
     import ray_tpu.train as train
-    from benchmarks.lib import compile_events, modelcfg, reference
+    from benchmarks.lib import compile_events, modelcfg, reference, settle
     from ray_tpu.models import llama
     from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
     from ray_tpu.parallel.sharding import named_sharding
@@ -104,6 +104,7 @@ def _loop(config: dict) -> None:
             "yardstick_rms": rms(coarse - exact),
             "yardstick_max": float(np.abs(coarse - exact).max())}
 
+        settle.settle_host()  # on this thread, last before the window: every run measures the fast class
         compiles_before = len(compiles)
         step_s, losses = [], [first_loss]
         # a traced run profiles `traced_steps` steps from the fourth of the window

@@ -96,7 +96,7 @@ def _loop(config: dict) -> None:
     import optax
 
     import ray_tpu.train as train
-    from benchmarks.lib import compile_events, modelcfg
+    from benchmarks.lib import compile_events, modelcfg, settle
     from ray_tpu.models import llama
     from ray_tpu.train import block_diffusion_noise, init_state, make_optimizer, make_train_step
 
@@ -292,13 +292,7 @@ def _loop(config: dict) -> None:
     for _ in range(tr["warmup_steps_run"] - 1):
         state, _ = one_step(state, -1)
 
-    # The chip machine's processes come in two classes, a step's host share ~3 ms or ~7.5, by a
-    # state of its sandboxed system-call path; a burst of system calls on the loop's thread before
-    # the window made 19 of 19 processes of the fast class, 6 of 23 without (PERF.md, PR 35;
-    # ROADMAP.md C8 leaves the accepted drivers' to a `benchmark` PR). Here 3 of 10 runs were slow
-    # (448 ms a step beside 443.5: PERF.md, PR 50), twice the spread a new cell is admitted with.
-    for _ in range(3000):
-        os.stat("/")
+    settle.settle_host()  # on this thread, last before the window: every run measures the fast class
     compiles_before = len(compiles)
     step_s, losses = [], [first_loss]
     # a traced run profiles `traced_steps` steps from the fourth of the window

@@ -144,7 +144,7 @@ def _loop(config: dict) -> None:
     import optax
 
     import ray_tpu.train as train
-    from benchmarks.lib import compile_events, modelcfg
+    from benchmarks.lib import compile_events, modelcfg, settle
     from ray_tpu.models import llama
     from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
     from ray_tpu.parallel.sharding import named_sharding
@@ -375,6 +375,7 @@ def _loop(config: dict) -> None:
         for _ in range(tr["warmup_steps_run"] - 1):
             state, _ = one_step(state, -1)
 
+        settle.settle_host()  # on this thread, last before the window: every run measures the fast class
         compiles_before = len(compiles)
         step_s, losses = [], [first_loss]
         # a traced run profiles `traced_steps` steps from the fourth of the window
