@@ -201,6 +201,9 @@ def _served(cfg):
         (cfg.diffusion_block, "generation by diffusion over blocks: the engine's step yields one token a sequence, "
                               "where a block of diffusion_block tokens takes several denoising passes and the "
                               "cache commits a block (llm/engine.py, llm/model_runner.py)"),
+        (cfg.loop_steps > 1, "a KV cache of loop_steps x n_layers entries for a looped stack (every recurrence "
+                             "attends over keys and values of its own: llama.forward(cache=) and llm/paged.py hold "
+                             "n_layers) and exit by the gate's threshold"),
         ("C" in cfg.layer_pattern, "a gated short convolution's tail of conv_taps - 1 positions a slot "
                                    "and layer (models/sconv.py keeps none)"),
     ) if has]

@@ -398,8 +398,43 @@ def _kimi_linear_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+# What ouro's config.json does not state: the weight of the entropy term in the first training
+# stage's objective (arXiv:2510.25741); a key of this name overrides it
+_OURO_EXIT_ENTROPY_WEIGHT = 0.05
+
+
+def _ouro_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `ouro` keys (Ouro-2.6B) as ModelConfig fields: Llama's keys map as they do (the block is
+    rotated attention then the SwiGLU MLP), every part between a norm on its input and one on its
+    output (`part_post_norm`), an untied head, and the stack run `total_ut_steps` times over the same
+    weights with an exit gate and a head behind each recurrence (`loop_steps`; llama.loss_fn's
+    expected-exit loss). What the program does not run is refused by name; what config.json does not
+    state is benchmarks/configs/ouro-2.6b-train-loop4.json's `assumed`. Weights' names are not mapped."""
+    kinds = hf.get("layer_types") or ["full_attention"] * hf["num_hidden_layers"]
+    head = hf.get("head_dim") or hf["hidden_size"] // hf["num_attention_heads"]
+    refused = [what for has, what in (
+        (len(kinds) != hf["num_hidden_layers"] or set(kinds) - {"full_attention"},
+         f"layer_types that are not num_hidden_layers of full_attention ({sorted(set(kinds))})"),
+        (hf.get("use_sliding_window", False), "window attention (use_sliding_window true)"),
+        (hf.get("rope_scaling") is not None, f"rope_scaling {hf.get('rope_scaling')!r}"),
+        (hf.get("early_exit_threshold", 1) < 1,
+         f"exit by the gate's threshold (early_exit_threshold {hf.get('early_exit_threshold')} under 1: every "
+         "recurrence runs, in training and in `forward`)"),
+        (hf.get("total_ut_steps", 1) < 1, f"total_ut_steps {hf.get('total_ut_steps')}"),
+        (hf.get("hidden_act", "silu") != "silu", f"hidden_act {hf.get('hidden_act')!r}"),
+    ) if has]
+    if refused:
+        raise ValueError("ouro as this config.json states it is not supported: " + "; ".join(refused))
+    steps = hf.get("total_ut_steps", 1)
+    return dict(
+        part_post_norm=True, loop_steps=steps,
+        exit_entropy_weight=float(hf.get("exit_entropy_weight", _OURO_EXIT_ENTROPY_WEIGHT)) if steps > 1 else 0.0,
+        attn_head_dim=0 if head * hf["num_attention_heads"] == hf["hidden_size"] else head,
+    )
+
+
 # a family's keys as ModelConfig fields, by its `model_type` (config_from_hf)
-_FAMILY_FIELDS = {"glm4_moe_lite": _glm4_moe_lite_fields, "nemotron_h": _nemotron_h_fields,
+_FAMILY_FIELDS = {"ouro": _ouro_fields, "glm4_moe_lite": _glm4_moe_lite_fields, "nemotron_h": _nemotron_h_fields,
                   "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields,
                   "afmoe": _afmoe_fields, "sdar_moe": _sdar_moe_fields, "kimi_linear": _kimi_linear_fields}
 

@@ -182,6 +182,14 @@ class ModelConfig:
     # doubled row.
     diffusion_block: int = 0
     diffusion_mask_token: int = 0
+    # A looped stack (ouro's total_ut_steps; arXiv:2510.25741): the layers run `loop_steps` times over
+    # the SAME weights, the final norm's output of one recurrence the input of the next, a head and
+    # an exit gate (one sigmoid a position, the leaf `exit_gate`: d_model weights and a bias) behind each; 1 is
+    # every other family's stack, run once. With more than one the objective is the expected-exit loss
+    # (llama.loss_fn -> `expected_exit_loss`): each recurrence's next-token cross entropy weighted by
+    # the probability of leaving there, less `exit_entropy_weight` times that distribution's entropy.
+    loop_steps: int = 1
+    exit_entropy_weight: float = 0.0
 
     def __post_init__(self):
         # JSON hands a list; the dataclass is a static (hashed) argument of jitted programs
@@ -211,6 +219,14 @@ class ModelConfig:
                 f"the block-diffusion objective (diffusion_block {self.diffusion_block}) with blocks that are no "
                 "power of two, MTP modules, a pattern of single-part layers, pipeline stages, or a mask token "
                 f"({self.diffusion_mask_token}) outside the vocabulary")
+        if self.loop_steps < 1 or self.loop_steps > 1 and (
+                self.layer_pattern or self.pipeline_stages > 1 or self.n_dense_layers or self.mtp_depth
+                or self.diffusion_block or self.n_experts):
+            raise NotImplementedError(
+                f"a looped stack (loop_steps {self.loop_steps}) of fewer than one recurrence, or around a pattern of "
+                "single-part layers (_pattern_layers), pipeline stages (_pipeline_layers), a leading dense stack, "
+                "MTP modules, the block-diffusion objective or expert layers (their counters are a row a layer, "
+                "not a row a layer and recurrence)")
         if self.mlp_activation not in ("silu_gated", "relu2"):
             raise ValueError(f"unknown mlp_activation {self.mlp_activation!r} (silu_gated | relu2)")
 
@@ -647,6 +663,29 @@ register_config(
         moe_scoring="sigmoid",
         moe_route_scale=2.446,
         moe_select_bias=True,
+    )
+)
+register_config(
+    # Toy of the ouro family (Ouro-2.6B) for the CPU tests: the block every layer is (rotated
+    # attention with as many key/value heads as query heads, then the dense SwiGLU MLP), each part
+    # between a norm on its input and one on its output, an untied head; the three layers run FOUR
+    # times over the same weights, an exit gate and a head behind each recurrence, under the
+    # expected-exit loss with its entropy term.
+    ModelConfig(
+        name="ouro-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=96,
+        max_seq_len=128,
+        rope_theta=1e6,
+        norm_eps=1e-6,
+        dtype="float32",
+        part_post_norm=True,
+        loop_steps=4,
+        exit_entropy_weight=0.05,
     )
 )
 register_config(

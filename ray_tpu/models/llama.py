@@ -60,6 +60,12 @@ FEED_FORWARD = {"experts": (moe, 4), "dense": (_dense, slice(4, 7))}
 # and no part's is the loop's own: the residual stacks' stores and reads, index arithmetic, the gradient
 # stacks' zeros, the expert layers' counts stacked (benchmarks/metrics/train_layer_stack_pct.json)
 LAYER_LOOP = "layer_stack"
+# a looped stack (cfg.loop_steps > 1): the scope around ONE recurrence, its whole stack (outside LAYER_LOOP) and
+# the final norm behind it (what carries this name and not LAYER_LOOP's is the recurrence's own: that norm, and
+# the carry's copies where the compiler names them so; the shared leaves' gradients are summed under LAYER_LOOP's
+# name, where the transposed scans leave them); the scope of the exit gates and the distribution they make; the
+# scope of the expectation and its entropy (benchmarks/metrics/train_loop_*.json)
+LOOP_STEP, EXIT_GATE, EXIT_LOSS = "loop_step", "exit_gate", "exit_loss"
 
 
 # ---------------------------------------------------------------------------- init
@@ -136,6 +142,8 @@ def param_axes(cfg: ModelConfig) -> Params:
     }
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")
+    if cfg.loop_steps > 1:
+        axes["exit_gate"] = (None,)  # d_model weights and the bias: one short vector, held whole
     if cfg.mtp_depth:
         axes["mtp"] = stacked({
             "embed_norm": ("embed",), "hidden_norm": ("embed",), "eh_proj": ("mlp", "embed"),
@@ -147,7 +155,8 @@ def n_params(cfg: ModelConfig) -> int:
     """Approximate parameter count (embeddings + blocks + norms), of what is held: each
     part's own count (beside its `init`), a norm a feed-forward part, one more behind every
     part under cfg.part_post_norm. Capacity-based experts count as the dense MLP they stand
-    in for, as they always have."""
+    in for, as they always have. A looped stack's layers are counted once, however often they run, and
+    its exit gate is one weight a channel and a bias (one leaf)."""
     d = cfg.d_model
 
     def layer(mixer, ff):
@@ -158,7 +167,8 @@ def n_params(cfg: ModelConfig) -> int:
 
     return (cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + d
             + sum(n * layer(mixer, ff) for n, mixer, ff in _layer_kinds(cfg).values())
-            + cfg.mtp_depth * (layer("attn", "experts") + 2 * d * d + 3 * d))
+            + cfg.mtp_depth * (layer("attn", "experts") + 2 * d * d + 3 * d)
+            + (d + 1) * (cfg.loop_steps > 1))
 
 
 def init(rng: jax.Array, cfg: ModelConfig) -> Params:
@@ -184,6 +194,11 @@ def init(rng: jax.Array, cfg: ModelConfig) -> Params:
     params["final_norm"] = jnp.ones((d,), jnp.float32)
     if not cfg.tie_embeddings:
         params["lm_head"] = norm(k_head, (d, cfg.vocab_size), d**-0.5)
+    if cfg.loop_steps > 1:
+        # the exit gate of a looped stack, ONE leaf: d weights (one number a position, of order 1 on a normed
+        # stream) and, last, the bias. A leaf of one number would be a row of its own wherever gradients are
+        # compared a row a leaf, and the quotient of two errors of one number has no bound
+        params["exit_gate"] = jnp.concatenate([norm(jax.random.fold_in(k_head, 1), (d,), d**-0.5), jnp.zeros((1,), jnp.float32)])
     if cfg.mtp_depth:
         def mtp_init(key):
             k_proj, k_block = jax.random.split(key)
@@ -359,12 +374,16 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
     return _onto(x, y2.reshape(b, s, d), lp, "mlp_post_norm", cfg, constrain), aux
 
 
+def _head(params: Params, normed: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The (tied or untied) head behind the final norm: [B, S, D] -> f32 logits [B, S, vocab]."""
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    logits = jnp.einsum("bsd,dv->bsv", normed, _w(head, cfg.activation_dtype))
+    return logits.astype(jnp.float32)
+
+
 def output_head(params: Params, x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """Final norm and the (tied or untied) head: x [B, S, D] -> f32 logits [B, S, vocab]."""
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, _w(head, cfg.activation_dtype))
-    return logits.astype(jnp.float32)
+    return _head(params, rms_norm(x, params["final_norm"], cfg.norm_eps), cfg)
 
 
 # ------------------------------------------------------------------------- forward
@@ -556,6 +575,54 @@ def _pattern_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
     return x, jnp.zeros((), jnp.float32) if auxs is None else auxs.sum()
 
 
+def _stacked_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids, token_mask,
+                    cache: Optional[KVCache] = None):
+    """x through the stacks of a model without a pattern, one loop a stack of layers (a leading
+    dense stack, then `layers`): a layer's parameters and, when there is a cache (one stack
+    only), its K/V (None is an empty pytree: the scan then carries no K/V in or out).
+    Returns (x, the last stack's new K/V or None, the last stack's aux a layer: the expert
+    layers' where there are any)."""
+    cache_len = None if cache is None else cache.length
+
+    def body(h, xs):
+        lp, kv = xs
+        h, new_kv, aux = _block(h, lp, cfg, positions, segment_ids, kv,
+                                cache_len, token_mask)
+        return h, (new_kv, aux)
+
+    for name in _layer_kinds(cfg):
+        with jax.named_scope(LAYER_LOOP):
+            x, (new_kv, auxs) = jax.lax.scan(
+                _maybe_remat(body, cfg), x,
+                (params[name], None if cache is None else (cache.k, cache.v)))
+    return x, new_kv, auxs
+
+
+def looped_outputs(params: Params, tokens: jax.Array, cfg: ModelConfig, *,
+                   positions: Optional[jax.Array] = None, segment_ids: Optional[jax.Array] = None):
+    """A looped stack (cfg.loop_steps = T > 1; Ouro, arXiv:2510.25741): tokens [B, S] -> [n_1 .. n_T],
+    each [B, S, D]. h_0 is the embedding; recurrence t runs every layer over h_{t-1} with the SAME
+    parameters, n_t is the (one, shared) final norm of what comes out, and h_t = n_t: the next
+    recurrence starts from the normed output. A head and an exit gate read each n_t
+    (`forward`: the last one's logits; `expected_exit_loss`: all of them). A layer application
+    keeps what `_maybe_remat` says, T x n_layers of them; the gradient of a layer's leaf is the
+    sum over its T uses."""
+    if positions is None:
+        positions = jnp.arange(tokens.shape[1])[None, :]
+    with jax.named_scope("embed"):
+        x = wsc(embed_tokens(params, tokens, cfg), "batch", "seq", "act_embed")
+    outs = []
+    for _ in range(cfg.loop_steps):
+        # the final norm is the head's, as in every model, and here the recurrence's too: what a recurrence
+        # costs beyond its layers
+        with jax.named_scope(LOOP_STEP):
+            x, _, _ = _stacked_layers(x, params, cfg, positions, segment_ids, None)
+            with jax.named_scope("lm_head"):
+                x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        outs.append(x)
+    return outs
+
+
 def forward(
     params: Params,
     tokens: jax.Array,
@@ -578,8 +645,20 @@ def forward(
 
     Under cfg.diffusion_block `tokens` is the block-diffusion objective's doubled row
     [noised ; clean] with `positions` repeated (`block_diffusion_loss` builds both), and
-    `head_rows` cuts the noised half out before the final norm and the head."""
+    `head_rows` cuts the noised half out before the final norm and the head.
+
+    Under cfg.loop_steps > 1 the logits are the LAST recurrence's (`looped_outputs`)."""
     b, s = tokens.shape
+    if cfg.loop_steps > 1:
+        if cache is not None:
+            raise NotImplementedError(
+                f"a looped stack under a KV cache: every one of its loop_steps ({cfg.loop_steps}) recurrences "
+                f"attends over keys and values of its own, a cache of loop_steps x n_layers entries, and "
+                "init_kv_cache builds n_layers")
+        last = looped_outputs(params, tokens, cfg, positions=positions, segment_ids=segment_ids)[-1]
+        with jax.named_scope("lm_head"):
+            logits = wsc(_head(params, last, cfg), "batch", "seq", "act_vocab")
+        return (logits, None, jnp.zeros((), jnp.float32)) if return_aux else (logits, None)
     if cfg.diffusion_block and s % (2 * cfg.diffusion_block):  # (a cache, packed documents: the mixer refuses them)
         raise NotImplementedError(
             f"the block-diffusion objective (cfg.diffusion_block) takes one doubled row [noised ; clean] of whole "
@@ -602,22 +681,7 @@ def forward(
         x, aux_total = _pipeline_layers(x, params, cfg, positions, segment_ids,
                                         token_mask)
     else:
-        # one loop a stack of layers (a leading dense stack, then `layers`): a layer's
-        # parameters and, when there is a cache (one stack only), its K/V (None is an
-        # empty pytree: the scan then carries no K/V in or out)
-        cache_len = None if cache is None else cache.length
-
-        def body(h, xs):
-            lp, kv = xs
-            h, new_kv, aux = _block(h, lp, cfg, positions, segment_ids, kv,
-                                    cache_len, token_mask)
-            return h, (new_kv, aux)
-
-        for name in _layer_kinds(cfg):
-            with jax.named_scope(LAYER_LOOP):
-                x, (new_kv, auxs) = jax.lax.scan(
-                    _maybe_remat(body, cfg), x,
-                    (params[name], None if cache is None else (cache.k, cache.v)))
+        x, new_kv, auxs = _stacked_layers(x, params, cfg, positions, segment_ids, token_mask, cache)
         # the last stack's: the expert layers' where there are any
         aux_total = dict(auxs, hidden=x) if cfg.moe_dropless else auxs.sum()
         if cache is not None:
@@ -684,6 +748,12 @@ def _cross_entropy(logits: jax.Array, targets: jax.Array, mask: jax.Array):
     return -((tgt - lse) * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
+def _token_losses(logits: jax.Array, targets: jax.Array) -> jax.Array:
+    """The cross entropy of `targets` [B, S] a position, float32 [B, S] (`_cross_entropy`'s form, not yet averaged)."""
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    return lse - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+
+
 def _expert_counters(load: jax.Array, chosen: jax.Array, cfg: ModelConfig) -> Dict[str, jax.Array]:
     """The dropless layers' counters of a step, a row an expert layer (the MTP modules' last):
     what the balance rule reads, what fell on the experts held here, the windows of the
@@ -727,14 +797,74 @@ def block_diffusion_loss(params: Params, batch: Dict[str, jax.Array], cfg: Model
     row, positions = block_diffusion_rows(tokens, masked, cfg)
     logits, _, aux = forward(params, row, cfg, positions=positions, return_aux=True, head_rows=n)
     with jax.named_scope("loss"):
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        ce = (lse - jnp.take_along_axis(logits, tokens[..., None], axis=-1)[..., 0]) * masked
+        ce = _token_losses(logits, tokens) * masked
         count = masked.sum().astype(jnp.float32)
         loss = (ce / p_mask.astype(jnp.float32)[:, None]).sum() / (b * n)
         metrics = {"loss": loss, "ce_loss": ce.sum() / jnp.maximum(count, 1.0), "masked_tokens": count,
                    "tokens": jnp.asarray(b * n, jnp.float32)}
     if isinstance(aux, dict):
         metrics.update(_expert_counters(aux["load"], aux["chosen"], cfg))
+    return loss, metrics
+
+
+def exit_distribution(gate_logits: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The exit gates' logits a_1 .. a_{T-1} [T - 1, ...] (lambda_t = sigmoid(a_t): the chance of leaving
+    behind recurrence t, having come that far) -> (p, log p) [T, ...] float32, the chance of leaving behind
+    each recurrence: p_t = lambda_t prod_{j<t} (1 - lambda_j), and the last takes what is left, p_T =
+    prod_{j<T} (1 - lambda_j). In logarithms, so that a gate that saturates makes no 0 x inf."""
+    a = gate_logits.astype(jnp.float32)
+    stayed = jnp.cumsum(jax.nn.log_sigmoid(-a), axis=0)  # log prod_{j<=t} (1 - lambda_j)
+    before = jnp.concatenate([jnp.zeros_like(stayed[:1]), stayed[:-1]])
+    log_p = jnp.concatenate([jax.nn.log_sigmoid(a) + before, stayed[-1:]])
+    return jnp.exp(log_p), log_p
+
+
+def expected_exit_loss(params: Params, batch: Dict[str, jax.Array], cfg: ModelConfig):
+    """A looped stack's objective (cfg.loop_steps = T > 1; Ouro's first stage, arXiv:2510.25741, which
+    trains the gate and the model together). Behind every recurrence the head's next-token cross
+    entropy a position, l_t, and (but for the last) the exit gate lambda_t = sigmoid(w_e . n_t + b_e) (the leaf `exit_gate` = [w_e ; b_e]);
+    with p the exit distribution a position (`exit_distribution`),
+
+        loss = mean over positions of [ sum_t p_t l_t - cfg.exit_entropy_weight H(p) ],  H(p) = -sum_t p_t log p_t,
+
+    and the gradient runs through p and through every l_t. A recurrence's head and loss are
+    rematerialised as a unit, so that one recurrence's [B, S, vocab] float32 logits live at a time and not T.
+    Beside `loss`: `ce_loss` (the expectation alone), `exit_entropy` (H's mean), `exit_step_mean` (the
+    mean of sum_t t p_t) and `ce_by_step` [T] (each recurrence's mean cross entropy)."""
+    tokens, seg = batch["tokens"], batch.get("segment_ids")
+    mask = batch.get("loss_mask")
+    mask = (jnp.ones(tokens.shape, jnp.float32) if mask is None else mask.astype(jnp.float32))[:, 1:]
+    outs = looped_outputs(params, tokens[:, :-1], cfg, segment_ids=None if seg is None else seg[:, :-1])
+
+    targets = tokens[:, 1:]
+    # the leaves a recurrence's outputs read, and no other: the rematerialised unit's backward pass makes a
+    # gradient for every leaf it is handed
+    read = {leaf: params[leaf] for leaf in ("embed" if cfg.tie_embeddings else "lm_head", "exit_gate")}
+
+    def behind(read, n, gated):  # one recurrence's outputs: (l_t [B, S], a_t [B, S] or None)
+        with jax.named_scope("lm_head"):
+            logits = wsc(_head(read, n, cfg), "batch", "seq", "act_vocab")
+        with jax.named_scope("loss"):
+            ce = _token_losses(logits, targets)
+        if not gated:  # the last recurrence takes what is left: its gate is never asked
+            return ce, None
+        with jax.named_scope(EXIT_GATE):
+            return ce, jnp.einsum("bsd,d->bs", n.astype(jnp.float32), read["exit_gate"][:-1]) + read["exit_gate"][-1]
+
+    if cfg.remat and cfg.remat_policy != "none":
+        behind = jax.checkpoint(behind, static_argnums=(2,))
+    ces, gates = zip(*(behind(read, n, t < cfg.loop_steps - 1) for t, n in enumerate(outs)))
+    with jax.named_scope(EXIT_GATE):
+        p, log_p = exit_distribution(jnp.stack(gates[:-1]))
+    with jax.named_scope(EXIT_LOSS):
+        count = jnp.maximum(mask.sum(), 1.0)
+        mean = lambda a: (a * mask).sum((-2, -1)) / count  # noqa: E731  ([.., B, S] -> [..])
+        ce = jnp.stack(ces)
+        expected, entropy = mean((p * ce).sum(0)), mean(-(p * log_p).sum(0))
+        loss = expected - cfg.exit_entropy_weight * entropy
+        steps = jnp.arange(1, cfg.loop_steps + 1, dtype=jnp.float32)[:, None, None]
+        metrics = {"loss": loss, "ce_loss": expected, "tokens": count, "exit_entropy": entropy,
+                   "exit_step_mean": mean((p * steps).sum(0)), "ce_by_step": mean(ce)}
     return loss, metrics
 
 
@@ -746,9 +876,12 @@ def loss_fn(
     """Next-token cross entropy. batch: tokens [B,S]; optional loss_mask/segment_ids.
     With MTP modules (cfg.mtp_depth) the mean of their losses is added at
     cfg.mtp_loss_weight; module m predicts the token m + 1 ahead. The configuration chooses
-    the objective: under cfg.diffusion_block it is `block_diffusion_loss`."""
+    the objective: under cfg.diffusion_block it is `block_diffusion_loss`, under
+    cfg.loop_steps > 1 `expected_exit_loss`."""
     if cfg.diffusion_block:
         return block_diffusion_loss(params, batch, cfg)
+    if cfg.loop_steps > 1:
+        return expected_exit_loss(params, batch, cfg)
     tokens = batch["tokens"]
     seg = batch.get("segment_ids")
     logits, _, aux = forward(

@@ -295,7 +295,8 @@ def test_bfloat16_activations_err_as_the_rounded_reference_does(family):
     cfg = dataclasses.replace(family.tiny, dtype="bfloat16")
     p, t = params(cfg, family.unsettle), family.batch_for(cfg, (2, 65))
     (loss, m), grads = system(p, t, cfg)
-    chosen = [np.asarray(c).reshape(2, -1, c.shape[-1]) for c in m["experts_chosen"]]  # 64 rows a sequence, or 2 x 64
+    # 64 rows a sequence, or 2 x 64 (a family that routes nothing has no selection to hand over)
+    chosen = [np.asarray(c).reshape(2, -1, c.shape[-1]) for c in m["experts_chosen"]] if cfg.n_experts else None
     exact, e_grads = reference(family.ref, cfg)(p, t, chosen)
     coarse, c_grads = reference(family.ref, cfg, jnp.bfloat16)(p, t, chosen)
     assert abs(float(loss - exact)) < 3 * abs(float(coarse - exact)) + 1e-3 * float(exact)
@@ -344,7 +345,8 @@ def test_packed_documents_and_a_cache_are_refused_by_name(family):
         packed = {**family.batch_for(cfg, (2, 33)), "segment_ids": jnp.ones((2, 32))}
         with pytest.raises(NotImplementedError, match="block-diffusion objective over packed documents"):
             jax.eval_shape(lambda p: llama.loss_fn(p, packed, cfg), p)
-    refused = "block-diffusion attention .* under a KV cache" if cfg.diffusion_block else "KV cache over layers of more than one kind"
+    refused = ("block-diffusion attention .* under a KV cache" if cfg.diffusion_block else
+               "a looped stack under a KV cache" if cfg.loop_steps > 1 else "KV cache over layers of more than one kind")
     with pytest.raises(NotImplementedError, match=refused):
         jax.eval_shape(lambda p: llama.forward(p, t[:, :32], cfg, cache=llama.init_kv_cache(cfg, 2, 64)), p)
 
@@ -494,6 +496,10 @@ def first_step(family):
 def test_the_bias_moves_by_the_balance_rule_a_row_an_expert_part_in_pattern_order(family, first_step):
     cfg = family.tiny
     state, after, m, _, _ = first_step
+    if not cfg.n_experts:  # a family that routes nothing: the step has no counts and no rule to follow
+        assert np.isfinite(float(m["loss"])) and not {"expert_load", "experts_chosen", "mtp_loss"} & set(m)
+        assert int(after.step) == int(state.step) + 1
+        return
     load = np.asarray(m["expert_load"])
     assert load.shape == (after.params["layers"]["router"].shape[0] + cfg.mtp_depth, cfg.n_experts)
     rows = 2 * 32 * (2 if cfg.diffusion_block else 1)  # (a doubled row: both halves choose)
@@ -542,8 +548,10 @@ def test_configuration_files_program_group_equals_its_published_keys(family):
     for published, field in family.pairs.items():
         assert getattr(cfg, field) == config[published], (published, field)
     assert sorted(config["reduced"]) == sorted(config["published"])
-    assert cfg.moe_dropless and (cfg.moe_scoring, cfg.moe_select_bias) == (family.tiny.moe_scoring, family.tiny.moe_select_bias)
-    assert (cfg.moe_scoring, cfg.moe_select_bias) in (("sigmoid", True), ("softmax", False))
+    assert (cfg.n_experts > 0) == (family.tiny.n_experts > 0)
+    if cfg.n_experts:
+        assert cfg.moe_dropless and (cfg.moe_scoring, cfg.moe_select_bias) == (family.tiny.moe_scoring, family.tiny.moe_select_bias)
+        assert (cfg.moe_scoring, cfg.moe_select_bias) in (("sigmoid", True), ("softmax", False))
     assert abs(cfg.n_params - family.cell_params) < 0.1e6  # the issue's arithmetic
     shapes = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
     held = sum(int(np.prod(a.shape)) for path, a in jax.tree_util.tree_flatten_with_path(shapes)[0]
@@ -650,17 +658,20 @@ def test_the_new_cell_rehearses_on_the_cpu(family):
     lines = [json.loads(ln) for ln in out.stdout.splitlines() if ln.startswith("{")]
     window = next(ln for ln in lines if ln.get("phase") == "window")
     assert all(window["checks"].values()), window["checks"]
-    assert {"selection_agrees_beyond_margin", "step_losses_match_reference",
-            "step_gradients_match_reference", "step_update_follows_its_moments",
-            "router_bias_moved_by_the_rule" if family.tiny.moe_select_bias else "masked_tokens_are_the_batchs",
-            } <= set(window["checks"])
+    routed = {"selection_agrees_beyond_margin",
+              "router_bias_moved_by_the_rule" if family.tiny.moe_select_bias else "masked_tokens_are_the_batchs"}
+    assert {"step_losses_match_reference", "step_gradients_match_reference", "step_update_follows_its_moments",
+            *(routed if family.tiny.n_experts else ())} <= set(window["checks"])
+    assert family.tiny.n_experts or not routed & set(window["checks"])
     assert window["parity"]["gradient"]["rows"] > rows
     assert set(window["parity"]["losses"]) == losses
     assert window["parity"]["positions"] == positions
     setup = next(ln for ln in lines if ln.get("phase") == "setup_split_s")
     assert 0 < setup["of_which_parity"] < setup["warmup_and_parity"]
     values = next(ln for ln in lines if ln.get("phase") == "rehearsal_values")["values"]
-    assert values["train_moe_imbalance"]["value"] >= 1.0
+    if family.tiny.n_experts:
+        assert values["train_moe_imbalance"]["value"] >= 1.0
+    assert values["train_step_ms"]["value"] > 0
     assert lines[-1]["correct"] is False and lines[-1]["metrics"] == {}
 
 

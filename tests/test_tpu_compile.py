@@ -609,6 +609,55 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on
         assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
 
 
+@pytest.mark.slow  # (beside Kimi-Linear's whole step: `-m slow -k ouro`, ~1 min; tier-1 holds the family at a small size)
+def test_the_ouro_cells_looped_step_compiles_inside_its_memory_at_five_layers_and_not_at_six(one_chip, on_tpu):
+    """The whole step of `ouro26b-train-loop4-s8192` as its configuration file states it (five layers run
+    four times over shared weights, four heads with their exit gates, the expected-exit loss), compiled for
+    the described chip. PR 58: arguments 5.500 GB (12 B a parameter, each counted ONCE) + temporaries 9.512 GB
+    = 15.01 of 15.75 GB; six layers are 6.116 + 10.148 = 16.26 GB and do not fit (the configuration's `cut`
+    says what a layer costs). A forward flash kernel a layer-stack loop (four: one a recurrence, none made
+    again: `out` and the logsumexp are kept by name, T x L of them) and ONE backward kernel a loop; XLA
+    rematerialises nothing of its own; the loop's three scopes stand in the `op_name`s, forward and backward,
+    and a recurrence's rematerialised head and loss keep theirs."""
+    import importlib
+
+    from ray_tpu.models import llama
+    from ray_tpu.train import make_optimizer, make_train_step
+    from ray_tpu.train.step import TrainState
+
+    attention_ops = importlib.import_module("ray_tpu.ops.attention")
+    cfg, file = _cell_file("ouro-2.6b-train-loop4")
+    trainer = file["trainer"]
+    assert (cfg.loop_steps, cfg.n_layers, cfg.remat_policy, trainer["mesh"]) == (4, 5, "full", None)
+    fallbacks = attention_ops.xla_fallback_count
+    tx = make_optimizer(**trainer["optimizer"])
+
+    def compiled_step(cfg):
+        params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
+        state = TrainState(step=_scalar(one_chip), params=params,
+                           opt_state=_shapes(jax.eval_shape(tx.init, params), one_chip))
+        batch = {"tokens": jax.ShapeDtypeStruct((trainer["batch"], trainer["seq"] + 1), jnp.int32, sharding=one_chip)}
+        return make_train_step(cfg, tx).lower(state, batch).compile()
+
+    compiled = compiled_step(cfg)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7  # a shared leaf is held once
+    assert memory.temp_size_in_bytes < (9.52 + 0.15) * 1e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
+    assert not _xla_remats(text) and attention_ops.xla_fallback_count == fallbacks
+    assert _kernel_calls(text, "flash_attention_fwd") == (cfg.loop_steps, 0)
+    assert _kernel_calls(text, "flash_attention_bwd_dkv_dq") == (cfg.loop_steps, 0)
+    assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (0, 0)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in (llama.LOOP_STEP, llama.EXIT_GATE, llama.EXIT_LOSS):
+        assert any(f"/jvp(model)/{scope}" in n for n in names) and any(f"/transpose(jvp(model))/{scope}" in n for n in names), scope
+    again = {n for n in names if "transpose(jvp(model))" in n and "rematted_computation" in n}
+    assert any("/lm_head/" in n for n in again) and any("/loss/" in n for n in again)
+    assert any(f"/{llama.LOOP_STEP}/{llama.LAYER_LOOP}/" in n for n in again)
+    deeper = compiled_step(dataclasses.replace(cfg, n_layers=6)).memory_analysis()
+    assert deeper.argument_size_in_bytes + deeper.temp_size_in_bytes > 15.75e9  # the greatest depth that is placed is five
+
+
 def test_the_block_diffusion_cells_step_compiles_inside_its_memory_and_walks_288_tiles_a_head(one_chip, on_tpu):
     """The whole step of `sdar30b-train-ep8share-s8192` as its configuration file states it (five
     layers; the batch a loader makes: tokens, masked, p_mask), compiled for the described chip:
