@@ -40,11 +40,19 @@ and exp(100) is no float32. A chunk is cut into sub-chunks of `_SUB` positions:
                     whose true weight is below exp(-87) = 1.6e-38: that is the bound.
 Where they are made decides what is alive: `overlaps` hands a chunk to the two Pallas kernels
 of ops/kda_overlaps.py wherever they tile it (`kda_overlaps.supports`: channels in whole
-128-lane registers, sub-chunks in whole registers of 8 rows; the Solar-Open2 cell's 128 /
-32 / 128), one grid step a chunk and head, and then a chunk's differences, decayed keys and
+128-lane registers, sub-chunks in whole registers of 8 rows; the Solar-Open2 and Kimi-Linear
+cells' 128 / 32 / 128), a chunk of up to 8 heads a grid step (`kda_overlaps._per_step`, the
+rule the second half's kernels share), and then a chunk's differences, decayed keys and
 factors live and die in fast memory in both passes and the backward pass keeps q, k and G
-alone (kk and b carry no name for the remat policies: kept they would spare a rematerialised
-layer the forward kernel's second run, 1.1 ms, for 201 MB a step, which takes the
+alone. The kernels cut the pairs inside a sub-chunk once more, the same sums under the same
+bound: a pair of two BLOCKS of 8 positions of one sub-chunk goes through a second reference,
+G_r at the end of the earlier block (s <= r < t, so exp(G_t - G_r) and exp(G_r - G_s) are
+both <= 1), and joins the pairs of two sub-chunks in their one product a sub-chunk on the
+MXU; only the 8 x 8 blocks on the diagonal are made from the differences themselves, on the
+vector unit (that file's docstring; on the chip the kernels' sums stand within 2e-6 of
+`_decayed_overlaps`' largest entry, the MXU's six passes over float32 operands; PERF.md
+section 6, PR 59). (kk and b carry no name for the remat policies: kept they would spare a
+rematerialised layer the forward kernel's second run for 201 MB a step, which takes the
 Solar-Open2 step's temporaries from 4.79 to 5.00 GB, over what its compile test allows; the
 second half's kernels freed 3 MB of them, not 200: PERF.md section 6, PRs 38 and 51). Any
 other shape (a width of 16, a chunk of 16: tier-1's) runs

@@ -10,7 +10,7 @@ the keys) [Q, Q], all float32,
 three products [Q, Q] x [Q, K + V] of float32 operands at the highest precision (Mosaic's
 contract_precision<fp32>, six passes of the MXU), the same two decays, each the exponential
 of a non-positive number. What differs is where the intermediates live: a grid step holds a
-chunk of `_per_step` heads and walks the heads in a loop (one body, traced once), and a
+chunk of `kda_overlaps._per_step` heads and walks the heads in a loop (one body, traced once), and a
 head's exp G, beta [k exp G | v], [W | U0] and Kend are made, used and dropped in fast
 memory. Nothing with the extents [Q, K + V] reaches HBM in either pass; the forward kernel
 writes P, O0, M, N and nothing else.
@@ -42,8 +42,9 @@ diagonal of a [Q, Q] select and one sum (exact: a value plus zeros).
 
 VMEM a grid step: forward 10 blocks of 64 KB a head at 128 x 128 (q, k, v, G, T, b in, P,
 O0, M, N out) and beta's row, twice for the pipeline's two buffers: 1.3 MB a head; backward
-16 blocks (the four cotangents in, six gradients and dbeta's row out), 2.1 MB. `_per_step`
-takes as many of a chunk's heads a step as `_VMEM_BLOCKS` allows (4 at 128 x 128).
+16 blocks (the four cotangents in, six gradients and dbeta's row out), 2.1 MB.
+`kda_overlaps._per_step` (beside `rows_block`: one rule for all four kernels) takes as many of a
+chunk's heads a step as `_VMEM_BLOCKS` allows (4 at 128 x 128).
 
 `kda_overlaps.supports` says which shapes go to the kernels (ops/kda.py's `takes_kernels`
 routes both halves by it); off a TPU they run in Pallas' interpreter.
@@ -56,14 +57,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import flash_attention as _fa
-from .kda_overlaps import _NN, _NT, _dot, rows_block
-
-_VMEM_BLOCKS = 10 * 2**20  # bytes of a grid step's blocks, the pipeline's two buffers (of a kernel's 16 MiB)
-
-
-def _per_step(heads: int, head_bytes: int) -> int:
-    """Heads of a chunk a grid step walks: the most of 8, 4, 2, 1 that divide the heads and fit."""
-    return next((p for p in (8, 4, 2) if heads % p == 0 and 2 * p * head_bytes <= _VMEM_BLOCKS), 1)
+from .kda_overlaps import _NN, _NT, _dot, _per_step, rows_block
 
 
 def _eye(n: int):

@@ -12,12 +12,17 @@ from ray_tpu.models.reference import solar_open2 as ref
 from ray_tpu.ops import kda as kda_op
 
 
-def _value_and_pull(fn, args, cot):
-    """fn(*args) and the pull-back of `cot` to every argument, as one program."""
+_PROGRAMS = {}  # (what, shape, path) -> the jitted program: the regimes of a shape share one trace and compile
+
+
+def _value_and_pull(fn, args, cot, key=None):
+    """fn(*args) and the pull-back of `cot` to every argument, as one program; with a `key`, the program of
+    the first call under it (the same fn at the same shapes: its caller's word) runs again."""
     def run(args, cot):
         value, pull = jax.vjp(fn, *args)
         return value, pull(cot)
-    return jax.jit(run)(args, cot)
+    program = _PROGRAMS.setdefault(key, jax.jit(run)) if key else jax.jit(run)
+    return program(args, cot)
 
 
 def _scan_inputs(t, regime, seed=0, b=2, h=3, width=16):
@@ -29,8 +34,9 @@ def _scan_inputs(t, regime, seed=0, b=2, h=3, width=16):
     # exp(g): near 1 (a long memory), a chunk's sum below -100 (none: 16 positions of -7 to
     # -30 a channel), and both in one layer; beta over (0, 2) or within 0.1 of 2, where
     # I - beta k k^T is all but a reflection
+    # below -87 inside a sub-chunk of 32 and not inside every block of 8 (4 to 30 a position)
     lo, hi = {"near_one": (1e-4, 1e-2), "below_minus_100_a_chunk": (7.0, 30.0), "mixed": (1e-3, 30.0),
-              "beta_near_2": (1e-3, 1.0)}[regime]
+              "beta_near_2": (1e-3, 1.0), "below_minus_87_a_sub_chunk": (4.0, 30.0)}[regime]
     g = -jnp.exp(jax.random.uniform(ks[3], (b, t, h, width), minval=jnp.log(lo), maxval=jnp.log(hi)))
     beta = jax.random.uniform(ks[4], (b, t, h), minval=1.9 if regime == "beta_near_2" else 0.0, maxval=2.0)
     return q, k, v, g, beta
@@ -91,28 +97,43 @@ def _pallas_calls(fn, *args):
     return names
 
 
-@pytest.mark.parametrize("chunk,sub,t", [(32, 8, 128), (32, 32, 64), (128, 32, 256)])
-@pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2"])
-def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, t, monkeypatch):
+@pytest.mark.parametrize("chunk,sub,t,b,h", [
+    (32, 8, 128, 1, 2), (32, 32, 64, 1, 2), (128, 32, 256, 1, 2),
+    (128, 32, 128, 1, 8),  # 8 heads a grid step in both of the overlaps' kernels, 4 in the second half's backward
+    (32, 32, 64, 2, 4),  # 4 heads a step out of two rows of a batch: `rows_block`'s map at `per` > 1
+])
+@pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2",
+                                    "below_minus_87_a_sub_chunk"])
+def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, t, b, h, monkeypatch):
     """At a width of 128 both halves go to their Pallas kernels (ops/kda_overlaps.py and
     ops/kda_parts.py, in the interpreter here): the scan's output and the gradient of q, k, v,
     g and beta against the same scan with `_decayed_overlaps` and `_chunk_parts` in their
-    place and against the recurrence a position at a time, 2 heads, 2 to 4 chunks, sub-chunks
-    of 8 and 32."""
+    place and against the recurrence a position at a time, 2 to 8 heads (2 to 8 of them a grid
+    step), 1 to 4 chunks, sub-chunks of 8 (one diagonal block each: no second reference) and 32.
+    In the last regime the decays between two positions of ONE sub-chunk fall below exp(-87)
+    and the factor through the end of a block of 8 columns underflows with them: the bound
+    ops/kda.py states for a pair of two sub-chunks, held for the pairs of two blocks of 8."""
     monkeypatch.setattr(kda_op, "_SUB", sub)
-    args = _scan_inputs(t, regime, b=1, h=2, width=128)
+    args = _scan_inputs(t, regime, b=b, h=h, width=128)
     if regime == "below_minus_100_a_chunk":
-        assert float(args[3].reshape(1, t // chunk, chunk, 2, 128).sum(2).max()) < -100
+        assert float(args[3].reshape(b, t // chunk, chunk, h, 128).sum(2).max()) < -100
+    if regime == "below_minus_87_a_sub_chunk":
+        assert float(args[3].reshape(b, t // 32, 32, h, 128)[:, :, 1:].sum(2).max()) < -87
+        across_8 = args[3].reshape(b, t // 8, 8, h, 128)[:, :, 1:].sum(2)
+        assert float(across_8.min()) < -87 < float(across_8.max())
     scan = lambda *a: kda_op.kda_scan(*a, chunk)  # noqa: E731
-    assert sorted(_pallas_calls(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=(0, 1, 3)), *args)) == [
-        "kda_overlaps_bwd", "kda_overlaps_fwd", "kda_parts_bwd", "kda_parts_fwd"]
+    shape = (chunk, sub, t, b, h)
+    if ("kernels", *shape) not in _PROGRAMS:
+        assert sorted(_pallas_calls(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=(0, 1, 3)), *args)) == [
+            "kda_overlaps_bwd", "kda_overlaps_fwd", "kda_parts_bwd", "kda_parts_fwd"]
     cot = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
-    got, mine = _value_and_pull(scan, args, cot)
-    want, theirs = _value_and_pull(ref.recurrence, args, cot)
+    got, mine = _value_and_pull(scan, args, cot, key=("kernels", *shape))
+    want, theirs = _value_and_pull(ref.recurrence, args, cot, key=("recurrence", t, b, h))
     with monkeypatch.context() as m:
         m.setattr(kda_op.kda_overlaps, "supports", lambda *shape: False)
-        assert not _pallas_calls(scan, *args)
-        plain, plains = _value_and_pull(scan, args, cot)
+        if ("plain", *shape) not in _PROGRAMS:
+            assert not _pallas_calls(scan, *args)
+        plain, plains = _value_and_pull(scan, args, cot, key=("plain", *shape))
     assert np.isfinite(np.asarray(got)).all()
     scale = float(jnp.abs(want).max())
     np.testing.assert_allclose(got, plain, atol=2e-6 * scale)  # the same sums: a rounding apart
@@ -128,11 +149,14 @@ def _parts_inputs(t, regime, chunk, seed=0, b=1):
     """What `kda_scan` hands the second half at `_scan_inputs`' draws: q, k, v [B, chunks, Q, H, K] (the
     positions in the mixer's order), G [chunks, B, H, Q, K], beta [chunks, B, H, Q], T = (I + A)^-1 and b
     [chunks, B, H, Q, Q]."""
-    q, k, v, g, beta = (x.reshape(b, t // chunk, chunk, *x.shape[2:])
-                        for x in _scan_inputs(t, regime, seed=seed, b=b, h=2, width=128))
-    run, beta = kda_op._lead(jnp.cumsum(g, 2)), kda_op._lead(beta)
-    a, b = kda_op._overlaps(q, k, run, beta)
-    return q, k, v, run, beta, kda_op._unit_lower_inverse(a), b
+    def first_half(q, k, v, g, beta):
+        q, k, v, g, beta = (x.reshape(b, t // chunk, chunk, *x.shape[2:]) for x in (q, k, v, g, beta))
+        run, beta = kda_op._lead(jnp.cumsum(g, 2)), kda_op._lead(beta)
+        a, overlap = kda_op._overlaps(q, k, run, beta)
+        return q, k, v, run, beta, kda_op._unit_lower_inverse(a), overlap
+
+    program = _PROGRAMS.setdefault(("first half", chunk, kda_op._SUB, t, b), jax.jit(first_half))
+    return program(*_scan_inputs(t, regime, seed=seed, b=b, h=2, width=128))
 
 
 @pytest.mark.parametrize("chunk,sub,t,b", [(32, 8, 128, 1), (32, 32, 64, 2), (128, 32, 256, 1)])
@@ -146,13 +170,15 @@ def test_the_second_halfs_kernels_are_chunk_parts(regime, chunk, sub, t, b, monk
     where the decays leave all but nothing of it)."""
     monkeypatch.setattr(kda_op, "_SUB", sub)
     args = _parts_inputs(t, regime, chunk, b=b)
+    shape = (chunk, sub, t, b)
     assert kda_op.takes_kernels(chunk, 128)
-    assert _pallas_calls(lambda *a: jax.vjp(kda_op.chunk_parts, *a)[1], *args) == ["kda_parts_fwd"]
+    if ("parts", *shape) not in _PROGRAMS:
+        assert _pallas_calls(lambda *a: jax.vjp(kda_op.chunk_parts, *a)[1], *args) == ["kda_parts_fwd"]
     plain = lambda *a: kda_op._chunk_parts(*(kda_op._lead(x) for x in a[:3]), *a[3:])  # noqa: E731
     want = jax.eval_shape(plain, *args)
     cot = tuple(jax.random.normal(key, x.shape) for key, x in zip(jax.random.split(jax.random.PRNGKey(9), 4), want))
-    got, mine = _value_and_pull(kda_op.chunk_parts, args, cot)
-    want, theirs = _value_and_pull(plain, args, cot)
+    got, mine = _value_and_pull(kda_op.chunk_parts, args, cot, key=("parts", *shape))
+    want, theirs = _value_and_pull(plain, args, cot, key=("plain parts", *shape))
     for name, x, y in zip("P O0 M N".split(), got, want):
         assert x.shape == y.shape and np.isfinite(np.asarray(x)).all(), name
         np.testing.assert_allclose(x, y, atol=2e-6 * float(jnp.abs(y).max()) + 1e-30, err_msg=name)
@@ -165,9 +191,12 @@ def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
     """The case above on the kernel path (width 128, chunks of 32): decays of exp(-3000) a
     position, chunks whose sums fall to -8e4; a value and gradients that are finite say that
     no exponential saw a positive number (exp(3000) is inf, and 0 x inf poisons a sum), the
-    masks cut before it in both of the overlaps' kernels, the factors across sub-chunks are
-    <= 1, and the second half's kernels take exp G and exp(G_Q - G) of sums that only fall."""
-    q, k, v, _, beta = _scan_inputs(64, "mixed", b=1, h=2, width=128)
+    masks cut before it in both of the overlaps' kernels, the factors across sub-chunks and,
+    inside one, through the end of a block of 8 columns (a chunk here is ONE sub-chunk of 32:
+    every pair outside the diagonal blocks goes through that second reference) are <= 1, and
+    the second half's kernels take exp G and exp(G_Q - G) of sums that only fall. 4 heads, all
+    of them one grid step's."""
+    q, k, v, _, beta = _scan_inputs(64, "mixed", b=1, h=4, width=128)
     g = jnp.full(q.shape, -3000.0).at[:, ::5].set(-1e-3)
     assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, 32), q, k, v, g, beta) == ["kda_overlaps_fwd", "kda_parts_fwd"]
     want = ref.recurrence(q, k, v, g, beta)
