@@ -24,6 +24,12 @@ import jax  # noqa: E402
 # Belt and braces for a jax that something imported before this file set the
 # environment: the config value is what backend selection reads.
 jax.config.update("jax_platforms", "cpu")
+# The tests' CPU programs run once, on a few dozen tokens, and are held to a reference's arithmetic, not
+# to XLA's CPU code generator: its optimiser was most of tier-1's CPU time and none of its point. What
+# is compiled for the described TPU is read for its optimised text and memory figure, and a few files hold
+# CPU programs to the optimiser's own bits: both turn it back on (tests/compiled_step_text.py: `tpu_side`
+# behind the `on_tpu` fixture, `optimised`). The benchmark's rehearsals are processes of their own and keep it.
+jax.config.update("jax_disable_most_optimizations", True)
 
 import pytest  # noqa: E402
 

@@ -8,7 +8,9 @@ a test over the `family` of the module that imports it. A family's file
 
 and below that only what the family alone has. pytest does not collect this module by
 itself (its name); conftest.py registers it for assertion rewriting. The file stays the
-unit the driver's `--dist loadfile` hands to a worker.
+unit the driver's `--dist loadfile` hands to a worker, and a family's whole cost in tier-1
+is its file's: the cell's whole step, compiled for the described v5e, is `Family.cell_step`
+and the `cell_step` fixture (once a file), not a case of tests/test_tpu_compile.py.
 
 The system under test runs as its users run it: loss and gradient under one `jax.jit`
 (the configuration static), the references likewise, and a seeded tree is made once a
@@ -22,6 +24,7 @@ import re
 import subprocess
 import sys
 import tempfile
+import types
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -29,10 +32,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from compiled_step_text import (  # noqa: F401  (`topo`, `one_chip`, `on_tpu`: fixtures a family's file gets with the contract)
+    CONV_PADDED_COPY, KEPT_PRODUCTS, OVERLAPS_INTERMEDIATES, PARTS_INTERMEDIATES, ROOT, cell_config, instructions, kept_copies,
+    kernel_calls, lower_cell_step, on_tpu, one_chip, products, topo, tpu_side, xla_remats)
 from ray_tpu.models import checkpoint, llama, moe
-from ray_tpu.models.config import ModelConfig
+from ray_tpu.models.config import LAYER_KINDS, ModelConfig
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 sys.path.insert(0, ROOT)  # benchmarks/ is read as its users read it, from the repository's root
 
 
@@ -85,6 +90,11 @@ class Family:
     # whether the `kept` cases (what a rematerialised recurrent mixer keeps) run from this family's file: a second
     # family of one mixer module leaves them to the first's
     kept_here: bool = True
+    # the cell's whole step compiled for the described v5e (`cell_step`): (bodies the layers' loop unrolls to: a period
+    # of the pattern's layers, or the scan's one and the MTP module's; the forward pick's loops under `moe_router` a
+    # body; the temporaries in GB, held to + 0.15). None: the cell's whole step is a test of its own shape in the
+    # family's file, which unbinds the contract's (`del test_a_family_cells_step_...`)
+    cell_step: Optional[Tuple[int, int, float]] = None
 
     @property
     def ref(self):
@@ -93,19 +103,11 @@ class Family:
     def cell_config(self):
         return cell_config(self.config)
 
-    def batch_for(self, cfg, shape=(2, 41), seed=1):
-        t = tokens(cfg, shape, seed)
+    def batch_for(self, cfg, shape=None, seed=1):
+        """The family's batch of seeded ids; `[batch, 41]` wherever a test is not about the shape, so that a
+        file compiles one program a (configuration, dtype)."""
+        t = tokens(cfg, shape or (self.batch, 41), seed)
         return t if self.batch_of is None else self.batch_of(cfg, t)
-
-
-def cell_config(name, directory="configs"):
-    """(a configuration file of the benchmark, the program's model keys, its ModelConfig)"""
-    from benchmarks.lib import modelcfg
-
-    with open(os.path.join(ROOT, "benchmarks", directory, f"{name}.json")) as f:
-        config = json.load(f)
-    model = modelcfg.model_keys(config)
-    return config, model, modelcfg.model_config(model)
 
 
 def pytest_generate_tests(metafunc):
@@ -182,8 +184,10 @@ def system(p, t, cfg):
     return jax.value_and_grad(llama.loss_fn, has_aux=True)(p, as_batch(t), cfg)
 
 
+@functools.lru_cache(maxsize=None)
 def reference(ref, cfg, dtype=jnp.float32, parts=False):
-    """fn(p, t, chosen) -> (loss or (loss, parts), gradients) of a reference module, as one program."""
+    """fn(p, t, chosen) -> (loss or (loss, parts), gradients) of a reference module, as one program, made
+    once for the same arguments (a file's tests share it, as they share `system`)."""
     model = model_of(cfg)
     return jax.jit(jax.value_and_grad(
         lambda p, t, chosen: ref.loss(p, t, model, dtype, chosen, parts), has_aux=parts))
@@ -260,7 +264,7 @@ def test_loss_and_every_gradient_match_the_reference(family, case):
     _, cfg, periods = case
     if cfg.layer_pattern:
         assert llama.pattern_period(cfg.layer_pattern)[1] == periods
-    p, t = params(cfg, family.unsettle), family.batch_for(cfg, (family.batch, 41))
+    p, t = params(cfg, family.unsettle), family.batch_for(cfg)
     assert ("mtp" in p) == bool(cfg.mtp_depth) and ("lm_head" in p) != cfg.tie_embeddings
     (loss, m), grads = system(p, t, cfg)
     (r_loss, parts), r_grads = reference(family.ref, cfg, parts=True)(p, t, None)
@@ -293,10 +297,10 @@ def test_bfloat16_activations_err_as_the_rounded_reference_does(family):
     against the float32 reference, loss and every leaf's gradient, in multiples of the
     error the same plain reference makes in bfloat16, on the experts the system chose."""
     cfg = dataclasses.replace(family.tiny, dtype="bfloat16")
-    p, t = params(cfg, family.unsettle), family.batch_for(cfg, (2, 65))
+    p, t = params(cfg, family.unsettle), family.batch_for(cfg)
     (loss, m), grads = system(p, t, cfg)
-    # 64 rows a sequence, or 2 x 64 (a family that routes nothing has no selection to hand over)
-    chosen = [np.asarray(c).reshape(2, -1, c.shape[-1]) for c in m["experts_chosen"]] if cfg.n_experts else None
+    # 40 rows a sequence, or 2 x 40 (a family that routes nothing has no selection to hand over)
+    chosen = [np.asarray(c).reshape(family.batch, -1, c.shape[-1]) for c in m["experts_chosen"]] if cfg.n_experts else None
     exact, e_grads = reference(family.ref, cfg)(p, t, chosen)
     coarse, c_grads = reference(family.ref, cfg, jnp.bfloat16)(p, t, chosen)
     assert abs(float(loss - exact)) < 3 * abs(float(coarse - exact)) + 1e-3 * float(exact)
@@ -332,7 +336,7 @@ def test_the_coarse_reference_is_the_same_code_rounded(family):
     assert 1e-6 < abs(float(coarse - exact)) / float(exact) < 2e-2
     assert frozenset(getattr(ref, "FLOAT32_LEAVES", ())) == family.float32_leaves
     one_a_position = getattr(ref, "next_token_losses", lambda *a: ref.position_losses(*a)[0])
-    assert jax.eval_shape(lambda: one_a_position(p, t, model_of(cfg))).shape == (2, 40)
+    assert jax.eval_shape(lambda: one_a_position(p, t, model_of(cfg))).shape == (family.batch, 40)
 
 
 def test_packed_documents_and_a_cache_are_refused_by_name(family):
@@ -541,6 +545,81 @@ def test_the_compiled_step_names_the_mixers_scopes(family):
     assert all(family.outer <= found for found in by_instruction.values() if found & family.mixer_scopes)
 
 
+# ------------------------------------------------------------------- the cell's whole step, compiled for the chip
+
+@pytest.fixture(scope="module")
+def cell_step(family, one_chip):
+    """The whole step of the family's cell as its configuration file states it, lowered and compiled for the
+    described v5e ONCE a file, for every case that reads it: cfg, trainer, the compiled text, its
+    `memory_analysis()`, the attention calls that fell to an XLA path while it was traced, the step and
+    the shapes it was lowered for."""
+    attention_ops = importlib.import_module("ray_tpu.ops.attention")  # (the package re-exports the function under this name)
+    file, _, cfg = family.cell_config()
+    fallbacks = attention_ops.xla_fallback_count
+    with tpu_side():
+        step, lowered, args = lower_cell_step(cfg, file["trainer"], one_chip)
+        compiled = lowered.compile()
+    return types.SimpleNamespace(cfg=cfg, trainer=file["trainer"], text=compiled.as_text(), memory=compiled.memory_analysis(),
+                                 fallbacks=attention_ops.xla_fallback_count - fallbacks, step=step, args=args)
+
+
+def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(family, cell_step):
+    """The whole step of the family's cell, compiled for the described chip: three router products an
+    expert layer and the forward pass's loops only (tests/test_tpu_compile_experts.py holds a layer alone to the
+    same); the temporaries within 0.15 GB of what `Family.cell_step` says they were, and XLA
+    rematerialises nothing of its own to fit (PERF.md section 7, after PR 26 (2)). The record's comment
+    says which PR moved the figure and by what."""
+    assert family.cell_step is not None, "a cell whose whole step is a test of its own shape unbinds this one (`del`)"
+    bodies, loops, temp_gb = family.cell_step
+    cfg, trainer, text, memory = cell_step.cfg, cell_step.trainer, cell_step.text, cell_step.memory
+    assert cfg.remat and cfg.remat_policy == "full" and trainer["mesh"] is None
+    assert len(instructions(text, "convolution", "moe_router")) == 3 * bodies
+    assert len(instructions(text, "while", "moe_router")) == loops * bodies
+    assert not xla_remats(text)
+    # under `full` a rematerialised layer keeps the forward flash kernel's results: a call an
+    # attention block forward, none made again (PR 43), ONE backward kernel a call (PR 53)
+    blocks = kernel_calls(text, "flash_attention_bwd_dkv_dq")[0]
+    assert blocks >= 1 and kernel_calls(text, "flash_attention_fwd") == (blocks, 0)
+    # a windowed part runs the windowed kernels, as often; no attention falls to an XLA path
+    windowed = sum(LAYER_KINDS[c].windowed for c in cfg.layer_pattern)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv_dq"):
+        assert kernel_calls(text, f"{kernel}_window") == (windowed, 0)
+    for suffix in ("", "_window"):  # the two kernels of a sequence longer than a span of K and V run nowhere
+        assert kernel_calls(text, f"flash_attention_bwd_dq{suffix}") == kernel_calls(text, f"flash_attention_bwd_dkv{suffix}") == (0, 0)
+    assert blocks + windowed == sum(LAYER_KINDS[c].mixer == "attn" for c in cfg.layer_pattern) + cfg.mtp_depth or not cfg.layer_pattern
+    assert cell_step.fallbacks == 0
+    # a recurrent mixer keeps its input product's result under `full` (PR 48): the product once a part
+    # forward, none made again in the rematerialised layer, two backward; one stored copy a part,
+    # rounded in the product's own epilogue
+    period = llama.pattern_period(cfg.layer_pattern)[0] if cfg.layer_pattern else ""
+    for kind, (scope, einsum, extents) in KEPT_PRODUCTS.items():
+        parts = period.count(kind)
+        assert products(text, scope, einsum) == (parts, 0, 2 * parts), kind
+        assert kept_copies(text, extents) == (parts, parts), kind
+    assert memory.temp_size_in_bytes < (temp_gb + 0.15) * 1e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
+    if cfg.kda_n_heads:  # a delta-rule part substitutes once, a call a block: the backward pass keeps the inverse
+        from ray_tpu.ops.kda import _SOLVE
+
+        parts = cfg.layer_pattern.count("K")
+        assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == parts * (cfg.kda_chunk // _SOLVE)
+        # the overlaps' kernels a part: forward, again in the rematerialised layer, backward (PR 38)
+        assert kernel_calls(text, "kda_overlaps_fwd") == (parts, parts)
+        assert kernel_calls(text, "kda_overlaps_bwd") == (parts, 0)
+        assert not re.search(OVERLAPS_INTERMEDIATES, text)
+        # the chunks' four matrices likewise (PR 51): [W | U0] and what it is made from stay in fast memory
+        assert kernel_calls(text, "kda_parts_fwd") == (parts, parts)
+        assert kernel_calls(text, "kda_parts_bwd") == (parts, 0)
+        assert not re.search(PARTS_INTERMEDIATES, text)
+        # the convolution, silu and norms of q, k and v: ONE call a part and pass whatever the three
+        # (9 a step; a call for each of q, k, v was 27, and 3 s of every first step: PR 44), and the
+        # plain form's float32 copy of q|k|v padded by the taps is gone with its shifted products
+        assert kernel_calls(text, "short_conv_fwd") == (parts, parts)
+        assert kernel_calls(text, "short_conv_bwd") == (parts, 0)
+        assert not re.search(CONV_PADDED_COPY, text)
+        assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
+
+
 # ------------------------------------------------------------------- the configuration
 
 def test_configuration_files_program_group_equals_its_published_keys(family):
@@ -676,5 +755,5 @@ def test_the_new_cell_rehearses_on_the_cpu(family):
 
 
 __all__ = [name for name in dir() if name.startswith("test_")] + [
-    "Family", "pytest_generate_tests", "family", "highest", "_highest", "first_step", "ROOT", "cell_config", "model_of", "seeded", "params",
+    "Family", "pytest_generate_tests", "family", "highest", "_highest", "first_step", "topo", "one_chip", "on_tpu", "cell_step", "ROOT", "cell_config", "model_of", "seeded", "params",
     "tokens", "system", "as_batch", "reference", "leaves_match", "config_from", "published_keys", "head_shares", "expert_shares"]

@@ -2,7 +2,7 @@
 loop by (models/attn.py:SCOPES, models/llama.py:LAYER_LOOP), held on the jaxpr of the
 loss's gradient of a tiny model of each attention kind: `eqn.source_info.name_stack` is
 what lowering writes into an instruction's `op_name`, a sub-jaxpr's equations under their
-caller's. No compile; the compiled text's side is tests/test_tpu_compile.py's.
+caller's. No compile; the compiled text's side is tests/test_tpu_compile_parts.py's.
 """
 import functools
 import re

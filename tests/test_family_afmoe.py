@@ -5,8 +5,8 @@ and rotated three to one with full attention that is not rotated, both gated a c
 normed a head; a leading dense layer, then sigmoid-routed SwiGLU experts at 8 of 128 beside
 a shared one; the embedding scaled; and the share of a layer's experts a chip holds. The
 contract is tests/family_contract.py's; here is what the family alone has. (The windowed
-kernels against the plain softmax: tests/test_flash_attention.py; compiled for the chip:
-tests/test_tpu_compile.py.)"""
+kernels against the plain softmax: tests/test_flash_window.py; compiled for the chip:
+tests/test_tpu_compile.py; the whole step: `Family.cell_step` below.)"""
 import dataclasses
 
 import jax
@@ -217,6 +217,11 @@ FAMILY = Family(
         "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
         "train_attn_passes_pct"}),
     own_metrics=("train_attn_window_pct", "train_attn_full_pct", "train_attn_window_roofline_pct", "train_mfu_swa_moe_pct"),
+    # the cell's whole step (`Family.cell_step`). PR 46: [2, 16384]: four attention parts inside a window of 2,048 (their
+    # kernels under their own names) and one full, gated, normed a head, a norm behind every part; four expert parts at 8
+    # of 128 over 32,768 tokens beside a shared expert; arguments 6.05 GB; the f32 logits [2, 16384, 25024] are 3.3 GB of
+    # the temporaries
+    cell_step=(4, 2, 8.75),
 )
 
 

@@ -146,6 +146,10 @@ FAMILY = Family(
         "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
         "train_layer_stack_pct", "train_mla_proj_pct"}),
     own_metrics=("train_mfu_mla_moe_pct", "train_moe_pct", "train_moe_gmm_mxu_pct", "train_moe_imbalance"),
+    # the cell's whole step (`Family.cell_step`): the scan over four layers has one body; the MTP module. PR 43: 6.045 ->
+    # 6.539 GB, remat `full` keeps the forward flash kernel's `out` [1, 20, 8192, 256] bfloat16 (84 MB) and logsumexp
+    # (0.66 MB) of six blocks, 0.51 GB, and runs the kernel 3 times a step where it ran 6
+    cell_step=(2, 0, 6.54),
 )
 
 

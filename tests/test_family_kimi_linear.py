@@ -209,10 +209,24 @@ FAMILY = Family(
         "train_kda_scan_roofline_pct", "train_attn_proj_pct", "train_attn_core_pct", "train_mla_proj_pct",
         "train_mfu_kda_mla_moe_pct", "train_attn_mla_roofline_pct", "train_mlp_pct"}),
     own_metrics=("train_mfu_kda_mla_moe_pct", "train_attn_mla_roofline_pct", "train_mlp_pct"),
+    # the cell's whole step (`Family.cell_step`). PR 54: [1, 8192]: four delta-rule parts at all 32 heads of 128 (their
+    # q | k | v [1, 8192, 12288] kept: 0.2 GB a part), one latent attention part WITHOUT a q latent whose kernels run q/k
+    # 192 | v 128 (the kernels under their own names: no XLA fallback), a dense part, four expert parts at 8 of 256 (the
+    # pick a slot at a time) beside a shared expert; arguments 7.23 GB; 12.86 of 15.75 GB in all
+    cell_step=(4, 2, 5.64),
 )
 
 
 # ------------------------------------------------------------------- the family's own
+
+_the_contracts_whole_step = test_a_family_cells_step_scores_once_a_layer_and_fits_as_before  # noqa: F821
+
+
+@pytest.mark.slow  # (the largest whole step, ~2.5 min of TPU compile: `-m slow -k cells_step`; tier-1 holds the cell's kernels, names
+# and scoped memory in tests/test_tpu_compile.py -k 192_beside. Back into tier-1 when its clock has the room: ROADMAP.md C13)
+def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(family, cell_step):  # noqa: F811
+    _the_contracts_whole_step(family, cell_step)
+
 
 def test_the_stacks_the_direct_q_and_the_published_pattern():
     assert llama._layer_kinds(CFG) == {"kda_layers": (4, "kda", None), "mlp_layers": (1, None, "dense"),

@@ -205,6 +205,14 @@ FAMILY = Family(
         "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
         "train_attn_passes_pct"}),
     own_metrics=("train_sconv_pct", "train_sconv_roofline_pct", "train_attn_w64_roofline_pct", "train_mfu_sconv_moe_pct"),
+    # the cell's whole step (`Family.cell_step`). PR 42: four expert parts at 4 of 64 over 32,768 tokens (the pick a slot
+    # at a time: 8.4 M mask elements), no shared expert, beside four gated short convolutions, a dense part and attention
+    # at head width 64 on padded lanes; arguments 5.63 GB (16 B a parameter less the gradient). PR 43: 5.193 -> 5.568 GB,
+    # the one attention part's `out` on its padded lanes [4, 32, 8192, 128] bfloat16 (268 MB) and logsumexp (4 MB) kept,
+    # 0.27 GB, and 0.10 GB of the compiler's placing. PR 48 (PR 47's compile): 5.568 -> 6.673 GB, `[B | C | x]` of four
+    # conv parts kept from forward to backward, [4, 8192, 3, 2048] bfloat16 = 403 MB a part, 4 x 403 MB = 1.61 GB, of which
+    # the compiler places 0.51 GB where the backward pass's float32 intermediates lay before: 1.105 GB more
+    cell_step=(4, 2, 6.68),
 )
 
 

@@ -205,4 +205,7 @@ FAMILY = Family(
         # loop's own is next to nothing where one period runs unrolled: not listed)
         "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct"}),
     own_metrics=("train_ssm_pct", "train_ssm_scan_roofline_pct", "train_mfu_ssm_moe_pct"),
+    # the cell's whole step (`Family.cell_step`): a period of layers, unrolled: a body each. PR 48: 3.866 -> 4.058 GB,
+    # `[z | xBC | dt]` of five Mamba-2 parts kept from forward to backward, [1, 8192, 2320] bfloat16 = 38 MB a part, 0.19 GB
+    cell_step=(5, 2, 4.06),
 )

@@ -3,8 +3,8 @@ weights: the block every layer is (rotated attention with as many key/value head
 then the dense SwiGLU MLP, each part between a norm on its input and one on its output) run
 `loop_steps` times over the SAME weights, a head and an exit gate behind every recurrence, under
 the expected-exit loss with its entropy term. The contract is tests/family_contract.py's; here is
-what the family alone has. (The whole step at the published widths, compiled for the chip:
-tests/test_tpu_compile.py, `-m slow -k ouro`.)"""
+what the family alone has, the cell's whole step at the published widths compiled for the chip among it
+(`-m slow -k looped_step`, ~2 min)."""
 import dataclasses
 import re
 
@@ -15,9 +15,12 @@ import pytest
 
 from family_contract import *  # noqa: F401,F403  (the contract's tests, bound to FAMILY)
 from family_contract import Family, params, seeded, system, tokens
+from compiled_step_text import kernel_calls, lower_cell_step, xla_remats
 from ray_tpu.models import get_config, llama
 from ray_tpu.models.config import ModelConfig
 from ray_tpu.models.reference import ouro as ref
+
+del test_a_family_cells_step_scores_once_a_layer_and_fits_as_before  # noqa: F821  (this cell's whole step is a shape of its own: below)
 
 CFG = get_config("ouro-tiny")
 T, L = CFG.loop_steps, CFG.n_layers
@@ -178,7 +181,7 @@ def test_the_leaves_the_gates_key_and_what_a_looped_configuration_refuses():
 def test_four_recurrences_are_an_unrolled_model_of_four_copies_and_a_shared_leafs_gradient_their_sum(monkeypatch):
     """T recurrences over L layers are T x L layers that hold T copies of the weights, the final norm and a
     head behind every L-th: the same loss, and the gradient of a shared leaf is the sum of its copies'."""
-    p, t = _own_params(), tokens(CFG, (2, 33))
+    p, t = _own_params(), tokens(CFG)  # (the contract's shape: `system` is compiled once for it)
     (loss, _), grads = system(p, t, CFG)
     copies = {**p, "layers": jax.tree.map(lambda a: jnp.tile(a, (T,) + (1,) * (a.ndim - 1)), p["layers"])}
     real, calls = llama._stacked_layers, iter(range(T))
@@ -210,7 +213,7 @@ def test_p_sums_to_one_and_the_loss_is_the_formula_of_the_gates_and_ce_by_step()
     assert np.isfinite(np.asarray(far * log_far)).all() and float(far[0, 0]) == 1.0 and float(far[1:].sum()) == 0.0
     # a gate that does not look at the stream leaves everywhere alike: the loss is the formula over the means
     blind = {**_own_params(), "exit_gate": jnp.zeros((CFG.d_model + 1,), jnp.float32).at[-1].set(0.3)}
-    (loss, m), grads = system(blind, tokens(CFG, (2, 33)), CFG)
+    (loss, m), grads = system(blind, tokens(CFG), CFG)
     gate = float(jax.nn.sigmoid(0.3))
     want = np.asarray([gate * (1 - gate) ** i for i in range(T - 1)] + [(1 - gate) ** (T - 1)])
     entropy = -(want * np.log(want)).sum()
@@ -226,7 +229,7 @@ def test_a_gate_that_always_leaves_behind_the_first_recurrence_makes_the_plain_d
     """One recurrence is the plain dense model: the program is the parent's. And the loop reduces to it: with
     p_1 = 1 exactly the loss is the first recurrence's cross entropy, whose layers are the plain model's."""
     once = dataclasses.replace(CFG, loop_steps=1, exit_entropy_weight=0.0)
-    p, t = _own_params(), tokens(CFG, (2, 33))
+    p, t = _own_params(), tokens(CFG)  # (the contract's shape: `system` is compiled once for it)
     plain = {k: v for k, v in p.items() if k != "exit_gate"}
     (loss, m), grads = system(plain, t, once)
     assert set(m) == {"loss", "ce_loss", "tokens", "moe_aux_loss"} and float(m["moe_aux_loss"]) == 0.0
@@ -269,3 +272,32 @@ def test_the_compiled_gradient_names_the_loops_scopes_forward_backward_and_remat
     again = {n for n in names if "transpose(jvp(model))" in n and ("checkpoint" in n or "remat" in n)}
     assert any("/lm_head/" in n for n in again) and any("/loss/" in n for n in again)
     assert any(f"/{llama.LOOP_STEP}/{llama.LAYER_LOOP}/" in n for n in again)  # a layer application's, inside both loops' scopes
+
+
+@pytest.mark.slow  # (two whole steps compiled, ~2 min: `-m slow -k looped_step`; tier-1 holds the family at a small size. ROADMAP.md C13)
+def test_the_ouro_cells_looped_step_compiles_inside_its_memory_at_five_layers_and_not_at_six(cell_step, one_chip, on_tpu):
+    """The whole step of `ouro26b-train-loop4-s8192` as its configuration file states it (five layers run
+    four times over shared weights, four heads with their exit gates, the expected-exit loss), compiled for
+    the described chip. PR 58: arguments 5.500 GB (12 B a parameter, each counted ONCE) + temporaries 9.512 GB
+    = 15.01 of 15.75 GB; six layers are 6.116 + 10.148 = 16.26 GB and do not fit (the configuration's `cut`
+    says what a layer costs). A forward flash kernel a layer-stack loop (four: one a recurrence, none made
+    again: `out` and the logsumexp are kept by name, T x L of them) and ONE backward kernel a loop; XLA
+    rematerialises nothing of its own; the loop's three scopes stand in the `op_name`s, forward and backward,
+    and a recurrence's rematerialised head and loss keep theirs."""
+    cfg, trainer, text, memory = cell_step.cfg, cell_step.trainer, cell_step.text, cell_step.memory
+    assert (cfg.loop_steps, cfg.n_layers, cfg.remat_policy, trainer["mesh"]) == (4, 5, "full", None)
+    assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7  # a shared leaf is held once
+    assert memory.temp_size_in_bytes < (9.52 + 0.15) * 1e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
+    assert not xla_remats(text) and cell_step.fallbacks == 0
+    assert kernel_calls(text, "flash_attention_fwd") == (cfg.loop_steps, 0)
+    assert kernel_calls(text, "flash_attention_bwd_dkv_dq") == (cfg.loop_steps, 0)
+    assert kernel_calls(text, "flash_attention_bwd_dq") == kernel_calls(text, "flash_attention_bwd_dkv") == (0, 0)
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in (llama.LOOP_STEP, llama.EXIT_GATE, llama.EXIT_LOSS):
+        assert any(f"/jvp(model)/{scope}" in n for n in names) and any(f"/transpose(jvp(model))/{scope}" in n for n in names), scope
+    again = {n for n in names if "transpose(jvp(model))" in n and "rematted_computation" in n}
+    assert any("/lm_head/" in n for n in again) and any("/loss/" in n for n in again)
+    assert any(f"/{llama.LOOP_STEP}/{llama.LAYER_LOOP}/" in n for n in again)
+    deeper = lower_cell_step(dataclasses.replace(cfg, n_layers=6), trainer, one_chip)[1].compile().memory_analysis()
+    assert deeper.argument_size_in_bytes + deeper.temp_size_in_bytes > 15.75e9  # the greatest depth that is placed is five

@@ -5,10 +5,11 @@ and the block-diffusion objective: every sequence through the layers twice, nois
 clean, as one row under the block-diffusion mask, the loss over the masked positions at the
 token's own position. The contract is tests/family_contract.py's, on the batch this family
 makes (`Family.batch_of`); here is what the family alone has. (The mask's kernels against the
-plain softmax: tests/test_flash_attention.py; compiled for the chip: tests/test_tpu_compile.py.)"""
+plain softmax: tests/test_flash_attention.py; the cell's whole step compiled for the chip: the last test here.)"""
 import dataclasses
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +18,12 @@ import pytest
 
 from family_contract import *  # noqa: F401,F403  (the contract's tests, bound to FAMILY)
 from family_contract import ROOT, Family, expert_shares, model_of, params, published_keys, system
+from compiled_step_text import instructions, kernel_calls, pallas_grids, xla_remats
 from ray_tpu.models import get_config, llama, moe
 from ray_tpu.models.reference import sdar_moe as ref
 from ray_tpu.train import block_diffusion_noise
+
+del test_a_family_cells_step_scores_once_a_layer_and_fits_as_before  # noqa: F821  (this cell's whole step is a shape of its own: below)
 
 CFG = get_config("sdar-tiny")
 
@@ -397,3 +401,41 @@ def test_the_drivers_placement_deals_the_mask_tokens_experts_round_the_shares():
         assert all(a is b for a, b in zip(jax.tree.leaves({k: v for k, v in placed.items() if k != "layers"}),
                                           jax.tree.leaves({k: v for k, v in p.items() if k != "layers"})))
     assert len({tuple(place_hot_experts(llama.init(jax.random.PRNGKey(s), cfg), cfg)[1]) for s in range(4)}) > 1  # the lottery it removes
+
+
+def test_the_block_diffusion_cells_step_compiles_inside_its_memory_and_walks_288_tiles_a_head(cell_step, on_tpu):
+    """The whole step of `sdar30b-train-ep8share-s8192` as its configuration file states it (five
+    layers; the batch a loader makes: tokens, masked, p_mask), compiled for the described chip:
+    [1, 16384] rows through the layers under the block-diffusion mask. The two kernels (forward;
+    the ONE backward kernel, PR 53) carry `_bd` behind their names, which the accepted kernel metrics and the cell's own roofline
+    metric find; the forward kernel runs once a layer (its `out` and logsumexp kept under
+    `full`); three router products a layer and the pick's loops, nothing made again by XLA, no
+    attention on an XLA path; 15.18 of 15.75 GB (PR 50: six layers were 17.31 and do not fit).
+    The grids: a step a q tile forward and backward (K/V of the doubled row are one span, and so
+    dK and dV of a kv head stay in VMEM); 288 tiles a head each way by `tile_counts`."""
+    from ray_tpu.ops import flash_attention as fa
+
+    cfg, trainer, text, memory = cell_step.cfg, cell_step.trainer, cell_step.text, cell_step.memory
+    b, n = trainer["batch"], trainer["seq"]
+    assert cfg.remat and cfg.remat_policy == "full" and (cfg.diffusion_block, cfg.n_layers, b, n) == (4, 5, 1, 8192)
+    assert set(cell_step.args[1]) == {"tokens", "masked", "p_mask"} and cell_step.args[1]["tokens"].shape == (b, n)
+    for path, count in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 1), ("train_attn_bd_roofline_pct", 2)):
+        with open(os.path.join(ROOT, "benchmarks", "metrics", f"{path}.json")) as f:
+            rx = re.compile(json.load(f)["args"]["pattern"])
+        kernels = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]
+        assert len(kernels) == count and all("_bd" in ln.split(" = ")[0] for ln in kernels), (path, kernels)
+    assert kernel_calls(text, "flash_attention_fwd_bd") == kernel_calls(text, "flash_attention_bwd_dkv_dq_bd") == (1, 0)
+    assert kernel_calls(text, "flash_attention_bwd_dq_bd") == kernel_calls(text, "flash_attention_bwd_dkv_bd") == (0, 0)
+    assert kernel_calls(text, "flash_attention_fwd") == (0, 0)
+    assert len(instructions(text, "convolution", "moe_router")) == 3 and len(instructions(text, "while", "moe_router")) == 2
+    assert not xla_remats(text) and cell_step.fallbacks == 0
+    assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7 and cfg.n_params == 550984960
+    assert memory.temp_size_in_bytes < (8.57 + 0.15) * 1e9
+    assert 0.25 * 15.75e9 < memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
+    t = fa._tiling(2 * n, 2 * n, 512, 512, 128, 2, 8)
+    assert (t.kv_span, t.q_span) == (16384, 1024)
+    grids = pallas_grids(jax.make_jaxpr(cell_step.step._jitted)(*cell_step.args).jaxpr)
+    assert fa._fuses(t, 2 * n) and grids.count((b, 32, 32, 1)) == 2 and (b, 4, 32, 16) not in grids  # forward; backward (PR 53)
+    for kernel, heads in (("fwd", 1), ("dq", 1), ("dkv", 8)):  # ("dq": the one backward kernel's walk too)
+        counts = fa.tile_counts(2 * n, 2 * n, False, 512, 512, n_rep=heads, kernel=kernel, block_diffusion=4)
+        assert counts.tiles_computed == heads * 288 and round(counts.tiles_needed / heads, 1) == 256.1

@@ -193,6 +193,14 @@ FAMILY = Family(
         "train_attn_proj_pct", "train_attn_core_pct", "train_moe_dispatch_pct", "train_moe_combine_pct",
         "train_attn_passes_pct"}),
     own_metrics=("train_kda_pct", "train_kda_scan_roofline_pct", "train_mfu_kda_moe_pct", "train_kda_conv_pct"),
+    # the cell's whole step (`Family.cell_step`). PR 37: four expert parts at 8 of 320 (the pick a slot at a time, as at
+    # 22 of 512), three delta-rule scans whose triangular systems are inverted once each and kept. PR 44: 4.650 ->
+    # 4.644 GB, the float32 `[1, 8195, 3072]` padded copies and the taps' products gone. PR 48: 4.644 -> 4.794 GB,
+    # q | k | v before the convolution of three delta-rule parts kept, [1, 8192, 3072] bfloat16 = 50 MB a part, 0.15 GB.
+    # PR 51: 4.7938 -> 4.7907 GB, 3 MB less: the scan's second half in kernels (its [.., 128, 256] right-hand sides and
+    # solutions and the [chunks, B, H, Q, K] copies of q, k, v gone) is not where the step's temporaries peak, so `kk`
+    # and `b` (0.2 GB: 4.996) stay unnamed
+    cell_step=(4, 2, 4.80),
 )
 
 

@@ -8,7 +8,7 @@ import pytest
 
 from ray_tpu.ops import flash_attention as fa
 from ray_tpu.ops.attention import attention_reference
-from test_flash_attention import _flash_names, _kernel_names, _packed, _rand
+from flash_cases import flash_names, kernel_names, packed_segments, rand
 
 # a walk: (what the call is given, its kernels' suffix); the row is 256 positions in 64-row tiles
 # (under the block-diffusion mask [noised ; clean] of 128 each), the window not a whole tile
@@ -29,7 +29,7 @@ def _grads(fn, q, k, v, g, **kw):
 def _inputs(group, width, dtype=jnp.float32):
     """q, k, v and the output's cotangent; `width` a number, or (q's and k's, v's and the output's)."""
     qk, v = width if isinstance(width, tuple) else (width, width)
-    return (_rand((1, S, heads, w), i, dtype) for i, (heads, w) in enumerate(((group, qk), (1, qk), (1, v), (group, v))))
+    return (rand((1, S, heads, w), i, dtype) for i, (heads, w) in enumerate(((group, qk), (1, qk), (1, v), (group, v))))
 
 
 # (the block-diffusion mask takes one document a row, `_block_diffusion`)
@@ -55,10 +55,10 @@ def test_the_one_backward_kernel_matches_the_reference(walk, group, packed, widt
     256 lanes beside v 128 wide (dq and dk come back 192 wide, dv 128: the reference's shapes)."""
     kw, suffix = WALKS[walk]
     q, k, v, g = _inputs(group, width)
-    kw = dict(kw, segment_ids=_packed(1, S, (70, 150, 201)) if packed else None)
+    kw = dict(kw, segment_ids=packed_segments(1, S, (70, 150, 201)) if packed else None)
     flash = dict(kw, block_q=TILE, block_kv=TILE)
-    names = _kernel_names(jax.make_jaxpr(lambda q: _grads(fa.flash_attention, q, k, v, g, **flash))(q).jaxpr)
-    assert sorted(names) == _flash_names(suffix), names
+    names = kernel_names(jax.make_jaxpr(lambda q: _grads(fa.flash_attention, q, k, v, g, **flash))(q).jaxpr)
+    assert sorted(names) == flash_names(suffix), names
     got = _grads(fa.flash_attention, q, k, v, g, **flash)
     want = _grads(attention_reference, q, k, v, g, **kw)
     for name, a, ref in zip(("dq", "dk", "dv"), got, want):
@@ -76,13 +76,13 @@ def test_the_two_kernels_run_where_k_and_v_are_not_one_span_and_agree_with_the_o
     the same dq, dk and dv from the same products in another order of the sums."""
     kw, suffix = WALKS[walk]
     q, k, v, g = _inputs(4, 128, dtype)
-    kw = dict(kw, block_q=TILE, block_kv=TILE, segment_ids=None if walk == "bd" else _packed(1, S, (90, 130)))
+    kw = dict(kw, block_q=TILE, block_kv=TILE, segment_ids=None if walk == "bd" else packed_segments(1, S, (90, 130)))
 
     def run(one_backward):
         t = fa._tiling(S, S, TILE, TILE, 128, jnp.dtype(dtype).itemsize, 4)
         assert fa._fuses(t, S) == one_backward and (t.kv_span == S) == one_backward, t
-        names = _kernel_names(jax.make_jaxpr(lambda q: _grads(fa.flash_attention, q, k, v, g, **kw))(q).jaxpr)
-        assert sorted(names) == _flash_names(suffix, one_backward), names
+        names = kernel_names(jax.make_jaxpr(lambda q: _grads(fa.flash_attention, q, k, v, g, **kw))(q).jaxpr)
+        assert sorted(names) == flash_names(suffix, one_backward), names
         return _grads(fa.flash_attention, q, k, v, g, **kw)
 
     one = run(True)

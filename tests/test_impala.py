@@ -50,6 +50,9 @@ def test_vtrace_matches_one_step_td():
     cfg = IMPALAConfig().environment("CartPole-v1")
     learner = IMPALALearner(cfg, spec)
     learner.build()
+    # (a seeded draw: of unseeded ones 1 in ~850 lands where r + gamma*V(next) - V(s) is under 3e-3 and its
+    # square cannot be held to an rtol of 1e-4 in float32, whatever compiles the loss)
+    np.random.seed(0)
     ep = _fake_episode(1, terminated=False)
     # make the behaviour logp exactly on-policy so rho = c = 1
     out = learner.module.apply_np(

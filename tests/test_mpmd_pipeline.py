@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from compiled_step_text import optimised  # noqa: F401  (a fixture)
 from ray_tpu.train.mpmd_pipeline import (
     MPMDPipelineConfig,
     build_1f1b_schedule,
@@ -21,6 +22,10 @@ from ray_tpu.train.mpmd_pipeline import (
     validate_schedule,
     warmup_len,
 )
+
+# the cross-process runner's stages compile in worker processes, which tests/conftest.py does not reach, and are held
+# to this process's SPMD program to the bit: both sides under XLA's optimiser, so this file keeps it
+pytestmark = pytest.mark.usefixtures("optimised")
 
 
 # ------------------------------------------------------------- schedule core

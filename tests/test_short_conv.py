@@ -9,11 +9,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from compiled_step_text import optimised  # noqa: F401  (a fixture)
 from family_contract import model_of
 from ray_tpu.models import get_config, kda, llama, sconv
 from ray_tpu.models.reference import lfm2_moe as lfm2_ref
 from ray_tpu.models.ssm import _causal_conv
 from ray_tpu.ops import short_conv
+
+# the kernels' results are held to the plain form's TO THE BIT, both sides one XLA program on the CPU: the same
+# float32 arithmetic in the same order only where XLA contracts and fuses both alike, so this file keeps the optimiser
+pytestmark = pytest.mark.usefixtures("optimised")
 
 QKV = (128**-0.5, 1.0, None)  # the delta-rule mixer's parts: q normed and scaled, k normed, v not
 

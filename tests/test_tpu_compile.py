@@ -1,14 +1,16 @@
-"""The main path's kernels and serving programs compile for a TPU v5e.
+"""The main path's kernels and whole programs that belong to no family's cell compile for a TPU v5e:
+the flash and rotate kernels at the cells' shapes, the Mistral step on four chips, the serving programs.
 
 Nothing runs: the TPU compiler is installed here and compiles for a chip that
 is described, not attached. What it refuses here (a slice not aligned to the
 tiling, too much fast memory, a program that does not fit 16 GB) it would
 refuse on the chip, so these cases guard every later PR at no chip time.
 
-The topology is described inside the module-scoped `topo` fixture and nowhere
-else: only one process may load the TPU's library, and xdist workers all
-import this file. Keep every such test in THIS file, and compile in the test's
-own process (no children).
+A cell's parts at the cell's shape are tests/test_tpu_compile_parts.py's (mixers, the attention part) and
+tests/test_tpu_compile_experts.py's (the expert layer); a family cell's WHOLE step is its family's
+(`Family.cell_step`, tests/family_contract.py; tests/test_family_<model_type>.py), compiled once in the
+family's file. The fixtures and the readers of a compiled program's text are tests/compiled_step_text.py's;
+compile in the test's own process (no children).
 """
 import dataclasses
 import json
@@ -18,51 +20,9 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
 
-
-@pytest.fixture(scope="module")
-def topo():
-    import os
-
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
-    from jax.experimental import topologies
-
-    try:
-        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 — no TPU compiler in this installation
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-
-
-@pytest.fixture(scope="module")
-def one_chip(topo):
-    return SingleDeviceSharding(topo.devices[0])
-
-
-@pytest.fixture
-def on_tpu(monkeypatch):
-    """Steer the code's own backend probes to their TPU side: they ask the
-    attached backend, which here is the CPU."""
-    from ray_tpu.ops import flash_attention as fa
-
-    monkeypatch.setattr(fa, "_interpret", lambda: False)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-
-
-def _shapes(tree, sharding):
-    return jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree)
-
-
-def _pallas_grids(jaxpr):
-    """The grid of every Pallas kernel in a program, nested calls included."""
-    grids = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            grids.append(eqn.params["grid_mapping"].grid)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            grids += _pallas_grids(sub)
-    return grids
+from compiled_step_text import (  # noqa: F401  (`on_tpu`, `one_chip`, `topo`: this module's fixtures)
+    on_tpu, one_chip, pallas_grids, scalar, shapes, topo)
 
 
 @pytest.mark.parametrize("b,s,h,kv,segments", [
@@ -95,7 +55,7 @@ def test_flash_attention_compiles(one_chip, on_tpu, b, s, h, kv, segments):
     assert bwd.as_text().count("tpu_custom_call") == (2 if one_backward else 3)
     # a grid step owns a span of K/V and walks its 512-wide tiles in the kernel: the
     # forward program's grid is the short one, not a step a (q tile, kv tile)
-    grid, = _pallas_grids(jax.make_jaxpr(
+    grid, = pallas_grids(jax.make_jaxpr(
         lambda q, k, v: flash_attention(q, k, v, causal=True))(q, k, k).jaxpr)
     tiles = -(-s // 512)
     steps = tile_counts(s, s, True, 512, 512).grid_steps
@@ -161,7 +121,7 @@ def test_windowed_flash_attention_compiles_at_the_cells_shape_and_visits_the_ban
     n = s // tile
     q_spans = fa._q_spans(s, s, t, window)
     assert (fa._kv_spans(s, s, t, window), q_spans, s // t.q_span) == (1, 3, 16)
-    grids = _pallas_grids(jax.make_jaxpr(grad)(q, k, k, pos).jaxpr)
+    grids = pallas_grids(jax.make_jaxpr(grad)(q, k, k, pos).jaxpr)
     assert fa._fuses(t, s) and grids.count((b, h, n, 1)) == 2 and not any(g[1] == kv for g in grids if len(g) == 4)
     # the band, by brute force over the tiles: tile (qi, kj) holds a kept score; the share of each computed
     first, last = np.arange(n) * tile, np.arange(n) * tile + tile - 1
@@ -262,7 +222,7 @@ def test_flash_attention_compiles_at_width_256(one_chip, on_tpu):
     assert len(calls) == 2
     for name in ("flash_attention_fwd", "flash_attention_bwd_dkv_dq"):
         assert sum(name in c for c in calls) == 1, (name, calls)
-    grids = _pallas_grids(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr)
+    grids = pallas_grids(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q).jaxpr)
     steps = tile_counts(s, s, True, 512, 512, head_dim=d).grid_steps
     assert grids == [(b, h, 16, steps // 16)] * 2, grids  # one kv span, forward and backward
 
@@ -305,496 +265,6 @@ def test_flash_attention_compiles_at_q_k_192_beside_v_128(one_chip, on_tpu):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 1.0e9  # the kernels keep no scores
 
 
-def _glm_share():
-    from ray_tpu.models.config import ModelConfig
-
-    return ModelConfig(
-        name="glm-shape", vocab_size=19360, d_model=2048, n_layers=5, n_heads=20, n_kv_heads=20,
-        d_ff=10240, n_experts=64, moe_top_k=4, moe_capacity_factor=0.0, d_ff_expert=1536,
-        n_shared_experts=1, moe_scoring="sigmoid", moe_route_scale=1.8, moe_select_bias=True,
-        experts_held=(0, 8))
-
-
-_LAYER_TEXTS = {}
-
-
-def _expert_layer_text(cfg, one_chip, remat=False):
-    """The compiled text of an expert layer's value and every gradient at 8,192 tokens
-    (`remat`: rematerialised under the configuration's policy, as a model's layer is),
-    made once a configuration (under `on_tpu`, which every caller has)."""
-    from ray_tpu.models import llama, moe
-
-    if (cfg.name, remat) not in _LAYER_TEXTS:
-        lp = _shapes(jax.eval_shape(lambda: moe.init_expert_weights(jax.random.PRNGKey(0), cfg)),
-                     one_chip)
-        x = jax.ShapeDtypeStruct((8192, cfg.d_model), jnp.bfloat16, sharding=one_chip)
-
-        def layer(x, lp):
-            return moe.expert_layer(x, lp, cfg)[0]
-
-        def loss(x, lp, cot):
-            with jax.named_scope("model"):  # as train/step.py: the first name inside `grad` is written jvp(..)
-                y = (llama._maybe_remat(layer, cfg) if remat else layer)(x, lp)
-                return jnp.sum((y * cot).astype(jnp.float32))
-
-        _LAYER_TEXTS[cfg.name, remat] = (
-            set(lp), jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp, x).compile().as_text())
-    return _LAYER_TEXTS[cfg.name, remat]
-
-
-def _grouped_kernels(text):
-    return [ln for ln in text.splitlines() if re.match(r"\s*%ragged-dot-none[\w.]* = ", ln)]
-
-
-def test_expert_layer_compiles_to_the_grouped_kernels(one_chip, on_tpu):
-    """The dropless expert layer at the cell's shape (8,192 tokens x 4 assignments, 8
-    held experts of 2048 x 1536, a window of 8,192 rows): its grouped products are the
-    TPU compiler's own ragged-dot kernels, not a dense product an expert over the whole
-    buffer. 21 of them: a window is 3 products forward and 9 in its backward pass (the
-    3 again, since only the walk's inputs are kept, and 2 transposes each), and the
-    window's body is in the program twice, for the first window (9: XLA shares its
-    forward products with the backward's, no rematerialisation standing between them
-    here) and in the loops that only an overflowing step enters (3 + 9); and since PR 34
-    the combine's own, 3 a body. No scatter in either direction, and nothing of tokens x
-    k rows by either width is left."""
-    from ray_tpu.models import moe
-
-    cfg = _glm_share()
-    assert moe.window_rows(cfg, 8192) == 8192
-    _, text = _expert_layer_text(cfg, one_chip)
-    kernels = _grouped_kernels(text)
-    assert len(kernels) == 21 + 6, len(kernels)
-    assert sum("bf16[8192," in ln.split(" custom-call(")[0] for ln in kernels) == 15
-    assert not re.search(r" scatter\(", text)
-    full = [ln.strip()[:160] for ln in text.splitlines()
-            if re.search(r"\[32768,(1536|2048)\]", ln) and re.search(r'op_name="[^"]*moe_', ln)]
-    assert not full, full[:4]
-    assert not re.search(r"\[32768,(1536|2048)\]", text)  # nor anywhere else in the layer
-
-
-def _nemotron_share():
-    from ray_tpu.models.config import ModelConfig
-
-    return ModelConfig(
-        name="nemotron-shape", vocab_size=16384, d_model=4096, n_layers=11, n_heads=32, n_kv_heads=2,
-        d_ff=2688, layer_pattern="MEMEMEM*EME", ssm_n_heads=16, ssm_head_dim=64, ssm_n_groups=1,
-        ssm_state=128, ssm_chunk=128, attn_heads_held=(4, 1), attention_rotation=False, n_experts=512,
-        moe_top_k=22, moe_capacity_factor=0.0, d_ff_expert=2688, n_shared_experts=1, d_ff_shared=5376,
-        moe_latent_dim=1024, mlp_activation="relu2", moe_scoring="sigmoid", moe_route_scale=5.0,
-        moe_select_bias=True, experts_held=(0, 64))
-
-
-def _cell_file(config):
-    """(ModelConfig, file) of a family cell's configuration as its file states it."""
-    import sys
-
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from benchmarks.lib import modelcfg
-
-    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", f"{config}.json")) as f:
-        file = json.load(f)
-    return modelcfg.model_config(modelcfg.model_keys(file)), file
-
-
-def test_latent_expert_layer_at_22_of_512_compiles_without_a_tokens_by_k_by_experts_operand(one_chip, on_tpu):
-    """The expert layer of the Nemotron-3-Super cell (8,192 tokens x 22 assignments over a
-    router of 512, 8 experts of 1024 x 2688 held in a latent, a window of 5,632 rows): two
-    grouped products an expert MLP (`ragged-dot-none`: 2 forward and 6 in the backward of a
-    window, the window's body in the program twice: 14; and since PR 34 the combine's own,
-    3 a body: the window's rows summed onto their tokens forward, for dx, and the gates'
-    gradient), no scatter, and no operand with the extents of tokens, k and experts
-    together: a mask `[8192, 22, 512]` is 92 M elements a layer, forward and again in the
-    backward pass."""
-    from ray_tpu.models import moe
-
-    cfg = _nemotron_share()
-    assert moe.window_rows(cfg, 8192) == 5632
-    names, text = _expert_layer_text(cfg, one_chip)
-    assert names == {"router", "router_bias", "w_up", "w_down", "shared_up", "shared_down",
-                     "latent_down", "latent_up"}
-    kernels = _grouped_kernels(text)
-    assert len(kernels) == 14 + 6, len(kernels)
-    assert not re.search(r" scatter\(", text)
-    assert not re.search(r"\[(8192,22,512|22,8192,512|8192,512,22|180224,512)\]", text)
-    assert not re.search(r"\[180224,(1024|2688|4096)\]", text)  # nor tokens x k rows of any width
-
-
-def _gathers_under(text, scope):
-    """Result shapes of the gather instructions (fused or not) traced under `scope`."""
-    return [m.group(1) for ln in text.splitlines()
-            if (m := re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\S+) gather\(", ln))
-            and re.search(rf'op_name="[^"]*/{scope}/', ln)]
-
-
-@pytest.mark.parametrize("cell,ratio,sums", [
-    ("nemotron", 32, ["f32[16,512,1024]"] * 4 + ["f32[3,512,128]"] * 2),
-    ("glm", 4, ["f32[1,512,128]"] * 2 + ["f32[16,512,2048]"] * 4)])
-def test_the_combine_follows_the_windows_rows_in_both_cells(one_chip, on_tpu, cell, ratio, sums):
-    """`moe.combine_from_rows` at the two cells' shapes, and the program it makes (PERF.md
-    sections 3 and 6, PR 34): Nemotron-3-Super sums 22 x 8,192 assignments over a window
-    of 5,632 rows (32 to 1), GLM-4.7-Flash 4 x 8,192 over 8,192 (4 to 1), both from the
-    window's side: no gather under `moe_combine` has a row a token or an assignment (the
-    sorted rows and the gates are gathered, a window's rows each), the sums are grouped
-    products by tile of 512 tokens (`[16, 512, width]`, forward and for dx in each body;
-    the gates' scalars 128 to a row), no scatter, no operand of tokens x k rows. A layer
-    that holds a quarter of its experts or more keeps a gather a slot."""
-    from ray_tpu.models import moe
-
-    cfg = {"nemotron": _nemotron_share, "glm": _glm_share}[cell]()
-    k, rows = cfg.moe_top_k, moe.window_rows(cfg, 8192)
-    assert 8192 * k == ratio * rows
-    assert moe.combine_from_rows(8192, k, rows) and moe.combine_from_rows(8192 * k, 1, rows)
-    for held in ((0, 4), (0, 2), (0, 1)):  # the same layer with a quarter, half or all of its experts
-        assert not moe.combine_from_rows(
-            8192, k, moe.window_rows(dataclasses.replace(cfg, experts_held=held), 8192))
-    _, text = _expert_layer_text(cfg, one_chip)
-    combined = [ln.split(" custom-call(")[0].split(" = ")[1].split("{")[0] for ln in _grouped_kernels(text)
-                if re.search(r"= f32\[\d+,512,\d+\]", ln)]
-    assert sorted(combined) == sums
-    # one gather a sum, the window's rows into the tokens' order (a gather a slot: k a sum)
-    gathered = [s.split("{")[0] for s in _gathers_under(text, "moe_combine")]
-    assert gathered.count(f"bf16[{rows},{cfg.moe_latent_dim or cfg.d_model}]") == 4, gathered
-    if rows != 8192:  # nor has any a row a token or an assignment
-        assert not [s for s in gathered if re.match(rf"\w+\[({8192 * k}|8192)[,\]]", s)], gathered
-    assert not re.search(r" scatter\(", text)
-    assert not re.search(rf"\[{8192 * k},\d+\]", text)
-
-
-def _xla_remats(text):
-    """The instructions XLA made again by itself to fit the program (`.remat` in their names)."""
-    return re.findall(r"^\s*(?:ROOT )?%[\w.\-]*\.remat\S*", text, re.M)
-
-
-def _instructions(text, op, scope=None):
-    """The program's instructions of kind `op`, fused or not (under `scope`, by `op_name`)."""
-    return [ln for ln in text.splitlines() if re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = .*?[\])}}] {op}\(", ln)
-            and (scope is None or re.search(rf'op_name="[^"]*/{scope}/', ln))]
-
-
-@pytest.mark.parametrize("cell,loops", [("nemotron", 2), ("glm", 0)])
-def test_a_rematerialised_expert_layer_scores_once_in_both_cells(one_chip, on_tpu, cell, loops):
-    """An expert layer of each family cell under remat `full` (as the cells run it), value
-    and every gradient at 8,192 tokens, compiled for the described chip (PERF.md section
-    6, PR 36): the router's products are three (the scores once, `[T, E]`; dx; the
-    weight's gradient), where a backward pass that scores again has four; at 22 of 512
-    the only loops under `moe_router` are the forward pick's and the count's (the pick
-    made again, its backward slot by slot into an accumulator and the count made again
-    were three more) and no operand has the extents of tokens, k and experts together
-    (4 of 64 picks by one fused mask of 2 M elements in the forward pass, as it did)."""
-    from ray_tpu.models import moe
-
-    cfg = {"nemotron": _nemotron_share, "glm": _glm_share}[cell]()
-    assert cfg.remat and cfg.remat_policy == "full"
-    _, text = _expert_layer_text(cfg, one_chip, remat=True)
-    t, k, e = 8192, cfg.moe_top_k, cfg.n_experts
-    products = _instructions(text, "convolution", "moe_router")
-    assert len(products) == 3, products
-    assert sum(f" = f32[{t},{e}]" in ln for ln in products) == 1, products
-    assert len(_instructions(text, "while", "moe_router")) == loops
-    if t * k * e > moe._MASK_ELEMENTS:
-        assert not re.search(rf"\[({t},{k},{e}|{k},{t},{e}|{t},{e},{k}|{t * k},{e})\]", text)
-    assert not re.search(r" scatter\(", text)
-
-
-@pytest.mark.parametrize("config,bodies,loops,temp_gb", [
-    # (a period of layers, unrolled: a body each). PR 48: 3.866 -> 4.058 GB, `[z | xBC | dt]` of five
-    # Mamba-2 parts kept from forward to backward, [1, 8192, 2320] bfloat16 = 38 MB a part, 0.19 GB
-    ("nemotron-3-super-train-tp8-ep64", 5, 2, 4.06),
-    # (the scan over four layers has one body; the MTP module). PR 43: 6.045 -> 6.539 GB, remat `full`
-    # keeps the forward flash kernel's `out` [1, 20, 8192, 256] bfloat16 (84 MB) and logsumexp (0.66 MB)
-    # of six blocks, 0.51 GB, and runs the kernel 3 times a step where it ran 6
-    ("glm-4.7-flash-train-ep8", 2, 0, 6.54),
-    # PR 37: four expert parts at 8 of 320 (the pick a slot at a time, as at 22 of 512), three
-    # delta-rule scans whose triangular systems are inverted once each and kept. PR 44: 4.650 ->
-    # 4.644 GB, the float32 `[1, 8195, 3072]` padded copies and the taps' products gone. PR 48: 4.644 ->
-    # 4.794 GB, q | k | v before the convolution of three delta-rule parts kept, [1, 8192, 3072] bfloat16 =
-    # 50 MB a part, 0.15 GB. PR 51: 4.7938 -> 4.7907 GB, 3 MB less: the scan's second half in kernels
-    # (its [.., 128, 256] right-hand sides and solutions and the [chunks, B, H, Q, K] copies of q, k, v
-    # gone) is not where the step's temporaries peak, so `kk` and `b` (0.2 GB: 4.996) stay unnamed
-    ("solar-open2-train-tp8-ep40", 4, 2, 4.80),
-    # PR 42: four expert parts at 4 of 64 over 32,768 tokens (the pick a slot at a time: 8.4 M mask
-    # elements), no shared expert, beside four gated short convolutions, a dense part and attention
-    # at head width 64 on padded lanes; arguments 5.63 GB (16 B a parameter less the gradient).
-    # PR 43: 5.193 -> 5.568 GB, the one attention part's `out` on its padded lanes [4, 32, 8192, 128]
-    # bfloat16 (268 MB) and logsumexp (4 MB) kept, 0.27 GB, and 0.10 GB of the compiler's placing
-    # PR 48 (PR 47's compile): 5.568 -> 6.673 GB, `[B | C | x]` of four conv parts kept from forward to
-    # backward, [4, 8192, 3, 2048] bfloat16 = 403 MB a part, 4 x 403 MB = 1.61 GB, of which the compiler
-    # places 0.51 GB where the backward pass's float32 intermediates lay before: 1.105 GB more
-    ("lfm2-24b-a2b-train-ep8", 4, 2, 6.68),
-    # PR 46: [2, 16384]: four attention parts inside a window of 2,048 (their kernels under their own
-    # names) and one full, gated, normed a head, a norm behind every part; four expert parts at 8 of 128
-    # over 32,768 tokens beside a shared expert; arguments 6.05 GB; the f32 logits [2, 16384, 25024] are
-    # 3.3 GB of the temporaries
-    ("trinity-mini-train-ep16", 4, 2, 8.75),
-    # PR 54: [1, 8192]: four delta-rule parts at all 32 heads of 128 (their q | k | v [1, 8192, 12288] kept:
-    # 0.2 GB a part), one latent attention part WITHOUT a q latent whose kernels run q/k 192 | v 128 (the
-    # kernels under their own names: no XLA fallback), a dense part, four expert parts at 8 of 256 (the
-    # pick a slot at a time) beside a shared expert; arguments 7.23 GB; 12.86 of 15.75 GB in all
-    # (marked slow: this file is tier-1's longest, one worker's from its start to the run's end, and a seventh
-    # whole step is two minutes more of it than the run's limit leaves: `-m slow -k kimi` runs it, ~2.5 min; tier-1
-    # holds the cell's kernels, names and scoped memory in `test_flash_attention_compiles_at_q_k_192_beside_v_128`)
-    pytest.param("kimi-linear-48b-a3b-train-ep32", 4, 2, 5.64, marks=pytest.mark.slow)])
-def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(one_chip, on_tpu, config, bodies,
-                                                                    loops, temp_gb):
-    """The whole step of each family cell as its configuration file states it, compiled for
-    the described chip: three router products an expert layer and the forward pass's loops
-    only (the test above, in the step); what the router keeps from forward to backward
-    (88 MB in the Nemotron cell, 10.5 MB in GLM's) leaves the temporaries within 0.15 GB of
-    what they were before it did (PR 35's programs: 3.83 and 6.01 GB), and XLA
-    rematerialises nothing of its own to fit (PERF.md section 7, after PR 26 (2))."""
-    import importlib
-
-    from ray_tpu.models import llama
-    from ray_tpu.models.config import LAYER_KINDS
-    from ray_tpu.train import make_optimizer, make_train_step
-    from ray_tpu.train.step import TrainState
-
-    attention_ops = importlib.import_module("ray_tpu.ops.attention")  # (the package re-exports the function under this name)
-    cfg, file = _cell_file(config)
-    trainer = file["trainer"]
-    assert cfg.remat and cfg.remat_policy == "full" and trainer["mesh"] is None
-    fallbacks = attention_ops.xla_fallback_count
-    tx = make_optimizer(**trainer["optimizer"])
-    params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
-    opt_state = _shapes(jax.eval_shape(tx.init, params), one_chip)
-    state = TrainState(step=_scalar(one_chip), params=params, opt_state=opt_state)
-    batch = {"tokens": jax.ShapeDtypeStruct((trainer["batch"], trainer["seq"] + 1), jnp.int32, sharding=one_chip)}
-    compiled = make_train_step(cfg, tx).lower(state, batch).compile()
-    text = compiled.as_text()
-    assert len(_instructions(text, "convolution", "moe_router")) == 3 * bodies
-    assert len(_instructions(text, "while", "moe_router")) == loops * bodies
-    assert not _xla_remats(text)
-    # under `full` a rematerialised layer keeps the forward flash kernel's results: a call an
-    # attention block forward, none made again (PR 43), ONE backward kernel a call (PR 53)
-    blocks = _kernel_calls(text, "flash_attention_bwd_dkv_dq")[0]
-    assert blocks >= 1 and _kernel_calls(text, "flash_attention_fwd") == (blocks, 0)
-    # a windowed part runs the windowed kernels, as often; no attention falls to an XLA path
-    windowed = sum(LAYER_KINDS[c].windowed for c in cfg.layer_pattern)
-    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dkv_dq"):
-        assert _kernel_calls(text, f"{kernel}_window") == (windowed, 0)
-    for suffix in ("", "_window"):  # the two kernels of a sequence longer than a span of K and V run nowhere
-        assert _kernel_calls(text, f"flash_attention_bwd_dq{suffix}") == _kernel_calls(text, f"flash_attention_bwd_dkv{suffix}") == (0, 0)
-    assert blocks + windowed == sum(LAYER_KINDS[c].mixer == "attn" for c in cfg.layer_pattern) + cfg.mtp_depth or not cfg.layer_pattern
-    assert attention_ops.xla_fallback_count == fallbacks
-    # a recurrent mixer keeps its input product's result under `full` (PR 48): the product once a part
-    # forward, none made again in the rematerialised layer, two backward; one stored copy a part,
-    # rounded in the product's own epilogue
-    period = llama.pattern_period(cfg.layer_pattern)[0] if cfg.layer_pattern else ""
-    for kind, (scope, einsum, extents) in _KEPT_PRODUCTS.items():
-        parts = period.count(kind)
-        assert _products(text, scope, einsum) == (parts, 0, 2 * parts), kind
-        assert _kept_copies(text, extents) == (parts, parts), kind
-    memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < (temp_gb + 0.15) * 1e9
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
-    if cfg.kda_n_heads:  # a delta-rule part substitutes once, a call a block: the backward pass keeps the inverse
-        from ray_tpu.ops.kda import _SOLVE
-
-        parts = cfg.layer_pattern.count("K")
-        assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == parts * (cfg.kda_chunk // _SOLVE)
-        # the overlaps' kernels a part: forward, again in the rematerialised layer, backward (PR 38)
-        assert _kernel_calls(text, "kda_overlaps_fwd") == (parts, parts)
-        assert _kernel_calls(text, "kda_overlaps_bwd") == (parts, 0)
-        assert not re.search(_OVERLAPS_INTERMEDIATES, text)
-        # the chunks' four matrices likewise (PR 51): [W | U0] and what it is made from stay in fast memory
-        assert _kernel_calls(text, "kda_parts_fwd") == (parts, parts)
-        assert _kernel_calls(text, "kda_parts_bwd") == (parts, 0)
-        assert not re.search(_PARTS_INTERMEDIATES, text)
-        # the convolution, silu and norms of q, k and v: ONE call a part and pass whatever the three
-        # (9 a step; a call for each of q, k, v was 27, and 3 s of every first step: PR 44), and the
-        # plain form's float32 copy of q|k|v padded by the taps is gone with its shifted products
-        assert _kernel_calls(text, "short_conv_fwd") == (parts, parts)
-        assert _kernel_calls(text, "short_conv_bwd") == (parts, 0)
-        assert not re.search(_CONV_PADDED_COPY, text)
-        assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
-
-
-@pytest.mark.slow  # (beside Kimi-Linear's whole step: `-m slow -k ouro`, ~1 min; tier-1 holds the family at a small size)
-def test_the_ouro_cells_looped_step_compiles_inside_its_memory_at_five_layers_and_not_at_six(one_chip, on_tpu):
-    """The whole step of `ouro26b-train-loop4-s8192` as its configuration file states it (five layers run
-    four times over shared weights, four heads with their exit gates, the expected-exit loss), compiled for
-    the described chip. PR 58: arguments 5.500 GB (12 B a parameter, each counted ONCE) + temporaries 9.512 GB
-    = 15.01 of 15.75 GB; six layers are 6.116 + 10.148 = 16.26 GB and do not fit (the configuration's `cut`
-    says what a layer costs). A forward flash kernel a layer-stack loop (four: one a recurrence, none made
-    again: `out` and the logsumexp are kept by name, T x L of them) and ONE backward kernel a loop; XLA
-    rematerialises nothing of its own; the loop's three scopes stand in the `op_name`s, forward and backward,
-    and a recurrence's rematerialised head and loss keep theirs."""
-    import importlib
-
-    from ray_tpu.models import llama
-    from ray_tpu.train import make_optimizer, make_train_step
-    from ray_tpu.train.step import TrainState
-
-    attention_ops = importlib.import_module("ray_tpu.ops.attention")
-    cfg, file = _cell_file("ouro-2.6b-train-loop4")
-    trainer = file["trainer"]
-    assert (cfg.loop_steps, cfg.n_layers, cfg.remat_policy, trainer["mesh"]) == (4, 5, "full", None)
-    fallbacks = attention_ops.xla_fallback_count
-    tx = make_optimizer(**trainer["optimizer"])
-
-    def compiled_step(cfg):
-        params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
-        state = TrainState(step=_scalar(one_chip), params=params,
-                           opt_state=_shapes(jax.eval_shape(tx.init, params), one_chip))
-        batch = {"tokens": jax.ShapeDtypeStruct((trainer["batch"], trainer["seq"] + 1), jnp.int32, sharding=one_chip)}
-        return make_train_step(cfg, tx).lower(state, batch).compile()
-
-    compiled = compiled_step(cfg)
-    text, memory = compiled.as_text(), compiled.memory_analysis()
-    assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7  # a shared leaf is held once
-    assert memory.temp_size_in_bytes < (9.52 + 0.15) * 1e9
-    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
-    assert not _xla_remats(text) and attention_ops.xla_fallback_count == fallbacks
-    assert _kernel_calls(text, "flash_attention_fwd") == (cfg.loop_steps, 0)
-    assert _kernel_calls(text, "flash_attention_bwd_dkv_dq") == (cfg.loop_steps, 0)
-    assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (0, 0)
-    names = set(re.findall(r'op_name="([^"]*)"', text))
-    for scope in (llama.LOOP_STEP, llama.EXIT_GATE, llama.EXIT_LOSS):
-        assert any(f"/jvp(model)/{scope}" in n for n in names) and any(f"/transpose(jvp(model))/{scope}" in n for n in names), scope
-    again = {n for n in names if "transpose(jvp(model))" in n and "rematted_computation" in n}
-    assert any("/lm_head/" in n for n in again) and any("/loss/" in n for n in again)
-    assert any(f"/{llama.LOOP_STEP}/{llama.LAYER_LOOP}/" in n for n in again)
-    deeper = compiled_step(dataclasses.replace(cfg, n_layers=6)).memory_analysis()
-    assert deeper.argument_size_in_bytes + deeper.temp_size_in_bytes > 15.75e9  # the greatest depth that is placed is five
-
-
-def test_the_block_diffusion_cells_step_compiles_inside_its_memory_and_walks_288_tiles_a_head(one_chip, on_tpu):
-    """The whole step of `sdar30b-train-ep8share-s8192` as its configuration file states it (five
-    layers; the batch a loader makes: tokens, masked, p_mask), compiled for the described chip:
-    [1, 16384] rows through the layers under the block-diffusion mask. The two kernels (forward;
-    the ONE backward kernel, PR 53) carry `_bd` behind their names, which the accepted kernel metrics and the cell's own roofline
-    metric find; the forward kernel runs once a layer (its `out` and logsumexp kept under
-    `full`); three router products a layer and the pick's loops, nothing made again by XLA, no
-    attention on an XLA path; 15.18 of 15.75 GB (PR 50: six layers were 17.31 and do not fit).
-    The grids: a step a q tile forward and backward (K/V of the doubled row are one span, and so
-    dK and dV of a kv head stay in VMEM); 288 tiles a head each way by `tile_counts`."""
-    import importlib
-
-    from ray_tpu.models import llama
-    from ray_tpu.ops import flash_attention as fa
-    from ray_tpu.train import make_optimizer, make_train_step
-    from ray_tpu.train.step import TrainState
-
-    attention_ops = importlib.import_module("ray_tpu.ops.attention")
-    cfg, file = _cell_file("sdar-30b-a3b-train-ep8")
-    trainer = file["trainer"]
-    b, n = trainer["batch"], trainer["seq"]
-    assert cfg.remat and cfg.remat_policy == "full" and (cfg.diffusion_block, cfg.n_layers, b, n) == (4, 5, 1, 8192)
-    fallbacks = attention_ops.xla_fallback_count
-    tx = make_optimizer(**trainer["optimizer"])
-    params = _shapes(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)), one_chip)
-    state = TrainState(step=_scalar(one_chip), params=params, opt_state=_shapes(jax.eval_shape(tx.init, params), one_chip))
-    batch = {"tokens": jax.ShapeDtypeStruct((b, n), jnp.int32, sharding=one_chip),
-             "masked": jax.ShapeDtypeStruct((b, n), jnp.bool_, sharding=one_chip),
-             "p_mask": jax.ShapeDtypeStruct((b,), jnp.float32, sharding=one_chip)}
-    step = make_train_step(cfg, tx)
-    compiled = step.lower(state, batch).compile()
-    text = compiled.as_text()
-    for path, count in (("train_attn_fwd_kernel_pct", 1), ("train_attn_bwd_kernel_pct", 1), ("train_attn_bd_roofline_pct", 2)):
-        with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "metrics", f"{path}.json")) as f:
-            rx = re.compile(json.load(f)["args"]["pattern"])
-        kernels = [ln.strip() for ln in text.splitlines() if "tpu_custom_call" in ln and rx.search(ln.strip())]
-        assert len(kernels) == count and all("_bd" in ln.split(" = ")[0] for ln in kernels), (path, kernels)
-    assert _kernel_calls(text, "flash_attention_fwd_bd") == _kernel_calls(text, "flash_attention_bwd_dkv_dq_bd") == (1, 0)
-    assert _kernel_calls(text, "flash_attention_bwd_dq_bd") == _kernel_calls(text, "flash_attention_bwd_dkv_bd") == (0, 0)
-    assert _kernel_calls(text, "flash_attention_fwd") == (0, 0)
-    assert len(_instructions(text, "convolution", "moe_router")) == 3 and len(_instructions(text, "while", "moe_router")) == 2
-    assert not _xla_remats(text) and attention_ops.xla_fallback_count == fallbacks
-    memory = compiled.memory_analysis()
-    assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7 and cfg.n_params == 550984960
-    assert memory.temp_size_in_bytes < (8.57 + 0.15) * 1e9
-    assert 0.25 * 15.75e9 < memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9  # what a v5e program may use
-    t = fa._tiling(2 * n, 2 * n, 512, 512, 128, 2, 8)
-    assert (t.kv_span, t.q_span) == (16384, 1024)
-    grids = _pallas_grids(jax.make_jaxpr(step._jitted)(state, batch).jaxpr)
-    assert fa._fuses(t, 2 * n) and grids.count((b, 32, 32, 1)) == 2 and (b, 4, 32, 16) not in grids  # forward; backward (PR 53)
-    for kernel, heads in (("fwd", 1), ("dq", 1), ("dkv", 8)):  # ("dq": the one backward kernel's walk too)
-        counts = fa.tile_counts(2 * n, 2 * n, False, 512, 512, n_rep=heads, kernel=kernel, block_diffusion=4)
-        assert counts.tiles_computed == heads * 288 and round(counts.tiles_needed / heads, 1) == 256.1
-
-
-@pytest.mark.parametrize("config,forward", [
-    ("glm-4.7-flash-train-ep8", (1, 0)),  # `full`, [1, 8192, 20 / 20, 256] behind the latent projections
-    ("lfm2-24b-a2b-train-ep8", (1, 0)),   # `full`, [4, 8192, 32 / 8, 64] on padded lanes
-    ("mistral-7b-train", (1, 1))])        # `dots`, the control: the forward kernel again in the backward pass
-def test_a_rematerialised_attention_part_runs_the_forward_kernel_once_under_full(one_chip, on_tpu, config, forward):
-    """A cell's attention part (norm, projections, rotation, the flash kernels, the output
-    projection, the residual) under the cell's remat, value and every gradient at the cell's
-    shape, compiled for the described chip: under `full` the forward kernel's `out` and
-    logsumexp are kept by name (`ops.attention.FLASH_NAMES`) and the program holds ONE
-    `flash_attention_fwd`, where it held a second in the rematerialised layer (PR 43); under
-    `dots`, which keeps neither (PERF.md section 7, after PR 26 (1)), it holds two as before.
-    One backward kernel, `_bwd_dkv_dq`, either way (PR 53)."""
-    from ray_tpu.models import attn, llama
-
-    cfg, file = _cell_file(config)
-    trainer = file["trainer"]
-    assert cfg.remat and cfg.remat_policy == ("full" if forward == (1, 0) else "dots")
-    stacks = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
-    stack = next(st for st in stacks.values() if isinstance(st, dict) and "attn_norm" in st)
-    lp = {n: jax.ShapeDtypeStruct(stack[n].shape[1:], stack[n].dtype, sharding=one_chip)
-          for n in llama._layer_axes(cfg, "attn", None)}
-    b, s = trainer["batch"], trainer["seq"]
-    x = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.bfloat16, sharding=one_chip)
-    pos = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
-
-    def loss(x, lp, pos):
-        with jax.named_scope("model"):  # as train/step.py
-            part = llama._maybe_remat(
-                lambda x, lp: attn.mixer(x, lp, cfg, pos, None, None, None)[0], cfg)
-            return jnp.sum(part(x, lp).astype(jnp.float32))
-
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp, pos).compile().as_text()
-    assert _kernel_calls(text, "flash_attention_fwd") == forward
-    assert _kernel_calls(text, "flash_attention_bwd_dkv_dq") == (1, 0)
-    assert _kernel_calls(text, "flash_attention_bwd_dq") == _kernel_calls(text, "flash_attention_bwd_dkv") == (0, 0)
-    assert not _xla_remats(text)
-    _every_instruction_of_the_part_carries_a_piece(text, cfg)
-
-
-def _every_instruction_of_the_part_carries_a_piece(text, cfg):
-    """In an attention part's compiled text every instruction whose `op_name` lies under `attn`
-    carries one of the part's five names (models/attn.py:SCOPES; a fusion its parts'), in the
-    forward pass, made again (`rematted_computation`) and in the backward pass
-    (`transpose(jvp(..))`): a `custom_vjp`'s backward rule is traced under its call site's names,
-    so the backward flash kernel and the rotate kernel's lie under `attn_core` (PR 52)."""
-    from ray_tpu.models import attn
-
-    paths = [p for p in re.findall(r'op_name="([^"]*)"', text) if "/attn/" in p]
-    assert paths
-    stray = [p for p in paths if sum(f"/{piece}/" in p for piece in attn.SCOPES) != 1]
-    assert not stray, stray[:5]
-    passes = {"forward": [p for p in paths if "transpose(" not in p],
-              "again": [p for p in paths if "rematted_computation" in p],
-              "backward": [p for p in paths if "transpose(" in p and "rematted_computation" not in p]}
-    wanted = {"attn_in_proj", "attn_core", "attn_out_proj"} | ({"attn_head_norm"} if cfg.attn_qk_norm else set())
-    for which, found in passes.items():
-        assert wanted <= {piece for piece in attn.SCOPES for p in found if f"/{piece}/" in p}, which
-    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and "op_name=" in ln]
-    assert kernels and all("/attn_core/" in ln for ln in kernels)
-    assert {k for k in ("fwd", "bwd_dkv_dq") for ln in kernels if f"flash_attention_{k}/" in ln} == {"fwd", "bwd_dkv_dq"}
-    if cfg.latent_attention:
-        assert all("/attn_in_proj/" in p for p in paths if "/mla_q/" in p or "/mla_kv/" in p)
-    elif cfg.head_dim >= 128:  # (narrower heads rotate in `jax.numpy`, in front of the padded kernels)
-        assert any("rope_fwd/" in ln for ln in kernels) and any("rope_bwd/" in ln for ln in kernels)
-
-
-def test_mamba2_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
-    """A Mamba-2 layer's share of the Nemotron-3-Super cell (16 heads of 64, 1 group, state
-    128, 8,192 positions in 64 chunks of 128), value and every gradient under the cell's remat:
-    plain XLA, no kernel, the chunked scan's float32 intermediates beside the projections' under
-    2 GB; `[z | xBC | dt]` `[1, 8192, 2320]` kept by name (`_rematerialised_mixer`, PR 48)."""
-    from ray_tpu.models import ssm
-
-    cfg = _nemotron_share()
-    lp = _shapes(jax.eval_shape(lambda: ssm.init(jax.random.PRNGKey(0), cfg)), one_chip)
-    assert lp["in_proj"].shape == (4096, 2 * 1024 + 2 * 128 + 16) and lp["out_proj"].shape == (1024, 4096)
-    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one_chip)
-    compiled = _rematerialised_mixer(ssm, "M", cfg, x, lp)
-    assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
-
-
 def test_flash_attention_compiles_at_head_width_64(one_chip, on_tpu):
     """[4, 8192, 32 / 8, 64], the LFM2 cell's shape (lfm2moe-train-ep8share-b4-s8192): heads
     half the lane width run the same kernels (forward; the backward's one, PR 53) on zero-padded lanes (Mosaic refuses a
@@ -823,149 +293,8 @@ def test_flash_attention_compiles_at_head_width_64(one_chip, on_tpu):
         assert sum(bool(rx.search(ln)) for ln in calls) == n, (path, calls)
     # the kernels see whole vregs: [4, 32, 8192, 128] and [4, 8, 8192, 128]
     assert all("bf16[4,32,8192,128]" in ln and "bf16[4,8,8192,128]" in ln for ln in calls)
-    grid, = _pallas_grids(jax.make_jaxpr(lambda q, k, v: fa.flash_attention(q, k, v, causal=True))(q, k, k).jaxpr)
+    grid, = pallas_grids(jax.make_jaxpr(lambda q, k, v: fa.flash_attention(q, k, v, causal=True))(q, k, k).jaxpr)
     assert grid == (b, h, 16, 1)  # K and V of a kv head, 8,192 rows of 128 lanes, are one span
-
-
-def test_gated_short_convolution_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
-    """A gated short-convolution part of the LFM2 cell ([4, 8192] tokens, 2048 wide, 3 taps),
-    value and every gradient under the cell's remat: plain XLA, no kernel, the float32
-    convolution beside the projections' outputs under 1.95 GB (1.880 by the compile, as before
-    PR 47: alone, the part's kept array lives no longer than the one made again did). Under
-    `full` the input product's result `[B | C | x]` is kept by name (`sconv.IN_PROJ_NAME`, PRs 47, 48):
-    the product runs once forward, not again in the rematerialised part (it did), twice backward;
-    the rounding jax.checkpoint gives a named residual is in the product's own epilogue; and the
-    one stored array `[4, 8192, 3, 2048]` is what forward and backward both read, through bitcasts."""
-    from ray_tpu.models import sconv
-
-    cfg, _ = _cell_file("lfm2-24b-a2b-train-ep8")
-    lp = _shapes(jax.eval_shape(lambda: sconv.init(jax.random.PRNGKey(0), cfg)), one_chip)
-    assert lp["sconv_in"].shape == (2048, 3, 2048) and lp["sconv_w"].shape == (3, 2048)
-    assert lp["sconv_out"].shape == (2048, 2048)
-    x = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16, sharding=one_chip)
-    compiled = _rematerialised_mixer(sconv, "C", cfg, x, lp)
-    assert "tpu_custom_call" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.95e9
-
-
-def _rematerialised_mixer(mixer, kind, cfg, x, lp):
-    """A recurrent mixer under remat `full` as `llama._maybe_remat` runs a part, value and every
-    gradient, compiled: its input product once forward, not again in the rematerialised part, twice
-    backward; ONE stored copy of the kept result, rounded in the product's own fusion; no `.remat`."""
-    from ray_tpu.models import llama
-
-    assert cfg.remat and cfg.remat_policy == "full" and mixer.KEPT["full"] == (mixer.IN_PROJ_NAME,)
-    part = llama._maybe_remat(lambda x, lp: mixer.mixer(x, lp, cfg), cfg)
-
-    def loss(x, lp):
-        with jax.named_scope("model"):  # as train/step.py
-            return jnp.sum(part(x, lp).astype(jnp.float32))
-
-    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile()
-    text = compiled.as_text()
-    scope, einsum, extents = _KEPT_PRODUCTS[kind]
-    assert _products(text, scope, einsum) == (1, 0, 2)
-    assert _kept_copies(text, extents) == (1, 1)
-    assert not _xla_remats(text)
-    return compiled
-
-
-# a recurrent mixer's pattern character -> (the scope of its input product, the product's einsum, the
-# kept result at its cell's shape in whichever order of its extents): `[B | C | x]` of the LFM2 cell,
-# `[z | xBC | dt]` of the Nemotron cell, q | k | v before the convolution of the Solar-Open2 cell
-_KEPT_PRODUCTS = {
-    "C": ("sconv_in_proj", "btd,dpe->btpe", r"bf16\[(?:4,8192,3,2048|3,4,8192,2048|4,3,8192,2048)\]"),
-    "M": ("ssm_in_proj", "btd,de->bte", r"bf16\[(?:1,)?8192,2320\]"),
-    "K": ("kda_in_proj", "btd,dphk->btphk", r"bf16\[(?:1,)?8192,(?:3072|3,8,128|12288|3,32,128)\]"),
-}
-
-
-def _products(text, scope, einsum):
-    """The compiled program's products (XLA's `convolution`) of `einsum` under `scope`, by what ran
-    them: forward, the forward made again by a rematerialised layer, backward."""
-    products = [ln for ln in _instructions(text, "convolution", scope) if f"/{scope}/{einsum}/" in ln]
-    again = sum("rematted_computation" in ln for ln in products)
-    backward = sum("transpose(jvp(" in ln for ln in products) - again
-    return len(products) - again - backward, again, backward
-
-
-def _kept_copies(text, extents):
-    """(arrays with the `extents` of a mixer's kept product that the program makes and stores: results
-    of fusions, products, copies and transposes outside fused computations (bitcasts, a loop's tuple
-    plumbing and the asynchronous copies between fast memory and HBM, which change no layout, apart);
-    how many of them the named residual's `reduce-precision` is fused behind the product itself).
-    Equal, and one a part: ONE copy, rounded where the product wrote it, no pass of its own, none
-    transposed."""
-    stored, fused, in_fusion = 0, 0, False
-    for ln in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", ln)
-        if head:
-            in_fusion = head.group(1).startswith("fused_computation")
-        made = re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = {extents}\S* ([\w\-]+)\((%[\w.\-]+)", ln)
-        if not made:
-            continue
-        if made.group(1) == "reduce-precision":
-            assert in_fusion and made.group(2).startswith("%convolution"), ln  # not stand-alone
-            fused += 1
-        elif not in_fusion and made.group(1) in ("fusion", "convolution", "copy", "transpose"):
-            stored += 1
-    return stored, fused
-
-
-def _kernel_calls(text, name):
-    """The compiled program's calls of the Pallas kernel `name`, by what ran them: forward,
-    the forward made again by a rematerialised layer, backward."""
-    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and f"/{name}/" in ln]
-    again = sum("rematted_computation" in ln for ln in calls)
-    return len(calls) - again, again
-
-
-# q|k|v `[1, 8192, 3072]` in float32 with the taps' 3 rows of zeros in front: `ssm._causal_conv`'s copy
-_CONV_PADDED_COPY = r"f32\[1,8195,(3072|12288)\]"
-
-# float32 arrays of every chunk with the extents of a sub-chunk's differences [.., 32, 32, 128]
-# or of the sub-chunks' factors [.., 4, 128, 128]: what `_decayed_overlaps` wrote to HBM
-_OVERLAPS_INTERMEDIATES = r"f32\[(\d+,)+(32,32,128|4,128,128)\]"
-
-
-# float32 arrays of every chunk with the extents of `_chunk_parts`' right-hand sides and solutions,
-# beta [k exp G | v] and [W | U0], [.., 128, 256]: what the second half wrote to HBM before its kernels
-_PARTS_INTERMEDIATES = r"f32\[(\d+,)+128,256\]"
-
-
-def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
-    """A Kimi-Delta-Attention part's share of the Solar-Open2 cell (8 heads of 128, 8,192
-    positions in 64 chunks of 128), value and every gradient under the cell's remat: the
-    overlaps are the two Pallas kernels by name (forward, forward again in the
-    rematerialised layer, backward: ISSUE 38's item 5 was not taken, PERF.md section 6), the
-    chunks' four matrices two more with the same three calls (PR 51: nine custom calls a part),
-    convolution, silu and norms of q, k and v likewise two kernels and three calls (PR 44), the
-    inverse the compiler's own triangular kernel once a block, no float32 array with the
-    extents of the differences, the sub-chunks' factors or the second half's right-hand
-    sides and solutions of all chunks, the scan's float32 intermediates beside the
-    projections' under 2 GB; q | k | v before the convolution
-    `[1, 8192, 3072]` kept by name in the layout the convolution's kernels read
-    (`_rematerialised_mixer`, PR 48: named before the reshape it was stored positions-minor and
-    copied for the kernels, forward and backward)."""
-    from ray_tpu.models import kda
-    from ray_tpu.ops.kda import _SOLVE, takes_kernels
-
-    cfg, _ = _cell_file("solar-open2-train-tp8-ep40")
-    lp = _shapes(jax.eval_shape(lambda: kda.init(jax.random.PRNGKey(0), cfg)), one_chip)
-    assert lp["kda_qkv"].shape == (4096, 3, 8, 128) and lp["kda_out"].shape == (8, 128, 4096)
-    assert lp["kda_f_down"].shape == lp["kda_g_down"].shape == (4096, 128)
-    assert cfg.kda_chunk == 128 and takes_kernels(cfg.kda_chunk, cfg.kda_head_dim)
-    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one_chip)
-    compiled = _rematerialised_mixer(kda, "K", cfg, x, lp)
-    text = compiled.as_text()
-    assert _kernel_calls(text, "kda_overlaps_fwd") == (1, 1) and _kernel_calls(text, "kda_overlaps_bwd") == (1, 0)
-    assert _kernel_calls(text, "short_conv_fwd") == (1, 1) and _kernel_calls(text, "short_conv_bwd") == (1, 0)
-    assert _kernel_calls(text, "kda_parts_fwd") == (1, 1) and _kernel_calls(text, "kda_parts_bwd") == (1, 0)
-    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 9
-    assert not re.search(_CONV_PADDED_COPY, text)
-    assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == cfg.kda_chunk // _SOLVE
-    assert not re.search(_OVERLAPS_INTERMEDIATES, text) and not re.search(_PARTS_INTERMEDIATES, text)
-    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
 @pytest.mark.parametrize("b,s,h,kv,per_row", [
@@ -993,7 +322,7 @@ def test_rope_kernel_compiles(one_chip, on_tpu, b, s, h, kv, per_row):
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     for name in ("rope_fwd", "rope_bwd"):
         assert sum(name in ln.split(" = ")[0] for ln in calls) == 1, (name, len(calls))
-    grids = _pallas_grids(jax.make_jaxpr(
+    grids = pallas_grids(jax.make_jaxpr(
         lambda q, k, pos: fa.rope_to_heads(q, k, pos, 1e6))(q, k, pos).jaxpr)
     rows = {2048: 256 if h == 32 else 512, 4096: 256, 200: 200}[s]
     assert grids == [(b, s // rows)], grids
@@ -1059,11 +388,7 @@ def serving(one_chip):
     params = jax.eval_shape(lambda: jax.tree.map(
         lambda x: x.astype(jnp.bfloat16), llama.init(jax.random.PRNGKey(0), cfg)))
     return dict(cfg=cfg, slots=slots, max_len=max_len, block=16,
-                params=_shapes(params, one_chip))
-
-
-def _scalar(sharding, dtype=jnp.int32):
-    return jax.ShapeDtypeStruct((), dtype, sharding=sharding)
+                params=shapes(params, one_chip))
 
 
 def _vec(n, sharding, dtype=jnp.int32):
@@ -1081,7 +406,7 @@ def _lower_slot(sv, sh, program):
     if program == "prefill":
         tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=sh)
         return model_runner.prefill.lower(
-            sv["params"], state, tokens, _scalar(sh), _scalar(sh), cfg)
+            sv["params"], state, tokens, scalar(sh), scalar(sh), cfg)
     return model_runner.decode_step.lower(
         sv["params"], state, _vec(slots, sh), _vec(slots, sh, jnp.bool_), cfg)
 
@@ -1094,7 +419,7 @@ def _lower_paged(sv, sh, program):
     if program == "prefill":
         tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32, sharding=sh)
         return model_runner.prefill_detached.lower(
-            sv["params"], tokens, _scalar(sh), cfg)
+            sv["params"], tokens, scalar(sh), cfg)
     pool = jax.ShapeDtypeStruct(
         (cfg.n_layers, n_blocks + 1, block, cfg.n_kv_heads, cfg.head_dim),
         jnp.bfloat16, sharding=sh)
@@ -1106,7 +431,7 @@ def _lower_paged(sv, sh, program):
         kv = jax.ShapeDtypeStruct((cfg.n_layers, 1, 64, cfg.n_kv_heads, cfg.head_dim),
                                   jnp.bfloat16, sharding=sh)
         return paged.install_prefill.lower(
-            state, kv, kv, _vec(64 // block, sh), _scalar(sh), _scalar(sh),
+            state, kv, kv, _vec(64 // block, sh), scalar(sh), scalar(sh),
             n_blocks=64 // block)
     k_steps = 4  # one fused decode+sample burst
     rngs = jax.ShapeDtypeStruct((k_steps, 2), jnp.uint32, sharding=sh)

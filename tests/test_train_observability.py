@@ -317,13 +317,36 @@ def _fresh_intervals(monkeypatch):
     return hist
 
 
-def _drive(seconds_of_step):
+class _SteppedClock:
+    """`time` as `telemetry` and `session` read it, with `perf_counter_ns` on the driven thread in the test's
+    hand: it advances by what the step callable is told (`sleep`) and by nothing else, so a step's length is
+    the entry of `seconds_of_step` whatever the machine's load. Every other thread reads the real clock."""
+
+    def __init__(self):
+        self.thread, self.now_ns = None, time.perf_counter_ns()
+
+    def __getattr__(self, name):  # everything else is `time`'s own
+        return getattr(time, name)
+
+    def perf_counter_ns(self):
+        return self.now_ns if threading.get_ident() == self.thread else time.perf_counter_ns()
+
+    def sleep(self, seconds):
+        self.now_ns += round(seconds * 1e9)
+
+
+def _drive(monkeypatch, seconds_of_step):
     """A step callable under the loop's clock, called once for every entry of `seconds_of_step`
-    on a thread (and so a loop) of its own. -> what `train.metrics()` read before and after."""
-    step = session.CountedStep(time.sleep)
+    on a thread (and so a loop) of its own, each call as long on the loop's clock as its entry says
+    (`_SteppedClock`). -> what `train.metrics()` read before and after."""
+    clock = _SteppedClock()
+    for module in (telemetry, session):  # the laps' clock, and the instant `_os_numbers` reads
+        monkeypatch.setattr(module, "time", clock)
+    step = session.CountedStep(clock.sleep)
     reads = []
 
     def run():
+        clock.thread = threading.get_ident()
         try:
             reads.append(train.metrics())
             for seconds in seconds_of_step:
@@ -346,7 +369,7 @@ def test_a_slow_step_leaves_one_line_and_one_count(monkeypatch, caplog):
     numbers since the threshold was read) and one count of `train_slow_steps_total`."""
     _fresh_intervals(monkeypatch)
     with caplog.at_level("WARNING", logger=session.LOGGER.name):
-        before, after = _drive([0.002] * 40 + [0.15] + [0.002] * 9)
+        before, after = _drive(monkeypatch, [0.002] * 40 + [0.15] + [0.002] * 9)
     assert after["train_steps_total"] - before["train_steps_total"] == 50
     assert after["train_slow_steps_total"] - before["train_slow_steps_total"] == 1
     lines = [r.getMessage() for r in caplog.records if r.name == session.LOGGER.name]
@@ -375,7 +398,7 @@ def test_no_step_is_slow_before_the_threshold_is_read_and_the_record_costs_no_sy
     real = session._os_numbers
     monkeypatch.setattr(session, "_os_numbers", lambda steps: asked.append(steps) or real(steps))
     with caplog.at_level("WARNING", logger=session.LOGGER.name):
-        before, after = _drive([0.001] * 5 + [0.1] + [0.001] * (session.FIRST_REFRESH + session.REFRESH_EVERY))
+        before, after = _drive(monkeypatch, [0.001] * 5 + [0.1] + [0.001] * (session.FIRST_REFRESH + session.REFRESH_EVERY))
     assert after["train_slow_steps_total"] == before["train_slow_steps_total"]
     assert not [r for r in caplog.records if r.name == session.LOGGER.name]
     assert asked == [session.FIRST_REFRESH, session.FIRST_REFRESH + session.REFRESH_EVERY]  # two refreshes in 86 steps
