@@ -22,8 +22,16 @@ sum of g inside the chunk (<= 0, falling),
 
 P, O0, M, N are a chunk's own (nothing carried): every chunk's at once, in batched
 products; the chunks are then joined by S <- M S + N, one [K, K] x [K, V] product a head and
-chunk, the only dependent steps (T / Q of them); the outputs P S_0 + O0 are one batched
-product more.
+chunk, the only dependent steps (T / Q of them), and a chunk's outputs are P S_0 + O0 from the
+state it starts from. `walk` hands that last step to the two Pallas kernels of ops/kda_walk.py
+wherever `takes_kernels` says so: a head's state stays in fast memory from its first chunk to
+its last, the two products of a chunk share their right operand and are one on the MXU, and o
+is written, and its cotangent read, a head's positions together, [B, H, T, K]: the order XLA
+gives the norm, the gate and the output product behind the scan, so the transpose to the
+mixer's [B, T, H, K] is a change of names. Any other shape, and any shape under a mesh, runs
+`_walk`: a `lax.scan` over the chunks with M, N and every chunk's start through HBM, the
+outputs one batched product more and a transpose to the mixer's order; the same sums in the
+same order, and what the kernels are tested against.
 
 Every decay is the exponential of a non-positive number, whatever g holds: exp G_t,
 exp(G_Q - G_s), and the pairs' exp(G_tc - G_sc). That one is NOT split into
@@ -42,7 +50,7 @@ Where they are made decides what is alive: `overlaps` hands a chunk to the two P
 of ops/kda_overlaps.py wherever they tile it (`kda_overlaps.supports`: channels in whole
 128-lane registers, sub-chunks in whole registers of 8 rows; the Solar-Open2 and Kimi-Linear
 cells' 128 / 32 / 128), a chunk of up to 8 heads a grid step (`kda_overlaps._per_step`, the
-rule the second half's kernels share), and then a chunk's differences, decayed keys and
+rule the second half's and the walk's kernels share), and then a chunk's differences, decayed keys and
 factors live and die in fast memory in both passes and the backward pass keeps q, k and G
 alone. The kernels cut the pairs inside a sub-chunk once more, the same sums under the same
 bound: a pair of two BLOCKS of 8 positions of one sub-chunk goes through a second reference,
@@ -86,14 +94,15 @@ buffers at 128). Any other shape, and any shape under a mesh, runs `_chunk_parts
 algebra in `jax.numpy`, differentiated by JAX, every chunk's [Q, K + V] intermediates
 through HBM; it is what the kernels are tested against. Both halves' kernels read q, k and v
 where the mixer wrote them, [B, T, H x K] with a position's heads side by side, and write
-their gradients there; only the `jax.numpy` forms take copies with the chunks leading.
+their gradients there; only the `jax.numpy` forms take copies with the chunks leading. P, O0,
+M, N lead with the chunks, [chunks, B, H, ., .]: the walk's kernels read a chunk of a few heads
+out of them as the parts' kernels wrote it.
 
-What is left outside the kernels is plain `jax.numpy`, differentiated by JAX: the running sum
-G (made once a scan in the mixer's order of the positions, transposed once and handed to both
-halves), the strict mask and beta over the keys' overlaps, the inverse (the compiler's
-substitution, above), the join and the output product P S_0 + O0. Every operand anywhere is
-float32 and every product, XLA's or a kernel's, runs at the highest matrix precision.
-PERF.md section 5 has the trace.
+What is left outside the six kernels is plain `jax.numpy`, differentiated by JAX: the running
+sum G (made once a scan in the mixer's order of the positions, transposed once and handed to both
+halves), the strict mask and beta over the keys' overlaps and the inverse (the compiler's
+substitution, above). Every operand anywhere is float32 and every product, XLA's or a kernel's,
+runs at the highest matrix precision. PERF.md section 5 has the trace.
 """
 import jax
 import jax.numpy as jnp
@@ -101,7 +110,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.parallel.sharding import partitioned_by_gspmd
 
-from . import kda_overlaps, kda_parts
+from . import kda_overlaps, kda_parts, kda_walk
 
 _HI = jax.lax.Precision.HIGHEST
 _SUB = 32  # positions of a sub-chunk: the differences are [_SUB, _SUB, K] a sub-chunk
@@ -236,6 +245,28 @@ def _chunk_parts(q, k, v, run, beta, inverse, b):
     return p, o0, m, n
 
 
+def walk(p, o0, m, n):
+    """`_walk`'s outputs [B, chunks, Q, H, V], the positions in the mixer's order, from P, O0 [chunks, B, H, Q, .] and
+    M, N [chunks, B, H, K, .]: by the Pallas kernels where they tile the shape (they hold the state in fast memory
+    from a head's first chunk to its last and write o as the mixer's next steps read it), else by it."""
+    size, width = p.shape[-2:]
+    if takes_kernels(size, width):
+        return kda_walk.walk(p, o0, m, n).transpose(0, 2, 3, 1, 4)  # a head's positions together: a change of names to XLA
+    return _walk(p, o0, m, n)
+
+
+def _walk(p, o0, m, n):
+    """The chunks joined, S <- M S + N from a zero state, one [K, K] x [K, V] product a head and chunk and the
+    only dependent steps, and the outputs P S_0 + O0 of the module's docstring, one batched product more."""
+    def join(state, mn):  # the state each chunk starts from
+        m_c, n_c = mn
+        return jnp.einsum("bhcd,bhdv->bhcv", m_c, state, precision=_HI) + n_c, state
+
+    _, starts = jax.lax.scan(join, jnp.zeros(n.shape[1:], n.dtype), (m, n))
+    o = jnp.einsum("kbhtc,kbhcv->kbhtv", p, starts, precision=_HI) + o0
+    return o.transpose(1, 0, 3, 2, 4)
+
+
 def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
              chunk: int) -> jax.Array:
     """q, k, v [B, T, H, K] (q scaled, k of unit length: the caller's), g [B, T, H, K]
@@ -257,11 +288,4 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
     q, k, v, run, beta = split(q), split(k), split(v), _lead(jnp.cumsum(split(g), axis=2)), _lead(split(beta))
     a, b = _overlaps(q, k, run, beta)
     p, o0, m, n = chunk_parts(q, k, v, run, beta, _unit_lower_inverse(a), b)
-
-    def join(state, mn):  # the state each chunk starts from
-        m_c, n_c = mn
-        return jnp.einsum("bhcd,bhdv->bhcv", m_c, state, precision=_HI) + n_c, state
-
-    _, starts = jax.lax.scan(join, jnp.zeros((bsz, h, width, width), f32), (m, n))
-    o = jnp.einsum("kbhtc,kbhcv->kbhtv", p, starts, precision=_HI) + o0
-    return o.transpose(1, 0, 3, 2, 4).reshape(bsz, t, h, width)
+    return walk(p, o0, m, n).reshape(bsz, t, h, width)

@@ -13,7 +13,10 @@ of a non-positive number. What differs is where the intermediates live: a grid s
 chunk of `kda_overlaps._per_step` heads and walks the heads in a loop (one body, traced once), and a
 head's exp G, beta [k exp G | v], [W | U0] and Kend are made, used and dropped in fast
 memory. Nothing with the extents [Q, K + V] reaches HBM in either pass; the forward kernel
-writes P, O0, M, N and nothing else.
+writes P, O0, M, N and nothing else, the chunks leading, [chunks, B, H, ., .]: what the walk
+over the chunks reads (ops/kda_walk.py's kernels a chunk of a few heads a grid step, the state
+in fast memory; `kda._walk`'s `lax.scan` elsewhere), and the order its backward pass hands
+dP, dO0, dM, dN back in (dO0 is o's cotangent, which that kernel writes with the chunks leading).
 
 q, k and v are read where the mixer wrote them, [B, chunks x Q, H x K] with a position's
 heads side by side (`kda_overlaps.rows_block`: a step's block is the chunk's Q rows of its
@@ -43,7 +46,7 @@ diagonal of a [Q, Q] select and one sum (exact: a value plus zeros).
 VMEM a grid step: forward 10 blocks of 64 KB a head at 128 x 128 (q, k, v, G, T, b in, P,
 O0, M, N out) and beta's row, twice for the pipeline's two buffers: 1.3 MB a head; backward
 16 blocks (the four cotangents in, six gradients and dbeta's row out), 2.1 MB.
-`kda_overlaps._per_step` (beside `rows_block`: one rule for all four kernels) takes as many of a
+`kda_overlaps._per_step` (beside `rows_block`: one rule for all six kernels) takes as many of a
 chunk's heads a step as `_VMEM_BLOCKS` allows (4 at 128 x 128).
 
 `kda_overlaps.supports` says which shapes go to the kernels (ops/kda.py's `takes_kernels`

@@ -214,6 +214,15 @@ def products(text, scope, einsum):
     backward = sum("transpose(jvp(" in ln for ln in products) - again
     return len(products) - again - backward, again, backward
 
+def _lines_by_fusion(text):
+    """A compiled text's lines, each with whether it lies in a fused computation's body."""
+    in_fusion = False
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", ln)
+        if head:
+            in_fusion = head.group(1).startswith("fused_computation")
+        yield ln, in_fusion
+
 def kept_copies(text, extents):
     """(arrays with the `extents` of a mixer's kept product that the program makes and stores: results
     of fusions, products, copies and transposes outside fused computations (bitcasts, a loop's tuple
@@ -221,11 +230,8 @@ def kept_copies(text, extents):
     how many of them the named residual's `reduce-precision` is fused behind the product itself).
     Equal, and one a part: ONE copy, rounded where the product wrote it, no pass of its own, none
     transposed."""
-    stored, fused, in_fusion = 0, 0, False
-    for ln in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", ln)
-        if head:
-            in_fusion = head.group(1).startswith("fused_computation")
+    stored, fused = 0, 0
+    for ln, in_fusion in _lines_by_fusion(text):
         made = re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = {extents}\S* ([\w\-]+)\((%[\w.\-]+)", ln)
         if not made:
             continue
@@ -253,6 +259,18 @@ OVERLAPS_INTERMEDIATES = r"f32\[(\d+,)+(32,32,128|4,128,128)\]"
 # float32 arrays of every chunk with the extents of `_chunk_parts`' right-hand sides and solutions,
 # beta [k exp G | v] and [W | U0], [.., 128, 256]: what the second half wrote to HBM before its kernels
 PARTS_INTERMEDIATES = r"f32\[(\d+,)+128,256\]"
+
+
+# the delta-rule scan's output o and its cotangent at the cells' shapes, [1, 8192, H, 128] cut in 64 chunks of 128 in
+# whichever grouping of its extents: what `kda._walk`'s `transpose` stored, forward, made again and backward
+WALK_OUTPUT = r"f32\[(?:1,)?(?:(?:8192|64,128),(?:8|32)|(?:8|32),(?:8192|64,128)),128\]"
+
+def stored_alone(text, extents, scope):
+    """The `copy` and `transpose` instructions under `scope` that store an array of `extents` in a pass over
+    HBM of their own: outside fused computations (inside one a `copy` is the fusion's read in another order)."""
+    made = re.compile(rf"\s*(?:ROOT )?%[\w.\-]+ = {extents}\S* (?:copy|transpose)\(")
+    return [ln for ln, in_fusion in _lines_by_fusion(text)
+            if not in_fusion and made.match(ln) and re.search(rf'op_name="[^"]*/{scope}/', ln)]
 
 
 # ------------------------------------------------------------------- two trees' steps, compared

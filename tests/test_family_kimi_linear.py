@@ -212,8 +212,9 @@ FAMILY = Family(
     # the cell's whole step (`Family.cell_step`). PR 54: [1, 8192]: four delta-rule parts at all 32 heads of 128 (their
     # q | k | v [1, 8192, 12288] kept: 0.2 GB a part), one latent attention part WITHOUT a q latent whose kernels run q/k
     # 192 | v 128 (the kernels under their own names: no XLA fallback), a dense part, four expert parts at 8 of 256 (the
-    # pick a slot at a time) beside a shared expert; arguments 7.23 GB; 12.86 of 15.75 GB in all
-    cell_step=(4, 2, 5.64),
+    # pick a slot at a time) beside a shared expert; arguments 7.23 GB; 12.86 of 15.75 GB in all. PR 61: 5.631 -> 5.349 GB,
+    # the walk over the scan's chunks in kernels (the loop's stacked states twice over and o's transposed copies gone): 12.58
+    cell_step=(4, 2, 5.35),
 )
 
 

@@ -199,7 +199,7 @@ FAMILY = Family(
     # q | k | v before the convolution of three delta-rule parts kept, [1, 8192, 3072] bfloat16 = 50 MB a part, 0.15 GB.
     # PR 51: 4.7938 -> 4.7907 GB, 3 MB less: the scan's second half in kernels (its [.., 128, 256] right-hand sides and
     # solutions and the [chunks, B, H, Q, K] copies of q, k, v gone) is not where the step's temporaries peak, so `kk`
-    # and `b` (0.2 GB: 4.996) stay unnamed
+    # and `b` (0.2 GB: 4.996) stay unnamed. PR 61: 4.7925 -> 4.7888 GB with the walk over the chunks in kernels (14.879 in all)
     cell_step=(4, 2, 4.80),
 )
 

@@ -1,7 +1,7 @@
-"""The gated delta rule in chunks (ops/kda.py) and its four Pallas kernels (the overlaps',
-ops/kda_overlaps.py, and the chunks' four matrices', ops/kda_parts.py, in the interpreter
-here) against the recurrence a position at a time (the solar_open2 reference's), at a small
-size on the CPU; and that the shape alone says which path runs."""
+"""The gated delta rule in chunks (ops/kda.py) and its six Pallas kernels (the overlaps',
+ops/kda_overlaps.py, the chunks' four matrices', ops/kda_parts.py, and the walk over the chunks',
+ops/kda_walk.py, in the interpreter here) against the recurrence a position at a time (the
+solar_open2 reference's), at a small size on the CPU; and that the shape alone says which path runs."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -106,15 +106,18 @@ def _pallas_calls(fn, *args):
     (32, 8, 128, 1, 2), (32, 32, 64, 1, 2), (128, 32, 256, 1, 2),
     (128, 32, 128, 1, 8),  # 8 heads a grid step in both of the overlaps' kernels, 4 in the second half's backward
     (32, 32, 64, 2, 4),  # 4 heads a step out of two rows of a batch: `rows_block`'s map at `per` > 1
+    (32, 32, 256, 2, 2),  # 8 chunks a row: the walk's state carried through seven of them, and zeroed for the second row
 ])
 @pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2",
                                     "below_minus_87_a_sub_chunk"])
 def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, t, b, h, monkeypatch):
-    """At a width of 128 both halves go to their Pallas kernels (ops/kda_overlaps.py and
-    ops/kda_parts.py, in the interpreter here): the scan's output and the gradient of q, k, v,
-    g and beta against the same scan with `_decayed_overlaps` and `_chunk_parts` in their
-    place and against the recurrence a position at a time, 2 to 8 heads (2 to 8 of them a grid
-    step), 1 to 4 chunks, sub-chunks of 8 (one diagonal block each: no second reference) and 32.
+    """At a width of 128 both halves and the walk over the chunks go to their Pallas kernels
+    (ops/kda_overlaps.py, ops/kda_parts.py and ops/kda_walk.py, in the interpreter here): the scan's
+    output and the gradient of q, k, v, g and beta against the same scan with `_decayed_overlaps`,
+    `_chunk_parts` and `_walk` in their place and against the recurrence a position at a time, 2 to
+    8 heads (2 to 8 of them a grid step), 1 to 8 chunks (the state the walk's kernels carry in fast
+    memory against the recurrence's own), sub-chunks of 8 (one diagonal block each: no second
+    reference) and 32.
     In the last regime the decays between two positions of ONE sub-chunk fall below exp(-87)
     and the factor through the end of a block of 8 columns underflows with them: the bound
     ops/kda.py states for a pair of two sub-chunks, held for the pairs of two blocks of 8."""
@@ -130,7 +133,7 @@ def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, 
     shape = (chunk, sub, t, b, h)
     if ("kernels", *shape) not in _PROGRAMS:
         assert sorted(_pallas_calls(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=(0, 1, 3)), *args)) == [
-            "kda_overlaps_bwd", "kda_overlaps_fwd", "kda_parts_bwd", "kda_parts_fwd"]
+            "kda_overlaps_bwd", "kda_overlaps_fwd", "kda_parts_bwd", "kda_parts_fwd", "kda_walk_bwd", "kda_walk_fwd"]
     cot = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     got, mine = _value_and_pull(scan, args, cot, key=("kernels", *shape))
     want, theirs = _value_and_pull(ref.recurrence, args, cot, key=("recurrence", t, b, h))
@@ -192,6 +195,50 @@ def test_the_second_halfs_kernels_are_chunk_parts(regime, chunk, sub, t, b, monk
         np.testing.assert_allclose(x, y, atol=5e-6 * float(jnp.abs(y).max()) + 1e-6, err_msg=name)
 
 
+def _walk_inputs(chunks, b, h, size=32, width=128, seed=0):
+    """P, O0 [chunks, B, H, Q, K] and M, N [chunks, B, H, K, K] of the sizes the second half hands on (M a
+    contraction: a state carried through every chunk neither dies nor grows), and a cotangent of o."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    p = jax.random.normal(ks[0], (chunks, b, h, size, width)) * width**-0.5
+    o0 = jax.random.normal(ks[1], p.shape)
+    m = jax.random.normal(ks[2], (chunks, b, h, width, width)) * 0.9 * width**-0.5
+    n = jax.random.normal(ks[3], m.shape)
+    return (p, o0, m, n), jax.random.normal(ks[4], (b, chunks, size, h, width))
+
+
+@pytest.mark.parametrize("b,h", [(1, 2), (2, 2), (2, 4), (1, 8), (2, 8)])
+@pytest.mark.parametrize("chunks", [1, 2, 8])
+def test_the_walks_kernels_are_the_join_and_the_output_product(chunks, b, h):
+    """ops/kda_walk.py's two kernels (in the interpreter here) against `_walk`, the `lax.scan` over M S + N, the
+    batched P S_0 + O0 and the transpose to the mixer's order, differentiated by JAX: o and the pull-back of a
+    random cotangent to P, O0, M, N, the same sums in the same order (a rounding of the largest entry apart, where
+    XLA's CPU products and the interpreter's differ at all); 1, 2 and 8 chunks (no state, one carried, seven), 2, 4
+    and 8 heads a grid step, one and two rows of a batch. The call that keeps no residuals (no `starts` written) is
+    the other's o bit for bit. A second row of a batch starts from a ZERO state whatever the first row left in
+    the scratch: its output and its gradients do not move when the first row's inputs do."""
+    args, cot = _walk_inputs(chunks, b, h)
+    assert kda_op.takes_kernels(*args[0].shape[-2:])
+    shape = (chunks, b, h)
+    if ("walk", *shape) not in _PROGRAMS:
+        assert _pallas_calls(kda_op.walk, *args) == ["kda_walk_fwd"]
+        assert _pallas_calls(lambda *a: jax.vjp(kda_op.walk, *a)[1](cot), *args) == ["kda_walk_fwd", "kda_walk_bwd"]
+    got, mine = _value_and_pull(kda_op.walk, args, cot, key=("walk", *shape))
+    want, theirs = _value_and_pull(kda_op._walk, args, cot, key=("plain walk", *shape))
+    assert got.shape == want.shape == cot.shape and np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=2e-6 * float(jnp.abs(want).max()))
+    np.testing.assert_array_equal(_PROGRAMS.setdefault(("walk alone", *shape), jax.jit(kda_op.walk))(*args), got)
+    for name, x, y in zip("P O0 M N".split(), mine, theirs):
+        assert x.shape == y.shape and np.isfinite(np.asarray(x)).all(), name
+        np.testing.assert_allclose(x, y, atol=2e-6 * float(jnp.abs(y).max()) + 1e-30, err_msg=name)
+    if b > 1:
+        moved = tuple(x.at[:, 0].multiply(-3.0) for x in args)
+        again, pulled = _value_and_pull(kda_op.walk, moved, cot, key=("walk", *shape))
+        assert float(jnp.abs(again[0] - got[0]).max()) > 1e-3
+        np.testing.assert_array_equal(again[1], got[1])
+        for x, y in zip(pulled, mine):
+            np.testing.assert_array_equal(x[:, 1], y[:, 1])
+
+
 def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
     """The case above on the kernel path (width 128, chunks of 32): decays of exp(-3000) a
     position, chunks whose sums fall to -8e4; a value and gradients that are finite say that
@@ -203,7 +250,8 @@ def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
     of them one grid step's."""
     q, k, v, _, beta = _scan_inputs(64, "mixed", b=1, h=4, width=128)
     g = jnp.full(q.shape, -3000.0).at[:, ::5].set(-1e-3)
-    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, 32), q, k, v, g, beta) == ["kda_overlaps_fwd", "kda_parts_fwd"]
+    assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, 32), q, k, v, g, beta) == [
+        "kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"]
     want = ref.recurrence(q, k, v, g, beta)
     got, grads = jax.value_and_grad(lambda *a: jnp.sum(kda_op.kda_scan(*a, 32) * want), argnums=(0, 1, 2, 3, 4))(
         q, k, v, g, beta)
@@ -225,20 +273,20 @@ def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
 ])
 def test_the_shape_alone_says_which_path_runs(chunk, width, sub, kernels, monkeypatch):
     """`kda.takes_kernels` reads the chunk and the width (and the sub-chunk they imply); the
-    scan's jaxpr holds both halves' forward kernels exactly where it says so. Nobody sets it."""
+    scan's jaxpr holds both halves' and the walk's forward kernels exactly where it says so. Nobody sets it."""
     monkeypatch.setattr(kda_op, "_SUB", sub)
     assert kda_op.takes_kernels(chunk, width) == kernels
     assert kda_op.kda_overlaps.supports(chunk, kda_op._sub(chunk), width) == kernels
     args = _scan_inputs(2 * chunk, "mixed", b=1, h=2, width=width)
     assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, chunk), *args) == (
-        ["kda_overlaps_fwd", "kda_parts_fwd"] if kernels else [])
+        ["kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"] if kernels else [])
 
 
 def test_under_a_mesh_that_shards_the_heads_the_jnp_path_runs():
     """GSPMD cannot partition a Mosaic call: with an axis of the ambient mesh still automatic
-    the scan runs `_decayed_overlaps` and `_chunk_parts` at the kernels' own shape, partitioned
-    by the compiler, and is the single-device scan's value; with every axis of size one both
-    halves' kernels run."""
+    the scan runs `_decayed_overlaps`, `_chunk_parts` and `_walk` at the kernels' own shape, partitioned
+    by the compiler, and is the single-device scan's value; with every axis of size one all
+    three pairs of kernels run."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
@@ -255,7 +303,8 @@ def test_under_a_mesh_that_shards_the_heads_the_jnp_path_runs():
         assert got.sharding.spec[2] == "tp"
     np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()))
     with use_mesh(build_mesh(MeshSpec(dp=1), jax.devices()[:1])):
-        assert kda_op.takes_kernels(32, 128) and _pallas_calls(scan, *args) == ["kda_overlaps_fwd", "kda_parts_fwd"]
+        assert kda_op.takes_kernels(32, 128) and _pallas_calls(scan, *args) == [
+            "kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"]
 
 
 def test_the_scan_asserts_whole_chunks():

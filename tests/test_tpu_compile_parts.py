@@ -9,8 +9,8 @@ import jax.numpy as jnp
 import pytest
 
 from compiled_step_text import (  # noqa: F401  (`on_tpu`, `one_chip` and the `topo` it is made of: this module's fixtures)
-    CONV_PADDED_COPY, KEPT_PRODUCTS, OVERLAPS_INTERMEDIATES, PARTS_INTERMEDIATES, cell_config, kept_copies, kernel_calls,
-    nemotron_share, on_tpu, one_chip, products, shapes, topo, xla_remats)
+    CONV_PADDED_COPY, KEPT_PRODUCTS, OVERLAPS_INTERMEDIATES, PARTS_INTERMEDIATES, WALK_OUTPUT, cell_config, instructions,
+    kept_copies, kernel_calls, nemotron_share, on_tpu, one_chip, products, shapes, stored_alone, topo, xla_remats)
 
 
 @pytest.mark.parametrize("config,forward", [
@@ -143,7 +143,10 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     positions in 64 chunks of 128), value and every gradient under the cell's remat: the
     overlaps are the two Pallas kernels by name (forward, forward again in the
     rematerialised layer, backward: ISSUE 38's item 5 was not taken, PERF.md section 6), the
-    chunks' four matrices two more with the same three calls (PR 51: nine custom calls a part),
+    chunks' four matrices two more with the same three calls (PR 51), the walk over the chunks two more
+    (PR 61: twelve custom calls a part, no `while` left under the scan and no stored transpose of o or of its
+    cotangent: the walk writes o and reads d o a head's positions together, the order XLA gives the norm, the gate and
+    the output product behind the scan),
     convolution, silu and norms of q, k and v likewise two kernels and three calls (PR 44), the
     inverse the compiler's own triangular kernel once a block, no float32 array with the
     extents of the differences, the sub-chunks' factors or the second half's right-hand
@@ -166,7 +169,11 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     assert kernel_calls(text, "kda_overlaps_fwd") == (1, 1) and kernel_calls(text, "kda_overlaps_bwd") == (1, 0)
     assert kernel_calls(text, "short_conv_fwd") == (1, 1) and kernel_calls(text, "short_conv_bwd") == (1, 0)
     assert kernel_calls(text, "kda_parts_fwd") == (1, 1) and kernel_calls(text, "kda_parts_bwd") == (1, 0)
-    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 9
+    assert kernel_calls(text, "kda_walk_fwd") == (1, 1) and kernel_calls(text, "kda_walk_bwd") == (1, 0)
+    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 12
+    assert not instructions(text, "while", "kda_scan")
+    # (G's gradient has o's extents and does come back to the mixer's order, once, behind the running sum's backward)
+    assert not [ln for ln in stored_alone(text, WALK_OUTPUT, "kda_scan") if "jit(cumsum)" not in ln]
     assert not re.search(CONV_PADDED_COPY, text)
     assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == cfg.kda_chunk // _SOLVE
     assert not re.search(OVERLAPS_INTERMEDIATES, text) and not re.search(PARTS_INTERMEDIATES, text)
