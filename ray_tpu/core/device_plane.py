@@ -46,6 +46,10 @@ class DevicePlaneError(RuntimeError):
     """Fetch could not complete device-natively; callers fall back to host bytes."""
 
 
+class _NoClusterSession(RuntimeError):
+    """The plane was asked for before any cluster session wrote its authkey: it stays off for now, not for the process."""
+
+
 # ------------------------------------------------------------------ descriptors
 
 @dataclass(frozen=True)
@@ -354,6 +358,8 @@ class DevicePlane:
                 return
             try:
                 self._start_locked()
+            except _NoClusterSession:
+                return  # (as `_ensure_control_started`: asked again once a session exists)
             except Exception as e:  # no transfer support on this backend/build
                 self._disabled_reason = f"{type(e).__name__}: {e}"
 
@@ -369,6 +375,10 @@ class DevicePlane:
                 return
             try:
                 self._start_control_locked()
+            except _NoClusterSession:
+                # not latched: a cluster that starts later in this process brings the key (a first touch before any
+                # session, e.g. an engine's host-bytes handoff, must not disable paged handoff for the process)
+                return
             except Exception as e:
                 self._control_disabled_reason = f"{type(e).__name__}: {e}"
 
@@ -381,7 +391,7 @@ class DevicePlane:
             # Never MINT a key here: two peers racing generate_authkey() would
             # persist different session keys and every fetch would fail auth.
             # No cluster session -> no plane (callers fall back to host bytes).
-            raise RuntimeError(
+            raise _NoClusterSession(
                 "no cluster session authkey (set RAY_TPU_CLIENT_AUTHKEY or "
                 "init a cluster first)")
         ip = _node_ip()

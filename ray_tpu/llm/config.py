@@ -206,6 +206,8 @@ def _served(cfg):
                              "n_layers) and exit by the gate's threshold"),
         ("C" in cfg.layer_pattern, "a gated short convolution's tail of conv_taps - 1 positions a slot "
                                    "and layer (models/sconv.py keeps none)"),
+        (cfg.hc_mult > 1, "a residual stream of hc_mult copies in the decode window (llm/model_runner.py adds a part's "
+                          "output to [B, S, d_model] itself; models/hyper.py's reading and writing are llama._block's)"),
     ) if has]
     if missing:
         raise NotImplementedError(

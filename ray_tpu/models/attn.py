@@ -8,10 +8,12 @@ serving programs (llm/model_runner.py) call the parts, `qkv_proj` and `attn_out`
 their own cache.
 """
 import contextlib
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import FLASH_NAMES, ROTATED_NAMES, Rotation
@@ -98,13 +100,51 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return (x * jax.lax.rsqrt(var + eps) * scale).astype(dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotate-half RoPE (HF Llama convention). x: [B, S, H, D], positions: [B, S]."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor (DeepSeek-V3's `yarn_get_mscale`): 1 + 0.1 mscale ln(factor), and 1 where nothing is scaled."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_range(cfg: ModelConfig, d: int) -> Tuple[int, int]:
+    """(low, high): the pairs of a rotated slice d wide between which YaRN blends, from the pair that turns
+    cfg.rope_beta_fast times over cfg.rope_original_len positions (below it theta's own frequencies stand) to the one
+    that turns cfg.rope_beta_slow times (above it they are divided by cfg.rope_factor)."""
+    def pair(turns):
+        return d * math.log(cfg.rope_original_len / (turns * 2 * math.pi)) / (2 * math.log(cfg.rope_theta))
+
+    return max(math.floor(pair(cfg.rope_beta_fast)), 0), min(math.ceil(pair(cfg.rope_beta_slow)), d - 1)
+
+
+def yarn(cfg: ModelConfig, d: int) -> Optional[Tuple[np.ndarray, float]]:
+    """(the d / 2 blended frequencies of a rotated slice d wide, what cos and sin are multiplied by) under YaRN, or
+    None where cfg.rope_factor scales nothing."""
+    if cfg.rope_factor == 1.0:
+        return None
+    low, high = yarn_range(cfg, d)
+    own = cfg.rope_theta ** (-np.arange(d // 2, dtype=np.float64) / (d // 2))
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    freqs = own * (1 - ramp) + own / cfg.rope_factor * ramp
+    return freqs.astype(np.float32), (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                                      / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+
+
+def softmax_scale(cfg: ModelConfig) -> Optional[float]:
+    """Attention's softmax scale where it is not head_dim^-1/2 (None): under YaRN times mscale(rope_mscale_all_dim)^2."""
+    if cfg.rope_factor == 1.0:
+        return None
+    return cfg.head_dim ** -0.5 * yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim) ** 2
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float, scaled: Optional[Tuple[np.ndarray, float]] = None) -> jax.Array:
+    """Rotate-half RoPE (HF Llama convention). x: [B, S, H, D], positions: [B, S]. `scaled` (`yarn`): the
+    frequencies in theta's place, and a factor on cos and sin."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2))
+    freqs = theta ** (-jnp.arange(0, d // 2, dtype=jnp.float32) / (d // 2)) if scaled is None else jnp.asarray(scaled[0])
     angles = positions[:, :, None].astype(jnp.float32) * freqs[None, None, :]  # [B,S,D/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scaled is not None and scaled[1] != 1.0:
+        cos, sin = cos * scaled[1], sin * scaled[1]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -150,6 +190,7 @@ def _latent_qkv(h: jax.Array, lp: dict, cfg: ModelConfig, positions: Optional[ja
     width."""
     dt = h.dtype
     nope, kvr = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    scaled = yarn(cfg, cfg.qk_rope_head_dim)
     with jax.named_scope("mla_q"):
         if "wq_a" in lp:
             cq = rms_norm(jnp.einsum("bsd,dr->bsr", h, _w(lp["wq_a"], dt)), lp["q_norm"], cfg.norm_eps)
@@ -157,12 +198,12 @@ def _latent_qkv(h: jax.Array, lp: dict, cfg: ModelConfig, positions: Optional[ja
         else:
             q = jnp.einsum("bsd,dhk->bshk", h, _w(lp["wq"], dt))
         if positions is not None:
-            q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)], -1)
+            q = jnp.concatenate([q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta, scaled)], -1)
     with jax.named_scope("mla_kv"):
         ckv = jnp.einsum("bsd,dr->bsr", h, _w(lp["wkv_a"], dt))
         k_rot = ckv[:, :, None, kvr:]  # [B, S, 1, rope]
         if positions is not None:
-            k_rot = rope(k_rot, positions, cfg.rope_theta)
+            k_rot = rope(k_rot, positions, cfg.rope_theta, scaled)
         kv = jnp.einsum("bsr,rhk->bshk", rms_norm(ckv[..., :kvr], lp["kv_norm"], cfg.norm_eps),
                         _w(lp["wkv_b"], dt))
         k = jnp.concatenate(
@@ -219,7 +260,8 @@ def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed=Fals
                 cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_len, axis=1)
                 new_kv = (ck, cv)
                 attn = attention(
-                    q, ck, cv, causal=True, q_offset=cache_len, kv_valid_len=cache_len + q.shape[1]
+                    q, ck, cv, causal=True, q_offset=cache_len, kv_valid_len=cache_len + q.shape[1],
+                    scale=softmax_scale(cfg),
                 )
             elif cfg.attention_impl in ("ring", "ulysses"):
                 # Sequence-parallel attention: activations stay seq-sharded over "sp"; KV chunks
@@ -250,6 +292,7 @@ def mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed=Fals
                                      rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
             else:
                 attn = attention(q, k, v, causal=True, segment_ids=segment_ids, impl=cfg.attention_impl,
+                                 scale=softmax_scale(cfg),
                                  shard_spec=auto_spec("batch", None, "act_heads", None), window=window,
                                  rotation=Rotation(positions, cfg.rope_theta, rope) if deferred else None)
         if "wo_gate" in lp:  # a channel of the output, from the layer's normed input

@@ -433,9 +433,41 @@ def _ouro_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+def _xing4_0_fields(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """The `xing4_0` keys (Xing4.0-29B-A4B) as ModelConfig fields: glm4_moe_lite's block (latent attention with a q
+    latent, leading dense layers, sigmoid-routed experts chosen by score + bias beside shared ones, served without
+    drops), its rotation under YaRN (`rope_scaling` of type yarn: this family's alone; every other family refuses it
+    as before), and around every part a manifold-constrained hyper-connection over `hc_mult` streams (the `hc_*` /
+    `mhc_*` keys: models/hyper.py). The MTP modules map (`mtp_depth`) and llama.mtp_logits refuses them over n streams.
+    What config.json does not state is benchmarks/configs/xing4.0-29b-a4b-train-tp8-ep8.json's `assumed`. Weights'
+    names are not mapped: the catalog gives keys, not tensor names."""
+    scaling = hf.get("rope_scaling")
+    clamp = (hf.get("mhc_h_res_clamp_min", -30), hf.get("mhc_h_res_clamp_max", 30))
+    refused = [what for has, what in (
+        (scaling is not None and (scaling.get("type", scaling.get("rope_type")) != "yarn"
+                                  or "original_max_position_embeddings" not in scaling),
+         f"rope_scaling {scaling!r} (yarn over original_max_position_embeddings is what maps)"),
+        (clamp[0] != -clamp[1] or clamp[1] <= 0,
+         f"a clamp of Hres' logits that is not symmetric (mhc_h_res_clamp_min / _max {clamp})"),
+        (hf.get("moe_layer_freq", 1) != 1, f"moe_layer_freq {hf.get('moe_layer_freq')}"),
+        (hf.get("scoring_func", "sigmoid") != "sigmoid", f"scoring_func {hf.get('scoring_func')!r}"),
+        (hf.get("hidden_act", "silu") != "silu", f"hidden_act {hf.get('hidden_act')!r}"),
+    ) if has]
+    if refused:
+        raise ValueError("xing4_0 as this config.json states it is not supported: " + "; ".join(refused))
+    yarn = {} if scaling is None else dict(
+        rope_factor=float(scaling["factor"]), rope_original_len=int(scaling["original_max_position_embeddings"]),
+        rope_beta_fast=float(scaling.get("beta_fast", 32)), rope_beta_slow=float(scaling.get("beta_slow", 1)),
+        rope_mscale=float(scaling.get("mscale", 1)), rope_mscale_all_dim=float(scaling.get("mscale_all_dim", 0)))
+    return dict(
+        _glm4_moe_lite_fields({**hf, "rope_scaling": None}), **yarn,
+        hc_mult=hf.get("hc_mult", 1), hc_sinkhorn_iters=hf.get("hc_sinkhorn_iters", 20),
+        hc_eps=float(hf.get("hc_eps", 1e-6)), hc_res_clamp=float(clamp[1]))
+
+
 # a family's keys as ModelConfig fields, by its `model_type` (config_from_hf)
-_FAMILY_FIELDS = {"ouro": _ouro_fields, "glm4_moe_lite": _glm4_moe_lite_fields, "nemotron_h": _nemotron_h_fields,
-                  "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields,
+_FAMILY_FIELDS = {"xing4_0": _xing4_0_fields, "ouro": _ouro_fields, "glm4_moe_lite": _glm4_moe_lite_fields,
+                  "nemotron_h": _nemotron_h_fields, "solar_open2": _solar_open2_fields, "lfm2_moe": _lfm2_moe_fields,
                   "afmoe": _afmoe_fields, "sdar_moe": _sdar_moe_fields, "kimi_linear": _kimi_linear_fields}
 
 

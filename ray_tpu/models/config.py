@@ -190,6 +190,27 @@ class ModelConfig:
     # the probability of leaving there, less `exit_entropy_weight` times that distribution's entropy.
     loop_steps: int = 1
     exit_entropy_weight: float = 0.0
+    # Hyper-connections (xing4_0's hc_mult; arXiv:2409.19606, manifold-constrained: mHC, arXiv:2512.24880): the
+    # residual stream is `hc_mult` copies of d_model channels a token, carried flat, [B, T, hc_mult x d_model]. A
+    # part reads a learned, input-dependent mixture of the copies and writes its output back through a second one,
+    # and the copies are mixed by a matrix a token that `hc_sinkhorn_iters` rounds of Sinkhorn-Knopp (column sums,
+    # then row sums, `hc_eps` in the denominators) make doubly stochastic; its logits are clipped to
+    # +-`hc_res_clamp` before the exponential (models/hyper.py). 1 is every other family's stream: x + F(x).
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # YaRN (arXiv:2309.00071, as DeepSeek-V3's latent attention applies it; `rope_scaling` of type yarn): the rotation's
+    # frequencies are blended between theta's own and theirs over `rope_factor` (1 = no scaling), by where a pair's
+    # wavelength lies between `rope_beta_fast` and `rope_beta_slow` turns over `rope_original_len` positions; cos and
+    # sin times mscale(rope_mscale) / mscale(rope_mscale_all_dim) and the softmax scale times mscale(rope_mscale_all_dim)^2
+    # (models/attn.py:yarn_mscale; 0 = none of the two).
+    rope_factor: float = 1.0
+    rope_original_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 0.0
+    rope_mscale_all_dim: float = 0.0
 
     def __post_init__(self):
         # JSON hands a list; the dataclass is a static (hashed) argument of jitted programs
@@ -227,6 +248,22 @@ class ModelConfig:
                 "single-part layers (_pattern_layers), pipeline stages (_pipeline_layers), a leading dense stack, "
                 "MTP modules, the block-diffusion objective or expert layers (their counters are a row a layer, "
                 "not a row a layer and recurrence)")
+        if self.hc_mult < 1 or self.hc_mult > 1 and (
+                self.layer_pattern or self.pipeline_stages > 1 or self.loop_steps > 1 or self.diffusion_block
+                or self.part_post_norm or self.hc_sinkhorn_iters < 1):
+            raise NotImplementedError(
+                f"a residual stream of hc_mult {self.hc_mult} copies: fewer than one, no round of the projection "
+                "(hc_sinkhorn_iters), or n streams over a pattern of single-part layers (_pattern_layers hands a part's "
+                "projection error out of no loop), across pipeline stages (_pipeline_layers: a stage hands on "
+                "[B, T, d_model]), under a looped stack (looped_outputs norms d_model channels between recurrences), "
+                "the block-diffusion objective or a norm behind each part")
+        if self.rope_factor != 1.0 and (
+                self.rope_factor < 1.0 or self.rope_original_len < 1 or not self.latent_attention
+                or not self.attention_rotation or self.attention_impl in ("ring", "ulysses")):
+            raise NotImplementedError(
+                f"YaRN (rope_factor {self.rope_factor}) under 1, without rope_original_len, on the ring / Ulysses paths "
+                "(ops/ring_attention.py takes no softmax scale) or outside rotated latent attention: the rotate kernel in "
+                "front of the flash kernels (ops/attention.py:Rotation) and the serving programs take theta alone")
         if self.mlp_activation not in ("silu_gated", "relu2"):
             raise ValueError(f"unknown mlp_activation {self.mlp_activation!r} (silu_gated | relu2)")
 
@@ -686,6 +723,48 @@ register_config(
         part_post_norm=True,
         loop_steps=4,
         exit_entropy_weight=0.05,
+    )
+)
+register_config(
+    # Toy of the xing4_0 family (Xing4.0-29B-A4B) for the CPU tests: glm-tiny's block (rotated latent attention with a q
+    # latent, a leading dense layer, sigmoid-routed experts beside a shared one) with v heads narrower than q's and k's,
+    # under YaRN (a factor of 8 over 32 positions: the tests' sequences run past them) and around it a residual stream
+    # of FOUR copies mixed by manifold-constrained hyper-connections; no MTP module. Everything held; tests cut shares
+    # of the heads and of the experts.
+    ModelConfig(
+        name="xing-tiny",
+        vocab_size=256,
+        d_model=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=160,
+        max_seq_len=128,
+        rope_theta=1e4,
+        norm_eps=1e-6,
+        dtype="float32",
+        q_lora_rank=48,
+        kv_lora_rank=32,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        n_experts=8,
+        moe_top_k=2,
+        moe_capacity_factor=0.0,
+        moe_aux_loss_coef=0.0,
+        d_ff_expert=48,
+        n_shared_experts=1,
+        n_dense_layers=1,
+        moe_scoring="sigmoid",
+        moe_route_scale=2.0,
+        moe_select_bias=True,
+        hc_mult=4,
+        rope_factor=8.0,
+        rope_original_len=32,
+        rope_beta_fast=4.0,
+        rope_beta_slow=0.5,
+        rope_mscale=1.0,
+        rope_mscale_all_dim=1.0,
     )
 )
 register_config(

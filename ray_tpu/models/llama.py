@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from ray_tpu.ops.quant import as_weight as _w
 from ray_tpu.parallel.sharding import with_sharding_constraint as wsc
 
-from . import attn, kda, moe, sconv, ssm
+from . import attn, hyper, kda, moe, sconv, ssm
 from .attn import attn_out, qkv_proj, rms_norm, rope, rope_pairs_to_halves  # noqa: F401  (llm/ calls them here)
 from .config import LAYER_KINDS, ModelConfig
 
@@ -116,13 +116,16 @@ def _layer_init(key: jax.Array, cfg: ModelConfig, mixer: Optional[str], ff: Opti
     if cfg.part_post_norm:  # a norm behind each part as well (`_onto`)
         out.update({leaf: jnp.ones((cfg.d_model,), jnp.float32)
                     for leaf, has in (("attn_post_norm", mixer), ("mlp_post_norm", ff)) if has})
+    if cfg.hc_mult > 1:  # each part reads and writes the n streams through a hyper-connection of its own (models/hyper.py)
+        for part, has in zip(hyper.PARTS, (mixer, ff)):
+            out.update(hyper.init(key, cfg, part) if has else {})
     return out
 
 
 @functools.lru_cache(maxsize=None)  # (an abstract `init` a call otherwise; callers copy, never write)
 def _layer_axes(cfg: ModelConfig, mixer: Optional[str], ff: Optional[str]) -> Params:
     """One layer's logical axes (no leading 'layer' axis): its parts', of the leaves their `init` makes."""
-    axes = {**dict.fromkeys(("mlp_norm", "attn_post_norm", "mlp_post_norm"), ("embed",)),
+    axes = {**dict.fromkeys(("mlp_norm", "attn_post_norm", "mlp_post_norm"), ("embed",)), **hyper.AXES,
             **(MIXERS[mixer][0].AXES if mixer else {}), **(FEED_FORWARD[ff][0].AXES if ff else {})}
     made = jax.eval_shape(functools.partial(_layer_init, cfg=cfg, mixer=mixer, ff=ff), jax.random.PRNGKey(0))
     return {leaf: axes[leaf] for leaf in made}
@@ -156,14 +159,15 @@ def n_params(cfg: ModelConfig) -> int:
     part's own count (beside its `init`), a norm a feed-forward part, one more behind every
     part under cfg.part_post_norm. Capacity-based experts count as the dense MLP they stand
     in for, as they always have. A looped stack's layers are counted once, however often they run, and
-    its exit gate is one weight a channel and a bias (one leaf)."""
+    its exit gate is one weight a channel and a bias (one leaf). Under cfg.hc_mult > 1 every part has a
+    hyper-connection beside it."""
     d = cfg.d_model
 
     def layer(mixer, ff):
         ff = "dense" if ff and not cfg.moe_dropless else ff
         return ((MIXERS[mixer][0].n_params(cfg) if mixer else 0)
                 + (FEED_FORWARD[ff][0].n_params(cfg) + d if ff else 0)
-                + d * cfg.part_post_norm * (bool(mixer) + bool(ff)))
+                + (d * cfg.part_post_norm + hyper.n_params(cfg) * (cfg.hc_mult > 1)) * (bool(mixer) + bool(ff)))
 
     return (cfg.vocab_size * d * (1 if cfg.tie_embeddings else 2) + d
             + sum(n * layer(mixer, ff) for n, mixer, ff in _layer_kinds(cfg).values())
@@ -342,15 +346,18 @@ def _onto(x: jax.Array, out: jax.Array, lp: Params, leaf: str, cfg: ModelConfig,
 
 
 def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
-                 token_mask: Optional[jax.Array] = None, constrain=_unconstrained):
+                 token_mask: Optional[jax.Array] = None, constrain=_unconstrained, onto=None):
     """Norm, the dense or MoE feed-forward (whichever the layer's parameters are),
     residual. Returns (x, aux): the capacity-based experts' load-balancing loss (a
     scalar, zero for a dense layer), or what the dropless layer counted and chose
     (moe.expert_layer: {"load": [E], "chosen": [B * S, k]}).
     token_mask [B, S] (1 = real) keeps pad tokens and inactive slots out of the
     experts' capacity; `constrain(array, *logical_axes)` is the caller's sharding
-    constraint on the dense product and on the result."""
+    constraint on the dense product and on the result. `onto(out)`: how the part's
+    output joins the stream where that is not x + out (`_block` under cfg.hc_mult > 1,
+    where x is the part's reading of n streams)."""
     dt = x.dtype
+    onto = onto or (lambda out: _onto(x, out, lp, "mlp_post_norm", cfg, constrain))
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     b, s, d = h.shape
     if "router" not in lp:
@@ -362,7 +369,7 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
             act = jnp.square(jax.nn.relu(jnp.einsum("bsd,df->bsf", h, _w(lp["w_up"], dt))))
         ff = constrain(act, "batch", "seq", "act_mlp")
         down = jnp.einsum("bsf,fd->bsd", ff, _w(lp["w_down"], dt))
-        return _onto(x, down, lp, "mlp_post_norm", cfg, constrain), jnp.zeros((), jnp.float32)
+        return onto(down), jnp.zeros((), jnp.float32)
     if cfg.moe_dropless:
         y2, aux = moe.expert_layer(h.reshape(b * s, d), lp, cfg)
     else:
@@ -371,7 +378,7 @@ def feed_forward(x: jax.Array, lp: Params, cfg: ModelConfig,
             lp["w_down"], cfg,
             mask=None if token_mask is None else token_mask.reshape(b * s),
         )
-    return _onto(x, y2.reshape(b, s, d), lp, "mlp_post_norm", cfg, constrain), aux
+    return onto(y2.reshape(b, s, d)), aux
 
 
 def _head(params: Params, normed: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -426,9 +433,16 @@ def _block(
     stream). Every family with a layer pattern holds one part a
     layer; the others attention and a feed-forward part in each: the decoder block.
     `windowed`: the attention is a `W` part's (config.LAYER_KINDS).
-    Returns (x, updated (k,v) if caching, moe aux loss)."""
+    Under cfg.hc_mult > 1 x is n streams [B, T, n d_model]: a part reads a mixture of them and
+    writes through its hyper-connection (models/hyper.py) in `_onto`'s place.
+    Returns (x, updated (k,v) if caching, moe aux loss; under cfg.hc_mult > 1 a pair of it and
+    the layer's largest projection error [2])."""
     new_kv, aux = None, jnp.zeros((), jnp.float32)
     part = next((part for part, _ in MIXERS.values() if part.LEAF in lp), None)
+    y, errs = x, []  # y: what a part reads of the stream (under cfg.hc_mult > 1 a mixture of its copies)
+    if part is not None and cfg.hc_mult > 1:
+        with jax.named_scope("attn"):
+            y, coef = _hc_read(x, lp, "attn", cfg, errs)
     if part is not None and part.RECURRENT:
         if segment_ids is not None or cache_kv is not None:
             raise NotImplementedError(
@@ -438,10 +452,13 @@ def _block(
         # `attn` is where the readers of the trace look for a layer's mixer
         # (benchmarks/metrics/train_scoped_pct.json, train_head_loss_pct.json)
         with jax.named_scope(part.SCOPE) if part.SCOPE else contextlib.nullcontext():
-            out = part.mixer(x, lp, cfg)
+            out = part.mixer(y, lp, cfg)
     elif part is not None:
-        out, new_kv = part.mixer(x, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed)
-    if part is not None:
+        out, new_kv = part.mixer(y, lp, cfg, positions, segment_ids, cache_kv, cache_len, windowed)
+    if part is not None and cfg.hc_mult > 1:
+        with jax.named_scope("attn"):
+            x = _hc_write(x, out, coef, cfg)
+    elif part is not None:
         # an attention part's residual counts with its output product, so that everything under `attn`
         # and no recurrent mixer's name carries one of attn.SCOPES
         piece = contextlib.nullcontext() if part.RECURRENT else jax.named_scope(attn.OUT_SCOPE)
@@ -449,8 +466,30 @@ def _block(
             x = _onto(x, out, lp, "attn_post_norm", cfg, wsc)
     if "mlp_norm" in lp:
         with jax.named_scope("mlp"):
-            x, aux = feed_forward(x, lp, cfg, token_mask, constrain=wsc)
+            if cfg.hc_mult > 1:
+                y, coef = _hc_read(x, lp, "mlp", cfg, errs)
+                x, aux = feed_forward(y, lp, cfg, token_mask, constrain=wsc,
+                                      onto=lambda out, x=x: _hc_write(x, out, coef, cfg))
+            else:
+                x, aux = feed_forward(x, lp, cfg, token_mask, constrain=wsc)
+    if cfg.hc_mult > 1:  # beside the part's own: how far the layer's projections ended from doubly stochastic
+        aux = (aux, jnp.stack(errs).max(0))
     return x, new_kv, aux
+
+
+def _hc_read(x: jax.Array, lp: Params, part: str, cfg: ModelConfig, errs: list):
+    """Under cfg.hc_mult > 1 (inside the part's scope): (what the part `part` of hyper.PARTS reads of the n streams
+    x [B, T, n C], its coefficients for `_hc_write`); the projection's error is appended to `errs`."""
+    with jax.named_scope(hyper.SCOPE):
+        coef, err = hyper.coefficients(x, lp[f"{part}_hc"], cfg)
+        errs.append(err)
+        return hyper.read(x, coef, cfg), coef
+
+
+def _hc_write(x: jax.Array, out: jax.Array, coef: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The part's output joins the n streams: `_onto`'s place under cfg.hc_mult > 1."""
+    with jax.named_scope(hyper.SCOPE):
+        return wsc(hyper.write(x, out, coef, cfg), "batch", "seq", "act_embed")
 
 
 def _pipeline_layers(
@@ -581,7 +620,8 @@ def _stacked_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
     dense stack, then `layers`): a layer's parameters and, when there is a cache (one stack
     only), its K/V (None is an empty pytree: the scan then carries no K/V in or out).
     Returns (x, the last stack's new K/V or None, the last stack's aux a layer: the expert
-    layers' where there are any)."""
+    layers' where there are any; under cfg.hc_mult > 1 a pair of it and every layer's largest
+    projection error)."""
     cache_len = None if cache is None else cache.length
 
     def body(h, xs):
@@ -590,11 +630,17 @@ def _stacked_layers(x, params: Params, cfg: ModelConfig, positions, segment_ids,
                                 cache_len, token_mask)
         return h, (new_kv, aux)
 
+    errs = []
     for name in _layer_kinds(cfg):
         with jax.named_scope(LAYER_LOOP):
             x, (new_kv, auxs) = jax.lax.scan(
                 _maybe_remat(body, cfg), x,
                 (params[name], None if cache is None else (cache.k, cache.v)))
+            if cfg.hc_mult > 1:
+                auxs, err = auxs
+                errs.append(err.max(0))
+    if cfg.hc_mult > 1:  # every stack's projection errors, the largest: (the last stack's aux, [2])
+        auxs = (auxs, jnp.stack(errs).max(0))
     return x, new_kv, auxs
 
 
@@ -641,7 +687,8 @@ def forward(
     dense configs) as a third element; for the dropless expert layer, which has no such
     loss, what each expert layer counted and chose and what the MTP modules go on from
     ({"load": [layers, E], "chosen": [layers, B * S, k], "hidden": the last block's
-    output before the final norm [B, S, D]}).
+    output before the final norm [B, S, D]}). Under cfg.hc_mult > 1 it is a dict either way, with `hc_err` [2] (the
+    largest distance of any Hres' row and column sums from 1) beside those or beside `aux_loss`, and `hidden` is n streams.
 
     Under cfg.diffusion_block `tokens` is the block-diffusion objective's doubled row
     [noised ; clean] with `positions` repeated (`block_diffusion_loss` builds both), and
@@ -663,11 +710,17 @@ def forward(
         raise NotImplementedError(
             f"the block-diffusion objective (cfg.diffusion_block) takes one doubled row [noised ; clean] of whole "
             f"blocks of {cfg.diffusion_block}, not {s} positions")
+    if cfg.hc_mult > 1 and cache is not None:
+        raise NotImplementedError(
+            f"a stream of hc_mult ({cfg.hc_mult}) copies under a KV cache: the serving programs (llm/model_runner.py) "
+            "add attention's output to [B, S, d_model] themselves and keep no stream of n copies a slot")
     if positions is None:  # one row, which every row of the batch shares
         start = cache.length if cache is not None else 0
         positions = jnp.arange(s)[None, :] + start
     with jax.named_scope("embed"):
         x = wsc(embed_tokens(params, tokens, cfg), "batch", "seq", "act_embed")
+        if cfg.hc_mult > 1:  # n streams from here to the head: [B, S, n d_model]
+            x = wsc(hyper.spread(x, cfg), "batch", "seq", "act_embed")
 
     new_cache = None
     if len(_layer_kinds(cfg)) > 1 and (cfg.pipeline_stages > 1 or cache is not None):
@@ -682,11 +735,18 @@ def forward(
                                         token_mask)
     else:
         x, new_kv, auxs = _stacked_layers(x, params, cfg, positions, segment_ids, token_mask, cache)
+        if cfg.hc_mult > 1:
+            auxs, hc_err = auxs
         # the last stack's: the expert layers' where there are any
         aux_total = dict(auxs, hidden=x) if cfg.moe_dropless else auxs.sum()
+        if cfg.hc_mult > 1:  # (a dict either way: the projections' error rides beside the experts' counters or the loss)
+            aux_total = dict(aux_total if cfg.moe_dropless else {"aux_loss": aux_total}, hc_err=hc_err)
         if cache is not None:
             new_cache = KVCache(k=new_kv[0], v=new_kv[1], length=cache.length + s)
 
+    if cfg.hc_mult > 1:  # the head reads the sum of the copies
+        with jax.named_scope("lm_head"):
+            x = hyper.gather(x, cfg)
     if head_rows is not None:
         with jax.named_scope("lm_head"), jax.named_scope("bd_rows"):
             x = x[:, :head_rows]
@@ -707,6 +767,10 @@ def mtp_logits(params: Params, hidden: jax.Array, tokens: jax.Array, cfg: ModelC
     that attention keeps the sequence's length and its kernel (S - m is no multiple of
     a tile); the last m, which look past the tokens, are cut before the head: returns
     [(logits [B, S - m, vocab], the block's aux)] a module."""
+    if cfg.hc_mult > 1:
+        raise NotImplementedError(
+            f"MTP modules over a stream of hc_mult ({cfg.hc_mult}) copies: xing4_0's config.json does not say how a "
+            "module's eh_proj reads the n streams, nor whether its block mixes them")
     s = hidden.shape[1]
     tokens = jnp.pad(tokens, ((0, 0), (0, max(0, s + cfg.mtp_depth - tokens.shape[1]))))
     positions = jnp.arange(s)[None, :]
@@ -893,6 +957,9 @@ def loss_fn(
     with jax.named_scope("loss"):
         ce = _cross_entropy(logits, tokens[:, 1:], mask[:, 1:])
     metrics = {"tokens": jnp.maximum(mask[:, 1:].sum(), 1.0)}
+    if cfg.hc_mult > 1:  # a projection that stopped converging shows in the step's metrics
+        metrics.update(hc_res_row_err=aux["hc_err"][0], hc_res_col_err=aux["hc_err"][1])
+        aux = aux if cfg.moe_dropless else aux["aux_loss"]
     if isinstance(aux, dict):  # the dropless layers' counters; no auxiliary loss: the selection bias balances (train/step.py)
         loss, load, chosen = ce, aux["load"], aux["chosen"]
     else:

@@ -350,7 +350,8 @@ def test_packed_documents_and_a_cache_are_refused_by_name(family):
         with pytest.raises(NotImplementedError, match="block-diffusion objective over packed documents"):
             jax.eval_shape(lambda p: llama.loss_fn(p, packed, cfg), p)
     refused = ("block-diffusion attention .* under a KV cache" if cfg.diffusion_block else
-               "a looped stack under a KV cache" if cfg.loop_steps > 1 else "KV cache over layers of more than one kind")
+               "a looped stack under a KV cache" if cfg.loop_steps > 1 else
+               "a stream of hc_mult .* copies under a KV cache" if cfg.hc_mult > 1 else "KV cache over layers of more than one kind")
     with pytest.raises(NotImplementedError, match=refused):
         jax.eval_shape(lambda p: llama.forward(p, t[:, :32], cfg, cache=llama.init_kv_cache(cfg, 2, 64)), p)
 

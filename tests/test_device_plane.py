@@ -406,3 +406,18 @@ def test_pd_disagg_unequal_pools_device_path(rt):
     ids, reshards = rt.get(dec_actor.decode.remote(pre, n_tokens), timeout=180)
     assert ids == want
     assert reshards == 1  # the pull really took the reshard path
+
+
+def test_a_plane_asked_for_before_any_session_is_off_for_now_not_for_the_process(monkeypatch):
+    """A first touch before any cluster session wrote its authkey (an engine's host-bytes handoff in a test that
+    starts no cluster) leaves the control channel off and asks again at the next call: latched, it took paged
+    handoff from every later file of an xdist worker (PR 62: tests/test_pd_paged.py, four cases, once)."""
+    from ray_tpu.core import device_plane
+    from ray_tpu.util.client import server
+
+    key = []
+    monkeypatch.setattr(server, "load_authkey", lambda: key[0] if key else None)
+    fresh = device_plane.DevicePlane()
+    assert not fresh.paged_available and fresh._control_disabled_reason is None and not fresh.available
+    key.append(b"k" * 32)
+    assert fresh.paged_available and fresh._control_disabled_reason is None
