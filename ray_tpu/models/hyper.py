@@ -23,20 +23,36 @@ a float32 [B, T, n, n] pads its 4 x 4 64-fold, and twenty rounds kept for a back
 the coefficients go to the two mixtures as one [B, T, 2n + n^2] array, whose columns a fusion broadcasts along C. No
 float32 array of the stream's size is stored: the product reads the bfloat16 stream and accumulates in float32, r is a
 reduction, a mixture is summed in float32 a channel and rounded once (tests/test_family_xing4_0.py holds the compiled step to it).
-The passes are XLA's fusions; which named scope each lies under is SCOPES.
+
+WHAT RUNS WHERE. A part goes through `enter` (the coefficients and its reading) and `write`. Where the shapes tile and
+GSPMD partitions nothing (`takes_kernels`: the training cell), the passes over the stream are ops/hyper_mix.py's four
+Pallas kernels, each reading the stream once and writing it at most once, positions minor as the compiled step holds it:
+`hc_read_fwd` (r, the coefficient product, Hpre and y in one pass) and `hc_read_bwd` under `hc_pre`, `hc_write_fwd` and
+`hc_write_bwd` under `hc_post`; XLA keeps what is [2n + n^2, B T] between them: Hpost and the logits (`hc_mix`) and the
+projection (`hc_sinkhorn`). `enter` hands x on for `write` to take, so that the writing's cotangent for x comes back to
+the entry's backward kernel as an operand and the stream's cotangent is summed in float32 and rounded ONCE; `spread`
+and `gather` repeat the embedding and sum the copies the positions minor too, behind a pinned turn of the ACTIVATION
+(59 MB, where XLA's layout assignment would turn the 235 MB stream), and y, the part's output and the two ends'
+activations are turned outside `hc`, so that the parts' own fusions do not read as the hyper-connection's. Elsewhere
+(a width that is no whole 128-lane tile, a partitioned program) the `jax.numpy` forms below run as XLA's fusions:
+`coefficients`, `read` and `_write`, the reference the kernels are tested against (tests/test_hyper_mix.py). Which named
+scope each equation lies under is SCOPES, by either path.
 """
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import hyper_mix
 from ray_tpu.ops.quant import as_weight as _w
+from ray_tpu.parallel.sharding import partitioned_by_gspmd
 
 from .config import ModelConfig
 
 # `hc` around every hyper-connection (inside its part's `attn` / `mlp`, or `embed` / `lm_head`), and inside it: the norm,
-# the product and the sigmoids; the projection; the part's reading (and the embedding's repeat); its writing (and the sum
-# in front of the head)
+# the product and the sigmoids (by the kernels' path: phi's cast and reshaping, Hpost and the logits); the projection; the
+# part's reading (and the embedding's repeat; by the kernels' path the entry's two kernels, which also make the norm, the
+# product and Hpre); its writing (and the sum in front of the head; the writing's two kernels)
 SCOPE, SCOPES = "hc", ("hc_mix", "hc_sinkhorn", "hc_pre", "hc_post")
 PARTS = ("attn", "mlp")  # a layer's parts by the scope each runs under: its leaf is `<part>_hc`
 AXES = {f"{part}_hc": (None, None) for part in PARTS}  # held whole: n C + 2 rows divide by no mesh axis, and it is 1.4 MB
@@ -72,14 +88,25 @@ def parts_of(hc: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
 
 def spread(x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """The embedding [B, T, C] as the first layer's stream: every copy the embedding."""
+    kernels = takes_kernels(x, cfg)
+    if kernels:  # the embedding turned (outside `hc`, as `enter` turns y), then repeated as the first layer's kernels take the stream: no pass turns the stream
+        x = hyper_mix.pinned(hyper_mix.turned(x))
     with jax.named_scope(SCOPE), jax.named_scope("hc_pre"):
+        if kernels:
+            return hyper_mix.turned(jnp.concatenate([x] * cfg.hc_mult, axis=1))
         return jnp.concatenate([x] * cfg.hc_mult, axis=-1)
 
 
 def gather(x: jax.Array, cfg: ModelConfig) -> jax.Array:
     """What the final norm and the head read of the last layer's stream: the sum of its copies, [B, T, C]."""
     with jax.named_scope(SCOPE), jax.named_scope("hc_post"):
-        return sum(c.astype(jnp.float32) for c in _copies(x, cfg)).astype(x.dtype)
+        if not takes_kernels(x, cfg):
+            return sum(c.astype(jnp.float32) for c in _copies(x, cfg)).astype(x.dtype)
+        # summed the positions minor, as the last layer's kernel wrote it
+        copies = hyper_mix.turned(x).reshape(x.shape[0], cfg.hc_mult, cfg.d_model, x.shape[1])
+        total = copies.astype(jnp.float32).sum(1)
+    # (rounded and turned outside `hc`: the final norm's fusions, forward and backward, take both into their bodies)
+    return hyper_mix.turned(hyper_mix.pinned(total.astype(x.dtype)))
 
 
 def _copies(x: jax.Array, cfg: ModelConfig):
@@ -127,14 +154,59 @@ def coefficients(x: jax.Array, hc: jax.Array, cfg: ModelConfig) -> Tuple[jax.Arr
         m = m.reshape(b * t, -1).T  # positions minor from here on
         alpha, bias = alpha.astype(jnp.float32), bias.astype(jnp.float32)[:, None]
         pre = jax.nn.sigmoid(alpha[0] * m[:n] + bias[:n])
-        post = 2 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + bias[n:2 * n])
-        logits = jnp.clip(alpha[2] * m[2 * n:] + bias[2 * n:], -cfg.hc_res_clamp, cfg.hc_res_clamp)
+        post, logits = _post_and_logits(m, alpha, bias, cfg)
     with jax.named_scope("hc_sinkhorn"):
-        res = sinkhorn(logits.reshape(n, n, b * t), cfg)
-        sums = jax.lax.stop_gradient(res)
-        err = jnp.stack([jnp.abs(sums.sum(1) - 1).max(), jnp.abs(sums.sum(0) - 1).max()])
-        coef = jnp.concatenate([pre, post, res.reshape(n * n, b * t)]).T.reshape(b, t, -1)
+        res, err = _projected(logits, cfg)
+        coef = jnp.concatenate([pre, post, res]).T.reshape(b, t, -1)
     return coef, err
+
+
+def _post_and_logits(m: jax.Array, alpha: jax.Array, bias: jax.Array, cfg: ModelConfig):
+    """m [2n + n^2, positions], the alphas [3] and the bias [2n + n^2, 1], float32 -> (Hpost [n, positions], Hres'
+    clipped logits [n^2, positions])."""
+    n = cfg.hc_mult
+    post = 2 * jax.nn.sigmoid(alpha[1] * m[n:2 * n] + bias[n:2 * n])
+    return post, jnp.clip(alpha[2] * m[2 * n:] + bias[2 * n:], -cfg.hc_res_clamp, cfg.hc_res_clamp)
+
+
+def _projected(logits: jax.Array, cfg: ModelConfig):
+    """Logits [n^2, positions] -> (Hres [n^2, positions] row-major, the projection's error [2])."""
+    n = cfg.hc_mult
+    res = sinkhorn(logits.reshape(n, n, -1), cfg)
+    sums = jax.lax.stop_gradient(res)
+    err = jnp.stack([jnp.abs(sums.sum(1) - 1).max(), jnp.abs(sums.sum(0) - 1).max()])
+    return res.reshape(n * n, -1), err
+
+
+def takes_kernels(x: jax.Array, cfg: ModelConfig) -> bool:
+    """Whether the passes over the stream x [B, T, n C] go to ops/hyper_mix.py's kernels: the shapes tile and no mesh
+    axis is GSPMD's to partition (a Pallas call there needs a `shard_map` around it)."""
+    return hyper_mix.supports(cfg.hc_mult, cfg.d_model, x.shape[1]) and not partitioned_by_gspmd()
+
+
+def enter(x: jax.Array, hc: jax.Array, cfg: ModelConfig):
+    """A part's entry (called inside the part's scope; `hc` is opened here): the stream x [B, T, n C] and the part's
+    leaf -> (y [B, T, C], what the part reads; its coefficients, for `write` alone to read; the projection's error [2];
+    x again, which `write` is to take: by the kernels' path the writing's cotangent for x then reaches the entry's
+    backward kernel as an operand)."""
+    with jax.named_scope(SCOPE):
+        if not takes_kernels(x, cfg):
+            coef, err = coefficients(x, hc, cfg)
+            return read(x, coef, cfg), coef, err, x
+        n = cfg.hc_mult
+        with jax.named_scope("hc_mix"):
+            phi, bias, alpha = parts_of(hc)
+            alpha, bias = alpha.astype(jnp.float32), bias.astype(jnp.float32)[:, None]
+            ab = jnp.concatenate([jnp.broadcast_to(alpha[0], (n, 1)), bias[:n]], axis=1)
+            phi = _w(phi, x.dtype)
+        with jax.named_scope("hc_pre"):
+            y, m, x = hyper_mix.read(x, phi, ab, n, cfg.norm_eps)
+        with jax.named_scope("hc_mix"):
+            post, logits = _post_and_logits(m, alpha, bias, cfg)
+        with jax.named_scope("hc_sinkhorn"):
+            res, err = _projected(logits, cfg)
+            coef = jnp.concatenate([post, res])  # [n + n^2, B T]: the rows the writing's kernels broadcast along C
+    return hyper_mix.turned(y), coef, err, x  # (the turn outside `hc`: the fusion that reads y through it is the part's)
 
 
 def read(x: jax.Array, coef: jax.Array, cfg: ModelConfig) -> jax.Array:
@@ -144,6 +216,17 @@ def read(x: jax.Array, coef: jax.Array, cfg: ModelConfig) -> jax.Array:
 
 
 def write(x: jax.Array, out: jax.Array, coef: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """The stream behind the part, [B, T, n C], from what `enter` handed on (x and coef) and the part's output
+    (called inside the part's scope; `hc` is opened here)."""
+    if not takes_kernels(x, cfg):
+        with jax.named_scope(SCOPE):
+            return _write(x, out, coef, cfg)
+    out = hyper_mix.turned(out)  # (outside `hc`: the product that writes it through the turn is the part's)
+    with jax.named_scope(SCOPE), jax.named_scope("hc_post"):
+        return hyper_mix.write(x, out, coef, cfg.hc_mult)
+
+
+def _write(x: jax.Array, out: jax.Array, coef: jax.Array, cfg: ModelConfig) -> jax.Array:
     """The stream behind the part, [B, T, n C]: copy i is sum_j Hres[i, j] X[j] + Hpost[i] out."""
     n = cfg.hc_mult
     with jax.named_scope("hc_post"):

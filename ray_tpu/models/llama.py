@@ -442,7 +442,7 @@ def _block(
     y, errs = x, []  # y: what a part reads of the stream (under cfg.hc_mult > 1 a mixture of its copies)
     if part is not None and cfg.hc_mult > 1:
         with jax.named_scope("attn"):
-            y, coef = _hc_read(x, lp, "attn", cfg, errs)
+            y, coef, x = _hc_read(x, lp, "attn", cfg, errs)
     if part is not None and part.RECURRENT:
         if segment_ids is not None or cache_kv is not None:
             raise NotImplementedError(
@@ -467,7 +467,7 @@ def _block(
     if "mlp_norm" in lp:
         with jax.named_scope("mlp"):
             if cfg.hc_mult > 1:
-                y, coef = _hc_read(x, lp, "mlp", cfg, errs)
+                y, coef, x = _hc_read(x, lp, "mlp", cfg, errs)
                 x, aux = feed_forward(y, lp, cfg, token_mask, constrain=wsc,
                                       onto=lambda out, x=x: _hc_write(x, out, coef, cfg))
             else:
@@ -479,17 +479,18 @@ def _block(
 
 def _hc_read(x: jax.Array, lp: Params, part: str, cfg: ModelConfig, errs: list):
     """Under cfg.hc_mult > 1 (inside the part's scope): (what the part `part` of hyper.PARTS reads of the n streams
-    x [B, T, n C], its coefficients for `_hc_write`); the projection's error is appended to `errs`."""
-    with jax.named_scope(hyper.SCOPE):
-        coef, err = hyper.coefficients(x, lp[f"{part}_hc"], cfg)
-        errs.append(err)
-        return hyper.read(x, coef, cfg), coef
+    x [B, T, n C], its coefficients and the stream for `_hc_write`: hyper.enter); the projection's error is appended
+    to `errs`."""
+    y, coef, err, x = hyper.enter(x, lp[f"{part}_hc"], cfg)
+    errs.append(err)
+    return y, coef, x
 
 
 def _hc_write(x: jax.Array, out: jax.Array, coef: jax.Array, cfg: ModelConfig) -> jax.Array:
     """The part's output joins the n streams: `_onto`'s place under cfg.hc_mult > 1."""
+    x = hyper.write(x, out, coef, cfg)
     with jax.named_scope(hyper.SCOPE):
-        return wsc(hyper.write(x, out, coef, cfg), "batch", "seq", "act_embed")
+        return wsc(x, "batch", "seq", "act_embed")
 
 
 def _pipeline_layers(

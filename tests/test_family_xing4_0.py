@@ -297,20 +297,37 @@ def test_yarn_stays_refused_for_the_family_whose_block_this_one_shares():
     assert config_from({**hf, "rope_scaling": None}).rope_factor == 1.0
 
 
-def test_every_equation_of_a_hyper_connection_carries_one_of_its_four_names_inside_its_parts():
+HC_KERNELS = {"hc_read_fwd": "hc_pre", "hc_read_bwd": "hc_pre", "hc_write_fwd": "hc_post", "hc_write_bwd": "hc_post"}  # a kernel's name -> the scope it runs under: that of what it writes
+
+
+@pytest.mark.parametrize("path", ["plain", "kernels"])
+def test_every_equation_of_a_hyper_connection_carries_one_of_its_four_names_inside_its_parts(path):
     """The jaxpr of the loss's gradient, as tests/test_attention_scopes.py reads it: whatever runs under `hc` carries
     exactly one of hyper.SCOPES and lies inside `attn`, `mlp`, `embed` or `lm_head`, forward, made again and backward;
-    the rotation under YaRN stays under `mla_q` / `mla_kv`; nothing of an attention part's five pieces is under `hc`."""
-    p = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), CFG))
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 33), jnp.int32)}
+    the rotation under YaRN stays under `mla_q` / `mla_kv`; nothing of an attention part's five pieces is under `hc`.
+    By both paths: the tiny configuration's 64 channels tile no kernel (`plain`: XLA's passes); at 128 channels and
+    128 positions the four kernels of ops/hyper_mix.py run (`kernels`), the entry's under `hc_pre`, the writing's under
+    `hc_post`, forward, made again and backward (the transpose carries the forward's names to the backward kernels)."""
+    cfg, positions = (CFG, 32) if path == "plain" else (dataclasses.replace(CFG, d_model=128), 128)
+    p = jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, positions + 1), jnp.int32)}
 
     def loss(p, batch):
         with jax.named_scope("model"):  # as train/step.py
-            return llama.loss_fn(p, batch, CFG)[0]
+            return llama.loss_fn(p, batch, cfg)[0]
 
     equations = list(_equations(jax.make_jaxpr(jax.grad(loss))(p, batch).jaxpr))
     under = [(prim, names) for prim, names in equations if hyper.SCOPE in names]
     assert len(under) > 500
+    calls = [names for prim, names in under if prim == "pallas_call"]
+    assert (path == "kernels") == bool(calls)
+    for kernel, scope in HC_KERNELS.items() if calls else ():
+        mine = [names for names in calls if names[-1] == kernel]
+        again = sum("rematted_computation" in names for names in mine)
+        # two stacks (the dense layer's, the expert layers'), two parts each; a layer's last writing is not made again
+        assert (len(mine) - again, again) == {"hc_read_fwd": (4, 4), "hc_write_fwd": (4, 2)}.get(kernel, (4, 0)), kernel
+        assert all(scope in names and ("transpose" in names) == (kernel.endswith("bwd") or "rematted_computation" in names)
+                   for names in mine), kernel
     assert not [e for e in under if len({n for n in e[1] if n in hyper.SCOPES}) != 1][:5]
     assert not [e for e in under if not {"attn", "mlp", "embed", "lm_head"} & set(e[1])][:5]
     assert not [e for e in under if set(e[1]) & set(attn.SCOPES)][:5]
@@ -319,21 +336,31 @@ def test_every_equation_of_a_hyper_connection_carries_one_of_its_four_names_insi
     for which, holds in passes.items():
         for part in hyper.PARTS:
             found = {n for _, names in under if holds(names) and part in names for n in names if n in hyper.SCOPES}
-            assert found == set(hyper.SCOPES), (which, part, found)
+            # (by the kernels a layer's last writing is not made again: its backward kernel reads x, o and the
+            # coefficients, not what it wrote)
+            spared = {"hc_post"} if (path, which, part) == ("kernels", "again", "mlp") else set()
+            assert found == set(hyper.SCOPES) - spared, (which, part, found)
     assert {n for _, names in under if "embed" in names for n in names if n in hyper.SCOPES} == {"hc_pre"}
     assert {n for _, names in under if "lm_head" in names for n in names if n in hyper.SCOPES} == {"hc_post"}
+    # the mixtures are sums, not products: no product under `hc` outside `hc_mix` or a kernel (the entry's make the
+    # coefficient product and its two transposes themselves)
     products = [names for prim, names in under if prim == "dot_general"]
-    assert products and all("hc_mix" in names for names in products)  # the mixtures are sums, not products
+    assert products and all("hc_mix" in names or {"hc_read_fwd", "hc_read_bwd"} & set(names) for names in products)
+    assert any("hc_mix" in names for names in products) == (path == "plain")
     turned = [names for prim, names in equations if prim in ("cos", "sin")]
     assert turned and all({"mla_q", "mla_kv"} & set(names) and "attn_in_proj" in names for names in turned)
     # every attention equation outside `hc` still carries exactly one of the part's five names
-    rest = [(prim, names) for prim, names in equations if "attn" in names and hyper.SCOPE not in names]
+    # (but the turns [B, T, C] <-> [B, C, T] of y and of the part's output around the kernels, which `hyper.enter` and
+    # `hyper.write` make outside `hc` so that the fusions that take them in stay the part's: changes of names, no passes)
+    rest = [(prim, names) for prim, names in equations if "attn" in names and hyper.SCOPE not in names
+            and not (path == "kernels" and prim == "transpose")]
     assert rest and not [e for e in rest if len({n for n in e[1] if n in attn.SCOPES}) != 1][:5]
 
 
 # ------------------------------------------------------------------- the cell's whole step, compiled for the chip
 
 STREAM = r"(?:1,)?8192,14336"
+MINOR = r"(?:1,)?(?:14336|4,3584),8192"  # the stream as ops/hyper_mix.py's kernels take it, the positions minor
 
 
 def _stored(text, pattern):
@@ -347,46 +374,69 @@ def _holds_the_streams_layouts(text):
     product's cotangent leaves its product in the stream's type: `hyper._product`); no array has the copies or the 4 x 4
     as its minor extents behind the positions (a bfloat16 [.., 8192, 4, 3584] tiles its 4 up to 16, a float32
     [.., 8192, 4, 4] pads 64-fold): the projection's rounds are [4, 4, 8192]."""
-    assert _stored(text, rf"bf16\[{STREAM}\]") and not _stored(text, rf"f32\[{STREAM}\]")
+    assert _stored(text, rf"bf16\[{STREAM}\]") and not _stored(text, rf"f32\[(?:{STREAM}|{MINOR})\]")
     assert not _stored(text, r"\w+\[(?:\d+,)*8192,4,(?:4|3584)\]")
     assert re.search(r"f32\[4,4,8192\]", text)
 
 
+HC_CALLS = {"hc_read_fwd": (2, 2), "hc_read_bwd": (2, 0), "hc_write_fwd": (2, 1), "hc_write_bwd": (2, 0)}  # (not again, again) a rematerialised layer
+
+
+def _passes_of_their_own(text):
+    """The `copy` and `transpose` instructions that store an array of the stream's extents, in whichever grouping, in a
+    pass over HBM of their own (outside fused computations): what a kernel that took the stream in another layout than
+    the step holds it in would cost, 0.73 ms a call (PERF.md section 6, PRs 61 and 63)."""
+    made = re.compile(rf"\s*(?:ROOT )?%[\w.\-]+ = \w+\[(?:{STREAM}|{MINOR})\]\S* (?:copy|transpose)\(")
+    return [ln[:200] for ln, in_fusion in _lines_by_fusion(text) if not in_fusion and made.match(ln)]
+
+
 def test_a_rematerialised_layer_of_the_cell_stores_no_float32_stream_and_keeps_positions_minor(family, one_chip, on_tpu):
-    """One expert layer of the cell under remat `full`, value and gradients, compiled for the described v5e (~30 s):
-    what the whole step (`-m slow`, below) holds of the stream's layouts, in tier-1."""
+    """One expert layer of the cell under remat `full`, value and gradients, compiled for the described v5e (~60 s):
+    what the whole step (`-m slow`, below) holds of the stream's layouts, in tier-1. The layer takes the stream and
+    hands back its cotangent the POSITIONS MINOR, as the step's loop over the layers carries them (a program's own
+    parameters and results are row-major, which the loop's carry is not): the four kernels of ops/hyper_mix.py run,
+    twice each and the forward ones again as the layer is made again (its last writing apart), and no array of the
+    stream's extents is copied or transposed in a pass of its own anywhere in the layer."""
     from compiled_step_text import shapes
 
     _, _, cfg = family.cell_config()
     lp = shapes(jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype),
                              jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg))["layers"]), one_chip)
-    x = jax.ShapeDtypeStruct((1, 8192, cfg.hc_mult * cfg.d_model), jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((1, cfg.hc_mult * cfg.d_model, 8192), jnp.bfloat16, sharding=one_chip)
     layer = llama._maybe_remat(lambda x, lp: llama._block(x, lp, cfg, jnp.arange(8192)[None], None)[0], cfg)
-    loss = lambda x, lp: jnp.sum(jnp.square(layer(x, lp).astype(jnp.float32)))  # noqa: E731
+    loss = lambda x, lp: jnp.sum(jnp.square(layer(x.transpose(0, 2, 1), lp).astype(jnp.float32)))  # noqa: E731
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(x, lp).compile().as_text()
     _holds_the_streams_layouts(text)
     assert kernel_calls(text, "flash_attention_fwd") == (1, 0) and not xla_remats(text)
+    assert {name: kernel_calls(text, name) for name in HC_CALLS} == HC_CALLS
+    assert not _passes_of_their_own(text)
 
 
 @pytest.mark.slow  # (the whole step, ~2 min of TPU compile: `-m slow -k cells_step`; tier-1 holds a layer of it to the same layouts. ROADMAP.md C13)
 def test_the_xing_cells_step_holds_the_streams_layouts_and_fits_the_chip(family, cell_step):
     """The whole step of the cell, compiled for the described v5e. Two bodies (the dense stack's layer, the expert
-    stack's): a flash call forward and one backward each, three router products.
+    stack's): a flash call forward and one backward each, three router products; the hyper-connections' four kernels
+    twice a body and the forward ones again where the layer is made again (20 / 10 / 15 / 10 calls a step over one
+    dense and four expert layers), and NO array of the stream's extents copied or transposed in a pass of its own (the
+    parent's step had two, at the head's end; the ends are made and summed the positions minor since PR 63).
 
-    Memory: `memory_analysis()` reads 7.87 + 9.62 = 17.50 GB, which is NOT what the chip holds: the compiler's own
-    buffer assignment of this program totals 14.43 GB (preallocated temporaries 6.41 GB), and on the chip the
-    allocator read 8.09 GB in use beside 6.42 GB reserved = 14.50 of 16.91 GB (15.75 GiB; my chip run, PR 62).
-    `temp_size_in_bytes` is held to what it read, as the other cells' records hold theirs: it moves when the program's
-    working set does."""
+    Memory: `memory_analysis()` reads 7.87 + 9.19 = 17.06 GB (9.62 before the kernels, PR 62), which is NOT what the
+    chip holds: the compiler's own buffer assignment of PR 62's program totalled 14.43 GB (preallocated temporaries
+    6.41 GB), and on the chip the allocator read 8.09 GB in use beside 6.42 GB reserved = 14.50 of 16.91 GB (15.75 GiB;
+    my chip run, PR 62). `temp_size_in_bytes` is held to what it read, as the other cells' records hold theirs: it moves
+    when the program's working set does."""
     cfg, text, memory = cell_step.cfg, cell_step.text, cell_step.memory
     assert cfg.remat and cfg.remat_policy == "full" and cfg.hc_mult == 4 and cell_step.trainer["mesh"] is None
     _holds_the_streams_layouts(text)
     bodies = 2
+    # 20 / 10 / 15 / 10 calls a step over the two bodies (one dense layer, four expert layers)
+    assert {name: kernel_calls(text, name) for name in HC_CALLS} == {name: (bodies * a, bodies * b) for name, (a, b) in HC_CALLS.items()}
     assert kernel_calls(text, "flash_attention_fwd") == (bodies, 0) and kernel_calls(text, "flash_attention_bwd_dkv_dq") == (bodies, 0)
     assert len(instructions(text, "convolution", "moe_router")) == 3 and not instructions(text, "while", "moe_router")
     assert not xla_remats(text) and cell_step.fallbacks == 0
     assert abs(memory.argument_size_in_bytes - 12 * cfg.n_params) < 1e7
-    assert memory.temp_size_in_bytes < (9.62 + 0.15) * 1e9
+    assert memory.temp_size_in_bytes < (9.19 + 0.15) * 1e9
+    assert not _passes_of_their_own(text)
     assert family.cell_step is None
 
 
