@@ -26,6 +26,11 @@ program read 0.848). Anywhere else `_conv_silu_norm` runs, the plain form: a flo
 copy of q|k|v padded by the taps, the taps' shifted products, silu, the norms and the casts,
 a pass of XLA's each (tier-1's width of 16; what the kernels are tested against).
 
+The fourth line is handed to the scan as what it is made of (`ops.kda.LogDecay`: the low-rank
+product in the activation's type, dt_bias, A_log): where the scan's kernels run, the one that sums g
+over a chunk computes it too, from the product as XLA wrote it, and g crosses HBM in no forward pass
+(ops/kda_prefix.py); anywhere else `kda_prefix.log_decay` runs, that line as it stands.
+
 Leaves: kda_norm [D], kda_qkv [D, 3, H, K], kda_conv [taps, 3, H, K] (the last tap is the
 current position's), kda_f_down [D, r], kda_f_up [r, H, K], kda_dt_bias [H, K], kda_A_log
 [H], kda_beta [D, H], kda_g_down [D, r], kda_g_up [r, H, K], kda_o_norm [K], kda_out
@@ -142,7 +147,7 @@ def mixer(x: jax.Array, lp, cfg: ModelConfig) -> jax.Array:
             q, k, v = _conv_silu_norm(qkv, conv_w, width)
     with jax.named_scope("kda_scan"):
         q, k, v = (a.reshape(bsz, t, h, width) for a in (q, k, v))
-        g = -jnp.exp(lp["kda_A_log"])[:, None] * jax.nn.softplus(decay.astype(f32) + lp["kda_dt_bias"])
+        g = kda.LogDecay(decay, lp["kda_dt_bias"], lp["kda_A_log"])  # made where the scan sums it
         beta = jax.nn.sigmoid(beta.astype(f32)) * (2.0 if cfg.kda_neg_eigval else 1.0)
         o = kda.kda_scan(q, k, v, g, beta, cfg.kda_chunk)
     with jax.named_scope("kda_norm_gate"):

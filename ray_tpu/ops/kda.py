@@ -50,7 +50,7 @@ Where they are made decides what is alive: `overlaps` hands a chunk to the two P
 of ops/kda_overlaps.py wherever they tile it (`kda_overlaps.supports`: channels in whole
 128-lane registers, sub-chunks in whole registers of 8 rows; the Solar-Open2 and Kimi-Linear
 cells' 128 / 32 / 128), a chunk of up to 8 heads a grid step (`kda_overlaps._per_step`, the
-rule the second half's and the walk's kernels share), and then a chunk's differences, decayed keys and
+rule the running sum's, the second half's and the walk's kernels share), and then a chunk's differences, decayed keys and
 factors live and die in fast memory in both passes and the backward pass keeps q, k and G
 alone. The kernels cut the pairs inside a sub-chunk once more, the same sums under the same
 bound: a pair of two BLOCKS of 8 positions of one sub-chunk goes through a second reference,
@@ -98,19 +98,33 @@ their gradients there; only the `jax.numpy` forms take copies with the chunks le
 M, N lead with the chunks, [chunks, B, H, ., .]: the walk's kernels read a chunk of a few heads
 out of them as the parts' kernels wrote it.
 
-What is left outside the six kernels is plain `jax.numpy`, differentiated by JAX: the running
-sum G (made once a scan in the mixer's order of the positions, transposed once and handed to both
-halves), the strict mask and beta over the keys' overlaps and the inverse (the compiler's
-substitution, above). Every operand anywhere is float32 and every product, XLA's or a kernel's,
-runs at the highest matrix precision. PERF.md section 5 has the trace.
+The running sum G is made the same way: `running_sum` hands g to the two Pallas kernels of
+ops/kda_prefix.py wherever `takes_kernels` says so (the one rule of all four pairs). The forward
+kernel reads a chunk of a few heads out of [B x H, T, K], a head's positions together (the order XLA
+gives the decay's product: g is the one operand the scan does NOT read in the mixer's order), sums
+the chunk's positions by shifted adds in fast memory and writes G with the chunks leading, where the
+overlaps' and the parts' kernels read it; where the mixer hands over what g is made of (`LogDecay`:
+its product, dt_bias and A_log) the kernel computes g too, and g crosses HBM in no forward pass. G
+comes back under two names, one a half, so that the halves' cotangents reach the backward kernel
+apart: it adds them, sums from the chunk's end and writes dg a head's positions together. Any other
+shape, and any shape under a mesh, runs `jnp.cumsum` over the positions in the mixer's order and a
+transposed copy with the chunks leading (XLA's `reduce-window`, differentiated by JAX): the same sum
+in another order, and what the kernels are tested against.
+
+What is left outside the eight kernels is plain `jax.numpy`, differentiated by JAX: the strict
+mask and beta over the keys' overlaps and the inverse (the compiler's substitution, above). Every
+operand anywhere is float32 and every product, XLA's or a kernel's, runs at the highest matrix
+precision. PERF.md section 5 has the trace.
 """
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.parallel.sharding import partitioned_by_gspmd
 
-from . import kda_overlaps, kda_parts, kda_walk
+from . import kda_overlaps, kda_parts, kda_prefix, kda_walk
 
 _HI = jax.lax.Precision.HIGHEST
 _SUB = 32  # positions of a sub-chunk: the differences are [_SUB, _SUB, K] a sub-chunk
@@ -130,6 +144,29 @@ def takes_kernels(size: int, width: int) -> bool:
 def _lead(x):
     """[B, chunks, Q, H, ...] (the positions in the mixer's order, cut in chunks) -> [chunks, B, H, Q, ...]."""
     return x.transpose(1, 0, 3, 2, *range(4, x.ndim))
+
+
+class LogDecay(NamedTuple):
+    """g = -exp(A_log) softplus(decay + dt_bias) (`kda_prefix.log_decay`) as what the mixer makes it from: its product
+    decay [B, T, H, K], dt_bias [H, K] and A_log [H]. `kda_scan` takes it in g's place, and the running sum's kernel
+    then makes g where it sums it."""
+    decay: jax.Array
+    dt_bias: jax.Array
+    a_log: jax.Array
+
+
+def running_sum(g, chunk: int):
+    """G [chunks, B, H, Q, K], the running sum of g [B, T, H, K] (or a `LogDecay`) over every chunk's Q positions, TWICE
+    (one value under two names, the overlaps' and the parts': ops/kda_prefix.py has why): by the Pallas kernels where
+    they tile the shape (they read g, or what it is made from, as XLA holds the decay's product and write G where it
+    is read), else by `jnp.cumsum` and a transposed copy."""
+    made_of = tuple(g) if isinstance(g, LogDecay) else (g.astype(jnp.float32),)
+    bsz, t, h, width = made_of[0].shape
+    if takes_kernels(chunk, width):
+        return kda_prefix.prefix(made_of, chunk)
+    g = kda_prefix.log_decay(*made_of) if isinstance(g, LogDecay) else made_of[0]
+    run = _lead(jnp.cumsum(g.reshape(bsz, t // chunk, chunk, h, width), axis=2))
+    return run, run
 
 
 def overlaps(q, k, run):
@@ -267,11 +304,10 @@ def _walk(p, o0, m, n):
     return o.transpose(1, 0, 3, 2, 4)
 
 
-def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.Array,
-             chunk: int) -> jax.Array:
+def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g, beta: jax.Array, chunk: int) -> jax.Array:
     """q, k, v [B, T, H, K] (q scaled, k of unit length: the caller's), g [B, T, H, K]
-    (<= 0: the log of a channel's decay), beta [B, T, H] -> o [B, T, H, K] in float32: the
-    recurrence's output from a zero state."""
+    (<= 0: the log of a channel's decay) or the `LogDecay` it is made from, beta [B, T, H] -> o [B, T, H, K]
+    in float32: the recurrence's output from a zero state."""
     bsz, t, h, width = q.shape
     if t % chunk:
         raise ValueError(f"sequence length {t} is not a multiple of the scan's chunk {chunk}")
@@ -283,9 +319,9 @@ def kda_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array, beta: jax.A
 
     # q, k, v stay in the mixer's order, a position's heads side by side: the kernels read a chunk of a
     # head out of it, and only `_decayed_overlaps` and `_chunk_parts` take [chunks, B, H, Q, K] copies.
-    # G is summed in that order too (a head's channels a register a position) and then leads with the
-    # chunks, as beta and what the halves hand on do
-    q, k, v, run, beta = split(q), split(k), split(v), _lead(jnp.cumsum(split(g), axis=2)), _lead(split(beta))
+    # G leads with the chunks, as beta and what the halves hand on do; each half gets it under a name of its own
+    q, k, v, beta = split(q), split(k), split(v), _lead(split(beta))
+    run, run_again = running_sum(g, chunk)
     a, b = _overlaps(q, k, run, beta)
-    p, o0, m, n = chunk_parts(q, k, v, run, beta, _unit_lower_inverse(a), b)
+    p, o0, m, n = chunk_parts(q, k, v, run_again, beta, _unit_lower_inverse(a), b)
     return walk(p, o0, m, n).reshape(bsz, t, h, width)

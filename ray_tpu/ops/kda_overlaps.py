@@ -62,8 +62,8 @@ rule).
 `supports` says which shapes the kernels tile; off a TPU they run in Pallas' interpreter
 (`flash_attention._interpret`'s rule). The scan's second half, which reads b and the inverse
 made from kk, is two kernels of the same kind beside these (ops/kda_parts.py), and the walk
-over the chunks behind it two more (ops/kda_walk.py); `supports` routes all three pairs,
-through `kda.takes_kernels`.
+over the chunks behind it two more (ops/kda_walk.py), and G itself two in front (ops/kda_prefix.py);
+`supports` routes all four pairs, through `kda.takes_kernels`.
 """
 import functools
 

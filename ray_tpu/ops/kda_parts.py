@@ -22,10 +22,10 @@ q, k and v are read where the mixer wrote them, [B, chunks x Q, H x K] with a po
 heads side by side (`kda_overlaps.rows_block`: a step's block is the chunk's Q rows of its
 heads' lanes), and dq, dk, dv are written there: XLA makes no [chunks, B, H, Q, K] copy of
 them or of their gradients for either half's kernels (five transposes a pass before). G
-comes with the chunks leading, as beta, T and b do: it is summed by XLA in the mixer's
-order and transposed once, which the compiler does well; summed or reverse-summed behind a
-reshape to [.., Q, H x K] its `reduce-window` took 1.9 ms a call where this takes 0.1
-(PERF.md section 6, PR 51).
+comes with the chunks leading, as beta, T and b do: ops/kda_prefix.py's kernel writes it so
+(PR 64; before it XLA summed it in the mixer's order and transposed it once; summed or
+reverse-summed behind a reshape to [.., Q, H x K] its `reduce-window` took 1.9 ms a call where
+that took 0.1: PERF.md section 6, PR 51).
 
 The backward kernel keeps nothing but the inputs: it makes [W | U0] again (one product) and
 from dP, dO0, dM, dN writes dq, dk, dv, dG, dbeta, dT, db with six products more. With
@@ -46,7 +46,7 @@ diagonal of a [Q, Q] select and one sum (exact: a value plus zeros).
 VMEM a grid step: forward 10 blocks of 64 KB a head at 128 x 128 (q, k, v, G, T, b in, P,
 O0, M, N out) and beta's row, twice for the pipeline's two buffers: 1.3 MB a head; backward
 16 blocks (the four cotangents in, six gradients and dbeta's row out), 2.1 MB.
-`kda_overlaps._per_step` (beside `rows_block`: one rule for all six kernels) takes as many of a
+`kda_overlaps._per_step` (beside `rows_block`: one rule for all eight kernels) takes as many of a
 chunk's heads a step as `_VMEM_BLOCKS` allows (4 at 128 x 128).
 
 `kda_overlaps.supports` says which shapes go to the kernels (ops/kda.py's `takes_kernels`

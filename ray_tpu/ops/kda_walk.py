@@ -35,9 +35,9 @@ holds the block and writes it there (64 KB a chunk and head), so no XLA transpos
 
 VMEM a grid step: forward 6 blocks of 64 KB a head at 128 x 128 (P, O0, M, N in, o and S_c out), backward
 8 (d o, P, M, S_c in, d P, d O0, d M, d N out), twice for the pipeline's two buffers, and the states'
-scratch: 8 heads a step in both passes by `_per_step`'s rule, the one all six of the scan's kernels share.
+scratch: 8 heads a step in both passes by `_per_step`'s rule, the one all eight of the scan's kernels share.
 
-`kda_overlaps.supports` says which shapes go to the kernels (ops/kda.py's `takes_kernels` routes all three
+`kda_overlaps.supports` says which shapes go to the kernels (ops/kda.py's `takes_kernels` routes all four
 pairs by it); off a TPU they run in Pallas' interpreter.
 """
 import jax

@@ -616,6 +616,10 @@ def test_a_family_cells_step_scores_once_a_layer_and_fits_as_before(family, cell
         assert kernel_calls(text, "kda_walk_fwd") == (parts, parts)
         assert kernel_calls(text, "kda_walk_bwd") == (parts, 0)
         assert not instructions(text, "while", "kda_scan")
+        # and the running sum G (PR 64): no `reduce-window` under the scan in any pass
+        assert kernel_calls(text, "kda_prefix_fwd") == (parts, parts)
+        assert kernel_calls(text, "kda_prefix_bwd") == (parts, 0)
+        assert not instructions(text, "reduce-window", "kda_scan")
         # the convolution, silu and norms of q, k and v: ONE call a part and pass whatever the three
         # (9 a step; a call for each of q, k, v was 27, and 3 s of every first step: PR 44), and the
         # plain form's float32 copy of q|k|v padded by the taps is gone with its shifted products

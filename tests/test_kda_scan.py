@@ -1,6 +1,6 @@
-"""The gated delta rule in chunks (ops/kda.py) and its six Pallas kernels (the overlaps',
-ops/kda_overlaps.py, the chunks' four matrices', ops/kda_parts.py, and the walk over the chunks',
-ops/kda_walk.py, in the interpreter here) against the recurrence a position at a time (the
+"""The gated delta rule in chunks (ops/kda.py) and its eight Pallas kernels (the running sum's,
+ops/kda_prefix.py, the overlaps', ops/kda_overlaps.py, the chunks' four matrices', ops/kda_parts.py, and the
+walk over the chunks', ops/kda_walk.py, in the interpreter here) against the recurrence a position at a time (the
 solar_open2 reference's), at a small size on the CPU; and that the shape alone says which path runs."""
 import jax
 import jax.numpy as jnp
@@ -111,10 +111,10 @@ def _pallas_calls(fn, *args):
 @pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2",
                                     "below_minus_87_a_sub_chunk"])
 def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, t, b, h, monkeypatch):
-    """At a width of 128 both halves and the walk over the chunks go to their Pallas kernels
-    (ops/kda_overlaps.py, ops/kda_parts.py and ops/kda_walk.py, in the interpreter here): the scan's
-    output and the gradient of q, k, v, g and beta against the same scan with `_decayed_overlaps`,
-    `_chunk_parts` and `_walk` in their place and against the recurrence a position at a time, 2 to
+    """At a width of 128 the running sum, both halves and the walk over the chunks go to their Pallas kernels
+    (ops/kda_prefix.py, ops/kda_overlaps.py, ops/kda_parts.py and ops/kda_walk.py, in the interpreter here): the
+    scan's output and the gradient of q, k, v, g and beta against the same scan with `_decayed_overlaps`,
+    `_chunk_parts` and `_walk` in their place (given the same G) and against the recurrence a position at a time, 2 to
     8 heads (2 to 8 of them a grid step), 1 to 8 chunks (the state the walk's kernels carry in fast
     memory against the recurrence's own), sub-chunks of 8 (one diagonal block each: no second
     reference) and 32.
@@ -133,14 +133,18 @@ def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, 
     shape = (chunk, sub, t, b, h)
     if ("kernels", *shape) not in _PROGRAMS:
         assert sorted(_pallas_calls(jax.grad(lambda *a: jnp.sum(scan(*a)), argnums=(0, 1, 3)), *args)) == [
-            "kda_overlaps_bwd", "kda_overlaps_fwd", "kda_parts_bwd", "kda_parts_fwd", "kda_walk_bwd", "kda_walk_fwd"]
+            "kda_overlaps_bwd", "kda_overlaps_fwd", "kda_parts_bwd", "kda_parts_fwd", "kda_prefix_bwd", "kda_prefix_fwd",
+            "kda_walk_bwd", "kda_walk_fwd"]
     cot = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
     got, mine = _value_and_pull(scan, args, cot, key=("kernels", *shape))
     want, theirs = _value_and_pull(ref.recurrence, args, cot, key=("recurrence", t, b, h))
     with monkeypatch.context() as m:
         m.setattr(kda_op.kda_overlaps, "supports", lambda *shape: False)
+        # G is the kernels' own on both sides: summed in another order it is a rounding of |G| apart, which the
+        # decays carry into o (1.3e-5 of the largest entry at chunks of 128); the test below holds it to `jnp.cumsum`
+        m.setattr(kda_op, "running_sum", lambda g, chunk: kda_op.kda_prefix.prefix((g,), chunk))
         if ("plain", *shape) not in _PROGRAMS:
-            assert not _pallas_calls(scan, *args)
+            assert _pallas_calls(scan, *args) == ["kda_prefix_fwd"]
         plain, plains = _value_and_pull(scan, args, cot, key=("plain", *shape))
     assert np.isfinite(np.asarray(got)).all()
     scale = float(jnp.abs(want).max())
@@ -151,6 +155,70 @@ def test_the_kernel_path_is_the_jnp_path_and_the_recurrence(regime, chunk, sub, 
         top = float(jnp.abs(theirs).max())
         np.testing.assert_allclose(x, same, atol=1e-5 * top + 1e-6, err_msg=name)
         np.testing.assert_allclose(x, theirs, atol=3e-5 * top + 1e-6, err_msg=name)
+
+
+def _decay_inputs(t, regime, b, h, seed=0):
+    """A mixer's product, dt_bias and A_log whose `log_decay` lies in `_scan_inputs`' range of the regime: the
+    product in bfloat16 as the cells hold it, the bias the inverse softplus of a draw of -g a channel, A_log 0."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    minus_g = -_scan_inputs(t, regime, seed=seed, b=b, h=h, width=128)[3]
+    middle = jnp.exp(jnp.mean(jnp.log(minus_g), axis=(0, 1)))  # [H, K]
+    bias = middle + jnp.log(-jnp.expm1(-middle))
+    wanted = minus_g + jnp.log(-jnp.expm1(-minus_g))  # softplus(wanted) == -g
+    a_log = jnp.log(jax.random.uniform(ks[0], (h,), minval=0.5, maxval=2.0))
+    return kda_op.LogDecay((wanted - bias).astype(jnp.bfloat16), bias, a_log)
+
+
+@pytest.mark.parametrize("chunk,h,b", [
+    (32, 1, 1), (32, 2, 2),  # 1 and 2 heads a grid step; two rows of a batch, which the blocks find a head's positions together
+    (128, 8, 1), (32, 8, 2),  # 8 heads a step, at the cells' chunk and out of two rows of a batch
+])
+@pytest.mark.parametrize("regime", ["near_one", "below_minus_100_a_chunk", "mixed", "beta_near_2"])
+@pytest.mark.parametrize("made_of", ["g", "decay"])
+def test_the_running_sums_kernels_are_cumsum(made_of, regime, chunk, h, b):
+    """ops/kda_prefix.py's two kernels (in the interpreter here) against `_lead(jnp.cumsum(..))` differentiated by
+    JAX and against a float64 running sum: G under both of its names and the pull-back of two DISTINCT cotangents
+    (the overlaps' and the parts': the rule gets them apart and adds them itself), each within 1e-6 of the largest
+    entry and no further from the float64 sum than `jnp.cumsum` is, times two, or than sqrt(Q) roundings of the
+    largest entry. From g itself, and from what the mixer makes it of (`LogDecay`: the forward kernel computes
+    `log_decay` where it sums it, the backward rule is the kernel and then that expression's own): there every
+    gradient, the bfloat16 product's, dt_bias' and A_log's, against the plain expression's under `jnp.cumsum`."""
+    t = 3 * chunk
+    if made_of == "decay":
+        args, g_of, given = tuple(_decay_inputs(t, regime, b, h)), kda_op.kda_prefix.log_decay, kda_op.LogDecay
+    else:
+        args, g_of, given = (_scan_inputs(t, regime, b=b, h=h, width=128)[3],), (lambda g: g), (lambda g: g)
+    g = g_of(*args)
+    sums = lambda *a: kda_op.running_sum(given(*a), chunk)  # noqa: E731
+    assert kda_op.takes_kernels(chunk, 128)
+    shape = (made_of, chunk, h, b)
+    if ("prefix", *shape) not in _PROGRAMS:
+        assert _pallas_calls(sums, *args) == ["kda_prefix_fwd"]
+        assert _pallas_calls(lambda *a: jax.vjp(sums, *a)[1], *args) == ["kda_prefix_fwd"]
+    plain = lambda *a: (kda_op._lead(jnp.cumsum(g_of(*a).reshape(b, 3, chunk, h, 128), axis=2)),) * 2  # noqa: E731
+    cots = tuple(jax.random.normal(key, (3, b, h, chunk, 128)) for key in jax.random.split(jax.random.PRNGKey(9)))
+    got, mine = _value_and_pull(sums, args, cots, key=("prefix", *shape))
+    want, theirs = _value_and_pull(plain, args, cots, key=("plain prefix", *shape))
+    lead = lambda x: x.transpose(1, 0, 3, 2, 4)  # noqa: E731
+    exact = lead(np.cumsum(np.asarray(g, np.float64).reshape(b, 3, chunk, h, 128), axis=2))
+    both = lead(np.asarray(cots[0], np.float64) + np.asarray(cots[1], np.float64))  # back to [B, chunks, Q, H, K]
+    exact_pull = np.flip(np.cumsum(np.flip(both, 2), axis=2), 2).reshape(b, t, h, 128)
+    np.testing.assert_array_equal(got[0], got[1])
+    pairs = [("G", got[0], want[0], exact)]
+    if made_of == "g":
+        pairs.append(("dg", mine[0], theirs[0], exact_pull))
+    for name, x, y, true in pairs:
+        assert x.shape == y.shape == true.shape and np.isfinite(np.asarray(x)).all(), name
+        top = float(np.abs(true).max())
+        np.testing.assert_allclose(x, y, atol=1e-6 * top, rtol=0, err_msg=name)
+        # (a float32 sum of Q terms in another order)
+        assert np.abs(x - true).max() <= max(2 * np.abs(y - true).max(), chunk**0.5 * 6e-8 * top), name
+    if made_of == "decay":
+        for name, x, y in zip(("decay", "dt_bias", "A_log"), mine, theirs):
+            assert x.shape == y.shape and x.dtype == y.dtype and np.isfinite(np.asarray(x, np.float32)).all(), name
+            x, y = np.asarray(x, np.float32), np.asarray(y, np.float32)
+            # (the product's gradient is rounded to bfloat16 on both sides: a rounding of the two float32 sums apart)
+            np.testing.assert_allclose(x, y, atol=(2**-7 if name == "decay" else 2e-6) * float(np.abs(y).max()), rtol=0, err_msg=name)
 
 
 def _parts_inputs(t, regime, chunk, seed=0, b=1):
@@ -251,7 +319,7 @@ def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
     q, k, v, _, beta = _scan_inputs(64, "mixed", b=1, h=4, width=128)
     g = jnp.full(q.shape, -3000.0).at[:, ::5].set(-1e-3)
     assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, 32), q, k, v, g, beta) == [
-        "kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"]
+        "kda_prefix_fwd", "kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"]
     want = ref.recurrence(q, k, v, g, beta)
     got, grads = jax.value_and_grad(lambda *a: jnp.sum(kda_op.kda_scan(*a, 32) * want), argnums=(0, 1, 2, 3, 4))(
         q, k, v, g, beta)
@@ -273,20 +341,21 @@ def test_no_decay_is_the_exponential_of_a_positive_number_in_the_kernels():
 ])
 def test_the_shape_alone_says_which_path_runs(chunk, width, sub, kernels, monkeypatch):
     """`kda.takes_kernels` reads the chunk and the width (and the sub-chunk they imply); the
-    scan's jaxpr holds both halves' and the walk's forward kernels exactly where it says so. Nobody sets it."""
+    scan's jaxpr holds the running sum's, both halves' and the walk's forward kernels exactly where it says so.
+    Nobody sets it."""
     monkeypatch.setattr(kda_op, "_SUB", sub)
     assert kda_op.takes_kernels(chunk, width) == kernels
     assert kda_op.kda_overlaps.supports(chunk, kda_op._sub(chunk), width) == kernels
     args = _scan_inputs(2 * chunk, "mixed", b=1, h=2, width=width)
     assert _pallas_calls(lambda *a: kda_op.kda_scan(*a, chunk), *args) == (
-        ["kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"] if kernels else [])
+        ["kda_prefix_fwd", "kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"] if kernels else [])
 
 
 def test_under_a_mesh_that_shards_the_heads_the_jnp_path_runs():
     """GSPMD cannot partition a Mosaic call: with an axis of the ambient mesh still automatic
-    the scan runs `_decayed_overlaps`, `_chunk_parts` and `_walk` at the kernels' own shape, partitioned
-    by the compiler, and is the single-device scan's value; with every axis of size one all
-    three pairs of kernels run."""
+    the scan runs `jnp.cumsum`, `_decayed_overlaps`, `_chunk_parts` and `_walk` at the kernels' own shape,
+    partitioned by the compiler, and is the single-device scan's value; with every axis of size one all
+    four pairs of kernels run."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from ray_tpu.parallel import MeshSpec, build_mesh, use_mesh
@@ -304,7 +373,7 @@ def test_under_a_mesh_that_shards_the_heads_the_jnp_path_runs():
     np.testing.assert_allclose(got, want, atol=1e-5 * float(jnp.abs(want).max()))
     with use_mesh(build_mesh(MeshSpec(dp=1), jax.devices()[:1])):
         assert kda_op.takes_kernels(32, 128) and _pallas_calls(scan, *args) == [
-            "kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"]
+            "kda_prefix_fwd", "kda_overlaps_fwd", "kda_parts_fwd", "kda_walk_fwd"]
 
 
 def test_the_scan_asserts_whole_chunks():

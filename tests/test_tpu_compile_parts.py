@@ -146,7 +146,9 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     chunks' four matrices two more with the same three calls (PR 51), the walk over the chunks two more
     (PR 61: twelve custom calls a part, no `while` left under the scan and no stored transpose of o or of its
     cotangent: the walk writes o and reads d o a head's positions together, the order XLA gives the norm, the gate and
-    the output product behind the scan),
+    the output product behind the scan), the running sum G two more (PR 64: fifteen custom calls a part, no
+    `reduce-window` under the scan, and no copy or transpose of g, G or their gradients in a pass of its own: the
+    kernels read g and write dg a head's positions together, the order XLA gives the decay's product),
     convolution, silu and norms of q, k and v likewise two kernels and three calls (PR 44), the
     inverse the compiler's own triangular kernel once a block, no float32 array with the
     extents of the differences, the sub-chunks' factors or the second half's right-hand
@@ -170,10 +172,11 @@ def test_kda_mixer_compiles_and_fits_at_the_cells_shape(one_chip, on_tpu):
     assert kernel_calls(text, "short_conv_fwd") == (1, 1) and kernel_calls(text, "short_conv_bwd") == (1, 0)
     assert kernel_calls(text, "kda_parts_fwd") == (1, 1) and kernel_calls(text, "kda_parts_bwd") == (1, 0)
     assert kernel_calls(text, "kda_walk_fwd") == (1, 1) and kernel_calls(text, "kda_walk_bwd") == (1, 0)
-    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 12
-    assert not instructions(text, "while", "kda_scan")
-    # (G's gradient has o's extents and does come back to the mixer's order, once, behind the running sum's backward)
-    assert not [ln for ln in stored_alone(text, WALK_OUTPUT, "kda_scan") if "jit(cumsum)" not in ln]
+    assert kernel_calls(text, "kda_prefix_fwd") == (1, 1) and kernel_calls(text, "kda_prefix_bwd") == (1, 0)
+    assert text.count("tpu_custom_call") == text.count('custom_call_target="tpu_custom_call"') == 15
+    assert not instructions(text, "while", "kda_scan") and not instructions(text, "reduce-window", "kda_scan")
+    # (g, G and their gradients have o's extents: none of them is copied or transposed in a pass of its own either)
+    assert not stored_alone(text, WALK_OUTPUT, "kda_scan")
     assert not re.search(CONV_PADDED_COPY, text)
     assert text.count('custom_call_target="InvertDiagBlocksLowerTriangular"') == cfg.kda_chunk // _SOLVE
     assert not re.search(OVERLAPS_INTERMEDIATES, text) and not re.search(PARTS_INTERMEDIATES, text)
